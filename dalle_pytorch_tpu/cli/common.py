@@ -19,7 +19,7 @@ import jax
 import numpy as np
 
 from dalle_pytorch_tpu import checkpoint as ckpt
-from dalle_pytorch_tpu.parallel import make_mesh
+from dalle_pytorch_tpu.parallel import make_mesh, replicate
 from dalle_pytorch_tpu.utils import MetricsLogger, StepProfiler, \
     enable_nan_checks
 
@@ -263,15 +263,19 @@ def add_common_args(parser: argparse.ArgumentParser,
                              "pinned to (docs/STATIC_ANALYSIS.md)")
 
 
-def step_rng(key, step: int):
-    """``fold_in(key, step)`` with the step counter shipped as an
-    EXPLICIT device transfer. Value-identical to ``fold_in(key, step)``
+def step_rng(key, step: int, mesh):
+    """``fold_in(key, step)`` with every crossing EXPLICIT: the step
+    counter shipped host->device, and the derived key placed replicated
+    over ``mesh`` — the jitted step runs on the whole mesh, and a key
+    left on the default device alone would be moved there by an implicit
+    device-to-device transfer. Value-identical to ``fold_in(key, step)``
     on a python int (fold_in folds the uint32 of the operand either
-    way), but eager fold_in on an int is an IMPLICIT host->device
-    transfer — the one thing ``--guard_transfers`` exists to catch —
-    so the per-step RNG derivation spells its transfer at the site,
-    like every other crossing in the guarded step body."""
-    return jax.random.fold_in(key, jax.device_put(np.uint32(step)))
+    way); eager fold_in on an int, like the implicit move, is the one
+    thing ``--guard_transfers`` exists to catch, so the per-step RNG
+    derivation spells its transfers at the site, like every other
+    crossing in the guarded step body."""
+    return replicate(mesh, jax.random.fold_in(
+        key, jax.device_put(np.uint32(step))))
 
 
 def resolve_schedule(args, steps_per_epoch: int = 0, start_epoch: int = 0,
@@ -570,7 +574,8 @@ def load_caption_dataset(args):
 
 
 def setup_run(args, unit_name: str = "tokens"):
-    """-> (mesh, MetricsLogger, StepProfiler). Applies NaN toggles/seeding.
+    """-> (mesh, MetricsLogger, StepProfiler). Applies NaN toggles/seeding,
+    places the compile cache (utils.device) and logs the device once.
 
     Joins the multi-host cluster first when configured (flags or env —
     parallel.multihost), so the mesh below spans every host's devices.
@@ -579,6 +584,9 @@ def setup_run(args, unit_name: str = "tokens"):
     failure record instead of hanging (resilience.retry)."""
     from dalle_pytorch_tpu.parallel.multihost import initialize
     from dalle_pytorch_tpu.resilience import BringupError, faults
+    from dalle_pytorch_tpu.utils.device import (describe_device,
+                                                enable_compile_cache)
+    enable_compile_cache()
     faults.maybe_activate_from_env()
     try:
         initialize(coordinator_address=args.coordinator or None,
@@ -592,6 +600,9 @@ def setup_run(args, unit_name: str = "tokens"):
         import json as _json
         raise SystemExit(
             "backend bring-up failed: " + _json.dumps(e.record)) from e
+    # the device this run actually got, once, first thing in every log:
+    # a jax that fell back to the CPU must not pass for a chip run
+    say(f"device: {describe_device()}")
     if args.nan_checks:
         enable_nan_checks(True)
     np.random.seed(args.seed)

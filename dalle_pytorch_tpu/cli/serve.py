@@ -379,8 +379,16 @@ def load_vocab(args):
     return Vocabulary.load(path)
 
 
-def main(argv=None):
+def start(argv=None):
+    """Everything ``main`` does short of the blocking HTTP loop: restore
+    the checkpoints, build and start the server (or the gateway over its
+    cells). -> ``(args, front, serve_loop)`` where ``serve_loop(front,
+    host, port)`` is the blocking loop for that front door. Split out so
+    a driver that must stop the server again (chip_smoke.py) starts it
+    exactly the way the CLI does."""
+    from dalle_pytorch_tpu.utils.device import enable_compile_cache
     args = build_parser().parse_args(argv)
+    enable_compile_cache()
 
     dalle_path = ckpt.ckpt_path(args.models_dir, f"{args.name}_dalle",
                                 args.dalle_epoch)
@@ -534,8 +542,7 @@ def main(argv=None):
         say(f"admin: POST /admin/tenants with Authorization: Bearer "
             f"{gw.admin_token} hot-reloads the tenant table; "
             f"GET /stats /metrics /tenants for the fleet surface")
-        serve_gateway_http(gw, args.host, args.port)
-        return
+        return args, gw, serve_gateway_http
 
     server = build_server()
     kv_desc = args.kv if args.kv == "dense" \
@@ -580,7 +587,12 @@ def main(argv=None):
             f"curl -s localhost:{args.port}/admin/scale -H "
             f"'Authorization: Bearer {server.admin_token}' -d "
             f"'{{\"op\": \"status\"}}'")
-    serve_http(server, args.host, args.port)
+    return args, server, serve_http
+
+
+def main(argv=None):
+    args, front, serve_loop = start(argv)
+    serve_loop(front, args.host, args.port)
 
 
 if __name__ == "__main__":

@@ -158,7 +158,7 @@ def main(argv=None):
         batch = sup.pre_step(state.global_step, batch)
         params, opt_state, loss = step(
             params, opt_state, batch,
-            step_rng(key, state.global_step))
+            step_rng(key, state.global_step, mesh))
         if ema is not None:
             ema = ema_update(ema, params)
         return loss, None

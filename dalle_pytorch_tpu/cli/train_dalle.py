@@ -143,7 +143,7 @@ def build_parser() -> argparse.ArgumentParser:
                         "near-zero FLOP cost), 'full' recomputes the whole "
                         "body (~1/3 more FLOPs, near-zero saved "
                         "activations) — the levers that let batches beyond "
-                        "16 fit one 16G chip (docs/ANALYSIS_NORTH.md)")
+                        "16 fit one 16G chip")
     p.set_defaults(name="test")
     return p
 
@@ -312,7 +312,7 @@ def main(argv=None):
         batch = sup.pre_step(state.global_step, batch)
         params, opt_state, loss = step(
             params, opt_state, batch,
-            step_rng(key, state.global_step))
+            step_rng(key, state.global_step, mesh))
         if ema is not None:
             ema = ema_update(ema, params)
         return loss, batch["text"]

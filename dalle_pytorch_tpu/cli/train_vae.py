@@ -41,7 +41,7 @@ from dalle_pytorch_tpu.cli.common import (LoopState, add_common_args,
 from dalle_pytorch_tpu.data import ImageFolderDataset, save_image_grid, \
     shard_for_host
 from dalle_pytorch_tpu.models import vae as V
-from dalle_pytorch_tpu.parallel import shard_batch
+from dalle_pytorch_tpu.parallel import replicate, shard_batch
 from dalle_pytorch_tpu.parallel.train import setup_sharded
 
 
@@ -220,15 +220,15 @@ def main(argv=None):
 
     def train_step(images, state):
         nonlocal params, opt_state, ema
-        # every host->device crossing is explicit (shard_batch's
-        # device_put, the device_put'd temperature scalar, step_rng) so
-        # the body runs clean under --guard_transfers
+        # every crossing is explicit and lands on the MESH (shard_batch's
+        # device_put, the replicated temperature scalar, step_rng) so
+        # the body runs clean under --guard_transfers at any dp
         batch = shard_batch(mesh, {"images": images})
-        batch["temperature"] = jax.device_put(np.float32(temperature))
+        batch["temperature"] = replicate(mesh, np.float32(temperature))
         batch = sup.pre_step(state.global_step, batch)
         params, opt_state, loss = step(
             params, opt_state, batch,
-            step_rng(key, state.global_step))
+            step_rng(key, state.global_step, mesh))
         if ema is not None:
             ema = ema_update(ema, params)
         return loss, batch

@@ -34,6 +34,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
 
+from dalle_pytorch_tpu.ops import core
 from dalle_pytorch_tpu.ops.flash_attention import (FILL, NUM_LANES,
                                                    NUM_SUBLANES,
                                                    blockwise_attention_bwd)
@@ -175,8 +176,17 @@ def _kernel(*refs, scale, causal, block_q, block_k, seq_len, has_mask, block,
     l_ref[0] = jnp.broadcast_to(l_safe, (block_q, NUM_LANES))
 
 
-def _bs_fwd(q, k, v, mask, scale, causal, block, num_local_blocks,
-            global_blocks, block_q, block_k, interpret):
+def _bs_fwd(q, k, v, mask, *static):
+    """The forward kernel, one shard of batch and heads per device under
+    a mesh (a Mosaic kernel cannot be auto-partitioned)."""
+    return core.shard_over_batch_and_heads(
+        lambda q, k, v, mask: _bs_fwd_local(q, k, v, mask, *static),
+        (q, k, v, mask), ["bhnd", "bhnd", "bhnd", "bn"],
+        ("bhnd", ("bhn", "bhn")))
+
+
+def _bs_fwd_local(q, k, v, mask, scale, causal, block, num_local_blocks,
+                  global_blocks, block_q, block_k, interpret):
     from dalle_pytorch_tpu.ops.flash_attention import _pad_seq
     b, h, n_orig, d = q.shape
     mult = max(block_q, block_k)
@@ -378,7 +388,7 @@ def block_sparse_attention(q: Array, k: Array, v: Array, *,
     if scale is None:
         scale = q.shape[-1] ** -0.5
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = core.pallas_interpret()
     n = q.shape[2]
     bq, bk = min(block_q, n), min(block_k, n)
     return _bs(q, k, v, mask, float(scale), bool(causal), int(block),

@@ -83,7 +83,7 @@ def layernorm(params: dict, x: Array, *, eps: float = 1e-5) -> Array:
     # Normalise in f32 for numerical stability, cast back to input dtype.
     # The two full-size f32 intermediates are tagged with checkpoint_name
     # so remat='save_ln' can drop EXACTLY these from the saved residuals
-    # (docs/ANALYSIS_NORTH.md: they dominate the un-rematerialized stack's
+    # (they dominate the un-rematerialized stack's
     # activation bytes — 2 x 4 bytes/elt vs the bf16 compute stream) while
     # keeping every matmul output saved. checkpoint_name is an identity
     # outside jax.checkpoint.
@@ -199,6 +199,77 @@ def positional_dropout(key: Optional[Array], x: Array, rate: float,
 
     mask = jnp.moveaxis(jax.vmap(pos_mask)(pos), 0, 1)
     return jnp.where(mask, x / keep, jnp.zeros_like(x))
+
+
+def pallas_interpret() -> bool:
+    """The one ``interpret=`` default of every Pallas kernel entry
+    (flash_attention, block_sparse, paged_attention): compiled by Mosaic
+    on a TPU backend, interpreted everywhere else — so the same kernel
+    code runs in tier-1 on the CPU mesh. Never a fallback: a kernel
+    Mosaic refuses fails the call."""
+    return jax.default_backend() != "tpu"
+
+
+def shard_over_batch_and_heads(fn, operands: Sequence[Optional[Array]],
+                               dims: Sequence[str], result_dims):
+    """``fn(*operands)``, run per device on its own shard of the batch
+    and head dimensions when traced under a device mesh — for functions
+    that hold a Pallas call. GSPMD cannot partition a Mosaic kernel by
+    itself ("Mosaic kernels cannot be automatically partitioned. Please
+    wrap the call in a shard_map"), so without this every dp/tp/fsdp
+    train step on more than one chip fails to compile the moment it
+    reaches a kernel. Attention kernels are independent per (batch, head).
+
+    The mesh is OBSERVED, not configured: the first operand carries the
+    enclosing jit's abstract mesh in its type. Which of its axes hold the
+    batch and the heads is the framework's naming convention
+    (parallel/mesh.py): ``dp`` and ``fsdp`` shard the batch, ``tp`` the
+    heads; an axis whose size does not divide the dimension is left out,
+    and every other axis and dimension is replicated. A guess that
+    differs from where XLA actually keeps the operands costs a reshard,
+    never correctness.
+
+    ``dims`` names each operand's dimensions, one letter each
+    (``"bhnd"``, ``"bn"``, ``"bhn"``), ``result_dims`` does the same for
+    ``fn``'s result pytree; an operand may be None (an absent pad mask)
+    and is passed through as None. Off a mesh (one device, or already
+    inside a fully manual shard_map) this is the plain call."""
+    from jax.sharding import AxisType, PartitionSpec as P
+    mesh = jax.typeof(operands[0]).sharding.mesh
+    auto = {a: n for a, n, t in zip(mesh.axis_names, mesh.axis_sizes,
+                                    mesh.axis_types)
+            if t != AxisType.Manual}
+    if all(n == 1 for n in auto.values()):
+        return fn(*operands)
+    sizes = dict(zip(dims[0], operands[0].shape))
+
+    def dividing(axes, dim):
+        kept, span = [], 1
+        for a in axes:
+            if a in auto and dim % (span * auto[a]) == 0:
+                kept.append(a)
+                span *= auto[a]
+        return tuple(kept) or None
+
+    axes = {"b": dividing(("dp", "fsdp"), sizes["b"]),
+            "h": dividing(("tp",), sizes["h"])}
+
+    def spec(names):
+        return P(*(axes.get(c) for c in names))
+
+    present = [i for i, x in enumerate(operands) if x is not None]
+
+    def local(*arrays):
+        full = [None] * len(operands)
+        for i, x in zip(present, arrays):
+            full[i] = x
+        return fn(*full)
+
+    return jax.shard_map(
+        local, mesh=mesh, in_specs=tuple(spec(dims[i]) for i in present),
+        out_specs=jax.tree.map(spec, result_dims),
+        axis_names=frozenset(auto), check_vma=False,
+    )(*(operands[i] for i in present))
 
 
 def neg_inf(dtype) -> Array:

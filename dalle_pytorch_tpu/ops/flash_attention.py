@@ -38,6 +38,9 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from dalle_pytorch_tpu.ops import core
 
 Array = jax.Array
 
@@ -151,7 +154,17 @@ def _pad_seq(x, mult, axis):
     return jnp.pad(x, widths)
 
 
-def _flash_fwd(q, k, v, mask, scale, causal, block_q, block_k, interpret):
+def _flash_fwd(q, k, v, mask, *static):
+    """The forward kernel, one shard of batch and heads per device under
+    a mesh (a Mosaic kernel cannot be auto-partitioned)."""
+    return core.shard_over_batch_and_heads(
+        lambda q, k, v, mask: _flash_fwd_local(q, k, v, mask, *static),
+        (q, k, v, mask), ["bhnd", "bhnd", "bhnd", "bn"],
+        ("bhnd", ("bhn", "bhn")))
+
+
+def _flash_fwd_local(q, k, v, mask, scale, causal, block_q, block_k,
+                     interpret):
     b, h, n_orig, d = q.shape
     # pad to tile multiples — pl.ds CLAMPS out-of-bounds starts
     # (dynamic_slice semantics), so ragged tails must be padded, not read
@@ -453,9 +466,20 @@ _bwd_dkv_kernel = functools.partial(_bwd_keygrid_kernel, with_dq=False)
 _bwd_fused_kernel = functools.partial(_bwd_keygrid_kernel, with_dq=True)
 
 
-def _pallas_attention_bwd(q, k, v, mask, dout, out, softmax_stats, *,
-                          scale, causal, block_q, block_k, interpret,
-                          fused: bool = False):
+def _pallas_attention_bwd(q, k, v, mask, dout, out, softmax_stats, **kw):
+    """The Pallas backward, sharded over batch and heads under a mesh
+    like the forward."""
+    return core.shard_over_batch_and_heads(
+        lambda q, k, v, mask, dout, out, m, l: _pallas_attention_bwd_local(
+            q, k, v, mask, dout, out, (m, l), **kw),
+        (q, k, v, mask, dout, out, *softmax_stats),
+        ["bhnd", "bhnd", "bhnd", "bn", "bhnd", "bhnd", "bhn", "bhn"],
+        ("bhnd", "bhnd", "bhnd"))
+
+
+def _pallas_attention_bwd_local(q, k, v, mask, dout, out, softmax_stats, *,
+                                scale, causal, block_q, block_k, interpret,
+                                fused: bool = False):
     """Pallas counterpart of ``blockwise_attention_bwd`` (dense/causal/pad
     only — the sparse layout keeps the XLA blockwise path). ``fused``
     selects the single-pass kernel (_bwd_fused_kernel) over the split
@@ -524,6 +548,11 @@ def _pallas_attention_bwd(q, k, v, mask, dout, out, softmax_stats, *,
             out_shape=[jax.ShapeDtypeStruct((bh, n, d), jnp.float32),
                        jax.ShapeDtypeStruct((bh, n, d), k.dtype),
                        jax.ShapeDtypeStruct((bh, n, d), v.dtype)],
+            # the dq block is REVISITED along the key-tile axis (zeroed
+            # at ik == 0, accumulated after): that axis must run in
+            # order on one core
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "arbitrary")),
             interpret=interpret,
         )(*mask_inputs, qf, kf, vf, dof, *stats)
         dq = dq.astype(q.dtype).reshape(b, h, n, d)[:, :, :n_orig]
@@ -652,7 +681,7 @@ def flash_attention(q: Array, k: Array, v: Array, *,
     if scale is None:
         scale = q.shape[-1] ** -0.5
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = core.pallas_interpret()
     if bwd_impl not in ("xla", "pallas", "pallas_fused"):
         raise ValueError(f"unknown bwd_impl {bwd_impl!r}")
     n = q.shape[2]

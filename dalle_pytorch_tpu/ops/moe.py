@@ -105,7 +105,9 @@ def moe_apply(params: dict, x: Array, *, cfg: MoEConfig
         keep = (ranks < cap)[:, None, :] * onehot        # (n, k, E)
 
         # dispatch: binary (n, E, C); combine: gate-weighted dispatch
-        pos = jax.nn.one_hot(ranks, cap, dtype=jnp.float32)    # (n, E, C)
+        # ranks are whole numbers carried in f32 (a cumsum of one-hots)
+        pos = jax.nn.one_hot(ranks.astype(jnp.int32), cap,
+                             dtype=jnp.float32)            # (n, E, C)
         dispatch = jnp.einsum("tke,tec->tec", keep, pos)
         combine = jnp.einsum("tke,tk,tec->tec", keep, gate_vals, pos)
 

@@ -11,25 +11,35 @@ a kernel that consumes the block tables IN PLACE.
 Shape of the computation (one ``pl.pallas_call`` per layer, inside the
 engine's fused K-step decode scan):
 
-  * grid = ``(slots, heads // head_tile)`` — one program per
-    (slot, head-tile), the ragged-paged-attention program shape;
-  * each program reads its slot's ``pos`` and block-table row from SMEM
-    and walks ``ceil(pos / page_size)`` pages — RAGGED per-slot trip
-    counts: a slot 10 tokens into a 1280-token sequence touches 1 page,
-    not 80, and a dead slot parked at pos 0 touches none (the reserved
-    trash page is never read);
-  * pages live in HBM (``memory_space=ANY``) and are staged into VMEM
-    scratch by explicit double-buffered async copies — page ``p+1``'s
-    DMA is in flight while page ``p`` is on the MXU, the guide's
-    canonical pipeline (the pool never transits VMEM whole, which is
-    what the dense-view gather effectively forces);
+  * grid = ``(slots, heads // head_tile, trips)`` — one (slot,
+    head-tile) pair per output block, walked page by page along the
+    innermost (sequential) trip axis;
+  * the per-slot walk state — ``pos``, the block-table row and, for a
+    sparse layer, the visible-page list — is SCALAR-PREFETCHED into
+    SMEM, and the K/V block specs' index maps chase the block table
+    through it: trip ``p`` of slot ``i`` stages physical page
+    ``block_tables[i, p]``. Pages stay in HBM and transit VMEM one at
+    a time, double-buffered by the Pallas pipeline — page ``p+1``'s
+    copy is in flight while page ``p`` is on the MXU (the pool never
+    transits VMEM whole, which is what the dense-view gather
+    effectively forces). Mosaic cannot slice a ``dim_head`` = 64 page
+    out of an HBM ref by hand (a manual ``make_async_copy`` window must
+    be 128-lane aligned), which is why the staging is the block
+    spec's;
+  * the walk is RAGGED per slot: a slot 10 tokens into a 1280-token
+    sequence computes on 1 page, not 80. Trips past the slot's
+    ``ceil(pos / page_size)`` re-address its last live page, so they
+    copy nothing (the pipeline skips a block it already holds) and
+    compute nothing; a dead slot parked at pos 0 holds the reserved
+    trash page and never reads it;
   * attention is the online-softmax recurrence over pages
-    (flash-attention's m/l bookkeeping), returning UNNORMALIZED
-    partials ``(acc, m, l)`` over the cached rows only — the caller
-    (``ops.decode._decode_step_math``) folds in the current token's
-    self-logit with the standard two-estimate softmax merge, which is
-    exactly ``softmax(concat([scores, self]))`` up to summation order;
-  * the int8-KV pool dequantizes PER PAGE: int8 K/V pages DMA in as
+    (flash-attention's m/l bookkeeping) carried in the revisited output
+    blocks, returning UNNORMALIZED partials ``(acc, m, l)`` over the
+    cached rows only — the caller (``ops.decode._decode_step_math``)
+    folds in the current token's self-logit with the standard
+    two-estimate softmax merge, which is exactly
+    ``softmax(concat([scores, self]))`` up to summation order;
+  * the int8-KV pool dequantizes PER PAGE: int8 K/V pages stage as
     int8 (half the bytes — the point of int8-KV), and the per-row f32
     scales apply outside the contractions, mirroring the gather path's
     register-upcast trick.
@@ -45,10 +55,10 @@ parity ORACLE: it is token-equal to the dense cache by construction,
 so any kernel regression surfaces as a diff against it rather than as
 silently wrong images.
 
-``interpret=None`` auto-selects the Pallas interpreter off-TPU (the
-flash_attention convention), so the same code path runs in tier-1 on
-the CPU mesh — including the DMA pipeline, which the interpreter
-emulates.
+``interpret=None`` takes ``ops.core.pallas_interpret()`` (compiled on a
+TPU, the Pallas interpreter elsewhere), so the same code path runs in
+tier-1 on the CPU mesh — including the scalar-prefetch pipeline, which
+the interpreter emulates.
 """
 
 from __future__ import annotations
@@ -85,130 +95,101 @@ FILL = -float(jnp.finfo(jnp.float32).max)
 NUM_LANES = 128        # f32 VREG lane width — m/l stats stored broadcast
 
 
-def _kernel(pos_ref, bt_ref, *refs,
-            scale: float, page_size: int, head_tile: int,
-            quantized: bool, visible: bool):
-    """One (slot, head-tile) program: walk the slot's mapped pages with
-    double-buffered HBM->VMEM DMA, accumulate the online softmax.
+def _walk(i, p, pos_ref, vis_refs, page_size: int):
+    """(live, logical page) of trip ``p`` of slot ``i``'s walk, from the
+    scalar-prefetched per-slot state. The prefix walk visits logical
+    pages ``0..ceil(pos/ps)``; the sparsity-aware walk (``vis_refs`` =
+    (visible, visible_cnt)) follows the slot's precomputed visible-page
+    LIST instead. Trips past the slot's ragged count re-address its
+    LAST live page (a dead slot parked at pos 0: its table's column 0,
+    the trash page), so consecutive dead trips name the block already
+    resident and the pipeline issues no copy for them. Shared by the
+    K/V index maps and the kernel body so the page a trip computes on
+    is by construction the page its block spec staged."""
+    if vis_refs:
+        vis_ref, cnt_ref = vis_refs
+        n_pages = cnt_ref[i]
+    else:
+        n_pages = lax.div(pos_ref[i] + (page_size - 1), page_size)
+    pc = jnp.minimum(p, jnp.maximum(n_pages - 1, 0))
+    return p < n_pages, (vis_ref[i, pc] if vis_refs else pc)
 
-    ``visible=True`` is the sparsity-aware walk: instead of the prefix
-    ``0..ceil(pos/ps)``, the trip follows the slot's precomputed
-    visible-page LIST (``vis_ref``, ascending logical page ids,
-    ``cnt_ref`` live entries — ops.sparse.visible_pages with the
-    token-causal trim applied by the caller). Skipped pages carry
+
+def _kernel(*refs, scale: float, page_size: int, head_tile: int,
+            quantized: bool, visible: bool):
+    """One (slot, head-tile, trip) grid step: fold the trip's staged
+    K/V page into the slot's online softmax.
+
+    The scalar-prefetch operands come first (whole arrays in SMEM):
+    ``pos`` (b,), ``block_tables`` (b, max_pages) and, under
+    ``visible=True``, the sparsity-aware walk's ``visible`` (b, W) page
+    list and ``visible_cnt`` (b,). The K/V (and scale) refs are the ONE
+    page this trip's index map selected through the block table; the
+    output blocks are revisited across the trip axis and carry the
+    running (acc, m, l). Under the visible walk, skipped pages carry
     exactly-zero softmax weight under the finite FILL, so the online
     recurrence over the remaining (still ascending) pages is bit-equal
     to the prefix walk: max(m, FILL)=m, l*exp(0)+0=l, acc*1+0=acc."""
-    if visible:
-        vis_ref, cnt_ref, *refs = refs
-    q_ref, allowed_ref, k_ref, v_ref, *refs = refs
+    n_prefetch = 4 if visible else 2
+    pos_ref, _bt_ref, *vis_refs = refs[:n_prefetch]
+    q_ref, allowed_ref, k_ref, v_ref, *refs = refs[n_prefetch:]
     if quantized:
-        (ksc_ref, vsc_ref, acc_ref, m_ref, l_ref,
-         kbuf, vbuf, kscb, vscb, sem_k, sem_v, sem_ks, sem_vs) = refs
+        ksc_ref, vsc_ref, acc_ref, m_ref, l_ref = refs
     else:
-        acc_ref, m_ref, l_ref, kbuf, vbuf, sem_k, sem_v = refs
-    t = pl.program_id(1)
-    ps, ht = page_size, head_tile
-    posi = pos_ref[0, 0]
-    # ragged trip count: rows [0, pos) span ceil(pos/ps) pages; a dead
-    # slot parked at pos 0 walks ZERO pages (its block-table entry 0
-    # points at the trash page, which is therefore never fetched).
-    # Under the visible walk the count is the precomputed per-slot
-    # visible-page count instead — same raggedness, fewer trips.
-    n_pages = cnt_ref[0, 0] if visible \
-        else lax.div(posi + (ps - 1), ps)
-    heads0 = t * ht
+        acc_ref, m_ref, l_ref = refs
+    i = pl.program_id(0)
+    p = pl.program_id(2)
+    ht = head_tile
+    live, lp = _walk(i, p, pos_ref, vis_refs, page_size)
 
-    def logical(p):
-        """Trip p's LOGICAL page id: p itself on the prefix walk, the
-        p-th visible page on the sparsity-aware walk."""
-        return vis_ref[0, p] if visible else p
+    @pl.when(p == 0)
+    def _init():
+        # a slot that walks zero pages returns exactly (0, FILL, 0)
+        acc_ref[0] = jnp.zeros(acc_ref.shape[1:], jnp.float32)
+        m_ref[0] = jnp.full(m_ref.shape[1:], FILL, jnp.float32)
+        l_ref[0] = jnp.zeros(l_ref.shape[1:], jnp.float32)
 
-    def copies(slot, p):
-        """The (slot, page) DMA descriptor set — recreated identically
-        for start and wait (the wait must describe the copy it joins)."""
-        page = bt_ref[0, logical(p)]
-        hs = pl.ds(heads0, ht)
-        out = [pltpu.make_async_copy(k_ref.at[page, hs], kbuf.at[slot],
-                                     sem_k.at[slot]),
-               pltpu.make_async_copy(v_ref.at[page, hs], vbuf.at[slot],
-                                     sem_v.at[slot])]
-        if quantized:
-            out += [pltpu.make_async_copy(ksc_ref.at[page, hs],
-                                          kscb.at[slot], sem_ks.at[slot]),
-                    pltpu.make_async_copy(vsc_ref.at[page, hs],
-                                          vscb.at[slot], sem_vs.at[slot])]
-        return out
-
-    @pl.when(n_pages > 0)
-    def _warm():
-        for dma in copies(0, 0):
-            dma.start()
-
-    q = q_ref[0]                                           # (ht, dh)
-
-    def body(p, carry):
-        m, l, acc = carry             # (ht, 1), (ht, 1), (ht, dh) f32
-        slot = lax.rem(p, 2)
-        nxt = lax.rem(p + 1, 2)
-
-        # overlap: page p+1 streams in while page p is on the MXU
-        @pl.when(p + 1 < n_pages)
-        def _prefetch():
-            for dma in copies(nxt, p + 1):
-                dma.start()
-
-        for dma in copies(slot, p):
-            dma.wait()
-
-        ok = allowed_ref[0, pl.ds(logical(p) * ps, ps)] != 0   # (ps,)
-        # per-head 2-D MXU dots (static unroll over the tile): q_h
-        # (1, dh) x page (ps, dh)^T -> (1, ps) scores in f32
-        s_rows, pv_holder = [], []
+    @pl.when(live)
+    def _page():
+        q = q_ref[0]                                       # (ht, dh)
+        # the page's mask row: allowed is laid out (max_pages, ps), so
+        # a page is one sublane row (a dynamic LANE slice of ps
+        # elements is not addressable)
+        ok = allowed_ref[0, pl.ds(lp, 1), :] != 0          # (1, ps)
+        # per-head 2-D row tiles (static unroll over the tile): Mosaic
+        # has no sublane concatenate for 1-row pieces, so the heads of
+        # a tile never meet in one array
         for h in range(ht):
-            kb = kbuf[slot, h]
+            row = slice(h, h + 1)
+            m = m_ref[0, row, :1]                          # (1, 1)
+            l = l_ref[0, row, :1]
+            kb, vb = k_ref[h], v_ref[h]                    # (ps, dh)
             if quantized:
-                kb = kb.astype(q.dtype)
-            s_h = lax.dot_general(
-                q[h][None, :], kb, (((1,), (1,)), ((), ())),
+                kb, vb = kb.astype(q.dtype), vb.astype(q.dtype)
+            # q_h (1, dh) x page (ps, dh)^T -> (1, ps) scores in f32
+            s = lax.dot_general(
+                q[row], kb, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32) * scale
             if quantized:
                 # scales OUTSIDE the contraction — no dequantized page
                 # copy materializes (ops/decode.py's int8 discipline)
-                s_h = s_h * kscb[slot, h][None, :]
-            s_rows.append(s_h)
-        s = jnp.concatenate(s_rows, axis=0)                # (ht, ps)
-        s = jnp.where(ok[None, :], s, FILL)
-
-        m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
-        pexp = jnp.exp(s - m_new)                          # (ht, ps)
-        alpha = jnp.exp(m - m_new)
-        l = l * alpha + pexp.sum(axis=-1, keepdims=True)
-        wj = pexp
-        if quantized:
-            wj = wj * vscb[slot]                           # (ht, ps)
-        for h in range(ht):
-            vb = vbuf[slot, h]
+                s = s * ksc_ref[row]
+            s = jnp.where(ok, s, FILL)
+            m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
+            pexp = jnp.exp(s - m_new)                      # (1, ps)
+            alpha = jnp.exp(m - m_new)
+            l = l * alpha + pexp.sum(axis=-1, keepdims=True)
+            wj = pexp
             if quantized:
-                vb = vb.astype(q.dtype)
-            pv_holder.append(lax.dot_general(
-                wj[h][None, :], vb, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32))       # (1, dh)
-        acc = acc * alpha + jnp.concatenate(pv_holder, axis=0)
-        return m_new, l, acc
-
-    dh = q_ref.shape[-1]
-    m0 = jnp.full((ht, 1), FILL, jnp.float32)
-    l0 = jnp.zeros((ht, 1), jnp.float32)
-    a0 = jnp.zeros((ht, dh), jnp.float32)
-    m, l, acc = lax.fori_loop(0, n_pages, body, (m0, l0, a0))
-
-    acc_ref[0] = acc
-    # lane-broadcast stats tiles (the flash_attention layout): Mosaic
-    # wants the last dim to be a 128-lane tile, and the caller reads
-    # lane 0
-    m_ref[0] = jnp.broadcast_to(m, (ht, NUM_LANES))
-    l_ref[0] = jnp.broadcast_to(l, (ht, NUM_LANES))
+                wj = wj * vsc_ref[row]
+            acc_ref[0, row] = acc_ref[0, row] * alpha + lax.dot_general(
+                wj.astype(vb.dtype), vb, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)        # (1, dh)
+            # lane-broadcast stats tiles (the flash_attention layout):
+            # Mosaic wants the last dim to be a 128-lane tile, and the
+            # caller reads lane 0
+            m_ref[0, row] = jnp.broadcast_to(m_new, (1, NUM_LANES))
+            l_ref[0, row] = jnp.broadcast_to(l, (1, NUM_LANES))
 
 
 def paged_decode_attention(q: Array, k_pages: Array, v_pages: Array,
@@ -260,7 +241,7 @@ def paged_decode_attention(q: Array, k_pages: Array, v_pages: Array,
                          "visible-page list is meaningless without its "
                          "per-slot live count (and vice versa)")
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = core.pallas_interpret()
     ht = int(head_tile) or heads
     if heads % ht:
         raise ValueError(f"head_tile {ht} must divide heads {heads}")
@@ -285,63 +266,61 @@ def paged_decode_attention(q: Array, k_pages: Array, v_pages: Array,
         _kernel, scale=float(scale), page_size=page_size, head_tile=ht,
         quantized=quantized, visible=visible is not None)
 
-    in_specs = [
-        pl.BlockSpec((1, 1), lambda i, t: (i, 0),
-                     memory_space=pltpu.SMEM),              # pos
-        pl.BlockSpec((1, max_pages), lambda i, t: (i, 0),
-                     memory_space=pltpu.SMEM),              # block table
-    ]
-    inputs = [pos.astype(jnp.int32).reshape(b, 1),
-              block_tables.astype(jnp.int32)]
+    # scalar prefetch: the per-slot walk state (positions, block tables,
+    # visible-page lists) lands whole in SMEM before the body runs, so
+    # the K/V index maps can chase the block table: trip p of slot i
+    # stages physical page block_tables[i, logical(p)], and the
+    # pipeline double-buffers — page p+1's copy is in flight while page
+    # p is on the MXU
+    prefetch = [pos.astype(jnp.int32), block_tables.astype(jnp.int32)]
+    trips = max_pages
     if visible is not None:
-        w_vis = visible.shape[1]
-        in_specs += [
-            pl.BlockSpec((1, w_vis), lambda i, t: (i, 0),
-                         memory_space=pltpu.SMEM),          # visible list
-            pl.BlockSpec((1, 1), lambda i, t: (i, 0),
-                         memory_space=pltpu.SMEM),          # visible count
-        ]
-        inputs += [visible.astype(jnp.int32),
-                   visible_cnt.astype(jnp.int32).reshape(b, 1)]
-    in_specs += [
-        pl.BlockSpec((1, ht, dh), lambda i, t: (i, t, 0)),  # q tile
-        pl.BlockSpec((1, L_pages), lambda i, t: (i, 0)),    # allowed row
-        pl.BlockSpec(memory_space=pltpu.ANY),               # K pool (HBM)
-        pl.BlockSpec(memory_space=pltpu.ANY),               # V pool (HBM)
+        prefetch += [visible.astype(jnp.int32),
+                     visible_cnt.astype(jnp.int32)]
+        trips = visible.shape[1]
+
+    def tile_map(i, t, p, *_):
+        return i, t, 0
+
+    def page_map(i, t, p, pos_ref, bt_ref, *vis_refs):
+        _, lp = _walk(i, p, pos_ref, vis_refs, page_size)
+        return bt_ref[i, lp], t, 0, 0
+
+    in_specs = [
+        pl.BlockSpec((1, ht, dh), tile_map),               # q tile
+        pl.BlockSpec((1, max_pages, page_size),
+                     lambda i, t, p, *_: (i, 0, 0)),       # allowed rows
+        pl.BlockSpec((None, ht, page_size, dh), page_map),  # K page
+        pl.BlockSpec((None, ht, page_size, dh), page_map),  # V page
     ]
-    inputs += [q, allowed.astype(jnp.int32), k_pages, v_pages]
-    scratch = [
-        pltpu.VMEM((2, ht, page_size, dh), k_pages.dtype),  # K double buf
-        pltpu.VMEM((2, ht, page_size, dh), v_pages.dtype),  # V double buf
-        pltpu.SemaphoreType.DMA((2,)),
-        pltpu.SemaphoreType.DMA((2,)),
-    ]
+    inputs = [q, allowed.astype(jnp.int32).reshape(b, max_pages, page_size),
+              k_pages, v_pages]
     if quantized:
-        in_specs += [pl.BlockSpec(memory_space=pltpu.ANY)] * 2
+        in_specs += [pl.BlockSpec((None, ht, page_size),
+                                  lambda *a: page_map(*a)[:3])] * 2
         inputs += [k_scales, v_scales]
-        scratch = scratch[:2] + [
-            pltpu.VMEM((2, ht, page_size), jnp.float32),
-            pltpu.VMEM((2, ht, page_size), jnp.float32),
-        ] + scratch[2:] + [pltpu.SemaphoreType.DMA((2,)),
-                           pltpu.SemaphoreType.DMA((2,))]
 
     acc, m, l = pl.pallas_call(
         kernel,
-        grid=(b, heads // ht),
-        in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((1, ht, dh), lambda i, t: (i, t, 0)),
-            pl.BlockSpec((1, ht, NUM_LANES), lambda i, t: (i, t, 0)),
-            pl.BlockSpec((1, ht, NUM_LANES), lambda i, t: (i, t, 0)),
-        ],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(prefetch),
+            grid=(b, heads // ht, trips),
+            in_specs=in_specs,
+            out_specs=[pl.BlockSpec((1, ht, dh), tile_map),
+                       pl.BlockSpec((1, ht, NUM_LANES), tile_map),
+                       pl.BlockSpec((1, ht, NUM_LANES), tile_map)]),
         out_shape=[
             jax.ShapeDtypeStruct((b, heads, dh), jnp.float32),
             jax.ShapeDtypeStruct((b, heads, NUM_LANES), jnp.float32),
             jax.ShapeDtypeStruct((b, heads, NUM_LANES), jnp.float32),
         ],
-        scratch_shapes=scratch,
+        # the (acc, m, l) output blocks are revisited along the trip
+        # axis — it must run in order on one core; slots and head
+        # tiles are independent
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(*inputs)
+    )(*prefetch, *inputs)
     return acc, m[:, :, 0], l[:, :, 0]
 
 

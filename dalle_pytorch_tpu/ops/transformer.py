@@ -144,8 +144,8 @@ def _maybe_remat(body, mode: str):
     save EVERYTHING except the two tagged f32 layernorm intermediates per
     block (core.layernorm's checkpoint_names) — the cheapest possible
     recompute (a layernorm each) for the bytes that actually drive OOM
-    (docs/ANALYSIS_NORTH.md: 8 f32 saves/layer dominate the flash stack's
-    activation footprint)."""
+    (8 f32 saves/layer dominate the flash stack's activation
+    footprint)."""
     if mode == "full":
         return jax.checkpoint(body)
     if mode == "dots":

@@ -1,70 +1,6 @@
-"""shard_map across jax versions — the one import the parallel package
-gates instead of letting version drift break every downstream import.
-
-The code targets jax >= 0.8 (``jax.shard_map`` with ``axis_names=``:
-partial-manual lowering where unnamed mesh axes stay GSPMD auto axes).
-Containers pinned to jax 0.4.x ship the same capability under
-``jax.experimental.shard_map.shard_map`` with the COMPLEMENT parameter:
-``auto=`` names the axes that stay automatic, and replication checking
-must be off for them. One adapter here keeps ring/sequence/pipeline
-importable on both — before this gate, a 0.4.x environment lost the
-entire parallel package (and everything importing it) to a single
-top-level ImportError.
-"""
+"""The one jit-donation gate the parallel and serve packages share."""
 
 from __future__ import annotations
-
-try:                                    # jax >= 0.8: top-level export
-    from jax import shard_map as _shard_map
-    _AXIS_NAMES_KW = True
-except ImportError:                     # jax 0.4.x fallback
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _AXIS_NAMES_KW = False
-
-# True when partial-manual lowering (auto axes riding through a manual
-# shard_map) is usable — callers (e.g. __graft_entry__.dryrun_multichip)
-# drop to fully-manual meshes when it is not.
-SUPPORTS_PARTIAL_MANUAL = _AXIS_NAMES_KW
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, axis_names=None, **kw):
-    """``jax.shard_map``-compatible wrapper. ``axis_names`` is the set of
-    MANUAL axes (None = all of them, both APIs' default)."""
-    if _AXIS_NAMES_KW:
-        if axis_names is not None:
-            kw["axis_names"] = axis_names
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, **kw)
-    # 0.4.x's replication checker predates primitives this codebase uses
-    # (e.g. the remat ``name`` tag from checkpoint_name: "No replication
-    # rule for name"); it is a static checker only, so disable it on the
-    # legacy path rather than lose shard_map entirely
-    kw.setdefault("check_rep", False)
-    if axis_names is not None:
-        auto = frozenset(mesh.axis_names) - frozenset(axis_names)
-        if auto:
-            # 0.4.x's experimental ``auto=`` can hard-ABORT inside XLA
-            # compile (observed on 0.4.37: partial-manual over a
-            # dp x tp x sp mesh kills the interpreter, taking a whole
-            # test session with it). Refuse cleanly instead: the caller
-            # sees a normal exception, the process survives.
-            raise NotImplementedError(
-                "partial-manual shard_map (auto axes "
-                f"{sorted(map(str, auto))}) requires jax>=0.8; this "
-                "environment has the 0.4.x experimental API, whose "
-                "auto-axis lowering is unstable")
-    return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, **kw)
-
-
-def pcast_varying(x, axes):
-    """``lax.pcast(x, axes, to="varying")`` on jax >= 0.8 (the varying-
-    manual-axes marking its replication checker requires); identity on
-    0.4.x, whose shard_map tracks replication without explicit casts."""
-    from jax import lax
-    if hasattr(lax, "pcast"):
-        return lax.pcast(x, tuple(axes), to="varying")
-    return x
 
 
 def donate_if_accelerator(*argnums: int) -> tuple:

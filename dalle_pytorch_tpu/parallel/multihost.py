@@ -62,9 +62,8 @@ def initialize(coordinator_address: Optional[str] = None,
     races it against a fresh call. That is acceptable for the wedge this
     defends against (the abandoned call is stuck in connect and never
     mutates the client), but a retried init that merely *straggles* can
-    interleave with its successor — bench avoids this by re-exec'ing a
-    fresh process per attempt (claim_backend), which is the right model
-    for anything beyond a launcher; see ROADMAP open items.
+    interleave with its successor — a fresh process per attempt is the
+    right model for anything beyond a launcher.
 
     Idempotent: a second call (same process) is a no-op returning True.
     """

@@ -31,12 +31,10 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+# shard_map(axis_names=...) keeps non-pipeline mesh axes (e.g. 'ep') as
+# GSPMD auto axes
+from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-# jax >= 0.8 required (pyproject pin): shard_map(axis_names=...) keeps
-# non-pipeline mesh axes (e.g. 'ep') as GSPMD auto axes
-from dalle_pytorch_tpu.parallel._compat import pcast_varying, shard_map
 
 Array = jax.Array
 
@@ -122,7 +120,7 @@ def pipeline_transformer(params, x: Array, *, cfg, mesh: Mesh,
     def stage_fn(stage_params, xm, maskm, rng):
         sp = jax.tree.map(lambda a: a[0], stage_params)   # local layer slice
         # static stage count from the enclosing mesh (== the manual axis
-        # size; lax.axis_size is a jax>=0.8 addition — see parallel._compat)
+        # size)
         P_ = num_stages
         idx = lax.axis_index(axis)
         ticks = M + P_ - 1
@@ -159,9 +157,10 @@ def pipeline_transformer(params, x: Array, *, cfg, mesh: Mesh,
             # from the activations, while the dense stack's aux is a
             # literal 0.0 constant (non-varying) — match each case
             if cfg.moe_experts:
-                zero_aux = pcast_varying(
+                zero_aux = lax.pcast(
                     jnp.float32(0.0),
-                    tuple(a for a in (axis, dp_axis) if a is not None))
+                    tuple(a for a in (axis, dp_axis) if a is not None),
+                    to="varying")
             else:
                 zero_aux = jnp.float32(0.0)
             out, aux = lax.cond(active, run, lambda h: (h, zero_aux), h)
@@ -171,7 +170,7 @@ def pipeline_transformer(params, x: Array, *, cfg, mesh: Mesh,
 
         # the carry is device-varying over pp (each stage holds a different
         # microbatch's activations) — mark the zero init accordingly
-        state0 = pcast_varying(jnp.zeros_like(xm[0]), (axis,))
+        state0 = lax.pcast(jnp.zeros_like(xm[0]), (axis,), to="varying")
         _, (outs, auxs) = lax.scan(tick, state0,
                                    (jnp.arange(ticks), stream[:ticks],
                                     masks))

@@ -31,12 +31,8 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-# jax >= 0.8 required (pyproject pin) — same discipline as
-# parallel.sequence / parallel.pipeline
-from dalle_pytorch_tpu.parallel._compat import shard_map
 
 
 def _online_block(carry, kb, vb, q, scale, allow, pair_ok=None):

@@ -39,7 +39,9 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+# shard_map(axis_names=...) is partial-manual lowering: unnamed mesh axes
+# stay GSPMD auto axes
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from dalle_pytorch_tpu.ops import attention as attn_ops
@@ -47,11 +49,6 @@ from dalle_pytorch_tpu.ops import core
 from dalle_pytorch_tpu.ops import transformer as T
 from dalle_pytorch_tpu.parallel.ring import (ring_attention_local,
                                              ulysses_attention_local)
-
-# jax >= 0.8 required: this module leans on shard_map(axis_names=...)
-# (partial-manual lowering) which the old experimental shard_map lacks —
-# a silent fallback would only defer the failure to every call site
-from dalle_pytorch_tpu.parallel._compat import shard_map
 
 
 def _check_cfg(cfg: T.TransformerConfig) -> None:

@@ -154,6 +154,15 @@ def setup_sharded(params, optimizer, mesh: Mesh, param_specs=None,
                 opt_state, is_leaf=is_param_tree)
     if opt_state is None:
         opt_state = jax.jit(optimizer.init)(params)
+        # leaves that do not depend on the params (adam's step count) are
+        # constants jit materializes on the default device alone; place
+        # them on the mesh HERE, explicitly, or the first train step moves
+        # them with an implicit device-to-device transfer (which
+        # --guard_transfers refuses on any mesh wider than one device)
+        devices = set(mesh.devices.flat)
+        opt_state = jax.tree.map(
+            lambda a: a if a.sharding.device_set == devices
+            else jax.device_put(a, NamedSharding(mesh, P())), opt_state)
     return params, opt_state
 
 

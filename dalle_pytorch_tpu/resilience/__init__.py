@@ -1,12 +1,11 @@
 """Fault-tolerance runtime (ISSUE 1): the failure modes that dominate long
 pod runs — wedged backend bring-up, preemption, loss-spike divergence, and
 flaky data paths — handled as first-class, *tested* behavior instead of
-11-hour losses (BENCH_r05.json rc=1: one wedged TPU init cost the whole
-round-5 window).
+lost runs.
 
 Layout:
   * ``retry``      — deadline + exponential backoff + jitter bring-up,
-                     shared by parallel.multihost, bench.py and the CLIs;
+                     shared by parallel.multihost, the server and the CLIs;
                      failures degrade to a structured record, never a hang.
   * ``supervisor`` — the supervised train-step wrapper the training CLIs
                      use: SIGTERM/SIGINT preemption checkpoints, cadence

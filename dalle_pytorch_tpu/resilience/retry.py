@@ -1,10 +1,10 @@
 """Backend/cluster bring-up under a deadline, with backoff + jitter.
 
-Round 5 lost its whole ~11-hour window to ONE wedged TPU backend init
-(BENCH_r05.json rc=1): ``jax.devices()`` pended inside the claim with no
-deadline and nothing retried. This module is the single bring-up discipline
-every entry point shares — ``parallel.multihost.initialize`` (the CLIs) and
-``bench.claim_backend`` both route through it:
+A backend or cluster join can pend forever (``jax.devices()`` inside a
+wedged claim has no deadline of its own, and nothing retries it). This
+module is the single bring-up discipline the entry points share —
+``parallel.multihost.initialize`` (the training CLIs) and
+``InferenceServer.start`` both route through it:
 
   * ``call_with_deadline`` — run a claim in a daemon thread; if it does not
     finish by the deadline, raise ``DeadlineExceeded`` (the wedged thread is
@@ -76,7 +76,7 @@ class RetryPolicy:
 def failure_record(label: str, errors: Sequence[str], attempts: int,
                    elapsed_s: float, **extra) -> dict:
     """The one structured shape for terminal bring-up failures (shared by
-    multihost init, bench's claim, and the tests that assert on it)."""
+    multihost init, the server's claim, and the tests that assert on it)."""
     from dalle_pytorch_tpu.utils.metrics import structured_event
     return structured_event("bringup_failure", label=label,
                             attempts=attempts, errors=list(errors),
@@ -90,7 +90,7 @@ def call_with_deadline(fn: Callable, deadline_s: Optional[float],
     Returns ``fn``'s result; re-raises its exception. On timeout raises
     ``DeadlineExceeded`` and ABANDONS the thread (daemon: it cannot keep
     the process alive) — the standard move for an uncancellable pending
-    claim (cf. bench's r3 outage postmortem, docs/TPU_OUTAGE_2026-07-30.md).
+    claim.
     ``deadline_s`` None or <= 0 calls ``fn`` inline."""
     if not deadline_s or deadline_s <= 0:
         return fn()

@@ -50,7 +50,7 @@ from dalle_pytorch_tpu.resilience import faults
 
 def _ckpt():
     # lazy: checkpoint pulls jax/flax, and resilience must stay importable
-    # from bench.py's pre-claim main thread (see utils/metrics.py note)
+    # without jax (see utils/metrics.py note)
     from dalle_pytorch_tpu import checkpoint
     return checkpoint
 

@@ -64,9 +64,11 @@ TRASH_PAGE = 0
 
 # the ragged paged-attention kernel's tile constraints
 # (ops/paged_attention.py): a page is the kernel's K-tile, staged whole
-# into VMEM, so its row count must be at least one f32 sublane tile (8)
-# and a lane-friendly multiple of 8 — Mosaic cannot tile a 4-row page.
-# The gather path has no such floor (any page_size works there).
+# into VMEM, and the gate keeps it to whole f32 sublane tiles (8 rows).
+# Mosaic itself compiles every page size from 4 to 32 rows for f32, bf16
+# and int8 pools (the block equals the array's last two dims), so one
+# gate serves every dtype; parity with the gather oracle is established
+# at 8 and 16 rows. The gather path has no floor (any page_size works).
 KERNEL_MIN_PAGE_SIZE = 8
 KERNEL_PAGE_MULTIPLE = 8
 
@@ -74,9 +76,9 @@ KERNEL_PAGE_MULTIPLE = 8
 class PageSizeError(ValueError):
     """Typed page-size rejection at pool init: the configured
     ``page_size`` cannot feed the ragged paged-attention kernel
-    (``ops/paged_attention.py`` stages one page per DMA as a VMEM
-    K-tile, so pages must be >= the 8-row f32 sublane tile and a
-    multiple of 8 lanes' worth of rows). Raised HERE, with the
+    (``ops/paged_attention.py`` stages one page per grid step as a VMEM
+    K-tile, and pages are kept to whole 8-row f32 sublane tiles).
+    Raised HERE, with the
     constraint named, instead of failing opaquely inside
     ``pl.pallas_call``. ``record`` is the structured event."""
 

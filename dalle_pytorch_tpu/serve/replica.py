@@ -218,6 +218,51 @@ class ReplayVersionMismatch(RuntimeError):
         self.record = record
 
 
+class ChipOwnershipError(RuntimeError):
+    """Typed start-up refusal of ``isolation='process'`` with locally
+    spawned workers on a TPU host. A chip belongs to ONE process: the
+    parent has already initialised jax on the TPU backend (it restored
+    and placed the weights), so its libtpu client holds every chip of
+    the host, and a child that builds its own jax client can only fail
+    on libtpu's lockfile or hang waiting for it. Making it work needs
+    a parent that never touches the accelerator plus a per-child chip
+    visibility — not a local repair (ROADMAP Queue 3 item 7). One
+    process drives all of a host's chips in thread isolation, one
+    engine per chip; workers on OTHER hosts still attach over
+    ``transport='socket'`` with an operator-started ``worker_cmd``.
+    ``record`` is the structured event (kind
+    ``serve_isolation_unsupported``)."""
+
+    def __init__(self, record: dict):
+        super().__init__(
+            f"isolation='process' cannot spawn workers on this host: "
+            f"the parent process already holds its "
+            f"{record.get('device_count')} {record.get('platform')} "
+            f"chip(s) ({record.get('device_kind')}) and a chip belongs "
+            f"to one process — a child's jax client would fail on "
+            f"libtpu's lock or hang. Use isolation='thread' (one "
+            f"engine per chip in this process), or attach workers "
+            f"from other hosts (transport='socket', worker_cmd='').")
+        self.record = record
+
+
+def check_chip_ownership(isolation: str, worker_cmd) -> None:
+    """Raise ``ChipOwnershipError`` when process isolation would spawn
+    a worker onto a chip this process already holds. ``worker_cmd``
+    None means "spawn locally" (pipe, or the dial-back socket child);
+    any other value hands the launch to an operator or launcher whose
+    workers own their own host's chips."""
+    if isolation != "process" or worker_cmd is not None:
+        return
+    from dalle_pytorch_tpu.utils.device import describe_device
+    device = describe_device()
+    if device["platform"] == "tpu":
+        raise ChipOwnershipError(S.structured_event(
+            "serve_isolation_unsupported", isolation=isolation,
+            platform=device["platform"], device_kind=device["kind"],
+            device_count=device["count"]))
+
+
 class _Replica:
     """One supervised slot of the set: the engine + its private queue,
     its loop thread (threaded mode), and the supervisor's bookkeeping
@@ -348,6 +393,7 @@ class ReplicaSet:
                 "that a worker on ANOTHER host loads weights from its "
                 "local checkpoint store instead of receiving pickled "
                 "params over the wire")
+        check_chip_ownership(isolation, worker_cmd)
         self.worker_use_ema = bool(worker_use_ema)
         self.worker_quantize = str(worker_quantize)
         if self.worker_quantize not in ("none", "int8", "int8_kv"):
@@ -2169,6 +2215,11 @@ class ReplicaSet:
             rec = {"replica": r.index, "state": r.state, "alive": alive,
                    "bringups": r.bringups,
                    "weights_version": r.version, "role": r.role}
+            if r.device is not None:
+                # thread mode: the chip (or mesh slice) this replica's
+                # engine is pinned to — distinct per replica on a
+                # multi-chip host
+                rec["device"] = str(r.device)
             if r.canary:
                 rec["canary"] = True    # upgrading: gate-only, unrouted
             if r.engine is not None:

@@ -22,6 +22,7 @@ Two call surfaces:
 from __future__ import annotations
 
 import json
+import sys
 import threading
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from typing import Callable, List, Optional
@@ -387,6 +388,12 @@ class InferenceServer:
                 **{k: v for k, v in rec.items()
                    if k not in ("time", "event", "kind")})
             ) if self.metrics is not None else None)
+        # the device this server actually got — logged once, and carried
+        # by /healthz: a jax that fell back to the CPU must be visible
+        # in the first line of the log, not inferred from latency
+        from dalle_pytorch_tpu.utils.device import describe_device
+        print(f"[serve] device: {describe_device()}", file=sys.stderr,
+              flush=True)
 
         if self.post is not None:
             self.post.start()
@@ -550,7 +557,11 @@ class InferenceServer:
         heartbeat age) — ``ok`` is False (HTTP 503) only when EVERY
         replica is dead."""
         from dalle_pytorch_tpu.parallel.serve_specs import SERVE_AXIS
+        from dalle_pytorch_tpu.utils.device import describe_device
+        device = describe_device()
         out = {"ok": self.engine_alive(),
+               "platform": device["platform"],
+               "device_kind": device["kind"],
                # mesh observability (/healthz satellite): how many
                # devices each replica's engine spans
                "devices_per_replica": self.mesh_devices,

@@ -252,7 +252,11 @@ def _run(spec: dict, conn, sender: _FrameSender, rx_seq: int) -> None:
     import jax
 
     from dalle_pytorch_tpu.serve.engine import Engine, MigrationError
+    from dalle_pytorch_tpu.utils.device import enable_compile_cache
 
+    # every worker of a fleet compiles the same engine programs: share
+    # them through the one persistent cache the parent uses
+    enable_compile_cache()
     devices = jax.devices()
     params = spec["params"]
     if params is None:

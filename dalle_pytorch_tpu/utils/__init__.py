@@ -2,8 +2,8 @@
 
 Lazy exports (mirroring the root package): ``utils.metrics`` must be
 importable without jax — resilience.retry emits structured bring-up
-failure records from bench.py's pre-claim main thread, where the jax
-import stays inside the deadline-bounded claim thread.
+failure records from threads that must not import jax themselves (the
+jax import stays inside the deadline-bounded claim thread).
 """
 
 __all__ = ["MetricsLogger", "StepProfiler", "trace", "enable_nan_checks",

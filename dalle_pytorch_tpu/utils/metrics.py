@@ -17,8 +17,8 @@ from typing import Optional
 
 # NOTE: jax is imported lazily inside MetricsLogger — ``structured_event``
 # must be importable before any backend exists (resilience.retry emits
-# bring-up failure records from bench.py's pre-claim main thread, where a
-# jax import must stay inside the deadline-bounded claim thread).
+# bring-up failure records from a caller whose jax import must stay inside
+# the deadline-bounded claim thread).
 
 
 def structured_event(kind: str, **fields) -> dict:

@@ -101,34 +101,20 @@ def main():
     ap.add_argument("--batch", type=int, default=1)
     ap.add_argument("--steps", type=int, default=5)
     ap.add_argument("--warmup", type=int, default=2)
-    ap.add_argument("--claim_retries", type=int, default=3)
     args = ap.parse_args()
-
-    from bench import claim_backend
-    claim = claim_backend(args.claim_retries, attempt_env="LONGCTX_ATTEMPT",
-                          retry_on_timeout=True,
-                          backoff=lambda a: min(60 * (a + 1), 300))
-    if claim is not None:
-        print(json.dumps({"error": claim[0], "claim_attempts": claim[1]}),
-              flush=True)
-        os._exit(1)
 
     import jax
 
     import bench
+    from dalle_pytorch_tpu.utils.device import enable_compile_cache
 
-    def _on_stall(failure):
-        print(json.dumps({"probe_stalled": True, **failure}), flush=True)
-        os._exit(1)
-
-    bench.start_stall_watchdog(on_stall=_on_stall)
+    enable_compile_cache()
 
     results = []
     # seq-major so each length yields its xla-vs-flash pair together — a
-    # window that closes mid-run still leaves comparable points
+    # run killed at its time limit still leaves comparable points
     for seq in (int(s) for s in args.seqs.split(",")):
         for impl in args.impls.split(","):
-            bench.beat(f"longctx {impl} seq={seq}")
             rec = {"impl": impl, "seq": seq, "depth": args.depth,
                    "batch": args.batch}
             try:

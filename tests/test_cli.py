@@ -248,7 +248,12 @@ class TestTrainDALLE:
         (ops/decode.py). Both still write a grid."""
         require_ckpt(workdir, "toy_dalle", 0)
         from dalle_pytorch_tpu.cli.gen_dalle import main
-        before = set(os.listdir(workdir / "results"))
+        # the grid's name carries whole seconds: with a warm compile
+        # cache both modes finish inside one, so clear the slate rather
+        # than diff the listing
+        for f in os.listdir(workdir / "results"):
+            if f.startswith("gendalletoy_epoch_0-"):
+                os.remove(workdir / "results" / f)
         main([
             "a red square",
             "--name", "toy", "--dalle_epoch", "0",
@@ -256,8 +261,8 @@ class TestTrainDALLE:
             "--results_dir", str(workdir / "results"),
             "--quantize", mode,
         ])
-        new = set(os.listdir(workdir / "results")) - before
-        assert any(f.startswith("gendalletoy_epoch_0-") for f in new), \
+        assert any(f.startswith("gendalletoy_epoch_0-")
+                   for f in os.listdir(workdir / "results")), \
             "quantized gen_dalle wrote no PNG"
 
     def test_gen_dalle_clip_rerank(self, workdir):
@@ -645,7 +650,7 @@ class TestTrainDALLEMoE:
 class TestTrainDALLERemat:
     def test_remat_full_trains_and_checkpoints(self, workdir):
         """--remat full: the rematerialized layer body trains end-to-end
-        through the CLI (the batch-unlocking lever, ANALYSIS_NORTH.md)."""
+        through the CLI (the batch-unlocking lever)."""
         require_ckpt(workdir, "vae", 2)
         from dalle_pytorch_tpu.cli.train_dalle import main
         main([

@@ -519,11 +519,6 @@ class TestSequenceParallelStack:
         jax.tree.map(lambda a, b: np.testing.assert_allclose(
             np.asarray(a), np.asarray(b), atol=3e-5), g1, g2)
 
-    @pytest.mark.skipif(
-        not __import__("dalle_pytorch_tpu.parallel._compat",
-                       fromlist=["x"]).SUPPORTS_PARTIAL_MANUAL,
-        reason="partial-manual shard_map (tp as auto axis) requires "
-               "jax>=0.8 (parallel/_compat.py)")
     def test_three_axis_dp_tp_sp(self):
         """dp x tp x sp in ONE program (VERDICT r4 item 7): the shard_map
         is manual over dp/sp only, so Megatron-tp param shardings ride
