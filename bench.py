@@ -1482,22 +1482,22 @@ def _serve_prefix_compare(*, num_slots=4, chunk_steps=8, n_samples=4):
         request) + N x pages(private span), strictly under the
         refcount-blind engine's measured peak — every stream
         byte-identical to its cold reference;
-      * ``warm_prefill``: p50 warm-admission wall time <= 0.1x the p50
-        cold prefill dispatch (both timed to completion via the
-        engine's ``time_admissions``, compiles excluded) and ZERO
-        prefill dispatches across the warm storm. The config is sized
-        so the prompt forward genuinely dominates dispatch overhead
-        (dim 256 x depth 4 x 32-token prompts) — on a tiny config the
-        ratio would measure the runtime, not the cache;
+      * ``warm_prefill``: ZERO prefill dispatches across the warm storm,
+        every warm stream byte-identical to its cold run. The host
+        milliseconds inside the admission calls are recorded from the
+        engine's always-on loop counters (``admit_prefill_s`` over
+        ``prefill_runs`` / ``warm_admits``): the time until the call
+        RETURNS, not until the device is done — the device's side of
+        an admission is the benchmark's ``prefill_device_ms``;
       * ``cfg_pair``: a guided request (cond/uncond pair) against a
         warmed index allocates < 2x the pages of a plain request's
         full map and runs < 2x its ms/token — the prompt and the null
         caption are both shared spans, so only the generated span pays
         double.
 
-    All CPU-safe: pages, dispatch counts, and admission wall time are
-    the asserted quantities — not kernel ms/token — so this asserts
-    everywhere, not just on real TPU."""
+    All CPU-safe: pages and dispatch counts are the asserted
+    quantities — not kernel ms/token — so this asserts everywhere, not
+    just on real TPU."""
     import numpy as np
 
     import jax
@@ -1528,8 +1528,7 @@ def _serve_prefix_compare(*, num_slots=4, chunk_steps=8, n_samples=4):
         queue = RequestQueue(max_depth=4 * slots + 8)
         engine = Engine(params, cfg, queue, num_slots=slots,
                         chunk_steps=chunk_steps, kv="paged",
-                        page_size=page_size, prefix_cache=prefix_cache,
-                        time_admissions=True)
+                        page_size=page_size, prefix_cache=prefix_cache)
         return engine, queue
 
     def run(engine, queue, reqs):
@@ -1582,8 +1581,10 @@ def _serve_prefix_compare(*, num_slots=4, chunk_steps=8, n_samples=4):
     cold_reqs = [Request(codes=tuple((1 + i + j) % 7 + 1
                                      for j in range(t0)), seed=i)
                  for i in range(3)]
+    s0 = engine.stats()
     for r in cold_reqs:
         run(engine, queue, [r])
+    s1 = engine.stats()
     runs_before = engine.prefill_runs
     warm_reqs = [Request(codes=cold_reqs[-1].codes, seed=100 + i)
                  for i in range(4)]
@@ -1599,20 +1600,15 @@ def _serve_prefix_compare(*, num_slots=4, chunk_steps=8, n_samples=4):
                 f"warm-hit tokens diverged from the cold run "
                 f"(seed {r.seed})")
     stats = engine.stats()
-    cold_p50 = stats["prefill_p50_ms"]
-    warm_p50 = stats["warm_admit_p50_ms"]
-    # a time ratio is a device claim: asserted on the chip only (on the
-    # CPU --tiny smoke the zero-prefill COUNT above is the contract, and a
-    # ~1.5 ms host floor against a ~12 ms tiny prefill flips this bound)
-    if jax.default_backend() == "tpu" and warm_p50 > 0.1 * cold_p50:
-        raise AssertionError(
-            f"warm admission p50 {warm_p50}ms > 0.1x cold prefill p50 "
-            f"{cold_p50}ms — the warm path must skip the prompt "
-            f"forward entirely")
+
+    def dispatch_ms(a, b, key):
+        return round(1e3 * (b["admit_prefill_s"] - a["admit_prefill_s"])
+                     / max(b[key] - a[key], 1), 4)
+
     out["warm_prefill"] = {
-        "cold_prefill_p50_ms": cold_p50,
-        "warm_admit_p50_ms": warm_p50,
-        "speedup": round(cold_p50 / max(warm_p50, 1e-6), 1),
+        "cold_admit_dispatch_ms": dispatch_ms(s0, s1, "prefill_runs"),
+        "warm_admit_dispatch_ms": dispatch_ms(s1, stats, "warm_admits"),
+        "warm_prefill_runs": stats["prefill_runs"] - s1["prefill_runs"],
         "prefix_hits": stats["prefix_hits"],
         "prefill_runs": stats["prefill_runs"],
         "token_mismatches": 0,

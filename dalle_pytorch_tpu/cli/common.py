@@ -502,39 +502,54 @@ def run_supervised_loop(args, *, sup, metrics, profiler, dataset, plan,
             state.pf = prefetch(it, depth=2, transform=transform,
                                 max_bad_records=args.max_bad_records,
                                 on_event=lambda r: metrics.event(**r))
-            for item in state.pf:
+            # the loop's phases on the profiler's clock (--profile_dir):
+            # inactive annotations cost an atomic read each; no metric of
+            # the benchmark reads them, its train cell drives its own loop
+            span = jax.profiler.TraceAnnotation
+            batches, done = iter(state.pf), object()
+            while True:
+                with span("trainer.next_batch"):    # the wait on prefetch
+                    item = next(batches, done)
+                if item is done:
+                    break
                 gs = state.global_step
-                profiler.maybe_start(gs)
-                if guard_transfers:
-                    # the ROADMAP's no_transfers-around-the-train-step
-                    # item: the step body must spell every host<->device
-                    # crossing as an explicit device_put at the site
-                    # (shard_batch, step_rng, the CLIs' batch loaders) —
-                    # an implicit one raises HERE, naming the call,
-                    # instead of stalling the chip silently every step.
-                    # The loss fetch (float(loss) below) stays OUTSIDE
-                    # the guard: it is the loop's one intentional
-                    # per-step host read
-                    with guards.no_transfers():
-                        loss, payload = train_step(item, state)
-                else:
-                    loss, payload = train_step(item, state)
-                profiler.maybe_stop(gs)
-                lv = float(loss)
-                if sup.check_step(gs, lv) == sup.ROLLBACK:
-                    on_rollback(state)
-                    state.global_step += 1
-                    state.epoch_i += 1
-                    continue
-                metrics.step(gs, lv, epoch=epoch,
-                             units=units_of(item) if units_of else 0,
-                             unit_name=unit_name)
-                state.train_loss += lv
-                state.n_batches += 1
-                state.global_step += 1
-                state.epoch_i += 1
-                state.last = payload
-                sup.end_step(state.global_step)
+                with jax.profiler.StepTraceAnnotation("train", step_num=gs):
+                    profiler.maybe_start(gs)
+                    if guard_transfers:
+                        # the ROADMAP's no_transfers-around-the-train-step
+                        # item: the step body must spell every host<->device
+                        # crossing as an explicit device_put at the site
+                        # (shard_batch, step_rng, the CLIs' batch loaders) —
+                        # an implicit one raises HERE, naming the call,
+                        # instead of stalling the chip silently every step.
+                        # The loss fetch (float(loss) below) stays OUTSIDE
+                        # the guard: it is the loop's one intentional
+                        # per-step host read
+                        with guards.no_transfers(), span("trainer.step"):
+                            loss, payload = train_step(item, state)
+                    else:
+                        with span("trainer.step"):
+                            loss, payload = train_step(item, state)
+                    with span("trainer.fetch_loss"):
+                        lv = float(loss)
+                    # after the fetch, so that a capture holds whole steps:
+                    # the device has then finished the last one it covers
+                    profiler.maybe_stop(gs)
+                    with span("trainer.supervise"):
+                        if sup.check_step(gs, lv) == sup.ROLLBACK:
+                            on_rollback(state)
+                            state.global_step += 1
+                            state.epoch_i += 1
+                            continue
+                        metrics.step(gs, lv, epoch=epoch,
+                                     units=units_of(item) if units_of else 0,
+                                     unit_name=unit_name)
+                        state.train_loss += lv
+                        state.n_batches += 1
+                        state.global_step += 1
+                        state.epoch_i += 1
+                        state.last = payload
+                        sup.end_step(state.global_step)
             if state.n_batches == 0:
                 raise RuntimeError("empty dataset epoch")
 

@@ -172,6 +172,7 @@ def dalle_init(key: Array, cfg: DALLEConfig,
 # embeddings / masks
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("embed")
 def image_pos_emb(params: dict, cfg: DALLEConfig, positions: Array) -> Array:
     """Summed-axial position embedding for flat image positions
     (0..image_seq_len). 'grid' maps n -> (n // g, n % g); 'full_image' maps
@@ -199,6 +200,7 @@ def logits_mask(cfg: DALLEConfig) -> Array:
     return forbidden
 
 
+@jax.named_scope("embed")
 def embed_prompt(params: dict, cfg: DALLEConfig, text: Array,
                  image_ids: Optional[Array] = None) -> Array:
     """Token embeddings for [text (b, t)] ++ [image ids (b, n_img)]."""
@@ -213,6 +215,7 @@ def embed_prompt(params: dict, cfg: DALLEConfig, text: Array,
     return tok
 
 
+@jax.named_scope("embed")
 def decode_token_embed(params: dict, cfg: DALLEConfig, cur_tok: Array,
                        pos: Array) -> Array:
     """Embedding of the token(s) fed at position(s) ``pos`` during KV-cache
@@ -239,6 +242,7 @@ def decode_token_embed(params: dict, cfg: DALLEConfig, cur_tok: Array,
     return jnp.where(is_text, text_e, img_e)
 
 
+@jax.named_scope("head")
 def to_logits(params: dict, h: Array) -> Array:
     h = core.layernorm(params["to_logits"]["ln"], h)
     return core.linear(params["to_logits"]["proj"], h)
@@ -321,9 +325,11 @@ def dalle_apply(params: dict, text: Array, image=None, *, cfg: DALLEConfig,
                                  train=train, with_aux=True)
 
     if not return_loss:
-        logits = to_logits(params, h)
-        forbidden = logits_mask(cfg)[:seq_len]
-        return jnp.where(forbidden[None], core.neg_inf(logits.dtype), logits)
+        with jax.named_scope("head"):
+            logits = to_logits(params, h)
+            forbidden = logits_mask(cfg)[:seq_len]
+            return jnp.where(forbidden[None], core.neg_inf(logits.dtype),
+                             logits)
 
     if image_ids is None:
         raise ValueError("when training, image must be supplied")
@@ -333,6 +339,7 @@ def dalle_apply(params: dict, text: Array, image=None, *, cfg: DALLEConfig,
     return loss
 
 
+@jax.named_scope("loss")
 def ce_from_hidden(params: dict, h: Array, text: Array, image_ids: Array, *,
                    cfg: DALLEConfig) -> Array:
     """The training-loss tail shared by every execution path (single-device
@@ -405,6 +412,7 @@ def _chunked_ce(params: dict, h: Array, targets: Array,
 # generation — jit lax.scan sampler with KV cache
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("sample")
 def top_k_filter(logits: Array, thres: float) -> Array:
     """Keep the top (1-thres)·vocab logits, -inf the rest (reference
     top_k helper, dalle_pytorch.py:41-47)."""
@@ -413,6 +421,7 @@ def top_k_filter(logits: Array, thres: float) -> Array:
     return jnp.where(logits < kth, core.neg_inf(logits.dtype), logits)
 
 
+@jax.named_scope("sample")
 def top_p_filter(logits: Array, p: float) -> Array:
     """Nucleus filter (beyond reference — the reference samples top-k
     only, dalle_pytorch.py:41-47): keep the smallest prefix of
@@ -436,6 +445,7 @@ def top_p_filter(logits: Array, p: float) -> Array:
     return jnp.where(logits < thresh, core.neg_inf(logits.dtype), logits)
 
 
+@jax.named_scope("sample")
 def sample_per_slot(logits: Array, pred_pos: Array, keys: Array,
                     temp: Array, topk_k: Array, top_p: Array,
                     cfg: DALLEConfig, *,
@@ -597,6 +607,7 @@ def generate_images(params: dict, vae_params: dict, text: Array, *,
     forbidden = logits_mask(cfg)
     uncond_rows = jnp.arange(rows) >= b
 
+    @jax.named_scope("sample")
     def sample(logits_row, pred_pos, key):
         """Sample the token for position pred_pos from last-row logits."""
         lg = jnp.where(forbidden[pred_pos - 1][None], core.neg_inf(

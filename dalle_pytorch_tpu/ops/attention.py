@@ -58,6 +58,7 @@ def merge_heads(x: Array) -> Array:
     return x.transpose(0, 2, 1, 3).reshape(b, n, h * d)
 
 
+@jax.named_scope("attn.proj")
 def qkv_project(params: dict, x: Array, heads: int):
     qkv = core.linear(params["qkv"], x)
     q, k, v = jnp.split(qkv, 3, axis=-1)
@@ -101,6 +102,7 @@ def dense_attention_weights(q: Array, k: Array, scale: float,
     return jax.nn.softmax(dots, axis=-1)
 
 
+@jax.named_scope("attn.proj")
 def output_tail(params: dict, out: Array, *, dropout_rate: float = 0.0,
                 dropout_key: Optional[Array] = None,
                 train: bool = False) -> Array:
@@ -137,8 +139,9 @@ def attention_apply(params: dict, x: Array, *, heads: int, dim_head: int,
                               bwd_impl=bwd_impl,
                               block_q=block_q, block_k=block_k)
     else:
-        attn = dense_attention_weights(q, k, scale, mask, causal)
-        out = jnp.einsum("bhij,bhjd->bhid", attn, v)
+        with jax.named_scope("attn.read"):
+            attn = dense_attention_weights(q, k, scale, mask, causal)
+            out = jnp.einsum("bhij,bhjd->bhid", attn, v)
 
     return output_tail(params, out, dropout_rate=dropout_rate,
                        dropout_key=dropout_key, train=train)

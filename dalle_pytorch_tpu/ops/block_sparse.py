@@ -176,6 +176,7 @@ def _kernel(*refs, scale, causal, block_q, block_k, seq_len, has_mask, block,
     l_ref[0] = jnp.broadcast_to(l_safe, (block_q, NUM_LANES))
 
 
+@jax.named_scope("attn.sparse_fwd")
 def _bs_fwd(q, k, v, mask, *static):
     """The forward kernel, one shard of batch and heads per device under
     a mesh (a Mosaic kernel cannot be auto-partitioned)."""
@@ -234,6 +235,7 @@ def _bs_fwd_local(q, k, v, mask, scale, causal, block, num_local_blocks,
             jax.ShapeDtypeStruct((bh, n, NUM_LANES), jnp.float32),
         ],
         interpret=interpret,
+        name="attn.sparse_fwd",
     )(*inputs)
     out = out.reshape(b, h, n, d)[:, :, :n_orig]
     m = m[:, :, 0].reshape(b, h, n)[:, :, :n_orig]
@@ -339,6 +341,7 @@ def _bs_bwd_static(q, k, v, mask, dout, out, stats, *, scale, block, window,
     return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
 
 
+@jax.named_scope("attn.sparse_bwd")
 def _bs_bwd_rule(scale, causal, block, num_local_blocks, global_blocks,
                  blocks_qk, interpret, res, dout):
     q, k, v, mask, out, stats = res

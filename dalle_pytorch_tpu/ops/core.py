@@ -79,6 +79,7 @@ def layernorm_init(dim: int, dtype=jnp.float32) -> dict:
     return {"g": jnp.ones((dim,), dtype), "b": jnp.zeros((dim,), dtype)}
 
 
+@jax.named_scope("norm")
 def layernorm(params: dict, x: Array, *, eps: float = 1e-5) -> Array:
     # Normalise in f32 for numerical stability, cast back to input dtype.
     # The two full-size f32 intermediates are tagged with checkpoint_name

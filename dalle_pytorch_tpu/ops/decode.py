@@ -79,6 +79,7 @@ def _quantize_rows(x: Array):
     return q, scale
 
 
+@jax.named_scope("kv.store")
 def _store_rows(cache: dict, ks: Array, vs: Array, pos) -> dict:
     """Write K/V rows (depth, b, heads, rows, dh) into the cache starting
     at ``pos`` — the ONE definition of the cache write for prefill and
@@ -110,6 +111,7 @@ def _store_rows(cache: dict, ks: Array, vs: Array, pos) -> dict:
     }
 
 
+@jax.named_scope("kv.store")
 def _store_rows_per_slot(cache: dict, ks: Array, vs: Array,
                          pos: Array) -> dict:
     """Scatter variant of ``_store_rows``: ks/vs are single rows
@@ -180,6 +182,7 @@ def _sparse_page_visibility(cfg, total_len: int, page_size: int):
                                        causal=cfg.causal)
 
 
+@jax.named_scope("attn.read")
 def _kernel_read(q: Array, k: Array, v: Array, pool_k: Array,
                  pool_v: Array, block_tables: Array, pos: Array,
                  allowed: Array, *, scale: float,
@@ -213,6 +216,7 @@ def _kernel_read(q: Array, k: Array, v: Array, pool_k: Array,
     return out.astype(q.dtype)[:, :, None, :]
 
 
+@jax.named_scope("attn.read")
 def _gather_read(q: Array, k: Array, v: Array, ck: Array, cv: Array,
                  allowed: Array, *, scale: float,
                  ksc: Optional[Array] = None,
@@ -262,9 +266,11 @@ def _attn_with_kv(lp: dict, h: Array, allowed: Array, cfg,
     p = lp["attn"]
     hn = core.layernorm(p["ln"], h)
     q, k, v = attn_ops.qkv_project(p, hn, cfg.heads)
-    dots = jnp.einsum("bhid,bhjd->bhij", q, k) * cfg.scale
-    dots = jnp.where(allowed, dots, core.neg_inf(dots.dtype))
-    out = jnp.einsum("bhij,bhjd->bhid", jax.nn.softmax(dots, axis=-1), v)
+    with jax.named_scope("attn.read"):
+        dots = jnp.einsum("bhid,bhjd->bhij", q, k) * cfg.scale
+        dots = jnp.where(allowed, dots, core.neg_inf(dots.dtype))
+        out = jnp.einsum("bhij,bhjd->bhid", jax.nn.softmax(dots, axis=-1),
+                         v)
     if out_sync is not None:
         out = out_sync(out)
     out = attn_ops.output_tail(p, out)
@@ -285,22 +291,24 @@ def prefill(params: dict, x: Array, *, cfg, total_len: int,
     sparse_flags = jnp.asarray(cfg.sparse_pattern)
     any_sparse = any(cfg.sparse_pattern)
 
-    tri = jnp.tril(jnp.ones((t0, t0), bool))[None, None]
-    pad_ok = jnp.ones((b, 1, t0, t0), bool)
-    if prompt_mask is not None:
-        pad_ok = (prompt_mask[:, None, :, None]
-                  & prompt_mask[:, None, None, :])
-    dense_allowed = tri & pad_ok
-    if any_sparse:
-        layout = _sparse_layout(cfg, total_len)[:t0, :t0][None, None]
-        sparse_allowed = dense_allowed & layout
-    else:
-        sparse_allowed = dense_allowed  # dead value for scan symmetry
+    with jax.named_scope("attn.read"):       # the masks
+        tri = jnp.tril(jnp.ones((t0, t0), bool))[None, None]
+        pad_ok = jnp.ones((b, 1, t0, t0), bool)
+        if prompt_mask is not None:
+            pad_ok = (prompt_mask[:, None, :, None]
+                      & prompt_mask[:, None, None, :])
+        dense_allowed = tri & pad_ok
+        if any_sparse:
+            layout = _sparse_layout(cfg, total_len)[:t0, :t0][None, None]
+            sparse_allowed = dense_allowed & layout
+        else:
+            sparse_allowed = dense_allowed  # dead value for scan symmetry
 
     def body(carry, xs):
         lp, is_sparse = xs
-        allowed = jnp.where(is_sparse, sparse_allowed, dense_allowed) \
-            if any_sparse else dense_allowed
+        with jax.named_scope("attn.read"):
+            allowed = jnp.where(is_sparse, sparse_allowed, dense_allowed) \
+                if any_sparse else dense_allowed
         if cfg.reversible:
             x1, x2 = carry
             a, k, v = _attn_with_kv(lp, x2, allowed, cfg, out_sync)
@@ -445,21 +453,22 @@ def _decode_step_math(params: dict, x_tok: Array, pos: Array, cache: dict,
         if block_tables is None:
             raise ValueError("attn_impl='kernel' requires block_tables")
 
-    j = jnp.arange(total_len)
-    # strictly-before rows; self added as the concatenated extra logit
-    causal_ok = (j[None, :] < pos[:, None]) if per_slot \
-        else (j < pos)[None, :]
-    dense_allowed = causal_ok & key_mask                     # (b, L)
-    if any_sparse:
-        layout = _sparse_layout(cfg, total_len)
-        if per_slot:
-            row = jnp.take(layout, pos, axis=0)              # (b, L)
-            sparse_allowed = dense_allowed & row
+    with jax.named_scope("attn.read"):       # the masks
+        j = jnp.arange(total_len)
+        # strictly-before rows; self added as the concatenated extra logit
+        causal_ok = (j[None, :] < pos[:, None]) if per_slot \
+            else (j < pos)[None, :]
+        dense_allowed = causal_ok & key_mask                     # (b, L)
+        if any_sparse:
+            layout = _sparse_layout(cfg, total_len)
+            if per_slot:
+                row = jnp.take(layout, pos, axis=0)              # (b, L)
+                sparse_allowed = dense_allowed & row
+            else:
+                row = lax.dynamic_slice(layout, (pos, 0), (1, total_len))[0]
+                sparse_allowed = dense_allowed & row[None, :]
         else:
-            row = lax.dynamic_slice(layout, (pos, 0), (1, total_len))[0]
-            sparse_allowed = dense_allowed & row[None, :]
-    else:
-        sparse_allowed = dense_allowed
+            sparse_allowed = dense_allowed
 
     h_in = x_tok[:, None, :]                                  # (b, 1, dim)
     quantized = "k_scale" in cache
@@ -468,8 +477,9 @@ def _decode_step_math(params: dict, x_tok: Array, pos: Array, cache: dict,
         p = lp["attn"]
         hn = core.layernorm(p["ln"], h)
         q, k, v = attn_ops.qkv_project(p, hn, cfg.heads)      # (b, h, 1, dh)
-        allowed = jnp.where(is_sparse, sparse_allowed, dense_allowed) \
-            if any_sparse else dense_allowed
+        with jax.named_scope("attn.read"):
+            allowed = jnp.where(is_sparse, sparse_allowed, dense_allowed) \
+                if any_sparse else dense_allowed
         if kernel_mode:
             # ck/cv are the raw page pool for this layer; the kernel
             # walks the block tables in place (_kernel_read completes
@@ -572,35 +582,37 @@ def _decode_step_math_sparse_reads(params: dict, x_tok: Array, pos: Array,
     ps = pool["k"].shape[3]
     quantized = "k_scale" in pool
 
-    j = jnp.arange(total_len)
-    causal_ok = j[None, :] < pos[:, None]
-    dense_allowed = causal_ok & key_mask                     # (b, L)
-    layout = _sparse_layout(cfg, total_len)
-    sparse_allowed = dense_allowed & jnp.take(layout, pos, axis=0)
+    with jax.named_scope("attn.read"):   # masks and visibility tables
+        j = jnp.arange(total_len)
+        causal_ok = j[None, :] < pos[:, None]
+        dense_allowed = causal_ok & key_mask                     # (b, L)
+        layout = _sparse_layout(cfg, total_len)
+        sparse_allowed = dense_allowed & jnp.take(layout, pos, axis=0)
 
-    vis_np, cnt_np, ccnt_np = _sparse_page_visibility(cfg, total_len, ps)
-    width = vis_np.shape[1]
-    # jaxlint: disable=JL001 — static-config visibility tables, trace-
-    # time constants hoisted once per compile (the _sparse_layout idiom)
-    vis_rows = jnp.take(jnp.asarray(vis_np), pos, axis=0)    # (b, W)
-    vis_cnt = jnp.take(jnp.asarray(cnt_np), pos)             # (b,)
-    vis_ccnt = jnp.take(jnp.asarray(ccnt_np), pos)           # (b,)
+        vis_np, cnt_np, ccnt_np = _sparse_page_visibility(cfg, total_len, ps)
+        width = vis_np.shape[1]
+        # jaxlint: disable=JL001 — static-config visibility tables, trace-
+        # time constants hoisted once per compile (the _sparse_layout idiom)
+        vis_rows = jnp.take(jnp.asarray(vis_np), pos, axis=0)    # (b, W)
+        vis_cnt = jnp.take(jnp.asarray(cnt_np), pos)             # (b,)
+        vis_ccnt = jnp.take(jnp.asarray(ccnt_np), pos)           # (b,)
 
-    need = -(-total_len // ps)               # pages_for(total_len)
-    bt = block_tables[:, :need]              # paged_view's table trim
-    vis_bt = KV.visible_table_view(bt, vis_rows)             # (b, W)
-    # remap the row mask onto the trimmed columns: column w*ps + o of
-    # the visible view is logical row vis_rows[:, w]*ps + o; columns
-    # past the live count are dead (they would re-count page 0), and so
-    # are tail rows past total_len on a partial last page
-    cols = (vis_rows[:, :, None] * ps
-            + jnp.arange(ps)[None, None, :]).reshape(b, width * ps)
-    pad_ok = jnp.repeat(
-        jnp.arange(width)[None, :] < vis_cnt[:, None], ps, axis=1)
-    vis_allowed = (jnp.take_along_axis(
-        sparse_allowed, jnp.minimum(cols, total_len - 1), axis=1)
-        & pad_ok & (cols < total_len))
+        need = -(-total_len // ps)               # pages_for(total_len)
+        bt = block_tables[:, :need]              # paged_view's table trim
+        vis_bt = KV.visible_table_view(bt, vis_rows)             # (b, W)
+        # remap the row mask onto the trimmed columns: column w*ps + o of
+        # the visible view is logical row vis_rows[:, w]*ps + o; columns
+        # past the live count are dead (they would re-count page 0), and so
+        # are tail rows past total_len on a partial last page
+        cols = (vis_rows[:, :, None] * ps
+                + jnp.arange(ps)[None, None, :]).reshape(b, width * ps)
+        pad_ok = jnp.repeat(
+            jnp.arange(width)[None, :] < vis_cnt[:, None], ps, axis=1)
+        vis_allowed = (jnp.take_along_axis(
+            sparse_allowed, jnp.minimum(cols, total_len - 1), axis=1)
+            & pad_ok & (cols < total_len))
 
+    @jax.named_scope("kv.view")
     def layer_pool_view(ck, cv, ksc, vsc, tables, rows_out):
         """``paged_view`` for ONE layer: ck/cv (P, heads, ps, dh)
         gathered through tables (b, w) into (b, heads, rows_out[, dh])
@@ -717,6 +729,7 @@ def _decode_step_math_sparse_reads(params: dict, x_tok: Array, pos: Array,
 # the parity oracle the kernel is tested against.
 
 
+@jax.named_scope("kv.view")
 def paged_view(pool: dict, block_tables: Array, total_len: int) -> dict:
     """Dense per-slot view of the page pool: pool (depth, P, heads,
     page_size, dh) gathered through block_tables (b, max_pages) into
@@ -757,6 +770,7 @@ def paged_view(pool: dict, block_tables: Array, total_len: int) -> dict:
     return out
 
 
+@jax.named_scope("kv.store")
 def _store_rows_paged(pool: dict, ks: Array, vs: Array, pos: Array,
                       block_tables: Array, active: Array) -> dict:
     """Paged scatter twin of ``_store_rows_per_slot``: slot i's single new
@@ -905,6 +919,7 @@ def decode_loop_paged(params: dict, cur_tok: Array, pos: Array,
 # speculation IS the eager loop.
 
 
+@jax.named_scope("attn.read")
 def _gather_read_wide(q: Array, k: Array, v: Array, ck: Array, cv: Array,
                       allowed_cached: Array, allowed_intra: Array, *,
                       scale: float, ksc: Optional[Array] = None,
@@ -939,6 +954,7 @@ def _gather_read_wide(q: Array, k: Array, v: Array, ck: Array, cv: Array,
             + jnp.einsum("bhqk,bhkd->bhqd", wi, v))
 
 
+@jax.named_scope("attn.read")
 def _kernel_read_wide(q: Array, k: Array, v: Array, pool_k: Array,
                       pool_v: Array, block_tables: Array, pos: Array,
                       allowed_cached: Array, allowed_intra: Array, *,
@@ -1010,30 +1026,31 @@ def _decode_chunk_math(params: dict, x_toks: Array, pos: Array,
         raise ValueError("the wide chunk math requires per-slot (b,) "
                          "positions (the serving decode shape)")
 
-    j = jnp.arange(total_len)
-    offs = jnp.arange(W)
-    # cached rows: strictly before the CHUNK START for every query
-    # (rows in [pos, pos+i) are stale — the fresh intra keys stand in)
-    causal_c = j[None, :] < pos[:, None]                      # (b, L)
-    dense_cached = jnp.broadcast_to(
-        (causal_c & key_mask)[:, None, :], (b, W, total_len))
-    # intra-chunk: key kk visible to query i iff kk <= i (self included)
-    tri = offs[:, None] >= offs[None, :]                      # (W, W)
-    dense_intra = jnp.broadcast_to(tri[None], (b, W, W))
-    if any_sparse:
-        layout = _sparse_layout(cfg, total_len)
-        qrows = jnp.minimum(pos[:, None] + offs[None, :],
-                            total_len - 1)                    # (b, W)
-        lrows = jnp.take(layout, qrows, axis=0)               # (b, W, L)
-        sparse_cached = dense_cached & lrows
-        intra_lay = jnp.take_along_axis(
-            lrows, jnp.broadcast_to(qrows[:, None, :], (b, W, W)),
-            axis=2)                      # (b, W, W): layout[p+i, p+kk]
-        # jaxlint: disable=JL001 — static W identity, trace-time const
-        self_eye = jnp.eye(W, dtype=bool)[None]
-        sparse_intra = dense_intra & (intra_lay | self_eye)
-    else:
-        sparse_cached, sparse_intra = dense_cached, dense_intra
+    with jax.named_scope("attn.read"):       # the masks
+        j = jnp.arange(total_len)
+        offs = jnp.arange(W)
+        # cached rows: strictly before the CHUNK START for every query
+        # (rows in [pos, pos+i) are stale — the fresh intra keys stand in)
+        causal_c = j[None, :] < pos[:, None]                      # (b, L)
+        dense_cached = jnp.broadcast_to(
+            (causal_c & key_mask)[:, None, :], (b, W, total_len))
+        # intra-chunk: key kk visible to query i iff kk <= i (self included)
+        tri = offs[:, None] >= offs[None, :]                      # (W, W)
+        dense_intra = jnp.broadcast_to(tri[None], (b, W, W))
+        if any_sparse:
+            layout = _sparse_layout(cfg, total_len)
+            qrows = jnp.minimum(pos[:, None] + offs[None, :],
+                                total_len - 1)                    # (b, W)
+            lrows = jnp.take(layout, qrows, axis=0)               # (b, W, L)
+            sparse_cached = dense_cached & lrows
+            intra_lay = jnp.take_along_axis(
+                lrows, jnp.broadcast_to(qrows[:, None, :], (b, W, W)),
+                axis=2)                      # (b, W, W): layout[p+i, p+kk]
+            # jaxlint: disable=JL001 — static W identity, trace-time const
+            self_eye = jnp.eye(W, dtype=bool)[None]
+            sparse_intra = dense_intra & (intra_lay | self_eye)
+        else:
+            sparse_cached, sparse_intra = dense_cached, dense_intra
 
     quantized = "k_scale" in cache
 
@@ -1041,10 +1058,11 @@ def _decode_chunk_math(params: dict, x_toks: Array, pos: Array,
         p = lp["attn"]
         hn = core.layernorm(p["ln"], h)
         q, k, v = attn_ops.qkv_project(p, hn, cfg.heads)  # (b, h, W, dh)
-        a_c = jnp.where(is_sparse, sparse_cached, dense_cached) \
-            if any_sparse else dense_cached
-        a_i = jnp.where(is_sparse, sparse_intra, dense_intra) \
-            if any_sparse else dense_intra
+        with jax.named_scope("attn.read"):
+            a_c = jnp.where(is_sparse, sparse_cached, dense_cached) \
+                if any_sparse else dense_cached
+            a_i = jnp.where(is_sparse, sparse_intra, dense_intra) \
+                if any_sparse else dense_intra
         if kernel_mode:
             out = _kernel_read_wide(q, k, v, ck, cv, block_tables, pos,
                                     a_c, a_i, scale=cfg.scale, ksc=ksc,
@@ -1085,6 +1103,7 @@ def _decode_chunk_math(params: dict, x_toks: Array, pos: Array,
     return h_out, ks, vs
 
 
+@jax.named_scope("kv.store")
 def _store_rows_wide(cache: dict, ks: Array, vs: Array,
                      pos: Array) -> dict:
     """W-wide twin of ``_store_rows_per_slot``: ks/vs (depth, b, heads,
@@ -1121,6 +1140,7 @@ def _store_rows_wide(cache: dict, ks: Array, vs: Array,
     return {"k": put_rows(cache["k"], ks), "v": put_rows(cache["v"], vs)}
 
 
+@jax.named_scope("kv.store")
 def _store_rows_paged_wide(pool: dict, ks: Array, vs: Array, pos: Array,
                            block_tables: Array, active: Array,
                            total_len: int) -> dict:
@@ -1250,6 +1270,7 @@ def speculative_verify(params: dict, cur_tok: Array, drafts: Array,
     return emit, cur_new, pos_new, act_new, ks, vs
 
 
+@jax.named_scope("kv.view")
 def _draft_cache_view(read_cache: dict, depth: int) -> dict:
     """The draft's read view: the first ``depth`` layers of the full
     cache/view/pool (every KV layout carries depth on the leading

@@ -154,6 +154,7 @@ def _pad_seq(x, mult, axis):
     return jnp.pad(x, widths)
 
 
+@jax.named_scope("attn.flash_fwd")
 def _flash_fwd(q, k, v, mask, *static):
     """The forward kernel, one shard of batch and heads per device under
     a mesh (a Mosaic kernel cannot be auto-partitioned)."""
@@ -215,6 +216,7 @@ def _flash_fwd_local(q, k, v, mask, scale, causal, block_q, block_k,
             jax.ShapeDtypeStruct((bh, n, NUM_LANES), jnp.float32),
         ],
         interpret=interpret,
+        name="attn.flash_fwd",
     )(*inputs)
     out = out.reshape(b, h, n, d)[:, :, :n_orig]
     m = m[:, :, 0].reshape(b, h, n)[:, :, :n_orig]
@@ -554,6 +556,7 @@ def _pallas_attention_bwd_local(q, k, v, mask, dout, out, softmax_stats, *,
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "arbitrary")),
             interpret=interpret,
+            name="attn.flash_bwd",
         )(*mask_inputs, qf, kf, vf, dof, *stats)
         dq = dq.astype(q.dtype).reshape(b, h, n, d)[:, :, :n_orig]
         dk = dk.reshape(b, h, n, d)[:, :, :n_orig]
@@ -581,6 +584,7 @@ def _pallas_attention_bwd_local(q, k, v, mask, dout, out, softmax_stats, *,
         out_specs=pl.BlockSpec((1, block_q, d), tile_q),
         out_shape=jax.ShapeDtypeStruct((bh, n, d), q.dtype),
         interpret=interpret,
+        name="attn.flash_bwd",
     )(*mask_inputs, qf, kf, vf, dof, *stats)
 
     # dk/dv: grid over key tiles
@@ -607,6 +611,7 @@ def _pallas_attention_bwd_local(q, k, v, mask, dout, out, softmax_stats, *,
         out_shape=[jax.ShapeDtypeStruct((bh, n, d), k.dtype),
                    jax.ShapeDtypeStruct((bh, n, d), v.dtype)],
         interpret=interpret,
+        name="attn.flash_bwd",
     )(*mask_inputs, qf, kf, vf, dof, *stats)
 
     dq = dq.reshape(b, h, n, d)[:, :, :n_orig]
@@ -634,6 +639,7 @@ def _flash_fwd_rule(q, k, v, mask, scale, causal, block_q, block_k,
     return out, (q, k, v, mask, out, stats)
 
 
+@jax.named_scope("attn.flash_bwd")
 def _flash_bwd_rule(scale, causal, block_q, block_k, interpret, bwd_impl,
                     res, dout):
     q, k, v, mask, out, stats = res

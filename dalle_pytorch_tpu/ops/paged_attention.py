@@ -320,6 +320,7 @@ def paged_decode_attention(q: Array, k_pages: Array, v_pages: Array,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="paged_attn",
     )(*prefetch, *inputs)
     return acc, m[:, :, 0], l[:, :, 0]
 

@@ -146,6 +146,7 @@ def visible_pages_causal(seq_len: int, page_size: int, block: int = 16, *,
     return vis, cnt, cnt_causal
 
 
+@jax.named_scope("attn.read")
 def sparse_attention_ref(q: Array, k: Array, v: Array, *, scale: float,
                          causal: bool, block: int = 16,
                          mask: Optional[Array] = None,
@@ -179,6 +180,7 @@ def sparse_attention_ref(q: Array, k: Array, v: Array, *, scale: float,
     return jnp.einsum("bhij,bhjd->bhid", attn, v)
 
 
+@jax.named_scope("attn.read")
 def sparse_attention_windowed(q: Array, k: Array, v: Array, *, scale: float,
                               causal: bool, block: int = 16,
                               mask: Optional[Array] = None,

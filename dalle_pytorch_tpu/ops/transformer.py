@@ -227,6 +227,7 @@ def attn_branch(layer_params: dict, x: Array, mask: Optional[Array],
     return lax.cond(is_sparse, sparse_fn, dense_fn, h)
 
 
+@jax.named_scope("ff")
 def ff_branch(layer_params: dict, x: Array, cfg: TransformerConfig,
               key: Optional[Array], train: bool,
               dropout_fn=None) -> Array:
@@ -250,10 +251,11 @@ def ff_or_moe(layer_params: dict, x: Array, cfg: TransformerConfig,
     MoE variant returns its load-balance loss (the scan accumulates it)."""
     if cfg.moe_experts:
         from dalle_pytorch_tpu.ops.moe import moe_apply
-        p = layer_params["ff"]
-        h = core.layernorm(p["ln"], x)
-        out, aux = moe_apply(p["moe"], h, cfg=cfg.moe)
-        return core.dropout(key, out, cfg.ff_dropout, train), aux
+        with jax.named_scope("ff"):
+            p = layer_params["ff"]
+            h = core.layernorm(p["ln"], x)
+            out, aux = moe_apply(p["moe"], h, cfg=cfg.moe)
+            return core.dropout(key, out, cfg.ff_dropout, train), aux
     return (ff_branch(layer_params, x, cfg, key, train),
             jnp.float32(0.0))
 

@@ -66,14 +66,26 @@ def make_train_step(loss_fn: Callable, optimizer,
         else:
             loss, grads = accumulate_grads(loss_fn, params, batch, rng,
                                            grad_accum)
-        updates, opt_state = optimizer.update(grads, opt_state, params)
-        if lr_scale is not None:
-            updates = jax.tree.map(
-                lambda u: (u * lr_scale).astype(u.dtype), updates)
-        params = optax.apply_updates(params, updates)
+        with jax.named_scope("optimizer"):
+            updates, opt_state = optimizer.update(grads, opt_state, params)
+            if lr_scale is not None:
+                updates = jax.tree.map(
+                    lambda u: (u * lr_scale).astype(u.dtype), updates)
+            params = optax.apply_updates(params, updates)
         return params, opt_state, loss
 
     return step
+
+
+def step_scopes(step, *args) -> dict:
+    """{instruction name: scope entry} of a jitted step compiled for
+    ``args`` (arrays, or ``ShapeDtypeStruct``s with shardings: nothing
+    runs and nothing is donated): the join from a profiler capture's
+    device events to the model's named scopes (``obs/device.py``). Not on
+    any hot path: it compiles the step once more (a hit in the persistent
+    compile cache only from an earlier call with the same code)."""
+    from dalle_pytorch_tpu.obs import device as odev
+    return odev.scopes_of_lowered(step.lower(*odev.abstract(args)))
 
 
 def accumulate_grads(loss_fn: Callable, params, batch: dict, rng,

@@ -125,6 +125,24 @@ COUNTERS = ("tokens_decoded", "decode_steps", "harvests",
             "decode_traces", "prefill_traces", "evicted",
             "prefix_hits", "cfg_pairs", "reaped")
 
+# the engine loop's cumulative seconds (stats(), /metrics): always on,
+# one clock pair a phase a chunk. engine_loop_s >= harvest_wait_s +
+# admit_s + deliver_s, and admit_prefill_s is a part of admit_s. They
+# answer, with no profiler: does the engine thread wait for the device
+# (harvest_wait_s near engine_loop_s) or the device for the engine thread
+LOOP_SECONDS = ("engine_loop_s", "harvest_wait_s", "admit_s",
+                "admit_prefill_s", "deliver_s")
+
+
+def _phase(name: str, **meta):
+    """One phase of the engine loop as a ``jax.profiler.TraceAnnotation``:
+    an atomic read when no capture runs; inside one (``POST
+    /admin/profile``, ``--profile_dir``, the benchmark's traced window)
+    a host event on the clock the device's ``XLA Ops`` are on. The
+    names are the span tree of docs/OBSERVABILITY.md."""
+    import jax
+    return jax.profiler.TraceAnnotation(name, **meta)
+
 
 class ProfileError(RuntimeError):
     """Typed rejection of a serve-side profiler capture request
@@ -235,16 +253,6 @@ class _Chunk:
         self.owners = owners
 
 
-def _p50_ms(samples: List[float]) -> float:
-    """Nearest-rank p50 of a list of wall-seconds, in ms (0.0 when
-    empty) — the admission-timing surface bench's prefix_compare
-    asserts warm-vs-cold prefill cost on."""
-    if not samples:
-        return 0.0
-    s = sorted(samples)
-    return round(1e3 * s[min(len(s) // 2, len(s) - 1)], 4)
-
-
 class _Row:
     """One SLOT's worth of admission plan. A plain request is one row; a
     guided request is two (cond + uncond shadow, ``pair_row`` linking
@@ -300,7 +308,6 @@ class Engine:
                  preview_every: int = 0,
                  model_version: str = "0",
                  weights_version: str = "0",
-                 time_admissions: bool = False,
                  flight_events: int = 256,
                  clock: Callable[[], float] = time.perf_counter,
                  device=None):
@@ -555,13 +562,6 @@ class Engine:
             self.prefix = PC.PrefixIndex(self.alloc,
                                          max_entries=prefix_entries)
             self._layer_sig = PC.layer_signature(cfg.transformer)
-        # admission timing (bench's prefix_compare reads these): wall
-        # seconds per cold prefill dispatch / warm admission, measured
-        # to completion (block_until_ready) — off by default, because
-        # the block is a host sync admission doesn't otherwise need
-        self.time_admissions = bool(time_admissions)
-        self.prefill_times: List[float] = []
-        self.warm_admit_times: List[float] = []
         # progressive image previews (streaming): every preview_every
         # harvested chunks per streaming slot, hand the image-token
         # prefix to on_preview (the postprocess stage pads it to the
@@ -573,7 +573,6 @@ class Engine:
             raise ValueError(f"preview_every must be >= 0, got "
                              f"{preview_every}")
         self.on_preview: Optional[Callable] = None
-        self.previews_requested = 0
         self._pending: deque = deque()   # dispatched, un-harvested chunks
         # memo for the config-static /stats read-bytes model, keyed by
         # the sparse_reads flag it was asked for
@@ -599,6 +598,8 @@ class Engine:
         self.warm_admit_traces = 0      # the warm-admission program: 1,
         #                                 ever (no bucket dependence)
         self._prefill_trace_counts: Dict[int, int] = {}
+        self._scopes_thread = None      # the thread inside device_scopes()
+        self._scope_maps: Dict[str, dict] = {}
         self.prefill_runs = 0           # prefill DISPATCHES (a warm hit
         #                                 runs zero of these)
         self.warm_admits = 0            # requests admitted zero-FLOP
@@ -609,6 +610,8 @@ class Engine:
         self.reaped = 0                 # externally-cancelled slots
         #                                 reclaimed (stream disconnect,
         #                                 group cancel, hedge loser)
+        for k in LOOP_SECONDS:
+            setattr(self, k, 0.0)
         self.decode_steps = 0           # fused steps dispatched (chunks*K)
         self.harvests = 0               # emit-ring device_gets — the ONLY
         #                                 host syncs in steady state
@@ -759,6 +762,18 @@ class Engine:
 
     # -- jitted programs ----------------------------------------------------
 
+    def _count_trace(self, counter: str, bucket: Optional[int] = None):
+        """Bump a trace counter from inside a program being TRACED — the
+        fixed-shape contract's proof. ``device_scopes()`` lowers the same
+        programs again from shapes, on its caller's thread; that is no
+        retrace of the serving path and is not counted."""
+        if threading.get_ident() == self._scopes_thread:
+            return
+        setattr(self, counter, getattr(self, counter) + 1)
+        if bucket is not None:
+            self._prefill_trace_counts[bucket] = \
+                self._prefill_trace_counts.get(bucket, 0) + 1
+
     def _cfg_closures(self, params, keys, temp, topk_k, top_p, partner,
                       cfgs, uncond):
         """The embed/sample closures BOTH fused decode programs share,
@@ -800,7 +815,7 @@ class Engine:
         ring. Traced exactly once (fixed shapes) — the side-effecting
         counter below proves it; the guidance-pair state rides as three
         more (num_slots,) arrays, never a new trace."""
-        self.decode_traces += 1
+        self._count_trace("decode_traces")
         from dalle_pytorch_tpu.models import dalle as D
         from dalle_pytorch_tpu.ops import decode as decode_ops
 
@@ -836,7 +851,7 @@ class Engine:
         (``ops.decode.decode_loop_paged``). The block tables are a
         per-chunk constant — the host maps every page the chunk could
         write before dispatch — so this too traces exactly once."""
-        self.decode_traces += 1
+        self._count_trace("decode_traces")
         from dalle_pytorch_tpu.models import dalle as D
         from dalle_pytorch_tpu.ops import decode as decode_ops
 
@@ -884,9 +899,7 @@ class Engine:
                 page_rows=None):
             # page_rows rides only the paged trace: dense admission
             # omits it entirely (no dead argument, no wasted transfer)
-            self.prefill_traces += 1
-            self._prefill_trace_counts[bucket] = \
-                self._prefill_trace_counts.get(bucket, 0) + 1
+            self._count_trace("prefill_traces", bucket)
             from dalle_pytorch_tpu.models import dalle as D
             from dalle_pytorch_tpu.ops import decode as decode_ops
 
@@ -894,7 +907,8 @@ class Engine:
             # PRNGKey(seed) the one-shot path uses): the host ships
             # plain int32 seeds, so admission stays free of implicit
             # transfers under guards.no_transfers
-            n_rng = jax.vmap(jax.random.PRNGKey)(n_seed)
+            with jax.named_scope("sample"):
+                n_rng = jax.vmap(jax.random.PRNGKey)(n_seed)
             tokens = D.embed_prompt(params, self.cfg, text)
             h, group = decode_ops.prefill(
                 params["transformer"], tokens, cfg=self.cfg.transformer,
@@ -918,16 +932,19 @@ class Engine:
                     return buf.at[:, page_rows, :, off].set(
                         jnp.transpose(val, (1, 3, 0, 2)))
 
-                cache = {k: put(cache[k], rows[k]) for k in cache}
+                with jax.named_scope("prefill.scatter"):
+                    cache = {k: put(cache[k], rows[k]) for k in cache}
             else:
-                cache = {k: cache[k].at[:, slots].set(group[k],
-                                                      mode="drop")
-                         for k in cache}
+                with jax.named_scope("prefill.scatter"):
+                    cache = {k: cache[k].at[:, slots].set(group[k],
+                                                          mode="drop")
+                             for k in cache}
             # logits at each row's TRUE last prompt position: rows are
             # padded to the bucket, but causality makes h[:, lens-1]
             # identical to the unpadded prefill's last row
-            h_last = jnp.take_along_axis(
-                h, (lens - 1)[:, None, None], axis=1)[:, 0]
+            with jax.named_scope("head"):
+                h_last = jnp.take_along_axis(
+                    h, (lens - 1)[:, None, None], axis=1)[:, 0]
             logits = self._logits_sync(D.to_logits(params, h_last))
             # n_partner is the GROUP-row index of a guided row's pair
             # (both members admit in the same bucket group: the null
@@ -940,18 +957,21 @@ class Engine:
                                       partner=n_partner,
                                       cfg_scale=n_cfgs,
                                       uncond=n_uncond)
-            cur_tok = cur_tok.at[slots].set(first, mode="drop")
-            pos = pos.at[slots].set(lens, mode="drop")
-            active = active.at[slots].set(True, mode="drop")
-            rng = rng.at[slots].set(n_rng, mode="drop")
-            temp = temp.at[slots].set(n_temp, mode="drop")
-            topk_k = topk_k.at[slots].set(n_topk, mode="drop")
-            top_p = top_p.at[slots].set(n_top_p, mode="drop")
+            with jax.named_scope("prefill.scatter"):
+                cur_tok = cur_tok.at[slots].set(first, mode="drop")
+                pos = pos.at[slots].set(lens, mode="drop")
+                active = active.at[slots].set(True, mode="drop")
+                rng = rng.at[slots].set(n_rng, mode="drop")
+                temp = temp.at[slots].set(n_temp, mode="drop")
+                topk_k = topk_k.at[slots].set(n_topk, mode="drop")
+                top_p = top_p.at[slots].set(n_top_p, mode="drop")
             # h_last rides back out for the prefix cache's insert (the
             # warm path's first token samples from exactly this row)
             return (cache, cur_tok, pos, active, rng, temp, topk_k,
                     top_p, h_last)
 
+        # the program's name in a trace's XLA Modules line: jit_prefill_b64
+        pre.__name__ = f"prefill_b{bucket}"
         fn = self._jit_prefill_program(pre)
         self._prefill_fns[bucket] = fn
         return fn
@@ -974,23 +994,26 @@ class Engine:
         def warm(params, cur_tok, pos, active, rng, temp, topk_k, top_p,
                  h_last, lens, slots, n_seed, n_temp, n_topk, n_top_p,
                  n_partner, n_cfgs, n_uncond):
-            self.warm_admit_traces += 1
+            self._count_trace("warm_admit_traces")
             from dalle_pytorch_tpu.models import dalle as D
-            n_rng = jax.vmap(jax.random.PRNGKey)(n_seed)
+            with jax.named_scope("sample"):
+                n_rng = jax.vmap(jax.random.PRNGKey)(n_seed)
             logits = self._logits_sync(D.to_logits(params, h_last))
             first = D.sample_per_slot(logits, lens, n_rng, n_temp,
                                       n_topk, n_top_p, self.cfg,
                                       partner=n_partner,
                                       cfg_scale=n_cfgs, uncond=n_uncond)
-            cur_tok = cur_tok.at[slots].set(first, mode="drop")
-            pos = pos.at[slots].set(lens, mode="drop")
-            active = active.at[slots].set(True, mode="drop")
-            rng = rng.at[slots].set(n_rng, mode="drop")
-            temp = temp.at[slots].set(n_temp, mode="drop")
-            topk_k = topk_k.at[slots].set(n_topk, mode="drop")
-            top_p = top_p.at[slots].set(n_top_p, mode="drop")
+            with jax.named_scope("prefill.scatter"):
+                cur_tok = cur_tok.at[slots].set(first, mode="drop")
+                pos = pos.at[slots].set(lens, mode="drop")
+                active = active.at[slots].set(True, mode="drop")
+                rng = rng.at[slots].set(n_rng, mode="drop")
+                temp = temp.at[slots].set(n_temp, mode="drop")
+                topk_k = topk_k.at[slots].set(n_topk, mode="drop")
+                top_p = top_p.at[slots].set(n_top_p, mode="drop")
             return cur_tok, pos, active, rng, temp, topk_k, top_p
 
+        warm.__name__ = "warm_admit"
         self._warm_fn = self._jit_warm_program(warm)
         return self._warm_fn
 
@@ -1230,6 +1253,17 @@ class Engine:
             # rather than dropping them on the floor
             self._orphan_handles(handles)
             return
+        with _phase("engine.admit.plan"):
+            rows, free = self._plan_admission(handles, now)
+        free = self._admit_cold(rows, free, now)
+        self._admit_warm(rows, free, now)
+
+    def _plan_admission(self, handles: List[S.RequestHandle], now: float
+                        ) -> Tuple[List[_Row], List[int]]:
+        """Validate the popped handles, fit them to the free slots (and,
+        paged, to the free pages) in arrival order, and plan each one's
+        rows -> (rows to admit, free slot indices). What does not fit
+        is re-queued here."""
         free = [i for i, s in enumerate(self.slots) if s is None]
         valid = []
         for h in handles:
@@ -1348,8 +1382,7 @@ class Engine:
             for h in take:
                 rows.extend(per_handle[h.request.request_id])
 
-        free = self._admit_cold(rows, free, now)
-        self._admit_warm(rows, free, now)
+        return rows, free
 
     def _admit_cold(self, rows: List[_Row], free: List[int],
                     now: float) -> List[int]:
@@ -1413,21 +1446,23 @@ class Engine:
                 if cold:
                     self.compiling = True
                 try:
+                    with _phase("engine.admit.put"):
+                        group_args = [put(a) for a in (
+                            text, lens, slots, n_seed, n_temp, n_topk,
+                            n_top_p, n_partner, n_cfgs, n_uncond)]
+                        paged_kw = {"page_rows": put(page_rows)} \
+                            if self.kv == "paged" else {}
                     t_pre = self.clock()
-                    outs = self._prefill_fn(bucket)(
-                        self.params, self.cache, self.cur_tok, self.pos,
-                        self.active, self.rng, self.temp, self.topk_k,
-                        self.top_p, put(text), put(lens), put(slots),
-                        put(n_seed), put(n_temp), put(n_topk),
-                        put(n_top_p), put(n_partner), put(n_cfgs),
-                        put(n_uncond),
-                        **({"page_rows": put(page_rows)}
-                           if self.kv == "paged" else {}))
+                    with _phase("engine.admit.prefill", bucket=bucket,
+                                mode="cold"):
+                        outs = self._prefill_fn(bucket)(
+                            self.params, self.cache, self.cur_tok,
+                            self.pos, self.active, self.rng, self.temp,
+                            self.topk_k, self.top_p, *group_args,
+                            **paged_kw)
+                    dispatch_s = self.clock() - t_pre
+                    self.admit_prefill_s += dispatch_s
                     self.prefill_runs += 1
-                    if self.time_admissions and not cold:
-                        import jax
-                        jax.block_until_ready(outs[1])
-                        self.prefill_times.append(self.clock() - t_pre)
                 finally:
                     if cold:
                         self.compiling = False
@@ -1470,7 +1505,8 @@ class Engine:
                 if not p.uncond:    # one admit span per request, not
                     #                 per slot of a guided pair
                     self._span(p.handle, "prefill_admit", t_slotted,
-                               bucket=bucket, mode="cold", slot=i)
+                               bucket=bucket, mode="cold", slot=i,
+                               dispatch_s=dispatch_s)
             self._wire_pairs(group)
             if self.prefix is not None:
                 for p in group:
@@ -1641,16 +1677,18 @@ class Engine:
                     h_rows = h_rows + [h_rows[0]] * (G - len(h_rows))
                 h_stack = jnp.stack(h_rows)
                 put = self._put
+                with _phase("engine.admit.put"):
+                    group_args = [put(a) for a in (
+                        lens, slots, n_seed, n_temp, n_topk, n_top_p,
+                        n_partner, n_cfgs, n_uncond)]
                 t_warm = self.clock()
-                outs = self._warm_admit_fn()(
-                    self.params, self.cur_tok, self.pos, self.active,
-                    self.rng, self.temp, self.topk_k, self.top_p,
-                    h_stack, put(lens), put(slots), put(n_seed),
-                    put(n_temp), put(n_topk), put(n_top_p),
-                    put(n_partner), put(n_cfgs), put(n_uncond))
-                if self.time_admissions and not coldw:
-                    jax.block_until_ready(outs[0])
-                    self.warm_admit_times.append(self.clock() - t_warm)
+                with _phase("engine.admit.prefill", mode="warm"):
+                    outs = self._warm_admit_fn()(
+                        self.params, self.cur_tok, self.pos, self.active,
+                        self.rng, self.temp, self.topk_k, self.top_p,
+                        h_stack, *group_args)
+                dispatch_s = self.clock() - t_warm
+                self.admit_prefill_s += dispatch_s
             finally:
                 if coldw:
                     self.compiling = False
@@ -1693,7 +1731,8 @@ class Engine:
             if not p.uncond:
                 self._span(p.handle, "prefill_admit", t_slotted,
                            mode="warm", slot=i,
-                           pages_shared=p.shared_n)
+                           pages_shared=p.shared_n,
+                           dispatch_s=dispatch_s)
             if self.metrics is not None:
                 self.metrics.event(**S.structured_event(
                     "serve_prefix_hit",
@@ -1930,7 +1969,18 @@ class Engine:
         time is the honest fulfillment time (docs/SERVING.md)."""
         import jax
         rec = self._pending.popleft()
-        ring, active_after = jax.device_get([rec.ring, rec.active])
+        t_wait = self.clock()
+        with _phase("engine.harvest_wait"):
+            ring, active_after = jax.device_get([rec.ring, rec.active])
+        t_got = self.clock()
+        self.harvest_wait_s += t_got - t_wait
+        with _phase("engine.deliver"):
+            self._deliver_chunk(rec, ring, active_after)
+        self.deliver_s += self.clock() - t_got
+
+    def _deliver_chunk(self, rec: _Chunk, ring, active_after) -> None:
+        """The host's half of a harvest, once the ring has landed: rings
+        to sinks and owners, spans, completions, the kill mask."""
         self.harvests += 1
         if self._profiler is not None:
             # chunks harvest FIFO, so the countdown set at capture
@@ -1997,7 +2047,6 @@ class Engine:
                     if slot.since_preview >= self.preview_every \
                             and img_done > 0:
                         slot.since_preview = 0
-                        self.previews_requested += 1
                         prefix = np.asarray(
                             slot.emitted[self.cfg.text_seq_len
                                          - slot.t0:], np.int32)
@@ -2164,11 +2213,13 @@ class Engine:
             # flush the in-flight pipeline first: the device pos and the
             # host's emitted list must describe the SAME point in the
             # stream, and no orphaned ring row may outlive the export
+            t_flush = self.clock()
             while self._pending:
                 # racelint: disable=RL003 — deliberate: _lock IS the
                 # step serializer; an export must flush (and sync) under
                 # it or the snapshot tears against a concurrent step
                 self._harvest_chunk()
+            self.engine_loop_s += self.clock() - t_flush
             slot = self.slots[i] if 0 <= i < self.num_slots else None
             if slot is None or slot.shadow_of is not None:
                 raise MigrationError("not_found", f"slot {i}")
@@ -2386,121 +2437,144 @@ class Engine:
             if self._t_start is None:
                 self._t_start = now
 
-            did = False
-            # mid-decode deadlines: chunk-boundary granularity — a slot
-            # past its deadline is cancelled before the next chunk is
-            # dispatched (its bit in the device mask is cleared, so the
-            # in-flight chunk's leftover tokens die with the owner check)
-            kill = []
-            for i, slot in enumerate(self.slots):
-                if slot is None or slot.shadow_of is not None:
-                    continue        # a shadow expires with its cond slot
-                if slot.handle.done():
-                    # cancelled externally mid-decode (stream client
-                    # disconnected, group cancelled, hedge lost): the
-                    # terminal result already stuck via first-write-
-                    # wins — reclaim the slot and its pages NOW instead
-                    # of decoding to the end for nobody
-                    self.reaped += 1
-                    if self.metrics is not None:
-                        self.metrics.event(**S.structured_event(
-                            "serve_slot_reaped",
-                            request_id=slot.handle.request.request_id,
-                            tokens_done=len(slot.emitted)))
-                    kill.extend(self._free_slot(i))
-                    continue
-                dt = slot.handle.request.deadline_t
-                if dt is not None and now > dt:
-                    self._expire(slot.handle, now, where="decoding")
-                    kill.extend(self._free_slot(i))
-            if kill:
-                keep = np.ones((self.num_slots,), bool)
-                keep[kill] = False
-                self.active = self._kill_fn(self.active, self._put(keep))
-                did = True
-
-            free = self.num_slots - self.active_slots()
-            if self.kv == "paged":
-                # don't pop just to defer/requeue every chunk (n=0 still
-                # reaps queued deadline expiries): with a head-of-line
-                # request waiting, hold admission until ITS need is
-                # free — freed pages accumulate for it; otherwise the
-                # floor is the smallest bucket's prompt span
-                floor = self._hol_need if self._hol_rid is not None \
-                    else self._min_admit_pages
-                if self.alloc.free < floor and self.prefix is not None \
-                        and self.queue.depth() > 0:
-                    # an idle pool held hostage by cached prefixes
-                    # would gate admission forever: shrink the LRU end
-                    # until the floor could pop
-                    self.prefix.shrink(floor)
-                if self.alloc.free < floor:
-                    free = 0
-            ready, expired = self.queue.pop_ready(free, now)
-            for h in expired:
-                self._expire(h, now, where="queued")
-                if self.kv == "paged":
-                    self._deferred_ids.discard(h.request.request_id)
-                    if h.request.request_id == self._hol_rid:
-                        self._hol_rid = None
-                        self._hol_need = 0
-            for h in ready:
-                # queue_wait closes HERE for a single-engine pop; a
-                # replica-set router already stamped it at routing
-                # (has_in_attempt keeps the two shapes from double-
-                # counting), and a page-deferred re-pop folds its extra
-                # wait into the next prefill_admit span
-                if h.trace is not None \
-                        and not h.trace.has_in_attempt("queue_wait"):
-                    self._span(h, "queue_wait", now)
-            if ready:
-                # published for the reclaim sweep BEFORE admission can
-                # block on a compile (see _admitting)
-                self._admitting = list(ready)
-                try:
-                    # racelint: disable=RL003 — deliberate: admission
-                    # compiles/donates into live slot buffers; it MUST
-                    # run under the step serializer (_lock), and the
-                    # reclaim sweep uses a timed acquire + _admitting
-                    # precisely so a slow compile cannot wedge it
-                    self._admit(ready, now)
-                finally:
-                    self._admitting = []
-            did = did or bool(ready or expired)
-
-            dispatched = False
-            if self.active_slots() > 0:
-                self._dispatch_chunk(now)
-                dispatched = did = True
-
-            # double buffer: while dispatching, keep exactly one chunk
-            # in flight un-harvested — the device_get below blocks on
-            # chunk N while the device computes chunk N+1. Once nothing
-            # new is dispatched (pool drained), flush the pipeline.
-            target = 1 if dispatched else 0
-            while len(self._pending) > target:
-                # racelint: disable=RL003 — deliberate: the harvest
-                # device_get is THE step; _lock is the step serializer,
-                # and the double-buffer above already bounds the stall
-                # to one chunk
-                self._harvest_chunk()
-                did = True
-
-            if self._profiler is not None and not dispatched \
-                    and not self._pending:
-                # the engine drained before the capture's K chunks ran:
-                # close it NOW with what it got (partial but valid) —
-                # an open process-global trace slows every replica in
-                # this process until the next traffic arrives, and "the
-                # next K chunks" cannot honestly outlive the work
-                self._finish_profile(partial=True)
-
-            if (self.metrics is not None and self.log_every
-                    and self.decode_steps - self._last_log
-                    >= self.log_every):
-                self._last_log = self.decode_steps
-                self.metrics.event(event="serve", **self.stats())
+            with _phase("engine.step"):
+                # racelint: disable=RL003 — deliberate: _lock IS the step
+                # serializer; the step's blocking calls (the harvest's
+                # device_get, an admission's compile) must run under it,
+                # each for the reason given at its own site in _step
+                did = self._step(now)
+            if did:
+                self.engine_loop_s += self.clock() - now
             return did
+
+    def _expire_and_pop(self, now: float):
+        """The head of an iteration: reap externally-cancelled slots,
+        expire slots and queued requests past their deadline, pop what
+        the free slots (and pages) can take -> (did work, popped)."""
+        did = False
+        # mid-decode deadlines: chunk-boundary granularity — a slot
+        # past its deadline is cancelled before the next chunk is
+        # dispatched (its bit in the device mask is cleared, so the
+        # in-flight chunk's leftover tokens die with the owner check)
+        kill = []
+        for i, slot in enumerate(self.slots):
+            if slot is None or slot.shadow_of is not None:
+                continue        # a shadow expires with its cond slot
+            if slot.handle.done():
+                # cancelled externally mid-decode (stream client
+                # disconnected, group cancelled, hedge lost): the
+                # terminal result already stuck via first-write-
+                # wins — reclaim the slot and its pages NOW instead
+                # of decoding to the end for nobody
+                self.reaped += 1
+                if self.metrics is not None:
+                    self.metrics.event(**S.structured_event(
+                        "serve_slot_reaped",
+                        request_id=slot.handle.request.request_id,
+                        tokens_done=len(slot.emitted)))
+                kill.extend(self._free_slot(i))
+                continue
+            dt = slot.handle.request.deadline_t
+            if dt is not None and now > dt:
+                self._expire(slot.handle, now, where="decoding")
+                kill.extend(self._free_slot(i))
+        if kill:
+            keep = np.ones((self.num_slots,), bool)
+            keep[kill] = False
+            self.active = self._kill_fn(self.active, self._put(keep))
+            did = True
+
+        free = self.num_slots - self.active_slots()
+        if self.kv == "paged":
+            # don't pop just to defer/requeue every chunk (n=0 still
+            # reaps queued deadline expiries): with a head-of-line
+            # request waiting, hold admission until ITS need is
+            # free — freed pages accumulate for it; otherwise the
+            # floor is the smallest bucket's prompt span
+            floor = self._hol_need if self._hol_rid is not None \
+                else self._min_admit_pages
+            if self.alloc.free < floor and self.prefix is not None \
+                    and self.queue.depth() > 0:
+                # an idle pool held hostage by cached prefixes
+                # would gate admission forever: shrink the LRU end
+                # until the floor could pop
+                self.prefix.shrink(floor)
+            if self.alloc.free < floor:
+                free = 0
+        ready, expired = self.queue.pop_ready(free, now)
+        for h in expired:
+            self._expire(h, now, where="queued")
+            if self.kv == "paged":
+                self._deferred_ids.discard(h.request.request_id)
+                if h.request.request_id == self._hol_rid:
+                    self._hol_rid = None
+                    self._hol_need = 0
+        for h in ready:
+            # queue_wait closes HERE for a single-engine pop; a
+            # replica-set router already stamped it at routing
+            # (has_in_attempt keeps the two shapes from double-
+            # counting), and a page-deferred re-pop folds its extra
+            # wait into the next prefill_admit span
+            if h.trace is not None \
+                    and not h.trace.has_in_attempt("queue_wait"):
+                self._span(h, "queue_wait", now)
+        return did or bool(ready or expired), ready
+
+    def _step(self, now: float) -> bool:
+        """``step_once`` under its lock, phase by phase."""
+        with _phase("engine.expire"):
+            did, ready = self._expire_and_pop(now)
+        if ready:
+            # published for the reclaim sweep BEFORE admission can
+            # block on a compile (see _admitting)
+            self._admitting = list(ready)
+            t_admit = self.clock()
+            try:
+                # racelint: disable=RL003 — deliberate: admission
+                # compiles/donates into live slot buffers; it MUST
+                # run under the step serializer (_lock), and the
+                # reclaim sweep uses a timed acquire + _admitting
+                # precisely so a slow compile cannot wedge it
+                with _phase("engine.admit"):
+                    self._admit(ready, now)
+            finally:
+                self._admitting = []
+                self.admit_s += self.clock() - t_admit
+
+        dispatched = False
+        if self.active_slots() > 0:
+            with _phase("engine.dispatch"):
+                self._dispatch_chunk(now)
+            dispatched = did = True
+
+        # double buffer: while dispatching, keep exactly one chunk
+        # in flight un-harvested — the device_get below blocks on
+        # chunk N while the device computes chunk N+1. Once nothing
+        # new is dispatched (pool drained), flush the pipeline.
+        target = 1 if dispatched else 0
+        while len(self._pending) > target:
+            # racelint: disable=RL003 — deliberate: the harvest
+            # device_get is THE step; _lock is the step serializer,
+            # and the double-buffer above already bounds the stall
+            # to one chunk
+            self._harvest_chunk()
+            did = True
+
+        if self._profiler is not None and not dispatched \
+                and not self._pending:
+            # the engine drained before the capture's K chunks ran:
+            # close it NOW with what it got (partial but valid) —
+            # an open process-global trace slows every replica in
+            # this process until the next traffic arrives, and "the
+            # next K chunks" cannot honestly outlive the work
+            self._finish_profile(partial=True)
+
+        if (self.metrics is not None and self.log_every
+                and self.decode_steps - self._last_log
+                >= self.log_every):
+            self._last_log = self.decode_steps
+            self.metrics.event(event="serve", **self.stats())
+        return did
 
     def idle(self) -> bool:
         """True when there is nothing left to do: queue empty, every slot
@@ -2607,6 +2681,71 @@ class Engine:
         return self._terminate_active(S.CANCELLED, reason)
 
     # -- observability ------------------------------------------------------
+
+    def device_scopes(self, buckets: Optional[Sequence[int]] = None
+                      ) -> Dict[str, dict]:
+        """{program name: {instruction name: scope entry}} for the decode
+        program and each admission program this engine has built (or,
+        given ``buckets``, those buckets' prefill programs): the join
+        from a profiler capture's ``XLA Ops`` events to the model's
+        named scopes (``obs/device.py``). The program names are the ones
+        a capture's ``XLA Modules`` line shows after ``jit_``.
+
+        Each program is lowered and compiled once more from the SHAPES
+        of the engine's own state — nothing runs, nothing is donated,
+        the serving thread is not touched — and the map is kept, since
+        an engine's programs never change. Never on a hot path: the first
+        call costs each program's compile time on the caller's thread
+        (``POST /admin/profile`` pays it on the HTTP thread, before it
+        arms the capture); the persistent compile cache serves it only
+        from an earlier call with the same code
+        (``obs.device.scopes_of_lowered``)."""
+        import jax
+        import jax.numpy as jnp
+
+        from dalle_pytorch_tpu.obs import device as odev
+
+        shapes = odev.abstract
+        # a host array goes where _put places it: as the per-slot state
+        rep = shapes(self.pos).sharding
+
+        def host(shape, dtype):
+            return jax.ShapeDtypeStruct(shape, dtype, sharding=rep)
+
+        G = self.num_slots
+        state = shapes((self.cur_tok, self.pos, self.active, self.rng,
+                        self.temp, self.topk_k, self.top_p))
+        group = (host((G,), jnp.int32),) * 3 + (      # lens, slots, n_seed
+            host((G,), jnp.float32), host((G,), jnp.int32),
+            host((G,), jnp.float32), host((G,), jnp.int32),
+            host((G,), jnp.float32), host((G,), jnp.bool_))
+        params, cache = shapes(self.params), shapes(self.cache)
+        paged = self.kv == "paged"
+        programs = {self._decode_fn.__name__: (self._decode_fn, (
+            params, cache,
+            *((shapes(self.block_tables),) if paged else ()),
+            *state, *shapes((self.cfg_partner, self.cfg_scale,
+                             self.cfg_uncond))))}
+        for b in sorted(self._prefill_fns if buckets is None else buckets):
+            fn = self._prefill_fn(b)
+            text = host((G, b), jnp.int32)
+            programs[fn.__name__] = (fn, (
+                params, cache, *state, text, *group,
+                *((text,) if paged else ())))       # page_rows
+        if self._warm_fn is not None:
+            h_last = host((G, self.cfg.dim),
+                          self.params["text_emb"]["w"].dtype)
+            programs[self._warm_fn.__name__] = (self._warm_fn, (
+                params, *state, h_last, *group))
+        self._scopes_thread = threading.get_ident()
+        try:
+            for name, (fn, args) in programs.items():
+                if name not in self._scope_maps:
+                    self._scope_maps[name] = odev.scopes_of_lowered(
+                        fn.lower(*args))
+        finally:
+            self._scopes_thread = None
+        return {name: self._scope_maps[name] for name in programs}
 
     def _finish_profile(self, partial: bool = False) -> None:
         """Stop the in-flight capture and emit ``serve_profile_done``
@@ -2781,13 +2920,7 @@ class Engine:
                     "prefix_entries": len(self.prefix),
                     "prefix_pages_held": self.prefix.pages_held,
                     "prefix_evictions": self.prefix.evicted,
-                    "warm_admits": self.warm_admits,
-                    "prefill_runs": self.prefill_runs,
                 })
-                if self.time_admissions:
-                    paged["prefill_p50_ms"] = _p50_ms(self.prefill_times)
-                    paged["warm_admit_p50_ms"] = _p50_ms(
-                        self.warm_admit_times)
         spec = {}
         if self.speculative:
             k = self.speculative
@@ -2827,10 +2960,14 @@ class Engine:
             "expired": self.expired,
             "cfg_pairs": self.cfg_pairs,
             "reaped": self.reaped,
-            "previews_requested": self.previews_requested,
             "rejected": self.queue.rejected,
             "decode_compiles": self.decode_traces,
             "prefill_compiles": self.prefill_traces,
+            # admissions dispatched, and the loop's cumulative seconds
+            # by phase (LOOP_SECONDS): unrounded, they are differenced
+            "prefill_runs": self.prefill_runs,
+            "warm_admits": self.warm_admits,
+            **{k: getattr(self, k) for k in LOOP_SECONDS},
             "prefill_buckets": list(self.buckets),
             "harvests": self.harvests,
             "host_round_trips_per_token": round(

@@ -146,6 +146,7 @@ from typing import Callable, List, Optional
 
 from dalle_pytorch_tpu.serve import scheduler as S
 from dalle_pytorch_tpu.serve.engine import COUNTERS as _COUNTERS
+from dalle_pytorch_tpu.serve.engine import LOOP_SECONDS as _LOOP_SECONDS
 from dalle_pytorch_tpu.serve.engine import MigrationError
 
 # replica lifecycle states (``replica_states()`` / ``stats()``)
@@ -2304,6 +2305,10 @@ class ReplicaSet:
                     "completed": e.completed,
                     "tokens_decoded": e.tokens_decoded,
                 })
+                # the engine loop's seconds by phase: a thread replica's
+                # engine has them; a child's proxy does not ship them
+                rec.update({k: getattr(e, k) for k in _LOOP_SECONDS
+                            if hasattr(e, k)})
                 if proc:
                     rec.update({"pid": e.pid, "rss_mb": e.rss_mb,
                                 "restarts": max(r.bringups - 1, 0),

@@ -22,6 +22,7 @@ Two call surfaces:
 from __future__ import annotations
 
 import json
+import os
 import sys
 import threading
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
@@ -742,6 +743,20 @@ class InferenceServer:
          "Tokens live migration avoided re-decoding"),
         ("profiles_taken", "dalle_serve_profiles_taken_total",
          "Completed POST /admin/profile captures"),
+        ("prefill_runs", "dalle_serve_prefill_runs_total",
+         "Bucket prefill programs dispatched (cold admissions)"),
+        ("warm_admits", "dalle_serve_warm_admits_total",
+         "Requests admitted from the prefix cache (no prefill)"),
+        ("engine_loop_s", "dalle_serve_engine_loop_seconds_total",
+         "Seconds the engine thread spent in iterations that did work"),
+        ("harvest_wait_s", "dalle_serve_harvest_wait_seconds_total",
+         "Seconds of it blocked fetching an emit ring (device-bound)"),
+        ("admit_s", "dalle_serve_admit_seconds_total",
+         "Seconds of it admitting requests (plan, transfers, prefill)"),
+        ("admit_prefill_s", "dalle_serve_admit_prefill_seconds_total",
+         "Seconds of admission inside the prefill / warm-admit call"),
+        ("deliver_s", "dalle_serve_deliver_seconds_total",
+         "Seconds of it delivering harvested tokens on the host"),
         ("reaped", "dalle_serve_reaped_total",
          "Slots freed because the handle terminated externally "
          "(stream disconnect, group cancel, hedge loser)"),
@@ -887,6 +902,7 @@ class InferenceServer:
             eng = self.engine.replicas[replica].engine
         else:
             eng = self.engine
+        self._write_scopes(eng, str(log_dir))
         with self._profile_arm_lock:
             if self._is_set:
                 # jax.profiler is a PER-PROCESS singleton: in a thread-
@@ -904,6 +920,24 @@ class InferenceServer:
             rec = dict(eng.request_profile(str(log_dir), chunks=chunks))
         rec["replica"] = int(replica) if self._is_set else 0
         return rec
+
+
+    @staticmethod
+    def _write_scopes(eng, log_dir: str) -> None:
+        """``scopes.json`` beside the capture: the engine's device
+        programs' instruction -> scope maps (``Engine.device_scopes``),
+        which turn the capture's ``XLA Ops`` events into time by named
+        scope. Here, on the HTTP thread, before the capture is armed: a
+        compile (a cache hit where the persistent cache is on) must
+        neither fall into the capture nor stall the engine thread. A
+        failure costs the maps, not the capture."""
+        os.makedirs(log_dir, exist_ok=True)
+        try:
+            maps = eng.device_scopes()
+        except Exception as e:  # noqa: BLE001 - the capture still runs
+            maps = {"error": repr(e)}
+        with open(os.path.join(log_dir, "scopes.json"), "w") as f:
+            json.dump(maps, f)
 
 
 # ---------------------------------------------------------------------------

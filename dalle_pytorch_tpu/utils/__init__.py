@@ -6,7 +6,7 @@ failure records from threads that must not import jax themselves (the
 jax import stays inside the deadline-bounded claim thread).
 """
 
-__all__ = ["MetricsLogger", "StepProfiler", "trace", "enable_nan_checks",
+__all__ = ["MetricsLogger", "StepProfiler", "enable_nan_checks",
            "check_finite_tree", "guard_loss", "structured_event"]
 
 _EXPORTS = {
@@ -14,7 +14,6 @@ _EXPORTS = {
     "structured_event": ("dalle_pytorch_tpu.utils.metrics",
                          "structured_event"),
     "StepProfiler": ("dalle_pytorch_tpu.utils.profiling", "StepProfiler"),
-    "trace": ("dalle_pytorch_tpu.utils.profiling", "trace"),
     "enable_nan_checks": ("dalle_pytorch_tpu.utils.debug",
                           "enable_nan_checks"),
     "check_finite_tree": ("dalle_pytorch_tpu.utils.debug",

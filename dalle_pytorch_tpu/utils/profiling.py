@@ -8,23 +8,9 @@ XLA's own tracer so a Perfetto/TensorBoard trace of the compiled train step
 
 from __future__ import annotations
 
-import contextlib
-from typing import Iterator, Optional
+from typing import Optional
 
 import jax
-
-
-@contextlib.contextmanager
-def trace(log_dir: Optional[str], *, first_step: int = 0,
-          num_steps: int = 3) -> Iterator[None]:
-    """No-op when ``log_dir`` is falsy; otherwise captures a jax.profiler
-    trace (viewable in TensorBoard / Perfetto). Wrap the steady-state steps,
-    not step 0 — compile time would swamp the trace."""
-    if not log_dir:
-        yield
-        return
-    with jax.profiler.trace(log_dir):
-        yield
 
 
 class StepProfiler:

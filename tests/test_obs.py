@@ -15,6 +15,7 @@ tier-1; the one process+socket test is the SIGKILL acceptance row.
 """
 
 import json
+import os
 import threading
 import urllib.error
 import urllib.request
@@ -363,6 +364,119 @@ class TestEngineTracing:
 
 
 # ---------------------------------------------------------------------------
+# the engine loop: phases on the profiler's clock, seconds in stats()
+# ---------------------------------------------------------------------------
+
+def _host_events(log_dir, prefix):
+    """[(name, start_ns, end_ns, line)] of the capture's host events whose
+    name starts with ``prefix``."""
+    import glob
+
+    from jax.profiler import ProfileData
+    path, = glob.glob(str(log_dir / "plugins" / "profile" / "*"
+                          / "*.xplane.pb"))
+    out = []
+    for plane in ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            out += [(e.name, e.start_ns, e.start_ns + e.duration_ns,
+                     line.name) for e in line.events
+                    if e.name.startswith(prefix)]
+    return sorted(out, key=lambda e: e[1])
+
+
+class TestEngineLoop:
+    def test_phases_are_host_events_of_a_capture_and_nest(self, bundle,
+                                                          tmp_path):
+        params, _ = bundle
+        queue = RequestQueue(max_depth=8)
+        engine = Engine(params, CFG, queue, num_slots=2, chunk_steps=4)
+        queue.submit(REQS[0])
+        engine.run_until_idle()             # compiles, outside the capture
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+        try:
+            for r in (REQS[0], REQS[3], REQS[0]):   # three admissions
+                queue.submit(r)
+                engine.run_until_idle()
+        finally:
+            jax.profiler.stop_trace()
+        events = _host_events(tmp_path, "engine.")
+        by_name = {}
+        for e in events:
+            by_name.setdefault(e[0], []).append(e)
+        assert set(by_name) == {
+            "engine.step", "engine.expire", "engine.admit",
+            "engine.admit.plan", "engine.admit.put",
+            "engine.admit.prefill", "engine.dispatch",
+            "engine.harvest_wait", "engine.deliver"}
+        # one engine.admit an admission, each holding its three parts
+        assert len(by_name["engine.admit"]) == 3
+        assert len(by_name["engine.admit.prefill"]) == 3
+        assert len(by_name["engine.dispatch"]) \
+            == len(by_name["engine.harvest_wait"]) \
+            == len(by_name["engine.deliver"])
+
+        def inside(inner, outer):
+            return [o for o in by_name[outer]
+                    if o[1] <= inner[1] and inner[2] <= o[2]
+                    and o[3] == inner[3]]
+
+        for name in ("engine.expire", "engine.admit", "engine.dispatch",
+                     "engine.harvest_wait", "engine.deliver"):
+            assert all(len(inside(e, "engine.step")) == 1
+                       for e in by_name[name]), name
+        for name in ("engine.admit.plan", "engine.admit.put",
+                     "engine.admit.prefill"):
+            assert all(len(inside(e, "engine.admit")) == 1
+                       for e in by_name[name]), name
+
+    def test_loop_seconds_are_monotone_and_add_up(self, bundle):
+        params, _ = bundle
+        queue = RequestQueue(max_depth=8)
+        engine = Engine(params, CFG, queue, num_slots=2, chunk_steps=4)
+        from dalle_pytorch_tpu.serve.engine import LOOP_SECONDS
+        seen = [engine.stats()]
+        assert all(seen[0][k] == 0.0 for k in LOOP_SECONDS)
+        assert seen[0]["prefill_runs"] == seen[0]["warm_admits"] == 0
+        for r in REQS[:2]:
+            queue.submit(r)
+        engine.run_until_idle()
+        seen.append(engine.stats())
+        # the steady loop with its clock reads and annotations stays
+        # transfer-clean
+        with guards.no_transfers():
+            for r in REQS[:2]:
+                queue.submit(r)
+            while not engine.idle():
+                engine.step_once()
+                seen.append(engine.stats())
+        for a, b in zip(seen, seen[1:]):
+            assert all(b[k] >= a[k] for k in LOOP_SECONDS)
+        last = seen[-1]
+        assert last["prefill_runs"] >= 2 and last["harvest_wait_s"] > 0
+        assert last["engine_loop_s"] >= last["harvest_wait_s"] \
+            + last["admit_s"] + last["deliver_s"]
+        assert last["admit_s"] >= last["admit_prefill_s"] > 0
+
+    def test_prefill_admit_span_carries_the_dispatch_seconds(self, bundle):
+        params, _ = bundle
+        queue = RequestQueue(max_depth=8)
+        engine = Engine(params, CFG, queue, num_slots=2, chunk_steps=4)
+        h = queue.submit(REQS[0])
+        engine.run_until_idle()
+        assert h.result(timeout=5).status == OK
+        span, = [r for r in engine.flight.dump()
+                 if r.get("span") == "prefill_admit"]
+        # the seconds inside the prefill call: a part of the span, which
+        # also holds the wait since the pop
+        assert 0 < span["dispatch_s"] <= span["dur_s"]
+        assert span["mode"] == "cold" and span["bucket"] in engine.buckets
+
+
+# ---------------------------------------------------------------------------
 # replica-set tracing: thread-mode failover replay link
 # ---------------------------------------------------------------------------
 
@@ -571,6 +685,10 @@ class TestServerObs:
                     "dalle_serve_e2e_latency_seconds_bucket",
                     "dalle_serve_queue_wait_seconds_count",
                     "dalle_serve_decode_ms_per_token_count",
+                    "dalle_serve_prefill_runs_total",
+                    "dalle_serve_engine_loop_seconds_total",
+                    "dalle_serve_harvest_wait_seconds_total",
+                    "dalle_serve_admit_prefill_seconds_total",
                     "dalle_serve_info"):
             assert fam in text, f"missing family {fam}"
         count = [ln for ln in text.splitlines()
@@ -599,6 +717,16 @@ class TestServerObs:
         st, rec = self._post(port, "/admin/profile", {"chunks": 500},
                              token=srv.admin_token)
         assert st == 200 and rec["kind"] == "serve_profile_armed"
+        # the capture's directory holds the join to the named scopes,
+        # written before the capture was armed; the lowering it took is
+        # no retrace of the serving path
+        with open(os.path.join(srv.profile_dir, "scopes.json")) as f:
+            maps = json.load(f)
+        assert "_decode_impl" in maps and any(
+            name.startswith("prefill_b") for name in maps)
+        assert {e["scope"] for e in maps["_decode_impl"].values()} \
+            >= {"kv.store", "attn.read", "ff", "sample"}
+        assert srv.stats()["decode_compiles"] == 1
         st, rec = self._post(port, "/admin/profile", {"chunks": 1},
                              token=srv.admin_token)
         assert st == 409 and rec["reason"] == "capture_active"
