@@ -216,6 +216,19 @@ def _kernel_read(q: Array, k: Array, v: Array, pool_k: Array,
     return out.astype(q.dtype)[:, :, None, :]
 
 
+def _softmax_with_self(scores: Array, allowed: Array, q: Array, k: Array,
+                       scale: float) -> Array:
+    """The one masked softmax of the cached-attention reads: scores
+    (b, h, 1, rows) over the cached rows, dead where ``allowed`` (b, rows)
+    is False, plus the current token's self logit as the last column.
+    Returns the weights (b, h, 1, rows + 1)."""
+    scores = jnp.where(allowed[:, None, None, :], scores,
+                       core.neg_inf(scores.dtype))
+    self_score = jnp.einsum("bhqd,bhqd->bhq", q, k)[..., None] * scale
+    return jax.nn.softmax(jnp.concatenate([scores, self_score], -1),
+                          axis=-1)
+
+
 @jax.named_scope("attn.read")
 def _gather_read(q: Array, k: Array, v: Array, ck: Array, cv: Array,
                  allowed: Array, *, scale: float,
@@ -223,8 +236,9 @@ def _gather_read(q: Array, k: Array, v: Array, ck: Array, cv: Array,
                  vsc: Optional[Array] = None) -> Array:
     """The dense-view half of the cached-attention read seam: one
     einsum softmax over a (b, heads, L, dh) view of the cached rows
-    (the real dense slot cache, ``paged_view``'s block-table gather,
-    or a visibility-trimmed slice of it) plus the self-logit. The
+    (the real dense slot cache, or ``paged_view``'s block-table gather;
+    the page pool itself is read by ``_paged_gather_read``) plus the
+    self-logit. The
     int8 cache reads int8 rows and upcasts in registers, scales
     applied OUTSIDE the contractions (along j) so no dequantized copy
     materializes — same trick as ops/quant. Returns the (b, h, 1, dh)
@@ -237,10 +251,7 @@ def _gather_read(q: Array, k: Array, v: Array, ck: Array, cv: Array,
         # promote the whole decode carry to f32 under bf16 params
         # (scan carry dtype mismatch) and double the vector bytes
         scores = scores * ksc[:, :, None, :].astype(scores.dtype)
-    scores = jnp.where(allowed[:, None, None, :], scores,
-                       core.neg_inf(scores.dtype))
-    self_score = jnp.einsum("bhqd,bhqd->bhq", q, k)[..., None] * scale
-    w = jax.nn.softmax(jnp.concatenate([scores, self_score], -1), axis=-1)
+    w = _softmax_with_self(scores, allowed, q, k, scale)
     wj = w[..., :-1]
     if quantized:
         wj = wj * vsc[:, :, None, :].astype(wj.dtype)
@@ -248,6 +259,80 @@ def _gather_read(q: Array, k: Array, v: Array, ck: Array, cv: Array,
     else:
         cvc = cv
     return jnp.einsum("bhqj,bhjd->bhqd", wj, cvc) + w[..., -1:] * v
+
+
+def _view_tables(block_tables: Array, total_len: int,
+                 page_size: int) -> Array:
+    """The block tables trimmed to the ``ceil(total_len / page_size)``
+    columns a view of ``total_len`` rows reads: a caller's table is sized
+    for the pool's longest sequence, and wholly unmapped logical pages
+    beyond ``total_len`` must never reach a gather."""
+    return block_tables[:, :-(-total_len // page_size)]   # pages_for
+
+
+@jax.named_scope("kv.view")
+def layer_pool_view(pool: dict, layer: Array, tables: Array):
+    """ONE layer's pages through the block tables, read where they lie:
+    pool buffers (depth, P, heads, ps[, dh]), ``layer`` a traced scalar,
+    tables (b, w) -> (gk, gv, gk_scale, gv_scale) of (b, w, heads, ps[,
+    dh]) (scales None for a float pool). The one per-layer view of BOTH
+    step maths: the full table trimmed to ``ceil(total_len / ps)``
+    columns, or a sparse layer's visible slice of it.
+
+    Three choices keep this a gather of whole pages and nothing else,
+    each read off the compiled TPU program (PERF.md, PR 25):
+      * indexed by ``layer * P + page`` into the (depth * P, ...) pool
+        (a bitcast), not taken from a scanned per-layer slice: the
+        scan's slice of a layer is a copy of that layer;
+      * page-major and unrelaid — a page is one contiguous run of the
+        pool, and ``_paged_gather_read`` contracts it as it lies; the
+        slot-major ``moveaxis`` + ``reshape`` of ``paged_view`` is what
+        turned the read into transposing copies of the pool;
+      * ``mode='clip'``: tables are in range by construction, and the
+        default fill mode adds a select over every gathered row."""
+    num_pages = pool["k"].shape[1]
+    idx = layer * num_pages + tables
+
+    def take(buf):
+        return jnp.take(buf.reshape((-1,) + buf.shape[2:]), idx, axis=0,
+                        mode="clip")
+    gk, gv = take(pool["k"]), take(pool["v"])
+    if "k_scale" not in pool:
+        return gk, gv, None, None
+    return gk, gv, take(pool["k_scale"]), take(pool["v_scale"])
+
+
+@jax.named_scope("attn.read")
+def _paged_gather_read(q: Array, k: Array, v: Array, gk: Array, gv: Array,
+                       allowed: Array, *, scale: float,
+                       ksc: Optional[Array] = None,
+                       vsc: Optional[Array] = None) -> Array:
+    """``_gather_read`` over ``layer_pool_view``'s page-major pages:
+    gk/gv (b, w, heads, ps, dh), allowed (b, rows) with rows <= w * ps
+    (logical row j is page j // ps, offset j % ps; a partial last page's
+    tail rows are dropped). The pages are contracted as they lie and
+    only the SCORES (b, heads, w * ps: kilobytes) are brought to logical
+    row order, so the softmax runs over rows 0..rows-1 in order plus the
+    self logit, exactly the dense view's; int8 scales apply outside the
+    contractions in score dtype, as there. Returns (b, h, 1, dh)."""
+    b, w, h, ps, _ = gk.shape
+    rows = allowed.shape[1]
+    quantized = ksc is not None
+    gkc = gk.astype(q.dtype) if quantized else gk
+    scores = jnp.einsum("bhd,bmhsd->bhms", q[:, :, 0, :], gkc) * scale
+    if quantized:
+        scores = scores * jnp.moveaxis(ksc, 1, 2).astype(scores.dtype)
+    scores = scores.reshape(b, h, 1, w * ps)[..., :rows]
+    wts = _softmax_with_self(scores, allowed, q, k, scale)
+    wj = jnp.pad(wts[:, :, 0, :-1], ((0, 0), (0, 0), (0, w * ps - rows))) \
+        .reshape(b, h, w, ps)
+    if quantized:
+        wj = wj * jnp.moveaxis(vsc, 1, 2).astype(wj.dtype)
+        gvc = gv.astype(q.dtype)
+    else:
+        gvc = gv
+    return (jnp.einsum("bhms,bmhsd->bhd", wj, gvc)[:, :, None, :]
+            + wts[..., -1:] * v)
 
 
 def _attn_with_kv(lp: dict, h: Array, allowed: Array, cfg,
@@ -409,18 +494,24 @@ def _decode_step_math(params: dict, x_tok: Array, pos: Array, cache: dict,
     never diverge on what a step computes (``decode_step_paged`` is the
     paged writer).
 
-    ``attn_impl`` is the paged-read seam: ``'gather'`` (default) reads
-    ``cache`` as a dense per-slot view — either the real dense slot
-    cache or ``paged_view``'s block-table gather — through one einsum
-    softmax; ``'kernel'`` reads ``cache`` as the raw PAGE POOL
-    ``(depth, P, heads, page_size, dh)`` and consumes ``block_tables``
-    in place via the Pallas ragged paged-attention kernel
+    ``cache`` is read in one of three ways. Without ``block_tables`` it
+    is DENSE per-slot rows ``(depth, b, heads, L, dh)`` — the real dense
+    slot cache, or a ``paged_view`` an oracle built — read by one einsum
+    softmax (``_gather_read``). With ``block_tables`` it is the raw PAGE
+    POOL ``(depth, P, heads, page_size, dh)`` and ``attn_impl`` is the
+    paged-read seam: ``'gather'`` (default) has the scan's body gather
+    ITS OWN layer's pages through the tables trimmed to
+    ``ceil(total_len / page_size)`` columns (``layer_pool_view``) and
+    contract them page-major under the same masked softmax
+    (``_paged_gather_read``) — one read of the pool a step, and no
+    buffer of the pool's size besides the pool; ``'kernel'`` consumes
+    the tables in place via the Pallas ragged paged-attention kernel
     (``ops.paged_attention``), which fetches only each slot's mapped
     live pages into VMEM and returns online-softmax partials that the
-    self-logit merge below completes. The gather path stays the parity
-    ORACLE: kernel output must be allclose to it under the same masks
-    (rows >= pos dead, trash-page rows never attended), and emitted
-    tokens byte-identical under greedy/seeded sampling
+    self-logit merge below completes. ``paged_view`` + ``_gather_read``
+    stay the parity ORACLE of both: outputs allclose under the same
+    masks (rows >= pos dead, trash-page rows never attended), and
+    emitted tokens byte-identical under greedy/seeded sampling
     (tests/test_paged_attention.py).
 
     ``sparse_reads=True`` is the per-layer VISIBILITY seam (sparsity-
@@ -452,6 +543,10 @@ def _decode_step_math(params: dict, x_tok: Array, pos: Array, cache: dict,
                              "positions (the serving decode shape)")
         if block_tables is None:
             raise ValueError("attn_impl='kernel' requires block_tables")
+    paged_gather = block_tables is not None and not kernel_mode
+    if paged_gather:
+        view_tables = _view_tables(block_tables, total_len,
+                                   cache["k"].shape[3])
 
     with jax.named_scope("attn.read"):       # the masks
         j = jnp.arange(total_len)
@@ -471,25 +566,32 @@ def _decode_step_math(params: dict, x_tok: Array, pos: Array, cache: dict,
             sparse_allowed = dense_allowed
 
     h_in = x_tok[:, None, :]                                  # (b, 1, dim)
-    quantized = "k_scale" in cache
 
-    def attn_cached(lp, h, ck, cv, is_sparse, ksc=None, vsc=None):
+    def attn_cached(lp, h, kv, is_sparse):
         p = lp["attn"]
         hn = core.layernorm(p["ln"], h)
         q, k, v = attn_ops.qkv_project(p, hn, cfg.heads)      # (b, h, 1, dh)
         with jax.named_scope("attn.read"):
             allowed = jnp.where(is_sparse, sparse_allowed, dense_allowed) \
                 if any_sparse else dense_allowed
-        if kernel_mode:
-            # ck/cv are the raw page pool for this layer; the kernel
-            # walks the block tables in place (_kernel_read completes
-            # the softmax with the self-logit merge)
-            out = _kernel_read(q, k, v, ck, cv, block_tables, pos,
-                               allowed, scale=cfg.scale, ksc=ksc,
-                               vsc=vsc)
+        if paged_gather:
+            # kv is this layer's INDEX: gather its pages from the pool
+            # and contract them as they lie
+            gk, gv, gks, gvs = layer_pool_view(cache, kv, view_tables)
+            out = _paged_gather_read(q, k, v, gk, gv, allowed,
+                                     scale=cfg.scale, ksc=gks, vsc=gvs)
+        elif kernel_mode:
+            # kv is the raw page pool for this layer; the kernel walks
+            # the block tables in place (_kernel_read completes the
+            # softmax with the self-logit merge)
+            out = _kernel_read(q, k, v, kv["k"], kv["v"], block_tables,
+                               pos, allowed, scale=cfg.scale,
+                               ksc=kv.get("k_scale"),
+                               vsc=kv.get("v_scale"))
         else:
-            out = _gather_read(q, k, v, ck, cv, allowed,
-                               scale=cfg.scale, ksc=ksc, vsc=vsc)
+            out = _gather_read(q, k, v, kv["k"], kv["v"], allowed,
+                               scale=cfg.scale, ksc=kv.get("k_scale"),
+                               vsc=kv.get("v_scale"))
         if out_sync is not None:
             # mesh-sharded serving (parallel/serve_specs.py): the
             # per-head output is re-replicated HERE, so the out
@@ -500,27 +602,25 @@ def _decode_step_math(params: dict, x_tok: Array, pos: Array, cache: dict,
         return attn_ops.output_tail(p, out), k, v
 
     def body(carry, xs):
-        if quantized:
-            lp, ck, cv, ksc, vsc, is_sparse = xs
-        else:
-            lp, ck, cv, is_sparse = xs
-            ksc = vsc = None
+        lp, kv, is_sparse = xs
         if cfg.reversible:
             x1, x2 = carry
-            a, k, v = attn_cached(lp, x2, ck, cv, is_sparse, ksc, vsc)
+            a, k, v = attn_cached(lp, x2, kv, is_sparse)
             y1 = x1 + a
             y2 = x2 + T.ff_or_moe(lp, y1, cfg, None, False)[0]
             return (y1, y2), (k, v)
         h = carry
-        a, k, v = attn_cached(lp, h, ck, cv, is_sparse, ksc, vsc)
+        a, k, v = attn_cached(lp, h, kv, is_sparse)
         h = h + a
         h = h + T.ff_or_moe(lp, h, cfg, None, False)[0]
         return h, (k, v)
 
     carry0 = (h_in, h_in) if cfg.reversible else h_in
-    xs = (params, cache["k"], cache["v"], cache["k_scale"],
-          cache["v_scale"], sparse_flags) if quantized else \
-        (params, cache["k"], cache["v"], sparse_flags)
+    # the scan hands each layer its slice of the cache, except on the
+    # paged gather path: there the slice would be a copy of the layer,
+    # so the layer gets its index and reads the pool itself
+    xs = (params, jnp.arange(cfg.depth) if paged_gather else cache,
+          sparse_flags)
     carry, (ks, vs) = lax.scan(body, carry0, xs)
     h_out = (carry[0] + carry[1]) * 0.5 if cfg.reversible else carry
 
@@ -550,7 +650,10 @@ def _decode_step_math_sparse_reads(params: dict, x_tok: Array, pos: Array,
       * ``'gather'``: sparse layers gather only the visible slice of
         the block table (``kv_pool.visible_table_view``, width = the
         static max visible count) with the row mask remapped onto the
-        trimmed columns; dense layers gather the full view per layer.
+        trimmed columns; dense layers gather the full table. Both go
+        through the one per-layer view and read that
+        ``_decode_step_math`` uses (``layer_pool_view``,
+        ``_paged_gather_read``).
 
     The dense/sparse choice is resolved STATICALLY by unrolling one
     period of ``cfg.sparse_pattern`` inside the layer scan (the
@@ -580,7 +683,6 @@ def _decode_step_math_sparse_reads(params: dict, x_tok: Array, pos: Array,
                          f"got {attn_impl!r}")
     kernel_mode = attn_impl == "kernel"
     ps = pool["k"].shape[3]
-    quantized = "k_scale" in pool
 
     with jax.named_scope("attn.read"):   # masks and visibility tables
         j = jnp.arange(total_len)
@@ -597,8 +699,7 @@ def _decode_step_math_sparse_reads(params: dict, x_tok: Array, pos: Array,
         vis_cnt = jnp.take(jnp.asarray(cnt_np), pos)             # (b,)
         vis_ccnt = jnp.take(jnp.asarray(ccnt_np), pos)           # (b,)
 
-        need = -(-total_len // ps)               # pages_for(total_len)
-        bt = block_tables[:, :need]              # paged_view's table trim
+        bt = _view_tables(block_tables, total_len, ps)
         vis_bt = KV.visible_table_view(bt, vis_rows)             # (b, W)
         # remap the row mask onto the trimmed columns: column w*ps + o of
         # the visible view is logical row vis_rows[:, w]*ps + o; columns
@@ -612,46 +713,27 @@ def _decode_step_math_sparse_reads(params: dict, x_tok: Array, pos: Array,
             sparse_allowed, jnp.minimum(cols, total_len - 1), axis=1)
             & pad_ok & (cols < total_len))
 
-    @jax.named_scope("kv.view")
-    def layer_pool_view(ck, cv, ksc, vsc, tables, rows_out):
-        """``paged_view`` for ONE layer: ck/cv (P, heads, ps, dh)
-        gathered through tables (b, w) into (b, heads, rows_out[, dh])
-        — the per-layer form the statically-unrolled body needs, since
-        dense and sparse layers gather different widths."""
-        def rows(buf):
-            g = jnp.take(buf, tables, axis=0)    # (b, w, heads, ps, dh)
-            g = jnp.moveaxis(g, 1, 2)            # (b, heads, w, ps, dh)
-            g = g.reshape(g.shape[0], g.shape[1], -1, g.shape[-1])
-            return g[:, :, :rows_out, :]
-        def scales(buf):
-            g = jnp.take(buf, tables, axis=0)    # (b, w, heads, ps)
-            g = jnp.moveaxis(g, 1, 2)            # (b, heads, w, ps)
-            return g.reshape(g.shape[0], g.shape[1], -1)[:, :, :rows_out]
-        if ksc is None:
-            return rows(ck), rows(cv), None, None
-        return rows(ck), rows(cv), scales(ksc), scales(vsc)
-
-    def attn_layer(lp, h, ck, cv, ksc, vsc, is_sparse: bool):
+    def attn_layer(lp, h, kv, is_sparse: bool):
+        # kv: this layer's slice of the pool (kernel), or its index
+        # (gather: ``layer_pool_view`` reads the pool itself)
         p = lp["attn"]
         hn = core.layernorm(p["ln"], h)
         q, k, v = attn_ops.qkv_project(p, hn, cfg.heads)  # (b, h, 1, dh)
         if kernel_mode:
             out = _kernel_read(
-                q, k, v, ck, cv, block_tables, pos,
+                q, k, v, kv["k"], kv["v"], block_tables, pos,
                 sparse_allowed if is_sparse else dense_allowed,
-                scale=cfg.scale, ksc=ksc, vsc=vsc,
+                scale=cfg.scale, ksc=kv.get("k_scale"),
+                vsc=kv.get("v_scale"),
                 visible=vis_rows if is_sparse else None,
                 visible_cnt=vis_ccnt if is_sparse else None)
-        elif is_sparse:
-            gk, gv, gks, gvs = layer_pool_view(ck, cv, ksc, vsc,
-                                               vis_bt, width * ps)
-            out = _gather_read(q, k, v, gk, gv, vis_allowed,
-                               scale=cfg.scale, ksc=gks, vsc=gvs)
         else:
-            gk, gv, gks, gvs = layer_pool_view(ck, cv, ksc, vsc,
-                                               bt, total_len)
-            out = _gather_read(q, k, v, gk, gv, dense_allowed,
-                               scale=cfg.scale, ksc=gks, vsc=gvs)
+            gk, gv, gks, gvs = layer_pool_view(
+                pool, kv, vis_bt if is_sparse else bt)
+            out = _paged_gather_read(
+                q, k, v, gk, gv,
+                vis_allowed if is_sparse else dense_allowed,
+                scale=cfg.scale, ksc=gks, vsc=gvs)
         if out_sync is not None:
             # the mesh seam, unchanged: gather heads before the out
             # projection instead of letting GSPMD partial-sum it
@@ -665,31 +747,22 @@ def _decode_step_math_sparse_reads(params: dict, x_tok: Array, pos: Array,
     def fold(a):
         return a.reshape(nsteps, period, *a.shape[1:])
 
-    bufs = (pool["k"], pool["v"]) + \
-        ((pool["k_scale"], pool["v_scale"]) if quantized else ())
-    xs = (jax.tree.map(fold, params),) + tuple(fold(a) for a in bufs)
+    xs = jax.tree.map(fold, (params, pool if kernel_mode
+                             else jnp.arange(cfg.depth)))
 
     def body(carry, xs):
-        if quantized:
-            lp, ck, cv, ksc, vsc = xs
-        else:
-            lp, ck, cv = xs
         ks_p, vs_p = [], []
         for i, is_sparse in enumerate(period_pat):
-            lpi = jax.tree.map(lambda a, _i=i: a[_i], lp)
-            ksci = ksc[i] if quantized else None
-            vsci = vsc[i] if quantized else None
+            lpi, kvi = jax.tree.map(lambda a, _i=i: a[_i], xs)
             if cfg.reversible:
                 x1, x2 = carry
-                a, k, v = attn_layer(lpi, x2, ck[i], cv[i], ksci, vsci,
-                                     is_sparse)
+                a, k, v = attn_layer(lpi, x2, kvi, is_sparse)
                 y1 = x1 + a
                 y2 = x2 + T.ff_or_moe(lpi, y1, cfg, None, False)[0]
                 carry = (y1, y2)
             else:
                 h = carry
-                a, k, v = attn_layer(lpi, h, ck[i], cv[i], ksci, vsci,
-                                     is_sparse)
+                a, k, v = attn_layer(lpi, h, kvi, is_sparse)
                 h = h + a
                 carry = h + T.ff_or_moe(lpi, h, cfg, None, False)[0]
             ks_p.append(k)
@@ -717,21 +790,39 @@ def _decode_step_math_sparse_reads(params: dict, x_tok: Array, pos: Array,
 # positions then share one physical budget: a slot 10 tokens into its
 # sequence holds ceil(11/page_size) pages, not total_len rows.
 #
-# ``paged_view`` gathers a slot-major dense view through the block tables,
-# so the attention math downstream of it is LITERALLY ``_decode_step_math``
-# — row j of the view is position j, making paged-vs-dense token equality
-# hold by construction. The gather materializes the per-step read (same
-# bytes a dense step reads); the HBM win is *residency* — the pool can be
-# far smaller than num_slots x total_len. The chip-side fix for the READ
-# traffic is ``attn_impl='kernel'``: the Pallas ragged paged-attention
-# kernel (ops/paged_attention.py) consumes the block tables in place —
-# only each slot's live pages move HBM->VMEM — with this gather kept as
-# the parity oracle the kernel is tested against.
+# The decode step reads the pool WHERE IT LIES (``attn_impl='gather'``, the
+# default): inside the layer scan each layer gathers its own pages through
+# the block tables (``layer_pool_view``: whole pages, page-major, indexed
+# by layer into the pool itself) and contracts them in that form
+# (``_paged_gather_read``); only the scores are brought to logical row
+# order, so the softmax is the dense step's and paged-vs-dense tokens are
+# equal. The new row is written by in-place row updates
+# (``_store_rows_paged``), so the pool keeps one layout, a page one
+# contiguous run, through the whole chunk: the compiled program holds no
+# buffer of the pool's size besides the pool (tests/test_paged_attention.py
+# pins it), and a step reads the mapped table's pages once. A view of ALL
+# layers, relaid slot-major before the layer scan, is about seven
+# pool-sized passes a step where attention needs one (PERF.md, PR 25).
+#
+# ``paged_view`` is that view, and the ORACLE: row j of the view is
+# position j, so ``_decode_step_math`` over it is literally the dense
+# step. The tests hold the per-layer read, the kernel and the engines to
+# it; the speculative verify (``decode_loop_spec_paged``) reads through it,
+# having no per-layer page-major wide read. The gather reads
+# every page of the trimmed table whether live or not; the HBM win of
+# paging is *residency* — the pool can be far smaller than num_slots x
+# total_len. ``attn_impl='kernel'`` reads only each slot's LIVE pages: the
+# Pallas ragged paged-attention kernel (ops/paged_attention.py) consumes
+# the block tables in place, HBM->VMEM.
 
 
 @jax.named_scope("kv.view")
 def paged_view(pool: dict, block_tables: Array, total_len: int) -> dict:
-    """Dense per-slot view of the page pool: pool (depth, P, heads,
+    """Dense per-slot view of the page pool, ALL layers at once — the
+    parity oracle of the per-layer read (``layer_pool_view`` +
+    ``_paged_gather_read``) and of the kernel, and the read of the
+    speculative verify. The decode step does not call it: it is a relaid
+    copy of the whole pool. Pool (depth, P, heads,
     page_size, dh) gathered through block_tables (b, max_pages) into
     (depth, b, heads, total_len, dh) — logical row j reads physical page
     ``block_tables[i, j // page_size]`` at offset ``j % page_size``.
@@ -748,9 +839,8 @@ def paged_view(pool: dict, block_tables: Array, total_len: int) -> dict:
     gather just to slice them off; rows and scales share the one trim
     so their shape contract ((..., total_len[, dh])) cannot drift
     (tests/test_paged_attention.py pins it)."""
-    page_size = pool["k"].shape[3]
-    need = -(-total_len // page_size)             # pages_for(total_len)
-    block_tables = block_tables[:, :need]
+    block_tables = _view_tables(block_tables, total_len,
+                                pool["k"].shape[3])
 
     def rows(buf):
         g = jnp.take(buf, block_tables, axis=1)   # (d, b, mp, heads, ps, dh)
@@ -773,7 +863,7 @@ def paged_view(pool: dict, block_tables: Array, total_len: int) -> dict:
 @jax.named_scope("kv.store")
 def _store_rows_paged(pool: dict, ks: Array, vs: Array, pos: Array,
                       block_tables: Array, active: Array) -> dict:
-    """Paged scatter twin of ``_store_rows_per_slot``: slot i's single new
+    """Paged twin of ``_store_rows_per_slot``: slot i's single new
     K/V row (depth, b, heads, 1, dh) lands in physical page
     ``block_tables[i, pos[i] // page_size]`` at offset ``pos[i] %
     page_size``. INACTIVE slots are redirected to the reserved trash page
@@ -789,58 +879,54 @@ def _store_rows_paged(pool: dict, ks: Array, vs: Array, pos: Array,
     page = jnp.where(active, block_tables[bidx, pos // ps], 0)
     off = jnp.where(active, pos % ps, 0)
 
-    def put_rows(buf, rows):
-        # buf (depth, P, heads, ps, dh); advanced indices at dims 1 and 3
-        # are non-adjacent, so the update value is (b, depth, heads, dh)
-        return buf.at[:, page, :, off, :].set(
-            jnp.moveaxis(rows[:, :, :, 0, :], 0, 1))
-
-    def put_scales(buf, sc):
-        # buf (depth, P, heads, ps); value (b, depth, heads)
-        return buf.at[:, page, :, off].set(
-            jnp.moveaxis(sc[:, :, :, 0], 0, 1))
+    def put(buf, rows):
+        # buf (depth, P, heads, ps[, dh]), rows (depth, b, heads, 1[, dh]):
+        # one in-place row update a slot, not one scatter. The TPU
+        # compiler gives a scatter whose indices fall in the tiled (ps, dh)
+        # dims a pool with heads and ps swapped, and then relays the whole
+        # pool every step for the read, which gathers pages
+        # (``layer_pool_view``); an update slice leaves the pool as it lies
+        tail = (0,) * (buf.ndim - 4)
+        for i in range(b):
+            buf = lax.dynamic_update_slice(
+                buf, rows[:, i:i + 1], (0, page[i], 0, off[i]) + tail)
+        return buf
 
     if "k_scale" in pool:
         kq, ksc = _quantize_rows(ks)
         vq, vsc = _quantize_rows(vs)
-        return {"k": put_rows(pool["k"], kq),
-                "v": put_rows(pool["v"], vq),
-                "k_scale": put_scales(pool["k_scale"], ksc),
-                "v_scale": put_scales(pool["v_scale"], vsc)}
-    return {"k": put_rows(pool["k"], ks), "v": put_rows(pool["v"], vs)}
+        return {"k": put(pool["k"], kq), "v": put(pool["v"], vq),
+                "k_scale": put(pool["k_scale"], ksc),
+                "v_scale": put(pool["v_scale"], vsc)}
+    return {"k": put(pool["k"], ks), "v": put(pool["v"], vs)}
 
 
 def decode_step_paged(params: dict, x_tok: Array, pos: Array, pool: dict,
                       block_tables: Array, *, cfg, key_mask: Array,
-                      total_len: int, active: Array,
-                      attn_impl: str = "gather",
+                      active: Array, attn_impl: str = "gather",
                       sparse_reads: bool = False,
                       out_sync=None) -> Tuple[Array, dict]:
-    """``decode_step`` against the paged pool. ``attn_impl='gather'``
-    (default, the parity oracle) gathers the dense view through the
-    block tables and runs the one shared step math — token-exact with
-    the dense step by construction. ``attn_impl='kernel'`` skips the
-    view entirely: the Pallas ragged paged-attention kernel consumes
-    the block tables in place (only each slot's live pages move), and
-    the same ``_decode_step_math`` body merges its partials, so the
-    two impls share every line outside the K/V read itself. Either
-    way the new row scatters back into its page; ``active`` routes
-    dead slots' writes to the trash page (``_store_rows_paged``).
+    """``decode_step`` against the paged pool: the one shared step math
+    is handed the RAW pool and the block tables, whatever the impl.
+    ``attn_impl='gather'`` (default) gathers each layer's pages inside
+    the layer scan and attends them where they lie (``layer_pool_view``
+    + ``_paged_gather_read``): the rows, masks and softmax of the dense
+    step, so tokens equal the dense step's. ``attn_impl='kernel'`` has
+    the Pallas ragged paged-attention kernel consume the tables in
+    place (only each slot's live pages move) and the same body merge
+    its partials; the two impls share every line outside the K/V read
+    itself. Either way the new row scatters back into its page;
+    ``active`` routes dead slots' writes to the trash page
+    (``_store_rows_paged``).
 
-    ``sparse_reads=True`` hands BOTH impls the raw pool: sparse layers
-    read only their statically visible pages while dense layers read
-    as before (``_decode_step_math_sparse_reads``) — same step math,
-    same writers, fewer bytes moved per token."""
-    if attn_impl == "kernel" or sparse_reads:
-        h_out, ks, vs = _decode_step_math(
-            params, x_tok, pos, pool, cfg=cfg, key_mask=key_mask,
-            attn_impl=attn_impl, block_tables=block_tables,
-            sparse_reads=sparse_reads, out_sync=out_sync)
-    else:
-        view = paged_view(pool, block_tables, total_len)
-        h_out, ks, vs = _decode_step_math(params, x_tok, pos, view,
-                                          cfg=cfg, key_mask=key_mask,
-                                          out_sync=out_sync)
+    ``sparse_reads=True``: sparse layers read only their statically
+    visible pages while dense layers read as before
+    (``_decode_step_math_sparse_reads``) — same step math, same
+    writers, fewer bytes moved per token."""
+    h_out, ks, vs = _decode_step_math(
+        params, x_tok, pos, pool, cfg=cfg, key_mask=key_mask,
+        attn_impl=attn_impl, block_tables=block_tables,
+        sparse_reads=sparse_reads, out_sync=out_sync)
     return h_out, _store_rows_paged(pool, ks, vs, pos, block_tables, active)
 
 
@@ -858,8 +944,8 @@ def decode_loop_paged(params: dict, cur_tok: Array, pos: Array,
     steps could write, so a mid-chunk page-boundary crossing finds its
     page already mapped). Dead slots park at (tok 0, pos 0) writing the
     trash page; emit semantics (-1 sentinel) are identical to the dense
-    loop. ``attn_impl`` selects the per-step K/V read: the dense-view
-    gather (oracle) or the in-place Pallas kernel — both run inside the
+    loop. ``attn_impl`` selects the per-step K/V read: the per-layer
+    page gather or the in-place Pallas kernel — both run inside the
     SAME fused scan, so the one-compile/emit-ring regime is unchanged.
     ``sparse_reads`` turns on sparsity-aware reads for the sparse
     layers (visibility tables are trace-time constants, so the fused
@@ -870,8 +956,7 @@ def decode_loop_paged(params: dict, cur_tok: Array, pos: Array,
         emit = jnp.where(act, cur_tok, -1)
         x = embed_fn(cur_tok, pos)
         h, pool = decode_step_paged(params, x, pos, pool, block_tables,
-                                    cfg=cfg, key_mask=key_mask,
-                                    total_len=total_len, active=act,
+                                    cfg=cfg, key_mask=key_mask, active=act,
                                     attn_impl=attn_impl,
                                     sparse_reads=sparse_reads,
                                     out_sync=out_sync)
@@ -1324,8 +1409,9 @@ def decode_loop_spec_paged(params: dict, draft_params: dict,
                            ) -> Tuple[Array, Array, Array, dict, Array]:
     """``decode_loop_paged`` with draft-and-verify speculation: the
     paged twin of ``decode_loop_spec`` — the k-wide verify rides the
-    block tables exactly like the narrow step (the dense-view gather
-    oracle, or one in-place Pallas kernel walk per offset under
+    block tables (``paged_view``'s all-layer dense view, which the
+    narrow step has left — the wide read has no per-layer page-major
+    form yet — or one in-place Pallas kernel walk per offset under
     ``attn_impl='kernel'``), and all k fresh rows scatter back through
     ``_store_rows_paged_wide`` (inactive/overflow rows to the trash
     page). The host maps the FULL speculative horizon (steps*k rows)

@@ -2,11 +2,12 @@
 
 The paged KV layout (serve/kv_pool.py) won HBM *residency*: the pool is
 far smaller than ``num_slots × seq_len``. It did not win read traffic —
-every decode step still materializes a dense ``[slots, seq]`` K/V view
-through ``ops.decode.paged_view``'s block-table gather, so the bytes
-moved per token are the dense layout's plus the gather's index traffic.
+the gather path (``ops.decode.layer_pool_view``, per layer since PR 25)
+reads every page of a slot's table on every step, live or not, so the
+bytes moved per token are the dense layout's.
 This module is the chip-side fix (PAPERS.md *Ragged Paged Attention*):
-a kernel that consumes the block tables IN PLACE.
+a kernel that consumes the block tables IN PLACE and walks only the
+live pages.
 
 Shape of the computation (one ``pl.pallas_call`` per layer, inside the
 engine's fused K-step decode scan):

@@ -845,9 +845,9 @@ class Engine:
                            cfgs, uncond):
         """The paged twin of ``_decode_impl``: identical fused K-step
         emit-ring program, but K/V reads go through the block tables —
-        the dense-view gather, or the in-place Pallas ragged
-        paged-attention kernel under ``paged_attn='kernel'`` — and
-        writes scatter into the page pool
+        each layer's pages gathered inside the layer scan, or the
+        in-place Pallas ragged paged-attention kernel under
+        ``paged_attn='kernel'`` — and writes land in the page pool
         (``ops.decode.decode_loop_paged``). The block tables are a
         per-chunk constant — the host maps every page the chunk could
         write before dispatch — so this too traces exactly once."""

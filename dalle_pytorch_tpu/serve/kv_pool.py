@@ -12,8 +12,9 @@ PAGES shared by every slot:
     page_size, dim_head)`` per K and V (``init_page_pool``; int8 variant
     carries per-row scale pages) plus per-slot block tables
     ``(num_slots, max_pages)`` int32 mapping logical page j → physical
-    page id — ``ops.decode.paged_view`` / ``_store_rows_paged`` are the
-    gather/scatter through them, and ``ops.paged_attention`` is the
+    page id — ``ops.decode.layer_pool_view`` / ``_store_rows_paged`` are
+    the decode step's read and write through them (``paged_view`` the
+    all-layer dense oracle), and ``ops.paged_attention`` is the
     Pallas kernel that consumes the tables in place
     (``paged_attn='kernel'``, which also imposes the page-size tile
     constraint ``validate_page_size`` gates);

@@ -12,7 +12,11 @@ fp32 and int8-KV, page_size ∈ {8, 16}, ragged per-slot positions
 Plus the typed page-size gate (``kv_pool.PageSizeError`` at pool init,
 naming the kernel tile constraint) and the ``paged_view`` trim: the
 gather never drags K/V or scale pages for wholly-unmapped logical pages
-beyond ``total_len``.
+beyond ``total_len``. Since ISSUE 25 the gather path itself reads the
+pool per layer and page-major (``layer_pool_view`` +
+``_paged_gather_read``): ``TestPerLayerRead`` holds it to the
+``paged_view`` + ``_gather_read`` oracle, to the dense loop's tokens,
+and to a temporaries budget that an all-layer view cannot meet.
 
 All CPU (the kernel runs under the Pallas interpreter — the same code
 path CI's serve-perf kernel leg smokes), tiny model, inside tier-1.
@@ -78,12 +82,14 @@ REQS = [
 ]
 
 
-def _random_pool(key, page_size, num_pages, quantized):
+def _random_pool(key, page_size, num_pages, quantized, *, dim_head=None,
+                 dtype=jnp.float32):
     """A pool with fully-random page content — including the trash page
     and unallocated pages, so an out-of-bounds read cannot hide behind
     zeros."""
     tcfg = CFG.transformer
-    shape = (tcfg.depth, num_pages, tcfg.heads, page_size, tcfg.dim_head)
+    shape = (tcfg.depth, num_pages, tcfg.heads, page_size,
+             dim_head or tcfg.dim_head)
     if quantized:
         return {
             "k": jax.random.randint(jax.random.fold_in(key, 0), shape,
@@ -97,8 +103,10 @@ def _random_pool(key, page_size, num_pages, quantized):
                                           shape[:-1], minval=0.01,
                                           maxval=0.1),
         }
-    return {"k": jax.random.normal(jax.random.fold_in(key, 0), shape),
-            "v": jax.random.normal(jax.random.fold_in(key, 1), shape)}
+    return {"k": jax.random.normal(jax.random.fold_in(key, 0), shape,
+                                   dtype),
+            "v": jax.random.normal(jax.random.fold_in(key, 1), shape,
+                                   dtype)}
 
 
 class TestKernelVsGatherOracle:
@@ -358,6 +366,157 @@ class TestPagedViewTrim:
             [e.primitive.name for e in consumers]
         assert all(tuple(e.outvars[0].aval.shape) == (2, need)
                    for e in consumers)
+
+
+class TestPerLayerRead:
+    """ISSUE 25: the gather path attends ONE layer's pages inside the
+    layer scan, page-major, straight from the pool. The oracle is the
+    all-layer dense view it replaced: ``paged_view`` + ``_gather_read``
+    — same rows, same masks, same scales."""
+
+    PS = 8
+    HEADS, DEPTH = CFG.transformer.heads, CFG.transformer.depth
+
+    def _case(self, kind, dim_head, total_len, tables):
+        key = jax.random.PRNGKey(dim_head + total_len)
+        need = KV.pages_for(total_len, self.PS)
+        dtype = jnp.bfloat16 if kind == "bf16" else jnp.float32
+        pool = _random_pool(key, self.PS, 3 * need + 1, kind == "int8",
+                            dim_head=dim_head, dtype=dtype)
+        bt = np.zeros((3, need), np.int32)
+        bt[0] = np.arange(1, need + 1)
+        bt[1] = np.arange(need + 1, 2 * need + 1)
+        bt[2] = np.arange(2 * need + 1, 3 * need + 1)
+        pos = [total_len - 1, total_len // 2, 5]
+        if tables == "wide":
+            # the pool-max table a caller holds: tail columns map OTHER
+            # live pages, which must never reach the read
+            bt = np.concatenate(
+                [bt, np.full((3, 3), need, np.int32)], axis=1)
+        elif tables == "shared":
+            # copy-on-write fan-out: two slots read the same prompt page
+            bt[1, 0] = bt[0, 0]
+        else:
+            # unmapped entries on the trash page (random content there):
+            # a mid-sequence slot and a parked dead one
+            bt[1, KV.pages_for(pos[1] + 1, self.PS):] = 0
+            bt[2] = 0
+            pos[2] = 0
+        qkv = [jax.random.normal(jax.random.fold_in(key, 10 + i),
+                                 (3, self.HEADS, 1, dim_head), dtype)
+               for i in range(3)]
+        allowed = (jnp.arange(total_len)[None, :]
+                   < jnp.asarray(pos)[:, None])
+        allowed = allowed.at[0, 1].set(False)        # a padded-off row
+        return pool, jnp.asarray(bt), qkv, allowed
+
+    @pytest.mark.parametrize("tables", ["wide", "shared", "trash"])
+    @pytest.mark.parametrize("total_len", [24, 20],
+                             ids=["whole_pages", "partial_last_page"])
+    @pytest.mark.parametrize("dim_head", [64, 128])
+    @pytest.mark.parametrize("kind", ["f32", "bf16", "int8"])
+    def test_per_layer_read_matches_view_oracle(self, kind, dim_head,
+                                                total_len, tables):
+        pool, bt, (q, k, v), allowed = self._case(kind, dim_head,
+                                                  total_len, tables)
+        scale = dim_head ** -0.5
+        view = decode_ops.paged_view(pool, bt, total_len)
+        need = KV.pages_for(total_len, self.PS)
+        tol = dict(rtol=2e-2, atol=2e-2) if kind == "bf16" else \
+            dict(rtol=2e-5, atol=2e-5)
+        for layer in range(self.DEPTH):
+            want = decode_ops._gather_read(
+                q, k, v, view["k"][layer], view["v"][layer], allowed,
+                scale=scale,
+                ksc=view["k_scale"][layer] if kind == "int8" else None,
+                vsc=view["v_scale"][layer] if kind == "int8" else None)
+            gk, gv, gks, gvs = decode_ops.layer_pool_view(
+                pool, jnp.asarray(layer), bt[:, :need])
+            assert gk.shape == (3, need, self.HEADS, self.PS, dim_head)
+            assert (gks is None) == (kind != "int8")
+            got = decode_ops._paged_gather_read(
+                q, k, v, gk, gv, allowed, scale=scale, ksc=gks, vsc=gvs)
+            assert got.shape == want.shape and got.dtype == want.dtype
+            np.testing.assert_allclose(
+                np.asarray(got, np.float32), np.asarray(want, np.float32),
+                **tol)
+
+    def _loop_args(self, bundle, page_size, quantized):
+        """A mid-sequence chunk: 3 slots at ragged positions (one parked
+        dead), random page content everywhere, greedy sampling through
+        the model's own embedding and logits head."""
+        params, _ = bundle
+        tcfg = CFG.transformer
+        L = CFG.seq_len
+        mp = KV.pages_for(L, page_size)
+        pool = _random_pool(jax.random.PRNGKey(21), page_size,
+                            3 * mp + 1, quantized)
+        bt = jnp.asarray(np.arange(1, 3 * mp + 1, dtype=np.int32)
+                         .reshape(3, mp))
+        pos = jnp.asarray([9, 14, 0], jnp.int32)
+        active = jnp.asarray([True, True, False])
+        cur = jnp.asarray([3, 7, 0], jnp.int32)
+
+        def embed_fn(tok, p):
+            return D.decode_token_embed(params, CFG, tok, p)
+
+        def sample_fn(h, pred_pos):
+            return jnp.argmax(D.to_logits(params, h), -1).astype(jnp.int32)
+
+        kw = dict(cfg=tcfg, key_mask=jnp.ones((3, L), bool), steps=6,
+                  embed_fn=embed_fn, sample_fn=sample_fn)
+        return params["transformer"], cur, pos, active, pool, bt, L, kw
+
+    @pytest.mark.parametrize("page_size", [8, 16],
+                             ids=["whole_pages", "partial_last_page"])
+    @pytest.mark.parametrize("quantized", [False, True],
+                             ids=["f32", "int8"])
+    def test_loop_tokens_identical_to_dense_loop(self, bundle, page_size,
+                                                 quantized):
+        """``decode_loop_paged`` (gather) emits the dense loop's tokens
+        under greedy, from the same rows: the dense cache is the
+        oracle's view of the same pool."""
+        tp, cur, pos, active, pool, bt, L, kw = self._loop_args(
+            bundle, page_size, quantized)
+        dense = decode_ops.decode_loop(
+            tp, cur, pos, active, decode_ops.paged_view(pool, bt, L), **kw)
+        paged = decode_ops.decode_loop_paged(
+            tp, cur, pos, active, pool, bt, total_len=L, **kw)
+        np.testing.assert_array_equal(np.asarray(paged[4]),
+                                      np.asarray(dense[4]))
+        assert (np.asarray(paged[4])[:2] >= 0).all()   # real tokens
+        for i in range(3):                             # tok, pos, active
+            np.testing.assert_array_equal(np.asarray(paged[i]),
+                                          np.asarray(dense[i]))
+        # and the rows the chunk stored are the rows the dense loop stored
+        after = decode_ops.paged_view(paged[3], bt, L)
+        for name in after:
+            np.testing.assert_allclose(
+                np.asarray(after[name][:, :2], np.float32),
+                np.asarray(dense[3][name][:, :2], np.float32),
+                rtol=1e-5, atol=1e-5)
+
+    def test_decode_program_holds_no_second_pool(self, bundle):
+        """The mechanism, not the speed: the compiled gather loop's
+        temporaries stay under half the pool's bytes. With the all-layer
+        dense view (``paged_view`` before the layer scan) they were over
+        one whole pool, so the view cannot come back unnoticed."""
+        tp, cur, pos, active, _, bt, L, kw = self._loop_args(
+            bundle, 8, False)
+        tcfg = CFG.transformer
+        num_pages = 40 * KV.pages_for(L, 8) + 1    # pool >> everything else
+        pool = {n: jnp.zeros((tcfg.depth, num_pages, tcfg.heads, 8,
+                              tcfg.dim_head)) for n in ("k", "v")}
+        pool_bytes = sum(a.size * a.dtype.itemsize for a in pool.values())
+
+        def loop(pool, bt, cur, pos, active):
+            return decode_ops.decode_loop_paged(
+                tp, cur, pos, active, pool, bt, total_len=L, **kw)
+
+        compiled = jax.jit(loop, donate_argnums=0).lower(
+            pool, bt, cur, pos, active).compile()
+        temp = compiled.memory_analysis().temp_size_in_bytes
+        assert temp < pool_bytes / 2, (temp, pool_bytes)
 
 
 class TestVisibilityOracle:
