@@ -1,0 +1,14 @@
+"""Device milliseconds of one fused decode step under the scope
+``moe.experts``: the grouped matrix products over the routed experts that hold rows, and their gate. None where the program has no such scope."""
+
+from benchmark import scopes
+
+
+def read(ctx):
+    if ctx["kind"] != "serve":
+        return None
+    got = scopes.program_seconds(ctx, r"decode_impl")
+    if got is None or "moe.experts" not in got["seconds"]:
+        return None
+    steps = got["runs"] * int(ctx["cell"].spec["engine"]["chunk_steps"])
+    return 1e3 * got["seconds"]["moe.experts"] / steps

@@ -82,6 +82,11 @@ class DALLEConfig:
     # is otherwise the largest train-time buffer. Same loss, bitwise-close
     # grads; one extra head matmul on the backward pass.
     loss_chunk: int = 0
+    # a described block in place of PreNorm LayerNorm + GEGLU + learned
+    # and axial positions (ops.transformer.LatentMoEBlock): rotary
+    # positions inside the block, so no position tables; an untied,
+    # bias-free head behind an RMSNorm. Served, not trained.
+    block: Optional[T.LatentMoEBlock] = None
 
     @property
     def image_seq_len(self) -> int:
@@ -116,7 +121,7 @@ class DALLEConfig:
             flash_block_k=self.flash_block_k,
             sparse_impl=self.sparse_impl, scale_mode=self.scale_mode,
             remat=self.remat, moe_experts=self.moe_experts,
-            moe_k=self.moe_k)
+            moe_k=self.moe_k, block=self.block)
 
 
 # ---------------------------------------------------------------------------
@@ -149,23 +154,33 @@ def dalle_init(key: Array, cfg: DALLEConfig,
         image_emb = core.embedding_init(ks[1], cfg.num_image_tokens, cfg.dim,
                                         dtype)
 
-    return {
+    params = {
         "text_emb": core.embedding_init(ks[0], cfg.num_text_tokens, cfg.dim,
                                         dtype),
         "image_emb": image_emb,
-        "text_pos_emb": core.embedding_init(ks[2], cfg.text_seq_len, cfg.dim,
-                                            dtype),
-        "image_pos_emb": {
-            "rows": core.normal_init(ks[3], (ax_rows, cfg.dim), 1.0, dtype),
-            "cols": core.normal_init(ks[4], (ax_cols, cfg.dim), 1.0, dtype),
-        },
         "transformer": T.transformer_init(ks[5], cfg.transformer, dtype),
-        "to_logits": {
-            "ln": core.layernorm_init(cfg.dim, dtype),
-            "proj": core.linear_init(jax.random.fold_in(ks[5], 1), cfg.dim,
-                                     cfg.total_tokens, dtype=dtype),
-        },
     }
+    k_head = jax.random.fold_in(ks[5], 1)
+    if cfg.block is not None:
+        # positions are the block's own (rotary): no tables; an untied,
+        # bias-free head behind an RMSNorm
+        params["to_logits"] = {
+            "ln": core.rmsnorm_init(cfg.dim, dtype),
+            "proj": core.linear_init(k_head, cfg.dim, cfg.total_tokens,
+                                     bias=False, dtype=dtype)}
+        return params
+    params["text_pos_emb"] = core.embedding_init(ks[2], cfg.text_seq_len,
+                                                 cfg.dim, dtype)
+    params["image_pos_emb"] = {
+        "rows": core.normal_init(ks[3], (ax_rows, cfg.dim), 1.0, dtype),
+        "cols": core.normal_init(ks[4], (ax_cols, cfg.dim), 1.0, dtype),
+    }
+    params["to_logits"] = {
+        "ln": core.layernorm_init(cfg.dim, dtype),
+        "proj": core.linear_init(k_head, cfg.dim, cfg.total_tokens,
+                                 dtype=dtype),
+    }
+    return params
 
 
 # ---------------------------------------------------------------------------
@@ -185,19 +200,27 @@ def image_pos_emb(params: dict, cfg: DALLEConfig, positions: Array) -> Array:
     return rows + cols
 
 
-def logits_mask(cfg: DALLEConfig) -> Array:
-    """(seq_len, total_tokens) bool, True = FORBIDDEN (fill with -max), the
-    reference's buffer (dalle_pytorch.py:303-315)."""
+def logits_mask_rows(cfg: DALLEConfig, rows: Array) -> Array:
+    """Rows ``rows`` (any shape, int) of ``logits_mask`` -> rows.shape +
+    (total_tokens,), computed from the positions: a per-slot sampler reads
+    its slots' rows and never builds the (seq_len, total_tokens) table,
+    which a compiler rebuilds inside the decode loop (0.56 GB a step at a
+    4352 x 128256 table)."""
     n, t = cfg.seq_len, cfg.total_tokens
-    seq = jnp.arange(n)[:, None]
-    logit = jnp.arange(t)[None, :]
+    seq = jnp.asarray(rows)[..., None]
+    logit = jnp.arange(t)
     text_boundary = cfg.text_seq_len - 1
-    forbidden = (
+    return (
         ((seq >= text_boundary) & (logit < cfg.num_text_tokens))
         | ((seq < text_boundary) & (logit >= cfg.num_text_tokens))
         | ((seq != (n - 1)) & (logit >= (t - 1)))
     )
-    return forbidden
+
+
+def logits_mask(cfg: DALLEConfig) -> Array:
+    """(seq_len, total_tokens) bool, True = FORBIDDEN (fill with -max), the
+    reference's buffer (dalle_pytorch.py:303-315)."""
+    return logits_mask_rows(cfg, jnp.arange(cfg.seq_len))
 
 
 @jax.named_scope("embed")
@@ -205,12 +228,15 @@ def embed_prompt(params: dict, cfg: DALLEConfig, text: Array,
                  image_ids: Optional[Array] = None) -> Array:
     """Token embeddings for [text (b, t)] ++ [image ids (b, n_img)]."""
     b, t = text.shape
-    tok = (jnp.take(params["text_emb"]["w"], text, axis=0)
-           + params["text_pos_emb"]["w"][None, :t])
+    learned = cfg.block is None      # else rotary, inside the block
+    tok = jnp.take(params["text_emb"]["w"], text, axis=0)
+    if learned:
+        tok = tok + params["text_pos_emb"]["w"][None, :t]
     if image_ids is not None and image_ids.shape[1] > 0:
         n_img = image_ids.shape[1]
-        img = (jnp.take(params["image_emb"]["w"], image_ids, axis=0)
-               + image_pos_emb(params, cfg, jnp.arange(n_img))[None])
+        img = jnp.take(params["image_emb"]["w"], image_ids, axis=0)
+        if learned:
+            img = img + image_pos_emb(params, cfg, jnp.arange(n_img))[None]
         tok = jnp.concatenate([tok, img], axis=1)
     return tok
 
@@ -226,16 +252,16 @@ def decode_token_embed(params: dict, cfg: DALLEConfig, cur_tok: Array,
     Ids are clipped into each table so the off-branch gather of the
     ``where`` select stays in range."""
     pos = jnp.asarray(pos)
-    text_e = (jnp.take(params["text_emb"]["w"],
-                       jnp.clip(cur_tok, 0, cfg.num_text_tokens - 1),
-                       axis=0)
-              + jnp.take(params["text_pos_emb"]["w"],
-                         jnp.clip(pos, 0, cfg.text_seq_len - 1), axis=0))
-    img_pos = jnp.clip(pos - cfg.text_seq_len, 0, cfg.image_seq_len - 1)
-    img_e = (jnp.take(params["image_emb"]["w"],
-                      jnp.clip(cur_tok, 0, cfg.num_image_tokens - 1),
-                      axis=0)
-             + image_pos_emb(params, cfg, img_pos))
+    text_e = jnp.take(params["text_emb"]["w"],
+                      jnp.clip(cur_tok, 0, cfg.num_text_tokens - 1), axis=0)
+    img_e = jnp.take(params["image_emb"]["w"],
+                     jnp.clip(cur_tok, 0, cfg.num_image_tokens - 1), axis=0)
+    if cfg.block is None:            # else rotary, inside the block
+        text_e = text_e + jnp.take(
+            params["text_pos_emb"]["w"],
+            jnp.clip(pos, 0, cfg.text_seq_len - 1), axis=0)
+        img_pos = jnp.clip(pos - cfg.text_seq_len, 0, cfg.image_seq_len - 1)
+        img_e = img_e + image_pos_emb(params, cfg, img_pos)
     is_text = pos < cfg.text_seq_len
     if pos.ndim:
         is_text = is_text[:, None]
@@ -244,7 +270,7 @@ def decode_token_embed(params: dict, cfg: DALLEConfig, cur_tok: Array,
 
 @jax.named_scope("head")
 def to_logits(params: dict, h: Array) -> Array:
-    h = core.layernorm(params["to_logits"]["ln"], h)
+    h = core.norm(params["to_logits"]["ln"], h)
     return core.linear(params["to_logits"]["proj"], h)
 
 
@@ -281,6 +307,8 @@ def quantize_for_decode(params: dict) -> dict:
     (no tangent through int8); quantize after restore, never checkpoint
     the result."""
     from dalle_pytorch_tpu.ops import quant
+    if T.is_block_params(params["transformer"]):
+        raise T.BlockOptionError(T.LatentMoEBlock.name, "--quantize int8*")
     out = dict(params)
     out["transformer"] = quant.quantize_tree_int8(params["transformer"])
     out["to_logits"] = quant.quantize_tree_int8(params["to_logits"])
@@ -481,8 +509,7 @@ def sample_per_slot(logits: Array, pred_pos: Array, keys: Array,
     — while its uncond partner takes the cond slot's drawn token (the
     one-shot path's ``tile``), so the pair's caches stay in step. Text
     positions sample from the cond stream alone, exactly as one-shot."""
-    forbidden = logits_mask(cfg)
-    lg = jnp.where(jnp.take(forbidden, pred_pos - 1, axis=0),
+    lg = jnp.where(logits_mask_rows(cfg, pred_pos - 1),
                    core.neg_inf(logits.dtype), logits)
     if partner is not None:
         # guided mix BEFORE temperature, on the masked logits — the
@@ -585,6 +612,10 @@ def generate_images(params: dict, vae_params: dict, text: Array, *,
     b, t0 = text.shape
     total_len = cfg.seq_len
     tcfg = cfg.transformer
+    if tcfg.block is not None:
+        raise T.BlockOptionError(tcfg.block.name, "generate_images",
+                                 "the one-shot sampler decodes over the "
+                                 "dense cache")
 
     guided = guidance > 0
     if guided:

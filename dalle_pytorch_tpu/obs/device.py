@@ -25,8 +25,10 @@ from typing import Dict, Optional
 # name= is one of these (tests/test_obs_device.py greps for it)
 SCOPES = (
     "embed",            # token + position embedding
-    "norm",             # LayerNorms
-    "attn.proj",        # qkv and output projections
+    "norm",             # LayerNorms and RMSNorms
+    "attn.proj",        # qkv (or q) and output projections
+    "attn.latent",      # latent attention: the kv latent's projection, norm
+                        # and RoPE, k_up / v_up (materialised or absorbed)
     "attn.read",        # q.k, mask, softmax, .v computed by XLA
     "attn.flash_fwd",   # the flash kernel, forward
     "attn.flash_bwd",   # its backward: the XLA blockwise one or the kernels
@@ -35,7 +37,11 @@ SCOPES = (
     "paged_attn",       # the ragged paged-attention kernel (inside attn.read)
     "kv.view",          # page pool / cache -> per-slot rows
     "kv.store",         # new rows -> cache or pool
-    "ff",               # the GEGLU block (or its MoE stand-in)
+    "ff",               # the GEGLU block (or its capacity-MoE stand-in),
+                        # a described block's dense SiLU-gated layer
+    "moe.route",        # dropless routing: router, top-k, sort, combine
+    "moe.experts",      # the grouped products over the experts with rows
+    "moe.shared",       # the shared experts
     "head",             # logits
     "sample",           # filtering and sampling
     "loss",             # cross-entropy

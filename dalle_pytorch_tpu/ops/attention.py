@@ -145,3 +145,138 @@ def attention_apply(params: dict, x: Array, *, heads: int, dim_head: int,
 
     return output_tail(params, out, dropout_rate=dropout_rate,
                        dropout_key=dropout_key, train=train)
+
+
+# ---------------------------------------------------------------------------
+# latent attention (the ``LatentMoEBlock`` of ops/transformer.py)
+# ---------------------------------------------------------------------------
+#
+# A token's cached state is ONE row a layer: the normed latent ``c``
+# (``kv_rank`` wide) and the roped key part ``k_rope`` shared by every
+# head, side by side and filled up with zeros to whole lanes (``row``,
+# ``blk.row_width`` wide; the zeros add nothing to a contraction). The
+# per-head keys and values are products of ``c`` with ``k_up`` / ``v_up``,
+# and there are two reads that are one identity: the MATERIALISED read
+# makes those products for the rows it attends (a whole prompt at once:
+# prefill, the full forward), the ABSORBED read moves ``k_up`` onto the
+# query and ``v_up`` behind the weighted sum, so that every head contracts
+# the same cached rows as they lie (decode: one matrix product a slot).
+
+def rope(x: Array, positions: Array, theta: float) -> Array:
+    """Rotary positions over interleaved pairs: (x[2i], x[2i+1]) turned by
+    ``positions * theta ** (-2i / d)``. ``positions`` broadcasts against
+    ``x.shape[:-1]``. Angles and the rotation in f32."""
+    d = x.shape[-1]
+    inv_freq = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    ang = jnp.asarray(positions, jnp.float32)[..., None] * inv_freq
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    xf = x.astype(jnp.float32).reshape(x.shape[:-1] + (d // 2, 2))
+    a, b = xf[..., 0], xf[..., 1]
+    out = jnp.stack([a * cos - b * sin, a * sin + b * cos], axis=-1)
+    return out.reshape(x.shape).astype(x.dtype)
+
+
+def latent_init(key: Array, dim: int, heads: int, blk,
+                dtype=jnp.float32) -> dict:
+    """No biases anywhere; ``k_up`` / ``v_up`` are the two halves of the
+    published ``kv_b_proj``, kept apart and per head so that neither read
+    slices a weight."""
+    ks = jax.random.split(key, 5)
+    r, dn, dr, dv = (blk.kv_rank, blk.qk_nope_dim, blk.qk_rope_dim,
+                     blk.v_head_dim)
+    return {
+        "q": core.linear_init(ks[0], dim, heads * (dn + dr), bias=False,
+                              dtype=dtype),
+        "kva": core.linear_init(ks[1], dim, r + dr, bias=False, dtype=dtype),
+        "kv_ln": core.rmsnorm_init(r, dtype),
+        "k_up": core.uniform_fan_in(ks[2], (r, heads, dn), r, dtype),
+        "v_up": core.uniform_fan_in(ks[3], (r, heads, dv), r, dtype),
+        "out": core.linear_init(ks[4], heads * dv, dim, bias=False,
+                                dtype=dtype),
+    }
+
+
+def latent_project(params: dict, h: Array, positions: Array, heads: int,
+                   blk):
+    """h (..., dim) normed input, ``positions`` broadcastable to
+    ``h.shape[:-1]`` -> (q_nope (..., heads, dn), q_rope (..., heads, dr)
+    roped, row (..., row_width): the row to cache, [c | k_rope | 0])."""
+    r, dn, dr = blk.kv_rank, blk.qk_nope_dim, blk.qk_rope_dim
+    positions = jnp.asarray(positions)
+    with jax.named_scope("attn.proj"):
+        q = core.linear(params["q"], h).reshape(h.shape[:-1]
+                                                + (heads, dn + dr))
+        q_nope = q[..., :dn]
+        q_rope = rope(q[..., dn:], positions[..., None], blk.rope_theta)
+    with jax.named_scope("attn.latent"):
+        kva = core.linear(params["kva"], h)
+        c = core.rmsnorm(params["kv_ln"], kva[..., :r], eps=blk.norm_eps)
+        k_rope = rope(kva[..., r:], positions, blk.rope_theta)
+        fill = jnp.zeros(c.shape[:-1] + (blk.row_width - blk.entry_width,),
+                         c.dtype)
+        row = jnp.concatenate([c, k_rope, fill], axis=-1)
+    return q_nope, q_rope, row
+
+
+def latent_attend_materialised(params: dict, q_nope: Array, q_rope: Array,
+                               entry: Array, allowed: Array, blk,
+                               scale: float) -> Array:
+    """The prefill read. q_* (b, n, heads, .), entry (b, m, row_width),
+    allowed broadcastable to (b, 1, n, m) -> (b, n, heads, dv): keys and
+    values are made from the latent for every row and attended per head."""
+    r = blk.kv_rank
+    with jax.named_scope("attn.latent"):
+        c, k_rope = entry[..., :r], entry[..., r:blk.entry_width]
+        k_nope = jnp.einsum("bjr,rhd->bjhd", c, params["k_up"].astype(c.dtype))
+        v = jnp.einsum("bjr,rhd->bjhd", c, params["v_up"].astype(c.dtype))
+    with jax.named_scope("attn.read"):
+        dots = (jnp.einsum("bihd,bjhd->bhij", q_nope, k_nope,
+                           preferred_element_type=jnp.float32)
+                + jnp.einsum("bihd,bjd->bhij", q_rope, k_rope,
+                             preferred_element_type=jnp.float32)) * scale
+        dots = jnp.where(allowed, dots, core.neg_inf(dots.dtype))
+        w = jax.nn.softmax(dots, axis=-1).astype(v.dtype)
+        return jnp.einsum("bhij,bjhd->bihd", w, v)
+
+
+def latent_attend_absorbed(params: dict, q_nope: Array, q_rope: Array,
+                           rows: Array, allowed: Array, entry: Array, blk,
+                           scale: float) -> Array:
+    """The decode read. One query a slot: q_* (b, heads, .), ``rows``
+    (b, m, row_width) cached rows with ``allowed`` (b, m), ``entry`` (b,
+    row_width) the token's own row (always attended) -> (b, heads, dv).
+    ``k_up`` is folded into the query and ``v_up`` applied to the weighted
+    sum of rows, so the rows are contracted as they lie, whole, all heads
+    at once: the query and ``v_up`` are given zeros for the part of a row
+    that is not theirs."""
+    fill = blk.row_width - blk.entry_width
+    with jax.named_scope("attn.latent"):
+        q_lat = jnp.einsum("bhd,rhd->bhr", q_nope,
+                           params["k_up"].astype(q_nope.dtype))
+        q = jnp.concatenate(
+            [q_lat, q_rope, jnp.zeros(q_rope.shape[:-1] + (fill,),
+                                      q_rope.dtype)], axis=-1)
+    with jax.named_scope("attn.read"):
+        scores = jnp.einsum("bhc,bjc->bhj", q, rows,
+                            preferred_element_type=jnp.float32) * scale
+        scores = jnp.where(allowed[:, None, :], scores,
+                           core.neg_inf(scores.dtype))
+        own = jnp.einsum("bhc,bc->bh", q, entry,
+                         preferred_element_type=jnp.float32) * scale
+        w = jax.nn.softmax(
+            jnp.concatenate([scores, own[..., None]], axis=-1), axis=-1)
+        w = w.astype(rows.dtype)
+        # the weighted sum of WHOLE rows: a slice of the rows (or of this
+        # sum: the compiler moves it onto the rows) is a copy of them
+        o_row = jnp.einsum("bhj,bjc->bhc", w[..., :-1], rows) \
+            + w[..., -1:] * entry[:, None, :]
+    with jax.named_scope("attn.latent"):
+        v_up = jnp.pad(params["v_up"].astype(o_row.dtype),
+                       ((0, blk.row_width - blk.kv_rank), (0, 0), (0, 0)))
+        return jnp.einsum("bhc,chd->bhd", o_row, v_up)
+
+
+@jax.named_scope("attn.proj")
+def latent_out(params: dict, o: Array) -> Array:
+    """(..., heads, dv) -> (..., dim): the output projection."""
+    return core.linear(params["out"], o.reshape(o.shape[:-2] + (-1,)))

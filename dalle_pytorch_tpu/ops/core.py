@@ -97,6 +97,27 @@ def layernorm(params: dict, x: Array, *, eps: float = 1e-5) -> Array:
     return y.astype(x.dtype)
 
 
+def rmsnorm_init(dim: int, dtype=jnp.float32) -> dict:
+    return {"g": jnp.ones((dim,), dtype)}
+
+
+@jax.named_scope("norm")
+def rmsnorm(params: dict, x: Array, *, eps: float = 1e-6) -> Array:
+    """``x / sqrt(mean(x^2) + eps) * g`` in f32, cast back: the norm of
+    the latent-attention block (ops/transformer.py ``LatentMoEBlock``)."""
+    xf = x.astype(jnp.float32)
+    y = xf * lax.rsqrt(jnp.mean(jnp.square(xf), axis=-1, keepdims=True)
+                       + eps)
+    return (y * params["g"].astype(jnp.float32)).astype(x.dtype)
+
+
+def norm(params: dict, x: Array) -> Array:
+    """The norm its parameters describe: a gain with a shift is a
+    LayerNorm, a gain alone an RMSNorm. For the callers that hold
+    parameters and no configuration (``models.dalle.to_logits``)."""
+    return layernorm(params, x) if "b" in params else rmsnorm(params, x)
+
+
 # ---------------------------------------------------------------------------
 # embedding
 # ---------------------------------------------------------------------------
@@ -167,6 +188,21 @@ def gelu(x: Array) -> Array:
     """Exact (erf) GELU, matching torch F.gelu default used by the reference
     GEGLU (reference dalle_pytorch/transformer.py:36)."""
     return jax.nn.gelu(x, approximate=False)
+
+
+def swiglu_init(key: Array, dim: int, hidden: int, dtype=jnp.float32) -> dict:
+    """SiLU-gated feed-forward without biases: ``w_in`` holds the gate and
+    the up projection side by side, (dim, 2 * hidden)."""
+    k_in, k_out = jax.random.split(key)
+    return {"w_in": uniform_fan_in(k_in, (dim, 2 * hidden), dim, dtype),
+            "w_out": uniform_fan_in(k_out, (hidden, dim), hidden, dtype)}
+
+
+def swiglu(params: dict, x: Array) -> Array:
+    """``W_down(silu(W_gate x) * (W_up x))``."""
+    gate, up = jnp.split(jnp.dot(x, params["w_in"].astype(x.dtype)), 2,
+                         axis=-1)
+    return jnp.dot(jax.nn.silu(gate) * up, params["w_out"].astype(x.dtype))
 
 
 def dropout(key: Optional[Array], x: Array, rate: float, train: bool) -> Array:

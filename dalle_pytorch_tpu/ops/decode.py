@@ -43,6 +43,21 @@ from dalle_pytorch_tpu.ops import core, sparse
 Array = jax.Array
 
 
+# the most gathered pages that the TPU compiler was seen to keep in VMEM
+# (of v5e's 128 MiB) from a gather to its readers: 89 MB yes, 178 MB no
+# (AOT compiles, PERF.md section 6, PR 27)
+_VIEW_VMEM_BYTES = 96 << 20
+
+
+def _refuse_block(cfg, option: str, why: str = "") -> None:
+    """The one typed refusal of a path that cannot run a described block
+    (``cfg.block``): it runs through ``prefill`` and the paged gather
+    step (``decode_step_block``) alone."""
+    if cfg.block is not None:
+        from dalle_pytorch_tpu.ops.transformer import BlockOptionError
+        raise BlockOptionError(cfg.block.name, option, why)
+
+
 def init_cache(cfg, batch: int, total_len: int, dtype=jnp.float32,
                quantized: bool = False) -> dict:
     """K/V buffers. ``quantized=True`` stores int8 rows with per-row f32
@@ -61,6 +76,7 @@ def init_cache(cfg, batch: int, total_len: int, dtype=jnp.float32,
     bounded at roughly 1% relative; tests/test_quant.py pins the
     end-to-end parity of the int8-KV path at < 2%, and that tolerance
     is this contract, not slack."""
+    _refuse_block(cfg, "kv='dense'", "its cache is the latent page pool")
     shape = (cfg.depth, batch, cfg.heads, total_len, cfg.dim_head)
     if quantized:
         return {"k": jnp.zeros(shape, jnp.int8),
@@ -275,8 +291,9 @@ def layer_pool_view(pool: dict, layer: Array, tables: Array):
     """ONE layer's pages through the block tables, read where they lie:
     pool buffers (depth, P, heads, ps[, dh]), ``layer`` a traced scalar,
     tables (b, w) -> (gk, gv, gk_scale, gv_scale) of (b, w, heads, ps[,
-    dh]) (scales None for a float pool). The one per-layer view of BOTH
-    step maths: the full table trimmed to ``ceil(total_len / ps)``
+    dh]) (scales None for a float pool); a latent pool (depth, P, ps,
+    width) -> its one buffer's pages (b, w, ps, width). The one per-layer
+    view of BOTH step maths: the full table trimmed to ``ceil(total_len / ps)``
     columns, or a sparse layer's visible slice of it.
 
     Three choices keep this a gather of whole pages and nothing else,
@@ -290,12 +307,13 @@ def layer_pool_view(pool: dict, layer: Array, tables: Array):
         turned the read into transposing copies of the pool;
       * ``mode='clip'``: tables are in range by construction, and the
         default fill mode adds a select over every gathered row."""
-    num_pages = pool["k"].shape[1]
-    idx = layer * num_pages + tables
-
     def take(buf):
-        return jnp.take(buf.reshape((-1,) + buf.shape[2:]), idx, axis=0,
-                        mode="clip")
+        return jnp.take(buf.reshape((-1,) + buf.shape[2:]),
+                        layer * buf.shape[1] + tables, axis=0, mode="clip")
+    if "latent" in pool:
+        # a latent-attention block's pool holds one buffer of whole rows
+        # (kv_pool.page_layout): its pages, (b, w, ps, row_width)
+        return take(pool["latent"])
     gk, gv = take(pool["k"]), take(pool["v"])
     if "k_scale" not in pool:
         return gk, gv, None, None
@@ -373,6 +391,15 @@ def prefill(params: dict, x: Array, *, cfg, total_len: int,
     """
     from dalle_pytorch_tpu.ops import transformer as T
     b, t0, _ = x.shape
+    if cfg.block is not None:
+        # the described block's prefill IS its full forward (the
+        # materialised read); the cache it returns is the prompt's rows
+        # alone, {"latent": (depth, b, t0, row_width)}: its store is the
+        # page pool, and the engine's admission writes whole pages
+        if quantize_cache:
+            _refuse_block(cfg, "quantize_cache")
+        h_out, entries, _ = T.block_apply_full(params, x, cfg, prompt_mask)
+        return h_out, {"latent": entries}
     sparse_flags = jnp.asarray(cfg.sparse_pattern)
     any_sparse = any(cfg.sparse_pattern)
 
@@ -519,6 +546,9 @@ def _decode_step_math(params: dict, x_tok: Array, pos: Array, cache: dict,
     impls, and sparse layers read only their statically visible pages
     (``_decode_step_math_sparse_reads``) while dense layers read
     exactly as here."""
+    _refuse_block(cfg, "this decode step",
+                  "kv='dense', paged_attn='kernel', sparse_reads and the "
+                  "oracle view read K/V per head")
     if sparse_reads:
         if block_tables is None:
             raise ValueError("sparse_reads requires block_tables — page "
@@ -901,6 +931,82 @@ def _store_rows_paged(pool: dict, ks: Array, vs: Array, pos: Array,
     return {"k": put(pool["k"], ks), "v": put(pool["v"], vs)}
 
 
+@jax.named_scope("kv.store")
+def _store_entries_paged(pool: dict, entries: Array, pos: Array,
+                         block_tables: Array, active: Array) -> dict:
+    """``_store_rows_paged`` for a latent pool: slot i's new row of every
+    layer, ``entries`` (depth, b, width), lands in physical page
+    ``block_tables[i, pos[i] // page_size]`` at offset ``pos[i] %
+    page_size`` (the trash page for an inactive slot), by one in-place
+    update a slot: the pool keeps the layout in which a page is one
+    contiguous run."""
+    buf = pool["latent"]                       # (depth, P, ps, width)
+    ps = buf.shape[2]
+    bidx = jnp.arange(pos.shape[0])
+    page = jnp.where(active, block_tables[bidx, pos // ps], 0)
+    off = jnp.where(active, pos % ps, 0)
+    for i in range(pos.shape[0]):
+        buf = lax.dynamic_update_slice(
+            buf, entries[:, i][:, None, None, :], (0, page[i], off[i], 0))
+    return {"latent": buf}
+
+
+def decode_step_block(params: dict, x_tok: Array, pos: Array, pool: dict,
+                      block_tables: Array, *, cfg, key_mask: Array,
+                      active: Array) -> Tuple[Array, dict, Array]:
+    """One token a slot through a described block (``cfg.block``) against
+    its latent page pool: ``decode_step_paged``'s gather step with the
+    block's branches (``ops.transformer.block_layer``) and the ABSORBED
+    read. Inside the layer scan each layer gathers its own pages through
+    the tables (``layer_pool_view``), merges them to logical row order (a
+    bitcast when the page is whole sublane tiles: no head axis lies
+    between page and row) and contracts them as they lie, every head
+    against the same rows. The new rows are written after the scan.
+    x_tok (b, dim), pos (b,) -> (h_out (b, dim), pool, load (3,) int32:
+    the routed layers' load summed over them, ops.moe.dropless_apply)."""
+    from dalle_pytorch_tpu.ops import transformer as T
+    blk = cfg.block
+    total_len = key_mask.shape[1]
+    ps = pool["latent"].shape[2]
+    tables = _view_tables(block_tables, total_len, ps)
+    rows_len = tables.shape[1] * ps
+
+    with jax.named_scope("attn.read"):       # the mask
+        j = jnp.arange(rows_len)
+        # strictly-before rows (self is the read's own extra logit); rows
+        # past total_len on a partial last page are dead
+        allowed = (j[None, :] < pos[:, None]) & jnp.pad(
+            key_mask, ((0, 0), (0, rows_len - total_len)))
+
+    # a layer's gathered pages stay in VMEM between the gather and the two
+    # contractions that read them if they fit it; all slots' at once do
+    # not at the published widths (178 MB: written to HBM and read back
+    # twice), so the slots are read in the fewest equal groups that do
+    b = x_tok.shape[0]
+    view_bytes = b * rows_len * blk.row_width * pool["latent"].dtype.itemsize
+    groups = next(g for g in range(1, b + 1)
+                  if b % g == 0 and view_bytes <= g * _VIEW_VMEM_BYTES)
+    sb = b // groups
+
+    def layer_fn(lp, h, layer, moe):
+        def read(p, q_nope, q_rope, entry):
+            outs = []
+            for g in range(groups):
+                sl = slice(g * sb, (g + 1) * sb)
+                with jax.named_scope("kv.view"):
+                    pages = layer_pool_view(pool, layer, tables[sl])
+                    rows = pages.reshape(pages.shape[0], rows_len, -1)
+                outs.append(attn_ops.latent_attend_absorbed(
+                    p, q_nope[sl], q_rope[sl], rows, allowed[sl], entry[sl],
+                    blk, cfg.scale))
+            return jnp.concatenate(outs) if groups > 1 else outs[0]
+        return T.block_layer(lp, h, pos, read, cfg, moe)
+
+    h_out, (entries, loads) = T.block_stack(params, x_tok, layer_fn)
+    return (h_out, _store_entries_paged(pool, entries, pos, block_tables,
+                                        active), jnp.sum(loads, axis=0))
+
+
 def decode_step_paged(params: dict, x_tok: Array, pos: Array, pool: dict,
                       block_tables: Array, *, cfg, key_mask: Array,
                       active: Array, attn_impl: str = "gather",
@@ -949,27 +1055,45 @@ def decode_loop_paged(params: dict, cur_tok: Array, pos: Array,
     SAME fused scan, so the one-compile/emit-ring regime is unchanged.
     ``sparse_reads`` turns on sparsity-aware reads for the sparse
     layers (visibility tables are trace-time constants, so the fused
-    program still traces exactly once)."""
+    program still traces exactly once).
+
+    A described block (``cfg.block``) runs the gather step with its own
+    branches (``decode_step_block``) and the program returns one value
+    more, after the ring: the routed layers' load (3,) int32 summed over
+    the chunk's steps, for the engine to fetch with the ring."""
+    blk = cfg.block
+    if blk is not None:
+        for option, on in (("paged_attn='kernel'", attn_impl != "gather"),
+                           ("sparse_reads", sparse_reads),
+                           ("a mesh (out_sync)", out_sync is not None)):
+            if on:
+                _refuse_block(cfg, option)
 
     def one_step(carry, _):
-        cur_tok, pos, act, pool = carry
+        cur_tok, pos, act, pool, *load = carry
         emit = jnp.where(act, cur_tok, -1)
         x = embed_fn(cur_tok, pos)
-        h, pool = decode_step_paged(params, x, pos, pool, block_tables,
-                                    cfg=cfg, key_mask=key_mask, active=act,
-                                    attn_impl=attn_impl,
-                                    sparse_reads=sparse_reads,
-                                    out_sync=out_sync)
+        if blk is None:
+            h, pool = decode_step_paged(
+                params, x, pos, pool, block_tables, cfg=cfg,
+                key_mask=key_mask, active=act, attn_impl=attn_impl,
+                sparse_reads=sparse_reads, out_sync=out_sync)
+        else:
+            h, pool, step_load = decode_step_block(
+                params, x, pos, pool, block_tables, cfg=cfg,
+                key_mask=key_mask, active=act)
+            load = [load[0] + step_load]
         nxt = sample_fn(h, pos + 1)
         pos = pos + 1
         act = act & (pos < total_len)
         cur_tok = jnp.where(act, nxt, 0)
         pos = jnp.where(act, pos, 0)
-        return (cur_tok, pos, act, pool), emit
+        return (cur_tok, pos, act, pool, *load), emit
 
-    (cur_tok, pos, active, pool), emits = lax.scan(
-        one_step, (cur_tok, pos, active, pool), None, length=steps)
-    return cur_tok, pos, active, pool, jnp.moveaxis(emits, 0, 1)
+    load0 = () if blk is None else (jnp.zeros((3,), jnp.int32),)
+    (cur_tok, pos, active, pool, *load), emits = lax.scan(
+        one_step, (cur_tok, pos, active, pool, *load0), None, length=steps)
+    return (cur_tok, pos, active, pool, jnp.moveaxis(emits, 0, 1), *load)
 
 
 # ---------------------------------------------------------------------------
@@ -1377,6 +1501,7 @@ def decode_loop_spec(params: dict, draft_params: dict, cur_tok: Array,
     offsets and finished slots, which the harvest's ``row[row >= 0]``
     already handles (delivered tokens only — rejected drafts never
     reach the host accounting)."""
+    _refuse_block(cfg, "speculative")
     total_len = cache["k"].shape[3]
 
     def one_round(carry, _):
@@ -1419,6 +1544,7 @@ def decode_loop_spec_paged(params: dict, draft_params: dict,
     advances, so the no-alloc-churn contract holds per round, not just
     per chunk. ``sparse_reads`` does not compose (rejected at engine
     construction): the wide verify has no trimmed-visibility wide read."""
+    _refuse_block(cfg, "speculative")
     kernel = attn_impl == "kernel"
 
     def one_round(carry, _):

@@ -1,24 +1,30 @@
-"""Mixture-of-Experts feed-forward — expert-parallel over an ``ep`` axis.
+"""Mixture-of-Experts feed-forwards: two routings, one file.
 
-Beyond-reference capability (the reference has no MoE anywhere — SURVEY.md
-§2b lists EP/MoE: absent); built because expert parallelism is one of the
-first-class distributed axes this framework commits to (dp/tp/fsdp/sp/pp/
-ep). The design is the standard dense-dispatch top-k MoE (GShard/Switch
-pattern): every routing decision is expressed as einsums over one-hot
-dispatch/combine tensors, so the whole layer is static-shaped, jit-friendly,
-and shards with nothing but GSPMD sharding annotations —
+**Dropless routing** (``dropless_apply``; the ``LatentMoEBlock`` of
+ops/transformer.py, served): sigmoid scores in float32, the k largest of
+``score + bias`` picked, their scores renormalised and scaled, and every
+(token, pick) pair computed. The pairs are sorted by expert and the
+experts run as grouped matrix products over the sorted rows
+(``jax.lax.ragged_dot``, which the TPU compiler lowers to its own grouped
+matmul kernel: it visits the groups that hold rows and reads no other
+expert's weights); the results go back to token order and are summed
+with their weights, shared experts added. No capacity exists and no
+token is dropped, whatever the load: the same function serves a
+prefill's thousands of tokens and a decode step's few dozen. It returns
+its load (picks routed, experts touched, the fullest expert's picks) for
+the engine's counters.
 
-  * expert-stacked GEGLU weights carry a leading (E, ...) axis; shard it
-    over ``ep`` (``moe_param_specs``) and each device stores and runs only
-    its E/ep experts;
-  * the dispatch einsum produces (E, C, d) expert batches sharded on
-    ``ep``; with tokens sharded on ``dp``, XLA inserts the token->expert
-    all-to-alls over ICI automatically.
-
-Top-k routing with renormalized gates, capacity C = ceil(T/E * k * cf)
-per expert (overflow tokens fall through to the residual — standard
-Switch behavior), and the Switch load-balancing auxiliary loss
-(mean-prob x token-fraction x E, minimized at uniform routing).
+**Capacity routing** (``moe_apply``; the trainable ``moe_experts`` option
+of the classic block, expert-parallel over an ``ep`` axis): the standard
+dense-dispatch top-k MoE (GShard/Switch pattern). Every routing decision
+is an einsum over one-hot dispatch/combine tensors, so the layer is
+static-shaped and shards with nothing but GSPMD annotations
+(``moe_param_specs``: expert-stacked weights split over ``ep``, the
+token->expert all-to-alls inserted by XLA). Softmax gates, capacity
+C = ceil(T/E * k * cf) per expert and batch row; a token over capacity
+is DROPPED from the expert and carried by the residual alone; the Switch
+load-balancing auxiliary loss. It is kept for training under ``ep``;
+serving a routed block uses the dropless path above.
 """
 
 from __future__ import annotations
@@ -132,3 +138,94 @@ def moe_param_specs(axis: str = "ep") -> dict:
     from jax.sharding import PartitionSpec as P
     return {"router": {"w": P()}, "w1": P(axis, None, None),
             "w2": P(axis, None, None)}
+
+
+# ---------------------------------------------------------------------------
+# dropless routing (served): sort by expert, grouped matrix products
+# ---------------------------------------------------------------------------
+
+def dropless_init(key: Array, dim: int, blk, dtype=jnp.float32) -> dict:
+    """Router (with its selection bias), expert-stacked SiLU-gated units
+    (gate and up side by side in ``w_in``) and the shared unit."""
+    k_r, k_in, k_out, k_s = jax.random.split(key, 4)
+    e, he = blk.num_experts, blk.expert_hidden
+    return {
+        "router": {"w": core.uniform_fan_in(k_r, (dim, e), dim, dtype),
+                   "bias": jnp.zeros((e,), jnp.float32)},
+        "experts": {
+            "w_in": core.uniform_fan_in(k_in, (e, dim, 2 * he), dim, dtype),
+            "w_out": core.uniform_fan_in(k_out, (e, he, dim), he, dtype)},
+        "shared": core.swiglu_init(k_s, dim, blk.shared_hidden, dtype),
+    }
+
+
+@jax.named_scope("moe.route")
+def route(router: dict, x: Array, k: int, scale: float):
+    """x (t, dim) -> (picks (t, k) expert ids, weights (t, k) f32).
+    Scores are sigmoids in f32; the bias moves the SELECTION only, the
+    weights are the picked scores over their sum, times ``scale``."""
+    scores = jax.nn.sigmoid(jnp.dot(x.astype(jnp.float32),
+                                    router["w"].astype(jnp.float32)))
+    _, picks = lax.top_k(scores + router["bias"].astype(jnp.float32), k)
+    picked = jnp.take_along_axis(scores, picks, axis=-1)
+    return picks, scale * picked / jnp.sum(picked, axis=-1, keepdims=True)
+
+
+def dropless_experts(experts: dict, x: Array, picks: Array,
+                     weights: Array):
+    """Every (token, pick) pair through its expert, summed per token with
+    its weight. x (t, dim), picks / weights (t, k) -> (out (t, dim),
+    sizes (E,) int32: picks an expert received).
+
+    ``experts`` holds ``w_in`` (E, dim, 2 * hidden) and ``w_out``; or, from
+    a scanned stack (``ops.transformer.block_stack``), the WHOLE stack's
+    (L, E, ...) with ``layer``, this layer's index in it. The grouped
+    product then runs over all L * E groups with this layer's sizes laid at
+    its offset and zero elsewhere: it reads the groups that hold rows, and
+    the compiler is given no slice of a layer to copy first (a scan's
+    slice of a layer's experts, handed to a kernel, is a copy of them:
+    1.2 GB a layer a step at the published widths)."""
+    t, k = picks.shape
+    w_in, w_out = experts["w_in"], experts["w_out"]
+    e = w_in.shape[-3]
+    with jax.named_scope("moe.route"):
+        flat = picks.reshape(-1)
+        order = jnp.argsort(flat, stable=True)      # pairs, by expert
+        sizes = jnp.zeros((e,), jnp.int32).at[flat].add(1)
+        rows = jnp.take(x, order // k, axis=0)      # (t * k, dim)
+        pair_weights = jnp.take(weights.reshape(-1), order)
+        groups = sizes
+        if "layer" in experts:
+            w_in = w_in.reshape((-1,) + w_in.shape[2:])
+            w_out = w_out.reshape((-1,) + w_out.shape[2:])
+            groups = lax.dynamic_update_slice(
+                jnp.zeros((w_in.shape[0],), jnp.int32), sizes,
+                (experts["layer"] * e,))
+    with jax.named_scope("moe.experts"):
+        gate, up = jnp.split(
+            lax.ragged_dot(rows, w_in.astype(x.dtype), groups), 2, axis=-1)
+        out = lax.ragged_dot(jax.nn.silu(gate) * up, w_out.astype(x.dtype),
+                             groups)
+        # each pair's weight, in f32, here: the compiler's grouped-product
+        # kernel carries no scope of its own and takes its first reader's
+        out = out.astype(jnp.float32) * pair_weights[:, None]
+    with jax.named_scope("moe.route"):
+        # back to (token, pick) order, and the sum over a token's picks
+        out = jnp.take(out, jnp.argsort(order), axis=0).reshape(t, k, -1)
+        out = jnp.sum(out, axis=1)
+    return out.astype(x.dtype), sizes
+
+
+def dropless_apply(params: dict, x: Array, blk):
+    """x (..., dim) -> (out (..., dim), load (3,) int32: picks routed,
+    experts that received one, the fullest expert's picks)."""
+    lead = x.shape[:-1]
+    xt = x.reshape(-1, x.shape[-1])
+    picks, weights = route(params["router"], xt, blk.experts_per_token,
+                           blk.routed_scale)
+    out, sizes = dropless_experts(params["experts"], xt, picks, weights)
+    with jax.named_scope("moe.shared"):
+        out = out + core.swiglu(params["shared"], xt)
+    load = jnp.stack([jnp.sum(sizes), jnp.sum(sizes > 0).astype(jnp.int32),
+                      jnp.max(sizes)])
+    return out.reshape(lead + (-1,)), load

@@ -40,6 +40,66 @@ from dalle_pytorch_tpu.ops import core, sparse
 Array = jax.Array
 
 
+class BlockOptionError(ValueError):
+    """The ONE refusal of an option that a described block cannot run:
+    it names the block and the option, at construction (of a
+    configuration, an engine, a cache), never as a trace-time surprise
+    and never by silently running something else."""
+
+    def __init__(self, block: str, option: str, why: str = ""):
+        super().__init__(
+            f"the {block!r} block does not run with {option}"
+            + (f": {why}" if why else "")
+            + " (it runs through dalle_apply and the paged gather engine:"
+              " --kv paged --paged_attn gather)")
+        self.block, self.option = block, option
+
+
+@dataclasses.dataclass(frozen=True)
+class LatentMoEBlock:
+    """A described block other than PreNorm LayerNorm + GEGLU + learned
+    positions (``TransformerConfig.block``; None is the classic block):
+    RMSNorm, rotary positions, latent attention without query
+    compression (a token caches ONE row a layer, ``kv_rank +
+    qk_rope_dim`` wide, read materialised by prefill and absorbed by
+    decode: ops/attention.py), SiLU-gated feed-forwards without biases,
+    ``dense_layers`` leading dense layers and then routed-and-shared
+    expert layers with dropless routing (ops/moe.py), scores scaled by
+    the query/key head size. ``TransformerConfig.heads`` gives the heads;
+    ``dim_head`` and ``ff_mult`` are not read."""
+    kv_rank: int = 512
+    qk_nope_dim: int = 128
+    qk_rope_dim: int = 64
+    v_head_dim: int = 128
+    rope_theta: float = 1e6
+    norm_eps: float = 1e-6
+    dense_layers: int = 1
+    dense_hidden: int = 6144
+    num_experts: int = 128
+    experts_per_token: int = 6
+    expert_hidden: int = 768
+    shared_hidden: int = 1536       # n_shared_experts x expert_hidden
+    routed_scale: float = 2.448
+    name: str = "latent_moe"
+
+    @property
+    def entry_width(self) -> int:
+        """Numbers a token caches a layer: the latent and the roped key."""
+        return self.kv_rank + self.qk_rope_dim
+
+    @property
+    def row_width(self) -> int:
+        """Width of the row that holds them: ``entry_width`` filled up with
+        zeros to whole 128-lane tiles (576 -> 640). A minor dimension that
+        is not whole tiles is padded to them in device memory anyway, and
+        the TPU then lays such a pool out PAGE-minor to avoid the pad:
+        every program that takes the pool relays it on the way in and out
+        (two pool-sized copies a chunk, 2.3 GB of temporaries at the
+        published widths; PERF.md section 6, PR 27). The zeros take part
+        in every contraction and add nothing."""
+        return -(-self.entry_width // 128) * 128
+
+
 @dataclasses.dataclass(frozen=True)
 class TransformerConfig:
     dim: int
@@ -77,6 +137,26 @@ class TransformerConfig:
     moe_experts: int = 0
     moe_k: int = 2
     moe_capacity: float = 1.25
+    # a described block (LatentMoEBlock) in place of the classic one
+    block: Optional[LatentMoEBlock] = None
+
+    def __post_init__(self):
+        blk = self.block
+        if blk is None:
+            return
+        refused = {"reversible": self.reversible,
+                   "sparse_attn": any(self.sparse_pattern),
+                   "attn_impl='flash'": self.attn_impl != "xla",
+                   "moe_experts (capacity routing)": bool(self.moe_experts),
+                   "remat": self.remat != "none",
+                   "dropout": bool(self.attn_dropout or self.ff_dropout),
+                   "causal=False": not self.causal}
+        for option, on in refused.items():
+            if on:
+                raise BlockOptionError(blk.name, option)
+        if not 0 <= blk.dense_layers <= self.depth:
+            raise ValueError(f"dense_layers {blk.dense_layers} not in "
+                             f"[0, depth={self.depth}]")
 
     @property
     def moe(self):
@@ -94,6 +174,8 @@ class TransformerConfig:
 
     @property
     def scale(self) -> float:
+        if self.block is not None:
+            return (self.block.qk_nope_dim + self.block.qk_rope_dim) ** -0.5
         base = self.dim if self.scale_mode == "dim" else self.dim_head
         return base ** -0.5
 
@@ -125,10 +207,45 @@ def layer_init(key: Array, cfg: TransformerConfig, dtype=jnp.float32) -> dict:
     }
 
 
+def block_layer_init(key: Array, cfg: TransformerConfig, moe: bool,
+                     dtype=jnp.float32) -> dict:
+    """One layer of the described block: dense or routed feed-forward."""
+    from dalle_pytorch_tpu.ops.moe import dropless_init
+    blk = cfg.block
+    k_attn, k_ff = jax.random.split(key)
+    ff = dropless_init(k_ff, cfg.dim, blk, dtype) if moe \
+        else core.swiglu_init(k_ff, cfg.dim, blk.dense_hidden, dtype)
+    return {
+        "attn": {"ln": core.rmsnorm_init(cfg.dim, dtype),
+                 **attn_ops.latent_init(k_attn, cfg.dim, cfg.heads, blk,
+                                        dtype)},
+        "ff": {"ln": core.rmsnorm_init(cfg.dim, dtype), **ff},
+    }
+
+
 def transformer_init(key: Array, cfg: TransformerConfig,
                      dtype=jnp.float32) -> dict:
+    """Layer parameters stacked on a leading depth axis. A described
+    block's stack is not uniform: its leading dense layers and its expert
+    layers are two subtrees, ``{"dense": ..., "moe": ...}``, each stacked
+    over its own layers (``block_stack`` scans one after the other)."""
+    if cfg.block is not None:
+        k_dense, k_moe = jax.random.split(key)
+        n_dense = cfg.block.dense_layers
+        return {
+            "dense": jax.vmap(lambda k: block_layer_init(
+                k, cfg, False, dtype))(jax.random.split(k_dense, n_dense)),
+            "moe": jax.vmap(lambda k: block_layer_init(
+                k, cfg, True, dtype))(
+                    jax.random.split(k_moe, cfg.depth - n_dense)),
+        }
     keys = jax.random.split(key, cfg.depth)
     return jax.vmap(lambda k: layer_init(k, cfg, dtype))(keys)
+
+
+def is_block_params(params: dict) -> bool:
+    """Whether a transformer subtree is a described block's two stacks."""
+    return "dense" in params and "moe" in params
 
 
 # ---------------------------------------------------------------------------
@@ -261,6 +378,100 @@ def ff_or_moe(layer_params: dict, x: Array, cfg: TransformerConfig,
 
 
 # ---------------------------------------------------------------------------
+# the described block (LatentMoEBlock): its branches, written once
+# ---------------------------------------------------------------------------
+#
+# ``transformer_apply``, ``ops.decode.prefill`` and the paged gather decode
+# step all run a layer as: norm, ``attn_ops.latent_project``, a READ,
+# ``attn_ops.latent_out``, residual, then ``block_ff``, residual. Only the
+# read differs (materialised over a whole sequence here and in prefill,
+# absorbed over the page pool in decode), and it is handed in.
+
+def block_layer(lp: dict, h: Array, positions: Array, read, cfg,
+                moe: bool):
+    """One layer. ``read(attn_params, q_nope, q_rope, entry) -> o`` is the
+    attention read; -> (h, (entry, load)): the row(s) to cache and the
+    routed layer's load (zeros for a dense layer)."""
+    blk = cfg.block
+    p = lp["attn"]
+    hn = core.rmsnorm(p["ln"], h, eps=blk.norm_eps)
+    q_nope, q_rope, entry = attn_ops.latent_project(p, hn, positions,
+                                                    cfg.heads, blk)
+    h = h + attn_ops.latent_out(p, read(p, q_nope, q_rope, entry))
+    f, load = block_ff(lp["ff"], h, blk, moe)
+    return h + f, (entry, load)
+
+
+def block_ff(p: dict, x: Array, blk, moe: bool):
+    """PreNorm feed-forward of the described block -> (out, load (3,))."""
+    hn = core.rmsnorm(p["ln"], x, eps=blk.norm_eps)
+    if moe:
+        from dalle_pytorch_tpu.ops.moe import dropless_apply
+        return dropless_apply(p, hn, blk)
+    with jax.named_scope("ff"):
+        return core.swiglu(p, hn), jnp.zeros((3,), jnp.int32)
+
+
+def block_stack(params: dict, h: Array, layer_fn):
+    """The non-uniform stack: the dense layers scanned, then the expert
+    layers. ``layer_fn(lp, h, layer, moe) -> (h, out)`` with ``layer`` the
+    traced index into the whole depth (the pool's layer index) and ``moe``
+    static. -> (h, outs stacked over the whole depth)."""
+    def scan_layers(h, sub, first: int, moe: bool):
+        n = jax.tree.leaves(sub)[0].shape[0]
+        whole = None
+        if moe:
+            # the routed experts are not scanned: a layer reads them out
+            # of the whole stack by its index (ops.moe.dropless_experts)
+            whole = sub["ff"]["experts"]
+            sub = {**sub, "ff": {k: v for k, v in sub["ff"].items()
+                                 if k != "experts"}}
+
+        def body(h, xs):
+            lp, local = xs
+            if whole is not None:
+                lp = {**lp, "ff": {**lp["ff"],
+                                   "experts": {**whole, "layer": local}}}
+            return layer_fn(lp, h, first + local, moe)
+
+        return lax.scan(body, h, (sub, jnp.arange(n)))
+
+    outs, first = [], 0
+    for name, moe in (("dense", False), ("moe", True)):
+        n = jax.tree.leaves(params[name])[0].shape[0]
+        if n:
+            h, out = scan_layers(h, params[name], first, moe)
+            outs.append(out)
+            first += n
+    return h, jax.tree.map(lambda *a: jnp.concatenate(a), *outs)
+
+
+def block_apply_full(params: dict, x: Array, cfg: TransformerConfig,
+                     mask: Optional[Array] = None):
+    """The described block over whole sequences x (b, n, dim) at positions
+    0..n-1, causal, the MATERIALISED read: the full forward and the
+    prefill are this one function. -> (h (b, n, dim), entries (depth, b,
+    n, entry_width): every layer's rows to cache, loads (depth, 3))."""
+    n = x.shape[1]
+    positions = jnp.arange(n)
+    with jax.named_scope("attn.read"):       # the mask
+        allowed = jnp.tril(jnp.ones((n, n), bool))[None, None]
+        if mask is not None:
+            allowed = allowed & (mask[:, None, :, None]
+                                 & mask[:, None, None, :])
+
+    def read(p, q_nope, q_rope, entry):
+        return attn_ops.latent_attend_materialised(
+            p, q_nope, q_rope, entry, allowed, cfg.block, cfg.scale)
+
+    def layer_fn(lp, h, _layer, moe):
+        return block_layer(lp, h, positions, read, cfg, moe)
+
+    h, (entries, loads) = block_stack(params, x, layer_fn)
+    return h, entries, loads
+
+
+# ---------------------------------------------------------------------------
 # apply
 # ---------------------------------------------------------------------------
 
@@ -318,6 +529,13 @@ def transformer_apply(params: dict, x: Array, *, cfg: TransformerConfig,
         raise ValueError(
             "transformer_apply(train=True) with nonzero dropout requires an "
             "explicit `rng` key — JAX has no global RNG state to fall back on")
+
+    if cfg.block is not None:
+        if train:
+            raise BlockOptionError(cfg.block.name, "train=True",
+                                   "the block is served, not trained")
+        out, _, _ = block_apply_full(params, x, cfg, mask)
+        return (out, jnp.float32(0.0)) if with_aux else out
 
     if cfg.reversible:
         if cfg.moe_experts:

@@ -133,6 +133,13 @@ COUNTERS = ("tokens_decoded", "decode_steps", "harvests",
 LOOP_SECONDS = ("engine_loop_s", "harvest_wait_s", "admit_s",
                 "admit_prefill_s", "deliver_s")
 
+# the routed layers' load of a described block (stats() of such an
+# engine), summed over its routed layers and every decode step: what the
+# fused decode program returns beside the ring, in the order it stacks
+# them (ops.moe.dropless_apply): token-picks routed, experts that received
+# one, the fullest expert's picks
+MOE_COUNTERS = ("moe_picks", "moe_experts_touched", "moe_load_max")
+
 
 def _phase(name: str, **meta):
     """One phase of the engine loop as a ``jax.profiler.TraceAnnotation``:
@@ -245,12 +252,13 @@ class _Chunk:
     — a slot expired and re-admitted while the chunk is in flight must
     not leak the old request's tokens into the new one."""
 
-    __slots__ = ("ring", "active", "owners")
+    __slots__ = ("ring", "active", "owners", "load")
 
-    def __init__(self, ring, active, owners):
+    def __init__(self, ring, active, owners, load=None):
         self.ring = ring
         self.active = active
         self.owners = owners
+        self.load = load        # MOE_COUNTERS of the chunk, or None
 
 
 class _Row:
@@ -329,6 +337,22 @@ class Engine:
         # replicated) without touching any of the loop's logic.
         self.device = device
         self.cfg = cfg
+        # a described block (ops.transformer.LatentMoEBlock) runs on the
+        # paged gather path alone; every other option is refused here,
+        # by the one typed error that names the block and the option
+        self.block = cfg.transformer.block
+        if self.block is not None:
+            from dalle_pytorch_tpu.ops import transformer as T_ops
+            for option, on in (
+                    ("kv='dense'", kv != "paged"),
+                    ("paged_attn='kernel'", paged_attn != "gather"),
+                    ("sparse_reads", sparse_reads),
+                    ("speculative", speculative),
+                    ("quantize_cache", quantize_cache),
+                    ("prefix_cache", prefix_cache),
+                    ("a device mesh", self._decode_out_sync() is not None)):
+                if on:
+                    raise T_ops.BlockOptionError(self.block.name, option)
         self.params = self._place_params(params)
         params = self.params
         self.queue = queue
@@ -631,6 +655,8 @@ class Engine:
         #                                 perfect draft scores exactly
         #                                 1.0, not "1.0 minus the last
         #                                 round's truncation"
+        for k in MOE_COUNTERS:          # a described block's routed load
+            setattr(self, k, 0)
         self._t_start = None
         self._last_log = 0
 
@@ -915,7 +941,24 @@ class Engine:
                 total_len=self.total_len, prompt_mask=None,
                 quantize_cache=self.quantize_cache,
                 out_sync=self._decode_out_sync())
-            if paged:
+            if paged and self.block is not None:
+                # the group's rows go in as WHOLE pages: (depth, G, bucket,
+                # width) cut into pages of page_size rows (the last one
+                # filled up with zeros) and written by page id alone, so
+                # no index falls in a page's tiled (row, width) dims and
+                # the pool keeps its layout. Page w of group-row g is
+                # the physical page of its row w * page_size (trash for
+                # the unused dummy rows and past a prompt's grants)
+                ps = self.page_size
+                n_pages = -(-bucket // ps)
+                with jax.named_scope("prefill.scatter"):
+                    rows = jnp.pad(group["latent"], (
+                        (0, 0), (0, 0), (0, n_pages * ps - bucket), (0, 0)))
+                    pages = rows.reshape(rows.shape[0], -1, ps,
+                                         rows.shape[-1])
+                    ids = page_rows[:, ::ps].reshape(-1)
+                    cache = {"latent": cache["latent"].at[:, ids].set(pages)}
+            elif paged:
                 # scatter the group's [0, bucket) rows into their pages:
                 # row j of group-row g lands in physical page
                 # page_rows[g, j] (trash 0 for the unused dummy rows) at
@@ -1949,14 +1992,15 @@ class Engine:
             if cold:
                 self.compiling = False
                 self.last_heartbeat = self.clock()
-        self.cur_tok, self.pos, self.active, self.cache, ring = outs
+        # a described block's program returns its routed load after the ring
+        self.cur_tok, self.pos, self.active, self.cache, ring, *load = outs
         owners = [(i, s) for i, s in enumerate(self.slots)
                   if s is not None]
         if self.kv == "paged":
             for i, _ in owners:
                 self._pos_est[i] = min(self._pos_est[i] + self._chunk_span,
                                        self.total_len)
-        self._pending.append(_Chunk(ring, self.active, owners))
+        self._pending.append(_Chunk(ring, self.active, owners, *load))
         self.decode_steps += self.chunk_steps
 
     def _harvest_chunk(self) -> None:
@@ -1971,8 +2015,13 @@ class Engine:
         rec = self._pending.popleft()
         t_wait = self.clock()
         with _phase("engine.harvest_wait"):
-            ring, active_after = jax.device_get([rec.ring, rec.active])
+            # the routed load rides the ring's fetch (None: an empty tree)
+            ring, active_after, load = jax.device_get(
+                [rec.ring, rec.active, rec.load])
         t_got = self.clock()
+        if load is not None:
+            for k, v in zip(MOE_COUNTERS, load):
+                setattr(self, k, getattr(self, k) + int(v))
         self.harvest_wait_s += t_got - t_wait
         with _phase("engine.deliver"):
             self._deliver_chunk(rec, ring, active_after)
@@ -2175,6 +2224,16 @@ class Engine:
         self._install_fn = self._jit_warm_program(install)
         return self._install_fn
 
+    def _refuse_block_migration(self) -> None:
+        """A described block's slots are not exported or imported (the
+        payload's format is the per-head K/V page's): the typed
+        ``MigrationError`` whose callers fall back to replay, naming the
+        block and the option as every other refusal of it does."""
+        if self.block is not None:
+            from dalle_pytorch_tpu.ops.transformer import BlockOptionError
+            raise MigrationError("block", str(BlockOptionError(
+                self.block.name, "slot export/import (MIGRATE frames)")))
+
     def find_slot(self, request_id: int) -> Optional[int]:
         """The cond slot index holding ``request_id`` (None when not
         in-slot — queued, mid-admission, or already gone)."""
@@ -2206,6 +2265,7 @@ class Engine:
         with self._lock:
             if self.fenced:
                 raise MigrationError("fenced")
+            self._refuse_block_migration()
             if self.kv != "paged":
                 raise MigrationError(
                     "kv_dense", "migration moves KV pages; the dense "
@@ -2293,6 +2353,7 @@ class Engine:
         with self._lock:
             if self.fenced:
                 raise MigrationError("fenced")
+            self._refuse_block_migration()
             if self.kv != "paged":
                 raise MigrationError("kv_dense")
             if str(payload.get("weights_version")) != self.weights_version:
@@ -2851,6 +2912,14 @@ class Engine:
             return self._modeled_read_bytes[sr]
         from dalle_pytorch_tpu.ops import paged_attention as PA
         tcfg = self.cfg.transformer
+        if self.block is not None:
+            # the gather reads every page of the table, whole rows, once
+            # a layer: no head axis, no V, no live-page trimming
+            out = (tcfg.depth * self.slot_max_pages * self.page_size
+                   * self.block.row_width
+                   * self.cache["latent"].dtype.itemsize)
+            self._modeled_read_bytes[sr] = out
+            return out
         out = int(PA.modeled_kv_read_bytes_per_token(
             depth=tcfg.depth, heads=tcfg.heads, dim_head=tcfg.dim_head,
             total_len=self.total_len, page_size=self.page_size,
@@ -2940,12 +3009,15 @@ class Engine:
                 "spec_tokens_per_round": round(
                     self.spec_delivered / max(self.spec_rounds, 1), 3),
             }
+        moe = {} if self.block is None else {
+            k: getattr(self, k) for k in MOE_COUNTERS}
         return {
             "kv": self.kv,
             "kv_hbm_bytes": self.kv_hbm_bytes(),
             **self._mesh_stats(),
             **paged,
             **spec,
+            **moe,
             "queue_depth": self.queue.depth(),
             "active_slots": self.active_slots(),
             "num_slots": self.num_slots,

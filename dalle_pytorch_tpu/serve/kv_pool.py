@@ -8,9 +8,13 @@ Attention"; the Gemma-on-TPU serving study credits this exact mechanism
 for most of its throughput headroom) breaks the cache into fixed-size
 PAGES shared by every slot:
 
-  * the device side is a page pool ``(depth, num_pages, heads,
-    page_size, dim_head)`` per K and V (``init_page_pool``; int8 variant
-    carries per-row scale pages) plus per-slot block tables
+  * the device side is a page pool whose entry comes from the model's
+    block (``page_layout``, the one place that says what a page of a
+    layer holds): ``(depth, num_pages, heads, page_size, dim_head)`` per
+    K and V for the classic block (the int8 variant carries per-row scale
+    pages), ``(depth, num_pages, page_size, row_width)`` for a
+    latent-attention block (one row a token, no head axis, no V); built
+    by ``init_page_pool``, plus per-slot block tables
     ``(num_slots, max_pages)`` int32 mapping logical page j → physical
     page id — ``ops.decode.layer_pool_view`` / ``_store_rows_paged`` are
     the decode step's read and write through them (``paged_view`` the
@@ -146,23 +150,47 @@ def pages_for(rows: int, page_size: int) -> int:
     return -(-rows // page_size)
 
 
+def page_layout(cfg, page_size: int, quantized: bool = False) -> dict:
+    """What ONE page of ONE layer holds, buffer by buffer: ``{name:
+    (shape of a page, bytes an element; None = the pool's float type)}``.
+    The one place that knows the entry's format. The pool
+    (``init_page_pool``), its modeled bytes (``modeled_kv_bytes``) and
+    through them the engine's sizing follow it; ``snapshot_page``,
+    ``restore_page`` and ``pool_bytes`` are generic over the buffers, and
+    in every buffer a page is one contiguous run whose first page axis is
+    ``num_pages`` (``(depth, num_pages) + page shape``).
+
+      * the classic block: K and V per head, ``(heads, page_size,
+        dim_head)`` each (int8 rows + per-row f32 scale pages when
+        ``quantized``: the layout/accuracy contract of
+        ``ops.decode.init_cache``, so int8-KV composes with paging);
+      * a latent-attention block (``cfg.block``): ONE row a token, the
+        latent and the roped key side by side and filled up to whole
+        lanes, ``(page_size, row_width)``: no head axis and no V."""
+    blk = getattr(cfg, "block", None)
+    if blk is not None:
+        if quantized:
+            from dalle_pytorch_tpu.ops.transformer import BlockOptionError
+            raise BlockOptionError(blk.name, "quantize_cache")
+        return {"latent": ((page_size, blk.row_width), None)}
+    page = (cfg.heads, page_size, cfg.dim_head)
+    if quantized:
+        return {"k": (page, 1), "v": (page, 1),
+                "k_scale": (page[:-1], 4), "v_scale": (page[:-1], 4)}
+    return {"k": (page, None), "v": (page, None)}
+
+
 def init_page_pool(cfg, num_pages: int, page_size: int, dtype=None,
                    quantized: bool = False) -> dict:
-    """Device-resident page pool: ``(depth, num_pages, heads, page_size,
-    dim_head)`` K/V buffers (int8 + per-row f32 scale pages when
-    ``quantized`` — the same layout/accuracy contract as
-    ``ops.decode.init_cache``, so int8-KV composes with paging
-    unchanged)."""
+    """Device-resident page pool: one ``(depth, num_pages) + page shape``
+    buffer for each entry of ``page_layout``."""
     import jax.numpy as jnp
     if dtype is None:
         dtype = jnp.float32
-    shape = (cfg.depth, num_pages, cfg.heads, page_size, cfg.dim_head)
-    if quantized:
-        return {"k": jnp.zeros(shape, jnp.int8),
-                "v": jnp.zeros(shape, jnp.int8),
-                "k_scale": jnp.zeros(shape[:-1], jnp.float32),
-                "v_scale": jnp.zeros(shape[:-1], jnp.float32)}
-    return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
+    kinds = {None: dtype, 1: jnp.int8, 4: jnp.float32}
+    return {name: jnp.zeros((cfg.depth, num_pages) + shape, kinds[size])
+            for name, (shape, size) in
+            page_layout(cfg, page_size, quantized).items()}
 
 
 def visible_table_view(block_tables, visible):
@@ -218,18 +246,20 @@ def modeled_kv_bytes(cfg, *, kv: str, num_slots: int, total_len: int,
     HBM-budget math read this). Mirrors the engine's defaults:
     ``page_size`` 0 -> min(16, total_len); ``num_pages`` 0 -> fully
     provisioned (num_slots full sequences + the trash page)."""
-    depth, heads, dh = cfg.depth, cfg.heads, cfg.dim_head
+    import math
     if kv == "paged":
         ps = int(page_size) or min(16, total_len)
         pages = int(num_pages) or \
             num_slots * pages_for(total_len, ps) + 1
-        rows = pages * ps
     else:
-        rows = num_slots * total_len
-    per_row = (1 + 4 / dh) if quantized else dtype_bytes
-    # k + v; quantized stores int8 rows (1 byte/elem) plus one f32
-    # scale per row — expressed per element as 1 + 4/dh
-    return int(2 * depth * heads * rows * dh * per_row)
+        # the dense slot cache holds the same rows, a slot a "page"
+        ps, pages = total_len, num_slots
+    # a page's bytes, buffer by buffer (quantized: int8 rows plus one
+    # f32 scale a row)
+    per_page = sum(math.prod(shape) * (size or dtype_bytes)
+                   for shape, size in
+                   page_layout(cfg, ps, quantized).values())
+    return int(cfg.depth * pages * per_page)
 
 
 class PageAllocator:
