@@ -35,6 +35,7 @@ from typing import Optional, Tuple, Union
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
 from dalle_pytorch_tpu.models import vae as vae_mod
@@ -440,13 +441,74 @@ def _chunked_ce(params: dict, h: Array, targets: Array,
 # generation — jit lax.scan sampler with KV cache
 # ---------------------------------------------------------------------------
 
+# key bits fixed by one counting pass of ``kth_largest``: 2 is three
+# compares over one read of the row and half the passes of 1; on a v5e,
+# where the keys stay in VMEM, 0.22 ms for 32 x 128256 float32 against
+# 0.27 at 1 and 0.43 at 4 (PERF.md section 6, PR 28)
+_SELECT_BITS = 2
+
+
+@jax.named_scope("sample")
+def kth_largest(x: Array, k) -> Array:
+    """The ``k``-th largest entry of each row of a float ``x`` (..., n),
+    kept as (..., 1), for a ``k`` in 1..n that may be traced and differ
+    per row — by exact selection, not by a sort.
+
+    The row is mapped to unsigned keys of the float's own width whose
+    order is the floats' (the bits, inverted where the sign is set, the
+    sign bit set elsewhere; ``-inf`` lowest, ``-0.0`` just under
+    ``0.0``), and the answer's key is fixed from the top, ``_SELECT_BITS``
+    a pass: the prefix found so far is extended by the largest digit
+    whose candidate still has ``k`` keys at or above it. 8 passes over
+    a 16-bit row and 16 over float32, the count taken from the dtype;
+    integer compares and counts only. The key found is an entry's own,
+    so the value returned compares equal to ``sort(x)[..., n - k]``
+    with ties, fills and infinities (a row with NaNs is ordered by
+    their bits, not as ``sort`` orders them)."""
+    if not jnp.issubdtype(x.dtype, jnp.floating):
+        raise TypeError(f"kth_largest orders float rows, got {x.dtype}")
+    nbits = x.dtype.itemsize * 8
+    uint = jnp.dtype(f"uint{nbits}")
+    sign = uint.type(1 << (nbits - 1))
+    bits = lax.bitcast_convert_type(x, uint)
+    keys = jnp.where(bits >= sign, ~bits, bits | sign)
+    k = jnp.broadcast_to(jnp.asarray(k, jnp.int32), x.shape[:-1])[..., None]
+    found = jnp.zeros(x.shape[:-1] + (1,), uint)
+    for shift in range(nbits - _SELECT_BITS, -1, -_SELECT_BITS):
+        digits = np.arange(1, 1 << _SELECT_BITS).astype(uint) << shift
+        cand = found | digits
+        at_or_above = jnp.sum(keys[..., None, :] >= cand[..., None],
+                              axis=-1, dtype=jnp.int32)
+        found = jnp.max(jnp.where(at_or_above >= k, cand, found),
+                        axis=-1, keepdims=True)
+    return lax.bitcast_convert_type(
+        jnp.where(found >= sign, found ^ sign, ~found), x.dtype)
+
+
 @jax.named_scope("sample")
 def top_k_filter(logits: Array, thres: float) -> Array:
     """Keep the top (1-thres)·vocab logits, -inf the rest (reference
-    top_k helper, dalle_pytorch.py:41-47)."""
+    top_k helper, dalle_pytorch.py:41-47). The threshold is
+    ``kth_largest``'s, the one ``sample_per_slot`` reads."""
     k = max(int((1 - thres) * logits.shape[-1]), 1)
-    kth = lax.top_k(logits, k)[0][..., -1:]
+    kth = kth_largest(logits, k)
     return jnp.where(logits < kth, core.neg_inf(logits.dtype), logits)
+
+
+def _nucleus_threshold(logits: Array, p) -> Array:
+    """The smallest logit of each row's nucleus, (..., 1): the descending
+    row's softmax and running mass, a token kept while the mass BEFORE
+    it is still < ``p`` (a scalar, or (..., 1) for a ``p`` per row) — so
+    the argmax always survives, and masked (-inf) tokens carry zero
+    mass and sit at cum == 1, never kept for p <= 1. The one place a
+    whole row is sorted."""
+    sorted_logits = jnp.flip(jnp.sort(logits, axis=-1), axis=-1)
+    probs = jax.nn.softmax(sorted_logits.astype(jnp.float32), axis=-1)
+    cum = jnp.cumsum(probs, axis=-1)
+    keep_sorted = (cum - probs) < p
+    return jnp.min(jnp.where(keep_sorted, sorted_logits,
+                             jnp.inf).astype(logits.dtype),
+                   axis=-1, keepdims=True)
 
 
 @jax.named_scope("sample")
@@ -460,16 +522,7 @@ def top_p_filter(logits: Array, p: float) -> Array:
     sampling distribution."""
     if not 0.0 < p <= 1.0:
         raise ValueError(f"top_p must be in (0, 1], got {p}")
-    sorted_logits = jnp.flip(jnp.sort(logits, axis=-1), axis=-1)
-    probs = jax.nn.softmax(sorted_logits.astype(jnp.float32), axis=-1)
-    cum = jnp.cumsum(probs, axis=-1)
-    # a token is kept when the mass BEFORE it is still < p, so the argmax
-    # always survives; masked (-inf) tokens carry zero mass and sit at
-    # cum == 1, never kept for p <= 1
-    keep_sorted = (cum - probs) < p
-    thresh = jnp.min(jnp.where(keep_sorted, sorted_logits,
-                               jnp.inf).astype(logits.dtype),
-                     axis=-1, keepdims=True)
+    thresh = _nucleus_threshold(logits, p)
     return jnp.where(logits < thresh, core.neg_inf(logits.dtype), logits)
 
 
@@ -479,7 +532,8 @@ def sample_per_slot(logits: Array, pred_pos: Array, keys: Array,
                     cfg: DALLEConfig, *,
                     partner: Optional[Array] = None,
                     cfg_scale: Optional[Array] = None,
-                    uncond: Optional[Array] = None) -> Array:
+                    uncond: Optional[Array] = None,
+                    live: Optional[Array] = None) -> Array:
     """Per-slot sampling: the traced-parameter form of ``generate_images``'s
     ``sample`` — forbidden-position mask, temperature, top-k OR nucleus
     filter, categorical — with every knob a (slots,) array instead of a
@@ -487,12 +541,17 @@ def sample_per_slot(logits: Array, pred_pos: Array, keys: Array,
     per-request mix (serve/engine.py holds the equivalence contract).
 
     Value-identical to the one-shot path per slot: the top-k threshold is
-    the k-th largest logit (what ``lax.top_k(...)[..., -1:]`` returns)
-    read off a full descending sort so k can vary per slot; the nucleus
-    branch is ``top_p_filter``'s exact math with p broadcast per slot.
-    Both filters are computed every step (fixed shape) and selected per
-    slot; ``top_p > 0`` selects nucleus, exactly as the python-level
-    branch does in ``generate_images``. Per-slot draws go through
+    the k-th largest logit at a per-slot k, selected exactly by
+    ``kth_largest`` (``top_k_filter`` reads the same helper) — counting
+    passes over the row, never an order of it; the nucleus threshold is
+    ``top_p_filter``'s exact math (``_nucleus_threshold``) with p
+    broadcast per slot, and its sort runs under a ``lax.cond`` only on
+    a step where some ``live`` slot (every slot when ``live`` is None)
+    has ``top_p > 0``: a pool of top-k requests never sorts, and the
+    program is still one trace. ``top_p > 0`` selects nucleus per slot,
+    exactly as the python-level branch does in ``generate_images`` (a
+    dead slot's stale ``top_p`` selects an unfiltered row whose token
+    nobody reads). Per-slot draws go through
     ``fold_in(key, pred_pos)`` — the one-shot sampler's key discipline —
     and ``jax.random.categorical`` over one slot's (vocab,) row equals
     the batch-1 call with the same key. Returns sampled ids with the
@@ -526,19 +585,15 @@ def sample_per_slot(logits: Array, pred_pos: Array, keys: Array,
         lg = jnp.where(guided_img[:, None], mix, lg)
     lg = lg / temp[:, None]
 
-    sorted_desc = jnp.flip(jnp.sort(lg, axis=-1), axis=-1)
-    kth = jnp.take_along_axis(sorted_desc, (topk_k - 1)[:, None], axis=-1)
-    by_k = jnp.where(lg < kth, core.neg_inf(lg.dtype), lg)
-
-    probs = jax.nn.softmax(sorted_desc.astype(jnp.float32), axis=-1)
-    cum = jnp.cumsum(probs, axis=-1)
-    keep_sorted = (cum - probs) < top_p[:, None]
-    thresh = jnp.min(jnp.where(keep_sorted, sorted_desc,
-                               jnp.inf).astype(lg.dtype),
-                     axis=-1, keepdims=True)
-    by_p = jnp.where(lg < thresh, core.neg_inf(lg.dtype), lg)
-
-    lg = jnp.where((top_p > 0)[:, None], by_p, by_k)
+    # both filters cut a row below a threshold, so a slot selects its
+    # threshold, not its filtered row
+    wants = top_p > 0
+    nucleus = lax.cond(
+        jnp.any(wants if live is None else live & wants),
+        lambda: _nucleus_threshold(lg, top_p[:, None]),
+        lambda: jnp.full((lg.shape[0], 1), -jnp.inf, lg.dtype))
+    thresh = jnp.where(wants[:, None], nucleus, kth_largest(lg, topk_k))
+    lg = jnp.where(lg < thresh, core.neg_inf(lg.dtype), lg)
     folded = jax.vmap(jax.random.fold_in)(keys, pred_pos)
     raw = jax.vmap(jax.random.categorical)(folded, lg)
     if partner is not None:

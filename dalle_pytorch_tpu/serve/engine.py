@@ -639,6 +639,9 @@ class Engine:
         self.decode_steps = 0           # fused steps dispatched (chunks*K)
         self.harvests = 0               # emit-ring device_gets — the ONLY
         #                                 host syncs in steady state
+        self.sample_sorted_chunks = 0   # chunks dispatched while a resident
+        #                                 request asks for nucleus sampling:
+        #                                 the only ones that sort the vocabulary
         self.tokens_decoded = 0
         self.completed = 0
         self.expired = 0
@@ -800,8 +803,8 @@ class Engine:
             self._prefill_trace_counts[bucket] = \
                 self._prefill_trace_counts.get(bucket, 0) + 1
 
-    def _cfg_closures(self, params, keys, temp, topk_k, top_p, partner,
-                      cfgs, uncond):
+    def _cfg_closures(self, params, active, keys, temp, topk_k, top_p,
+                      partner, cfgs, uncond):
         """The embed/sample closures BOTH fused decode programs share,
         with per-request classifier-free guidance folded in: a guided
         pair's cond slot samples image positions from the mixed logits
@@ -812,7 +815,9 @@ class Engine:
         positions embed PAD — ``generate_images``' guided scan
         verbatim. With every scale at 0 (no guided request in the
         pool) each extra op is an exact identity, so the unguided
-        byte-identity contract is untouched."""
+        byte-identity contract is untouched. ``active`` is the chunk's
+        live mask at dispatch: the sampler sorts the vocabulary only
+        while one of those slots asks for nucleus sampling."""
         import jax.numpy as jnp
 
         from dalle_pytorch_tpu.models import dalle as D
@@ -829,7 +834,7 @@ class Engine:
             return D.sample_per_slot(logits, pred_pos, keys, temp,
                                      topk_k, top_p, self.cfg,
                                      partner=partner, cfg_scale=cfgs,
-                                     uncond=uncond)
+                                     uncond=uncond, live=active)
 
         return embed_fn, sample_fn
 
@@ -846,7 +851,8 @@ class Engine:
         from dalle_pytorch_tpu.ops import decode as decode_ops
 
         embed_fn, sample_fn = self._cfg_closures(
-            params, keys, temp, topk_k, top_p, partner, cfgs, uncond)
+            params, active, keys, temp, topk_k, top_p, partner, cfgs,
+            uncond)
         if self.speculative:
             # the draft weights are a leading-layers slice of the SAME
             # resident params, taken inside the traced fn so hot-swap,
@@ -882,7 +888,8 @@ class Engine:
         from dalle_pytorch_tpu.ops import decode as decode_ops
 
         embed_fn, sample_fn = self._cfg_closures(
-            params, keys, temp, topk_k, top_p, partner, cfgs, uncond)
+            params, active, keys, temp, topk_k, top_p, partner, cfgs,
+            uncond)
         if self.speculative:
             draft_p = D.draft_transformer_params(
                 params["transformer"], self.draft_layers)
@@ -2002,6 +2009,8 @@ class Engine:
                                        self.total_len)
         self._pending.append(_Chunk(ring, self.active, owners, *load))
         self.decode_steps += self.chunk_steps
+        self.sample_sorted_chunks += any(
+            s.handle.request.sampling.top_p > 0 for _, s in owners)
 
     def _harvest_chunk(self) -> None:
         """Fetch the OLDEST in-flight chunk's emit ring — the single
@@ -3044,6 +3053,7 @@ class Engine:
             "harvests": self.harvests,
             "host_round_trips_per_token": round(
                 self.harvests / max(self.tokens_decoded, 1), 6),
+            "sample_sorted_chunks": self.sample_sorted_chunks,
             # the obs surface: flight-recorder occupancy (retention is
             # the ring capacity, /debug/events serves the contents) and
             # the serve-side profiler state

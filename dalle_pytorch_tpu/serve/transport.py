@@ -213,14 +213,21 @@ class SocketTransport:
         self._fill()
         if self._ready():
             return True
-        if timeout > 0 and not self._eof:
+        # a fragment that completes no frame is not the answer: wait out
+        # the rest of the timeout for the frame's remaining bytes
+        deadline = time.monotonic() + timeout
+        while not self._ready():
+            left = deadline - time.monotonic()
+            if left <= 0:
+                return False
             try:
-                r, _, _ = select.select([self._sock], [], [], timeout)
+                r, _, _ = select.select([self._sock], [], [], left)
             except (OSError, ValueError):
                 return True           # fd died: recv_bytes surfaces it
-            if r:
-                self._fill()
-        return self._ready()
+            if not r:
+                return False
+            self._fill()
+        return True
 
     def recv_bytes(self) -> bytes:
         if self._closed:

@@ -243,6 +243,52 @@ class TestEquivalence:
             np.asarray(h_b.result(timeout=5).tokens), ref_b)
         assert engine.decode_traces == 1
 
+    @pytest.mark.parametrize("kv", [{}, {"kv": "paged", "page_size": 4}],
+                             ids=["dense", "paged"])
+    def test_nucleus_join_keeps_one_trace_and_counts_its_chunks(
+            self, bundle, kv):
+        """A nucleus request joins a pool of top-k ones and leaves it
+        again: the decode program (whose vocabulary sort lies under a
+        conditional the program steers from its own ``top_p`` input) is
+        still traced once, every request's tokens are the one-shot
+        sampler's, and ``sample_sorted_chunks`` counts the chunks
+        dispatched while the nucleus request held a slot — found here by
+        its request id — and no others."""
+        params, vae_params = bundle
+        topk_a, topk_b, nucleus = REQS
+        queue = RequestQueue(max_depth=8)
+        engine = Engine(params, CFG, queue, num_slots=2, chunk_steps=2,
+                        **kv)
+        dispatch, resident = engine._dispatch_chunk, []
+
+        def spy(now):
+            resident.append(any(
+                engine.find_slot(h.request.request_id) is not None
+                for h in handles if h.request.sampling.top_p > 0))
+            dispatch(now)
+
+        engine._dispatch_chunk = spy
+        handles = [queue.submit(topk_a)]
+        for _ in range(3):
+            engine.step_once()          # top-k alone: nothing sorts
+        assert engine.stats()["sample_sorted_chunks"] == 0
+        # the nucleus request joins mid-stream, ends, and a top-k
+        # request takes its slot and outlives it
+        handles += [queue.submit(nucleus), queue.submit(topk_b),
+                    queue.submit(topk_a)]
+        engine.run_until_idle()
+
+        for h in handles:
+            np.testing.assert_array_equal(
+                np.asarray(h.result(timeout=5).tokens),
+                reference_tokens(params, vae_params, h.request))
+        assert engine.decode_traces == 1
+        st = engine.stats()
+        assert len(resident) == st["decode_steps"] // st["chunk_steps"]
+        assert resident[:3] == [False] * 3 and not resident[-1]
+        assert 0 < sum(resident) < len(resident)
+        assert st["sample_sorted_chunks"] == sum(resident)
+
     def test_int8_kv_slot_cache_runs(self, bundle):
         """quantize_cache composes with the slot pool: the engine matches
         generate_images(quantize_cache=True) token-for-token (both sides
