@@ -8,8 +8,8 @@
 # __graft_entry__.dryrun_multichip set up the 8-device CPU mesh themselves
 CPU_ENV := JAX_PLATFORMS=cpu
 
-.PHONY: test test-fast dryrun bench-smoke bench chip-smoke demo-rehearsal \
-	demo lint serve-stream
+.PHONY: test test-fast dryrun chip-smoke demo-rehearsal demo lint \
+	serve-stream
 
 test:            ## full suite on the virtual 8-device CPU mesh (~25 min)
 	$(CPU_ENV) python -m pytest tests/ -q
@@ -21,27 +21,12 @@ test-fast:       ## kernels + transformer + parallel only (~5 min)
 dryrun:          ## the driver's multi-chip validation (8 virtual devices)
 	$(CPU_ENV) python -c "import __graft_entry__ as g; g.dryrun_multichip(8)"
 
-bench-smoke:     ## tiny CPU bench — structural check of every config
-	$(CPU_ENV) XLA_FLAGS= python bench.py --tiny --steps 2 --warmup 1 \
-	    --gen_reps 1
-
-bench:           ## full bench on the chip (exits non-zero with no TPU)
-	python bench.py
-
 chip-smoke:      ## the main path end to end on the chip (PERF.md)
 	python chip_smoke.py
 
-serve-stream:    ## streaming/fan-out tier: unit tests + asserted bench leg
+serve-stream:    ## streaming/fan-out tier
 	$(CPU_ENV) python -m pytest tests/test_stream.py tests/test_fanout.py \
 	    tests/test_ipc.py -q
-	$(CPU_ENV) XLA_FLAGS= python bench.py --tiny --config serve \
-	    --serve_fanout 4 --serve_requests 4 --serve_loads 8 \
-	    --serve_chunks 8 \
-	    | python -c "import json,sys; \
-	        r = json.load(sys.stdin); fc = r['fanout_compare']; \
-	        assert 'error' not in fc, fc; \
-	        assert 'error' not in r, r.get('error'); \
-	        print('serve-stream OK:', json.dumps(fc['best_of_n']))"
 
 demo-rehearsal:  ## end-to-end demo pipeline, tiny knobs, scratch dirs
 	$(CPU_ENV) OUT=/tmp/demo_rehearsal/out DATA=/tmp/demo_rehearsal/data \
@@ -54,9 +39,9 @@ demo:            ## the real trained demo on the chip
 
 lint:            ## syntax check + jaxlint + racelint (AST rule gates)
 	$(CPU_ENV) python -m compileall -q dalle_pytorch_tpu tests scripts \
-	    bench.py chip_smoke.py __graft_entry__.py
+	    chip_smoke.py __graft_entry__.py
 	for f in scripts/*.sh; do bash -n $$f || exit 1; done
 	$(CPU_ENV) python -m dalle_pytorch_tpu.analysis.jaxlint \
-	    dalle_pytorch_tpu tests scripts bench.py chip_smoke.py
+	    dalle_pytorch_tpu tests scripts chip_smoke.py
 	$(CPU_ENV) python -m dalle_pytorch_tpu.analysis.racelint \
-	    dalle_pytorch_tpu tests scripts bench.py chip_smoke.py
+	    dalle_pytorch_tpu tests scripts chip_smoke.py

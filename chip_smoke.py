@@ -1,8 +1,8 @@
 """chip_smoke.py — the main path, end to end, on the chip.
 
 The quickest proof that the system still starts on a TPU, through the
-entry points a user would call, at the full width of the repo's "north"
-model (DiscreteVAE 256 px / 3 layers / 2048 tokens / codebook 512 /
+entry points a user would call, at the widths ``NORTH`` below
+(DiscreteVAE 256 px / 3 layers / 2048 tokens / codebook 512 /
 hidden 64; DALLE dim 512, depth 12, 8 x 64 heads, 256 text + 1024 image
 positions, 10000 text tokens, bf16 parameters), weights random from a
 seed. In ONE process (a chip belongs to one process):
@@ -18,8 +18,7 @@ seed. In ONE process (a chip belongs to one process):
   kernels     every Pallas variant a public flag reaches, compiled
               (``interpret=False``) at the north widths vs its XLA oracle
   sync        the same N train steps timed ending in
-              ``block_until_ready`` and in a host fetch must agree; the
-              implied MFU must lie in (0, 1)
+              ``block_until_ready`` and in a host fetch must agree
   multichip   (more than one device) every device holds its share; one
               tp x fsdp train step; one ``--mesh_devices`` engine answer
 
@@ -101,9 +100,9 @@ TINY = Widths(image_size=16, vae_layers=2, num_tokens=24, codebook_dim=16,
               n_samples=2, sync_steps=4, sync_tolerance=1.0, f32_depth=2,
               f32_tokens=8)
 
-# kernel-vs-oracle relative error bound — bench_kernels' own (max |a-b| /
-# max |b|; MXU operands round through bf16, so ~0.5% is by construction
-# and a wrong mask/tile/stat blows past 100%)
+# kernel-vs-oracle relative error bound: max |a-b| / max |b| under 2%
+# (MXU operands round through bf16, so ~0.5% is by construction, and a
+# wrong mask/tile/stat blows past 100%)
 KERNEL_RELDIFF = 2e-2
 # the paged kernel against the gather oracle on f32 parameters with exact
 # matmuls — tests/test_paged_attention.py's tolerance
@@ -117,29 +116,14 @@ PAGED_RTOL, PAGED_ATOL = 2e-5, 2e-6
 class Report:
     """Per-phase wall/compile/run seconds, peak device bytes and outcome.
     Compile seconds are jax's own backend-compile durations (cache
-    retrieval included), summed over every thread of the process."""
+    retrieval included), summed over every thread of the process: the
+    benchmark's listener."""
 
     def __init__(self):
-        import jax
+        from benchmark.harness import CompileListener
         self.phases = []
         self.failed = []
-        self._compile_s = 0.0
-        self._cache = {"hits": 0, "misses": 0}
-        self._lock = threading.Lock()
-        jax.monitoring.register_event_duration_secs_listener(
-            self._on_duration)
-        jax.monitoring.register_event_listener(self._on_event)
-
-    def _on_duration(self, event, duration_secs, **_):
-        if event == "/jax/core/compile/backend_compile_duration":
-            with self._lock:
-                self._compile_s += duration_secs
-
-    def _on_event(self, event, **_):
-        for key in self._cache:
-            if event.endswith(f"compilation_cache/cache_{key}"):
-                with self._lock:
-                    self._cache[key] += 1
+        self._compiles = CompileListener()
 
     @staticmethod
     def peak_bytes():
@@ -162,8 +146,7 @@ class Report:
             self.phases.append(rec)
             print(json.dumps(rec), flush=True)
             return None
-        with self._lock:
-            c0, cache0 = self._compile_s, dict(self._cache)
+        before = self._compiles.snapshot()
         t0 = time.perf_counter()
         info, value = {}, None
         try:
@@ -174,12 +157,12 @@ class Report:
             traceback.print_exc(file=sys.stderr)
             self.failed.append(name)
         wall = time.perf_counter() - t0
-        with self._lock:
-            compile_s = self._compile_s - c0
-            cache = {k: self._cache[k] - cache0[k] for k in self._cache}
+        after = self._compiles.snapshot()
+        compile_s = after["compile_s"] - before["compile_s"]
         rec.update(wall_s=round(wall, 2), compile_s=round(compile_s, 2),
                    run_s=round(max(wall - compile_s, 0.0), 2),
-                   cache_hits=cache["hits"], cache_misses=cache["misses"],
+                   cache_hits=after["hits"] - before["hits"],
+                   cache_misses=after["misses"] - before["misses"],
                    peak_bytes_in_use=self.peak_bytes(),
                    t_s=round(time.perf_counter() - T0, 1), **info)
         self.phases.append(rec)
@@ -804,18 +787,16 @@ def _train_setup(cfg, batch: int, mesh, dtype, param_specs_fn=None,
     return step, params, opt_state, data, key
 
 
-def phase_sync(w: Widths, n_dev: int, info: dict, peak_flops=None) -> None:
-    """ROADMAP Queue 1 item 1c, settled on the device it runs on: the
-    same N chained train steps timed once ending in
+def phase_sync(w: Widths, n_dev: int, info: dict) -> None:
+    """The timing rule (PERF.md section 2), settled on the device it runs
+    on: the same N chained train steps timed once ending in
     ``jax.block_until_ready`` and once in a host fetch of the last loss
-    must agree, and (with a peak for the device) the implied MFU must lie
-    in (0, 1). Checks, printed as such — not metrics."""
+    must agree. A check, printed as such — not a metric."""
     import math
 
     import jax
     import jax.numpy as jnp
 
-    import bench
     from dalle_pytorch_tpu.parallel import make_mesh
     cfg = _dalle_cfg(w)
     batch = w.batch * n_dev
@@ -843,12 +824,6 @@ def phase_sync(w: Widths, n_dev: int, info: dict, peak_flops=None) -> None:
     check(abs(t_block - t_fetch) <= w.sync_tolerance * max(t_block, t_fetch),
           f"block_until_ready ({t_block:.4f}s) and host fetch "
           f"({t_fetch:.4f}s) disagree on the same {w.sync_steps} steps")
-    if peak_flops is not None:
-        tokens_per_s = w.sync_steps * batch * cfg.seq_len / t_fetch / n_dev
-        mfu = tokens_per_s * bench.dalle_train_flops_per_token(cfg) \
-            / peak_flops
-        info["check_mfu_in_unit_interval"] = round(mfu, 4)
-        check(0.0 < mfu < 1.0, f"implied MFU {mfu} outside (0, 1)")
 
 
 # ---------------------------------------------------------------------------
@@ -927,7 +902,7 @@ def phase_multichip(root: str, w: Widths, n_dev: int, info: dict) -> None:
 # the run
 # ---------------------------------------------------------------------------
 
-def run_phases(w: Widths, report: Report, root: str, peak_flops=None) -> None:
+def run_phases(w: Widths, report: Report, root: str) -> None:
     """Every phase, in order, each reported; a failed phase fails the
     phases that need its output and lets the others run."""
     import jax
@@ -956,7 +931,7 @@ def run_phases(w: Widths, report: Report, root: str, peak_flops=None) -> None:
         needs=["train_dalle"])
     report.run("serve_kernel", serve_kernel, needs=["serve"])
     report.run("kernels", lambda info: phase_kernels(w, info))
-    report.run("sync", lambda info: phase_sync(w, n_dev, info, peak_flops))
+    report.run("sync", lambda info: phase_sync(w, n_dev, info))
     if n_dev > 1:
         report.run("multichip",
                    lambda info: phase_multichip(root, w, n_dev, info),
@@ -1009,7 +984,7 @@ def main() -> int:
     report = Report()
     root = tempfile.mkdtemp(prefix="chip_smoke_")
     try:
-        run_phases(NORTH, report, root, peak_flops=peaks["bf16_flops"])
+        run_phases(NORTH, report, root)
     finally:
         shutil.rmtree(root, ignore_errors=True)
     total = {"wall_s": round(time.perf_counter() - T0, 1),

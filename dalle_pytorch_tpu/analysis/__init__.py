@@ -7,7 +7,6 @@ Rule catalog and rationale: docs/STATIC_ANALYSIS.md.
 """
 
 from dalle_pytorch_tpu.analysis.guards import (CompileCountError,  # noqa: F401
-                                               CompileCountGuard,
                                                compile_count, counting,
                                                no_transfers)
 

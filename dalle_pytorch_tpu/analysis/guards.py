@@ -3,11 +3,10 @@
 The two invariants the lint can only approximate from source — "this
 region performs no implicit host-device transfer" and "this program
 compiled exactly N times" — are checkable exactly at runtime, and both
-already had ad-hoc open-coded versions in the tree (``bench_serve``'s
-post-sweep ``decode_compiles != 1`` check, ``test_serve``'s
+had ad-hoc open-coded versions in the tree (``test_serve``'s
 ``engine.decode_traces == 1`` asserts). These context managers are the
-one shared implementation: benches record violations, tests fail on
-them, and any future kernel test gets the same contract for one line.
+one shared implementation: tests fail on a violation, and any future
+kernel test gets the same contract for one line.
 
   * ``no_transfers()`` — ``jax.transfer_guard("disallow")``: implicit
     transfers raise; EXPLICIT ``jax.device_put``/``jax.device_get``
@@ -43,8 +42,7 @@ from typing import (Callable, Dict, Iterable, Iterator, List, Optional,
 
 class CompileCountError(AssertionError):
     """A guarded region compiled a different number of programs than its
-    contract allows. Carries ``expected``/``actual`` for structured
-    reporting (bench records them instead of raising)."""
+    contract allows. Carries ``expected``/``actual``."""
 
     def __init__(self, label: str, expected, actual: int):
         super().__init__(
@@ -54,47 +52,24 @@ class CompileCountError(AssertionError):
         self.actual = actual
 
 
-class CompileCountGuard:
-    """State handed back by ``compile_count`` — ``delta()`` mid-block,
-    ``error`` after a non-raising exit."""
-
-    def __init__(self, counter: Callable[[], int], label: str):
-        self._counter = counter
-        self.label = label
-        self.start = counter()
-        self.error: Optional[CompileCountError] = None
-
-    def delta(self) -> int:
-        return self._counter() - self.start
-
-
 @contextlib.contextmanager
 def compile_count(counter: Callable[[], int], *, expect: Optional[int]
                   = None, at_most: Optional[int] = None,
-                  label: str = "compile_count",
-                  raise_on_violation: bool = True
-                  ) -> Iterator[CompileCountGuard]:
+                  label: str = "compile_count") -> Iterator[None]:
     """Assert that ``counter`` (a zero-arg callable returning a
     monotonically increasing trace/compile count — e.g.
     ``lambda: engine.decode_traces``) advances by exactly ``expect``
-    (or by at most ``at_most``) across the block.
-
-    ``raise_on_violation=False`` records the violation on the yielded
-    guard's ``.error`` instead of raising — bench_serve's mode, where a
-    recompile must land in the JSON record, not kill the sweep. A
-    violation is only checked on clean exit: if the body itself raised,
-    that error wins."""
+    (or by at most ``at_most``) across the block. A violation is only
+    checked on clean exit: if the body itself raised, that error wins."""
     if (expect is None) == (at_most is None):
         raise ValueError("pass exactly one of expect= / at_most=")
-    guard = CompileCountGuard(counter, label)
-    yield guard
-    actual = guard.delta()
+    start = counter()
+    yield
+    actual = counter() - start
     bad = actual != expect if expect is not None else actual > at_most
     if bad:
         want = expect if expect is not None else f"<= {at_most}"
-        guard.error = CompileCountError(label, want, actual)
-        if raise_on_violation:
-            raise guard.error
+        raise CompileCountError(label, want, actual)
 
 
 @contextlib.contextmanager

@@ -61,9 +61,8 @@ def _refuse_block(cfg, option: str, why: str = "") -> None:
 def init_cache(cfg, batch: int, total_len: int, dtype=jnp.float32,
                quantized: bool = False) -> dict:
     """K/V buffers. ``quantized=True`` stores int8 rows with per-row f32
-    scales (beyond reference — the decode roofline in bench.py shows
-    cache reads are ~22% of batch-1 decode bytes and the dominant term
-    at batch > 1; int8 halves them). Rows are written once and read
+    scales (beyond reference: cache reads are the term of a decode
+    step's bytes that grows with the batch, and int8 halves them). Rows are written once and read
     every later step, so the quantization cost is paid once per row.
 
     Accuracy contract: the int8 rows carry ~0.4% relative error

@@ -387,9 +387,7 @@ def _bwd_keygrid_kernel(*refs, scale, causal, block_q, block_k, seq_len,
 
     Fused: dq, dk AND dv come from ONE score/probability computation per
     (query-tile, key-tile) pair — the split dq/dkv pair recomputes s, p,
-    dp twice (7 MXU dots per pair vs 4 here), which is the structural
-    reason it measured SLOWER than the XLA blockwise scan in r4 (147.4
-    vs 126.9 ms, docs/PROFILE_NORTH.json). Grid is (bh, key-tile) with
+    dp twice (7 MXU dots per pair vs 4 here). Grid is (bh, key-tile) with
     ik innermost; the full-length dq block's index map ignores ik, so on
     TPU's sequential grid the block stays resident in VMEM across all
     key tiles of one bh (output revisiting) and row tiles accumulate in

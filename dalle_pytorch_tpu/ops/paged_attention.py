@@ -335,9 +335,8 @@ def modeled_kv_read_bytes_per_token(*, depth: int, heads: int,
                                     sparse_pattern=None,
                                     sparse_block: int = 16,
                                     causal: bool = True) -> float:
-    """Analytic KV-read bytes per decoded token for one slot — the
-    number ``bench_serve --serve_paged_attn`` records for both legs
-    (HBM counters are not observable from the host, and on CPU the
+    """Analytic KV-read bytes per decoded token for one slot, for
+    either read (HBM counters are not observable from the host, and on CPU the
     kernel runs interpreted, so the comparison is a model: the gather
     path reads the FULL ``total_len`` view every step regardless of
     position, the kernel reads only the ``ceil(pos/page_size)`` mapped

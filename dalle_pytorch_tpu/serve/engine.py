@@ -54,8 +54,8 @@ KV layouts (``kv=``): the default ``"dense"`` slot pool reserves
 shared page pool + per-slot block tables (``serve/kv_pool.py``,
 ``ops.decode.decode_loop_paged``) so HBM residency tracks where requests
 actually ARE in their sequences, not where they could end up — the same
-budget sustains strictly more concurrent requests (bench_serve asserts
-it). Pages are allocated at admission for the prompt span, grown ahead
+budget sustains strictly more concurrent requests (tests/test_serve.py
+holds it). Pages are allocated at admission for the prompt span, grown ahead
 of each fused chunk as ``pos`` crosses page boundaries, and freed at
 completion/expiry/eviction; when the pool runs dry mid-decode the
 lowest-priority active request is EVICTED back to the queue (typed
@@ -614,7 +614,7 @@ class Engine:
         self._profile_lock = threading.Lock()
         self.profiles_taken = 0
 
-        # counters (stats()/bench_serve read these)
+        # counters (stats() reads these)
         self.decode_traces = 0          # bumped only while TRACING: the
         self.prefill_traces = 0         # fixed-shape contract keeps the
         #                                 decode program at 1 and prefill
@@ -2650,7 +2650,7 @@ class Engine:
         """True when there is nothing left to do: queue empty, every slot
         free, every in-flight chunk harvested. The termination predicate
         for any caller driving ``step_once`` by hand (``run_until_idle``,
-        bench_serve's budget-compare loop)."""
+        tests that watch the slots fill)."""
         return self.queue.depth() == 0 and self.active_slots() == 0 \
             and not self._pending
 
@@ -2898,8 +2898,7 @@ class Engine:
 
     def kv_hbm_bytes(self) -> int:
         """Resident HBM bytes of the KV store — the page pool under
-        ``kv='paged'``, the full slot cache under ``kv='dense'`` (what
-        bench_serve's budget comparison reads)."""
+        ``kv='paged'``, the full slot cache under ``kv='dense'``."""
         from dalle_pytorch_tpu.serve import kv_pool as KV
         return KV.pool_bytes(self.cache)
 

@@ -136,7 +136,7 @@ class Gateway:
     engines: they parameterize the routing key so it matches what each
     cell's PrefixIndex computes at admission. ``affinity=False``
     degrades routing to hash-blind least-loaded — the control arm of
-    the bench's affinity comparison, and an escape hatch."""
+    the tests' affinity comparison, and an escape hatch."""
 
     def __init__(self, cells: Sequence, *, tenants=None, cfg=None,
                  model_version: str = "v0", quantized: bool = False,

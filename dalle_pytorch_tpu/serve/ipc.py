@@ -441,8 +441,8 @@ class ChildEngineClient:
         # (export reply or import ack) — single-owner control thread,
         # migrations run serially, so one slot suffices
         self.migrate_reply: Optional[dict] = None
-        # child-stamp -> parent-absorb lag per frame (the isolation tax
-        # bench_serve's --isolation leg reports); perf_counter is
+        # child-stamp -> parent-absorb lag per frame (what isolation
+        # adds to a harvest); perf_counter is
         # CLOCK_MONOTONIC on Linux — one epoch across processes
         self.ipc_lag_s: deque = deque(maxlen=10_000)
 

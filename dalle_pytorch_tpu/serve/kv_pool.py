@@ -230,8 +230,8 @@ def restore_page(pool: dict, page, snap: dict) -> dict:
 
 
 def pool_bytes(pool: dict) -> int:
-    """Resident HBM bytes of a pool (or of a dense cache dict) — the
-    number ``bench_serve --serve_kv`` compares across layouts."""
+    """Resident HBM bytes of a pool (or of a dense cache dict): one
+    number for either layout."""
     return int(sum(x.nbytes for x in pool.values()))
 
 

@@ -210,9 +210,7 @@ def hbm_report(engine: Engine) -> dict:
     and the KV store — global and per shard. Works on a plain ``Engine``
     (per-shard == global: one chip holds everything) and a ``MeshEngine``
     (per-shard is what one device of the slice actually stores). This is
-    the number ``bench_serve``'s ``mesh_compare`` HBM-budget leg asserts
-    against a device budget, and what operators read next to
-    ``mesh_shape`` in /stats."""
+    what operators read next to ``mesh_shape`` in /stats."""
     from dalle_pytorch_tpu.parallel import serve_specs as SS
     params_b = SS.param_bytes(engine.params)
     kv_b = engine.kv_hbm_bytes()

@@ -2243,7 +2243,7 @@ class ReplicaSet:
 
     def decode_compiles_per_replica(self) -> List[int]:
         """Each LIVE replica's decode-program trace count — the
-        one-compile-per-replica contract bench_serve asserts (a
+        one-compile-per-replica contract the tests assert (a
         replaced engine is a fresh program, counted on its own)."""
         return [r.engine.decode_traces for r in self.replicas
                 if r.engine is not None]

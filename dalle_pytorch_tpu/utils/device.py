@@ -1,7 +1,7 @@
 """What device the process got, what it can do, and where compiles go.
 
 Three facts every entry point (the training CLIs, the server, its worker
-processes, bench.py, the sweep scripts, chip_smoke.py, the test suite)
+processes, benchmark/run.py, chip_smoke.py, the test suite)
 needs the same answer to — kept in one module so none of them can hide
 the device behind a default:
 

@@ -3,8 +3,8 @@
 The reference's observability is bare ``print`` of per-interval batch loss
 and per-epoch averages (reference trainVAE.py:98-102,116-117,
 trainDALLE.py:201-210). SURVEY.md §5.5 asks the rebuild for real counters —
-tokens/sec/chip is the north-star metric, so the training CLIs log it per
-interval, not just in bench.py.
+tokens/sec/chip is what a trainer pays for, so the training CLIs log it
+per interval.
 """
 
 from __future__ import annotations

@@ -37,21 +37,6 @@ def test_refuses_without_a_tpu():
     assert proc.returncode != 0 and proc.stdout.strip() == ""
 
 
-def test_north_is_the_repos_north_model():
-    """The widths the smoke runs at are bench.py's flagship, depth 12."""
-    import bench
-    cfg, w = bench.build_cfg(False), chip_smoke.NORTH
-    assert (cfg.dim, cfg.depth, cfg.heads, cfg.dim_head) \
-        == (w.dim, w.depth, w.heads, w.dim_head)
-    assert (cfg.text_seq_len, cfg.image_seq_len, cfg.num_text_tokens) \
-        == (w.text_seq_len, w.image_seq_len, w.num_text_tokens)
-    assert (cfg.vae.image_size, cfg.vae.num_layers, cfg.vae.num_tokens,
-            cfg.vae.codebook_dim, cfg.vae.hidden_dim) \
-        == (w.image_size, w.vae_layers, w.num_tokens, w.codebook_dim,
-            w.hidden_dim)
-    assert w.param_dtype == "bfloat16" and w.page_size == 16
-
-
 @pytest.mark.slow
 def test_phases_pass_at_tiny_widths(tmp_path, capsys):
     """Every phase the chip run takes, driven on the CPU test backend:

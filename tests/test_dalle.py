@@ -358,8 +358,8 @@ class TestChunkedCE:
 
 
 def test_north_composition_remat_flash_chunk_matches_plain(key, params):
-    """The tuned bench config composes remat='full' + attn_impl='flash' +
-    chunked CE in one train step (bench.py build_cfg); loss and grads must
+    """remat='full' + attn_impl='flash' + chunked CE compose in one train
+    step (what the train cell runs, at toy widths); loss and grads must
     match the plain dense/xla/un-rematerialized path, since remat and the
     CE streaming are pure memory strategies and flash is an exact
     attention algorithm (not an approximation)."""

@@ -1,6 +1,7 @@
 """utils/device.py: the compile cache is placed from outside or at one
-fixed path (never a temporary name), and a TPU host refuses process
-isolation with a typed error instead of dying in libtpu's lock."""
+fixed path (never a temporary name), a chip's peaks come by its
+``device_kind`` or not at all, and a TPU host refuses process isolation
+with a typed error instead of dying in libtpu's lock."""
 
 import os
 import re
@@ -67,6 +68,20 @@ class TestCompileCache:
         assert writers == [os.path.join("dalle_pytorch_tpu", "utils",
                                         "device.py")]
         assert suspects == []
+
+
+def test_peaks_keyed_by_device_kind_with_source():
+    """Known ``device_kind`` -> published peaks WITH their source; an
+    unknown device is the typed error, never another chip's numbers."""
+    v5e = device.chip_peaks("TPU v5 lite")
+    assert v5e["bf16_flops"] == 197e12
+    assert v5e["hbm_bytes_per_s"] == 819e9
+    assert "TPU v5e" in v5e["source"]
+    for peaks in device.CHIP_PEAKS.values():
+        assert peaks["source"]
+    with pytest.raises(device.UnknownDeviceError) as ei:
+        device.chip_peaks("cpu")
+    assert ei.value.device_kind == "cpu"
 
 
 class TestChipOwnership:

@@ -266,6 +266,71 @@ class TestCOWSharing:
                                          seed=sample_seed(9, i))))
 
 
+class TestServerGroup:
+    def test_streamed_group_previews_rank_and_stats(self, bundle):
+        """One ``submit(n_samples=N, stream=True)`` through the whole
+        server (paged KV, prefix cache, previews, CLIP re-rank): the
+        ranked samples are CLIP-score descending; each sample's token
+        events, reassembled by position, are its result; each sample's
+        closing ``final`` preview frame IS its result image, bit for
+        bit (the same zero-padded row through the same jitted VAE
+        program); and /stats banks the group, its COW dividend and its
+        preview frames, with no stream left open."""
+        from dalle_pytorch_tpu.models import clip as C
+        from dalle_pytorch_tpu.serve import unpack_image
+        from dalle_pytorch_tpu.serve.server import InferenceServer
+
+        params, vae_params = bundle
+        ccfg = C.CLIPConfig(
+            dim_text=16, dim_image=16, dim_latent=16,
+            num_text_tokens=CFG.num_text_tokens, text_enc_depth=1,
+            text_seq_len=CFG.text_seq_len, text_heads=2,
+            visual_enc_depth=1, visual_heads=2,
+            visual_image_size=VCFG.image_size, visual_patch_size=8,
+            sparse_attn=False)
+        clip_params = C.clip_init(jax.random.PRNGKey(7), ccfg)
+        n, page_size = 3, 8
+        prompt = tuple(1 + (i % 7) for i in range(CFG.text_seq_len))
+        shared = len(prompt) // page_size
+        server = InferenceServer(
+            params, vae_params, CFG, num_slots=n, queue_depth=16,
+            chunk_steps=4, kv="paged", page_size=page_size,
+            prefix_cache=True, preview_every=2, clip_params=clip_params,
+            clip_cfg=ccfg).start()
+        try:
+            group = server.submit(prompt, seed=7, n_samples=n,
+                                  stream=True)
+            streamed = {i: {} for i in range(n)}
+            finals = {}
+            for ev in group.sink.events():
+                if ev["event"] == "tokens":
+                    streamed[ev["sample"]][ev["pos"]] = ev["tokens"]
+                elif ev["event"] == "preview" and ev.get("final"):
+                    finals[ev["sample"]] = unpack_image(ev["image"])
+            res = group.result(timeout=120)
+            assert res.ok, (res.status, res.reason)
+            assert len(res.samples) == n and all(s.ok for s in res.samples)
+            scores = [s.clip_score for s in res.samples]
+            assert None not in scores and scores == sorted(scores,
+                                                           reverse=True)
+            for i, m in enumerate(group.members):
+                mres = m.result(timeout=5)
+                toks = [t for pos in sorted(streamed[i])
+                        for t in streamed[i][pos]]
+                np.testing.assert_array_equal(
+                    np.asarray(toks[-len(mres.tokens):], np.int32),
+                    np.asarray(mres.tokens))
+                np.testing.assert_array_equal(finals[i], mres.image)
+            st = server.stats()
+            assert st["groups_completed"] == 1
+            assert st["fanout_pages_saved"] == (n - 1) * shared
+            assert st["preview_frames"] >= n
+            assert st["streams_active"] == 0
+            assert st["groups_in_flight"] == 0
+        finally:
+            server.close()
+
+
 # ---------------------------------------------------------------------------
 # THE resilience criterion: replica death mid-group
 # ---------------------------------------------------------------------------

@@ -431,6 +431,28 @@ class TestGateway:
         finally:
             gw.close()
 
+    def test_affinity_hits_more_than_hash_blind_on_repeats(self, bundle):
+        # the same two prompts, five waves, order rotated a wave,
+        # through two fresh fleets: affinity sends a repeat to the
+        # cell that holds its pages; least-loaded placement follows
+        # arrival order, which the rotation scrambles, so each prompt
+        # pays a cold prefill on both cells
+        prompts = [(1,) * 4, (2,) * 4]
+        rates = {}
+        for affinity in (True, False):
+            gw = _gateway(bundle, affinity=affinity)
+            try:
+                for wave in range(5):
+                    order = prompts if wave % 2 == 0 else prompts[::-1]
+                    for h in [gw.submit(p, seed=0) for p in order]:
+                        assert h.result(90).ok
+                st = gw.stats()
+                assert st["fleet"]["completed"] == 10
+                rates[affinity] = st["fleet_prefix_hit_rate"]
+            finally:
+                gw.close()
+        assert rates[True] > rates[False], rates
+
     def test_spill_when_affine_cell_saturated(self, bundle):
         gw = _gateway(bundle)
         try:

@@ -572,7 +572,7 @@ class TestVisibilityOracle:
 
 class TestReadBytesModel:
     def test_kernel_model_reads_fewer_bytes_than_gather(self):
-        """The analytic model bench_serve records: the kernel's
+        """The analytic read-bytes model: the kernel's
         ragged-page reads must undercut the gather's full-view reads
         for any prompt shorter than the sequence."""
         common = dict(depth=2, heads=8, dim_head=64, total_len=1088,

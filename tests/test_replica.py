@@ -25,6 +25,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from dalle_pytorch_tpu.analysis import guards
 from dalle_pytorch_tpu.models import dalle as D
 from dalle_pytorch_tpu.models import vae as V
 from dalle_pytorch_tpu.resilience import faults
@@ -571,6 +572,10 @@ class TestProcessHardKill:
                             chunk_steps=4, isolation="process",
                             transport=transport,
                             child_rss_limit_mb=1408,
+                            # the ballast loop sends no frame while it
+                            # allocates: the supervisor's hang deadline
+                            # must not fire before the child's watchdog
+                            heartbeat_s=90.0,
                             bringup_policy=FAST_BRINGUP)
             try:
                 wait_all_ready(rs)
@@ -920,6 +925,14 @@ class TestRoutingAndStats:
         assert stats["decode_compiles"] == 2        # one per replica
         assert stats["alive_replicas"] == 2
         assert stats["failovers"] == 0
+        # the replicated steady state is transfer-clean: routing
+        # hand-offs are host-side, and a harvest stays one explicit
+        # device_get a chunk a replica
+        again = [queue.submit(r) for r in REQS[:4]]
+        with guards.no_transfers():
+            rs.run_until_idle()
+        assert_all_token_exact(params, vae_params, again, REQS[:4])
+        assert rs.stats()["decode_compiles"] == 2
 
     def test_page_aware_routing_prefers_replica_with_free_pages(
             self, bundle):

@@ -34,7 +34,7 @@ from dalle_pytorch_tpu.serve import (DEADLINE_EXCEEDED, ERROR, OK,
                                      InvalidRequest, PageAllocator,
                                      PagePoolExhausted, QueueClosed,
                                      QueueFull, Request, RequestQueue,
-                                     SamplingParams, bucket_for,
+                                     SamplingParams, bucket_for, pages_for,
                                      prefill_buckets)
 from dalle_pytorch_tpu.serve.engine import Engine
 
@@ -112,8 +112,8 @@ class TestEquivalence:
         queue = RequestQueue(max_depth=8)
         engine = Engine(params, CFG, queue, num_slots=2)
         handles = [queue.submit(r) for r in REQS]
-        # the shared guard (analysis.guards — same one bench_serve runs
-        # under): a recompiling decode step fails tier-1, not just bench
+        # the shared guard (analysis.guards): a recompiling decode step
+        # fails tier-1
         with guards.compile_count(lambda: engine.decode_traces, expect=1,
                                   label="serve decode program"):
             engine.run_until_idle()
@@ -450,6 +450,41 @@ class TestPagedKV:
             np.asarray(h_a.result(timeout=5).tokens), refs[0])
         np.testing.assert_array_equal(
             np.asarray(h_b.result(timeout=5).tokens), refs[1])
+
+    def test_one_page_budget_holds_more_requests_than_dense_slots(
+            self, bundle):
+        """What the paged pool is for: the bytes of ``dense_slots``
+        whole-sequence caches, spent through block tables, hold MORE
+        requests at once than the dense engine has slots (a request
+        maps only the pages it has reached), and the overcommit costs
+        no request — every one completes token-exact in both engines."""
+        params, vae_params = bundle
+        page_size, dense_slots = 4, 2
+        budget = dense_slots * pages_for(CFG.seq_len, page_size)
+        layouts = {
+            "dense": dict(num_slots=dense_slots),
+            # + 1: the reserved trash page is no part of the budget
+            "paged": dict(num_slots=2 * dense_slots, kv="paged",
+                          page_size=page_size, num_pages=budget + 1)}
+        reqs = REQS + REQS                  # more than either has slots
+        peaks = {}
+        for kv, kw in layouts.items():
+            queue = RequestQueue(max_depth=8)
+            engine = Engine(params, CFG, queue, chunk_steps=4, **kw)
+            handles = [queue.submit(r) for r in reqs]
+            peaks[kv] = 0
+            while engine.step_once() or not engine.idle():
+                peaks[kv] = max(peaks[kv], engine.active_slots())
+            for h, r in zip(handles, reqs):
+                res = h.result(timeout=5)
+                assert res.status == OK, (kv, res.status, res.reason)
+                np.testing.assert_array_equal(
+                    np.asarray(res.tokens),
+                    reference_tokens(params, vae_params, r))
+            if kv == "paged":
+                assert engine.stats()["pages_peak"] <= budget
+        assert peaks["dense"] == dense_slots
+        assert peaks["paged"] > dense_slots, peaks
 
     def test_head_of_line_request_not_starved_by_smaller(self, bundle):
         """No-starvation: a page-deferred request at the head of the
