@@ -31,6 +31,7 @@ generate_images in eval_decorator, reference dalle_pytorch.py:30-36,318).
 
 from __future__ import annotations
 
+import math
 from typing import Optional, Tuple
 
 import jax
@@ -47,6 +48,55 @@ Array = jax.Array
 # (of v5e's 128 MiB) from a gather to its readers: 89 MB yes, 178 MB no
 # (AOT compiles, PERF.md section 6, PR 27)
 _VIEW_VMEM_BYTES = 96 << 20
+_TILE = (8, 128)    # a TPU tile: 8 rows of 128 lanes of 4-byte words
+
+
+def view_slot_groups(slots: int, columns: int, page_shape, dtype) -> int:
+    """The rule of the paged gather reads (``_read_in_slot_groups``): the
+    fewest equal groups of ``slots`` whose gathered pages, ONE buffer of
+    one layer (K's readers finish before V's gather starts,
+    ``_paged_gather_read``), stay under ``_VIEW_VMEM_BYTES``; one slot a
+    group where no divisor fits. It reads what a trace sees and nothing
+    else: the slots, the table's ``columns``, a page's shape (heads, rows,
+    dim_head), or (rows, width) for a latent pool, and the pool's dtype.
+    The bytes are counted AS LAID OUT: the minor dimension is filled to
+    whole 128-lane tiles, so a 64-wide head takes the room of a 128-wide
+    one, and the rows to whole tiles of 8 words (16 bf16 rows, 32 int8
+    rows: the int8 pool's 16-row page takes the room of the bf16 one)."""
+    *outer, rows, minor = page_shape
+    itemsize = jnp.dtype(dtype).itemsize
+    tile = (_TILE[0] * max(4 // itemsize, 1), _TILE[1])
+    filled = [-(-n // t) * t for n, t in zip((rows, minor), tile)]
+    slot_bytes = columns * math.prod(outer) * math.prod(filled) * itemsize
+    return next((g for g in range(1, slots) if slots % g == 0
+                 and slots // g * slot_bytes <= _VIEW_VMEM_BYTES), slots)
+
+
+def pool_view_groups(pool: dict, slots: int, columns: int) -> int:
+    """``view_slot_groups`` of a page pool read through a table of
+    (slots, columns), trimmed as the read trims it."""
+    buf = pool["latent"] if "latent" in pool else pool["k"]
+    return view_slot_groups(slots, columns, buf.shape[2:], buf.dtype)
+
+
+def _read_in_slot_groups(pool: dict, tables: Array, read) -> Array:
+    """The one slot-group loop of the paged gather reads, the classic
+    block's and a described block's: a layer's gathered pages stay in VMEM
+    between the gather and the contractions that read them only if they
+    fit it, and what does not fit is written to HBM and read back by each
+    contraction (three crossings of every page where one is needed). So
+    ``read(sl)``, which gathers and attends the slots of the slice ``sl``
+    (``tables[sl]``, ``q[sl]``, ...), runs once a group of
+    ``pool_view_groups`` and the outputs are concatenated along slots. A
+    slot's result is computed from the same rows in the same dtype
+    whichever slots share its group (on the chip, bit-equal under a plain
+    ``jit``; between two whole engine programs the compiler may still
+    round a layer's output in another place: PERF.md section 6, PR 31)."""
+    slots = tables.shape[0]
+    groups = pool_view_groups(pool, *tables.shape)
+    per = slots // groups
+    outs = [read(slice(g * per, (g + 1) * per)) for g in range(groups)]
+    return outs[0] if groups == 1 else jnp.concatenate(outs)
 
 
 def _refuse_block(cfg, option: str, why: str = "") -> None:
@@ -286,14 +336,17 @@ def _view_tables(block_tables: Array, total_len: int,
 
 
 @jax.named_scope("kv.view")
-def layer_pool_view(pool: dict, layer: Array, tables: Array):
-    """ONE layer's pages through the block tables, read where they lie:
-    pool buffers (depth, P, heads, ps[, dh]), ``layer`` a traced scalar,
-    tables (b, w) -> (gk, gv, gk_scale, gv_scale) of (b, w, heads, ps[,
-    dh]) (scales None for a float pool); a latent pool (depth, P, ps,
-    width) -> its one buffer's pages (b, w, ps, width). The one per-layer
-    view of BOTH step maths: the full table trimmed to ``ceil(total_len / ps)``
-    columns, or a sparse layer's visible slice of it.
+def layer_pool_view(buf: Array, layer: Array, tables: Array) -> Array:
+    """ONE layer's pages of one pool buffer through the block tables, read
+    where they lie: ``buf`` (depth, P, ...page) is a pool's ``k`` or ``v``
+    (page = heads, ps, dh), an int8 pool's ``k_scale`` / ``v_scale``
+    (heads, ps) or a latent pool's ``latent`` (ps, width); ``layer`` a
+    traced scalar, tables (b, w) -> (b, w, ...page). The one per-layer
+    view of BOTH step maths and of a described block's step: the full
+    table trimmed to ``ceil(total_len / ps)`` columns, or a sparse layer's
+    visible slice of it, always the rows of ONE slot group
+    (``_read_in_slot_groups`` decides the groups), so that the pages stay
+    in VMEM from this gather to the contraction that reads them.
 
     Three choices keep this a gather of whole pages and nothing else,
     each read off the compiled TPU program (PERF.md, PR 25):
@@ -306,50 +359,76 @@ def layer_pool_view(pool: dict, layer: Array, tables: Array):
         turned the read into transposing copies of the pool;
       * ``mode='clip'``: tables are in range by construction, and the
         default fill mode adds a select over every gathered row."""
-    def take(buf):
-        return jnp.take(buf.reshape((-1,) + buf.shape[2:]),
-                        layer * buf.shape[1] + tables, axis=0, mode="clip")
-    if "latent" in pool:
-        # a latent-attention block's pool holds one buffer of whole rows
-        # (kv_pool.page_layout): its pages, (b, w, ps, row_width)
-        return take(pool["latent"])
-    gk, gv = take(pool["k"]), take(pool["v"])
-    if "k_scale" not in pool:
-        return gk, gv, None, None
-    return gk, gv, take(pool["k_scale"]), take(pool["v_scale"])
+    return jnp.take(buf.reshape((-1,) + buf.shape[2:]),
+                    layer * buf.shape[1] + tables, axis=0, mode="clip")
 
 
-@jax.named_scope("attn.read")
-def _paged_gather_read(q: Array, k: Array, v: Array, gk: Array, gv: Array,
-                       allowed: Array, *, scale: float,
-                       ksc: Optional[Array] = None,
-                       vsc: Optional[Array] = None) -> Array:
-    """``_gather_read`` over ``layer_pool_view``'s page-major pages:
-    gk/gv (b, w, heads, ps, dh), allowed (b, rows) with rows <= w * ps
-    (logical row j is page j // ps, offset j % ps; a partial last page's
-    tail rows are dropped). The pages are contracted as they lie and
-    only the SCORES (b, heads, w * ps: kilobytes) are brought to logical
-    row order, so the softmax runs over rows 0..rows-1 in order plus the
-    self logit, exactly the dense view's; int8 scales apply outside the
-    contractions in score dtype, as there. Returns (b, h, 1, dh)."""
+def _paged_gather_read(pool: dict, layer: Array, tables: Array, q: Array,
+                       k: Array, v: Array, allowed: Array, *, scale: float,
+                       v_after_k: bool = False) -> Array:
+    """``_gather_read`` for ONE slot group over ``layer_pool_view``'s
+    page-major pages: tables (b, w) into the K/V pool, q/k/v (b, h, 1, dh),
+    allowed (b, rows) with rows <= w * ps (logical row j is page j // ps,
+    offset j % ps; a partial last page's tail rows are dropped). K's pages
+    (b, w, heads, ps, dh) are gathered and contracted as they lie and only
+    the SCORES (b, heads, w * ps: kilobytes) are brought to logical row
+    order, so the softmax runs over rows 0..rows-1 in order plus the self
+    logit, exactly the dense view's; then V's pages the same way. The int8
+    pool's scale pages are gathered with their rows and apply outside the
+    contractions in score dtype, as there. Returns (b, h, 1, dh).
+
+    ``v_after_k`` ties V's gather to the softmax's weights. The budget of
+    a slot group is ONE gathered buffer (``view_slot_groups``), which
+    holds only while K's readers finish before V's gather starts. Alone
+    in its layer a group is scheduled so; among several the TPU scheduler
+    was seen to lift one group's V gather above that group's K gather
+    (AOT, PR 31: of 12b's six 81 MB gathers a layer one then misses VMEM),
+    so there the order is stated."""
+    def view(name, t):
+        sc = pool.get(name + "_scale")
+        return (layer_pool_view(pool[name], layer, t),
+                None if sc is None else layer_pool_view(sc, layer, t))
+
+    gk, ksc = view("k", tables)
     b, w, h, ps, _ = gk.shape
     rows = allowed.shape[1]
     quantized = ksc is not None
-    gkc = gk.astype(q.dtype) if quantized else gk
-    scores = jnp.einsum("bhd,bmhsd->bhms", q[:, :, 0, :], gkc) * scale
-    if quantized:
-        scores = scores * jnp.moveaxis(ksc, 1, 2).astype(scores.dtype)
-    scores = scores.reshape(b, h, 1, w * ps)[..., :rows]
-    wts = _softmax_with_self(scores, allowed, q, k, scale)
-    wj = jnp.pad(wts[:, :, 0, :-1], ((0, 0), (0, 0), (0, w * ps - rows))) \
-        .reshape(b, h, w, ps)
-    if quantized:
-        wj = wj * jnp.moveaxis(vsc, 1, 2).astype(wj.dtype)
-        gvc = gv.astype(q.dtype)
-    else:
-        gvc = gv
-    return (jnp.einsum("bhms,bmhsd->bhd", wj, gvc)[:, :, None, :]
-            + wts[..., -1:] * v)
+    with jax.named_scope("attn.read"):
+        gkc = gk.astype(q.dtype) if quantized else gk
+        scores = jnp.einsum("bhd,bmhsd->bhms", q[:, :, 0, :], gkc) * scale
+        if quantized:
+            scores = scores * jnp.moveaxis(ksc, 1, 2).astype(scores.dtype)
+        scores = scores.reshape(b, h, 1, w * ps)[..., :rows]
+        wts = _softmax_with_self(scores, allowed, q, k, scale)
+    if v_after_k:
+        wts, tables = lax.optimization_barrier((wts, tables))
+    gv, vsc = view("v", tables)
+    with jax.named_scope("attn.read"):
+        wj = jnp.pad(wts[:, :, 0, :-1],
+                     ((0, 0), (0, 0), (0, w * ps - rows))).reshape(b, h, w, ps)
+        if quantized:
+            wj = wj * jnp.moveaxis(vsc, 1, 2).astype(wj.dtype)
+            gvc = gv.astype(q.dtype)
+        else:
+            gvc = gv
+        return (jnp.einsum("bhms,bmhsd->bhd", wj, gvc)[:, :, None, :]
+                + wts[..., -1:] * v)
+
+
+def _paged_gather_attend(pool: dict, layer: Array, tables: Array,
+                         q: Array, k: Array, v: Array, allowed: Array, *,
+                         scale: float) -> Array:
+    """One layer's paged gather read of the classic block, whole:
+    ``_paged_gather_read`` over the slots of ``tables`` (b, w), a slot
+    group at a time (``_read_in_slot_groups`` decides the groups from the
+    shapes). q/k/v (b, h, 1, dh), allowed (b, rows) -> (b, h, 1, dh)
+    BEFORE out_sync/out-projection."""
+    def read(sl):
+        t = tables[sl]
+        return _paged_gather_read(
+            pool, layer, t, q[sl], k[sl], v[sl], allowed[sl], scale=scale,
+            v_after_k=t.shape[0] < tables.shape[0])
+    return _read_in_slot_groups(pool, tables, read)
 
 
 def _attn_with_kv(lp: dict, h: Array, allowed: Array, cfg,
@@ -529,7 +608,8 @@ def _decode_step_math(params: dict, x_tok: Array, pos: Array, cache: dict,
     ITS OWN layer's pages through the tables trimmed to
     ``ceil(total_len / page_size)`` columns (``layer_pool_view``) and
     contract them page-major under the same masked softmax
-    (``_paged_gather_read``) — one read of the pool a step, and no
+    (``_paged_gather_read``), a slot group at a time
+    (``_paged_gather_attend``) — one read of the pool a step, and no
     buffer of the pool's size besides the pool; ``'kernel'`` consumes
     the tables in place via the Pallas ragged paged-attention kernel
     (``ops.paged_attention``), which fetches only each slot's mapped
@@ -606,9 +686,8 @@ def _decode_step_math(params: dict, x_tok: Array, pos: Array, cache: dict,
         if paged_gather:
             # kv is this layer's INDEX: gather its pages from the pool
             # and contract them as they lie
-            gk, gv, gks, gvs = layer_pool_view(cache, kv, view_tables)
-            out = _paged_gather_read(q, k, v, gk, gv, allowed,
-                                     scale=cfg.scale, ksc=gks, vsc=gvs)
+            out = _paged_gather_attend(cache, kv, view_tables, q, k, v,
+                                       allowed, scale=cfg.scale)
         elif kernel_mode:
             # kv is the raw page pool for this layer; the kernel walks
             # the block tables in place (_kernel_read completes the
@@ -757,12 +836,10 @@ def _decode_step_math_sparse_reads(params: dict, x_tok: Array, pos: Array,
                 visible=vis_rows if is_sparse else None,
                 visible_cnt=vis_ccnt if is_sparse else None)
         else:
-            gk, gv, gks, gvs = layer_pool_view(
-                pool, kv, vis_bt if is_sparse else bt)
-            out = _paged_gather_read(
-                q, k, v, gk, gv,
+            out = _paged_gather_attend(
+                pool, kv, vis_bt if is_sparse else bt, q, k, v,
                 vis_allowed if is_sparse else dense_allowed,
-                scale=cfg.scale, ksc=gks, vsc=gvs)
+                scale=cfg.scale)
         if out_sync is not None:
             # the mesh seam, unchanged: gather heads before the out
             # projection instead of letting GSPMD partial-sum it
@@ -825,7 +902,11 @@ def _decode_step_math_sparse_reads(params: dict, x_tok: Array, pos: Array,
 # by layer into the pool itself) and contracts them in that form
 # (``_paged_gather_read``); only the scores are brought to logical row
 # order, so the softmax is the dense step's and paged-vs-dense tokens are
-# equal. The new row is written by in-place row updates
+# equal. The slots are read a group at a time, so that a group's gathered
+# pages stay in VMEM between the gather and its readers; the groups are
+# decided from the shapes in ONE place (``view_slot_groups``, looped by
+# ``_read_in_slot_groups``) for this step and a described block's
+# (``decode_step_block``). The new row is written by in-place row updates
 # (``_store_rows_paged``), so the pool keeps one layout, a page one
 # contiguous run, through the whole chunk: the compiled program holds no
 # buffer of the pool's size besides the pool (tests/test_paged_attention.py
@@ -957,8 +1038,10 @@ def decode_step_block(params: dict, x_tok: Array, pos: Array, pool: dict,
     its latent page pool: ``decode_step_paged``'s gather step with the
     block's branches (``ops.transformer.block_layer``) and the ABSORBED
     read. Inside the layer scan each layer gathers its own pages through
-    the tables (``layer_pool_view``), merges them to logical row order (a
-    bitcast when the page is whole sublane tiles: no head axis lies
+    the tables (``layer_pool_view``), a slot group at a time (the groups
+    are decided by ``view_slot_groups``, the classic step's rule, and
+    looped by ``_read_in_slot_groups``), merges them to logical row order
+    (a bitcast when the page is whole sublane tiles: no head axis lies
     between page and row) and contracts them as they lie, every head
     against the same rows. The new rows are written after the scan.
     x_tok (b, dim), pos (b,) -> (h_out (b, dim), pool, load (3,) int32:
@@ -977,28 +1060,19 @@ def decode_step_block(params: dict, x_tok: Array, pos: Array, pool: dict,
         allowed = (j[None, :] < pos[:, None]) & jnp.pad(
             key_mask, ((0, 0), (0, rows_len - total_len)))
 
-    # a layer's gathered pages stay in VMEM between the gather and the two
-    # contractions that read them if they fit it; all slots' at once do
-    # not at the published widths (178 MB: written to HBM and read back
-    # twice), so the slots are read in the fewest equal groups that do
-    b = x_tok.shape[0]
-    view_bytes = b * rows_len * blk.row_width * pool["latent"].dtype.itemsize
-    groups = next(g for g in range(1, b + 1)
-                  if b % g == 0 and view_bytes <= g * _VIEW_VMEM_BYTES)
-    sb = b // groups
-
     def layer_fn(lp, h, layer, moe):
         def read(p, q_nope, q_rope, entry):
-            outs = []
-            for g in range(groups):
-                sl = slice(g * sb, (g + 1) * sb)
+            # all slots' pages at once miss VMEM at the published widths
+            # (178 MB), so they are read a slot group at a time
+            def read_group(sl):
                 with jax.named_scope("kv.view"):
-                    pages = layer_pool_view(pool, layer, tables[sl])
+                    pages = layer_pool_view(pool["latent"], layer,
+                                            tables[sl])
                     rows = pages.reshape(pages.shape[0], rows_len, -1)
-                outs.append(attn_ops.latent_attend_absorbed(
+                return attn_ops.latent_attend_absorbed(
                     p, q_nope[sl], q_rope[sl], rows, allowed[sl], entry[sl],
-                    blk, cfg.scale))
-            return jnp.concatenate(outs) if groups > 1 else outs[0]
+                    blk, cfg.scale)
+            return _read_in_slot_groups(pool, tables, read_group)
         return T.block_layer(lp, h, pos, read, cfg, moe)
 
     h_out, (entries, loads) = T.block_stack(params, x_tok, layer_fn)

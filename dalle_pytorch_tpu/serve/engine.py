@@ -660,6 +660,9 @@ class Engine:
         #                                 round's truncation"
         for k in MOE_COUNTERS:          # a described block's routed load
             setattr(self, k, 0)
+        self.kv_view_groups = 1         # slot groups a layer's paged gather
+        #                                 read was traced with (ops.decode
+        #                                 view_slot_groups; 1 = no such read)
         self._t_start = None
         self._last_log = 0
 
@@ -901,6 +904,9 @@ class Engine:
                 k=self.speculative, embed_fn=embed_fn,
                 sample_fn=sample_fn, attn_impl=self.paged_attn,
                 out_sync=self._decode_out_sync())
+        if self.paged_attn == "gather":
+            self.kv_view_groups = decode_ops.pool_view_groups(
+                cache, self.num_slots, self.slot_max_pages)
         return decode_ops.decode_loop_paged(
             params["transformer"], cur_tok, pos, active, cache,
             block_tables, cfg=self.cfg.transformer,
@@ -3053,6 +3059,7 @@ class Engine:
             "host_round_trips_per_token": round(
                 self.harvests / max(self.tokens_decoded, 1), 6),
             "sample_sorted_chunks": self.sample_sorted_chunks,
+            "kv_view_groups": self.kv_view_groups,
             # the obs surface: flight-recorder occupancy (retention is
             # the ring capacity, /debug/events serves the contents) and
             # the serve-side profiler state

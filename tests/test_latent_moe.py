@@ -157,9 +157,11 @@ def test_prefill_then_paged_decode_matches_the_full_forward(
                                   sequences[:, t0 + 15])
     rows = slice(t0, t0 + 16)
     live = np.asarray(decode_ops.layer_pool_view(
-        chunk_pool, jnp.int32(1), tables)).reshape(b, -1, BLK.row_width)
+        chunk_pool["latent"], jnp.int32(1), tables)
+        ).reshape(b, -1, BLK.row_width)
     want = np.asarray(decode_ops.layer_pool_view(
-        step_pool, jnp.int32(1), tables)).reshape(b, -1, BLK.row_width)
+        step_pool["latent"], jnp.int32(1), tables)
+        ).reshape(b, -1, BLK.row_width)
     np.testing.assert_allclose(live[:, rows], want[:, rows], atol=1e-6)
 
 

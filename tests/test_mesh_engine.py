@@ -131,18 +131,26 @@ class TestMeshByteIdentity:
         for a, b in zip(ref, toks):
             np.testing.assert_array_equal(a, b)
 
+    @pytest.mark.parametrize("groups", [1, 2])
     @pytest.mark.parametrize("K", [1, 8])
-    def test_paged_tokens_byte_identical(self, bundle, K):
+    def test_paged_tokens_byte_identical(self, bundle, monkeypatch, K,
+                                         groups):
         """Paged KV on the mesh: the page pool shards along heads, the
         block tables stay host-authoritative and replicated, and the
-        gather oracle rides the per-shard slices — tokens unchanged."""
+        gather oracle rides the per-shard slices — tokens unchanged,
+        also where the layer's read runs a slot group at a time (the
+        slices are along slots, ``out_sync`` sees the whole output)."""
+        from dalle_pytorch_tpu.ops import decode as decode_ops
         params, _ = bundle
         kw = dict(kv="paged", page_size=8)
         ref = single_device_tokens(params, K=K, **kw)
+        if groups > 1:      # the rule's floor: one slot a group
+            monkeypatch.setattr(decode_ops, "_VIEW_VMEM_BYTES", 1)
         engine, toks = engine_tokens(params, MeshEngine, K=K,
                                      devices=mesh_devices(), **kw)
         assert engine.decode_traces == 1
         assert engine.kv_sharded
+        assert engine.stats()["kv_view_groups"] == groups
         for a, b in zip(ref, toks):
             np.testing.assert_array_equal(a, b)
 

@@ -16,7 +16,9 @@ beyond ``total_len``. Since ISSUE 25 the gather path itself reads the
 pool per layer and page-major (``layer_pool_view`` +
 ``_paged_gather_read``): ``TestPerLayerRead`` holds it to the
 ``paged_view`` + ``_gather_read`` oracle, to the dense loop's tokens,
-and to a temporaries budget that an all-layer view cannot meet.
+and to a temporaries budget that an all-layer view cannot meet; since
+ISSUE 31 the read runs a slot group at a time, and the same class holds
+the grouped read to the one-group read, the rule to the cells' shapes.
 
 All CPU (the kernel runs under the Pallas interpreter — the same code
 path CI's serve-perf kernel leg smokes), tiny model, inside tier-1.
@@ -41,6 +43,13 @@ VCFG = V.VAEConfig(image_size=16, num_tokens=32, codebook_dim=16,
                    num_layers=2, hidden_dim=8)
 CFG = D.DALLEConfig(dim=16, depth=2, vae=VCFG, num_text_tokens=50,
                     text_seq_len=8, heads=2, dim_head=8)
+
+
+# the sparse-reads step needs sparse layers whose window is narrower than
+# the 24-token sequence (tests/test_sparse_reads.py's configuration)
+SPARSE_CFG = D.DALLEConfig(dim=16, depth=2, vae=VCFG, num_text_tokens=50,
+                           text_seq_len=8, heads=2, dim_head=8,
+                           sparse_attn=(True, False), sparse_block=4)
 
 
 @pytest.fixture(scope="module")
@@ -430,24 +439,125 @@ class TestPerLayerRead:
                 scale=scale,
                 ksc=view["k_scale"][layer] if kind == "int8" else None,
                 vsc=view["v_scale"][layer] if kind == "int8" else None)
-            gk, gv, gks, gvs = decode_ops.layer_pool_view(
-                pool, jnp.asarray(layer), bt[:, :need])
+            gk = decode_ops.layer_pool_view(
+                pool["k"], jnp.asarray(layer), bt[:, :need])
             assert gk.shape == (3, need, self.HEADS, self.PS, dim_head)
-            assert (gks is None) == (kind != "int8")
             got = decode_ops._paged_gather_read(
-                q, k, v, gk, gv, allowed, scale=scale, ksc=gks, vsc=gvs)
+                pool, jnp.asarray(layer), bt[:, :need], q, k, v, allowed,
+                scale=scale)
             assert got.shape == want.shape and got.dtype == want.dtype
             np.testing.assert_allclose(
                 np.asarray(got, np.float32), np.asarray(want, np.float32),
                 **tol)
 
-    def _loop_args(self, bundle, page_size, quantized):
+    @staticmethod
+    def _laid_out(page, dtype):
+        """A page's bytes as the TPU lays it out, written out here on its
+        own: the minor dimension in whole 128-lane tiles, the rows in
+        whole tiles of 8 four-byte words."""
+        size = jnp.dtype(dtype).itemsize
+        tile_rows = 8 * max(4 // size, 1)
+        return (int(np.prod(page[:-2])) * -(-page[-2] // tile_rows)
+                * tile_rows * -(-page[-1] // 128) * 128 * size)
+
+    def _force_groups(self, monkeypatch, pool, slots, columns, groups):
+        """Set the VMEM budget (the constant, not a knob of the program)
+        so that the rule gives ``groups`` for this pool and table."""
+        monkeypatch.setattr(decode_ops, "_VIEW_VMEM_BYTES", 1)
+        # with a budget of 1 byte nothing fits: the rule's floor, one slot
+        assert decode_ops.pool_view_groups(pool, slots, columns) == slots
+        buf = pool["k"]
+        monkeypatch.setattr(
+            decode_ops, "_VIEW_VMEM_BYTES", slots // groups * columns
+            * self._laid_out(buf.shape[2:], buf.dtype))
+        assert decode_ops.pool_view_groups(pool, slots, columns) == groups
+
+    @pytest.mark.parametrize("groups", [1, 2, 3])
+    @pytest.mark.parametrize("dim_head", [64, 128])
+    @pytest.mark.parametrize("kind", ["f32", "bf16", "int8"])
+    def test_grouped_read_matches_one_group_and_oracle(
+            self, monkeypatch, kind, dim_head, groups):
+        """ISSUE 31: the slots are attended in the groups the rule gives
+        (``_read_in_slot_groups``). Six slots, one sharing a page with
+        another (copy-on-write), one mid-sequence with trash entries, one
+        parked dead: the grouped read equals the one-group read bit for
+        bit (a slot's result does not depend on its group) and the
+        ``paged_view`` + ``_gather_read`` oracle within rounding."""
+        total_len = 20                               # partial last page
+        key = jax.random.PRNGKey(31 + dim_head)
+        need = KV.pages_for(total_len, self.PS)
+        dtype = jnp.bfloat16 if kind == "bf16" else jnp.float32
+        pool = _random_pool(key, self.PS, 6 * need + 1, kind == "int8",
+                            dim_head=dim_head, dtype=dtype)
+        bt = np.arange(1, 6 * need + 1, dtype=np.int32).reshape(6, need)
+        pos = np.array([total_len - 1, 9, 5, 0, 13, total_len - 1])
+        bt[4, 0] = bt[0, 0]                          # a shared page
+        bt[1, KV.pages_for(pos[1] + 1, self.PS):] = 0    # trash entries
+        bt[3] = 0                                    # a parked dead slot
+        bt = jnp.asarray(bt)
+        q, k, v = [jax.random.normal(jax.random.fold_in(key, 10 + i),
+                                     (6, self.HEADS, 1, dim_head), dtype)
+                   for i in range(3)]
+        allowed = (jnp.arange(total_len)[None, :]
+                   < jnp.asarray(pos)[:, None]).at[0, 1].set(False)
+        scale = dim_head ** -0.5
+        layer = jnp.asarray(1)
+
+        def attend():
+            return decode_ops._paged_gather_attend(
+                pool, layer, bt, q, k, v, allowed, scale=scale)
+
+        assert decode_ops.pool_view_groups(pool, 6, need) == 1
+        whole = attend()
+        self._force_groups(monkeypatch, pool, 6, need, groups)
+        got = attend()
+        np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                      np.asarray(whole, np.float32))
+        view = decode_ops.paged_view(pool, bt, total_len)
+        want = decode_ops._gather_read(
+            q, k, v, view["k"][1], view["v"][1], allowed, scale=scale,
+            ksc=view["k_scale"][1] if kind == "int8" else None,
+            vsc=view["v_scale"][1] if kind == "int8" else None)
+        assert got.shape == want.shape and got.dtype == want.dtype
+        tol = dict(rtol=2e-2, atol=2e-2) if kind == "bf16" else \
+            dict(rtol=2e-5, atol=2e-5)
+        np.testing.assert_allclose(np.asarray(got, np.float32),
+                                   np.asarray(want, np.float32), **tol)
+
+    @pytest.mark.parametrize("slots,columns,page,dtype,want", [
+        (16, 72, (16, 16, 128), jnp.bfloat16, 1),    # rudalle-xl.serve-full
+        (12, 80, (62, 16, 64), jnp.bfloat16, 3),     # dalle-12b.serve-full
+        (32, 272, (16, 640), jnp.bfloat16, 2),       # the latent pool
+        (12, 80, (62, 16, 64), jnp.int8, 3),         # its int8 pool
+        (7, 80, (62, 16, 64), jnp.bfloat16, 7),      # no divisor fits
+    ], ids=["rudalle-xl", "dalle-12b", "latent", "dalle-12b-int8",
+            "prime_slots"])
+    def test_group_rule_on_the_cells_shapes(self, slots, columns, page,
+                                            dtype, want):
+        """The rule sees slots, table columns, the page's shape and the
+        pool's dtype, and counts bytes as laid out (a 64-wide minor
+        dimension fills 128 lanes, 16 int8 rows a 32-row tile): ruDALL-E's
+        75.5 MB a buffer is one group, 12b's 244 MB three of 81 MB (its
+        int8 pool's the same), the latent pool's 178 MB two; a slot count
+        with no divisor that fits falls to one slot a group and does not
+        raise."""
+        groups = decode_ops.view_slot_groups(slots, columns, page, dtype)
+        assert groups == want
+        assert slots % groups == 0
+        slot_bytes = columns * self._laid_out(page, dtype)
+        if groups < slots:
+            assert slots // groups * slot_bytes <= decode_ops._VIEW_VMEM_BYTES
+        if groups > 1:       # and one group fewer would not have fitted
+            fewer = max(g for g in range(1, groups) if slots % g == 0)
+            assert slots // fewer * slot_bytes > decode_ops._VIEW_VMEM_BYTES
+
+    def _loop_args(self, bundle, page_size, quantized, cfg=CFG):
         """A mid-sequence chunk: 3 slots at ragged positions (one parked
         dead), random page content everywhere, greedy sampling through
         the model's own embedding and logits head."""
         params, _ = bundle
-        tcfg = CFG.transformer
-        L = CFG.seq_len
+        tcfg = cfg.transformer
+        L = cfg.seq_len
         mp = KV.pages_for(L, page_size)
         pool = _random_pool(jax.random.PRNGKey(21), page_size,
                             3 * mp + 1, quantized)
@@ -458,7 +568,7 @@ class TestPerLayerRead:
         cur = jnp.asarray([3, 7, 0], jnp.int32)
 
         def embed_fn(tok, p):
-            return D.decode_token_embed(params, CFG, tok, p)
+            return D.decode_token_embed(params, cfg, tok, p)
 
         def sample_fn(h, pred_pos):
             return jnp.argmax(D.to_logits(params, h), -1).astype(jnp.int32)
@@ -495,6 +605,58 @@ class TestPerLayerRead:
                 np.asarray(after[name][:, :2], np.float32),
                 np.asarray(dense[3][name][:, :2], np.float32),
                 rtol=1e-5, atol=1e-5)
+
+    @pytest.mark.parametrize("quantized", [False, True],
+                             ids=["f32", "int8"])
+    @pytest.mark.parametrize("sparse_reads", [False, True],
+                             ids=["dense_reads", "sparse_reads"])
+    def test_grouped_loop_tokens_identical_to_dense_loop(
+            self, monkeypatch, bundle, sparse_reads, quantized):
+        """ISSUE 31: with the rule forced to one slot a group (the
+        constant patched here, no knob in the program) the fused loop
+        still emits the dense loop's tokens, for the plain gather step
+        and for ``sparse_reads=True`` (whose sparse layers read a
+        narrower table through the same loop)."""
+        cfg = SPARSE_CFG if sparse_reads else CFG
+        if sparse_reads:
+            params = D.dalle_init(jax.random.PRNGKey(0), cfg, bundle[1])
+            bundle = (params, bundle[1])
+        tp, cur, pos, active, pool, bt, L, kw = self._loop_args(
+            bundle, 8, quantized, cfg)
+        dense = decode_ops.decode_loop(
+            tp, cur, pos, active, decode_ops.paged_view(pool, bt, L), **kw)
+        monkeypatch.setattr(decode_ops, "_VIEW_VMEM_BYTES", 1)
+        assert decode_ops.pool_view_groups(pool, *bt.shape) == 3
+        paged = decode_ops.decode_loop_paged(
+            tp, cur, pos, active, pool, bt, total_len=L,
+            sparse_reads=sparse_reads, **kw)
+        np.testing.assert_array_equal(np.asarray(paged[4]),
+                                      np.asarray(dense[4]))
+        assert (np.asarray(paged[4])[:2] >= 0).all()   # real tokens
+        for i in range(3):                             # tok, pos, active
+            np.testing.assert_array_equal(np.asarray(paged[i]),
+                                          np.asarray(dense[i]))
+
+    @pytest.mark.parametrize("budget,want", [(None, 1), (1, 2)],
+                             ids=["the_rule", "one_slot_a_group"])
+    def test_engine_reports_the_groups_it_traced(self, monkeypatch,
+                                                 bundle, budget, want):
+        """``stats()["kv_view_groups"]``: the group count the decode
+        program was traced with; the served tokens do not depend on it."""
+        params, vae_params = bundle
+        if budget is not None:
+            monkeypatch.setattr(decode_ops, "_VIEW_VMEM_BYTES", budget)
+        queue = RequestQueue(max_depth=4)
+        engine = Engine(params, CFG, queue, num_slots=2, chunk_steps=4,
+                        kv="paged", page_size=8)
+        assert engine.stats()["kv_view_groups"] == 1   # nothing traced yet
+        h = queue.submit(REQS[0])
+        engine.run_until_idle()
+        np.testing.assert_array_equal(
+            np.asarray(h.result(5).tokens),
+            reference_tokens(params, vae_params, REQS[0]))
+        assert engine.stats()["kv_view_groups"] == want
+        assert engine.decode_traces == 1
 
     def test_decode_program_holds_no_second_pool(self, bundle):
         """The mechanism, not the speed: the compiled gather loop's
