@@ -87,7 +87,7 @@ class DALLEConfig:
     # and axial positions (ops.transformer.LatentMoEBlock): rotary
     # positions inside the block, so no position tables; an untied,
     # bias-free head behind an RMSNorm. Served, not trained.
-    block: Optional[T.LatentMoEBlock] = None
+    block: Optional[Union[T.LatentMoEBlock, T.WindowGQABlock]] = None
 
     @property
     def image_seq_len(self) -> int:
@@ -239,7 +239,16 @@ def embed_prompt(params: dict, cfg: DALLEConfig, text: Array,
         if learned:
             img = img + image_pos_emb(params, cfg, jnp.arange(n_img))[None]
         tok = jnp.concatenate([tok, img], axis=1)
-    return tok
+    return _block_embed_scale(cfg, tok)
+
+
+def _block_embed_scale(cfg: DALLEConfig, tok: Array) -> Array:
+    """A described block may take its token embeddings times a constant
+    (``embed_scale``: the square root of the width, where the model was
+    parameterised so)."""
+    if cfg.block is None or cfg.block.embed_scale == 1.0:
+        return tok
+    return tok * jnp.asarray(cfg.block.embed_scale, tok.dtype)
 
 
 @jax.named_scope("embed")
@@ -266,12 +275,19 @@ def decode_token_embed(params: dict, cfg: DALLEConfig, cur_tok: Array,
     is_text = pos < cfg.text_seq_len
     if pos.ndim:
         is_text = is_text[:, None]
-    return jnp.where(is_text, text_e, img_e)
+    return _block_embed_scale(cfg, jnp.where(is_text, text_e, img_e))
 
 
 @jax.named_scope("head")
-def to_logits(params: dict, h: Array) -> Array:
-    h = core.norm(params["to_logits"]["ln"], h)
+def to_logits(params: dict, h: Array,
+              cfg: Optional[DALLEConfig] = None) -> Array:
+    """The head behind its norm; a described block's configuration says
+    the norm's epsilon (without one it is the norm's default)."""
+    ln = params["to_logits"]["ln"]
+    if cfg is not None and cfg.block is not None:
+        h = core.rmsnorm(ln, h, eps=cfg.block.norm_eps)
+    else:
+        h = core.norm(ln, h)
     return core.linear(params["to_logits"]["proj"], h)
 
 
@@ -309,7 +325,8 @@ def quantize_for_decode(params: dict) -> dict:
     the result."""
     from dalle_pytorch_tpu.ops import quant
     if T.is_block_params(params["transformer"]):
-        raise T.BlockOptionError(T.LatentMoEBlock.name, "--quantize int8*")
+        raise T.BlockOptionError(T.block_name_of(params["transformer"]),
+                                 "--quantize int8*")
     out = dict(params)
     out["transformer"] = quant.quantize_tree_int8(params["transformer"])
     out["to_logits"] = quant.quantize_tree_int8(params["to_logits"])
@@ -355,7 +372,7 @@ def dalle_apply(params: dict, text: Array, image=None, *, cfg: DALLEConfig,
 
     if not return_loss:
         with jax.named_scope("head"):
-            logits = to_logits(params, h)
+            logits = to_logits(params, h, cfg)
             forbidden = logits_mask(cfg)[:seq_len]
             return jnp.where(forbidden[None], core.neg_inf(logits.dtype),
                              logits)

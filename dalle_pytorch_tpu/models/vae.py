@@ -51,10 +51,15 @@ class VAEConfig:
     straight_through: bool = False
 
     def __post_init__(self):
-        if not math.log2(self.image_size).is_integer():
-            raise ValueError("image size must be a power of 2")
         if self.num_layers < 1:
             raise ValueError("number of layers must be >= 1")
+        # each layer halves the side: what the grid needs is whole
+        # halvings (the reference asks for a power of 2; a 768-pixel
+        # image is 96 x 96 codes at 8 x 8 compression)
+        if self.image_size < 1 or self.image_size % 2 ** self.num_layers:
+            raise ValueError(
+                f"image size must be a multiple of 2**num_layers = "
+                f"{2 ** self.num_layers}, got {self.image_size}")
 
     @property
     def grid_size(self) -> int:

@@ -27,13 +27,17 @@ def build(force: bool = False, quiet: bool = False) -> str:
     cxx = os.environ.get("CXX") or shutil.which("g++") or shutil.which("c++")
     if cxx is None:
         raise RuntimeError("no C++ compiler found (set CXX)")
+    # a temporary name of this process's own: the test workers of a fresh
+    # checkout all build at once, and one shared name let a worker install
+    # (or find gone) the file another was still writing
+    tmp = f"{LIB}.{os.getpid()}.tmp"
     cmd = [cxx, "-O3", "-std=c++17", "-shared", "-fPIC", SRC,
-           "-o", LIB + ".tmp", "-ljpeg", "-lpng", "-pthread"]
+           "-o", tmp, "-ljpeg", "-lpng", "-pthread"]
     proc = subprocess.run(cmd, capture_output=True, text=True)
     if proc.returncode != 0:
         raise RuntimeError(
             f"native loader build failed:\n{' '.join(cmd)}\n{proc.stderr}")
-    os.replace(LIB + ".tmp", LIB)
+    os.replace(tmp, LIB)
     if not quiet:
         print(f"built {LIB}")
     return LIB

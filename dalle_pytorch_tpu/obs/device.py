@@ -37,6 +37,9 @@ SCOPES = (
     "paged_attn",       # the ragged paged-attention kernel (inside attn.read)
     "kv.view",          # page pool / cache -> per-slot rows
     "kv.store",         # new rows -> cache or pool
+    "kv.window",        # a window layer's pool: its ring's page gather and
+                        # the new rows' store
+    "attn.window",      # a window layer's read of the gathered ring
     "ff",               # the GEGLU block (or its capacity-MoE stand-in),
                         # a described block's dense SiLU-gated layer
     "moe.route",        # dropless routing: router, top-k, sort, combine

@@ -280,3 +280,161 @@ def latent_attend_absorbed(params: dict, q_nope: Array, q_rope: Array,
 def latent_out(params: dict, o: Array) -> Array:
     """(..., heads, dv) -> (..., dim): the output projection."""
     return core.linear(params["out"], o.reshape(o.shape[:-2] + (-1,)))
+
+
+# ---------------------------------------------------------------------------
+# grouped-query, gated attention over a window or the whole sequence (the
+# ``WindowGQABlock`` of ops/transformer.py)
+# ---------------------------------------------------------------------------
+#
+# ``heads`` query heads read ``kv_heads`` key/value heads: query head i
+# reads head ``i // (heads / kv_heads)``, so the query heads of one group
+# lie side by side and (..., heads, d) reshapes to (..., kv_heads, group,
+# d) as it lies. A token caches one K and one V row a layer, every
+# key/value head's numbers side by side in it. The reads differ in where
+# the rows lie (a sequence at once: prefill and the full forward, a whole
+# group against its head's rows in one product; gathered pages: decode,
+# whole rows against block-diagonal queries) and share the mask semantics:
+# ``allowed`` says which rows, and a sliding layer's says the window too.
+
+def rope_half(x: Array, positions: Array, theta: float) -> Array:
+    """Rotary positions with the rotate-half pairing: (x[i], x[i + d/2])
+    turned by ``positions * theta ** (-2i / d)``. ``positions``
+    broadcasts against ``x.shape[:-1]``. Angles and the rotation in f32."""
+    d = x.shape[-1]
+    inv_freq = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    ang = jnp.asarray(positions, jnp.float32)[..., None] * inv_freq
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    xf = x.astype(jnp.float32)
+    a, b = xf[..., :d // 2], xf[..., d // 2:]
+    out = jnp.concatenate([a * cos - b * sin, b * cos + a * sin], axis=-1)
+    return out.astype(x.dtype)
+
+
+def gqa_init(key: Array, dim: int, heads: int, blk,
+             dtype=jnp.float32) -> dict:
+    """No biases; the norms over a query and a key head have one gain
+    vector for all heads."""
+    ks = jax.random.split(key, 5)
+    dh, kvh = blk.head_dim, blk.kv_heads
+    return {
+        "q": core.linear_init(ks[0], dim, heads * dh, bias=False,
+                              dtype=dtype),
+        "k": core.linear_init(ks[1], dim, kvh * dh, bias=False, dtype=dtype),
+        "v": core.linear_init(ks[2], dim, kvh * dh, bias=False, dtype=dtype),
+        "gate": core.linear_init(ks[3], dim, heads * dh, bias=False,
+                                 dtype=dtype),
+        "q_ln": core.rmsnorm_init(dh, dtype),
+        "k_ln": core.rmsnorm_init(dh, dtype),
+        "out": core.linear_init(ks[4], heads * dh, dim, bias=False,
+                                dtype=dtype),
+    }
+
+
+def gqa_project(params: dict, h: Array, positions: Array, heads: int, blk,
+                rotary: bool):
+    """h (..., dim) normed input, ``positions`` broadcastable to
+    ``h.shape[:-1]`` -> (q (..., heads, dh), gate (..., heads * dh), (k,
+    v) each (..., kv_heads, dh): the rows to cache). Query and key heads
+    are normed; ``rotary`` (a sliding layer) turns them by position, a
+    full layer's carry no position."""
+    dh, kvh = blk.head_dim, blk.kv_heads
+    lead = h.shape[:-1]
+    with jax.named_scope("attn.proj"):
+        q = core.linear(params["q"], h).reshape(lead + (heads, dh))
+        k = core.linear(params["k"], h).reshape(lead + (kvh, dh))
+        v = core.linear(params["v"], h).reshape(lead + (kvh, dh))
+        gate = core.linear(params["gate"], h)
+    q = core.rmsnorm(params["q_ln"], q, eps=blk.norm_eps)
+    k = core.rmsnorm(params["k_ln"], k, eps=blk.norm_eps)
+    if rotary:
+        with jax.named_scope("attn.proj"):
+            at = jnp.asarray(positions)[..., None]
+            q = rope_half(q, at, blk.rope_theta)
+            k = rope_half(k, at, blk.rope_theta)
+    return q, gate, (k, v)
+
+
+def _read_scope(window: bool):
+    """A read's name in a trace: a window layer's or a full layer's."""
+    return jax.named_scope("attn.window") if window \
+        else jax.named_scope("attn.read")
+
+
+def gqa_attend_materialised(q: Array, k: Array, v: Array, allowed: Array,
+                            scale: float, window: bool) -> Array:
+    """The prefill read. q (b, n, heads, dh), k / v (b, m, kv_heads, dh),
+    allowed broadcastable to (b, 1, n, m) -> (b, n, heads, dh). ``window``
+    names the read in a trace (``_read_scope``); the window itself is in
+    ``allowed``."""
+    b, n, heads, dh = q.shape
+    kvh = k.shape[2]
+    with _read_scope(window):
+        qg = q.reshape(b, n, kvh, heads // kvh, dh)
+        dots = jnp.einsum("bikgd,bjkd->bkgij", qg, k,
+                          preferred_element_type=jnp.float32) * scale
+        dots = jnp.where(allowed[:, :, None], dots,
+                         core.neg_inf(dots.dtype))
+        w = jax.nn.softmax(dots, axis=-1).astype(v.dtype)
+        return jnp.einsum("bkgij,bjkd->bikgd", w, v).reshape(q.shape)
+
+
+def gqa_attend_rows(q: Array, k: Array, v: Array, rows_k: Array,
+                    gather_v, allowed: Array, scale: float,
+                    window: bool) -> Array:
+    """The decode read, one query a slot: q (b, heads, dh); k / v (b,
+    kv_heads, dh) the token's own rows (always attended); ``rows_k`` (b,
+    m, kv_heads * dh) the cached K rows as they lie in the gathered pages,
+    every key/value head's dh numbers side by side in a row, and
+    ``gather_v()`` the V rows the same way, asked for once K's readers are
+    done (the budget of a slot group is ONE gathered buffer: ops/decode.py
+    ``view_slot_groups``); ``allowed`` (b, m) -> (b, heads, dh).
+
+    The rows are contracted whole, all query heads at once, as the latent
+    block's absorbed read contracts its rows: a query head is given zeros
+    for the columns of the key/value heads it does not read (block
+    diagonal, ``_own_columns``), and of the weighted sum of whole V rows it
+    keeps its own head's columns. A contraction of one head's columns
+    alone (a batch dimension between page and row) is what the compiler
+    turned into a relayout of every gathered page (PERF.md section 6,
+    PR 33); the zeros cost matrix-unit passes that have no other use here.
+    ``window`` names the read in a trace."""
+    b, heads, dh = q.shape
+    kvh = k.shape[1]
+    qg = q.reshape(b, kvh, heads // kvh, dh)
+    with _read_scope(window):
+        scores = jnp.einsum("bhc,bjc->bhj", _own_columns(qg), rows_k,
+                            preferred_element_type=jnp.float32) * scale
+        scores = jnp.where(allowed[:, None, :], scores,
+                           core.neg_inf(scores.dtype))
+        own = jnp.einsum("bkgd,bkd->bkg", qg, k,
+                         preferred_element_type=jnp.float32) * scale
+        wts = jax.nn.softmax(jnp.concatenate(
+            [scores, own.reshape(b, heads, 1)], axis=-1), axis=-1)
+        wts = wts.astype(v.dtype)
+    rows_v = gather_v(wts)
+    with _read_scope(window):
+        o_rows = jnp.einsum("bhj,bjc->bhc", wts[..., :-1], rows_v)
+        # of each whole-row sum, the columns of the head's own kv head
+        o = jnp.einsum("bkgjd,kj->bkgd",
+                       o_rows.reshape(b, kvh, heads // kvh, kvh, dh),
+                       jnp.eye(kvh, dtype=o_rows.dtype))
+        o = o + wts[..., -1].reshape(qg.shape[:3] + (1,)) * v[:, :, None, :]
+        return o.reshape(b, heads, dh)
+
+
+def _own_columns(qg: Array) -> Array:
+    """(b, kv_heads, group, dh) -> (b, heads, kv_heads * dh): each query
+    head's dh numbers in the columns of its own key/value head, zeros in
+    the others'."""
+    b, kvh, g, dh = qg.shape
+    wide = jnp.einsum("bkgd,kj->bkgjd", qg, jnp.eye(kvh, dtype=qg.dtype))
+    return wide.reshape(b, kvh * g, kvh * dh)
+
+
+@jax.named_scope("attn.proj")
+def gqa_out(params: dict, o: Array, gate: Array) -> Array:
+    """(..., heads, dh), gate (..., heads * dh) -> (..., dim): the output
+    gate and the output projection."""
+    o = o.reshape(o.shape[:-2] + (-1,))
+    return core.linear(params["out"], o * jax.nn.sigmoid(gate))

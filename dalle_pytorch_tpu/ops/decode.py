@@ -36,10 +36,12 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
 from dalle_pytorch_tpu.ops import attention as attn_ops
 from dalle_pytorch_tpu.ops import core, sparse
+from dalle_pytorch_tpu.ops.moe import load_width
 
 Array = jax.Array
 
@@ -75,7 +77,7 @@ def view_slot_groups(slots: int, columns: int, page_shape, dtype) -> int:
 def pool_view_groups(pool: dict, slots: int, columns: int) -> int:
     """``view_slot_groups`` of a page pool read through a table of
     (slots, columns), trimmed as the read trims it."""
-    buf = pool["latent"] if "latent" in pool else pool["k"]
+    buf = pool["latent"] if "latent" in pool else next(iter(pool.values()))
     return view_slot_groups(slots, columns, buf.shape[2:], buf.dtype)
 
 
@@ -335,8 +337,16 @@ def _view_tables(block_tables: Array, total_len: int,
     return block_tables[:, :-(-total_len // page_size)]   # pages_for
 
 
-@jax.named_scope("kv.view")
-def layer_pool_view(buf: Array, layer: Array, tables: Array) -> Array:
+def _pool_scope(window: bool):
+    """A pool access's name in a trace: a window pool's gather and store
+    go by ``kv.window``, every other pool's by ``kv.view`` / ``kv.store``
+    (this is the gather's)."""
+    return jax.named_scope("kv.window") if window \
+        else jax.named_scope("kv.view")
+
+
+def layer_pool_view(buf: Array, layer: Array, tables: Array,
+                    window: bool = False) -> Array:
     """ONE layer's pages of one pool buffer through the block tables, read
     where they lie: ``buf`` (depth, P, ...page) is a pool's ``k`` or ``v``
     (page = heads, ps, dh), an int8 pool's ``k_scale`` / ``v_scale``
@@ -358,9 +368,13 @@ def layer_pool_view(buf: Array, layer: Array, tables: Array) -> Array:
         slot-major ``moveaxis`` + ``reshape`` of ``paged_view`` is what
         turned the read into transposing copies of the pool;
       * ``mode='clip'``: tables are in range by construction, and the
-        default fill mode adds a select over every gathered row."""
-    return jnp.take(buf.reshape((-1,) + buf.shape[2:]),
-                    layer * buf.shape[1] + tables, axis=0, mode="clip")
+        default fill mode adds a select over every gathered row.
+
+    ``window`` names the gather in a trace ``kv.window``: a window pool's
+    (its pages are a ring, ``window_rows``)."""
+    with _pool_scope(window):
+        return jnp.take(buf.reshape((-1,) + buf.shape[2:]),
+                        layer * buf.shape[1] + tables, axis=0, mode="clip")
 
 
 def _paged_gather_read(pool: dict, layer: Array, tables: Array, q: Array,
@@ -470,13 +484,16 @@ def prefill(params: dict, x: Array, *, cfg, total_len: int,
     from dalle_pytorch_tpu.ops import transformer as T
     b, t0, _ = x.shape
     if cfg.block is not None:
-        # the described block's prefill IS its full forward (the
+        # a described block's prefill IS its full forward (the
         # materialised read); the cache it returns is the prompt's rows
-        # alone, {"latent": (depth, b, t0, row_width)}: its store is the
-        # page pool, and the engine's admission writes whole pages
+        # alone, {"latent": (depth, b, t0, row_width)} or {"k", "v":
+        # (depth, b, t0, kv_heads, head_dim)}: its store is the page
+        # pool, and the engine's admission writes whole pages
         if quantize_cache:
             _refuse_block(cfg, "quantize_cache")
         h_out, entries, _ = T.block_apply_full(params, x, cfg, prompt_mask)
+        if isinstance(cfg.block, T.WindowGQABlock):
+            return h_out, {"k": entries[0], "v": entries[1]}
         return h_out, {"latent": entries}
     sparse_flags = jnp.asarray(cfg.sparse_pattern)
     any_sparse = any(cfg.sparse_pattern)
@@ -1011,73 +1028,209 @@ def _store_rows_paged(pool: dict, ks: Array, vs: Array, pos: Array,
     return {"k": put(pool["k"], ks), "v": put(pool["v"], vs)}
 
 
-@jax.named_scope("kv.store")
-def _store_entries_paged(pool: dict, entries: Array, pos: Array,
-                         block_tables: Array, active: Array) -> dict:
-    """``_store_rows_paged`` for a latent pool: slot i's new row of every
-    layer, ``entries`` (depth, b, width), lands in physical page
-    ``block_tables[i, pos[i] // page_size]`` at offset ``pos[i] %
-    page_size`` (the trash page for an inactive slot), by one in-place
-    update a slot: the pool keeps the layout in which a page is one
-    contiguous run."""
-    buf = pool["latent"]                       # (depth, P, ps, width)
-    ps = buf.shape[2]
-    bidx = jnp.arange(pos.shape[0])
-    page = jnp.where(active, block_tables[bidx, pos // ps], 0)
-    off = jnp.where(active, pos % ps, 0)
-    for i in range(pos.shape[0]):
-        buf = lax.dynamic_update_slice(
-            buf, entries[:, i][:, None, None, :], (0, page[i], off[i], 0))
-    return {"latent": buf}
+def _store_entries_paged(pool: dict, entries: dict, pos: Array,
+                         block_tables: Array, active: Array,
+                         ring: bool = False) -> dict:
+    """``_store_rows_paged`` for pools of whole rows (a latent pool; a
+    grouped-query block's K and V rows): slot i's new row of every layer,
+    ``entries[name]`` (layers, b, width), lands in ``pool[name]`` (layers,
+    P, ps, width) in physical page ``block_tables[i, pos[i] //
+    page_size]`` at offset ``pos[i] % page_size`` (the trash page for an
+    inactive slot), by one in-place update a slot a buffer: the pool keeps
+    the layout in which a page is one contiguous run. A window pool's
+    table is a ``ring`` of its columns: the column is ``pos[i] //
+    page_size`` modulo their number, and the store goes by ``kv.window``
+    in a trace."""
+    with jax.named_scope("kv.window") if ring \
+            else jax.named_scope("kv.store"):
+        ps = next(iter(pool.values())).shape[2]
+        bidx = jnp.arange(pos.shape[0])
+        column = pos // ps
+        if ring:
+            column = column % block_tables.shape[1]
+        page = jnp.where(active, block_tables[bidx, column], 0)
+        off = jnp.where(active, pos % ps, 0)
+        out = {}
+        for name, buf in pool.items():      # (layers, P, ps, width)
+            for i in range(pos.shape[0]):
+                buf = lax.dynamic_update_slice(
+                    buf, entries[name][:, i][:, None, None, :],
+                    (0, page[i], off[i], 0))
+            out[name] = buf
+        return out
 
 
-def decode_step_block(params: dict, x_tok: Array, pos: Array, pool: dict,
-                      block_tables: Array, *, cfg, key_mask: Array,
-                      active: Array) -> Tuple[Array, dict, Array]:
-    """One token a slot through a described block (``cfg.block``) against
-    its latent page pool: ``decode_step_paged``'s gather step with the
-    block's branches (``ops.transformer.block_layer``) and the ABSORBED
-    read. Inside the layer scan each layer gathers its own pages through
-    the tables (``layer_pool_view``), a slot group at a time (the groups
-    are decided by ``view_slot_groups``, the classic step's rule, and
-    looped by ``_read_in_slot_groups``), merges them to logical row order
-    (a bitcast when the page is whole sublane tiles: no head axis lies
-    between page and row) and contracts them as they lie, every head
-    against the same rows. The new rows are written after the scan.
-    x_tok (b, dim), pos (b,) -> (h_out (b, dim), pool, load (3,) int32:
-    the routed layers' load summed over them, ops.moe.dropless_apply)."""
+def window_rows(pos: Array, rows: int, window: int) -> Array:
+    """Which position each row of a slot's window ring holds: the ring is
+    ``rows`` long, position p is written at row ``p % rows``, so at
+    position ``pos`` (b,) row r holds the latest position before ``pos``
+    that is r modulo ``rows``. -> (position (b, rows), negative where the
+    row was never written; in_window (b, rows): written and less than
+    ``window`` behind ``pos``). A row is overwritten ``rows`` positions
+    after it was written, when it has left the window
+    (``WindowGQABlock.ring_pages``)."""
+    # the turn of the ring that ``pos - 1`` lies in, a division a slot;
+    # a row behind ``pos - 1`` on that turn holds it, one ahead of it
+    # still holds the turn before
+    last = pos[:, None] - 1
+    turn = jnp.floor_divide(last, rows) * rows + jnp.arange(rows)[None, :]
+    held = jnp.where(turn <= last, turn, turn - rows)
+    return held, (held >= 0) & (pos[:, None] - held < window)
+
+
+def ring_key_mask(key_mask: Array, held: Array) -> Array:
+    """``key_mask`` (b, total_len) at the positions ``held`` (b, rows)
+    that a ring's rows hold (``window_rows``; False where negative). Row r
+    only ever holds the positions r, r + rows, ...: one select a turn of
+    the ring over whole rows, where a gather of each row's own position
+    was 0.66 ms of an 8.2 ms step at 16 x 4112 rows (PERF.md section 6,
+    PR 33)."""
+    b, rows = held.shape
+    total_len = key_mask.shape[1]
+    turns = -(-total_len // rows)
+    by_turn = jnp.pad(key_mask, (
+        (0, 0), (0, turns * rows - total_len))).reshape(b, turns, rows)
+    ok = jnp.zeros(held.shape, bool)
+    for t in range(turns):
+        ok = ok | ((held >= t * rows) & (held < (t + 1) * rows)
+                   & by_turn[:, t])
+    return ok
+
+
+def _block_reads(cfg, pool: dict, block_tables, pos: Array,
+                 key_mask: Array):
+    """The paged reads of a described block's decode step, one query a
+    slot: -> ``read_of(layer, run)``, which gives ``block_layer`` its
+    read for the layer ``layer`` (traced, its index in its pool) of the
+    run ``run``. Each read gathers its layer's pages through the tables
+    (``layer_pool_view``), a slot group at a time (the groups are decided
+    by ``view_slot_groups``, the classic step's rule, and looped by
+    ``_read_in_slot_groups``), and contracts them as they lie."""
     from dalle_pytorch_tpu.ops import transformer as T
     blk = cfg.block
     total_len = key_mask.shape[1]
-    ps = pool["latent"].shape[2]
-    tables = _view_tables(block_tables, total_len, ps)
-    rows_len = tables.shape[1] * ps
 
-    with jax.named_scope("attn.read"):       # the mask
-        j = jnp.arange(rows_len)
-        # strictly-before rows (self is the read's own extra logit); rows
-        # past total_len on a partial last page are dead
-        allowed = (j[None, :] < pos[:, None]) & jnp.pad(
+    def before(tables, ps):
+        """(b, w * ps) rows strictly before ``pos`` (self is each read's
+        own extra logit); rows past total_len on a partial last page are
+        dead."""
+        rows_len = tables.shape[1] * ps
+        return (jnp.arange(rows_len)[None, :] < pos[:, None]) & jnp.pad(
             key_mask, ((0, 0), (0, rows_len - total_len)))
 
-    def layer_fn(lp, h, layer, moe):
-        def read(p, q_nope, q_rope, entry):
-            # all slots' pages at once miss VMEM at the published widths
-            # (178 MB), so they are read a slot group at a time
-            def read_group(sl):
-                with jax.named_scope("kv.view"):
-                    pages = layer_pool_view(pool["latent"], layer,
-                                            tables[sl])
-                    rows = pages.reshape(pages.shape[0], rows_len, -1)
-                return attn_ops.latent_attend_absorbed(
-                    p, q_nope[sl], q_rope[sl], rows, allowed[sl], entry[sl],
-                    blk, cfg.scale)
-            return _read_in_slot_groups(pool, tables, read_group)
-        return T.block_layer(lp, h, pos, read, cfg, moe)
+    def rows_of(name, layer, t, window=False):
+        """One slot group's pages of a buffer of whole rows, merged to
+        rows in the order they were gathered: a bitcast when the page is
+        whole sublane tiles (no head axis lies between page and row)."""
+        pages = layer_pool_view(pool[name], layer, t, window)
+        with _pool_scope(window):
+            return pages.reshape(pages.shape[0], -1, pages.shape[-1])
 
-    h_out, (entries, loads) = T.block_stack(params, x_tok, layer_fn)
-    return (h_out, _store_entries_paged(pool, entries, pos, block_tables,
-                                        active), jnp.sum(loads, axis=0))
+    ps = next(iter(pool.values())).shape[2]
+    if isinstance(blk, T.LatentMoEBlock):
+        tables = _view_tables(block_tables, total_len, ps)
+        with jax.named_scope("attn.read"):       # the mask
+            allowed = before(tables, ps)
+
+        def read_of(layer, _run):
+            def read(p, query, entry):
+                # all slots' pages at once miss VMEM at the published
+                # widths (178 MB), so they are read a slot group at a time
+                def read_group(sl):
+                    return attn_ops.latent_attend_absorbed(
+                        p, query[0][sl], query[1][sl],
+                        rows_of("latent", layer, tables[sl]), allowed[sl],
+                        entry[sl], blk, cfg.scale)
+                return _read_in_slot_groups(pool, tables, read_group)
+            return read
+        return read_of
+
+    # the window-and-full block: a pool and a table a layer type. A full
+    # layer's table is as wide as the sequence and its rows lie in order;
+    # a window layer's is a ring of ``ring_pages`` columns
+    full_t = _view_tables(block_tables["full"], total_len, ps)
+    ring_t = block_tables["window"]
+    with jax.named_scope("attn.read"):
+        full_ok = before(full_t, ps)
+    with jax.named_scope("attn.window"):
+        held, ring_ok = window_rows(pos, ring_t.shape[1] * ps, blk.window)
+        ring_ok = ring_ok & ring_key_mask(key_mask, held)
+
+    def read_of(layer, run):
+        window = not run.full
+        k_name, v_name = blk.pool_buffers(run.full)
+        tables, allowed = (ring_t, ring_ok) if window else (full_t, full_ok)
+
+        def read(_p, q, entry):
+            def read_group(sl):
+                t = tables[sl]
+
+                def gather_v(wts):
+                    # among several groups the scheduler was seen to lift
+                    # one group's V gather above its K gather
+                    # (``_paged_gather_read``): there the order is stated
+                    tv = t
+                    if t.shape[0] < tables.shape[0]:
+                        _, tv = lax.optimization_barrier((wts, t))
+                    return rows_of(v_name, layer, tv, window)
+
+                return attn_ops.gqa_attend_rows(
+                    q[sl], entry[0][sl], entry[1][sl],
+                    rows_of(k_name, layer, t, window), gather_v,
+                    allowed[sl], cfg.scale, window)
+            return _read_in_slot_groups(pool, tables, read_group)
+        return read
+    return read_of
+
+
+def _store_block_rows(cfg, pool: dict, entries, pos: Array, block_tables,
+                      active: Array) -> dict:
+    """A decode step's new rows of every layer into a described block's
+    pool(s) (``_store_entries_paged``)."""
+    from dalle_pytorch_tpu.ops import transformer as T
+    blk = cfg.block
+    if isinstance(blk, T.LatentMoEBlock):
+        return _store_entries_paged(pool, {"latent": entries}, pos,
+                                    block_tables, active)
+    # (depth, b, kv_heads, dh) -> (depth, b, kv_heads * dh): a row
+    rows = [e.reshape(e.shape[:2] + (-1,)) for e in entries]
+    out = {}
+    for full, table in ((True, block_tables["full"]),
+                        (False, block_tables["window"])):
+        # jaxlint: disable=JL001 — the layers of one type, static
+        # configuration: a trace-time const
+        layers = np.asarray(blk.cache_layers(full), np.int32)
+        if layers.size:
+            names = blk.pool_buffers(full)
+            out.update(_store_entries_paged(
+                {n: pool[n] for n in names},
+                {n: r[layers] for n, r in zip(names, rows)},
+                pos, table, active, ring=not full))
+    return out
+
+
+def decode_step_block(params: dict, x_tok: Array, pos: Array, pool: dict,
+                      block_tables, *, cfg, key_mask: Array,
+                      active: Array) -> Tuple[Array, dict, Array]:
+    """One token a slot through a described block (``cfg.block``) against
+    its page pool(s): ``decode_step_paged``'s gather step with the block's
+    branches (``ops.transformer.block_layer``) and the block's paged read
+    (``_block_reads``): the latent block's ABSORBED read of its one latent
+    pool, or the window-and-full block's grouped-query reads, a full
+    layer's of the full pool in row order and a window layer's of the
+    window pool's ring (``block_tables`` is then ``{"full": ...,
+    "window": ...}``). The new rows are written after the scan.
+    x_tok (b, dim), pos (b,) -> (h_out (b, dim), pool, load int32: the
+    routed layers' load summed over them, ops.moe.dropless_apply)."""
+    from dalle_pytorch_tpu.ops import transformer as T
+    read_of = _block_reads(cfg, pool, block_tables, pos, key_mask)
+
+    def layer_fn(lp, h, layer, run):
+        return T.block_layer(lp, h, pos, read_of(layer, run), cfg, run)
+
+    h_out, (entries, loads) = T.block_stack(params, x_tok, layer_fn, cfg)
+    return (h_out, _store_block_rows(cfg, pool, entries, pos, block_tables,
+                                     active), jnp.sum(loads, axis=0))
 
 
 def decode_step_paged(params: dict, x_tok: Array, pos: Array, pool: dict,
@@ -1132,8 +1285,10 @@ def decode_loop_paged(params: dict, cur_tok: Array, pos: Array,
 
     A described block (``cfg.block``) runs the gather step with its own
     branches (``decode_step_block``) and the program returns one value
-    more, after the ring: the routed layers' load (3,) int32 summed over
-    the chunk's steps, for the engine to fetch with the ring."""
+    more, after the ring: the routed layers' load (``ops.moe.load_width``
+    int32s) summed over the chunk's steps, for the engine to fetch with
+    the ring; its ``block_tables`` are what ``decode_step_block`` takes
+    (a table a pool for a window-and-full block)."""
     blk = cfg.block
     if blk is not None:
         for option, on in (("paged_attn='kernel'", attn_impl != "gather"),
@@ -1163,7 +1318,8 @@ def decode_loop_paged(params: dict, cur_tok: Array, pos: Array,
         pos = jnp.where(act, pos, 0)
         return (cur_tok, pos, act, pool, *load), emit
 
-    load0 = () if blk is None else (jnp.zeros((3,), jnp.int32),)
+    load0 = () if blk is None else (
+        jnp.zeros((load_width(blk),), jnp.int32),)
     (cur_tok, pos, active, pool, *load), emits = lax.scan(
         one_step, (cur_tok, pos, active, pool, *load0), None, length=steps)
     return (cur_tok, pos, active, pool, jnp.moveaxis(emits, 0, 1), *load)

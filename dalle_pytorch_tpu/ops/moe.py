@@ -12,7 +12,12 @@ with their weights, shared experts added. No capacity exists and no
 token is dropped, whatever the load: the same function serves a
 prefill's thousands of tokens and a decode step's few dozen. It returns
 its load (picks routed, experts touched, the fullest expert's picks) for
-the engine's counters.
+the engine's counters. A block may HOLD a share of its experts (one chip
+of an expert-parallel deployment: ``blk.first_expert``,
+``blk.experts_held``): the router still scores and picks among all of
+them, the pairs whose expert is held elsewhere lie in no group and add
+nothing, and the load says how many picks were held. Nothing stands in
+for the absent chips: their part of the sum is left out.
 
 **Capacity routing** (``moe_apply``; the trainable ``moe_experts`` option
 of the classic block, expert-parallel over an ``ep`` axis): the standard
@@ -144,17 +149,33 @@ def moe_param_specs(axis: str = "ep") -> dict:
 # dropless routing (served): sort by expert, grouped matrix products
 # ---------------------------------------------------------------------------
 
+def holds_all(blk) -> bool:
+    """Whether every routed expert of the block is held here."""
+    return blk.experts_held == blk.num_experts
+
+
+def load_width(blk) -> int:
+    """Entries of a routed layer's load: picks routed, experts touched,
+    the fullest expert's picks; and, where a share is held, the picks
+    that fell on it."""
+    return 3 if holds_all(blk) else 4
+
+
 def dropless_init(key: Array, dim: int, blk, dtype=jnp.float32) -> dict:
-    """Router (with its selection bias), expert-stacked SiLU-gated units
-    (gate and up side by side in ``w_in``) and the shared unit."""
+    """Router (with its selection bias) over every expert, the HELD
+    experts' stacked SiLU-gated units (gate and up side by side in
+    ``w_in``) and the shared unit."""
     k_r, k_in, k_out, k_s = jax.random.split(key, 4)
     e, he = blk.num_experts, blk.expert_hidden
+    held = blk.experts_held
     return {
         "router": {"w": core.uniform_fan_in(k_r, (dim, e), dim, dtype),
                    "bias": jnp.zeros((e,), jnp.float32)},
         "experts": {
-            "w_in": core.uniform_fan_in(k_in, (e, dim, 2 * he), dim, dtype),
-            "w_out": core.uniform_fan_in(k_out, (e, he, dim), he, dtype)},
+            "w_in": core.uniform_fan_in(k_in, (held, dim, 2 * he), dim,
+                                        dtype),
+            "w_out": core.uniform_fan_in(k_out, (held, he, dim), he,
+                                         dtype)},
         "shared": core.swiglu_init(k_s, dim, blk.shared_hidden, dtype),
     }
 
@@ -172,10 +193,16 @@ def route(router: dict, x: Array, k: int, scale: float):
 
 
 def dropless_experts(experts: dict, x: Array, picks: Array,
-                     weights: Array):
+                     weights: Array, first: Optional[int] = None):
     """Every (token, pick) pair through its expert, summed per token with
     its weight. x (t, dim), picks / weights (t, k) -> (out (t, dim),
-    sizes (E,) int32: picks an expert received).
+    sizes (E,) int32: picks each HELD expert received).
+
+    ``first`` is None where the E experts of ``experts`` are all that the
+    picks name. Where they are a share, ``first`` .. ``first + E`` of
+    them, a pair whose expert is held elsewhere is sorted behind every
+    group and lies in none: the grouped products pass its row by and it
+    adds nothing.
 
     ``experts`` holds ``w_in`` (E, dim, 2 * hidden) and ``w_out``; or, from
     a scanned stack (``ops.transformer.block_stack``), the WHOLE stack's
@@ -190,6 +217,11 @@ def dropless_experts(experts: dict, x: Array, picks: Array,
     e = w_in.shape[-3]
     with jax.named_scope("moe.route"):
         flat = picks.reshape(-1)
+        if first is not None:
+            # an absent pair's key is E: behind the last group, and out
+            # of range of ``sizes`` (a scatter drops such an index)
+            here = (flat >= first) & (flat < first + e)
+            flat = jnp.where(here, flat - first, e)
         order = jnp.argsort(flat, stable=True)      # pairs, by expert
         sizes = jnp.zeros((e,), jnp.int32).at[flat].add(1)
         rows = jnp.take(x, order // k, axis=0)      # (t * k, dim)
@@ -209,6 +241,9 @@ def dropless_experts(experts: dict, x: Array, picks: Array,
         # each pair's weight, in f32, here: the compiler's grouped-product
         # kernel carries no scope of its own and takes its first reader's
         out = out.astype(jnp.float32) * pair_weights[:, None]
+        if first is not None:
+            # rows behind the last group are no product's output
+            out = jnp.where((jnp.take(flat, order) < e)[:, None], out, 0.0)
     with jax.named_scope("moe.route"):
         # back to (token, pick) order, and the sum over a token's picks
         out = jnp.take(out, jnp.argsort(order), axis=0).reshape(t, k, -1)
@@ -217,15 +252,22 @@ def dropless_experts(experts: dict, x: Array, picks: Array,
 
 
 def dropless_apply(params: dict, x: Array, blk):
-    """x (..., dim) -> (out (..., dim), load (3,) int32: picks routed,
-    experts that received one, the fullest expert's picks)."""
+    """x (..., dim) -> (out (..., dim), load (``load_width``,) int32:
+    picks routed, held experts that received one, the fullest held
+    expert's picks and, where a share is held, the picks that fell on
+    it)."""
     lead = x.shape[:-1]
     xt = x.reshape(-1, x.shape[-1])
     picks, weights = route(params["router"], xt, blk.experts_per_token,
                            blk.routed_scale)
-    out, sizes = dropless_experts(params["experts"], xt, picks, weights)
+    whole = holds_all(blk)
+    out, sizes = dropless_experts(params["experts"], xt, picks, weights,
+                                  None if whole else blk.first_expert)
     with jax.named_scope("moe.shared"):
         out = out + core.swiglu(params["shared"], xt)
-    load = jnp.stack([jnp.sum(sizes), jnp.sum(sizes > 0).astype(jnp.int32),
-                      jnp.max(sizes)])
-    return out.reshape(lead + (-1,)), load
+    held = jnp.sum(sizes)
+    load = [held if whole else jnp.int32(picks.size),
+            jnp.sum(sizes > 0).astype(jnp.int32), jnp.max(sizes)]
+    if not whole:
+        load.append(held)
+    return out.reshape(lead + (-1,)), jnp.stack(load)

@@ -56,6 +56,53 @@ class BlockOptionError(ValueError):
 
 
 @dataclasses.dataclass(frozen=True)
+class LayerKind:
+    """The two ways in which a described block's layers differ: a dense
+    or a routed feed-forward, and attention over every earlier row
+    (``full``) or over a window of them."""
+    moe: bool
+    full: bool
+
+
+@dataclasses.dataclass(frozen=True)
+class LayerRun:
+    """``count`` consecutive layers of one kind, scanned as one
+    (``block_stack``): ``at`` is the first one's index in its parameter
+    stack (``"moe"`` or ``"dense"``) and ``cache`` its index among the
+    layers of its attention type, which is its layer index in that type's
+    page pool."""
+    kind: LayerKind
+    count: int
+    at: int
+    cache: int
+
+    @property
+    def moe(self) -> bool:
+        return self.kind.moe
+
+    @property
+    def full(self) -> bool:
+        return self.kind.full
+
+
+def layer_runs(blk, depth: int) -> Tuple[LayerRun, ...]:
+    """The stack as runs of layers alike in both kinds, in the published
+    order: a deeper cut or a whole model is the same code with more runs."""
+    kinds = blk.layer_kinds(depth)
+    runs, i = [], 0
+    while i < depth:
+        j = i
+        while j < depth and kinds[j] == kinds[i]:
+            j += 1
+        runs.append(LayerRun(
+            kinds[i], j - i,
+            at=sum(k.moe == kinds[i].moe for k in kinds[:i]),
+            cache=sum(k.full == kinds[i].full for k in kinds[:i])))
+        i = j
+    return tuple(runs)
+
+
+@dataclasses.dataclass(frozen=True)
 class LatentMoEBlock:
     """A described block other than PreNorm LayerNorm + GEGLU + learned
     positions (``TransformerConfig.block``; None is the classic block):
@@ -81,6 +128,21 @@ class LatentMoEBlock:
     shared_hidden: int = 1536       # n_shared_experts x expert_hidden
     routed_scale: float = 2.448
     name: str = "latent_moe"
+    embed_scale = 1.0               # token embeddings enter as they are
+    first_expert = 0                # every routed expert is held here
+
+    @property
+    def experts_held(self) -> int:
+        return self.num_experts
+
+    @property
+    def score_dim(self) -> int:
+        """The width whose inverse root scales the scores."""
+        return self.qk_nope_dim + self.qk_rope_dim
+
+    def layer_kinds(self, depth: int) -> Tuple["LayerKind", ...]:
+        return tuple(LayerKind(moe=i >= self.dense_layers, full=True)
+                     for i in range(depth))
 
     @property
     def entry_width(self) -> int:
@@ -98,6 +160,89 @@ class LatentMoEBlock:
         published widths; PERF.md section 6, PR 27). The zeros take part
         in every contraction and add nothing."""
         return -(-self.entry_width // 128) * 128
+
+
+@dataclasses.dataclass(frozen=True)
+class WindowGQABlock:
+    """The second described block: grouped-query attention
+    (``TransformerConfig.heads`` query heads read ``kv_heads`` key/value
+    heads of ``head_dim``, query head i the head ``i // (heads /
+    kv_heads)``) behind RMSNorms over each query and key head, with an
+    output gate (``o * sigmoid(W_g a)``); ``layer_types`` says, layer by
+    layer of the layers run here, whether a layer attends the last
+    ``window`` rows with rotary positions (``"sliding"``) or every earlier
+    row with no position at all (``"full"``): a token caches one K and one
+    V row a layer, the key/value heads side by side in it, and the page
+    pool is held per layer type (serve/kv_pool.py). Four RMSNorms a layer (before and after each
+    branch, the second on the branch's output before it is added).
+    SiLU-gated feed-forwards without biases: ``dense_layers`` leading
+    dense ones, then routed-and-shared expert layers whose router scores
+    all ``num_experts`` while the chip HOLDS ``experts_held`` of them from
+    ``first_expert`` on (ops/moe.py: the picks that fall on other experts
+    take no part). Token embeddings enter times ``embed_scale``.
+    ``dim_head`` and ``ff_mult`` of the configuration are not read."""
+    kv_heads: int = 8
+    head_dim: int = 128
+    window: int = 4096
+    layer_types: Tuple[str, ...] = ("sliding", "sliding", "sliding", "full")
+    rope_theta: float = 1e4
+    norm_eps: float = 1e-5
+    dense_layers: int = 1
+    dense_hidden: int = 12288
+    num_experts: int = 256
+    experts_per_token: int = 4
+    expert_hidden: int = 3072
+    shared_hidden: int = 3072
+    routed_scale: float = 2.448
+    experts_held: int = 256
+    first_expert: int = 0
+    embed_scale: float = 1.0
+    name: str = "window_gqa_moe"
+
+    def __post_init__(self):
+        bad = set(self.layer_types) - {"sliding", "full"}
+        if bad:
+            raise ValueError(f"layer_types holds {sorted(bad)}: a layer is "
+                             f"'sliding' or 'full'")
+        if not 0 <= self.first_expert <= self.first_expert \
+                + self.experts_held <= self.num_experts \
+                or self.experts_held < 1:
+            raise ValueError(
+                f"experts {self.first_expert}..{self.first_expert} + "
+                f"{self.experts_held} are not a share of "
+                f"{self.num_experts}")
+
+    @property
+    def score_dim(self) -> int:
+        return self.head_dim
+
+    def layer_kinds(self, depth: int) -> Tuple[LayerKind, ...]:
+        if len(self.layer_types) != depth:
+            raise ValueError(f"layer_types names {len(self.layer_types)} "
+                             f"layers, depth is {depth}")
+        return tuple(LayerKind(moe=i >= self.dense_layers,
+                               full=t == "full")
+                     for i, t in enumerate(self.layer_types))
+
+    def cache_layers(self, full: bool) -> Tuple[int, ...]:
+        """The layers (indices into the layers run here) of one attention
+        type, in order: their number is the depth of that type's pool."""
+        return tuple(i for i, t in enumerate(self.layer_types)
+                     if (t == "full") == full)
+
+    @staticmethod
+    def pool_buffers(full: bool) -> Tuple[str, str]:
+        """The K and V buffers of the pool of one attention type."""
+        return ("k", "v") if full else ("window_k", "window_v")
+
+    def ring_pages(self, page_size: int, total_len: int) -> int:
+        """Pages a slot holds of a window layer at most: the window and
+        one more, through which it slides (a ring: the row of position p
+        lies at ``p % (ring_pages * page_size)`` and overwrites a row
+        that left the window at least ``page_size`` positions ago); never
+        more than the whole sequence's."""
+        return min(-(-self.window // page_size) + 1,
+                   -(-total_len // page_size))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -137,8 +282,8 @@ class TransformerConfig:
     moe_experts: int = 0
     moe_k: int = 2
     moe_capacity: float = 1.25
-    # a described block (LatentMoEBlock) in place of the classic one
-    block: Optional[LatentMoEBlock] = None
+    # a described block in place of the classic one
+    block: Optional[Union[LatentMoEBlock, WindowGQABlock]] = None
 
     def __post_init__(self):
         blk = self.block
@@ -157,6 +302,7 @@ class TransformerConfig:
         if not 0 <= blk.dense_layers <= self.depth:
             raise ValueError(f"dense_layers {blk.dense_layers} not in "
                              f"[0, depth={self.depth}]")
+        blk.layer_kinds(self.depth)      # it names every layer run here
 
     @property
     def moe(self):
@@ -175,7 +321,7 @@ class TransformerConfig:
     @property
     def scale(self) -> float:
         if self.block is not None:
-            return (self.block.qk_nope_dim + self.block.qk_rope_dim) ** -0.5
+            return self.block.score_dim ** -0.5
         base = self.dim if self.scale_mode == "dim" else self.dim_head
         return base ** -0.5
 
@@ -207,20 +353,23 @@ def layer_init(key: Array, cfg: TransformerConfig, dtype=jnp.float32) -> dict:
     }
 
 
-def block_layer_init(key: Array, cfg: TransformerConfig, moe: bool,
+def block_layer_init(key: Array, cfg: TransformerConfig, kind: LayerKind,
                      dtype=jnp.float32) -> dict:
-    """One layer of the described block: dense or routed feed-forward."""
+    """One layer of a described block: dense or routed feed-forward. The
+    window-and-full block's layers carry a second norm a branch, on the
+    branch's output."""
     from dalle_pytorch_tpu.ops.moe import dropless_init
     blk = cfg.block
     k_attn, k_ff = jax.random.split(key)
-    ff = dropless_init(k_ff, cfg.dim, blk, dtype) if moe \
+    ff = dropless_init(k_ff, cfg.dim, blk, dtype) if kind.moe \
         else core.swiglu_init(k_ff, cfg.dim, blk.dense_hidden, dtype)
-    return {
-        "attn": {"ln": core.rmsnorm_init(cfg.dim, dtype),
-                 **attn_ops.latent_init(k_attn, cfg.dim, cfg.heads, blk,
-                                        dtype)},
-        "ff": {"ln": core.rmsnorm_init(cfg.dim, dtype), **ff},
-    }
+    norm = {"ln": core.rmsnorm_init(cfg.dim, dtype)}
+    if isinstance(blk, WindowGQABlock):
+        norm["post_ln"] = core.rmsnorm_init(cfg.dim, dtype)
+        attn = attn_ops.gqa_init(k_attn, cfg.dim, cfg.heads, blk, dtype)
+    else:
+        attn = attn_ops.latent_init(k_attn, cfg.dim, cfg.heads, blk, dtype)
+    return {"attn": {**norm, **attn}, "ff": {**norm, **ff}}
 
 
 def transformer_init(key: Array, cfg: TransformerConfig,
@@ -232,11 +381,13 @@ def transformer_init(key: Array, cfg: TransformerConfig,
     if cfg.block is not None:
         k_dense, k_moe = jax.random.split(key)
         n_dense = cfg.block.dense_layers
+        # a layer's attention type changes no parameter's shape
         return {
             "dense": jax.vmap(lambda k: block_layer_init(
-                k, cfg, False, dtype))(jax.random.split(k_dense, n_dense)),
+                k, cfg, LayerKind(False, True), dtype))(
+                    jax.random.split(k_dense, n_dense)),
             "moe": jax.vmap(lambda k: block_layer_init(
-                k, cfg, True, dtype))(
+                k, cfg, LayerKind(True, True), dtype))(
                     jax.random.split(k_moe, cfg.depth - n_dense)),
         }
     keys = jax.random.split(key, cfg.depth)
@@ -246,6 +397,13 @@ def transformer_init(key: Array, cfg: TransformerConfig,
 def is_block_params(params: dict) -> bool:
     """Whether a transformer subtree is a described block's two stacks."""
     return "dense" in params and "moe" in params
+
+
+def block_name_of(params: dict) -> str:
+    """Which described block a transformer subtree holds, for a caller
+    that has parameters and no configuration to name in its refusal."""
+    return WindowGQABlock.name if "gate" in params["moe"]["attn"] \
+        else LatentMoEBlock.name
 
 
 # ---------------------------------------------------------------------------
@@ -378,96 +536,127 @@ def ff_or_moe(layer_params: dict, x: Array, cfg: TransformerConfig,
 
 
 # ---------------------------------------------------------------------------
-# the described block (LatentMoEBlock): its branches, written once
+# the described blocks: their branches, written once
 # ---------------------------------------------------------------------------
 #
 # ``transformer_apply``, ``ops.decode.prefill`` and the paged gather decode
-# step all run a layer as: norm, ``attn_ops.latent_project``, a READ,
-# ``attn_ops.latent_out``, residual, then ``block_ff``, residual. Only the
-# read differs (materialised over a whole sequence here and in prefill,
-# absorbed over the page pool in decode), and it is handed in.
+# step all run a layer as: norm, the block's projections, a READ, the
+# block's output projection, residual, then ``block_ff``, residual. Only
+# the read differs (materialised over a whole sequence here and in prefill,
+# over the page pool in decode), and it is handed in.
 
 def block_layer(lp: dict, h: Array, positions: Array, read, cfg,
-                moe: bool):
-    """One layer. ``read(attn_params, q_nope, q_rope, entry) -> o`` is the
-    attention read; -> (h, (entry, load)): the row(s) to cache and the
-    routed layer's load (zeros for a dense layer)."""
+                run: LayerRun):
+    """One layer of the run ``run``. ``read(attn_params, query, entry) ->
+    o`` is the attention read, where ``query`` and ``entry`` are what the
+    block's projection gives: (q_nope, q_rope) and the latent row for the
+    latent block; q and the (k, v) rows for the window-and-full block.
+    -> (h, (entry, load)): the row(s) to cache and the routed layer's
+    load (zeros for a dense layer)."""
     blk = cfg.block
     p = lp["attn"]
     hn = core.rmsnorm(p["ln"], h, eps=blk.norm_eps)
-    q_nope, q_rope, entry = attn_ops.latent_project(p, hn, positions,
-                                                    cfg.heads, blk)
-    h = h + attn_ops.latent_out(p, read(p, q_nope, q_rope, entry))
-    f, load = block_ff(lp["ff"], h, blk, moe)
+    if isinstance(blk, WindowGQABlock):
+        q, gate, entry = attn_ops.gqa_project(
+            p, hn, positions, cfg.heads, blk, rotary=not run.full)
+        a = attn_ops.gqa_out(p, read(p, q, entry), gate)
+        a = core.rmsnorm(p["post_ln"], a, eps=blk.norm_eps)
+    else:
+        q_nope, q_rope, entry = attn_ops.latent_project(p, hn, positions,
+                                                        cfg.heads, blk)
+        a = attn_ops.latent_out(p, read(p, (q_nope, q_rope), entry))
+    h = h + a
+    f, load = block_ff(lp["ff"], h, blk, run.moe)
     return h + f, (entry, load)
 
 
 def block_ff(p: dict, x: Array, blk, moe: bool):
-    """PreNorm feed-forward of the described block -> (out, load (3,))."""
+    """Feed-forward branch of a described block behind its norm(s) ->
+    (out, load: ops.moe.dropless_apply's, zeros for a dense layer)."""
+    from dalle_pytorch_tpu.ops.moe import dropless_apply, load_width
     hn = core.rmsnorm(p["ln"], x, eps=blk.norm_eps)
     if moe:
-        from dalle_pytorch_tpu.ops.moe import dropless_apply
-        return dropless_apply(p, hn, blk)
-    with jax.named_scope("ff"):
-        return core.swiglu(p, hn), jnp.zeros((3,), jnp.int32)
+        out, load = dropless_apply(p, hn, blk)
+    else:
+        with jax.named_scope("ff"):
+            out = core.swiglu(p, hn)
+        load = jnp.zeros((load_width(blk),), jnp.int32)
+    if "post_ln" in p:
+        out = core.rmsnorm(p["post_ln"], out, eps=blk.norm_eps)
+    return out, load
 
 
-def block_stack(params: dict, h: Array, layer_fn):
-    """The non-uniform stack: the dense layers scanned, then the expert
-    layers. ``layer_fn(lp, h, layer, moe) -> (h, out)`` with ``layer`` the
-    traced index into the whole depth (the pool's layer index) and ``moe``
-    static. -> (h, outs stacked over the whole depth)."""
-    def scan_layers(h, sub, first: int, moe: bool):
-        n = jax.tree.leaves(sub)[0].shape[0]
+def block_stack(params: dict, h: Array, layer_fn, cfg):
+    """The non-uniform stack: one scan a run of layers alike in both kinds
+    (``layer_runs``), in the published order. ``layer_fn(lp, h, layer,
+    run) -> (h, out)`` with ``layer`` the traced index into the pool of
+    the run's attention type (``run.cache`` + the index in the run) and
+    ``run`` static. -> (h, outs stacked over the whole depth)."""
+    def scan_layers(h, run: LayerRun):
+        sub = params["moe" if run.moe else "dense"]
         whole = None
-        if moe:
+        if run.moe:
             # the routed experts are not scanned: a layer reads them out
             # of the whole stack by its index (ops.moe.dropless_experts)
             whole = sub["ff"]["experts"]
             sub = {**sub, "ff": {k: v for k, v in sub["ff"].items()
                                  if k != "experts"}}
+        whole_stack = run.count == jax.tree.leaves(sub)[0].shape[0]
 
         def body(h, xs):
             lp, local = xs
+            if not whole_stack:
+                # a run that is part of its stack indexes the stack
+                # itself: a slice of it handed to the scan is a copy of
+                # the run's weights, every step
+                lp = jax.tree.map(lambda a: lax.dynamic_index_in_dim(
+                    a, run.at + local, keepdims=False), sub)
             if whole is not None:
-                lp = {**lp, "ff": {**lp["ff"],
-                                   "experts": {**whole, "layer": local}}}
-            return layer_fn(lp, h, first + local, moe)
+                lp = {**lp, "ff": {**lp["ff"], "experts": {
+                    **whole, "layer": run.at + local}}}
+            return layer_fn(lp, h, run.cache + local, run)
 
-        return lax.scan(body, h, (sub, jnp.arange(n)))
+        return lax.scan(body, h, (sub if whole_stack else None,
+                                  jnp.arange(run.count)))
 
-    outs, first = [], 0
-    for name, moe in (("dense", False), ("moe", True)):
-        n = jax.tree.leaves(params[name])[0].shape[0]
-        if n:
-            h, out = scan_layers(h, params[name], first, moe)
-            outs.append(out)
-            first += n
+    outs = []
+    for run in layer_runs(cfg.block, cfg.depth):
+        h, out = scan_layers(h, run)
+        outs.append(out)
     return h, jax.tree.map(lambda *a: jnp.concatenate(a), *outs)
 
 
 def block_apply_full(params: dict, x: Array, cfg: TransformerConfig,
                      mask: Optional[Array] = None):
-    """The described block over whole sequences x (b, n, dim) at positions
-    0..n-1, causal, the MATERIALISED read: the full forward and the
-    prefill are this one function. -> (h (b, n, dim), entries (depth, b,
-    n, entry_width): every layer's rows to cache, loads (depth, 3))."""
+    """A described block over whole sequences x (b, n, dim) at positions
+    0..n-1, causal (and windowed on a sliding layer), the MATERIALISED
+    read: the full forward and the prefill are this one function. ->
+    (h (b, n, dim), entries stacked over the depth: every layer's rows to
+    cache, (depth, b, n, row_width) or the pair of (depth, b, n, kv_heads,
+    head_dim) K and V; loads (depth, load width))."""
+    blk = cfg.block
     n = x.shape[1]
     positions = jnp.arange(n)
-    with jax.named_scope("attn.read"):       # the mask
+    with jax.named_scope("attn.read"):       # the masks
         allowed = jnp.tril(jnp.ones((n, n), bool))[None, None]
         if mask is not None:
             allowed = allowed & (mask[:, None, :, None]
                                  & mask[:, None, None, :])
+        if isinstance(blk, WindowGQABlock):
+            near = (positions[:, None] - positions[None, :]) < blk.window
+            in_window = allowed & near[None, None]
 
-    def read(p, q_nope, q_rope, entry):
-        return attn_ops.latent_attend_materialised(
-            p, q_nope, q_rope, entry, allowed, cfg.block, cfg.scale)
+    def layer_fn(lp, h, _layer, run):
+        def read(p, query, entry):
+            if isinstance(blk, WindowGQABlock):
+                return attn_ops.gqa_attend_materialised(
+                    query, *entry, allowed if run.full else in_window,
+                    cfg.scale, window=not run.full)
+            return attn_ops.latent_attend_materialised(
+                p, *query, entry, allowed, blk, cfg.scale)
+        return block_layer(lp, h, positions, read, cfg, run)
 
-    def layer_fn(lp, h, _layer, moe):
-        return block_layer(lp, h, positions, read, cfg, moe)
-
-    h, (entries, loads) = block_stack(params, x, layer_fn)
+    h, (entries, loads) = block_stack(params, x, layer_fn, cfg)
     return h, entries, loads
 
 

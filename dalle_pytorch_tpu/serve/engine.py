@@ -137,8 +137,10 @@ LOOP_SECONDS = ("engine_loop_s", "harvest_wait_s", "admit_s",
 # engine), summed over its routed layers and every decode step: what the
 # fused decode program returns beside the ring, in the order it stacks
 # them (ops.moe.dropless_apply): token-picks routed, experts that received
-# one, the fullest expert's picks
-MOE_COUNTERS = ("moe_picks", "moe_experts_touched", "moe_load_max")
+# one, the fullest expert's picks and, where the block holds a share of
+# its experts, the picks that fell on the share
+MOE_COUNTERS = ("moe_picks", "moe_experts_touched", "moe_load_max",
+                "moe_picks_held")
 
 
 def _phase(name: str, **meta):
@@ -273,7 +275,7 @@ class _Row:
 
     __slots__ = ("handle", "codes", "uncond", "pair_row", "t0", "bucket",
                  "total_pages", "mode", "shared_n", "key", "entry",
-                 "grants", "slot", "group_idx")
+                 "grants", "window_grants", "slot", "group_idx")
 
     def __init__(self, handle: S.RequestHandle, codes, uncond: bool):
         self.handle = handle
@@ -288,6 +290,7 @@ class _Row:
         self.key: Optional[str] = None
         self.entry = None
         self.grants: List[int] = []
+        self.window_grants: List[int] = []    # of a window pool
         self.slot = -1
         self.group_idx = -1
 
@@ -341,6 +344,7 @@ class Engine:
         # paged gather path alone; every other option is refused here,
         # by the one typed error that names the block and the option
         self.block = cfg.transformer.block
+        self.window = None      # a window-and-full block's second pool
         if self.block is not None:
             from dalle_pytorch_tpu.ops import transformer as T_ops
             for option, on in (
@@ -495,16 +499,31 @@ class Engine:
                     f"num_pages={self.num_pages} cannot hold even one "
                     f"full sequence ({self.slot_max_pages} pages of "
                     f"{self.page_size} rows + the reserved trash page)")
+            # a window-and-full block holds a second pool for its window
+            # layers, with an allocator and ring tables of its own
+            # (``KV.WindowPages``): ``self.alloc`` and ``_bt_host`` are
+            # then the FULL layers' pool
+            window_pages = KV.window_pool_pages(
+                cfg.transformer, S_, self.total_len, self.page_size,
+                self.num_pages)
+            self.window = None if not window_pages else KV.WindowPages(
+                S_, window_pages, self.block.ring_pages(
+                    self.page_size, self.total_len), self.page_size)
+            if window_pages and self.num_pages * window_pages >= 2 ** 31:
+                raise ValueError(
+                    f"{self.num_pages} x {window_pages} pages: an "
+                    f"admission names a row's page in both pools by one "
+                    f"int32 (_prefill_fn)")
             self.cache = self._place_kv(KV.init_page_pool(
                 cfg.transformer, self.num_pages, self.page_size,
                 dtype=params["text_emb"]["w"].dtype,
-                quantized=self.quantize_cache))
+                quantized=self.quantize_cache, window_pages=window_pages))
             self.alloc = KV.PageAllocator(self.num_pages)
             # the host owns the authoritative block tables (it owns the
             # allocator); the device copy is pushed — one explicit
             # device_put of a few KB — only when the mapping changes
             self._bt_host = np.zeros((S_, self.slot_max_pages), np.int32)
-            self.block_tables = self._put(self._bt_host)
+            self.block_tables = self._put(self._tables_host())
             self._bt_dirty = False
             self._slot_pages: List[List[int]] = [[] for _ in range(S_)]
             # safe host-side upper bound of each slot's device pos
@@ -833,7 +852,7 @@ class Engine:
             return D.decode_token_embed(params, self.cfg, tok, p)
 
         def sample_fn(h, pred_pos):
-            logits = self._logits_sync(D.to_logits(params, h))
+            logits = self._logits_sync(D.to_logits(params, h, self.cfg))
             return D.sample_per_slot(logits, pred_pos, keys, temp,
                                      topk_k, top_p, self.cfg,
                                      partner=partner, cfg_scale=cfgs,
@@ -905,6 +924,7 @@ class Engine:
                 sample_fn=sample_fn, attn_impl=self.paged_attn,
                 out_sync=self._decode_out_sync())
         if self.paged_attn == "gather":
+            # (the full layers' groups, of a window-and-full block)
             self.kv_view_groups = decode_ops.pool_view_groups(
                 cache, self.num_slots, self.slot_max_pages)
         return decode_ops.decode_loop_paged(
@@ -965,12 +985,42 @@ class Engine:
                 ps = self.page_size
                 n_pages = -(-bucket // ps)
                 with jax.named_scope("prefill.scatter"):
-                    rows = jnp.pad(group["latent"], (
-                        (0, 0), (0, 0), (0, n_pages * ps - bucket), (0, 0)))
-                    pages = rows.reshape(rows.shape[0], -1, ps,
-                                         rows.shape[-1])
                     ids = page_rows[:, ::ps].reshape(-1)
-                    cache = {"latent": cache["latent"].at[:, ids].set(pages)}
+
+                    def whole_pages(rows):      # (layers, G, bucket, ...)
+                        fill = [(0, 0)] * rows.ndim
+                        fill[2] = (0, n_pages * ps - bucket)
+                        rows = jnp.pad(rows, fill)
+                        return rows.reshape((rows.shape[0], -1, ps)
+                                            + rows.shape[3:])
+
+                    if "latent" in cache:
+                        cache = {"latent": cache["latent"].at[:, ids].set(
+                            whole_pages(group["latent"]))}
+                    else:
+                        # a page id of each pool in one int32: full id x
+                        # the window pool's pages + window id (the trash
+                        # page for a page the ring no longer holds)
+                        n_win = 1 if self.window is None \
+                            else self.window.alloc.num_pages
+                        new = {}
+                        for full, at in ((True, ids // n_win),
+                                         (False, ids % n_win)):
+                            # jaxlint: disable=JL001 — the layers of one
+                            # type, static configuration: a trace-time const
+                            layers = np.asarray(
+                                self.block.cache_layers(full), np.int32)
+                            if not layers.size:     # no pool of this type
+                                continue
+                            for name, rows in zip(
+                                    self.block.pool_buffers(full),
+                                    (group["k"], group["v"])):
+                                # a row: the kv heads side by side
+                                rows = rows[layers]
+                                new[name] = cache[name].at[:, at].set(
+                                    whole_pages(rows.reshape(
+                                        rows.shape[:3] + (-1,))))
+                        cache = new
             elif paged:
                 # scatter the group's [0, bucket) rows into their pages:
                 # row j of group-row g lands in physical page
@@ -1001,7 +1051,7 @@ class Engine:
             with jax.named_scope("head"):
                 h_last = jnp.take_along_axis(
                     h, (lens - 1)[:, None, None], axis=1)[:, 0]
-            logits = self._logits_sync(D.to_logits(params, h_last))
+            logits = self._logits_sync(D.to_logits(params, h_last, self.cfg))
             # n_partner is the GROUP-row index of a guided row's pair
             # (both members admit in the same bucket group: the null
             # caption has the cond prompt's length); the same
@@ -1054,7 +1104,7 @@ class Engine:
             from dalle_pytorch_tpu.models import dalle as D
             with jax.named_scope("sample"):
                 n_rng = jax.vmap(jax.random.PRNGKey)(n_seed)
-            logits = self._logits_sync(D.to_logits(params, h_last))
+            logits = self._logits_sync(D.to_logits(params, h_last, self.cfg))
             first = D.sample_per_slot(logits, lens, n_rng, n_temp,
                                       n_topk, n_top_p, self.cfg,
                                       partner=n_partner,
@@ -1403,7 +1453,11 @@ class Engine:
                     # cached prefixes are a perf lever, live requests
                     # are work: drop LRU entries before deferring
                     self.prefix.shrink(need)
-                if self.alloc.free < need:
+                window_need = 0 if self.window is None else sum(
+                    self.window.prompt_need(p.t0) for p in hrows)
+                if self.alloc.free < need or (
+                        window_need
+                        and self.window.alloc.free < window_need):
                     # head-of-line block: requeue this and every later
                     # pop (arrival order preserved by queue_seq)
                     for hh in take[k:]:
@@ -1427,6 +1481,9 @@ class Engine:
                 for p in hrows:
                     p.grants = self.alloc.alloc(
                         p.total_pages - p.shared_n)
+                    if self.window is not None:
+                        p.window_grants = self.window.alloc.alloc(
+                            self.window.prompt_need(p.t0))
                 fits.append(h)
                 rows.extend(hrows)
                 self._deferred_ids.discard(rid)
@@ -1486,6 +1543,16 @@ class Engine:
                     self._bt_host[idx[j], :len(p.grants)] = p.grants
                     page_rows[j] = self._bt_host[
                         idx[j], np.arange(bucket) // self.page_size]
+                    if self.window is not None:
+                        # the row's page in both pools, as one int32
+                        # (_prefill_fn): the full pool's id x the window
+                        # pool's pages + the window pool's id
+                        ring = self.window.admit(idx[j], p.t0,
+                                                 p.window_grants)
+                        ring = np.pad(ring, (0, p.total_pages - len(ring)))
+                        page_rows[j] = page_rows[j] \
+                            * self.window.alloc.num_pages + ring[
+                                np.arange(bucket) // self.page_size]
             for j, p in enumerate(group):
                 # a pair's rows always share the bucket, hence the group
                 if p.pair_row is not None and p.pair_row in group:
@@ -1533,6 +1600,8 @@ class Engine:
                         self.alloc.release(p.grants)
                         p.grants = []
                         self._bt_host[idx[j], :] = 0
+                        if self.window is not None:
+                            self.window.release(idx[j])
                     self._bt_dirty = True
                 for h in self._unique_handles(group):
                     self._error(h, now, f"prefill failed: {e!r}")
@@ -1812,6 +1881,8 @@ class Engine:
         if self._slot_pages[i]:
             self.alloc.release(self._slot_pages[i])
             self._slot_pages[i] = []
+        if self.window is not None:
+            self.window.release(i)
         self._bt_host[i, :] = 0
         self._pos_est[i] = 0
         self._bt_dirty = True
@@ -1908,20 +1979,30 @@ class Engine:
                 # their rows are stale-by-invariant, not unmapped)
                 target = min(self._pos_est[i] + self._chunk_span,
                              self.total_len)
-                short = KV.pages_for(target, self.page_size) \
-                    - len(self._slot_pages[i])
-                if short <= 0:
+                short = max(KV.pages_for(target, self.page_size)
+                            - len(self._slot_pages[i]), 0)
+                # a window pool's ring grows to its width and then turns:
+                # its pages are reused in place (KV.WindowPages.grow)
+                window_short = 0 if self.window is None \
+                    else self.window.short(i, target)
+                if not short and not window_short:
+                    if self.window is not None:
+                        self.window.grow(i, target)
                     break
+                window_ok = self.window is None or \
+                    self.window.alloc.free >= window_short
                 if self.alloc.free < short and self.prefix is not None:
                     # drop cached prefixes (LRU first) before evicting
                     # live work — an index-held page a live slot no
                     # longer shares frees immediately at release
                     self.prefix.shrink(short)
-                if self.alloc.free >= short:
+                if self.alloc.free >= short and window_ok:
                     for p in self.alloc.alloc(short):
                         self._bt_host[i, len(self._slot_pages[i])] = p
                         self._slot_pages[i].append(p)
-                    self._bt_dirty = True
+                        self._bt_dirty = True
+                    if self.window is not None:
+                        self.window.grow(i, target)
                     break
                 # pool exhausted mid-decode: typed backpressure — the
                 # victim may be slot i itself, which ends its while loop
@@ -1934,9 +2015,19 @@ class Engine:
         """Push the host's authoritative block tables to the device when
         the mapping changed — ONE explicit device_put of a few KB, the
         only paged-specific host->device traffic in steady state."""
-        if self._bt_dirty:
-            self.block_tables = self._put(self._bt_host)
+        if self._bt_dirty or (self.window is not None
+                              and self.window.dirty):
+            self.block_tables = self._put(self._tables_host())
             self._bt_dirty = False
+            if self.window is not None:
+                self.window.dirty = False
+
+    def _tables_host(self):
+        """What the decode program takes as its block tables: the one
+        table, or a table a pool of a window-and-full block."""
+        if self.window is None:
+            return self._bt_host
+        return {"full": self._bt_host, "window": self.window.tables}
 
     # -- the fused-chunk pipeline -------------------------------------------
 
@@ -2927,11 +3018,14 @@ class Engine:
         from dalle_pytorch_tpu.ops import paged_attention as PA
         tcfg = self.cfg.transformer
         if self.block is not None:
-            # the gather reads every page of the table, whole rows, once
-            # a layer: no head axis, no V, no live-page trimming
-            out = (tcfg.depth * self.slot_max_pages * self.page_size
-                   * self.block.row_width
-                   * self.cache["latent"].dtype.itemsize)
+            # the gather reads every page of a layer's table, whole, once:
+            # no live-page trimming. A latent pool has one row a token
+            # and no V; a window layer's table is its ring
+            columns = {name: self.window.ring if name.startswith("window")
+                       else self.slot_max_pages for name in self.cache}
+            out = sum(buf.shape[0] * columns[name]
+                      * int(np.prod(buf.shape[2:])) * buf.dtype.itemsize
+                      for name, buf in self.cache.items())
             self._modeled_read_bytes[sr] = out
             return out
         out = int(PA.modeled_kv_read_bytes_per_token(
@@ -2945,6 +3039,34 @@ class Engine:
             sparse_block=tcfg.sparse_block, causal=tcfg.causal))
         self._modeled_read_bytes[sr] = out
         return out
+
+    def _window_stats(self) -> dict:
+        """A window-and-full block's two pools, side by side: physical
+        pages in use of each (``pages_in_use`` is the full pool's), the
+        window pool's ring, the pages it reused in place, and what an
+        all-full cache would hold at the same positions (every layer's
+        pages to each slot's mapped position: the denominator of the
+        saving)."""
+        if self.window is None:
+            return {}
+        w = self.window
+        blk = self.block
+        full_layers, win_layers = (len(blk.cache_layers(True)),
+                                   len(blk.cache_layers(False)))
+        full_in_use = self.alloc.in_use
+        return {
+            "full_pages_in_use": full_in_use,
+            "window_pages_in_use": w.alloc.in_use,
+            "window_ring_pages": w.ring,
+            "window_pages_reused": w.reused,
+            # layer-pages held now, and what they would be were every
+            # layer a full one (a slot's full-pool pages are its pages
+            # to its mapped position)
+            "layer_pages_in_use": full_layers * full_in_use
+            + win_layers * w.alloc.in_use,
+            "layer_pages_all_full": (full_layers + win_layers)
+            * full_in_use,
+        }
 
     def pages_in_use_p95(self) -> int:
         """Nearest-rank p95 of pages in use, sampled at every chunk
@@ -2992,6 +3114,7 @@ class Engine:
                 "pages_in_use_p95": self.pages_in_use_p95(),
                 "pages_shared": self.alloc.pages_shared,
                 "pages_shared_saved": self.alloc.refs_saved,
+                **self._window_stats(),
                 "evicted": self.evicted,
                 "deferred": self.deferred,
                 "requeued": self.queue.requeued,
@@ -3023,8 +3146,12 @@ class Engine:
                 "spec_tokens_per_round": round(
                     self.spec_delivered / max(self.spec_rounds, 1), 3),
             }
-        moe = {} if self.block is None else {
-            k: getattr(self, k) for k in MOE_COUNTERS}
+        moe = {}
+        if self.block is not None:
+            from dalle_pytorch_tpu.ops.moe import load_width
+            # (moe_picks_held only where the block holds a share)
+            moe = {k: getattr(self, k)
+                   for k in MOE_COUNTERS[:load_width(self.block)]}
         return {
             "kv": self.kv,
             "kv_hbm_bytes": self.kv_hbm_bytes(),

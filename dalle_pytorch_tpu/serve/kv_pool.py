@@ -166,12 +166,24 @@ def page_layout(cfg, page_size: int, quantized: bool = False) -> dict:
         ``ops.decode.init_cache``, so int8-KV composes with paging);
       * a latent-attention block (``cfg.block``): ONE row a token, the
         latent and the roped key side by side and filled up to whole
-        lanes, ``(page_size, row_width)``: no head axis and no V."""
+        lanes, ``(page_size, row_width)``: no head axis and no V;
+      * a window-and-full, grouped-query block: one K row and one V row
+        a token, every KEY/VALUE head's numbers side by side in it,
+        ``(page_size, kv_heads * head_dim)`` each (no head axis between
+        page and row: the read contracts whole rows, as the latent
+        block's does), in two pools, one a layer type (``pool_plan``):
+        ``k`` / ``v`` hold the full layers, ``window_k`` / ``window_v``
+        the window layers."""
     blk = getattr(cfg, "block", None)
     if blk is not None:
         if quantized:
             from dalle_pytorch_tpu.ops.transformer import BlockOptionError
             raise BlockOptionError(blk.name, "quantize_cache")
+        if hasattr(blk, "window"):
+            page = ((page_size, blk.kv_heads * blk.head_dim), None)
+            return {name: page for full in (True, False)
+                    if blk.cache_layers(full)
+                    for name in blk.pool_buffers(full)}
         return {"latent": ((page_size, blk.row_width), None)}
     page = (cfg.heads, page_size, cfg.dim_head)
     if quantized:
@@ -180,15 +192,51 @@ def page_layout(cfg, page_size: int, quantized: bool = False) -> dict:
     return {"k": (page, None), "v": (page, None)}
 
 
+def pool_plan(cfg, num_pages: int, window_pages: int) -> dict:
+    """``{buffer: (layers, pages)}``: how many layers and pages each
+    buffer of ``page_layout`` spans. Every block but one holds ONE pool,
+    every layer's pages under one page id; a window-and-full block holds
+    a pool a layer type, because a window layer never reads more than its
+    window of a slot's rows and its pages are reused as the slot moves on
+    (``WindowPages``): the full layers' ``k`` / ``v`` with ``num_pages``
+    pages and the window layers' ``window_k`` / ``window_v`` with
+    ``window_pages``."""
+    blk = getattr(cfg, "block", None)
+    # (by attribute: this module imports nothing of jax's at its top)
+    if blk is None or not hasattr(blk, "window"):
+        names = ("latent",) if blk is not None else \
+            ("k", "v", "k_scale", "v_scale")
+        return {name: (cfg.depth, num_pages) for name in names}
+    return {name: (len(blk.cache_layers(full)), pages)
+            for full, pages in ((True, num_pages), (False, window_pages))
+            for name in blk.pool_buffers(full)}
+
+
+def window_pool_pages(cfg, num_slots: int, total_len: int, page_size: int,
+                      num_pages: int) -> int:
+    """Pages of the window pool (trash page included) beside a full pool
+    of ``num_pages``: the same share of what every slot could hold at
+    once. 0 where the block has no window layers."""
+    blk = getattr(cfg, "block", None)
+    if blk is None or not hasattr(blk, "window") \
+            or not blk.cache_layers(False):
+        return 0
+    ring = blk.ring_pages(page_size, total_len)
+    whole = num_slots * pages_for(total_len, page_size)
+    return max(-(-(num_pages - 1) * num_slots * ring // whole), ring) + 1
+
+
 def init_page_pool(cfg, num_pages: int, page_size: int, dtype=None,
-                   quantized: bool = False) -> dict:
-    """Device-resident page pool: one ``(depth, num_pages) + page shape``
-    buffer for each entry of ``page_layout``."""
+                   quantized: bool = False, window_pages: int = 0) -> dict:
+    """Device-resident page pool(s): one ``(layers, pages) + page shape``
+    buffer for each entry of ``page_layout``, spanning what ``pool_plan``
+    says."""
     import jax.numpy as jnp
     if dtype is None:
         dtype = jnp.float32
     kinds = {None: dtype, 1: jnp.int8, 4: jnp.float32}
-    return {name: jnp.zeros((cfg.depth, num_pages) + shape, kinds[size])
+    plan = pool_plan(cfg, num_pages, window_pages)
+    return {name: jnp.zeros(plan[name] + shape, kinds[size])
             for name, (shape, size) in
             page_layout(cfg, page_size, quantized).items()}
 
@@ -254,12 +302,13 @@ def modeled_kv_bytes(cfg, *, kv: str, num_slots: int, total_len: int,
     else:
         # the dense slot cache holds the same rows, a slot a "page"
         ps, pages = total_len, num_slots
+    plan = pool_plan(cfg, pages, window_pool_pages(
+        cfg, num_slots, total_len, ps, pages))
     # a page's bytes, buffer by buffer (quantized: int8 rows plus one
-    # f32 scale a row)
-    per_page = sum(math.prod(shape) * (size or dtype_bytes)
-                   for shape, size in
-                   page_layout(cfg, ps, quantized).values())
-    return int(cfg.depth * pages * per_page)
+    # f32 scale a row), times the layers and pages the buffer spans
+    return int(sum(math.prod(plan[name]) * math.prod(shape)
+                   * (size or dtype_bytes) for name, (shape, size) in
+                   page_layout(cfg, ps, quantized).items()))
 
 
 class PageAllocator:
@@ -378,3 +427,80 @@ class PageAllocator:
                 del self._refs[p]
                 self._free.append(p)
                 self._free_set.add(p)
+
+
+class WindowPages:
+    """Host side of a window pool (``pool_plan``): its own allocator, a
+    ring table a slot and each slot's pages. A slot's table has ``ring``
+    columns (``WindowGQABlock.ring_pages``: the window's pages and one
+    more) and logical page j lies in column ``j % ring``: as ``pos`` moves
+    on, the page that has wholly left the window is REUSED in place for
+    the page ahead, so a slot never holds more than ``ring`` pages of a
+    window layer however long its sequence, and a short one holds only
+    what it has reached. Map-ahead, exhaustion (``PagePoolExhausted`` from
+    ``alloc``) and the trash page are the full pool's; the engine owns an
+    instance under its step lock."""
+
+    def __init__(self, num_slots: int, num_pages: int, ring: int,
+                 page_size: int):
+        import numpy as np
+        self.alloc = PageAllocator(num_pages)
+        self.ring, self.page_size = int(ring), int(page_size)
+        self.tables = np.zeros((num_slots, self.ring), np.int32)
+        self.dirty = False
+        self.reused = 0                         # pages reused in place
+        self._pages: List[List[int]] = [[] for _ in range(num_slots)]
+        self._mapped = [0] * num_slots          # logical pages reached
+
+    def pages_of(self, slot: int) -> List[int]:
+        return list(self._pages[slot])
+
+    def prompt_need(self, t0: int) -> int:
+        """Pages a prompt of ``t0`` rows holds at admission: those of its
+        last ``ring`` logical pages."""
+        return min(pages_for(t0, self.page_size), self.ring)
+
+    def short(self, slot: int, rows: int) -> int:
+        """Pages that mapping ``rows`` rows of ``slot`` would allocate."""
+        want = min(pages_for(rows, self.page_size), self.ring)
+        return max(want - len(self._pages[slot]), 0)
+
+    def admit(self, slot: int, t0: int, grants: List[int]):
+        """Map a prompt of ``t0`` rows onto ``grants`` (``prompt_need(t0)``
+        pages). -> for each LOGICAL page of the prompt its physical page,
+        the trash page for those already behind the ring."""
+        import numpy as np
+        n = pages_for(t0, self.page_size)
+        self.tables[slot, :] = TRASH_PAGE
+        out = np.zeros((n,), np.int32)
+        for g, j in zip(grants, range(n - len(grants), n)):
+            self.tables[slot, j % self.ring] = g
+            out[j] = g
+        self._pages[slot] = list(grants)
+        self._mapped[slot] = n
+        self.dirty = True
+        return out
+
+    def grow(self, slot: int, rows: int) -> None:
+        """Map every logical page of ``slot`` up to ``rows`` rows: a new
+        page while the ring has a free column, else the column's own page
+        again. Raises ``PagePoolExhausted`` before changing anything."""
+        want = pages_for(rows, self.page_size)
+        fresh = self.alloc.alloc(self.short(slot, rows))
+        for j in range(self._mapped[slot], want):
+            if self.tables[slot, j % self.ring] == TRASH_PAGE:
+                page = fresh.pop()
+                self.tables[slot, j % self.ring] = page
+                self._pages[slot].append(page)
+                self.dirty = True
+            else:
+                self.reused += 1
+        self._mapped[slot] = max(self._mapped[slot], want)
+
+    def release(self, slot: int) -> None:
+        if self._pages[slot]:
+            self.alloc.release(self._pages[slot])
+            self._pages[slot] = []
+        self.tables[slot, :] = TRASH_PAGE
+        self._mapped[slot] = 0
+        self.dirty = True
