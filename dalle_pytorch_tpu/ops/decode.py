@@ -53,6 +53,12 @@ _VIEW_VMEM_BYTES = 96 << 20
 _TILE = (8, 128)    # a TPU tile: 8 rows of 128 lanes of 4-byte words
 
 
+def _tile_of(dtype) -> Tuple[int, int]:
+    """(rows, lanes) of one TPU tile of ``dtype``: 8 rows of four-byte
+    words (16 bf16 rows, 32 int8 rows) by 128 lanes."""
+    return (_TILE[0] * max(4 // jnp.dtype(dtype).itemsize, 1), _TILE[1])
+
+
 def view_slot_groups(slots: int, columns: int, page_shape, dtype) -> int:
     """The rule of the paged gather reads (``_read_in_slot_groups``): the
     fewest equal groups of ``slots`` whose gathered pages, ONE buffer of
@@ -67,8 +73,8 @@ def view_slot_groups(slots: int, columns: int, page_shape, dtype) -> int:
     rows: the int8 pool's 16-row page takes the room of the bf16 one)."""
     *outer, rows, minor = page_shape
     itemsize = jnp.dtype(dtype).itemsize
-    tile = (_TILE[0] * max(4 // itemsize, 1), _TILE[1])
-    filled = [-(-n // t) * t for n, t in zip((rows, minor), tile)]
+    filled = [-(-n // t) * t
+              for n, t in zip((rows, minor), _tile_of(dtype))]
     slot_bytes = columns * math.prod(outer) * math.prod(filled) * itemsize
     return next((g for g in range(1, slots) if slots % g == 0
                  and slots // g * slot_bytes <= _VIEW_VMEM_BYTES), slots)
@@ -79,6 +85,41 @@ def pool_view_groups(pool: dict, slots: int, columns: int) -> int:
     (slots, columns), trimmed as the read trims it."""
     buf = pool["latent"] if "latent" in pool else next(iter(pool.values()))
     return view_slot_groups(slots, columns, buf.shape[2:], buf.dtype)
+
+
+def read_heads_merged(page_shape, dtype, mesh: bool = False) -> bool:
+    """The rule of the classic block's paged gather read
+    (``_paged_gather_read``): whether a slot group's gathered pages are
+    contracted as whole rows against ALL heads' queries at once (True) or
+    a head's query against its own pages (False). Like
+    ``view_slot_groups`` it reads what a trace sees and nothing else: a
+    page's shape (heads, rows, dim_head), the pool's dtype, and whether
+    the call was handed the mesh seam (``out_sync``).
+
+    Both forms are one algorithm (the same rows, mask and softmax) and
+    differ in two einsums. Per head, ``b`` and ``h`` are batch dimensions
+    over ONE query row, and the TPU compiler makes each contraction a
+    multiply-and-reduce over lanes on the vector unit, as slow as the
+    gather that fed it. Merged, a slot's pages are ``columns * heads *
+    rows`` rows of ``dim_head`` against a (heads, dim_head) matrix of
+    queries: a product on the matrix unit, of which a head keeps the
+    scores of its own pages' rows; the other ``heads - 1`` parts are
+    passes of a unit that has no other use in a decode step, and the
+    compiler fuses the keeping into the product, so the all-heads scores
+    are never written (AOT text of both ``dalle`` cells' programs). On
+    the chip (PERF.md section 6, PR 34) ``decode_attend_ms`` fell 4.33 ->
+    2.01 at ruDALL-E's 16 heads of 128 and 4.35 -> 1.98 at 12b's 62 heads
+    of 64, whose rows are half lane padding in either form. The merged
+    form is taken where both of these hold:
+
+      * no mesh: with the heads sharded over ``tp``, rows that hold
+        every head's pages would make the partitioner gather the pool;
+      * a page's rows are whole tiles of the pool's dtype (16 bf16 rows,
+        32 int8 rows, 8 float32 rows), so that (columns, heads, rows,
+        dim_head) -> (columns * heads * rows, dim_head) is a bitcast of
+        the gathered buffer and not a copy of it."""
+    _, rows, _ = page_shape
+    return not mesh and rows % _tile_of(dtype)[0] == 0
 
 
 def _read_in_slot_groups(pool: dict, tables: Array, read) -> Array:
@@ -379,7 +420,7 @@ def layer_pool_view(buf: Array, layer: Array, tables: Array,
 
 def _paged_gather_read(pool: dict, layer: Array, tables: Array, q: Array,
                        k: Array, v: Array, allowed: Array, *, scale: float,
-                       v_after_k: bool = False) -> Array:
+                       v_after_k: bool = False, mesh: bool = False) -> Array:
     """``_gather_read`` for ONE slot group over ``layer_pool_view``'s
     page-major pages: tables (b, w) into the K/V pool, q/k/v (b, h, 1, dh),
     allowed (b, rows) with rows <= w * ps (logical row j is page j // ps,
@@ -389,7 +430,24 @@ def _paged_gather_read(pool: dict, layer: Array, tables: Array, q: Array,
     order, so the softmax runs over rows 0..rows-1 in order plus the self
     logit, exactly the dense view's; then V's pages the same way. The int8
     pool's scale pages are gathered with their rows and apply outside the
-    contractions in score dtype, as there. Returns (b, h, 1, dh).
+    contractions, as there. Returns (b, h, 1, dh).
+
+    The two contractions take one of two forms, by the shapes
+    (``read_heads_merged``, which gives the reasons and the readings;
+    ``mesh`` is whether the step was handed ``out_sync``):
+      * all heads merged (``_scores_heads_merged``,
+        ``_values_heads_merged``): the pages as ``w * heads * ps`` whole
+        rows (a bitcast) against every head's query in one matrix
+        product a slot, of which a head keeps its own pages' rows, and
+        the weights spread back over the rows with zeros in the other
+        heads' for V. Float32 accumulation, the scale (and an int8
+        pool's) applied to the float32 scores before they are rounded
+        once to the query's dtype. Both ``dalle`` cells' reads;
+      * per head (``"bhd,bmhsd->bhms"``, ``"bhms,bmhsd->bhd"``): a head's
+        one query row against its own pages, ``b`` and ``h`` batch
+        dimensions. On the TPU these are lane reductions on the vector
+        unit. It is what a mesh or a page short of a whole tile of rows
+        gets.
 
     ``v_after_k`` ties V's gather to the softmax's weights. The budget of
     a slot group is ONE gathered buffer (``view_slot_groups``), which
@@ -407,11 +465,15 @@ def _paged_gather_read(pool: dict, layer: Array, tables: Array, q: Array,
     b, w, h, ps, _ = gk.shape
     rows = allowed.shape[1]
     quantized = ksc is not None
+    merged = read_heads_merged(gk.shape[2:], gk.dtype, mesh)
     with jax.named_scope("attn.read"):
         gkc = gk.astype(q.dtype) if quantized else gk
-        scores = jnp.einsum("bhd,bmhsd->bhms", q[:, :, 0, :], gkc) * scale
-        if quantized:
-            scores = scores * jnp.moveaxis(ksc, 1, 2).astype(scores.dtype)
+        if merged:
+            scores = _scores_heads_merged(q[:, :, 0, :], gkc, scale, ksc)
+        else:
+            scores = jnp.einsum("bhd,bmhsd->bhms", q[:, :, 0, :], gkc) * scale
+            if quantized:
+                scores = scores * jnp.moveaxis(ksc, 1, 2).astype(scores.dtype)
         scores = scores.reshape(b, h, 1, w * ps)[..., :rows]
         wts = _softmax_with_self(scores, allowed, q, k, scale)
     if v_after_k:
@@ -425,23 +487,66 @@ def _paged_gather_read(pool: dict, layer: Array, tables: Array, q: Array,
             gvc = gv.astype(q.dtype)
         else:
             gvc = gv
-        return (jnp.einsum("bhms,bmhsd->bhd", wj, gvc)[:, :, None, :]
-                + wts[..., -1:] * v)
+        out = _values_heads_merged(wj, gvc) if merged \
+            else jnp.einsum("bhms,bmhsd->bhd", wj, gvc)
+        return out[:, :, None, :] + wts[..., -1:] * v
+
+
+def _own_rows(heads: int, w: int, ps: int) -> Array:
+    """(heads, w * heads * ps) bool: of a slot's gathered pages as rows
+    (page, head, row-in-page), those of each head's own pages."""
+    head_of_row = jnp.arange(w * heads * ps) // ps % heads
+    return head_of_row[None, :] == jnp.arange(heads)[:, None]
+
+
+def _scores_heads_merged(q: Array, gk: Array, scale: float,
+                         ksc: Optional[Array]) -> Array:
+    """The merged form's K contraction: q (b, heads, dh) against gk (b, w,
+    heads, ps, dh) read as R = w * heads * ps rows -> (b, heads, w, ps) in
+    q's dtype. ``"bgd,bRd->bgR"`` is a (heads x dh) @ (dh x R) product a
+    slot; row r belongs to one head, so the sum over ``g`` of the scores
+    masked to ``_own_rows`` IS that head's score of row r, in the pages'
+    own (w, heads, ps) order (an int8 pool's scale pages lie in the same
+    order); only those (b, R) are brought head-major."""
+    b, w, heads, ps, dh = gk.shape
+    every = jnp.einsum("bgd,bRd->bgR", q, gk.reshape(b, w * heads * ps, dh),
+                       preferred_element_type=jnp.float32)
+    own = jnp.where(_own_rows(heads, w, ps), every, 0.0).sum(axis=1) * scale
+    if ksc is not None:
+        own = own * ksc.reshape(own.shape)
+    return jnp.moveaxis(own.astype(q.dtype).reshape(b, w, heads, ps), 2, 1)
+
+
+def _values_heads_merged(wj: Array, gv: Array) -> Array:
+    """The merged form's V contraction: the weights wj (b, heads, w, ps)
+    laid along the pages' rows (page, head, row-in-page), each head's in
+    its own pages' rows and zeros in the others' (the block-diagonal
+    operand of ``ops.attention.gqa_attend_rows``, here on the weights'
+    side), against gv (b, w, heads, ps, dh) read as rows: a (heads x R) @
+    (R x dh) product a slot -> (b, heads, dh)."""
+    b, w, heads, ps, dh = gv.shape
+    flat = jnp.moveaxis(wj, 1, 2).reshape(b, 1, w * heads * ps)
+    spread = jnp.where(_own_rows(heads, w, ps), flat, 0)
+    return jnp.einsum("bgR,bRd->bgd", spread,
+                      gv.reshape(b, w * heads * ps, dh),
+                      preferred_element_type=jnp.float32).astype(wj.dtype)
 
 
 def _paged_gather_attend(pool: dict, layer: Array, tables: Array,
                          q: Array, k: Array, v: Array, allowed: Array, *,
-                         scale: float) -> Array:
+                         scale: float, mesh: bool = False) -> Array:
     """One layer's paged gather read of the classic block, whole:
     ``_paged_gather_read`` over the slots of ``tables`` (b, w), a slot
     group at a time (``_read_in_slot_groups`` decides the groups from the
-    shapes). q/k/v (b, h, 1, dh), allowed (b, rows) -> (b, h, 1, dh)
-    BEFORE out_sync/out-projection."""
+    shapes, ``read_heads_merged`` a group's contraction form from them and
+    from ``mesh``: whether the step was handed ``out_sync``). q/k/v (b, h,
+    1, dh), allowed (b, rows) -> (b, h, 1, dh) BEFORE
+    out_sync/out-projection."""
     def read(sl):
         t = tables[sl]
         return _paged_gather_read(
             pool, layer, t, q[sl], k[sl], v[sl], allowed[sl], scale=scale,
-            v_after_k=t.shape[0] < tables.shape[0])
+            v_after_k=t.shape[0] < tables.shape[0], mesh=mesh)
     return _read_in_slot_groups(pool, tables, read)
 
 
@@ -627,7 +732,13 @@ def _decode_step_math(params: dict, x_tok: Array, pos: Array, cache: dict,
     contract them page-major under the same masked softmax
     (``_paged_gather_read``), a slot group at a time
     (``_paged_gather_attend``) — one read of the pool a step, and no
-    buffer of the pool's size besides the pool; ``'kernel'`` consumes
+    buffer of the pool's size besides the pool. Which contraction runs
+    is decided from the shapes and from ``out_sync`` by
+    ``read_heads_merged``: all heads' queries against a slot's pages as
+    whole rows, one product on the matrix unit (pages of whole tiles of
+    rows and no mesh: both ``dalle`` cells), or a head's query against
+    its own pages, lane reductions on the vector unit (a mesh; PERF.md
+    section 6, PR 34); ``'kernel'`` consumes
     the tables in place via the Pallas ragged paged-attention kernel
     (``ops.paged_attention``), which fetches only each slot's mapped
     live pages into VMEM and returns online-softmax partials that the
@@ -704,7 +815,8 @@ def _decode_step_math(params: dict, x_tok: Array, pos: Array, cache: dict,
             # kv is this layer's INDEX: gather its pages from the pool
             # and contract them as they lie
             out = _paged_gather_attend(cache, kv, view_tables, q, k, v,
-                                       allowed, scale=cfg.scale)
+                                       allowed, scale=cfg.scale,
+                                       mesh=out_sync is not None)
         elif kernel_mode:
             # kv is the raw page pool for this layer; the kernel walks
             # the block tables in place (_kernel_read completes the
@@ -856,7 +968,7 @@ def _decode_step_math_sparse_reads(params: dict, x_tok: Array, pos: Array,
             out = _paged_gather_attend(
                 pool, kv, vis_bt if is_sparse else bt, q, k, v,
                 vis_allowed if is_sparse else dense_allowed,
-                scale=cfg.scale)
+                scale=cfg.scale, mesh=out_sync is not None)
         if out_sync is not None:
             # the mesh seam, unchanged: gather heads before the out
             # projection instead of letting GSPMD partial-sum it
@@ -917,7 +1029,9 @@ def _decode_step_math_sparse_reads(params: dict, x_tok: Array, pos: Array,
 # default): inside the layer scan each layer gathers its own pages through
 # the block tables (``layer_pool_view``: whole pages, page-major, indexed
 # by layer into the pool itself) and contracts them in that form
-# (``_paged_gather_read``); only the scores are brought to logical row
+# (``_paged_gather_read``: as whole rows against all heads' queries on the
+# matrix unit where ``read_heads_merged`` finds the shapes for it, per head
+# otherwise); only the scores are brought to logical row
 # order, so the softmax is the dense step's and paged-vs-dense tokens are
 # equal. The slots are read a group at a time, so that a group's gathered
 # pages stay in VMEM between the gather and its readers; the groups are

@@ -682,6 +682,11 @@ class Engine:
         self.kv_view_groups = 1         # slot groups a layer's paged gather
         #                                 read was traced with (ops.decode
         #                                 view_slot_groups; 1 = no such read)
+        self.attn_read_heads_merged = 0     # 1: that read, the classic
+        #                                 block's, was traced with all heads'
+        #                                 queries against whole rows (ops.
+        #                                 decode read_heads_merged); 0: per
+        #                                 head, or no such read
         self._t_start = None
         self._last_log = 0
 
@@ -927,6 +932,11 @@ class Engine:
             # (the full layers' groups, of a window-and-full block)
             self.kv_view_groups = decode_ops.pool_view_groups(
                 cache, self.num_slots, self.slot_max_pages)
+            if self.block is None:
+                self.attn_read_heads_merged = int(
+                    decode_ops.read_heads_merged(
+                        cache["k"].shape[2:], cache["k"].dtype,
+                        mesh=self._decode_out_sync() is not None))
         return decode_ops.decode_loop_paged(
             params["transformer"], cur_tok, pos, active, cache,
             block_tables, cfg=self.cfg.transformer,
@@ -3187,6 +3197,7 @@ class Engine:
                 self.harvests / max(self.tokens_decoded, 1), 6),
             "sample_sorted_chunks": self.sample_sorted_chunks,
             "kv_view_groups": self.kv_view_groups,
+            "attn_read_heads_merged": self.attn_read_heads_merged,
             # the obs surface: flight-recorder occupancy (retention is
             # the ring capacity, /debug/events serves the contents) and
             # the serve-side profiler state

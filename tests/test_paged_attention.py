@@ -551,6 +551,167 @@ class TestPerLayerRead:
             fewer = max(g for g in range(1, groups) if slots % g == 0)
             assert slots // fewer * slot_bytes > decode_ops._VIEW_VMEM_BYTES
 
+    # ---- ISSUE 34: the all-heads (merged) form of the read ----
+
+    @staticmethod
+    def _merged_case(kind, total_len, slots=4, heads=4, dim_head=128):
+        """A pool of the shapes the rule gives the merged form (whole
+        lanes, a page a whole tile of rows: 16 bf16 rows, 32 int8 rows),
+        random everywhere (trash and unmapped pages too), ``slots`` slots
+        at ragged positions: one sharing a page, one with trash entries,
+        one on its last row."""
+        ps = 32 if kind == "int8" else 16
+        need = KV.pages_for(total_len, ps)
+        dtype = jnp.float32 if kind == "int8" else jnp.bfloat16
+        key = jax.random.PRNGKey(34 + total_len)
+        shape = (2, slots * need + 1, heads, ps, dim_head)
+        if kind == "int8":
+            pool = {n: jax.random.randint(jax.random.fold_in(key, i), shape,
+                                          -127, 128, jnp.int8)
+                    for i, n in enumerate("kv")}
+            pool.update({n + "_scale": jax.random.uniform(
+                jax.random.fold_in(key, 2 + i), shape[:-1], minval=0.01,
+                maxval=0.1) for i, n in enumerate("kv")})
+        else:
+            pool = {n: jax.random.normal(jax.random.fold_in(key, i), shape,
+                                         dtype) for i, n in enumerate("kv")}
+        bt = np.arange(1, slots * need + 1, dtype=np.int32).reshape(
+            slots, need)
+        pos = np.array([total_len - 1, total_len // 2, 5, total_len - 3])
+        bt[3, 0] = bt[0, 0]                          # a shared page
+        bt[1, KV.pages_for(pos[1] + 1, ps):] = 0     # trash entries
+        q, k, v = [jax.random.normal(jax.random.fold_in(key, 10 + i),
+                                     (slots, heads, 1, dim_head), dtype)
+                   for i in range(3)]
+        allowed = (jnp.arange(total_len)[None, :]
+                   < jnp.asarray(pos)[:, None]).at[0, 1].set(False)
+        return pool, jnp.asarray(bt), (q, k, v), allowed, ps
+
+    @pytest.mark.parametrize("groups", [1, 2], ids=["one_group",
+                                                    "two_groups"])
+    @pytest.mark.parametrize("total_len", [96, 83],
+                             ids=["whole_pages", "partial_last_page"])
+    @pytest.mark.parametrize("table", ["full", "visible_slice"])
+    @pytest.mark.parametrize("kind", ["bf16", "int8"])
+    def test_merged_read_matches_view_oracle_and_per_head(
+            self, monkeypatch, kind, table, total_len, groups):
+        """All heads' queries against a slot's pages as whole rows
+        (``read_heads_merged`` gives that form for these shapes) equals
+        the ``paged_view`` + ``_gather_read`` oracle under the same masks
+        and the per-head form: over the bf16 pool and the int8 pool with
+        its scale pages, the full table and a sparse layer's visible
+        slice of it, whole pages and a partial last page, one slot group
+        and two (``v_after_k``)."""
+        pool, bt, (q, k, v), allowed, ps = self._merged_case(kind, total_len)
+        slots, need = bt.shape
+        scale = 128 ** -0.5
+        layer = jnp.asarray(1)
+        view = decode_ops.paged_view(pool, bt, total_len)
+        if table == "visible_slice":
+            # a sparse layer reads a narrower table: each slot's visible
+            # logical pages, and the row mask remapped onto its columns
+            visible = jnp.asarray(
+                [[0, need - 1], [0, 1], [0, 0], [1, need - 1]], jnp.int32)
+            live = jnp.asarray([2, 2, 1, 2])
+            cols = (visible[:, :, None] * ps
+                    + jnp.arange(ps)[None, None, :]).reshape(slots, -1)
+            pad_ok = jnp.repeat(jnp.arange(2)[None, :] < live[:, None], ps,
+                                axis=1)
+            read_allowed = (jnp.take_along_axis(
+                allowed, jnp.minimum(cols, total_len - 1), axis=1)
+                & pad_ok & (cols < total_len))
+            read_bt = KV.visible_table_view(bt, visible)
+            seen = jnp.zeros((slots, need * ps), bool).at[
+                jnp.arange(slots)[:, None], cols].max(pad_ok)
+            oracle_allowed = allowed & seen[:, :total_len]
+        else:
+            read_bt, read_allowed, oracle_allowed = bt, allowed, allowed
+        want = decode_ops._gather_read(
+            q, k, v, view["k"][1], view["v"][1], oracle_allowed, scale=scale,
+            ksc=view["k_scale"][1] if kind == "int8" else None,
+            vsc=view["v_scale"][1] if kind == "int8" else None)
+
+        def attend():
+            return decode_ops._paged_gather_attend(
+                pool, layer, read_bt, q, k, v, read_allowed, scale=scale)
+
+        if groups > 1:
+            self._force_groups(monkeypatch, pool, slots, read_bt.shape[1],
+                               groups)
+        assert decode_ops.read_heads_merged(pool["k"].shape[2:],
+                                            pool["k"].dtype)
+        calls = []
+        real = decode_ops._scores_heads_merged
+        monkeypatch.setattr(
+            decode_ops, "_scores_heads_merged",
+            lambda *a: calls.append(a[1].shape[0]) or real(*a))
+        got = attend()
+        assert calls == [slots // groups] * groups   # the merged form ran
+        monkeypatch.setattr(decode_ops, "read_heads_merged",
+                            lambda *a, **kw: False)
+        per_head = attend()
+        assert len(calls) == groups                  # and here it did not
+        assert got.shape == want.shape and got.dtype == want.dtype
+        tol = dict(rtol=2e-2, atol=2e-2) if kind == "bf16" else \
+            dict(rtol=2e-5, atol=2e-5)
+        for other in (want, per_head):
+            np.testing.assert_allclose(np.asarray(got, np.float32),
+                                       np.asarray(other, np.float32), **tol)
+
+    @pytest.mark.parametrize("page,dtype,mesh,want", [
+        ((16, 16, 128), jnp.bfloat16, False, True),
+        ((62, 16, 64), jnp.bfloat16, False, True),
+        ((16, 16, 128), jnp.bfloat16, True, False),
+        ((62, 16, 64), jnp.bfloat16, True, False),
+        ((16, 16, 128), jnp.int8, False, False),
+        ((16, 32, 128), jnp.int8, False, True),
+        ((2, 8, 128), jnp.float32, False, True),
+        ((2, 8, 128), jnp.bfloat16, False, False),
+        ((2, 24, 8), jnp.bfloat16, False, False),
+    ], ids=["rudalle-xl", "dalle-12b", "rudalle-xl-mesh", "dalle-12b-mesh",
+            "int8-half-a-tile-of-rows", "int8-whole-tile", "f32-8-rows",
+            "bf16-8-rows", "bf16-a-tile-and-a-half"])
+    def test_read_form_rule_on_the_cells_shapes(self, page, dtype, mesh,
+                                                want):
+        """``read_heads_merged`` sees the page's shape, the pool's dtype
+        and the mesh seam: the published shapes of both ``dalle``
+        configurations are merged (ruDALL-E's 16 heads of 128; 12b's 62
+        of 64, which the chip run of ISSUE 34 admitted), a mesh call is
+        per head, and so is a page that is not whole tiles of rows (the
+        merge of pages into rows would copy the gathered buffer)."""
+        assert decode_ops.read_heads_merged(page, dtype, mesh) is want
+
+    @pytest.mark.parametrize("mesh", [False, True],
+                             ids=["one_device", "mesh_seam"])
+    def test_step_hands_the_rule_the_mesh_seam(self, monkeypatch, mesh):
+        """The step decides the form from what it is handed: with
+        ``out_sync`` given (the mesh engine's seam) the rule is asked
+        with ``mesh=True`` and the read stays per head."""
+        cfg = self.WIDE_CFG
+        tcfg = cfg.transformer
+        params = D.dalle_init(jax.random.PRNGKey(0), cfg,
+                              V.vae_init(jax.random.PRNGKey(1), VCFG))
+        L, ps = cfg.seq_len, 8
+        mp = KV.pages_for(L, ps)
+        pool = _random_pool(jax.random.PRNGKey(3), ps, 2 * mp + 1, False,
+                            dim_head=128)
+        bt = jnp.asarray(np.arange(1, 2 * mp + 1, dtype=np.int32)
+                         .reshape(2, mp))
+        asked = []
+        real = decode_ops.read_heads_merged
+
+        def spy(*a):
+            asked.append(a[-1])
+            return real(*a)
+        monkeypatch.setattr(decode_ops, "read_heads_merged", spy)
+        decode_ops._decode_step_math(
+            params["transformer"], jnp.zeros((2, tcfg.dim)),
+            jnp.asarray([9, 3], jnp.int32), pool, cfg=tcfg,
+            key_mask=jnp.ones((2, L), bool), block_tables=bt,
+            out_sync=(lambda out: out) if mesh else None)
+        assert asked and all(m is mesh for m in asked)
+        assert real((2, ps, 128), jnp.float32, mesh) is (not mesh)
+
     def _loop_args(self, bundle, page_size, quantized, cfg=CFG):
         """A mid-sequence chunk: 3 slots at ragged positions (one parked
         dead), random page content everywhere, greedy sampling through
@@ -560,7 +721,7 @@ class TestPerLayerRead:
         L = cfg.seq_len
         mp = KV.pages_for(L, page_size)
         pool = _random_pool(jax.random.PRNGKey(21), page_size,
-                            3 * mp + 1, quantized)
+                            3 * mp + 1, quantized, dim_head=tcfg.dim_head)
         bt = jnp.asarray(np.arange(1, 3 * mp + 1, dtype=np.int32)
                          .reshape(3, mp))
         pos = jnp.asarray([9, 14, 0], jnp.int32)
@@ -636,6 +797,76 @@ class TestPerLayerRead:
         for i in range(3):                             # tok, pos, active
             np.testing.assert_array_equal(np.asarray(paged[i]),
                                           np.asarray(dense[i]))
+
+    WIDE_CFG = D.DALLEConfig(dim=16, depth=2, vae=VCFG, num_text_tokens=50,
+                             text_seq_len=8, heads=2, dim_head=128)
+    WIDE_SPARSE_CFG = D.DALLEConfig(
+        dim=16, depth=2, vae=VCFG, num_text_tokens=50, text_seq_len=8,
+        heads=2, dim_head=128, sparse_attn=(True, False), sparse_block=4)
+
+    @pytest.mark.parametrize("groups", [1, 3], ids=["one_group",
+                                                    "a_slot_a_group"])
+    @pytest.mark.parametrize("quantized,page_size", [(False, 8), (True, 32)],
+                             ids=["f32", "int8"])
+    @pytest.mark.parametrize("sparse_reads", [False, True],
+                             ids=["dense_reads", "sparse_reads"])
+    def test_merged_loop_tokens_equal_per_head_loop(
+            self, monkeypatch, sparse_reads, quantized, page_size, groups):
+        """ISSUE 34: on float32 weights the fused loop emits the same
+        greedy tokens whether its reads are traced merged (the rule's
+        choice at 128-wide heads and whole tiles of rows) or per head
+        (the rule patched here; no knob in the program), and both emit
+        the dense loop's."""
+        cfg = self.WIDE_SPARSE_CFG if sparse_reads else self.WIDE_CFG
+        vae_params = V.vae_init(jax.random.PRNGKey(1), VCFG)
+        params = D.dalle_init(jax.random.PRNGKey(0), cfg, vae_params)
+        tp, cur, pos, active, pool, bt, L, kw = self._loop_args(
+            (params, vae_params), page_size, quantized, cfg)
+        assert pool["k"].shape[2:] == (2, page_size, 128)
+        if groups > 1:
+            monkeypatch.setattr(decode_ops, "_VIEW_VMEM_BYTES", 1)
+        assert decode_ops.pool_view_groups(pool, *bt.shape) == groups
+        assert decode_ops.read_heads_merged(pool["k"].shape[2:],
+                                            pool["k"].dtype)
+
+        def loop():
+            return decode_ops.decode_loop_paged(
+                tp, cur, pos, active, pool, bt, total_len=L,
+                sparse_reads=sparse_reads, **kw)
+
+        merged = loop()
+        monkeypatch.setattr(decode_ops, "read_heads_merged",
+                            lambda *a, **kw: False)
+        per_head = loop()
+        dense = decode_ops.decode_loop(
+            tp, cur, pos, active, decode_ops.paged_view(pool, bt, L), **kw)
+        assert (np.asarray(merged[4])[:2] >= 0).all()   # real tokens
+        for other in (per_head, dense):
+            for i in (0, 1, 2, 4):                # tok, pos, active, ring
+                np.testing.assert_array_equal(np.asarray(merged[i]),
+                                              np.asarray(other[i]))
+
+    @pytest.mark.parametrize("page_size,want", [(4, 0), (8, 1)],
+                             ids=["half_a_tile_of_rows", "whole_tiles"])
+    def test_engine_reports_the_read_form_it_traced(self, bundle, page_size,
+                                                    want):
+        """``stats()["attn_read_heads_merged"]``: the form the decode
+        program's classic read was traced with (float32 pages of 8 rows
+        are whole tiles, of 4 are not), beside ``kv_view_groups``; the
+        served tokens do not depend on it."""
+        params, vae_params = bundle
+        queue = RequestQueue(max_depth=4)
+        engine = Engine(params, CFG, queue, num_slots=2, chunk_steps=4,
+                        kv="paged", page_size=page_size)
+        assert engine.stats()["attn_read_heads_merged"] == 0   # no trace yet
+        h = queue.submit(REQS[0])
+        engine.run_until_idle()
+        np.testing.assert_array_equal(
+            np.asarray(h.result(5).tokens),
+            reference_tokens(params, vae_params, REQS[0]))
+        assert engine.stats()["attn_read_heads_merged"] == want
+        assert engine.stats()["kv_view_groups"] == 1
+        assert engine.decode_traces == 1
 
     @pytest.mark.parametrize("budget,want", [(None, 1), (1, 2)],
                              ids=["the_rule", "one_slot_a_group"])
