@@ -87,7 +87,8 @@ class DALLEConfig:
     # and axial positions (ops.transformer.LatentMoEBlock): rotary
     # positions inside the block, so no position tables; an untied,
     # bias-free head behind an RMSNorm. Served, not trained.
-    block: Optional[Union[T.LatentMoEBlock, T.WindowGQABlock]] = None
+    block: Optional[Union[T.LatentMoEBlock, T.WindowGQABlock,
+                          T.SSMHybridBlock]] = None
 
     @property
     def image_seq_len(self) -> int:
@@ -162,6 +163,13 @@ def dalle_init(key: Array, cfg: DALLEConfig,
         "transformer": T.transformer_init(ks[5], cfg.transformer, dtype),
     }
     k_head = jax.random.fold_in(ks[5], 1)
+    if cfg.block is not None and cfg.block.tied_head:
+        # no position of any kind; the head is the embedding rows
+        # themselves behind a LayerNorm (``to_logits``): text rows, image
+        # rows, and the EOS row, which is never an input
+        params["eos_emb"] = core.embedding_init(k_head, 1, cfg.dim, dtype)
+        params["to_logits"] = {"ln": core.layernorm_init(cfg.dim, dtype)}
+        return params
     if cfg.block is not None:
         # positions are the block's own (rotary): no tables; an untied,
         # bias-free head behind an RMSNorm
@@ -229,7 +237,7 @@ def embed_prompt(params: dict, cfg: DALLEConfig, text: Array,
                  image_ids: Optional[Array] = None) -> Array:
     """Token embeddings for [text (b, t)] ++ [image ids (b, n_img)]."""
     b, t = text.shape
-    learned = cfg.block is None      # else rotary, inside the block
+    learned = cfg.block is None      # else the block's own, or none
     tok = jnp.take(params["text_emb"]["w"], text, axis=0)
     if learned:
         tok = tok + params["text_pos_emb"]["w"][None, :t]
@@ -282,13 +290,20 @@ def decode_token_embed(params: dict, cfg: DALLEConfig, cur_tok: Array,
 def to_logits(params: dict, h: Array,
               cfg: Optional[DALLEConfig] = None) -> Array:
     """The head behind its norm; a described block's configuration says
-    the norm's epsilon (without one it is the norm's default)."""
+    the norm's epsilon (without one it is the norm's default). A head
+    without a projection of its own is TIED: the logits are ``h`` against
+    the text, image and EOS embedding rows themselves, in the order of
+    the vocabulary (the rows are not held twice)."""
     ln = params["to_logits"]["ln"]
     if cfg is not None and cfg.block is not None:
-        h = core.rmsnorm(ln, h, eps=cfg.block.norm_eps)
+        h = T.block_norm(ln, h, cfg.block)
     else:
         h = core.norm(ln, h)
-    return core.linear(params["to_logits"]["proj"], h)
+    if "proj" in params["to_logits"]:
+        return core.linear(params["to_logits"]["proj"], h)
+    return jnp.concatenate(
+        [jnp.einsum("...d,vd->...v", h, params[name]["w"].astype(h.dtype))
+         for name in ("text_emb", "image_emb", "eos_emb")], axis=-1)
 
 
 def draft_transformer_config(tcfg: T.TransformerConfig,

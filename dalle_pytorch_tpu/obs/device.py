@@ -45,6 +45,10 @@ SCOPES = (
     "moe.route",        # dropless routing: router, top-k, sort, combine
     "moe.experts",      # the grouped products over the experts with rows
     "moe.shared",       # the shared experts
+    "ssm.proj",         # a state-space layer's four matrix products
+    "ssm.scan",         # its convolution, the state's read, update, readout
+                        # and write; in prefill the scan over the positions
+    "gmu",              # a gated memory unit: its two products and the gate
     "head",             # logits
     "sample",           # filtering and sampling
     "loss",             # cross-entropy

@@ -362,11 +362,13 @@ def _read_scope(window: bool):
 
 
 def gqa_attend_materialised(q: Array, k: Array, v: Array, allowed: Array,
-                            scale: float, window: bool) -> Array:
+                            scale: float, window: bool,
+                            diff_lam: Optional[Array] = None) -> Array:
     """The prefill read. q (b, n, heads, dh), k / v (b, m, kv_heads, dh),
     allowed broadcastable to (b, 1, n, m) -> (b, n, heads, dh). ``window``
     names the read in a trace (``_read_scope``); the window itself is in
-    ``allowed``."""
+    ``allowed``. With ``diff_lam`` the read is the differential one
+    (``_pair_weights``) -> (b, n, heads / 2, 2 dh)."""
     b, n, heads, dh = q.shape
     kvh = k.shape[2]
     with _read_scope(window):
@@ -375,13 +377,17 @@ def gqa_attend_materialised(q: Array, k: Array, v: Array, allowed: Array,
                           preferred_element_type=jnp.float32) * scale
         dots = jnp.where(allowed[:, :, None], dots,
                          core.neg_inf(dots.dtype))
-        w = jax.nn.softmax(dots, axis=-1).astype(v.dtype)
-        return jnp.einsum("bkgij,bjkd->bikgd", w, v).reshape(q.shape)
+        w = jax.nn.softmax(dots, axis=-1)
+        if diff_lam is not None:
+            w = _pair_weights(w, diff_lam)
+            v = v.reshape(v.shape[:2] + (kvh // 2, 2 * dh))
+        o = jnp.einsum("bkgij,bjkd->bikgd", w.astype(v.dtype), v)
+        return o.reshape((b, n, -1, v.shape[-1]))
 
 
 def gqa_attend_rows(q: Array, k: Array, v: Array, rows_k: Array,
                     gather_v, allowed: Array, scale: float,
-                    window: bool) -> Array:
+                    window: bool, diff_lam: Optional[Array] = None) -> Array:
     """The decode read, one query a slot: q (b, heads, dh); k / v (b,
     kv_heads, dh) the token's own rows (always attended); ``rows_k`` (b,
     m, kv_heads * dh) the cached K rows as they lie in the gathered pages,
@@ -398,7 +404,9 @@ def gqa_attend_rows(q: Array, k: Array, v: Array, rows_k: Array,
     alone (a batch dimension between page and row) is what the compiler
     turned into a relayout of every gathered page (PERF.md section 6,
     PR 33); the zeros cost matrix-unit passes that have no other use here.
-    ``window`` names the read in a trace."""
+    ``window`` names the read in a trace. With ``diff_lam`` the read is
+    the differential one: the same products and softmaxes, the pairing
+    (``_pair_weights``) after them -> (b, heads / 2, 2 dh)."""
     b, heads, dh = q.shape
     kvh = k.shape[1]
     qg = q.reshape(b, kvh, heads // kvh, dh)
@@ -411,6 +419,14 @@ def gqa_attend_rows(q: Array, k: Array, v: Array, rows_k: Array,
                          preferred_element_type=jnp.float32) * scale
         wts = jax.nn.softmax(jnp.concatenate(
             [scores, own.reshape(b, heads, 1)], axis=-1), axis=-1)
+        if diff_lam is not None:
+            # from here on a pair of heads is one head of twice the width
+            # over a pair of key/value heads
+            wts = _pair_weights(
+                wts.reshape(b, kvh, heads // kvh, -1), diff_lam
+            ).reshape(b, heads // 2, -1)
+            heads, kvh, dh = heads // 2, kvh // 2, 2 * dh
+            v = v.reshape(b, kvh, dh)
         wts = wts.astype(v.dtype)
     rows_v = gather_v(wts)
     with _read_scope(window):
@@ -419,8 +435,21 @@ def gqa_attend_rows(q: Array, k: Array, v: Array, rows_k: Array,
         o = jnp.einsum("bkgjd,kj->bkgd",
                        o_rows.reshape(b, kvh, heads // kvh, kvh, dh),
                        jnp.eye(kvh, dtype=o_rows.dtype))
-        o = o + wts[..., -1].reshape(qg.shape[:3] + (1,)) * v[:, :, None, :]
+        o = o + wts[..., -1].reshape(b, kvh, heads // kvh, 1) \
+            * v[:, :, None, :]
         return o.reshape(b, heads, dh)
+
+
+def _pair_weights(w: Array, lam: Array) -> Array:
+    """The differential read's pairing, after the softmaxes: ``w`` (b,
+    kv_heads, group, ...) float32 softmax weights, head (k, g) reading
+    key/value head k. Key/value heads pair up (2p, 2p + 1); the heads that
+    read 2p + 1 are subtracted, times ``lam``, from the heads that read 2p
+    -> (b, kv_heads / 2, group, ...): the weights of ``group`` double
+    heads a PAIR of key/value heads, over whose values side by side (2 dh
+    wide) they are summed."""
+    paired = w.reshape((w.shape[0], w.shape[1] // 2, 2) + w.shape[2:])
+    return paired[:, :, 0] - lam.astype(w.dtype) * paired[:, :, 1]
 
 
 def _own_columns(qg: Array) -> Array:
@@ -438,3 +467,71 @@ def gqa_out(params: dict, o: Array, gate: Array) -> Array:
     gate and the output projection."""
     o = o.reshape(o.shape[:-2] + (-1,))
     return core.linear(params["out"], o * jax.nn.sigmoid(gate))
+
+
+# ---------------------------------------------------------------------------
+# differential attention (the attention layers of the ``SSMHybridBlock`` of
+# ops/transformer.py; arXiv:2410.05258)
+# ---------------------------------------------------------------------------
+#
+# The grouped-query reads above with one step more: heads come in pairs,
+# each softmax over its own key head of a PAIR of key/value heads, and the
+# second map is subtracted, times a learned scalar a layer, from the first
+# before the pair's values (side by side, twice the width) are summed
+# (``_pair_weights``); the pair's output is RMS-normed and scaled by 1 -
+# lam_init. The query heads lie grouped by the key head they read, as the
+# grouped-query reads take them: head 4p + 2s + j is the s-th head (0: the
+# map that stays, 1: the map that is subtracted) of the j-th pair over the
+# key/value heads (2p, 2p + 1) and reads key head 2p + s. Biases on every
+# projection. No position enters.
+
+def diff_init(key: Array, dim: int, heads: int, blk, lam_init: float,
+              dtype=jnp.float32, own_kv: bool = True) -> dict:
+    """``own_kv`` False: a layer that reads another layer's keys and
+    values and projects none. ``lam`` holds the four vectors of the
+    lambda, (q1, k1, q2, k2), N(0, 0.1); ``lam_init`` the layer's own
+    constant."""
+    ks = jax.random.split(key, 5)
+    dh, kvh = blk.head_dim, blk.kv_heads
+    out = {
+        "q": core.linear_init(ks[0], dim, heads * dh, dtype=dtype),
+        "lam": core.normal_init(ks[3], (4, dh), 0.1, jnp.float32),
+        "lam_init": jnp.asarray(lam_init, jnp.float32),
+        "sub_ln": core.rmsnorm_init(2 * dh, dtype),
+        "out": core.linear_init(ks[4], heads * dh, dim, dtype=dtype),
+    }
+    if own_kv:
+        out["k"] = core.linear_init(ks[1], dim, kvh * dh, dtype=dtype)
+        out["v"] = core.linear_init(ks[2], dim, kvh * dh, dtype=dtype)
+    return out
+
+
+@jax.named_scope("attn.proj")
+def diff_project(params: dict, h: Array, heads: int, blk):
+    """h (..., dim) normed input -> (q (..., heads, dh), (k, v) each (...,
+    kv_heads, dh): the rows to cache, or None for a layer that projects
+    none)."""
+    dh, kvh = blk.head_dim, blk.kv_heads
+    lead = h.shape[:-1]
+    q = core.linear(params["q"], h).reshape(lead + (heads, dh))
+    if "k" not in params:
+        return q, None
+    return q, (core.linear(params["k"], h).reshape(lead + (kvh, dh)),
+               core.linear(params["v"], h).reshape(lead + (kvh, dh)))
+
+
+def diff_lambda(params: dict) -> Array:
+    """exp(lq1 . lk1) - exp(lq2 . lk2) + lam_init, a float32 scalar."""
+    lam = params["lam"].astype(jnp.float32)
+    return jnp.exp(jnp.sum(lam[0] * lam[1])) \
+        - jnp.exp(jnp.sum(lam[2] * lam[3])) + params["lam_init"]
+
+
+def diff_out(params: dict, o: Array, eps: float) -> Array:
+    """o (..., heads / 2, 2 dh), a pair's output -> (..., dim): the norm
+    over each pair's output, the scale 1 - lam_init, the output
+    projection."""
+    o = core.rmsnorm(params["sub_ln"], o, eps=eps)
+    with jax.named_scope("attn.proj"):
+        o = o * (1.0 - params["lam_init"]).astype(o.dtype)
+        return core.linear(params["out"], o.reshape(o.shape[:-2] + (-1,)))

@@ -83,7 +83,7 @@ def view_slot_groups(slots: int, columns: int, page_shape, dtype) -> int:
 def pool_view_groups(pool: dict, slots: int, columns: int) -> int:
     """``view_slot_groups`` of a page pool read through a table of
     (slots, columns), trimmed as the read trims it."""
-    buf = pool["latent"] if "latent" in pool else next(iter(pool.values()))
+    buf = pool["latent"] if "latent" in pool else pool["k"]
     return view_slot_groups(slots, columns, buf.shape[2:], buf.dtype)
 
 
@@ -580,26 +580,33 @@ def _attn_with_kv(lp: dict, h: Array, allowed: Array, cfg,
 def prefill(params: dict, x: Array, *, cfg, total_len: int,
             prompt_mask: Optional[Array] = None,
             quantize_cache: bool = False,
-            out_sync=None) -> Tuple[Array, dict]:
+            out_sync=None,
+            lens: Optional[Array] = None) -> Tuple[Array, dict]:
     """Run the prompt embeddings x (b, t0, dim) through the stack.
 
     Returns (h_out (b, t0, dim), cache with rows [0, t0) filled).
-    ``quantize_cache`` stores the cache int8 (see init_cache).
+    ``quantize_cache`` stores the cache int8 (see init_cache). ``lens``
+    (b,) is each row's own prompt length where the rows are padded to t0:
+    a described block's recurrent state is the one after exactly that many
+    tokens (rows need no telling: a padded row is never attended before
+    the decode overwrites it).
     """
     from dalle_pytorch_tpu.ops import transformer as T
     b, t0, _ = x.shape
     if cfg.block is not None:
         # a described block's prefill IS its full forward (the
         # materialised read); the cache it returns is the prompt's rows
-        # alone, {"latent": (depth, b, t0, row_width)} or {"k", "v":
-        # (depth, b, t0, kv_heads, head_dim)}: its store is the page
-        # pool, and the engine's admission writes whole pages
+        # alone, a buffer of the pool each (``block_stack``): {"latent":
+        # (depth, b, t0, row_width)}, or {"k", "v", "window_k",
+        # "window_v": (the pool's layers, b, t0, kv_heads, head_dim)},
+        # and a state-space layer's state after each row's last token:
+        # its store is the page pool, and the engine's admission writes
+        # whole pages
         if quantize_cache:
             _refuse_block(cfg, "quantize_cache")
-        h_out, entries, _ = T.block_apply_full(params, x, cfg, prompt_mask)
-        if isinstance(cfg.block, T.WindowGQABlock):
-            return h_out, {"k": entries[0], "v": entries[1]}
-        return h_out, {"latent": entries}
+        h_out, entries, _ = T.block_apply_full(params, x, cfg, prompt_mask,
+                                               lens)
+        return h_out, entries
     sparse_flags = jnp.asarray(cfg.sparse_pattern)
     any_sparse = any(cfg.sparse_pattern)
 
@@ -1211,15 +1218,21 @@ def ring_key_mask(key_mask: Array, held: Array) -> Array:
     return ok
 
 
-def _block_reads(cfg, pool: dict, block_tables, pos: Array,
+def _block_reads(cfg, pool: dict, block_tables: dict, pos: Array,
                  key_mask: Array):
-    """The paged reads of a described block's decode step, one query a
-    slot: -> ``read_of(layer, run)``, which gives ``block_layer`` its
-    read for the layer ``layer`` (traced, its index in its pool) of the
-    run ``run``. Each read gathers its layer's pages through the tables
+    """The reads of a described block's decode step, one query a slot
+    (``block_tables``: a table a page pool, ``{"full": ..., "window":
+    ...}``): ->
+    ``read_of(layer, run)``, which gives ``block_layer`` its read for the
+    layer ``layer`` (traced, its index in the cache it reads) of the run
+    ``run``. A paged read gathers its layer's pages through the tables
     (``layer_pool_view``), a slot group at a time (the groups are decided
     by ``view_slot_groups``, the classic step's rule, and looped by
-    ``_read_in_slot_groups``), and contracts them as they lie."""
+    ``_read_in_slot_groups``), and contracts them as they lie; a layer
+    that reads ANOTHER layer's rows (``LayerKind.stores`` False) reads the
+    same pool at that layer's index. A state-space layer's read takes the
+    layer's state of every slot and advances it by the token."""
+    from dalle_pytorch_tpu.ops import ssm as ssm_ops
     from dalle_pytorch_tpu.ops import transformer as T
     blk = cfg.block
     total_len = key_mask.shape[1]
@@ -1240,9 +1253,9 @@ def _block_reads(cfg, pool: dict, block_tables, pos: Array,
         with _pool_scope(window):
             return pages.reshape(pages.shape[0], -1, pages.shape[-1])
 
-    ps = next(iter(pool.values())).shape[2]
     if isinstance(blk, T.LatentMoEBlock):
-        tables = _view_tables(block_tables, total_len, ps)
+        ps = pool["latent"].shape[2]
+        tables = _view_tables(block_tables["full"], total_len, ps)
         with jax.named_scope("attn.read"):       # the mask
             allowed = before(tables, ps)
 
@@ -1259,23 +1272,42 @@ def _block_reads(cfg, pool: dict, block_tables, pos: Array,
             return read
         return read_of
 
-    # the window-and-full block: a pool and a table a layer type. A full
+    # pools of whole K and V rows: a pool and a table a layer type. A full
     # layer's table is as wide as the sequence and its rows lie in order;
     # a window layer's is a ring of ``ring_pages`` columns
-    full_t = _view_tables(block_tables["full"], total_len, ps)
-    ring_t = block_tables["window"]
-    with jax.named_scope("attn.read"):
-        full_ok = before(full_t, ps)
-    with jax.named_scope("attn.window"):
-        held, ring_ok = window_rows(pos, ring_t.shape[1] * ps, blk.window)
-        ring_ok = ring_ok & ring_key_mask(key_mask, held)
+    by_type = {}
+    if "k" in pool:
+        ps = pool["k"].shape[2]
+        full_t = _view_tables(block_tables["full"], total_len, ps)
+        with jax.named_scope("attn.read"):
+            by_type[True] = (full_t, before(full_t, ps))
+    if "window_k" in pool:
+        ring_t = block_tables["window"]
+        with jax.named_scope("attn.window"):
+            held, ring_ok = window_rows(
+                pos, ring_t.shape[1] * pool["window_k"].shape[2], blk.window)
+            by_type[False] = (ring_t,
+                              ring_ok & ring_key_mask(key_mask, held))
 
     def read_of(layer, run):
+        if run.kind.pool is None:
+            return None             # a layer that reads no cache
+        if run.kind.pool == "state":
+            def advance(p, x, _entry):
+                with jax.named_scope("ssm.scan"):
+                    state = tuple(lax.dynamic_index_in_dim(
+                        pool[name], layer, keepdims=False)
+                        for name in blk.pool_buffers("state"))
+                return ssm_ops.ssm_step(p, x, state)
+            return advance
         window = not run.full
-        k_name, v_name = blk.pool_buffers(run.full)
-        tables, allowed = (ring_t, ring_ok) if window else (full_t, full_ok)
+        k_name, v_name = blk.pool_buffers(run.kind.pool)
+        tables, allowed = by_type[run.full]
+        view = {"k": pool[k_name]}      # what decides the slot groups
 
-        def read(_p, q, entry):
+        def read(p, q, entry):
+            lam = attn_ops.diff_lambda(p) if "lam" in p else None
+
             def read_group(sl):
                 t = tables[sl]
 
@@ -1291,35 +1323,35 @@ def _block_reads(cfg, pool: dict, block_tables, pos: Array,
                 return attn_ops.gqa_attend_rows(
                     q[sl], entry[0][sl], entry[1][sl],
                     rows_of(k_name, layer, t, window), gather_v,
-                    allowed[sl], cfg.scale, window)
-            return _read_in_slot_groups(pool, tables, read_group)
+                    allowed[sl], cfg.scale, window, diff_lam=lam)
+            return _read_in_slot_groups(view, tables, read_group)
         return read
     return read_of
 
 
-def _store_block_rows(cfg, pool: dict, entries, pos: Array, block_tables,
-                      active: Array) -> dict:
-    """A decode step's new rows of every layer into a described block's
-    pool(s) (``_store_entries_paged``)."""
-    from dalle_pytorch_tpu.ops import transformer as T
+def _store_block_rows(cfg, pool: dict, entries: dict, pos: Array,
+                      block_tables, active: Array) -> dict:
+    """A decode step's new entries (``block_stack``'s, a buffer of the
+    pool each) into a described block's pool: the new rows of every layer
+    of a paged pool through its table (``_store_entries_paged``), a
+    state-space layer's new state over the old one where the slot is
+    active."""
     blk = cfg.block
-    if isinstance(blk, T.LatentMoEBlock):
-        return _store_entries_paged(pool, {"latent": entries}, pos,
-                                    block_tables, active)
-    # (depth, b, kv_heads, dh) -> (depth, b, kv_heads * dh): a row
-    rows = [e.reshape(e.shape[:2] + (-1,)) for e in entries]
     out = {}
-    for full, table in ((True, block_tables["full"]),
-                        (False, block_tables["window"])):
-        # jaxlint: disable=JL001 — the layers of one type, static
-        # configuration: a trace-time const
-        layers = np.asarray(blk.cache_layers(full), np.int32)
-        if layers.size:
-            names = blk.pool_buffers(full)
-            out.update(_store_entries_paged(
-                {n: pool[n] for n in names},
-                {n: r[layers] for n, r in zip(names, rows)},
-                pos, table, active, ring=not full))
+    for kind, names in blk.pools(cfg.depth).items():
+        if kind == "state":
+            with jax.named_scope("ssm.scan"):
+                for name in names:
+                    on = active.reshape((1, -1) + (1,) * (
+                        pool[name].ndim - 2))
+                    out[name] = jnp.where(on, entries[name], pool[name])
+            continue
+        # (layers, b, kv_heads, dh) -> (layers, b, kv_heads * dh): a row
+        out.update(_store_entries_paged(
+            {n: pool[n] for n in names},
+            {n: entries[n].reshape(entries[n].shape[:2] + (-1,))
+             for n in names},
+            pos, block_tables[kind], active, ring=kind == "window"))
     return out
 
 
@@ -1328,21 +1360,26 @@ def decode_step_block(params: dict, x_tok: Array, pos: Array, pool: dict,
                       active: Array) -> Tuple[Array, dict, Array]:
     """One token a slot through a described block (``cfg.block``) against
     its page pool(s): ``decode_step_paged``'s gather step with the block's
-    branches (``ops.transformer.block_layer``) and the block's paged read
+    branches (``ops.transformer.block_layer``) and the block's reads
     (``_block_reads``): the latent block's ABSORBED read of its one latent
-    pool, or the window-and-full block's grouped-query reads, a full
-    layer's of the full pool in row order and a window layer's of the
-    window pool's ring (``block_tables`` is then ``{"full": ...,
-    "window": ...}``). The new rows are written after the scan.
+    pool; grouped-query or differential reads, a full layer's of the full
+    pool in row order and a window layer's of the window pool's ring
+    (``block_tables`` is then ``{"full": ..., "window": ...}``), a cross
+    layer's of the full pool at the sharing layer's index; a state-space
+    layer's step against its slot's state. The new rows and states are
+    written after the scan; an inactive slot's state stays as it was.
     x_tok (b, dim), pos (b,) -> (h_out (b, dim), pool, load int32: the
     routed layers' load summed over them, ops.moe.dropless_apply)."""
     from dalle_pytorch_tpu.ops import transformer as T
+    if not isinstance(block_tables, dict):      # one pool: its one table
+        block_tables = {"full": block_tables}
     read_of = _block_reads(cfg, pool, block_tables, pos, key_mask)
 
-    def layer_fn(lp, h, layer, run):
-        return T.block_layer(lp, h, pos, read_of(layer, run), cfg, run)
+    def layer_fn(lp, h, shared, layer, run):
+        return T.block_layer(lp, h, shared, pos, read_of(layer, run), cfg,
+                             run)
 
-    h_out, (entries, loads) = T.block_stack(params, x_tok, layer_fn, cfg)
+    h_out, entries, loads = T.block_stack(params, x_tok, layer_fn, cfg)
     return (h_out, _store_block_rows(cfg, pool, entries, pos, block_tables,
                                      active), jnp.sum(loads, axis=0))
 

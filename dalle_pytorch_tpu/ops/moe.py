@@ -157,7 +157,9 @@ def holds_all(blk) -> bool:
 def load_width(blk) -> int:
     """Entries of a routed layer's load: picks routed, experts touched,
     the fullest expert's picks; and, where a share is held, the picks
-    that fell on it."""
+    that fell on it. None for a block without routed layers."""
+    if not blk.num_experts:
+        return 0
     return 3 if holds_all(blk) else 4
 
 

@@ -36,6 +36,7 @@ from jax import lax
 
 from dalle_pytorch_tpu.ops import attention as attn_ops
 from dalle_pytorch_tpu.ops import core, sparse
+from dalle_pytorch_tpu.ops import ssm as ssm_ops
 
 Array = jax.Array
 
@@ -57,20 +58,43 @@ class BlockOptionError(ValueError):
 
 @dataclasses.dataclass(frozen=True)
 class LayerKind:
-    """The two ways in which a described block's layers differ: a dense
-    or a routed feed-forward, and attention over every earlier row
-    (``full``) or over a window of them."""
+    """The ways in which a described block's layers differ: a dense or a
+    routed feed-forward; attention over every earlier row (``full``) or
+    over a window of them; and the ``mixer``: ``"attn"`` (attends its own
+    keys and values and caches them), ``"cross"`` (attends the rows that
+    an earlier full layer cached, and caches nothing), ``"ssm"`` (a
+    state-space layer: a recurrent state a slot, no rows) or ``"gmu"``
+    (reads an earlier state-space layer's output of the same token, and
+    caches nothing)."""
     moe: bool
     full: bool
+    mixer: str = "attn"
+
+    @property
+    def pool(self) -> Optional[str]:
+        """The cache this layer reads: ``"full"``, ``"window"``,
+        ``"state"`` or None."""
+        if self.mixer == "ssm":
+            return "state"
+        if self.mixer == "gmu":
+            return None
+        return "full" if self.full else "window"
+
+    @property
+    def stores(self) -> bool:
+        """Whether the layer writes the cache it reads."""
+        return self.mixer in ("attn", "ssm")
 
 
 @dataclasses.dataclass(frozen=True)
 class LayerRun:
-    """``count`` consecutive layers of one kind, scanned as one
-    (``block_stack``): ``at`` is the first one's index in its parameter
-    stack (``"moe"`` or ``"dense"``) and ``cache`` its index among the
-    layers of its attention type, which is its layer index in that type's
-    page pool."""
+    """``count`` layers of one kind scanned as one (``block_stack``):
+    consecutive ones, or every ``period``-th of a block whose stack
+    repeats a pattern of several kinds. ``at`` is the first one's index
+    in its parameter stack (``stack_of``) and ``cache`` its layer index in
+    the cache it reads (``LayerKind.pool``): its own layer's in the pool
+    it stores to or, for a layer that stores nothing, the layer's whose
+    rows it reads."""
     kind: LayerKind
     count: int
     at: int
@@ -85,25 +109,93 @@ class LayerRun:
         return self.kind.full
 
 
-def layer_runs(blk, depth: int) -> Tuple[LayerRun, ...]:
-    """The stack as runs of layers alike in both kinds, in the published
-    order: a deeper cut or a whole model is the same code with more runs."""
+def stack_scans(blk, depth: int) -> Tuple[Tuple[LayerRun, ...], ...]:
+    """The stack as the scans that run it, in the published order: each a
+    pattern of ``blk.period`` kinds (one ``LayerRun`` a member) repeated
+    ``count`` times. At period 1 these are the runs of layers alike; a
+    deeper cut or a whole model is the same code with more scans."""
     kinds = blk.layer_kinds(depth)
-    runs, i = [], 0
+    scans, i = [], 0
     while i < depth:
-        j = i
-        while j < depth and kinds[j] == kinds[i]:
-            j += 1
-        runs.append(LayerRun(
-            kinds[i], j - i,
-            at=sum(k.moe == kinds[i].moe for k in kinds[:i]),
-            cache=sum(k.full == kinds[i].full for k in kinds[:i])))
-        i = j
-    return tuple(runs)
+        pattern = kinds[i:i + blk.period]
+        p, count = len(pattern), 1
+        while kinds[i + count * p:i + (count + 1) * p] == pattern:
+            count += 1
+        stacks = [blk.stack_of(k) for k in pattern]
+        pools = [k.pool for k in pattern if k.stores]
+        if len(set(stacks)) < p or len(set(pools)) < len(pools):
+            raise ValueError(
+                f"{blk.name}: a scanned pattern of layers {i}..{i + p} "
+                f"holds two of one parameter stack or of one pool")
+        scans.append(tuple(LayerRun(
+            kind, count,
+            at=sum(blk.stack_of(k) == blk.stack_of(kind)
+                   for k in kinds[:i + j]),
+            cache=_cache_index(kinds, i + j))
+            for j, kind in enumerate(pattern)))
+        i += count * p
+    return tuple(scans)
+
+
+def _cache_index(kinds, i: int) -> int:
+    stored = sum(k.stores and k.pool == kinds[i].pool for k in kinds[:i])
+    return stored if kinds[i].stores else max(stored - 1, 0)
+
+
+def layer_runs(blk, depth: int) -> Tuple[LayerRun, ...]:
+    """Every ``LayerRun`` of ``stack_scans``, in order."""
+    return tuple(run for scan in stack_scans(blk, depth) for run in scan)
+
+
+class DescribedBlock:
+    """What the stack, the caches and the engine ask of a described block
+    beyond its own fields; each block overrides what differs."""
+    period = 1      # kinds in the pattern that the stack repeats
+    tied_head = False   # an output head of its own (models/dalle.py)
+    layer_norms = False     # RMSNorms (a gain alone), not LayerNorms
+
+    @staticmethod
+    def stack_of(kind: LayerKind) -> str:
+        """The parameter stack that holds a layer of ``kind``."""
+        return "moe" if kind.moe else "dense"
+
+    @staticmethod
+    def pool_buffers(pool: str) -> Tuple[str, ...]:
+        """The buffers of one cache: a pool's K and V, or the recurrent
+        state and the convolution's tail."""
+        return {"full": ("k", "v"), "window": ("window_k", "window_v"),
+                "state": ("ssm_state", "ssm_conv")}[pool]
+
+    def cache_layers(self, pool: str, depth: int) -> Tuple[int, ...]:
+        """The layers (indices into the layers run here) that STORE to one
+        cache, in order: their number is the depth of its buffers."""
+        return tuple(i for i, k in enumerate(self.layer_kinds(depth))
+                     if k.stores and k.pool == pool)
+
+    def pools(self, depth: int) -> dict:
+        """``{cache: its buffers}`` of the caches that the block holds."""
+        return {pool: self.pool_buffers(pool)
+                for pool in ("full", "window", "state")
+                if self.cache_layers(pool, depth)}
+
+    def carried(self, x: Array) -> dict:
+        """What a layer hands to later layers inside one pass over the
+        stack, beside the residual stream (``block_layer``'s ``shared``),
+        as zeros shaped after the stream ``x``."""
+        return {}
+
+    def ring_pages(self, page_size: int, total_len: int) -> int:
+        """Pages a slot holds of a window layer at most: the window and
+        one more, through which it slides (a ring: the row of position p
+        lies at ``p % (ring_pages * page_size)`` and overwrites a row
+        that left the window at least ``page_size`` positions ago); never
+        more than the whole sequence's."""
+        return min(-(-self.window // page_size) + 1,
+                   -(-total_len // page_size))
 
 
 @dataclasses.dataclass(frozen=True)
-class LatentMoEBlock:
+class LatentMoEBlock(DescribedBlock):
     """A described block other than PreNorm LayerNorm + GEGLU + learned
     positions (``TransformerConfig.block``; None is the classic block):
     RMSNorm, rotary positions, latent attention without query
@@ -131,6 +223,14 @@ class LatentMoEBlock:
     embed_scale = 1.0               # token embeddings enter as they are
     first_expert = 0                # every routed expert is held here
 
+    @staticmethod
+    def mixer_of(kind: LayerKind) -> str:
+        return "latent"
+
+    @staticmethod
+    def pool_buffers(pool: str) -> Tuple[str, ...]:
+        return ("latent",)          # one row a token: no V
+
     @property
     def experts_held(self) -> int:
         return self.num_experts
@@ -150,6 +250,10 @@ class LatentMoEBlock:
         return self.kv_rank + self.qk_rope_dim
 
     @property
+    def page_row_width(self) -> int:
+        return self.row_width
+
+    @property
     def row_width(self) -> int:
         """Width of the row that holds them: ``entry_width`` filled up with
         zeros to whole 128-lane tiles (576 -> 640). A minor dimension that
@@ -163,7 +267,7 @@ class LatentMoEBlock:
 
 
 @dataclasses.dataclass(frozen=True)
-class WindowGQABlock:
+class WindowGQABlock(DescribedBlock):
     """The second described block: grouped-query attention
     (``TransformerConfig.heads`` query heads read ``kv_heads`` key/value
     heads of ``head_dim``, query head i the head ``i // (heads /
@@ -224,25 +328,118 @@ class WindowGQABlock:
                                full=t == "full")
                      for i, t in enumerate(self.layer_types))
 
-    def cache_layers(self, full: bool) -> Tuple[int, ...]:
-        """The layers (indices into the layers run here) of one attention
-        type, in order: their number is the depth of that type's pool."""
-        return tuple(i for i, t in enumerate(self.layer_types)
-                     if (t == "full") == full)
+    @staticmethod
+    def mixer_of(kind: LayerKind) -> str:
+        return "gqa"
+
+    @property
+    def page_row_width(self) -> int:
+        """A cached row: every key/value head's numbers side by side."""
+        return self.kv_heads * self.head_dim
+
+
+MIXER_NAMES = ("ssm", "window", "full", "cross", "gmu")
+
+
+@dataclasses.dataclass(frozen=True)
+class SSMHybridBlock(DescribedBlock):
+    """The third described block: a strictly alternating stack of
+    recurrent and attention mixers, each layer ``h = x + Mixer(LN(x))``,
+    ``y = h + MLP(LN(h))`` with LayerNorms (gain and bias) and a dense
+    SiLU-gated feed-forward without biases. ``mixers`` names each layer's:
+
+      * ``"ssm"``: a selective state-space layer (ops/ssm.py). A slot
+        carries a fixed-size state and the convolution's tail, no rows;
+      * ``"window"`` / ``"full"``: differential attention
+        (ops/attention.py) over the last ``window`` rows / over every
+        earlier row: ``TransformerConfig.heads`` query heads over
+        ``kv_heads`` key/value heads of ``head_dim``, in pairs. A token
+        caches one K and one V row a layer, the key/value heads side by
+        side in it, in the pool of its layer type (serve/kv_pool.py);
+      * ``"cross"``: the same attention with a query projection alone,
+        over the rows that the nearest earlier ``"full"`` layer cached (and
+        that layer's row of the current token): it projects no key or
+        value and caches nothing;
+      * ``"gmu"``: a gated memory unit over the scan output that the
+        nearest earlier ``"ssm"`` layer gave for the same token: no state
+        of its own.
+
+    No position enters anywhere (the state-space layers carry order).
+    The stack is scanned in periods of two layers (``stack_scans``).
+    ``dim_head`` and ``ff_mult`` of the configuration are not read."""
+    mixers: Tuple[str, ...] = ("ssm", "window", "ssm", "full", "gmu",
+                               "cross")
+    kv_heads: int = 20
+    head_dim: int = 64
+    window: int = 512
+    d_inner: int = 5120
+    d_state: int = 16
+    d_conv: int = 4
+    dt_rank: int = 160
+    dense_hidden: int = 10240
+    norm_eps: float = 1e-5
+    name: str = "ssm_hybrid"
+    period = 2
+    tied_head = True                # logits against the embedding rows
+    layer_norms = True              # a gain and a bias
+    embed_scale = 1.0
+    num_experts = 0                 # every feed-forward is dense
+
+    def __post_init__(self):
+        bad = set(self.mixers) - set(MIXER_NAMES)
+        if bad:
+            raise ValueError(f"mixers holds {sorted(bad)}: a layer is one "
+                             f"of {MIXER_NAMES}")
+        for reader, source in (("gmu", "ssm"), ("cross", "full")):
+            if reader in self.mixers and source not in self.mixers[
+                    :self.mixers.index(reader)]:
+                raise ValueError(f"a {reader!r} layer reads an earlier "
+                                 f"{source!r} layer, and none comes first")
+        if self.kv_heads % 2:
+            raise ValueError(f"kv_heads {self.kv_heads}: differential "
+                             f"attention pairs the key/value heads up")
+
+    @property
+    def dense_layers(self) -> int:
+        return len(self.mixers)
+
+    @property
+    def score_dim(self) -> int:
+        return self.head_dim
+
+    @property
+    def page_row_width(self) -> int:
+        return self.kv_heads * self.head_dim
+
+    def layer_kinds(self, depth: int) -> Tuple[LayerKind, ...]:
+        if len(self.mixers) != depth:
+            raise ValueError(f"mixers names {len(self.mixers)} layers, "
+                             f"depth is {depth}")
+        return tuple(LayerKind(
+            moe=False, full=m in ("full", "cross"),
+            mixer=m if m in ("ssm", "cross", "gmu") else "attn")
+            for m in self.mixers)
 
     @staticmethod
-    def pool_buffers(full: bool) -> Tuple[str, str]:
-        """The K and V buffers of the pool of one attention type."""
-        return ("k", "v") if full else ("window_k", "window_v")
+    def stack_of(kind: LayerKind) -> str:
+        return kind.mixer       # "attn": window and full layers alike
 
-    def ring_pages(self, page_size: int, total_len: int) -> int:
-        """Pages a slot holds of a window layer at most: the window and
-        one more, through which it slides (a ring: the row of position p
-        lies at ``p % (ring_pages * page_size)`` and overwrites a row
-        that left the window at least ``page_size`` positions ago); never
-        more than the whole sequence's."""
-        return min(-(-self.window // page_size) + 1,
-                   -(-total_len // page_size))
+    @staticmethod
+    def mixer_of(kind: LayerKind) -> str:
+        return "diff" if kind.mixer == "attn" else kind.mixer
+
+    def carried(self, x: Array) -> dict:
+        """The last state-space layer's scan output and the full layer's
+        K and V rows, of the tokens in ``x``."""
+        lead = x.shape[:-1]
+        rows = jnp.zeros(lead + (self.kv_heads, self.head_dim), x.dtype)
+        return {"m": jnp.zeros(lead + (self.d_inner,), x.dtype),
+                "k": rows, "v": rows}
+
+    @staticmethod
+    def lam_init(layer) -> Array:
+        """Differential attention's constant of layer ``layer``."""
+        return 0.8 - 0.6 * jnp.exp(-0.3 * jnp.asarray(layer, jnp.float32))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -283,7 +480,8 @@ class TransformerConfig:
     moe_k: int = 2
     moe_capacity: float = 1.25
     # a described block in place of the classic one
-    block: Optional[Union[LatentMoEBlock, WindowGQABlock]] = None
+    block: Optional[Union[LatentMoEBlock, WindowGQABlock,
+                          SSMHybridBlock]] = None
 
     def __post_init__(self):
         blk = self.block
@@ -299,10 +497,10 @@ class TransformerConfig:
         for option, on in refused.items():
             if on:
                 raise BlockOptionError(blk.name, option)
+        blk.layer_kinds(self.depth)      # it names every layer run here
         if not 0 <= blk.dense_layers <= self.depth:
             raise ValueError(f"dense_layers {blk.dense_layers} not in "
                              f"[0, depth={self.depth}]")
-        blk.layer_kinds(self.depth)      # it names every layer run here
 
     @property
     def moe(self):
@@ -354,56 +552,75 @@ def layer_init(key: Array, cfg: TransformerConfig, dtype=jnp.float32) -> dict:
 
 
 def block_layer_init(key: Array, cfg: TransformerConfig, kind: LayerKind,
-                     dtype=jnp.float32) -> dict:
-    """One layer of a described block: dense or routed feed-forward. The
+                     dtype=jnp.float32, layer: int = 0) -> dict:
+    """One layer of a described block: its mixer under ``"attn"`` and its
+    dense or routed feed-forward under ``"ff"``, each with its norm. The
     window-and-full block's layers carry a second norm a branch, on the
-    branch's output."""
+    branch's output; the state-space hybrid's norms are LayerNorms and its
+    differential layers hold the constant of their own index ``layer``."""
     from dalle_pytorch_tpu.ops.moe import dropless_init
     blk = cfg.block
     k_attn, k_ff = jax.random.split(key)
     ff = dropless_init(k_ff, cfg.dim, blk, dtype) if kind.moe \
         else core.swiglu_init(k_ff, cfg.dim, blk.dense_hidden, dtype)
-    norm = {"ln": core.rmsnorm_init(cfg.dim, dtype)}
-    if isinstance(blk, WindowGQABlock):
+    mixer = blk.mixer_of(kind)
+    norm = {"ln": (core.layernorm_init if blk.layer_norms
+                   else core.rmsnorm_init)(cfg.dim, dtype)}
+    if mixer == "gqa":
         norm["post_ln"] = core.rmsnorm_init(cfg.dim, dtype)
         attn = attn_ops.gqa_init(k_attn, cfg.dim, cfg.heads, blk, dtype)
-    else:
+    elif mixer == "latent":
         attn = attn_ops.latent_init(k_attn, cfg.dim, cfg.heads, blk, dtype)
+    elif mixer == "ssm":
+        attn = ssm_ops.ssm_init(k_attn, cfg.dim, blk, dtype)
+    elif mixer == "gmu":
+        attn = ssm_ops.gmu_init(k_attn, cfg.dim, blk, dtype)
+    else:
+        attn = attn_ops.diff_init(k_attn, cfg.dim, cfg.heads, blk,
+                                  blk.lam_init(layer), dtype,
+                                  own_kv=mixer == "diff")
     return {"attn": {**norm, **attn}, "ff": {**norm, **ff}}
 
 
 def transformer_init(key: Array, cfg: TransformerConfig,
                      dtype=jnp.float32) -> dict:
     """Layer parameters stacked on a leading depth axis. A described
-    block's stack is not uniform: its leading dense layers and its expert
-    layers are two subtrees, ``{"dense": ..., "moe": ...}``, each stacked
-    over its own layers (``block_stack`` scans one after the other)."""
-    if cfg.block is not None:
-        k_dense, k_moe = jax.random.split(key)
-        n_dense = cfg.block.dense_layers
-        # a layer's attention type changes no parameter's shape
-        return {
-            "dense": jax.vmap(lambda k: block_layer_init(
-                k, cfg, LayerKind(False, True), dtype))(
-                    jax.random.split(k_dense, n_dense)),
-            "moe": jax.vmap(lambda k: block_layer_init(
-                k, cfg, LayerKind(True, True), dtype))(
-                    jax.random.split(k_moe, cfg.depth - n_dense)),
-        }
+    block's stack is not uniform: it is one subtree a parameter stack
+    (``DescribedBlock.stack_of``: ``{"dense": ..., "moe": ...}``, or a
+    subtree a kind of mixer), each stacked over its own layers in the
+    published order (``block_stack`` scans them)."""
+    blk = cfg.block
+    if blk is not None:
+        kinds = blk.layer_kinds(cfg.depth)
+        names = list(dict.fromkeys(blk.stack_of(k) for k in kinds))
+        out = {}
+        for name, k_stack in zip(names, jax.random.split(key, len(names))):
+            layers = [i for i, k in enumerate(kinds)
+                      if blk.stack_of(k) == name]
+            # within a stack a layer's kind changes no parameter's shape
+            out[name] = jax.vmap(lambda k, i: block_layer_init(
+                k, cfg, kinds[layers[0]], dtype, layer=i))(
+                    jax.random.split(k_stack, len(layers)),
+                    jnp.asarray(layers))
+        return out
     keys = jax.random.split(key, cfg.depth)
     return jax.vmap(lambda k: layer_init(k, cfg, dtype))(keys)
 
 
 def is_block_params(params: dict) -> bool:
-    """Whether a transformer subtree is a described block's two stacks."""
-    return "dense" in params and "moe" in params
+    """Whether a transformer subtree is a described block's stacks (and
+    not the classic block's one stack of layers, each a mixer and a
+    feed-forward)."""
+    return "ff" not in params
 
 
 def block_name_of(params: dict) -> str:
     """Which described block a transformer subtree holds, for a caller
     that has parameters and no configuration to name in its refusal."""
-    return WindowGQABlock.name if "gate" in params["moe"]["attn"] \
-        else LatentMoEBlock.name
+    if not {"dense", "moe"} & set(params):
+        return SSMHybridBlock.name      # ``stack_of``: stacks by mixer
+    attn = next(iter(params.values()))["attn"]
+    return WindowGQABlock.name if "gate" in attn else LatentMoEBlock.name
 
 
 # ---------------------------------------------------------------------------
@@ -545,36 +762,87 @@ def ff_or_moe(layer_params: dict, x: Array, cfg: TransformerConfig,
 # the read differs (materialised over a whole sequence here and in prefill,
 # over the page pool in decode), and it is handed in.
 
-def block_layer(lp: dict, h: Array, positions: Array, read, cfg,
-                run: LayerRun):
-    """One layer of the run ``run``. ``read(attn_params, query, entry) ->
-    o`` is the attention read, where ``query`` and ``entry`` are what the
-    block's projection gives: (q_nope, q_rope) and the latent row for the
-    latent block; q and the (k, v) rows for the window-and-full block.
-    -> (h, (entry, load)): the row(s) to cache and the routed layer's
-    load (zeros for a dense layer)."""
+def block_norm(p: dict, x: Array, blk) -> Array:
+    """The norm its parameters describe (a gain with a shift is a
+    LayerNorm, a gain alone an RMSNorm) at the block's epsilon."""
+    if "b" in p:
+        return core.layernorm(p, x, eps=blk.norm_eps)
+    return core.rmsnorm(p, x, eps=blk.norm_eps)
+
+
+# A mixer: (p, hn, positions, read, shared, cfg, run) -> (the branch's
+# output, the entry to cache or None, ``shared`` as later layers get it).
+
+def _mix_latent(p, hn, positions, read, shared, cfg, run):
+    q_nope, q_rope, entry = attn_ops.latent_project(p, hn, positions,
+                                                    cfg.heads, cfg.block)
+    a = attn_ops.latent_out(p, read(p, (q_nope, q_rope), entry))
+    return a, entry, shared
+
+
+def _mix_gqa(p, hn, positions, read, shared, cfg, run):
+    blk = cfg.block
+    q, gate, entry = attn_ops.gqa_project(
+        p, hn, positions, cfg.heads, blk, rotary=not run.full)
+    a = attn_ops.gqa_out(p, read(p, q, entry), gate)
+    return core.rmsnorm(p["post_ln"], a, eps=blk.norm_eps), entry, shared
+
+
+def _mix_ssm(p, hn, positions, read, shared, cfg, run):
+    out, m, state = read(p, hn, None)
+    return out, state, {**shared, "m": m}
+
+
+def _mix_gmu(p, hn, positions, read, shared, cfg, run):
+    return ssm_ops.gmu(p, hn, shared["m"]), None, shared
+
+
+def _mix_diff(p, hn, positions, read, shared, cfg, run):
+    """A window or full differential layer (its own K and V, which a full
+    layer also hands on) or a cross layer (the handed-on ones)."""
+    blk = cfg.block
+    q, entry = attn_ops.diff_project(p, hn, cfg.heads, blk)
+    if entry is None:
+        o = read(p, q, (shared["k"], shared["v"]))
+    else:
+        o = read(p, q, entry)
+        if run.full:
+            shared = {**shared, "k": entry[0], "v": entry[1]}
+    return attn_ops.diff_out(p, o, blk.norm_eps), entry, shared
+
+
+_MIXERS = {"latent": _mix_latent, "gqa": _mix_gqa, "ssm": _mix_ssm,
+           "gmu": _mix_gmu, "diff": _mix_diff, "cross": _mix_diff}
+
+
+def block_layer(lp: dict, h: Array, shared: dict, positions: Array, read,
+                cfg, run: LayerRun):
+    """One layer of the run ``run``: norm, the mixer, residual, norm, the
+    feed-forward, residual. ``read(mixer_params, query, entry) -> o`` is
+    the layer's read of its cache, where ``query`` and ``entry`` are what
+    the block's projection gives: (q_nope, q_rope) and the latent row for
+    the latent block; q and the (k, v) rows for the grouped-query and the
+    differential layers; the normed input and None for a state-space
+    layer, whose read is the whole recurrence (-> out, m, the new state).
+    ``shared`` is what earlier layers handed on (``DescribedBlock
+    .carried``). -> (h, shared, (entry, load)): what to cache (None for a
+    layer that caches nothing) and the routed layer's load (zeros for a
+    dense layer)."""
     blk = cfg.block
     p = lp["attn"]
-    hn = core.rmsnorm(p["ln"], h, eps=blk.norm_eps)
-    if isinstance(blk, WindowGQABlock):
-        q, gate, entry = attn_ops.gqa_project(
-            p, hn, positions, cfg.heads, blk, rotary=not run.full)
-        a = attn_ops.gqa_out(p, read(p, q, entry), gate)
-        a = core.rmsnorm(p["post_ln"], a, eps=blk.norm_eps)
-    else:
-        q_nope, q_rope, entry = attn_ops.latent_project(p, hn, positions,
-                                                        cfg.heads, blk)
-        a = attn_ops.latent_out(p, read(p, (q_nope, q_rope), entry))
+    hn = block_norm(p["ln"], h, blk)
+    a, entry, shared = _MIXERS[blk.mixer_of(run.kind)](
+        p, hn, positions, read, shared, cfg, run)
     h = h + a
     f, load = block_ff(lp["ff"], h, blk, run.moe)
-    return h + f, (entry, load)
+    return h + f, shared, (entry, load)
 
 
 def block_ff(p: dict, x: Array, blk, moe: bool):
     """Feed-forward branch of a described block behind its norm(s) ->
     (out, load: ops.moe.dropless_apply's, zeros for a dense layer)."""
     from dalle_pytorch_tpu.ops.moe import dropless_apply, load_width
-    hn = core.rmsnorm(p["ln"], x, eps=blk.norm_eps)
+    hn = block_norm(p["ln"], x, blk)
     if moe:
         out, load = dropless_apply(p, hn, blk)
     else:
@@ -587,13 +855,18 @@ def block_ff(p: dict, x: Array, blk, moe: bool):
 
 
 def block_stack(params: dict, h: Array, layer_fn, cfg):
-    """The non-uniform stack: one scan a run of layers alike in both kinds
-    (``layer_runs``), in the published order. ``layer_fn(lp, h, layer,
-    run) -> (h, out)`` with ``layer`` the traced index into the pool of
-    the run's attention type (``run.cache`` + the index in the run) and
-    ``run`` static. -> (h, outs stacked over the whole depth)."""
-    def scan_layers(h, run: LayerRun):
-        sub = params["moe" if run.moe else "dense"]
+    """The non-uniform stack: one scan a pattern of layers repeated
+    (``stack_scans``: at period 1 a run of layers alike), in the published
+    order. ``layer_fn(lp, h, shared, layer, run) -> (h, shared, (entry,
+    load))`` with ``layer`` the traced index into the cache that the run
+    reads (``run.cache`` + the index in the run) and ``run`` static. ->
+    (h, entries: ``{buffer: what the layers that store to it gave, stacked
+    over them}`` for every buffer of ``blk.pools``, loads stacked over the
+    whole depth)."""
+    blk = cfg.block
+
+    def member(run: LayerRun):
+        sub = params[blk.stack_of(run.kind)]
         whole = None
         if run.moe:
             # the routed experts are not scanned: a layer reads them out
@@ -602,62 +875,105 @@ def block_stack(params: dict, h: Array, layer_fn, cfg):
             sub = {**sub, "ff": {k: v for k, v in sub["ff"].items()
                                  if k != "experts"}}
         whole_stack = run.count == jax.tree.leaves(sub)[0].shape[0]
+        return sub, whole, whole_stack
 
-        def body(h, xs):
-            lp, local = xs
-            if not whole_stack:
-                # a run that is part of its stack indexes the stack
-                # itself: a slice of it handed to the scan is a copy of
-                # the run's weights, every step
-                lp = jax.tree.map(lambda a: lax.dynamic_index_in_dim(
-                    a, run.at + local, keepdims=False), sub)
-            if whole is not None:
-                lp = {**lp, "ff": {**lp["ff"], "experts": {
-                    **whole, "layer": run.at + local}}}
-            return layer_fn(lp, h, run.cache + local, run)
+    def scan_pattern(carry, scan):
+        members = [member(run) for run in scan]
 
-        return lax.scan(body, h, (sub if whole_stack else None,
-                                  jnp.arange(run.count)))
+        def body(carry, xs):
+            h, shared = carry
+            lps, local = xs
+            outs = []
+            for run, (sub, whole, whole_stack), lp in zip(scan, members,
+                                                          lps):
+                if not whole_stack:
+                    # a run that is part of its stack indexes the stack
+                    # itself: a slice of it handed to the scan is a copy
+                    # of the run's weights, every step
+                    lp = jax.tree.map(lambda a: lax.dynamic_index_in_dim(
+                        a, run.at + local, keepdims=False), sub)
+                if whole is not None:
+                    lp = {**lp, "ff": {**lp["ff"], "experts": {
+                        **whole, "layer": run.at + local}}}
+                # a layer that stores nothing reads ONE earlier layer's
+                # rows, whichever of the run's layers it is
+                layer = run.cache + local if run.kind.stores else run.cache
+                h, shared, out = layer_fn(lp, h, shared, layer, run)
+                outs.append(out)
+            return (h, shared), tuple(outs)
 
-    outs = []
-    for run in layer_runs(cfg.block, cfg.depth):
-        h, out = scan_layers(h, run)
-        outs.append(out)
-    return h, jax.tree.map(lambda *a: jnp.concatenate(a), *outs)
+        return lax.scan(body, carry, (
+            tuple(sub if whole_stack else None
+                  for sub, _, whole_stack in members),
+            jnp.arange(scan[0].count)))
+
+    def joined(parts):
+        return parts[0] if len(parts) == 1 else jax.tree.map(
+            lambda *a: jnp.concatenate(a), *parts)
+
+    carry = (h, blk.carried(h))
+    stored, loads = {}, []
+    for scan in stack_scans(blk, cfg.depth):
+        carry, outs = scan_pattern(carry, scan)
+        for run, (entry, load) in zip(scan, outs):
+            loads.append(load)
+            if run.kind.stores:
+                stored.setdefault(run.kind.pool, []).append(entry)
+    entries = {}
+    for pool, parts in stored.items():
+        rows = joined(parts)
+        names = blk.pool_buffers(pool)
+        entries.update(zip(names, rows if len(names) > 1 else (rows,)))
+    return carry[0], entries, joined(loads)
 
 
 def block_apply_full(params: dict, x: Array, cfg: TransformerConfig,
-                     mask: Optional[Array] = None):
+                     mask: Optional[Array] = None,
+                     lens: Optional[Array] = None):
     """A described block over whole sequences x (b, n, dim) at positions
-    0..n-1, causal (and windowed on a sliding layer), the MATERIALISED
-    read: the full forward and the prefill are this one function. ->
-    (h (b, n, dim), entries stacked over the depth: every layer's rows to
-    cache, (depth, b, n, row_width) or the pair of (depth, b, n, kv_heads,
-    head_dim) K and V; loads (depth, load width))."""
+    0..n-1, causal (and windowed on a window layer), the MATERIALISED
+    read: the full forward and the prefill are this one function.
+    ``lens`` (b,) is each row's own length where the rows are padded on
+    the right to n: a recurrent state stops there (attention needs no
+    telling: a row never attends what comes after it). -> (h (b, n, dim),
+    entries: every buffer's rows to cache stacked over the layers that
+    store to it (``block_stack``): (layers, b, n, row_width), or (layers,
+    b, n, kv_heads, head_dim) K and V, or a state-space layer's (layers,
+    b, d_state, d_inner) and (layers, b, d_conv - 1, d_inner) after each
+    row's last position; loads (depth, load width))."""
     blk = cfg.block
     n = x.shape[1]
     positions = jnp.arange(n)
+    pools = blk.pools(cfg.depth)
     with jax.named_scope("attn.read"):       # the masks
         allowed = jnp.tril(jnp.ones((n, n), bool))[None, None]
         if mask is not None:
             allowed = allowed & (mask[:, None, :, None]
                                  & mask[:, None, None, :])
-        if isinstance(blk, WindowGQABlock):
+        if "window" in pools:
             near = (positions[:, None] - positions[None, :]) < blk.window
             in_window = allowed & near[None, None]
+    if "state" in pools:
+        with jax.named_scope("ssm.scan"):
+            advance = mask
+            if lens is not None:
+                within = positions[None, :] < lens[:, None]
+                advance = within if mask is None else mask & within
 
-    def layer_fn(lp, h, _layer, run):
+    def layer_fn(lp, h, shared, _layer, run):
         def read(p, query, entry):
-            if isinstance(blk, WindowGQABlock):
-                return attn_ops.gqa_attend_materialised(
-                    query, *entry, allowed if run.full else in_window,
-                    cfg.scale, window=not run.full)
-            return attn_ops.latent_attend_materialised(
-                p, *query, entry, allowed, blk, cfg.scale)
-        return block_layer(lp, h, positions, read, cfg, run)
+            if run.kind.pool == "state":
+                return ssm_ops.ssm_sequence(p, query, advance)
+            if blk.mixer_of(run.kind) == "latent":
+                return attn_ops.latent_attend_materialised(
+                    p, *query, entry, allowed, blk, cfg.scale)
+            return attn_ops.gqa_attend_materialised(
+                query, *entry, allowed if run.full else in_window,
+                cfg.scale, window=not run.full,
+                diff_lam=attn_ops.diff_lambda(p) if "lam" in p else None)
+        return block_layer(lp, h, shared, positions, read, cfg, run)
 
-    h, (entries, loads) = block_stack(params, x, layer_fn, cfg)
-    return h, entries, loads
+    return block_stack(params, x, layer_fn, cfg)
 
 
 # ---------------------------------------------------------------------------

@@ -15,7 +15,7 @@ from dalle_pytorch_tpu.serve.scheduler import (  # noqa: F401
     CANCELLED, DEADLINE_EXCEEDED, ERROR, OK, REJECTED, InvalidRequest,
     QueueClosed, QueueFull, Request, RequestHandle, RequestQueue, Result,
     SamplingParams, ServeRejected, WeightedFairQueue, bucket_for,
-    group_by_bucket, prefill_buckets)
+    group_by_bucket, prefill_buckets, prefill_groups)
 from dalle_pytorch_tpu.serve.fanout import (  # noqa: F401
     GroupFuture, group_pages_saved, rank_samples, sample_seed,
     submit_group)

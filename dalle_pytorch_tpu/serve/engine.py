@@ -30,10 +30,11 @@ cost on a real chip — a steady-state loop the host is NOT in:
     can wait up to K-1 dead steps plus one in-flight chunk for it —
     docs/SERVING.md "Choosing K");
   * admission pads prompts up to a small fixed set of BUCKET lengths
-    (``scheduler.prefill_buckets``) and always prefills a full
-    ``num_slots``-row group (unused rows scatter to a dropped
+    (``scheduler.prefill_buckets``) and prefills the smallest GROUP of
+    rows that holds the need (``scheduler.prefill_groups``: a small
+    group or all ``num_slots``; unused rows scatter to a dropped
     out-of-range slot index), so prefill compiles exactly once per
-    bucket for the engine's life — asserted by tests through
+    bucket and group for the engine's life — asserted by tests through
     ``analysis.guards.compile_count``. Padding is causal-safe: cache
     rows [0, t0) and the first sampled token depend only on positions
     < t0, and every padded garbage row [t0, bucket) is overwritten by
@@ -464,6 +465,7 @@ class Engine:
                     f"prefill_buckets must be >= 1 and end at "
                     f"text_seq_len ({cfg.text_seq_len}), got {buckets}")
         self.buckets = buckets
+        self.prefill_groups = S.prefill_groups(self.num_slots)
 
         S_ = self.num_slots
         self.total_len = cfg.seq_len
@@ -517,7 +519,8 @@ class Engine:
             self.cache = self._place_kv(KV.init_page_pool(
                 cfg.transformer, self.num_pages, self.page_size,
                 dtype=params["text_emb"]["w"].dtype,
-                quantized=self.quantize_cache, window_pages=window_pages))
+                quantized=self.quantize_cache, window_pages=window_pages,
+                num_slots=S_))
             self.alloc = KV.PageAllocator(self.num_pages)
             # the host owns the authoritative block tables (it owns the
             # allocator); the device copy is pushed — one explicit
@@ -637,10 +640,10 @@ class Engine:
         self.decode_traces = 0          # bumped only while TRACING: the
         self.prefill_traces = 0         # fixed-shape contract keeps the
         #                                 decode program at 1 and prefill
-        #                                 at 1 per bucket
+        #                                 at 1 per bucket and group
         self.warm_admit_traces = 0      # the warm-admission program: 1,
         #                                 ever (no bucket dependence)
-        self._prefill_trace_counts: Dict[int, int] = {}
+        self._prefill_trace_counts: Dict[tuple, int] = {}
         self._scopes_thread = None      # the thread inside device_scopes()
         self._scope_maps: Dict[str, dict] = {}
         self.prefill_runs = 0           # prefill DISPATCHES (a warm hit
@@ -818,7 +821,7 @@ class Engine:
 
     # -- jitted programs ----------------------------------------------------
 
-    def _count_trace(self, counter: str, bucket: Optional[int] = None):
+    def _count_trace(self, counter: str, program: Optional[tuple] = None):
         """Bump a trace counter from inside a program being TRACED — the
         fixed-shape contract's proof. ``device_scopes()`` lowers the same
         programs again from shapes, on its caller's thread; that is no
@@ -826,9 +829,9 @@ class Engine:
         if threading.get_ident() == self._scopes_thread:
             return
         setattr(self, counter, getattr(self, counter) + 1)
-        if bucket is not None:
-            self._prefill_trace_counts[bucket] = \
-                self._prefill_trace_counts.get(bucket, 0) + 1
+        if program is not None:     # a prefill's (bucket, rows)
+            self._prefill_trace_counts[program] = \
+                self._prefill_trace_counts.get(program, 0) + 1
 
     def _cfg_closures(self, params, active, keys, temp, topk_k, top_p,
                       partner, cfgs, uncond):
@@ -946,20 +949,22 @@ class Engine:
             sparse_reads=self.sparse_reads,
             out_sync=self._decode_out_sync())
 
-    def _prefill_fn(self, bucket: int):
-        """Admission program for one prompt-length BUCKET: batched prefill
-        of a full num_slots-row group (prompts padded to ``bucket``,
-        unused rows aimed at the dropped out-of-range slot index),
-        scatter of the KV rows into the slot pool, each request's FIRST
-        sampled token (position t0 = the TRUE prompt length, key
-        ``fold_in(rng, t0)`` — ``generate_images``'s first_tok), and the
-        device-side merge of the new slots' decode state. Compiled once
-        per bucket for the engine's life — group size is pinned at
-        num_slots, so no other shape can ever reach it."""
+    def _prefill_fn(self, bucket: int, n_rows: Optional[int] = None):
+        """Admission program for one prompt-length BUCKET and one GROUP
+        of ``n_rows`` rows (of ``self.prefill_groups``; all ``num_slots``
+        where not given): batched prefill of the group (prompts padded
+        to ``bucket``, unused rows aimed at the dropped out-of-range
+        slot index), scatter of the KV rows into the slot pool, each
+        request's FIRST sampled token (position t0 = the TRUE prompt
+        length, key ``fold_in(rng, t0)`` — ``generate_images``'s
+        first_tok), and the device-side merge of the new slots' decode
+        state. One jitted function a (bucket, n_rows), which only ever
+        sees that shape: compiled once for the engine's life."""
         import jax
         import jax.numpy as jnp
-        if bucket in self._prefill_fns:
-            return self._prefill_fns[bucket]
+        n_rows = self.num_slots if n_rows is None else n_rows
+        if (bucket, n_rows) in self._prefill_fns:
+            return self._prefill_fns[bucket, n_rows]
         paged = self.kv == "paged"
 
         def pre(params, cache, cur_tok, pos, active, rng, temp, topk_k,
@@ -968,7 +973,7 @@ class Engine:
                 page_rows=None):
             # page_rows rides only the paged trace: dense admission
             # omits it entirely (no dead argument, no wasted transfer)
-            self._count_trace("prefill_traces", bucket)
+            self._count_trace("prefill_traces", (bucket, n_rows))
             from dalle_pytorch_tpu.models import dalle as D
             from dalle_pytorch_tpu.ops import decode as decode_ops
 
@@ -983,7 +988,7 @@ class Engine:
                 params["transformer"], tokens, cfg=self.cfg.transformer,
                 total_len=self.total_len, prompt_mask=None,
                 quantize_cache=self.quantize_cache,
-                out_sync=self._decode_out_sync())
+                out_sync=self._decode_out_sync(), lens=lens)
             if paged and self.block is not None:
                 # the group's rows go in as WHOLE pages: (depth, G, bucket,
                 # width) cut into pages of page_size rows (the last one
@@ -1004,33 +1009,29 @@ class Engine:
                         return rows.reshape((rows.shape[0], -1, ps)
                                             + rows.shape[3:])
 
-                    if "latent" in cache:
-                        cache = {"latent": cache["latent"].at[:, ids].set(
-                            whole_pages(group["latent"]))}
-                    else:
-                        # a page id of each pool in one int32: full id x
-                        # the window pool's pages + window id (the trash
-                        # page for a page the ring no longer holds)
-                        n_win = 1 if self.window is None \
-                            else self.window.alloc.num_pages
-                        new = {}
-                        for full, at in ((True, ids // n_win),
-                                         (False, ids % n_win)):
-                            # jaxlint: disable=JL001 — the layers of one
-                            # type, static configuration: a trace-time const
-                            layers = np.asarray(
-                                self.block.cache_layers(full), np.int32)
-                            if not layers.size:     # no pool of this type
+                    # a page id of each pool in one int32: full id x
+                    # the window pool's pages + window id (the trash
+                    # page for a page the ring no longer holds)
+                    n_win = 1 if self.window is None \
+                        else self.window.alloc.num_pages
+                    at = {"full": ids // n_win, "window": ids % n_win}
+                    new = {}
+                    for pool, names in self.block.pools(
+                            self.cfg.transformer.depth).items():
+                        for name in names:
+                            rows = group[name]
+                            if pool == "state":
+                                # not pages: each admitted row's state
+                                # after its own prompt, over whatever the
+                                # slot's last request left there
+                                new[name] = cache[name].at[:, slots].set(
+                                    rows, mode="drop")
                                 continue
-                            for name, rows in zip(
-                                    self.block.pool_buffers(full),
-                                    (group["k"], group["v"])):
-                                # a row: the kv heads side by side
-                                rows = rows[layers]
-                                new[name] = cache[name].at[:, at].set(
-                                    whole_pages(rows.reshape(
-                                        rows.shape[:3] + (-1,))))
-                        cache = new
+                            # a row: the kv heads side by side
+                            new[name] = cache[name].at[:, at[pool]].set(
+                                whole_pages(rows.reshape(
+                                    rows.shape[:3] + (-1,))))
+                    cache = new
             elif paged:
                 # scatter the group's [0, bucket) rows into their pages:
                 # row j of group-row g lands in physical page
@@ -1087,9 +1088,11 @@ class Engine:
                     top_p, h_last)
 
         # the program's name in a trace's XLA Modules line: jit_prefill_b64
-        pre.__name__ = f"prefill_b{bucket}"
+        # for the whole group, jit_prefill_b64_g4 for a group of 4 rows
+        pre.__name__ = f"prefill_b{bucket}" + (
+            "" if n_rows == self.num_slots else f"_g{n_rows}")
         fn = self._jit_prefill_program(pre)
-        self._prefill_fns[bucket] = fn
+        self._prefill_fns[bucket, n_rows] = fn
         return fn
 
     def _warm_admit_fn(self):
@@ -1214,12 +1217,21 @@ class Engine:
                     if key in self.prefix or key in seen:
                         return True
                     seen.add(key)
+        queued: Dict[int, int] = {}
         for n in self.queue.pending_prompt_lens():
             try:
                 b = S.bucket_for(n, self.buckets)
             except ValueError:
                 continue            # admission rejects it, no compile
-            if b not in self._prefill_fns:
+            queued[b] = queued.get(b, 0) + 1
+        free = sum(s is None for s in self.slots)
+        for b, count in queued.items():
+            # a request is one row, a guided pair two: the admission may
+            # take any group up to the one that holds them all
+            most = S.bucket_for(max(min(free, 2 * count), 1),
+                                self.prefill_groups)
+            if free and any((b, g) not in self._prefill_fns
+                            for g in self.prefill_groups if g <= most):
                 return True
         return False
 
@@ -1524,10 +1536,11 @@ class Engine:
                 continue
             idx = free[:len(group)]
             free = free[len(group):]
-            G = self.num_slots
-            # fixed-shape group: prompts padded to the bucket, unused
-            # rows parked on slot index num_slots — out of range, so
-            # every scatter drops them (mode='drop' in the program)
+            G = S.bucket_for(len(group), self.prefill_groups)
+            # fixed-shape group, the smallest that holds the rows:
+            # prompts padded to the bucket, unused rows parked on slot
+            # index num_slots — out of range, so every scatter drops
+            # them (mode='drop' in the program)
             text = np.zeros((G, bucket), np.int32)
             lens = np.ones((G,), np.int32)
             slots = np.full((G,), self.num_slots, np.int32)
@@ -1575,7 +1588,7 @@ class Engine:
                 # replica ships straight to its own chip, a mesh engine
                 # replicates across its slice
                 put = self._put
-                cold = bucket not in self._prefill_fns
+                cold = (bucket, G) not in self._prefill_fns
                 if cold:
                     self.compiling = True
                 try:
@@ -1588,7 +1601,7 @@ class Engine:
                     t_pre = self.clock()
                     with _phase("engine.admit.prefill", bucket=bucket,
                                 mode="cold"):
-                        outs = self._prefill_fn(bucket)(
+                        outs = self._prefill_fn(bucket, G)(
                             self.params, self.cache, self.cur_tok,
                             self.pos, self.active, self.rng, self.temp,
                             self.topk_k, self.top_p, *group_args,
@@ -2863,7 +2876,8 @@ class Engine:
                       ) -> Dict[str, dict]:
         """{program name: {instruction name: scope entry}} for the decode
         program and each admission program this engine has built (or,
-        given ``buckets``, those buckets' prefill programs): the join
+        given ``buckets``, those buckets' prefill programs at every
+        group of ``prefill_groups``): the join
         from a profiler capture's ``XLA Ops`` events to the model's
         named scopes (``obs/device.py``). The program names are the ones
         a capture's ``XLA Modules`` line shows after ``jit_``.
@@ -2889,13 +2903,15 @@ class Engine:
         def host(shape, dtype):
             return jax.ShapeDtypeStruct(shape, dtype, sharding=rep)
 
-        G = self.num_slots
         state = shapes((self.cur_tok, self.pos, self.active, self.rng,
                         self.temp, self.topk_k, self.top_p))
-        group = (host((G,), jnp.int32),) * 3 + (      # lens, slots, n_seed
-            host((G,), jnp.float32), host((G,), jnp.int32),
-            host((G,), jnp.float32), host((G,), jnp.int32),
-            host((G,), jnp.float32), host((G,), jnp.bool_))
+
+        def group(G):       # lens, slots, n_seed, then the sampling knobs
+            return (host((G,), jnp.int32),) * 3 + (
+                host((G,), jnp.float32), host((G,), jnp.int32),
+                host((G,), jnp.float32), host((G,), jnp.int32),
+                host((G,), jnp.float32), host((G,), jnp.bool_))
+
         params, cache = shapes(self.params), shapes(self.cache)
         paged = self.kv == "paged"
         programs = {self._decode_fn.__name__: (self._decode_fn, (
@@ -2903,17 +2919,19 @@ class Engine:
             *((shapes(self.block_tables),) if paged else ()),
             *state, *shapes((self.cfg_partner, self.cfg_scale,
                              self.cfg_uncond))))}
-        for b in sorted(self._prefill_fns if buckets is None else buckets):
-            fn = self._prefill_fn(b)
+        built = sorted(self._prefill_fns) if buckets is None else [
+            (b, g) for b in sorted(buckets) for g in self.prefill_groups]
+        for b, G in built:
+            fn = self._prefill_fn(b, G)
             text = host((G, b), jnp.int32)
             programs[fn.__name__] = (fn, (
-                params, cache, *state, text, *group,
+                params, cache, *state, text, *group(G),
                 *((text,) if paged else ())))       # page_rows
         if self._warm_fn is not None:
-            h_last = host((G, self.cfg.dim),
+            h_last = host((self.num_slots, self.cfg.dim),
                           self.params["text_emb"]["w"].dtype)
             programs[self._warm_fn.__name__] = (self._warm_fn, (
-                params, *state, h_last, *group))
+                params, *state, h_last, *group(self.num_slots)))
         self._scopes_thread = threading.get_ident()
         try:
             for name, (fn, args) in programs.items():
@@ -2998,10 +3016,13 @@ class Engine:
         its next dispatch evade the hang deadline forever."""
         return self._profiler is not None
 
-    def prefill_trace_count(self, bucket: int) -> int:
-        """Traces of one bucket's prefill program (contract: <= 1 for the
-        engine's life; the guards.compile_count counter in tests)."""
-        return self._prefill_trace_counts.get(bucket, 0)
+    def prefill_trace_count(self, bucket: int,
+                            n_rows: Optional[int] = None) -> int:
+        """Traces of one bucket's prefill program for a group of
+        ``n_rows`` (all the slots where not given; contract: <= 1 for
+        the engine's life; the guards.compile_count counter in tests)."""
+        return self._prefill_trace_counts.get(
+            (bucket, self.num_slots if n_rows is None else n_rows), 0)
 
     def kv_hbm_bytes(self) -> int:
         """Resident HBM bytes of the KV store — the page pool under
@@ -3028,14 +3049,19 @@ class Engine:
         from dalle_pytorch_tpu.ops import paged_attention as PA
         tcfg = self.cfg.transformer
         if self.block is not None:
-            # the gather reads every page of a layer's table, whole, once:
-            # no live-page trimming. A latent pool has one row a token
-            # and no V; a window layer's table is its ring
-            columns = {name: self.window.ring if name.startswith("window")
-                       else self.slot_max_pages for name in self.cache}
-            out = sum(buf.shape[0] * columns[name]
-                      * int(np.prod(buf.shape[2:])) * buf.dtype.itemsize
-                      for name, buf in self.cache.items())
+            # the gather reads every page of a layer's table, whole, once
+            # a layer that READS the pool (a full pool of one layer may
+            # have many readers): no live-page trimming. A latent pool has
+            # one row a token and no V; a window layer's table is its
+            # ring; a state-space layer reads its slot's state
+            kinds = self.block.layer_kinds(tcfg.depth)
+            columns = {"full": self.slot_max_pages, "state": 1,
+                       "window": self.window.ring if self.window else 0}
+            out = sum(sum(k.pool == pool for k in kinds) * columns[pool]
+                      * int(np.prod(self.cache[name].shape[2:]))
+                      * self.cache[name].dtype.itemsize
+                      for pool, names in self.block.pools(
+                          tcfg.depth).items() for name in names)
             self._modeled_read_bytes[sr] = out
             return out
         out = int(PA.modeled_kv_read_bytes_per_token(
@@ -3050,33 +3076,52 @@ class Engine:
         self._modeled_read_bytes[sr] = out
         return out
 
-    def _window_stats(self) -> dict:
-        """A window-and-full block's two pools, side by side: physical
-        pages in use of each (``pages_in_use`` is the full pool's), the
-        window pool's ring, the pages it reused in place, and what an
-        all-full cache would hold at the same positions (every layer's
-        pages to each slot's mapped position: the denominator of the
-        saving)."""
-        if self.window is None:
+    def _block_cache_stats(self) -> dict:
+        """A described block's caches beside the one page pool. With a
+        window pool, the two pools side by side: physical pages in use of
+        each (``pages_in_use`` is the full pool's), the window pool's
+        ring, the pages it reused in place, and what an unshared,
+        unwindowed cache would hold at the same positions (every
+        attention layer's pages to each slot's mapped position: the
+        denominator of the saving); ``full_pool_readers``, the layers
+        that read the full pool, where they are more than those that
+        store to it. With state-space layers, the state buffers' bytes
+        and layers."""
+        if self.block is None:
             return {}
-        w = self.window
-        blk = self.block
-        full_layers, win_layers = (len(blk.cache_layers(True)),
-                                   len(blk.cache_layers(False)))
-        full_in_use = self.alloc.in_use
-        return {
-            "full_pages_in_use": full_in_use,
-            "window_pages_in_use": w.alloc.in_use,
-            "window_ring_pages": w.ring,
-            "window_pages_reused": w.reused,
-            # layer-pages held now, and what they would be were every
-            # layer a full one (a slot's full-pool pages are its pages
-            # to its mapped position)
-            "layer_pages_in_use": full_layers * full_in_use
-            + win_layers * w.alloc.in_use,
-            "layer_pages_all_full": (full_layers + win_layers)
-            * full_in_use,
-        }
+        blk, depth = self.block, self.cfg.transformer.depth
+        kinds = blk.layer_kinds(depth)
+        out = {}
+        if self.window is not None:
+            w = self.window
+            full_layers, win_layers = (len(blk.cache_layers(p, depth))
+                                       for p in ("full", "window"))
+            readers = sum(k.pool == "full" for k in kinds)
+            full_in_use = self.alloc.in_use
+            out.update({
+                "full_pages_in_use": full_in_use,
+                "window_pages_in_use": w.alloc.in_use,
+                "window_ring_pages": w.ring,
+                "window_pages_reused": w.reused,
+                # layer-pages held now, and what they would be were
+                # every attention layer a full one with rows of its own
+                # (a slot's full-pool pages are its pages to its mapped
+                # position)
+                "layer_pages_in_use": full_layers * full_in_use
+                + win_layers * w.alloc.in_use,
+                "layer_pages_all_full": (readers + win_layers)
+                * full_in_use,
+            })
+            if readers > full_layers:
+                out["full_pool_readers"] = readers
+        state = blk.pools(depth).get("state")
+        if state:
+            out.update({
+                "state_bytes": int(sum(self.cache[n].nbytes
+                                       for n in state)),
+                "state_layers": int(self.cache[state[0]].shape[0]),
+            })
+        return out
 
     def pages_in_use_p95(self) -> int:
         """Nearest-rank p95 of pages in use, sampled at every chunk
@@ -3124,7 +3169,7 @@ class Engine:
                 "pages_in_use_p95": self.pages_in_use_p95(),
                 "pages_shared": self.alloc.pages_shared,
                 "pages_shared_saved": self.alloc.refs_saved,
-                **self._window_stats(),
+                **self._block_cache_stats(),
                 "evicted": self.evicted,
                 "deferred": self.deferred,
                 "requeued": self.queue.requeued,
@@ -3192,6 +3237,7 @@ class Engine:
             "warm_admits": self.warm_admits,
             **{k: getattr(self, k) for k in LOOP_SECONDS},
             "prefill_buckets": list(self.buckets),
+            "prefill_groups": list(self.prefill_groups),
             "harvests": self.harvests,
             "host_round_trips_per_token": round(
                 self.harvests / max(self.tokens_decoded, 1), 6),

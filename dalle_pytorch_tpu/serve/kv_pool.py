@@ -167,24 +167,34 @@ def page_layout(cfg, page_size: int, quantized: bool = False) -> dict:
       * a latent-attention block (``cfg.block``): ONE row a token, the
         latent and the roped key side by side and filled up to whole
         lanes, ``(page_size, row_width)``: no head axis and no V;
-      * a window-and-full, grouped-query block: one K row and one V row
-        a token, every KEY/VALUE head's numbers side by side in it,
-        ``(page_size, kv_heads * head_dim)`` each (no head axis between
-        page and row: the read contracts whole rows, as the latent
-        block's does), in two pools, one a layer type (``pool_plan``):
-        ``k`` / ``v`` hold the full layers, ``window_k`` / ``window_v``
-        the window layers."""
+      * a block of grouped-query or differential attention layers, window
+        and full: one K row and one V row a token, every KEY/VALUE head's
+        numbers side by side in it, ``(page_size, kv_heads * head_dim)``
+        each (no head axis between page and row: the read contracts whole
+        rows, as the latent block's does), in two pools, one a layer type
+        (``pool_plan``): ``k`` / ``v`` hold the full layers, ``window_k``
+        / ``window_v`` the window layers;
+      * a block with state-space layers holds, beside its page pools, a
+        cache that is NOT pages: a fixed-size recurrent state a slot a
+        layer, ``ssm_state`` ``(d_state, d_inner)`` (the wide dimension
+        minor: whole lanes) in float32 whatever the pool's type, and the
+        convolution's tail ``ssm_conv`` ``(d_conv - 1, d_inner)``. The axis that is a pool's ``num_pages`` is the
+        engine's slots there (``pool_plan``): nothing is allocated or
+        freed, a slot's state is overwritten at admission."""
     blk = getattr(cfg, "block", None)
     if blk is not None:
         if quantized:
             from dalle_pytorch_tpu.ops.transformer import BlockOptionError
             raise BlockOptionError(blk.name, "quantize_cache")
-        if hasattr(blk, "window"):
-            page = ((page_size, blk.kv_heads * blk.head_dim), None)
-            return {name: page for full in (True, False)
-                    if blk.cache_layers(full)
-                    for name in blk.pool_buffers(full)}
-        return {"latent": ((page_size, blk.row_width), None)}
+        out = {}
+        for pool, names in blk.pools(cfg.depth).items():
+            if pool == "state":
+                out[names[0]] = ((blk.d_state, blk.d_inner), 4)
+                out[names[1]] = ((blk.d_conv - 1, blk.d_inner), None)
+            else:
+                out.update({name: ((page_size, blk.page_row_width), None)
+                            for name in names})
+        return out
     page = (cfg.heads, page_size, cfg.dim_head)
     if quantized:
         return {"k": (page, 1), "v": (page, 1),
@@ -192,24 +202,32 @@ def page_layout(cfg, page_size: int, quantized: bool = False) -> dict:
     return {"k": (page, None), "v": (page, None)}
 
 
-def pool_plan(cfg, num_pages: int, window_pages: int) -> dict:
+def pool_plan(cfg, num_pages: int, window_pages: int,
+              num_slots: int = 0) -> dict:
     """``{buffer: (layers, pages)}``: how many layers and pages each
-    buffer of ``page_layout`` spans. Every block but one holds ONE pool,
-    every layer's pages under one page id; a window-and-full block holds
-    a pool a layer type, because a window layer never reads more than its
-    window of a slot's rows and its pages are reused as the slot moves on
-    (``WindowPages``): the full layers' ``k`` / ``v`` with ``num_pages``
-    pages and the window layers' ``window_k`` / ``window_v`` with
-    ``window_pages``."""
+    buffer of ``page_layout`` spans. The layers of a buffer are the layers
+    that STORE to it, not those that read it: a full pool of one layer may
+    be read by many. Every block but the described ones with window layers
+    holds ONE page pool, every layer's pages under one page id; those
+    hold a pool a layer type, because a window layer never reads more than
+    its window of a slot's rows and its pages are reused as the slot moves
+    on (``WindowPages``): the full layers' ``k`` / ``v`` with
+    ``num_pages`` pages and the window layers' ``window_k`` / ``window_v``
+    with ``window_pages``. A state-space layer's buffers span
+    ``num_slots``: a state a slot, and a block that has such layers is
+    given no plan without them."""
     blk = getattr(cfg, "block", None)
-    # (by attribute: this module imports nothing of jax's at its top)
-    if blk is None or not hasattr(blk, "window"):
-        names = ("latent",) if blk is not None else \
-            ("k", "v", "k_scale", "v_scale")
-        return {name: (cfg.depth, num_pages) for name in names}
-    return {name: (len(blk.cache_layers(full)), pages)
-            for full, pages in ((True, num_pages), (False, window_pages))
-            for name in blk.pool_buffers(full)}
+    if blk is None:
+        return {name: (cfg.depth, num_pages)
+                for name in ("k", "v", "k_scale", "v_scale")}
+    spans = {"full": num_pages, "window": window_pages, "state": num_slots}
+    pools = blk.pools(cfg.depth)
+    if "state" in pools and num_slots <= 0:
+        raise ValueError(
+            f"block {blk.name!r} holds a recurrent state a slot: "
+            f"num_slots={num_slots} gives its state no room")
+    return {name: (len(blk.cache_layers(pool, cfg.depth)), spans[pool])
+            for pool, names in pools.items() for name in names}
 
 
 def window_pool_pages(cfg, num_slots: int, total_len: int, page_size: int,
@@ -218,8 +236,7 @@ def window_pool_pages(cfg, num_slots: int, total_len: int, page_size: int,
     of ``num_pages``: the same share of what every slot could hold at
     once. 0 where the block has no window layers."""
     blk = getattr(cfg, "block", None)
-    if blk is None or not hasattr(blk, "window") \
-            or not blk.cache_layers(False):
+    if blk is None or not blk.cache_layers("window", cfg.depth):
         return 0
     ring = blk.ring_pages(page_size, total_len)
     whole = num_slots * pages_for(total_len, page_size)
@@ -227,18 +244,19 @@ def window_pool_pages(cfg, num_slots: int, total_len: int, page_size: int,
 
 
 def init_page_pool(cfg, num_pages: int, page_size: int, dtype=None,
-                   quantized: bool = False, window_pages: int = 0) -> dict:
+                   quantized: bool = False, window_pages: int = 0,
+                   num_slots: int = 0) -> dict:
     """Device-resident page pool(s): one ``(layers, pages) + page shape``
     buffer for each entry of ``page_layout``, spanning what ``pool_plan``
-    says."""
+    says (``num_slots`` for a state-space layer's state)."""
     import jax.numpy as jnp
     if dtype is None:
         dtype = jnp.float32
     kinds = {None: dtype, 1: jnp.int8, 4: jnp.float32}
-    plan = pool_plan(cfg, num_pages, window_pages)
+    layout = page_layout(cfg, page_size, quantized)
+    plan = pool_plan(cfg, num_pages, window_pages, num_slots)
     return {name: jnp.zeros(plan[name] + shape, kinds[size])
-            for name, (shape, size) in
-            page_layout(cfg, page_size, quantized).items()}
+            for name, (shape, size) in layout.items()}
 
 
 def visible_table_view(block_tables, visible):
@@ -303,7 +321,7 @@ def modeled_kv_bytes(cfg, *, kv: str, num_slots: int, total_len: int,
         # the dense slot cache holds the same rows, a slot a "page"
         ps, pages = total_len, num_slots
     plan = pool_plan(cfg, pages, window_pool_pages(
-        cfg, num_slots, total_len, ps, pages))
+        cfg, num_slots, total_len, ps, pages), num_slots)
     # a page's bytes, buffer by buffer (quantized: int8 rows plus one
     # f32 scale a row), times the layers and pages the buffer spans
     return int(sum(math.prod(plan[name]) * math.prod(shape)

@@ -55,8 +55,26 @@ def prefill_buckets(text_seq_len: int) -> Tuple[int, ...]:
     return tuple(out)
 
 
+SMALL_PREFILL_GROUP = 4
+
+
+def prefill_groups(num_slots: int) -> Tuple[int, ...]:
+    """The row counts an admission's prefill program is compiled for: a
+    small group and the whole of the slots. An engine in steady state
+    admits one request as one slot frees (a guided pair two rows, a
+    fan-out of four its four), and a ``num_slots``-row prefill for that
+    one row is the dearest thing in an image (an admission of 461 ms
+    against 60 at 32 slots of a 32-layer block, PERF.md section 6, PR 35);
+    a cold start or a burst fills many slots at once and takes the whole
+    group. Two shapes a bucket, both a pure function of ``num_slots``, so
+    the set of programs stays small and fixed; with four slots or fewer
+    there is one."""
+    return tuple(sorted({min(SMALL_PREFILL_GROUP, num_slots), num_slots}))
+
+
 def bucket_for(n: int, buckets: Sequence[int]) -> int:
-    """Smallest bucket holding a length-``n`` prompt. ``buckets`` must be
+    """Smallest bucket holding a length-``n`` prompt (or, of
+    ``prefill_groups``, an ``n``-row admission). ``buckets`` must be
     sorted ascending; raises for a prompt no bucket can hold (callers
     validate prompt length before bucketing)."""
     for b in buckets:

@@ -141,17 +141,21 @@ def _prefilled_pools(params, sequences, t0):
                        jnp.asarray(sequences[:, t:t0]))
     h, cache = decode_ops.prefill(params["transformer"], x, cfg=TCFG,
                                   total_len=DIMS.seq_len)
-    assert cache["k"].shape == (5, b, t0, 2, 8)
+    # the prompt's rows come a buffer of the pool each, over the layers
+    # that store to it
+    assert cache["k"].shape == (len(FULL_LAYERS), b, t0, 2, 8)
+    assert cache["window_k"].shape == (len(WINDOW_LAYERS), b, t0, 2, 8)
     pool = dict(pool)
     for name in ("k", "v"):
         full, ring = pool[name], pool["window_" + name]
         for i in range(b):
             for j in range(t0):
-                row = cache[name][:, i, j].reshape(DIMS.depth, -1)
-                full = full.at[:, tables["full"][i, j // PS],
-                               j % PS].set(row[np.asarray(FULL_LAYERS)])
+                full = full.at[:, tables["full"][i, j // PS], j % PS].set(
+                    cache[name][:, i, j].reshape(len(FULL_LAYERS), -1))
                 ring = ring.at[:, tables["window"][i, (j // PS) % RING],
-                               j % PS].set(row[np.asarray(WINDOW_LAYERS)])
+                               j % PS].set(
+                    cache["window_" + name][:, i, j].reshape(
+                        len(WINDOW_LAYERS), -1))
         pool[name], pool["window_" + name] = full, ring
     return h, pool, tables
 

@@ -271,6 +271,7 @@ def test_stack_of_one_dense_and_two_expert_layers_indexes_the_pool(params):
     assert jax.tree.map(jnp.shape, init) == jax.tree.map(jnp.shape, tp)
     x = jax.random.normal(jax.random.PRNGKey(2), (1, 6, 32))
     h, entries, loads = T.block_apply_full(tp, x, TCFG)
+    entries = entries["latent"]         # the one pool's one buffer
     assert entries.shape == (3, 1, 6, BLK.row_width)
     assert np.asarray(loads)[0].tolist() == [0, 0, 0]       # the dense layer
     assert (np.asarray(loads)[1:, 0] == 6 * 2).all()

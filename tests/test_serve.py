@@ -35,7 +35,7 @@ from dalle_pytorch_tpu.serve import (DEADLINE_EXCEEDED, ERROR, OK,
                                      PagePoolExhausted, QueueClosed,
                                      QueueFull, Request, RequestQueue,
                                      SamplingParams, bucket_for, pages_for,
-                                     prefill_buckets)
+                                     prefill_buckets, prefill_groups)
 from dalle_pytorch_tpu.serve.engine import Engine
 
 VCFG = V.VAEConfig(image_size=16, num_tokens=32, codebook_dim=16,
@@ -619,6 +619,64 @@ class TestBucketedPrefill:
         for h, ref in zip(handles, refs):
             np.testing.assert_array_equal(
                 np.asarray(h.result(timeout=5).tokens), ref)
+
+
+class TestPrefillGroupSizedToNeed:
+    """An admission prefills the smallest group of rows that holds it
+    (``scheduler.prefill_groups``: a small group or all the slots): one
+    request joining a busy engine does not pay for ``num_slots`` rows,
+    and the group's size is invisible in the tokens."""
+
+    @pytest.mark.parametrize("slots,groups", [
+        (1, (1,)), (3, (3,)), (4, (4,)), (5, (4, 5)), (32, (4, 32))])
+    def test_groups_are_a_small_one_and_all_the_slots(self, slots, groups):
+        assert prefill_groups(slots) == groups
+
+    @pytest.mark.parametrize("kv", ["dense", "paged"])
+    def test_one_request_takes_the_small_group_a_burst_the_whole(
+            self, bundle, kv):
+        """Six slots: a lone request admits through the 4-row program, a
+        burst of six through the 6-row one, each compiled once, under
+        their own names; every stream equals the one-shot sampler's."""
+        params, vae_params = bundle
+        refs = [reference_tokens(params, vae_params, r) for r in REQS]
+        queue = RequestQueue(max_depth=16)
+        paged = dict(kv="paged", page_size=4) if kv == "paged" else {}
+        engine = Engine(params, CFG, queue, num_slots=6,
+                        prefill_buckets=(CFG.text_seq_len,), **paged)
+        b = CFG.text_seq_len
+        assert engine.prefill_groups == (4, 6)
+        assert engine.stats()["prefill_groups"] == [4, 6]
+
+        lone = queue.submit(REQS[0])
+        assert engine.compile_pending()
+        engine.run_until_idle()
+        assert (engine.prefill_trace_count(b, 4),
+                engine.prefill_trace_count(b)) == (1, 0)
+        np.testing.assert_array_equal(
+            np.asarray(lone.result(timeout=5).tokens), refs[0])
+
+        burst = [queue.submit(r) for r in REQS + REQS]
+        assert engine.compile_pending()     # the whole group is not built
+        engine.run_until_idle()
+        assert (engine.prefill_trace_count(b, 4),
+                engine.prefill_trace_count(b)) == (1, 1)
+        for h, ref in zip(burst, refs + refs):
+            np.testing.assert_array_equal(
+                np.asarray(h.result(timeout=5).tokens), ref)
+
+        again = [queue.submit(r) for r in REQS[:2]]
+        assert not engine.compile_pending()
+        with guards.compile_count(lambda: engine.prefill_traces, expect=0,
+                                  label="prefill groups, both built"):
+            engine.run_until_idle()
+        for h, ref in zip(again, refs):
+            np.testing.assert_array_equal(
+                np.asarray(h.result(timeout=5).tokens), ref)
+        assert engine.prefill_runs == 3
+        assert sorted(engine.device_scopes()) == sorted(
+            [engine._decode_fn.__name__, f"prefill_b{b}",
+             f"prefill_b{b}_g4"])
 
 
 class TestBackpressure:
