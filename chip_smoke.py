@@ -613,7 +613,8 @@ def check_paged_step_math(w: Widths, info: dict) -> None:
         L, ps = cfg.seq_len, w.page_size
         mp = KV.pages_for(L, ps)
         params = D.dalle_init(jax.random.PRNGKey(1), cfg, dtype=pdtype)
-        shape = (tcfg.depth, 2 * mp + 1, tcfg.heads, ps, tcfg.dim_head)
+        shape = (tcfg.depth, 2 * mp + 1, ps, tcfg.heads * tcfg.dim_head)
+        scales = shape[:-1] + (tcfg.heads,)
         key = jax.random.PRNGKey(7)
         if quantized:
             pool = {
@@ -622,10 +623,10 @@ def check_paged_step_math(w: Widths, info: dict) -> None:
                 "v": jax.random.randint(jax.random.fold_in(key, 1), shape,
                                         -127, 128, jnp.int8),
                 "k_scale": jax.random.uniform(
-                    jax.random.fold_in(key, 2), shape[:-1], minval=0.01,
+                    jax.random.fold_in(key, 2), scales, minval=0.01,
                     maxval=0.1),
                 "v_scale": jax.random.uniform(
-                    jax.random.fold_in(key, 3), shape[:-1], minval=0.01,
+                    jax.random.fold_in(key, 3), scales, minval=0.01,
                     maxval=0.1)}
         else:
             pool = {"k": jax.random.normal(jax.random.fold_in(key, 0),
@@ -646,7 +647,7 @@ def check_paged_step_math(w: Widths, info: dict) -> None:
 
         @jax.jit
         def both(params, pool, x_tok):
-            view = decode_ops.paged_view(pool, bt, L)
+            view = decode_ops.paged_view(pool, bt, L, tcfg.heads)
             gather = decode_ops._decode_step_math(
                 params["transformer"], x_tok, pos, view, cfg=tcfg,
                 key_mask=key_mask)[0]

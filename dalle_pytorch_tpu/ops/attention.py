@@ -387,14 +387,17 @@ def gqa_attend_materialised(q: Array, k: Array, v: Array, allowed: Array,
 
 def gqa_attend_rows(q: Array, k: Array, v: Array, rows_k: Array,
                     gather_v, allowed: Array, scale: float,
-                    window: bool, diff_lam: Optional[Array] = None) -> Array:
+                    window: bool, diff_lam: Optional[Array] = None,
+                    k_scale: Optional[Array] = None, gather_v_scale=None,
+                    per_head: bool = False) -> Array:
     """The decode read, one query a slot: q (b, heads, dh); k / v (b,
     kv_heads, dh) the token's own rows (always attended); ``rows_k`` (b,
     m, kv_heads * dh) the cached K rows as they lie in the gathered pages,
     every key/value head's dh numbers side by side in a row, and
-    ``gather_v()`` the V rows the same way, asked for once K's readers are
-    done (the budget of a slot group is ONE gathered buffer: ops/decode.py
-    ``view_slot_groups``); ``allowed`` (b, m) -> (b, heads, dh).
+    ``gather_v(wts)`` the V rows the same way, asked for once K's readers
+    are done (the budget of a slot group is ONE gathered buffer:
+    ops/decode.py ``view_slot_groups``); ``allowed`` (b, m) -> (b, heads,
+    dh). The classic block's read is this one at ``kv_heads == heads``.
 
     The rows are contracted whole, all query heads at once, as the latent
     block's absorbed read contracts its rows: a query head is given zeros
@@ -404,15 +407,40 @@ def gqa_attend_rows(q: Array, k: Array, v: Array, rows_k: Array,
     alone (a batch dimension between page and row) is what the compiler
     turned into a relayout of every gathered page (PERF.md section 6,
     PR 33); the zeros cost matrix-unit passes that have no other use here.
-    ``window`` names the read in a trace. With ``diff_lam`` the read is
-    the differential one: the same products and softmaxes, the pairing
-    (``_pair_weights``) after them -> (b, heads / 2, 2 dh)."""
+    ``per_head`` asks for exactly that contraction, the row viewed as (m,
+    kv_heads, dh): under a mesh that shards the heads a whole-row
+    contraction would sum partial scores across chips, and a head's own
+    columns lie on one chip. The mask, the softmax and the self logit are
+    the same in both forms.
+
+    An int8 pool hands its float32 scale pages beside the rows, ``k_scale``
+    (b, m, kv_heads) and ``gather_v_scale()`` the same: they multiply the
+    float32 scores and the weights, outside the contractions, so that no
+    dequantised copy of a page is made. ``window`` names the read in a
+    trace. With ``diff_lam`` the read is the differential one: the same
+    products and softmaxes, the pairing (``_pair_weights``) after them
+    -> (b, heads / 2, 2 dh); it has no int8 form."""
     b, heads, dh = q.shape
     kvh = k.shape[1]
-    qg = q.reshape(b, kvh, heads // kvh, dh)
+    g = heads // kvh
+    qg = q.reshape(b, kvh, g, dh)
+
+    def by_head(sc):        # (b, m, kv_heads) -> (b, heads, m)
+        return jnp.repeat(jnp.moveaxis(sc, 1, 2), g, axis=1)
+
     with _read_scope(window):
-        scores = jnp.einsum("bhc,bjc->bhj", _own_columns(qg), rows_k,
-                            preferred_element_type=jnp.float32) * scale
+        if k_scale is not None:
+            rows_k = rows_k.astype(q.dtype)
+        if per_head:
+            scores = jnp.einsum(
+                "bkgd,bjkd->bkgj", qg, rows_k.reshape(b, -1, kvh, dh),
+                preferred_element_type=jnp.float32).reshape(b, heads, -1)
+        else:
+            scores = jnp.einsum("bhc,bjc->bhj", _own_columns(qg), rows_k,
+                                preferred_element_type=jnp.float32)
+        scores = scores * scale
+        if k_scale is not None:
+            scores = scores * by_head(k_scale)
         scores = jnp.where(allowed[:, None, :], scores,
                            core.neg_inf(scores.dtype))
         own = jnp.einsum("bkgd,bkd->bkg", qg, k,
@@ -423,20 +451,27 @@ def gqa_attend_rows(q: Array, k: Array, v: Array, rows_k: Array,
             # from here on a pair of heads is one head of twice the width
             # over a pair of key/value heads
             wts = _pair_weights(
-                wts.reshape(b, kvh, heads // kvh, -1), diff_lam
+                wts.reshape(b, kvh, g, -1), diff_lam
             ).reshape(b, heads // 2, -1)
             heads, kvh, dh = heads // 2, kvh // 2, 2 * dh
             v = v.reshape(b, kvh, dh)
         wts = wts.astype(v.dtype)
     rows_v = gather_v(wts)
     with _read_scope(window):
-        o_rows = jnp.einsum("bhj,bjc->bhc", wts[..., :-1], rows_v)
-        # of each whole-row sum, the columns of the head's own kv head
-        o = jnp.einsum("bkgjd,kj->bkgd",
-                       o_rows.reshape(b, kvh, heads // kvh, kvh, dh),
-                       jnp.eye(kvh, dtype=o_rows.dtype))
-        o = o + wts[..., -1].reshape(b, kvh, heads // kvh, 1) \
-            * v[:, :, None, :]
+        wj = wts[..., :-1]
+        if gather_v_scale is not None:
+            wj = wj * by_head(gather_v_scale()).astype(wj.dtype)
+            rows_v = rows_v.astype(wj.dtype)
+        if per_head:
+            o = jnp.einsum("bkgj,bjkd->bkgd", wj.reshape(b, kvh, g, -1),
+                           rows_v.reshape(b, -1, kvh, dh))
+        else:
+            o_rows = jnp.einsum("bhj,bjc->bhc", wj, rows_v)
+            # of each whole-row sum, the columns of the head's own kv head
+            o = jnp.einsum("bkgjd,kj->bkgd",
+                           o_rows.reshape(b, kvh, g, kvh, dh),
+                           jnp.eye(kvh, dtype=o_rows.dtype))
+        o = o + wts[..., -1].reshape(b, kvh, g, 1) * v[:, :, None, :]
         return o.reshape(b, heads, dh)
 
 

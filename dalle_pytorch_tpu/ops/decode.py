@@ -65,17 +65,16 @@ def view_slot_groups(slots: int, columns: int, page_shape, dtype) -> int:
     one layer (K's readers finish before V's gather starts,
     ``_paged_gather_read``), stay under ``_VIEW_VMEM_BYTES``; one slot a
     group where no divisor fits. It reads what a trace sees and nothing
-    else: the slots, the table's ``columns``, a page's shape (heads, rows,
-    dim_head), or (rows, width) for a latent pool, and the pool's dtype.
-    The bytes are counted AS LAID OUT: the minor dimension is filled to
-    whole 128-lane tiles, so a 64-wide head takes the room of a 128-wide
-    one, and the rows to whole tiles of 8 words (16 bf16 rows, 32 int8
-    rows: the int8 pool's 16-row page takes the room of the bf16 one)."""
-    *outer, rows, minor = page_shape
+    else: the slots, the table's ``columns``, a page's shape (rows, width:
+    ``kv_pool.page_layout``'s, the same in every block) and the pool's
+    dtype. The bytes are counted AS LAID OUT: the width is filled to whole
+    128-lane tiles (the classic block's ``heads * dim_head`` is whole
+    tiles at every published head size) and the rows to whole tiles of 8
+    words (16 bf16 rows, 32 int8 rows: the int8 pool's 16-row page takes
+    the room of the bf16 one)."""
     itemsize = jnp.dtype(dtype).itemsize
-    filled = [-(-n // t) * t
-              for n, t in zip((rows, minor), _tile_of(dtype))]
-    slot_bytes = columns * math.prod(outer) * math.prod(filled) * itemsize
+    filled = [-(-n // t) * t for n, t in zip(page_shape, _tile_of(dtype))]
+    slot_bytes = columns * math.prod(filled) * itemsize
     return next((g for g in range(1, slots) if slots % g == 0
                  and slots // g * slot_bytes <= _VIEW_VMEM_BYTES), slots)
 
@@ -85,41 +84,6 @@ def pool_view_groups(pool: dict, slots: int, columns: int) -> int:
     (slots, columns), trimmed as the read trims it."""
     buf = pool["latent"] if "latent" in pool else pool["k"]
     return view_slot_groups(slots, columns, buf.shape[2:], buf.dtype)
-
-
-def read_heads_merged(page_shape, dtype, mesh: bool = False) -> bool:
-    """The rule of the classic block's paged gather read
-    (``_paged_gather_read``): whether a slot group's gathered pages are
-    contracted as whole rows against ALL heads' queries at once (True) or
-    a head's query against its own pages (False). Like
-    ``view_slot_groups`` it reads what a trace sees and nothing else: a
-    page's shape (heads, rows, dim_head), the pool's dtype, and whether
-    the call was handed the mesh seam (``out_sync``).
-
-    Both forms are one algorithm (the same rows, mask and softmax) and
-    differ in two einsums. Per head, ``b`` and ``h`` are batch dimensions
-    over ONE query row, and the TPU compiler makes each contraction a
-    multiply-and-reduce over lanes on the vector unit, as slow as the
-    gather that fed it. Merged, a slot's pages are ``columns * heads *
-    rows`` rows of ``dim_head`` against a (heads, dim_head) matrix of
-    queries: a product on the matrix unit, of which a head keeps the
-    scores of its own pages' rows; the other ``heads - 1`` parts are
-    passes of a unit that has no other use in a decode step, and the
-    compiler fuses the keeping into the product, so the all-heads scores
-    are never written (AOT text of both ``dalle`` cells' programs). On
-    the chip (PERF.md section 6, PR 34) ``decode_attend_ms`` fell 4.33 ->
-    2.01 at ruDALL-E's 16 heads of 128 and 4.35 -> 1.98 at 12b's 62 heads
-    of 64, whose rows are half lane padding in either form. The merged
-    form is taken where both of these hold:
-
-      * no mesh: with the heads sharded over ``tp``, rows that hold
-        every head's pages would make the partitioner gather the pool;
-      * a page's rows are whole tiles of the pool's dtype (16 bf16 rows,
-        32 int8 rows, 8 float32 rows), so that (columns, heads, rows,
-        dim_head) -> (columns * heads * rows, dim_head) is a bitcast of
-        the gathered buffer and not a copy of it."""
-    _, rows, _ = page_shape
-    return not mesh and rows % _tile_of(dtype)[0] == 0
 
 
 def _read_in_slot_groups(pool: dict, tables: Array, read) -> Array:
@@ -389,10 +353,12 @@ def _pool_scope(window: bool):
 def layer_pool_view(buf: Array, layer: Array, tables: Array,
                     window: bool = False) -> Array:
     """ONE layer's pages of one pool buffer through the block tables, read
-    where they lie: ``buf`` (depth, P, ...page) is a pool's ``k`` or ``v``
-    (page = heads, ps, dh), an int8 pool's ``k_scale`` / ``v_scale``
-    (heads, ps) or a latent pool's ``latent`` (ps, width); ``layer`` a
-    traced scalar, tables (b, w) -> (b, w, ...page). The one per-layer
+    where they lie: ``buf`` (depth, P, ps, row) is any buffer of any
+    block's page pool (``kv_pool.page_layout``: a page is ``(page_size,
+    row)`` in every block: the classic block's ``k`` / ``v`` rows of
+    ``heads * dim_head``, its int8 pool's scale rows of ``heads``, a
+    latent pool's ``latent``, a grouped-query pool's rows); ``layer`` a
+    traced scalar, tables (b, w) -> (b, w, ps, row). The one per-layer
     view of BOTH step maths and of a described block's step: the full
     table trimmed to ``ceil(total_len / ps)`` columns, or a sparse layer's
     visible slice of it, always the rows of ONE slot group
@@ -405,9 +371,10 @@ def layer_pool_view(buf: Array, layer: Array, tables: Array,
         (a bitcast), not taken from a scanned per-layer slice: the
         scan's slice of a layer is a copy of that layer;
       * page-major and unrelaid — a page is one contiguous run of the
-        pool, and ``_paged_gather_read`` contracts it as it lies; the
-        slot-major ``moveaxis`` + ``reshape`` of ``paged_view`` is what
-        turned the read into transposing copies of the pool;
+        pool, and the reads contract its rows as they lie
+        (``_gathered_rows``); the slot-major ``moveaxis`` + ``reshape``
+        of ``paged_view`` is what turned the read into transposing
+        copies of the pool;
       * ``mode='clip'``: tables are in range by construction, and the
         default fill mode adds a select over every gathered row.
 
@@ -418,118 +385,64 @@ def layer_pool_view(buf: Array, layer: Array, tables: Array,
                         layer * buf.shape[1] + tables, axis=0, mode="clip")
 
 
+def _gathered_rows(buf: Array, layer: Array, tables: Array,
+                   window: bool = False, after=None) -> Array:
+    """One slot group's pages of a pool buffer (``layer_pool_view``),
+    merged to rows in the order they were gathered -> (b, w * ps, row): a
+    bitcast when the page is whole sublane tiles (no axis lies between
+    page and row). ``after`` ties the gather to a value that must be
+    computed first (the softmax's weights, for V's pages): among several
+    slot groups the TPU scheduler was seen to lift one group's V gather
+    above that group's K gather (AOT, PR 31: of 12b's six gathers a layer
+    one then misses VMEM), and the budget of a group is ONE gathered
+    buffer (``view_slot_groups``), so there the order is stated."""
+    if after is not None:
+        _, tables = lax.optimization_barrier((after, tables))
+    pages = layer_pool_view(buf, layer, tables, window)
+    with _pool_scope(window):
+        return pages.reshape(pages.shape[0], -1, pages.shape[-1])
+
+
 def _paged_gather_read(pool: dict, layer: Array, tables: Array, q: Array,
                        k: Array, v: Array, allowed: Array, *, scale: float,
                        v_after_k: bool = False, mesh: bool = False) -> Array:
-    """``_gather_read`` for ONE slot group over ``layer_pool_view``'s
-    page-major pages: tables (b, w) into the K/V pool, q/k/v (b, h, 1, dh),
-    allowed (b, rows) with rows <= w * ps (logical row j is page j // ps,
-    offset j % ps; a partial last page's tail rows are dropped). K's pages
-    (b, w, heads, ps, dh) are gathered and contracted as they lie and only
-    the SCORES (b, heads, w * ps: kilobytes) are brought to logical row
-    order, so the softmax runs over rows 0..rows-1 in order plus the self
-    logit, exactly the dense view's; then V's pages the same way. The int8
-    pool's scale pages are gathered with their rows and apply outside the
-    contractions, as there. Returns (b, h, 1, dh).
+    """``_gather_read`` for ONE slot group over the classic block's page
+    pool: tables (b, w) into the K/V pool, q/k/v (b, h, 1, dh), allowed
+    (b, rows) with rows <= w * ps (logical row j is page j // ps, offset
+    j % ps; a partial last page's tail rows are dead). A page is whole
+    rows, ``(ps, heads * dh)``, so the gathered pages ARE the slot's rows
+    in logical order (``_gathered_rows``: a bitcast), and the read is the
+    grouped-query one at ``kv_heads == heads``
+    (``ops.attention.gqa_attend_rows``, which the described blocks run):
+    every head's query against whole rows in one product on the matrix
+    unit, float32 scores (b, heads, w * ps) in row order, the masked
+    softmax over rows 0..rows-1 plus the self logit, exactly the dense
+    view's; then V's pages the same way, gathered once K's readers are
+    done (``v_after_k`` states that order where a layer reads several
+    groups). The int8 pool's scale pages ``(ps, heads)`` are gathered
+    with their rows and apply outside the contractions, to the float32
+    scores and to the weights. Returns (b, h, 1, dh).
 
-    The two contractions take one of two forms, by the shapes
-    (``read_heads_merged``, which gives the reasons and the readings;
-    ``mesh`` is whether the step was handed ``out_sync``):
-      * all heads merged (``_scores_heads_merged``,
-        ``_values_heads_merged``): the pages as ``w * heads * ps`` whole
-        rows (a bitcast) against every head's query in one matrix
-        product a slot, of which a head keeps its own pages' rows, and
-        the weights spread back over the rows with zeros in the other
-        heads' for V. Float32 accumulation, the scale (and an int8
-        pool's) applied to the float32 scores before they are rounded
-        once to the query's dtype. Both ``dalle`` cells' reads;
-      * per head (``"bhd,bmhsd->bhms"``, ``"bhms,bmhsd->bhd"``): a head's
-        one query row against its own pages, ``b`` and ``h`` batch
-        dimensions. On the TPU these are lane reductions on the vector
-        unit. It is what a mesh or a page short of a whole tile of rows
-        gets.
-
-    ``v_after_k`` ties V's gather to the softmax's weights. The budget of
-    a slot group is ONE gathered buffer (``view_slot_groups``), which
-    holds only while K's readers finish before V's gather starts. Alone
-    in its layer a group is scheduled so; among several the TPU scheduler
-    was seen to lift one group's V gather above that group's K gather
-    (AOT, PR 31: of 12b's six 81 MB gathers a layer one then misses VMEM),
-    so there the order is stated."""
-    def view(name, t):
-        sc = pool.get(name + "_scale")
-        return (layer_pool_view(pool[name], layer, t),
-                None if sc is None else layer_pool_view(sc, layer, t))
-
-    gk, ksc = view("k", tables)
-    b, w, h, ps, _ = gk.shape
-    rows = allowed.shape[1]
-    quantized = ksc is not None
-    merged = read_heads_merged(gk.shape[2:], gk.dtype, mesh)
-    with jax.named_scope("attn.read"):
-        gkc = gk.astype(q.dtype) if quantized else gk
-        if merged:
-            scores = _scores_heads_merged(q[:, :, 0, :], gkc, scale, ksc)
-        else:
-            scores = jnp.einsum("bhd,bmhsd->bhms", q[:, :, 0, :], gkc) * scale
-            if quantized:
-                scores = scores * jnp.moveaxis(ksc, 1, 2).astype(scores.dtype)
-        scores = scores.reshape(b, h, 1, w * ps)[..., :rows]
-        wts = _softmax_with_self(scores, allowed, q, k, scale)
-    if v_after_k:
-        wts, tables = lax.optimization_barrier((wts, tables))
-    gv, vsc = view("v", tables)
-    with jax.named_scope("attn.read"):
-        wj = jnp.pad(wts[:, :, 0, :-1],
-                     ((0, 0), (0, 0), (0, w * ps - rows))).reshape(b, h, w, ps)
-        if quantized:
-            wj = wj * jnp.moveaxis(vsc, 1, 2).astype(wj.dtype)
-            gvc = gv.astype(q.dtype)
-        else:
-            gvc = gv
-        out = _values_heads_merged(wj, gvc) if merged \
-            else jnp.einsum("bhms,bmhsd->bhd", wj, gvc)
-        return out[:, :, None, :] + wts[..., -1:] * v
-
-
-def _own_rows(heads: int, w: int, ps: int) -> Array:
-    """(heads, w * heads * ps) bool: of a slot's gathered pages as rows
-    (page, head, row-in-page), those of each head's own pages."""
-    head_of_row = jnp.arange(w * heads * ps) // ps % heads
-    return head_of_row[None, :] == jnp.arange(heads)[:, None]
-
-
-def _scores_heads_merged(q: Array, gk: Array, scale: float,
-                         ksc: Optional[Array]) -> Array:
-    """The merged form's K contraction: q (b, heads, dh) against gk (b, w,
-    heads, ps, dh) read as R = w * heads * ps rows -> (b, heads, w, ps) in
-    q's dtype. ``"bgd,bRd->bgR"`` is a (heads x dh) @ (dh x R) product a
-    slot; row r belongs to one head, so the sum over ``g`` of the scores
-    masked to ``_own_rows`` IS that head's score of row r, in the pages'
-    own (w, heads, ps) order (an int8 pool's scale pages lie in the same
-    order); only those (b, R) are brought head-major."""
-    b, w, heads, ps, dh = gk.shape
-    every = jnp.einsum("bgd,bRd->bgR", q, gk.reshape(b, w * heads * ps, dh),
-                       preferred_element_type=jnp.float32)
-    own = jnp.where(_own_rows(heads, w, ps), every, 0.0).sum(axis=1) * scale
-    if ksc is not None:
-        own = own * ksc.reshape(own.shape)
-    return jnp.moveaxis(own.astype(q.dtype).reshape(b, w, heads, ps), 2, 1)
-
-
-def _values_heads_merged(wj: Array, gv: Array) -> Array:
-    """The merged form's V contraction: the weights wj (b, heads, w, ps)
-    laid along the pages' rows (page, head, row-in-page), each head's in
-    its own pages' rows and zeros in the others' (the block-diagonal
-    operand of ``ops.attention.gqa_attend_rows``, here on the weights'
-    side), against gv (b, w, heads, ps, dh) read as rows: a (heads x R) @
-    (R x dh) product a slot -> (b, heads, dh)."""
-    b, w, heads, ps, dh = gv.shape
-    flat = jnp.moveaxis(wj, 1, 2).reshape(b, 1, w * heads * ps)
-    spread = jnp.where(_own_rows(heads, w, ps), flat, 0)
-    return jnp.einsum("bgR,bRd->bgd", spread,
-                      gv.reshape(b, w * heads * ps, dh),
-                      preferred_element_type=jnp.float32).astype(wj.dtype)
+    ``mesh`` (whether the step was handed ``out_sync``) asks for the same
+    rows read per head: with the pool's row sharded over the heads a
+    whole-row contraction would sum partial scores across chips."""
+    ps = pool["k"].shape[2]
+    with jax.named_scope("attn.read"):    # a partial last page's tail
+        allowed = jnp.pad(allowed, ((0, 0), (
+            0, tables.shape[1] * ps - allowed.shape[1])))
+    scales = {}
+    if "k_scale" in pool:
+        scales = dict(
+            k_scale=_gathered_rows(pool["k_scale"], layer, tables),
+            gather_v_scale=lambda: _gathered_rows(pool["v_scale"], layer,
+                                                  tables))
+    out = attn_ops.gqa_attend_rows(
+        q[:, :, 0], k[:, :, 0], v[:, :, 0],
+        _gathered_rows(pool["k"], layer, tables),
+        lambda wts: _gathered_rows(pool["v"], layer, tables,
+                                   after=wts if v_after_k else None),
+        allowed, scale, False, per_head=mesh, **scales)
+    return out[:, :, None, :]
 
 
 def _paged_gather_attend(pool: dict, layer: Array, tables: Array,
@@ -538,10 +451,9 @@ def _paged_gather_attend(pool: dict, layer: Array, tables: Array,
     """One layer's paged gather read of the classic block, whole:
     ``_paged_gather_read`` over the slots of ``tables`` (b, w), a slot
     group at a time (``_read_in_slot_groups`` decides the groups from the
-    shapes, ``read_heads_merged`` a group's contraction form from them and
-    from ``mesh``: whether the step was handed ``out_sync``). q/k/v (b, h,
-    1, dh), allowed (b, rows) -> (b, h, 1, dh) BEFORE
-    out_sync/out-projection."""
+    shapes; ``mesh``, whether the step was handed ``out_sync``, the form
+    of a group's two contractions). q/k/v (b, h, 1, dh), allowed (b,
+    rows) -> (b, h, 1, dh) BEFORE out_sync/out-projection."""
     def read(sl):
         t = tables[sl]
         return _paged_gather_read(
@@ -732,20 +644,18 @@ def _decode_step_math(params: dict, x_tok: Array, pos: Array, cache: dict,
     is DENSE per-slot rows ``(depth, b, heads, L, dh)`` — the real dense
     slot cache, or a ``paged_view`` an oracle built — read by one einsum
     softmax (``_gather_read``). With ``block_tables`` it is the raw PAGE
-    POOL ``(depth, P, heads, page_size, dh)`` and ``attn_impl`` is the
+    POOL ``(depth, P, page_size, heads * dh)`` and ``attn_impl`` is the
     paged-read seam: ``'gather'`` (default) has the scan's body gather
     ITS OWN layer's pages through the tables trimmed to
     ``ceil(total_len / page_size)`` columns (``layer_pool_view``) and
-    contract them page-major under the same masked softmax
+    contract them as the rows they are under the same masked softmax
     (``_paged_gather_read``), a slot group at a time
     (``_paged_gather_attend``) — one read of the pool a step, and no
-    buffer of the pool's size besides the pool. Which contraction runs
-    is decided from the shapes and from ``out_sync`` by
-    ``read_heads_merged``: all heads' queries against a slot's pages as
-    whole rows, one product on the matrix unit (pages of whole tiles of
-    rows and no mesh: both ``dalle`` cells), or a head's query against
-    its own pages, lane reductions on the vector unit (a mesh; PERF.md
-    section 6, PR 34); ``'kernel'`` consumes
+    buffer of the pool's size besides the pool. Every head's query is
+    contracted against a slot's whole rows in one product on the matrix
+    unit; under a mesh (``out_sync``), whose chips each hold some heads'
+    columns of every row, a head's query against its own columns;
+    ``'kernel'`` consumes
     the tables in place via the Pallas ragged paged-attention kernel
     (``ops.paged_attention``), which fetches only each slot's mapped
     live pages into VMEM and returns online-softmax partials that the
@@ -790,7 +700,7 @@ def _decode_step_math(params: dict, x_tok: Array, pos: Array, cache: dict,
     paged_gather = block_tables is not None and not kernel_mode
     if paged_gather:
         view_tables = _view_tables(block_tables, total_len,
-                                   cache["k"].shape[3])
+                                   cache["k"].shape[2])
 
     with jax.named_scope("attn.read"):       # the masks
         j = jnp.arange(total_len)
@@ -926,7 +836,7 @@ def _decode_step_math_sparse_reads(params: dict, x_tok: Array, pos: Array,
         raise ValueError(f"attn_impl must be 'gather' or 'kernel', "
                          f"got {attn_impl!r}")
     kernel_mode = attn_impl == "kernel"
-    ps = pool["k"].shape[3]
+    ps = pool["k"].shape[2]
 
     with jax.named_scope("attn.read"):   # masks and visibility tables
         j = jnp.arange(total_len)
@@ -1026,26 +936,26 @@ def _decode_step_math_sparse_reads(params: dict, x_tok: Array, pos: Array,
 # The serve engine's dense slot cache reserves num_slots x total_len rows of
 # HBM whether or not a slot is anywhere near total_len. The paged layout
 # (PAPERS.md "Ragged Paged Attention"; serve/kv_pool.py is the allocator)
-# stores K/V in a shared pool of fixed-size PAGES, (depth, num_pages,
-# heads, page_size, dim_head), and gives each slot a small int32 block
-# table mapping logical page j -> physical page id. Requests at different
+# stores K/V in a shared pool of fixed-size PAGES of whole rows, (depth,
+# num_pages, page_size, heads * dim_head), and gives each slot a small int32
+# block table mapping logical page j -> physical page id. Requests at different
 # positions then share one physical budget: a slot 10 tokens into its
 # sequence holds ceil(11/page_size) pages, not total_len rows.
 #
 # The decode step reads the pool WHERE IT LIES (``attn_impl='gather'``, the
 # default): inside the layer scan each layer gathers its own pages through
 # the block tables (``layer_pool_view``: whole pages, page-major, indexed
-# by layer into the pool itself) and contracts them in that form
-# (``_paged_gather_read``: as whole rows against all heads' queries on the
-# matrix unit where ``read_heads_merged`` finds the shapes for it, per head
-# otherwise); only the scores are brought to logical row
-# order, so the softmax is the dense step's and paged-vs-dense tokens are
-# equal. The slots are read a group at a time, so that a group's gathered
-# pages stay in VMEM between the gather and its readers; the groups are
+# by layer into the pool itself) and contracts them as the rows they are
+# (``_paged_gather_read``: whole rows against all heads' queries on the
+# matrix unit, the described blocks' grouped-query read at kv_heads ==
+# heads); the gathered pages are the slot's rows in logical order, so the
+# softmax is the dense step's and paged-vs-dense tokens are equal. The
+# slots are read a group at a time, so that a group's gathered pages stay
+# in VMEM between the gather and its readers; the groups are
 # decided from the shapes in ONE place (``view_slot_groups``, looped by
 # ``_read_in_slot_groups``) for this step and a described block's
 # (``decode_step_block``). The new row is written by in-place row updates
-# (``_store_rows_paged``), so the pool keeps one layout, a page one
+# (``_store_entries_paged``), so the pool keeps one layout, a page one
 # contiguous run, through the whole chunk: the compiled program holds no
 # buffer of the pool's size besides the pool (tests/test_paged_attention.py
 # pins it), and a step reads the mapped table's pages once. A view of ALL
@@ -1065,19 +975,22 @@ def _decode_step_math_sparse_reads(params: dict, x_tok: Array, pos: Array,
 
 
 @jax.named_scope("kv.view")
-def paged_view(pool: dict, block_tables: Array, total_len: int) -> dict:
-    """Dense per-slot view of the page pool, ALL layers at once — the
-    parity oracle of the per-layer read (``layer_pool_view`` +
-    ``_paged_gather_read``) and of the kernel, and the read of the
+def paged_view(pool: dict, block_tables: Array, total_len: int,
+               heads: int) -> dict:
+    """Dense per-slot view of the classic block's page pool, ALL layers
+    at once — the parity oracle of the per-layer read (``layer_pool_view``
+    + ``_paged_gather_read``) and of the kernel, and the read of the
     speculative verify. The decode step does not call it: it is a relaid
-    copy of the whole pool. Pool (depth, P, heads,
-    page_size, dh) gathered through block_tables (b, max_pages) into
-    (depth, b, heads, total_len, dh) — logical row j reads physical page
-    ``block_tables[i, j // page_size]`` at offset ``j % page_size``.
-    Unmapped table entries point at the reserved trash page 0; their rows
-    are never attended (causality masks every row >= the slot's pos,
-    and the allocator maps pages ahead of pos). Scales gather the same
-    way for the int8 pool (kv_pool.init_page_pool).
+    copy of the whole pool. Pool (depth, P, page_size, heads * dh)
+    gathered through block_tables (b, max_pages) into (depth, b, heads,
+    total_len, dh) — logical row j reads physical page ``block_tables[i,
+    j // page_size]`` at offset ``j % page_size``, and a row's ``heads``
+    runs of dh numbers become the dense cache's head axis. Unmapped table
+    entries point at the reserved trash page 0; their rows are never
+    attended (causality masks every row >= the slot's pos, and the
+    allocator maps pages ahead of pos). Scales (depth, P, page_size,
+    heads) gather the same way for the int8 pool
+    (kv_pool.init_page_pool).
 
     The gather width is TRIMMED to ``ceil(total_len / page_size)``
     table columns up front: a caller handing a wider table (block
@@ -1088,77 +1001,92 @@ def paged_view(pool: dict, block_tables: Array, total_len: int) -> dict:
     so their shape contract ((..., total_len[, dh])) cannot drift
     (tests/test_paged_attention.py pins it)."""
     block_tables = _view_tables(block_tables, total_len,
-                                pool["k"].shape[3])
+                                pool["k"].shape[2])
 
-    def rows(buf):
-        g = jnp.take(buf, block_tables, axis=1)   # (d, b, mp, heads, ps, dh)
-        g = jnp.moveaxis(g, 2, 3)                 # (d, b, heads, mp, ps, dh)
-        g = g.reshape(g.shape[:3] + (g.shape[3] * g.shape[4],) + g.shape[5:])
-        return g[:, :, :, :total_len, :]
+    def view(buf, per_head):
+        g = jnp.take(buf, block_tables, axis=1)   # (d, b, mp, ps, row)
+        g = g.reshape(g.shape[:2] + (-1, heads) + per_head)
+        return jnp.moveaxis(g[:, :, :total_len], 2, 3)
 
-    def scales(buf):
-        g = jnp.take(buf, block_tables, axis=1)   # (d, b, mp, heads, ps)
-        g = jnp.moveaxis(g, 2, 3)                 # (d, b, heads, mp, ps)
-        return g.reshape(g.shape[:3] + (-1,))[:, :, :, :total_len]
-
-    out = {"k": rows(pool["k"]), "v": rows(pool["v"])}
-    if "k_scale" in pool:
-        out["k_scale"] = scales(pool["k_scale"])
-        out["v_scale"] = scales(pool["v_scale"])
-    return out
+    dh = pool["k"].shape[-1] // heads
+    return {name: view(buf, () if name.endswith("_scale") else (dh,))
+            for name, buf in pool.items()}
 
 
-@jax.named_scope("kv.store")
+def _token_rows(x: Array) -> Array:
+    """K or V rows per head (depth, b, heads, n, dh), or their int8 scales
+    (depth, b, heads, n) -> (depth, b, n, heads * dh) / (depth, b, n,
+    heads): a token's row as the page pool holds it
+    (``kv_pool.page_layout``), every head's numbers side by side,
+    head-major."""
+    x = jnp.moveaxis(x, 2, 3)
+    return x.reshape(x.shape[:3] + (-1,))
+
+
+def _store_prompt_pages(buf: Array, rows: Array, page_ids: Array) -> Array:
+    """The admission's write of one pool buffer, every block's: the
+    prompts' rows ``rows`` (layers, G, n, width) go into ``buf`` (layers,
+    P, ps, width) as WHOLE pages: each prompt's rows cut into pages of ps
+    rows (the last one filled up with zeros: rows past a prompt are never
+    read before the decode overwrites them) and written by page id alone,
+    ``page_ids`` (G * ceil(n / ps),) in prompt order, so no index falls in
+    a page's tiled (row, width) dims and the pool keeps its layout (a row
+    scatter there made the TPU compiler relay the pool: PERF.md, PR 25).
+    The trash page takes the pages of unused group rows."""
+    ps = buf.shape[2]
+    fill = [(0, 0)] * rows.ndim
+    fill[2] = (0, -rows.shape[2] % ps)
+    rows = jnp.pad(rows, fill)
+    return buf.at[:, page_ids].set(
+        rows.reshape((rows.shape[0], -1, ps) + rows.shape[3:]))
+
+
 def _store_rows_paged(pool: dict, ks: Array, vs: Array, pos: Array,
-                      block_tables: Array, active: Array) -> dict:
-    """Paged twin of ``_store_rows_per_slot``: slot i's single new
-    K/V row (depth, b, heads, 1, dh) lands in physical page
-    ``block_tables[i, pos[i] // page_size]`` at offset ``pos[i] %
-    page_size``. INACTIVE slots are redirected to the reserved trash page
-    0: a dead slot parks at pos 0, and its block-table entry 0 may map a
-    physical page the allocator has already handed to a NEWER request —
-    writing through it would corrupt live rows (the dense layout never
-    has this hazard because a slot owns its rows forever). Same
-    quantization contract as the dense writers (one write definition per
-    layout)."""
-    ps = pool["k"].shape[3]
-    b = pos.shape[0]
-    bidx = jnp.arange(b)
-    page = jnp.where(active, block_tables[bidx, pos // ps], 0)
-    off = jnp.where(active, pos % ps, 0)
-
-    def put(buf, rows):
-        # buf (depth, P, heads, ps[, dh]), rows (depth, b, heads, 1[, dh]):
-        # one in-place row update a slot, not one scatter. The TPU
-        # compiler gives a scatter whose indices fall in the tiled (ps, dh)
-        # dims a pool with heads and ps swapped, and then relays the whole
-        # pool every step for the read, which gathers pages
-        # (``layer_pool_view``); an update slice leaves the pool as it lies
-        tail = (0,) * (buf.ndim - 4)
-        for i in range(b):
-            buf = lax.dynamic_update_slice(
-                buf, rows[:, i:i + 1], (0, page[i], 0, off[i]) + tail)
-        return buf
-
+                      block_tables: Array, active: Array,
+                      total_len: Optional[int] = None) -> dict:
+    """The classic block's new K/V rows (depth, b, heads, W, dh) into its
+    page pool: row i of slot b is position ``pos[b] + i``, a row of the
+    pool as ``_token_rows`` forms it, stored by ``_store_entries_paged``
+    (which says where it lands, and why an INACTIVE slot's goes to the
+    trash page). Same quantization contract as the dense writers: the
+    int8 pool stores int8 rows and their scales. The decode step writes
+    W = 1; the speculative verify W = k rows, of which those past
+    ``total_len`` go to the trash page too (the engine's ``_map_ahead``
+    maps the FULL speculative horizon before dispatch, so every in-range
+    row finds its page mapped)."""
+    new = {"k": ks, "v": vs}
     if "k_scale" in pool:
-        kq, ksc = _quantize_rows(ks)
-        vq, vsc = _quantize_rows(vs)
-        return {"k": put(pool["k"], kq), "v": put(pool["v"], vq),
-                "k_scale": put(pool["k_scale"], ksc),
-                "v_scale": put(pool["v_scale"], vsc)}
-    return {"k": put(pool["k"], ks), "v": put(pool["v"], vs)}
+        new["k"], new["k_scale"] = _quantize_rows(ks)
+        new["v"], new["v_scale"] = _quantize_rows(vs)
+    rows = {name: _token_rows(x) for name, x in new.items()}
+    for i in range(ks.shape[3]):
+        at, on = pos + i, active
+        if total_len is not None:
+            on = active & (at < total_len)
+            at = jnp.minimum(at, total_len - 1)
+        pool = _store_entries_paged(
+            pool, {name: r[:, :, i] for name, r in rows.items()}, at,
+            block_tables, on)
+    return pool
 
 
 def _store_entries_paged(pool: dict, entries: dict, pos: Array,
                          block_tables: Array, active: Array,
                          ring: bool = False) -> dict:
-    """``_store_rows_paged`` for pools of whole rows (a latent pool; a
-    grouped-query block's K and V rows): slot i's new row of every layer,
-    ``entries[name]`` (layers, b, width), lands in ``pool[name]`` (layers,
-    P, ps, width) in physical page ``block_tables[i, pos[i] //
-    page_size]`` at offset ``pos[i] % page_size`` (the trash page for an
-    inactive slot), by one in-place update a slot a buffer: the pool keeps
-    the layout in which a page is one contiguous run. A window pool's
+    """The one store of a decode step's new rows, every block's (a page is
+    whole rows in all of them, ``kv_pool.page_layout``): slot i's new row
+    of every layer, ``entries[name]`` (layers, b, width), lands in
+    ``pool[name]`` (layers, P, ps, width) in physical page
+    ``block_tables[i, pos[i] // page_size]`` at offset ``pos[i] %
+    page_size``, by one in-place update a slot a buffer: the pool keeps
+    the layout in which a page is one contiguous run (a scatter whose
+    indices fall in a page's tiled dims made the TPU compiler relay the
+    whole pool every step: PERF.md, PR 25). INACTIVE slots are redirected
+    to the reserved trash page 0: a dead slot parks at pos 0, and its
+    block-table entry 0 may map a physical page the allocator has already
+    handed to a NEWER request: writing through it would corrupt live rows
+    (the dense layout never has this hazard because a slot owns its rows
+    forever). A window pool's
     table is a ``ring`` of its columns: the column is ``pos[i] //
     page_size`` modulo their number, and the store goes by ``kv.window``
     in a trace."""
@@ -1245,14 +1173,6 @@ def _block_reads(cfg, pool: dict, block_tables: dict, pos: Array,
         return (jnp.arange(rows_len)[None, :] < pos[:, None]) & jnp.pad(
             key_mask, ((0, 0), (0, rows_len - total_len)))
 
-    def rows_of(name, layer, t, window=False):
-        """One slot group's pages of a buffer of whole rows, merged to
-        rows in the order they were gathered: a bitcast when the page is
-        whole sublane tiles (no head axis lies between page and row)."""
-        pages = layer_pool_view(pool[name], layer, t, window)
-        with _pool_scope(window):
-            return pages.reshape(pages.shape[0], -1, pages.shape[-1])
-
     if isinstance(blk, T.LatentMoEBlock):
         ps = pool["latent"].shape[2]
         tables = _view_tables(block_tables["full"], total_len, ps)
@@ -1266,7 +1186,8 @@ def _block_reads(cfg, pool: dict, block_tables: dict, pos: Array,
                 def read_group(sl):
                     return attn_ops.latent_attend_absorbed(
                         p, query[0][sl], query[1][sl],
-                        rows_of("latent", layer, tables[sl]), allowed[sl],
+                        _gathered_rows(pool["latent"], layer, tables[sl]),
+                        allowed[sl],
                         entry[sl], blk, cfg.scale)
                 return _read_in_slot_groups(pool, tables, read_group)
             return read
@@ -1310,19 +1231,13 @@ def _block_reads(cfg, pool: dict, block_tables: dict, pos: Array,
 
             def read_group(sl):
                 t = tables[sl]
-
-                def gather_v(wts):
-                    # among several groups the scheduler was seen to lift
-                    # one group's V gather above its K gather
-                    # (``_paged_gather_read``): there the order is stated
-                    tv = t
-                    if t.shape[0] < tables.shape[0]:
-                        _, tv = lax.optimization_barrier((wts, t))
-                    return rows_of(v_name, layer, tv, window)
-
+                several = t.shape[0] < tables.shape[0]
                 return attn_ops.gqa_attend_rows(
                     q[sl], entry[0][sl], entry[1][sl],
-                    rows_of(k_name, layer, t, window), gather_v,
+                    _gathered_rows(pool[k_name], layer, t, window),
+                    lambda wts: _gathered_rows(
+                        pool[v_name], layer, t, window,
+                        after=wts if several else None),
                     allowed[sl], cfg.scale, window, diff_lam=lam)
             return _read_in_slot_groups(view, tables, read_group)
         return read
@@ -1729,49 +1644,6 @@ def _store_rows_wide(cache: dict, ks: Array, vs: Array,
     return {"k": put_rows(cache["k"], ks), "v": put_rows(cache["v"], vs)}
 
 
-@jax.named_scope("kv.store")
-def _store_rows_paged_wide(pool: dict, ks: Array, vs: Array, pos: Array,
-                           block_tables: Array, active: Array,
-                           total_len: int) -> dict:
-    """W-wide twin of ``_store_rows_paged``: slot b's row i lands in
-    physical page ``block_tables[b, (pos[b]+i) // ps]`` at offset
-    ``(pos[b]+i) % ps``. Rows past ``total_len`` and every row of an
-    inactive slot are redirected to the reserved trash page 0 — a dead
-    slot's block-table entries may map pages the allocator already
-    handed to a newer request, the same hazard the narrow writer
-    guards. The engine's ``_map_ahead`` maps the FULL speculative
-    horizon before dispatch, so every in-range row finds its page
-    mapped."""
-    ps = pool["k"].shape[3]
-    b = pos.shape[0]
-    W = ks.shape[3]
-    bidx = jnp.arange(b)[:, None]                             # (b, 1)
-    rows = pos[:, None] + jnp.arange(W)[None, :]              # (b, W)
-    valid = active[:, None] & (rows < total_len)
-    safe = jnp.minimum(rows, total_len - 1)
-    page = jnp.where(valid, block_tables[bidx, safe // ps], 0)
-    off = jnp.where(valid, safe % ps, 0)
-
-    def put_rows(buf, r):
-        # buf (depth, P, heads, ps, dh); value (b, W, depth, heads, dh)
-        return buf.at[:, page, :, off, :].set(
-            jnp.transpose(r, (1, 3, 0, 2, 4)))
-
-    def put_scales(buf, sc):
-        # buf (depth, P, heads, ps); value (b, W, depth, heads)
-        return buf.at[:, page, :, off].set(
-            jnp.transpose(sc, (1, 3, 0, 2)))
-
-    if "k_scale" in pool:
-        kq, ksc = _quantize_rows(ks)
-        vq, vsc = _quantize_rows(vs)
-        return {"k": put_rows(pool["k"], kq),
-                "v": put_rows(pool["v"], vq),
-                "k_scale": put_scales(pool["k_scale"], ksc),
-                "v_scale": put_scales(pool["v_scale"], vsc)}
-    return {"k": put_rows(pool["k"], ks), "v": put_rows(pool["v"], vs)}
-
-
 def speculative_draft(draft_params: dict, cur_tok: Array, pos: Array,
                       read_cache: dict, *, cfg, key_mask: Array, k: int,
                       embed_fn, sample_fn, attn_impl: str = "gather",
@@ -1917,8 +1789,8 @@ def decode_loop_spec_paged(params: dict, draft_params: dict,
     block tables (``paged_view``'s all-layer dense view, which the
     narrow step has left — the wide read has no per-layer page-major
     form yet — or one in-place Pallas kernel walk per offset under
-    ``attn_impl='kernel'``), and all k fresh rows scatter back through
-    ``_store_rows_paged_wide`` (inactive/overflow rows to the trash
+    ``attn_impl='kernel'``), and all k fresh rows go back through
+    ``_store_rows_paged`` (inactive/overflow rows to the trash
     page). The host maps the FULL speculative horizon (steps*k rows)
     before dispatch, and rejection never unmaps anything — pos only
     advances, so the no-alloc-churn contract holds per round, not just
@@ -1930,7 +1802,7 @@ def decode_loop_spec_paged(params: dict, draft_params: dict,
     def one_round(carry, _):
         cur_tok, pos, act, pool = carry
         read = pool if kernel else paged_view(pool, block_tables,
-                                              total_len)
+                                              total_len, cfg.heads)
         bt = block_tables if kernel else None
         impl = "kernel" if kernel else "gather"
         drafts = speculative_draft(
@@ -1944,8 +1816,8 @@ def decode_loop_spec_paged(params: dict, draft_params: dict,
             key_mask=key_mask, total_len=total_len, embed_fn=embed_fn,
             sample_fn=sample_fn, attn_impl=impl, block_tables=bt,
             out_sync=out_sync)
-        pool = _store_rows_paged_wide(pool, ks, vs, pos, block_tables,
-                                      act, total_len)
+        pool = _store_rows_paged(pool, ks, vs, pos, block_tables, act,
+                                 total_len)
         return (cur_tok, pos_new, act, pool), emit
 
     (cur_tok, pos, active, pool), emits = lax.scan(

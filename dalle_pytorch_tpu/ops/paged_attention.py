@@ -14,7 +14,12 @@ engine's fused K-step decode scan):
 
   * grid = ``(slots, heads // head_tile, trips)`` — one (slot,
     head-tile) pair per output block, walked page by page along the
-    innermost (sequential) trip axis;
+    innermost (sequential) trip axis. A page is whole rows, ``(page_size,
+    heads * dh)`` (``kv_pool.page_layout``), and a tile's heads are read
+    in ONE product a page: the tile's queries block-diagonal against the
+    tile's columns of the rows (``ops.attention._own_columns``, the
+    gather read's form), so the scores of all its heads come out ``(head
+    tile, page_size)`` and the softmax recurrence runs on them at once;
   * the per-slot walk state — ``pos``, the block-table row and, for a
     sparse layer, the visible-page list — is SCALAR-PREFETCHED into
     SMEM, and the K/V block specs' index maps chase the block table
@@ -23,10 +28,7 @@ engine's fused K-step decode scan):
     a time, double-buffered by the Pallas pipeline — page ``p+1``'s
     copy is in flight while page ``p`` is on the MXU (the pool never
     transits VMEM whole, which is what the dense-view gather
-    effectively forces). Mosaic cannot slice a ``dim_head`` = 64 page
-    out of an HBM ref by hand (a manual ``make_async_copy`` window must
-    be 128-lane aligned), which is why the staging is the block
-    spec's;
+    effectively forces);
   * the walk is RAGGED per slot: a slot 10 tokens into a 1280-token
     sequence computes on 1 page, not 80. Trips past the slot's
     ``ceil(pos / page_size)`` re-address its last live page, so they
@@ -41,9 +43,9 @@ engine's fused K-step decode scan):
     two-estimate softmax merge, which is exactly
     ``softmax(concat([scores, self]))`` up to summation order;
   * the int8-KV pool dequantizes PER PAGE: int8 K/V pages stage as
-    int8 (half the bytes — the point of int8-KV), and the per-row f32
-    scales apply outside the contractions, mirroring the gather path's
-    register-upcast trick.
+    int8 (half the bytes — the point of int8-KV), and the f32 scale
+    pages ``(page_size, heads)`` apply outside the contractions,
+    mirroring the gather path's register-upcast trick.
 
 Masking parity with the gather path (``_decode_step_math``): dead rows
 (causal ``j >= pos``, pad, sparse-layout holes) are filled with the
@@ -73,7 +75,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from dalle_pytorch_tpu.ops import core
+from dalle_pytorch_tpu.ops import attention, core
 
 # NOTE: this module deliberately has no module-level serve import (ops
 # must not depend on serve at import time — the dependency runs the
@@ -116,21 +118,40 @@ def _walk(i, p, pos_ref, vis_refs, page_size: int):
     return p < n_pages, (vis_ref[i, pc] if vis_refs else pc)
 
 
-def _kernel(*refs, scale: float, page_size: int, head_tile: int,
-            quantized: bool, visible: bool):
+def _by_head(scales):
+    """An int8 pool's scale page (page_size, head tile) as the scores lie,
+    (head tile, page_size): the product with an identity, exact in
+    float32 at the highest precision, where Mosaic has no transpose of a
+    tile this small."""
+    ht = scales.shape[1]
+    eye = (lax.broadcasted_iota(jnp.int32, (ht, ht), 0)
+           == lax.broadcasted_iota(jnp.int32, (ht, ht), 1))
+    return lax.dot_general(eye.astype(jnp.float32), scales,
+                           (((1,), (1,)), ((), ())),
+                           precision=lax.Precision.HIGHEST,
+                           preferred_element_type=jnp.float32)
+
+
+def _kernel(*refs, scale: float, page_size: int, quantized: bool,
+            visible: bool):
     """One (slot, head-tile, trip) grid step: fold the trip's staged
-    K/V page into the slot's online softmax.
+    K/V page into the slot's online softmax, all the tile's heads at once.
 
     The scalar-prefetch operands come first (whole arrays in SMEM):
     ``pos`` (b,), ``block_tables`` (b, max_pages) and, under
     ``visible=True``, the sparsity-aware walk's ``visible`` (b, W) page
-    list and ``visible_cnt`` (b,). The K/V (and scale) refs are the ONE
-    page this trip's index map selected through the block table; the
-    output blocks are revisited across the trip axis and carry the
-    running (acc, m, l). Under the visible walk, skipped pages carry
-    exactly-zero softmax weight under the finite FILL, so the online
-    recurrence over the remaining (still ascending) pages is bit-equal
-    to the prefix walk: max(m, FILL)=m, l*exp(0)+0=l, acc*1+0=acc."""
+    list and ``visible_cnt`` (b,). ``q_ref`` holds the tile's queries
+    block-diagonal, (head tile, head tile * dh): head h's dh numbers in
+    its own columns and zeros in the others'. The K/V (and scale) refs
+    are the tile's columns of the ONE page this trip's index map selected
+    through the block table, (page_size, head tile * dh); the output
+    blocks are revisited across the trip axis and carry the running (acc,
+    m, l), acc as whole-row sums (head tile, head tile * dh) of which the
+    caller keeps each head's own columns. Under the visible walk, skipped
+    pages carry exactly-zero softmax weight under the finite FILL, so the
+    online recurrence over the remaining (still ascending) pages is
+    bit-equal to the prefix walk: max(m, FILL)=m, l*exp(0)+0=l,
+    acc*1+0=acc."""
     n_prefetch = 4 if visible else 2
     pos_ref, _bt_ref, *vis_refs = refs[:n_prefetch]
     q_ref, allowed_ref, k_ref, v_ref, *refs = refs[n_prefetch:]
@@ -140,7 +161,6 @@ def _kernel(*refs, scale: float, page_size: int, head_tile: int,
         acc_ref, m_ref, l_ref = refs
     i = pl.program_id(0)
     p = pl.program_id(2)
-    ht = head_tile
     live, lp = _walk(i, p, pos_ref, vis_refs, page_size)
 
     @pl.when(p == 0)
@@ -152,45 +172,40 @@ def _kernel(*refs, scale: float, page_size: int, head_tile: int,
 
     @pl.when(live)
     def _page():
-        q = q_ref[0]                                       # (ht, dh)
+        q = q_ref[0]                                       # (ht, ht * dh)
         # the page's mask row: allowed is laid out (max_pages, ps), so
         # a page is one sublane row (a dynamic LANE slice of ps
         # elements is not addressable)
         ok = allowed_ref[0, pl.ds(lp, 1), :] != 0          # (1, ps)
-        # per-head 2-D row tiles (static unroll over the tile): Mosaic
-        # has no sublane concatenate for 1-row pieces, so the heads of
-        # a tile never meet in one array
-        for h in range(ht):
-            row = slice(h, h + 1)
-            m = m_ref[0, row, :1]                          # (1, 1)
-            l = l_ref[0, row, :1]
-            kb, vb = k_ref[h], v_ref[h]                    # (ps, dh)
-            if quantized:
-                kb, vb = kb.astype(q.dtype), vb.astype(q.dtype)
-            # q_h (1, dh) x page (ps, dh)^T -> (1, ps) scores in f32
-            s = lax.dot_general(
-                q[row], kb, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) * scale
-            if quantized:
-                # scales OUTSIDE the contraction — no dequantized page
-                # copy materializes (ops/decode.py's int8 discipline)
-                s = s * ksc_ref[row]
-            s = jnp.where(ok, s, FILL)
-            m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
-            pexp = jnp.exp(s - m_new)                      # (1, ps)
-            alpha = jnp.exp(m - m_new)
-            l = l * alpha + pexp.sum(axis=-1, keepdims=True)
-            wj = pexp
-            if quantized:
-                wj = wj * vsc_ref[row]
-            acc_ref[0, row] = acc_ref[0, row] * alpha + lax.dot_general(
-                wj.astype(vb.dtype), vb, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)        # (1, dh)
-            # lane-broadcast stats tiles (the flash_attention layout):
-            # Mosaic wants the last dim to be a 128-lane tile, and the
-            # caller reads lane 0
-            m_ref[0, row] = jnp.broadcast_to(m_new, (1, NUM_LANES))
-            l_ref[0, row] = jnp.broadcast_to(l, (1, NUM_LANES))
+        kb, vb = k_ref[...], v_ref[...]                    # (ps, ht * dh)
+        if quantized:
+            kb, vb = kb.astype(q.dtype), vb.astype(q.dtype)
+        m = m_ref[0, :, :1]                                # (ht, 1)
+        l = l_ref[0, :, :1]
+        # block-diagonal queries x whole rows -> (ht, ps) scores in f32
+        s = lax.dot_general(
+            q, kb, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale
+        if quantized:
+            # scales OUTSIDE the contraction — no dequantized page
+            # copy materializes (ops/decode.py's int8 discipline)
+            s = s * _by_head(ksc_ref[...])
+        s = jnp.where(ok, s, FILL)
+        m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
+        pexp = jnp.exp(s - m_new)                          # (ht, ps)
+        alpha = jnp.exp(m - m_new)
+        l = l * alpha + pexp.sum(axis=-1, keepdims=True)
+        wj = pexp
+        if quantized:
+            wj = wj * _by_head(vsc_ref[...])
+        acc_ref[0] = acc_ref[0] * alpha + lax.dot_general(
+            wj.astype(vb.dtype), vb, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)            # (ht, ht * dh)
+        # lane-broadcast stats tiles (the flash_attention layout):
+        # Mosaic wants the last dim to be a 128-lane tile, and the
+        # caller reads lane 0
+        m_ref[0] = jnp.broadcast_to(m_new, m_ref.shape[1:])
+        l_ref[0] = jnp.broadcast_to(l, l_ref.shape[1:])
 
 
 def paged_decode_attention(q: Array, k_pages: Array, v_pages: Array,
@@ -206,8 +221,11 @@ def paged_decode_attention(q: Array, k_pages: Array, v_pages: Array,
     """Online-softmax attention partials over one layer's paged K/V.
 
     q: (b, heads, dh) — the decode step's single query per slot.
-    k_pages/v_pages: (P, heads, page_size, dh) page pool (int8 when
-    quantized, with k_scales/v_scales (P, heads, page_size) f32).
+    k_pages/v_pages: (P, page_size, heads * dh) page pool of whole rows
+    (int8 when quantized, with k_scales/v_scales (P, page_size, heads)
+    f32). ``head_tile`` heads a grid step (0: all of them); a tile's
+    columns of a page, ``head_tile * dh``, must be whole 128-lane tiles
+    unless the tile is every head.
     block_tables: (b, max_pages) int32; pos: (b,) int32 per-slot
     positions; allowed: (b, L) bool — the gather path's full row mask
     (causal & pad & sparse), True = attend.
@@ -233,7 +251,7 @@ def paged_decode_attention(q: Array, k_pages: Array, v_pages: Array,
     """
     from dalle_pytorch_tpu.serve import kv_pool as KV
     b, heads, dh = q.shape
-    P, _, page_size, _ = k_pages.shape
+    P, page_size, _ = k_pages.shape
     L = allowed.shape[1]
     KV.validate_page_size(page_size)
     quantized = k_scales is not None
@@ -264,8 +282,11 @@ def paged_decode_attention(q: Array, k_pages: Array, v_pages: Array,
         allowed = jnp.pad(allowed, ((0, 0), (0, L_pages - L)))
 
     kernel = functools.partial(
-        _kernel, scale=float(scale), page_size=page_size, head_tile=ht,
+        _kernel, scale=float(scale), page_size=page_size,
         quantized=quantized, visible=visible is not None)
+    # a tile's queries block-diagonal over the tile's columns of a row
+    q_wide = attention._own_columns(
+        q.reshape(b * (heads // ht), ht, 1, dh)).reshape(b, heads, ht * dh)
 
     # scalar prefetch: the per-slot walk state (positions, block tables,
     # visible-page lists) lands whole in SMEM before the body runs, so
@@ -285,20 +306,20 @@ def paged_decode_attention(q: Array, k_pages: Array, v_pages: Array,
 
     def page_map(i, t, p, pos_ref, bt_ref, *vis_refs):
         _, lp = _walk(i, p, pos_ref, vis_refs, page_size)
-        return bt_ref[i, lp], t, 0, 0
+        return bt_ref[i, lp], 0, t
 
     in_specs = [
-        pl.BlockSpec((1, ht, dh), tile_map),               # q tile
+        pl.BlockSpec((1, ht, ht * dh), tile_map),          # q tile
         pl.BlockSpec((1, max_pages, page_size),
                      lambda i, t, p, *_: (i, 0, 0)),       # allowed rows
-        pl.BlockSpec((None, ht, page_size, dh), page_map),  # K page
-        pl.BlockSpec((None, ht, page_size, dh), page_map),  # V page
+        pl.BlockSpec((None, page_size, ht * dh), page_map),  # K page
+        pl.BlockSpec((None, page_size, ht * dh), page_map),  # V page
     ]
-    inputs = [q, allowed.astype(jnp.int32).reshape(b, max_pages, page_size),
+    inputs = [q_wide,
+              allowed.astype(jnp.int32).reshape(b, max_pages, page_size),
               k_pages, v_pages]
     if quantized:
-        in_specs += [pl.BlockSpec((None, ht, page_size),
-                                  lambda *a: page_map(*a)[:3])] * 2
+        in_specs += [pl.BlockSpec((None, page_size, ht), page_map)] * 2
         inputs += [k_scales, v_scales]
 
     acc, m, l = pl.pallas_call(
@@ -307,11 +328,11 @@ def paged_decode_attention(q: Array, k_pages: Array, v_pages: Array,
             num_scalar_prefetch=len(prefetch),
             grid=(b, heads // ht, trips),
             in_specs=in_specs,
-            out_specs=[pl.BlockSpec((1, ht, dh), tile_map),
+            out_specs=[pl.BlockSpec((1, ht, ht * dh), tile_map),
                        pl.BlockSpec((1, ht, NUM_LANES), tile_map),
                        pl.BlockSpec((1, ht, NUM_LANES), tile_map)]),
         out_shape=[
-            jax.ShapeDtypeStruct((b, heads, dh), jnp.float32),
+            jax.ShapeDtypeStruct((b, heads, ht * dh), jnp.float32),
             jax.ShapeDtypeStruct((b, heads, NUM_LANES), jnp.float32),
             jax.ShapeDtypeStruct((b, heads, NUM_LANES), jnp.float32),
         ],
@@ -323,7 +344,11 @@ def paged_decode_attention(q: Array, k_pages: Array, v_pages: Array,
         interpret=interpret,
         name="paged_attn",
     )(*prefetch, *inputs)
-    return acc, m[:, :, 0], l[:, :, 0]
+    # of each whole-row sum, the head's own columns
+    acc = jnp.einsum("btgjd,gj->btgd",
+                     acc.reshape(b, heads // ht, ht, ht, dh),
+                     jnp.eye(ht, dtype=acc.dtype))
+    return acc.reshape(b, heads, dh), m[:, :, 0], l[:, :, 0]
 
 
 def modeled_kv_read_bytes_per_token(*, depth: int, heads: int,

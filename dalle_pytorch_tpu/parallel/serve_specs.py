@@ -147,17 +147,20 @@ def kv_heads_shard(heads: int, mesh_size: int) -> bool:
     return int(mesh_size) > 0 and heads % int(mesh_size) == 0
 
 
-def serve_kv_specs(cache: dict, mesh: Mesh, axis: str = SERVE_AXIS) -> dict:
+def serve_kv_specs(cache: dict, mesh: Mesh, heads: int, paged: bool,
+                   axis: str = SERVE_AXIS) -> dict:
     """NamedSharding dict for a KV store — the dense slot cache or the
-    paged page pool (``serve/kv_pool.py``), int8 scale pages included.
-    Both layouts carry heads at dim 2 (``(depth, slots|pages, heads,
-    rows[, dh])``), the one axis whose shards attend independently."""
-    out = {}
-    for k, buf in cache.items():
-        shard = kv_heads_shard(buf.shape[2], mesh.shape[axis])
-        out[k] = NamedSharding(
-            mesh, P(None, None, axis) if shard else P())
-    return out
+    paged page pool (``serve/kv_pool.py``), int8 scale pages included —
+    sharded over the heads, the one axis whose shards attend
+    independently. The dense cache carries them at dim 2 (``(depth, slots,
+    heads, rows[, dh])``); a page of the pool is whole rows (``(depth,
+    pages, page_size, heads * dh)``, scales ``... heads``), every head's
+    numbers side by side in the row's last axis, head-major, so a shard
+    of that axis is whole heads."""
+    spec = P()
+    if kv_heads_shard(heads, mesh.shape[axis]):
+        spec = P(None, None, None, axis) if paged else P(None, None, axis)
+    return {k: NamedSharding(mesh, spec) for k in cache}
 
 
 def kv_is_sharded(specs: dict) -> bool:

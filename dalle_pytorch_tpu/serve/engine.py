@@ -184,6 +184,12 @@ class MigrationError(RuntimeError):
         self.reason = reason
 
 
+# what a MIGRATE payload's page snapshots hold: 2 = a page of whole rows
+# (``kv_pool.page_layout``: (depth, page_size, heads * dim_head)); 1 was
+# the page per head, (depth, heads, page_size, dim_head)
+MIGRATE_FORMAT = 2
+
+
 def _pack_array(a) -> dict:
     """One host array as a JSON-safe dict (dtype/shape/base64 bytes) —
     the page-snapshot wire form MIGRATE frames carry. Exact: raw bytes,
@@ -685,11 +691,6 @@ class Engine:
         self.kv_view_groups = 1         # slot groups a layer's paged gather
         #                                 read was traced with (ops.decode
         #                                 view_slot_groups; 1 = no such read)
-        self.attn_read_heads_merged = 0     # 1: that read, the classic
-        #                                 block's, was traced with all heads'
-        #                                 queries against whole rows (ops.
-        #                                 decode read_heads_merged); 0: per
-        #                                 head, or no such read
         self._t_start = None
         self._last_log = 0
 
@@ -935,11 +936,6 @@ class Engine:
             # (the full layers' groups, of a window-and-full block)
             self.kv_view_groups = decode_ops.pool_view_groups(
                 cache, self.num_slots, self.slot_max_pages)
-            if self.block is None:
-                self.attn_read_heads_merged = int(
-                    decode_ops.read_heads_merged(
-                        cache["k"].shape[2:], cache["k"].dtype,
-                        mesh=self._decode_out_sync() is not None))
         return decode_ops.decode_loop_paged(
             params["transformer"], cur_tok, pos, active, cache,
             block_tables, cfg=self.cfg.transformer,
@@ -989,26 +985,14 @@ class Engine:
                 total_len=self.total_len, prompt_mask=None,
                 quantize_cache=self.quantize_cache,
                 out_sync=self._decode_out_sync(), lens=lens)
-            if paged and self.block is not None:
-                # the group's rows go in as WHOLE pages: (depth, G, bucket,
-                # width) cut into pages of page_size rows (the last one
-                # filled up with zeros) and written by page id alone, so
-                # no index falls in a page's tiled (row, width) dims and
-                # the pool keeps its layout. Page w of group-row g is
-                # the physical page of its row w * page_size (trash for
-                # the unused dummy rows and past a prompt's grants)
-                ps = self.page_size
-                n_pages = -(-bucket // ps)
+            if paged:
+                # the group's rows go in as WHOLE pages, every block's
+                # (``ops.decode._store_prompt_pages``). Page w of
+                # group-row g is the physical page of its row w *
+                # page_size (trash for the unused dummy rows and past a
+                # prompt's grants)
                 with jax.named_scope("prefill.scatter"):
-                    ids = page_rows[:, ::ps].reshape(-1)
-
-                    def whole_pages(rows):      # (layers, G, bucket, ...)
-                        fill = [(0, 0)] * rows.ndim
-                        fill[2] = (0, n_pages * ps - bucket)
-                        rows = jnp.pad(rows, fill)
-                        return rows.reshape((rows.shape[0], -1, ps)
-                                            + rows.shape[3:])
-
+                    ids = page_rows[:, ::self.page_size].reshape(-1)
                     # a page id of each pool in one int32: full id x
                     # the window pool's pages + window id (the trash
                     # page for a page the ring no longer holds)
@@ -1016,8 +1000,10 @@ class Engine:
                         else self.window.alloc.num_pages
                     at = {"full": ids // n_win, "window": ids % n_win}
                     new = {}
-                    for pool, names in self.block.pools(
-                            self.cfg.transformer.depth).items():
+                    # the classic block holds one pool, every buffer of it
+                    pools = {"full": tuple(cache)} if self.block is None \
+                        else self.block.pools(self.cfg.transformer.depth)
+                    for pool, names in pools.items():
                         for name in names:
                             rows = group[name]
                             if pool == "state":
@@ -1027,30 +1013,16 @@ class Engine:
                                 new[name] = cache[name].at[:, slots].set(
                                     rows, mode="drop")
                                 continue
+                            if self.block is None:
+                                # the classic prefill returns a dense
+                                # cache, rows per head
+                                rows = decode_ops._token_rows(
+                                    rows[:, :, :, :bucket])
                             # a row: the kv heads side by side
-                            new[name] = cache[name].at[:, at[pool]].set(
-                                whole_pages(rows.reshape(
-                                    rows.shape[:3] + (-1,))))
+                            new[name] = decode_ops._store_prompt_pages(
+                                cache[name], rows.reshape(
+                                    rows.shape[:3] + (-1,)), at[pool])
                     cache = new
-            elif paged:
-                # scatter the group's [0, bucket) rows into their pages:
-                # row j of group-row g lands in physical page
-                # page_rows[g, j] (trash 0 for the unused dummy rows) at
-                # offset j % page_size. Advanced indices at dims 1 and 3
-                # are non-adjacent, so updates are (G, bucket, depth,
-                # heads[, dh])
-                off = (jnp.arange(bucket) % self.page_size)[None, :]
-                rows = {k: group[k][:, :, :, :bucket] for k in group}
-
-                def put(buf, val):
-                    if val.ndim == 5:
-                        return buf.at[:, page_rows, :, off, :].set(
-                            jnp.transpose(val, (1, 3, 0, 2, 4)))
-                    return buf.at[:, page_rows, :, off].set(
-                        jnp.transpose(val, (1, 3, 0, 2)))
-
-                with jax.named_scope("prefill.scatter"):
-                    cache = {k: put(cache[k], rows[k]) for k in cache}
             else:
                 with jax.named_scope("prefill.scatter"):
                     cache = {k: cache[k].at[:, slots].set(group[k],
@@ -2355,7 +2327,7 @@ class Engine:
 
     def _refuse_block_migration(self) -> None:
         """A described block's slots are not exported or imported (the
-        payload's format is the per-head K/V page's): the typed
+        payload's format is the classic block's one pool): the typed
         ``MigrationError`` whose callers fall back to replay, naming the
         block and the option as every other refusal of it does."""
         if self.block is not None:
@@ -2433,7 +2405,7 @@ class Engine:
                         "pages": self._export_pages(self._slot_pages[j])}
 
             payload = {
-                "format": 1,
+                "format": MIGRATE_FORMAT,
                 "request_id": int(slot.handle.request.request_id),
                 "handle": slot.handle.to_wire(now),
                 "emitted": [int(t) for t in slot.emitted],
@@ -2500,6 +2472,11 @@ class Engine:
                 raise MigrationError(
                     "layout", "int8-KV snapshot into an fp32 pool (or "
                     "vice versa)")
+            if int(payload.get("format", 0)) != MIGRATE_FORMAT:
+                raise MigrationError(
+                    "layout", f"snapshot pages of format "
+                    f"{payload.get('format')}, target pool holds format "
+                    f"{MIGRATE_FORMAT} (whole rows)")
             now = self.clock()
             if handle is None:
                 handle = S.RequestHandle.from_wire(payload["handle"], now)
@@ -3243,7 +3220,6 @@ class Engine:
                 self.harvests / max(self.tokens_decoded, 1), 6),
             "sample_sorted_chunks": self.sample_sorted_chunks,
             "kv_view_groups": self.kv_view_groups,
-            "attn_read_heads_merged": self.attn_read_heads_merged,
             # the obs surface: flight-recorder occupancy (retention is
             # the ring capacity, /debug/events serves the contents) and
             # the serve-side profiler state

@@ -10,18 +10,20 @@ PAGES shared by every slot:
 
   * the device side is a page pool whose entry comes from the model's
     block (``page_layout``, the one place that says what a page of a
-    layer holds): ``(depth, num_pages, heads, page_size, dim_head)`` per
-    K and V for the classic block (the int8 variant carries per-row scale
-    pages), ``(depth, num_pages, page_size, row_width)`` for a
-    latent-attention block (one row a token, no head axis, no V); built
-    by ``init_page_pool``, plus per-slot block tables
-    ``(num_slots, max_pages)`` int32 mapping logical page j → physical
-    page id — ``ops.decode.layer_pool_view`` / ``_store_rows_paged`` are
-    the decode step's read and write through them (``paged_view`` the
-    all-layer dense oracle), and ``ops.paged_attention`` is the
-    Pallas kernel that consumes the tables in place
-    (``paged_attn='kernel'``, which also imposes the page-size tile
-    constraint ``validate_page_size`` gates);
+    layer holds). In every block a page is ``(page_size, row)``: whole
+    rows, a token's numbers side by side, no axis between page and row.
+    The classic block's K and V rows hold every head's ``dim_head``
+    numbers, head-major: ``(depth, num_pages, page_size, heads *
+    dim_head)`` each (the int8 variant carries scale pages
+    ``(page_size, heads)``); a latent-attention block has one row a
+    token and no V. Built by ``init_page_pool``, plus per-slot block
+    tables ``(num_slots, max_pages)`` int32 mapping logical page j →
+    physical page id — ``ops.decode.layer_pool_view`` /
+    ``_store_entries_paged`` are the decode step's read and write through
+    them (``paged_view`` the all-layer dense oracle), and
+    ``ops.paged_attention`` is the Pallas kernel that consumes the tables
+    in place (``paged_attn='kernel'``, which also imposes the page-size
+    tile constraint ``validate_page_size`` gates);
   * the host side is THIS module's ``PageAllocator``: a free-list over
     physical pages. Physical page 0 is reserved as the TRASH page —
     dead slots park their writes there (see ops/decode.py), so it is
@@ -160,10 +162,16 @@ def page_layout(cfg, page_size: int, quantized: bool = False) -> dict:
     in every buffer a page is one contiguous run whose first page axis is
     ``num_pages`` (``(depth, num_pages) + page shape``).
 
-      * the classic block: K and V per head, ``(heads, page_size,
-        dim_head)`` each (int8 rows + per-row f32 scale pages when
-        ``quantized``: the layout/accuracy contract of
-        ``ops.decode.init_cache``, so int8-KV composes with paging);
+    A page is ``(page_size, row)`` in every block: whole rows, no axis
+    between page and row, so a gathered page is read as it lies and a new
+    row is one contiguous run of it.
+
+      * the classic block: one K row and one V row a token, every head's
+        ``dim_head`` numbers side by side in it, head-major:
+        ``(page_size, heads * dim_head)`` each, the grouped-query page at
+        ``kv_heads == heads`` (``quantized``: int8 rows and a float32
+        scale a head a row, ``(page_size, heads)``: the accuracy contract
+        of ``ops.decode.init_cache``, so int8-KV composes with paging);
       * a latent-attention block (``cfg.block``): ONE row a token, the
         latent and the roped key side by side and filled up to whole
         lanes, ``(page_size, row_width)``: no head axis and no V;
@@ -195,10 +203,11 @@ def page_layout(cfg, page_size: int, quantized: bool = False) -> dict:
                 out.update({name: ((page_size, blk.page_row_width), None)
                             for name in names})
         return out
-    page = (cfg.heads, page_size, cfg.dim_head)
+    page = (page_size, cfg.heads * cfg.dim_head)
     if quantized:
+        scales = (page_size, cfg.heads)
         return {"k": (page, 1), "v": (page, 1),
-                "k_scale": (page[:-1], 4), "v_scale": (page[:-1], 4)}
+                "k_scale": (scales, 4), "v_scale": (scales, 4)}
     return {"k": (page, None), "v": (page, None)}
 
 
@@ -276,7 +285,7 @@ def visible_table_view(block_tables, visible):
 
 def snapshot_page(pool: dict, page) -> dict:
     """Device-side copy of ONE physical page across every layer (and the
-    int8 pool's scale pages): ``{k: (depth, heads, page_size[, dh])}``.
+    int8 pool's scale pages): ``{k: (layers, page_size, row)}``.
     The prefix cache's copy-on-write source — taken at insert time,
     BEFORE the inserting request's decode can write past its prompt
     span into the same physical page. Traced (jax.numpy); the engine

@@ -129,7 +129,9 @@ class MeshEngine(Engine):
         import jax
 
         from dalle_pytorch_tpu.parallel import serve_specs as SS
-        self._kv_shardings = SS.serve_kv_specs(cache, self.mesh)
+        self._kv_shardings = SS.serve_kv_specs(
+            cache, self.mesh, self.cfg.transformer.heads,
+            paged=self.kv == "paged")
         self.kv_sharded = SS.kv_is_sharded(self._kv_shardings)
         return {k: jax.device_put(v, self._kv_shardings[k])
                 for k, v in cache.items()}

@@ -150,6 +150,15 @@ class TestMeshByteIdentity:
                                      devices=mesh_devices(), **kw)
         assert engine.decode_traces == 1
         assert engine.kv_sharded
+        # a page is whole rows (ps, heads * dh): a shard holds its heads'
+        # columns of every row, and the read there is per head
+        from jax.sharding import PartitionSpec as P
+        tcfg = CFG.transformer
+        for buf in engine.cache.values():
+            assert buf.shape[2:] == (8, tcfg.heads * tcfg.dim_head)
+            assert buf.sharding.spec == P(None, None, None, "mp")
+            assert buf.sharding.shard_shape(buf.shape)[-1] == \
+                tcfg.heads * tcfg.dim_head // len(mesh_devices())
         assert engine.stats()["kv_view_groups"] == groups
         for a, b in zip(ref, toks):
             np.testing.assert_array_equal(a, b)
@@ -327,13 +336,23 @@ class TestMeshSurfaceAndSpecs:
         assert specs["text_emb"]["w"].spec == P("mp")
         assert specs["image_emb"]["w"].spec == P("mp")
         assert specs["text_pos_emb"]["w"].spec == P()   # replicated
+        # the dense cache carries its heads at dim 2
         kv_specs = SS.serve_kv_specs(
-            {"k": jnp.zeros((2, 3, 2, 8, 8))}, mesh)
+            {"k": jnp.zeros((2, 3, 2, 8, 8))}, mesh, heads=2, paged=False)
         assert kv_specs["k"].spec == P(None, None, "mp")
-        # heads=3 does not divide 2: falls back replicated, not wrong
+        # a page of the pool is whole rows (depth, pages, page_size,
+        # heads * dh), head-major: a shard of the row is whole heads;
+        # the int8 pool's scale pages (..., heads) the same
         kv_specs = SS.serve_kv_specs(
-            {"k": jnp.zeros((2, 3, 3, 8, 8))}, mesh)
-        assert kv_specs["k"].spec == P()
+            {"k": jnp.zeros((2, 3, 8, 2 * 8), jnp.int8),
+             "k_scale": jnp.zeros((2, 3, 8, 2))}, mesh, heads=2, paged=True)
+        assert kv_specs["k"].spec == P(None, None, None, "mp")
+        assert kv_specs["k_scale"].spec == P(None, None, None, "mp")
+        # heads=3 does not divide 2: falls back replicated, not wrong
+        for paged, shape in ((False, (2, 3, 3, 8, 8)), (True, (2, 3, 8, 24))):
+            kv_specs = SS.serve_kv_specs({"k": jnp.zeros(shape)}, mesh,
+                                         heads=3, paged=paged)
+            assert kv_specs["k"].spec == P()
 
 
 class TestMeshServer:

@@ -19,10 +19,18 @@ pool per layer and page-major (``layer_pool_view`` +
 and to a temporaries budget that an all-layer view cannot meet; since
 ISSUE 31 the read runs a slot group at a time, and the same class holds
 the grouped read to the one-group read, the rule to the cells' shapes.
+Since ISSUE 36 a page of the classic pool is whole rows ``(page_size,
+heads * dim_head)``: the read is the grouped-query one at ``kv_heads ==
+heads`` (held to the oracle and to its per-head form under the mesh seam
+at several head shapes), the store ``_store_entries_paged``
+(``TestRowPageWrites``: the round trip, and the admission's whole-page
+write).
 
 All CPU (the kernel runs under the Pallas interpreter — the same code
 path CI's serve-perf kernel leg smokes), tiny model, inside tier-1.
 """
+
+import dataclasses
 
 import jax
 import jax.numpy as jnp
@@ -32,6 +40,7 @@ import pytest
 from dalle_pytorch_tpu.analysis import guards
 from dalle_pytorch_tpu.models import dalle as D
 from dalle_pytorch_tpu.models import vae as V
+from dalle_pytorch_tpu.ops import attention as attn_ops
 from dalle_pytorch_tpu.ops import decode as decode_ops
 from dalle_pytorch_tpu.ops import paged_attention as PA
 from dalle_pytorch_tpu.serve import (Request, RequestQueue,
@@ -92,25 +101,27 @@ REQS = [
 
 
 def _random_pool(key, page_size, num_pages, quantized, *, dim_head=None,
-                 dtype=jnp.float32):
+                 dtype=jnp.float32, heads=None):
     """A pool with fully-random page content — including the trash page
     and unallocated pages, so an out-of-bounds read cannot hide behind
-    zeros."""
+    zeros. A page is whole rows (``kv_pool.page_layout``)."""
     tcfg = CFG.transformer
-    shape = (tcfg.depth, num_pages, tcfg.heads, page_size,
-             dim_head or tcfg.dim_head)
+    heads = heads or tcfg.heads
+    shape = (tcfg.depth, num_pages, page_size,
+             heads * (dim_head or tcfg.dim_head))
+    assert shape[2:] == KV.page_layout(dataclasses.replace(
+        tcfg, heads=heads, dim_head=shape[-1] // heads), page_size)["k"][0]
     if quantized:
+        scales = shape[:-1] + (heads,)
         return {
             "k": jax.random.randint(jax.random.fold_in(key, 0), shape,
                                     -127, 128, jnp.int8),
             "v": jax.random.randint(jax.random.fold_in(key, 1), shape,
                                     -127, 128, jnp.int8),
             "k_scale": jax.random.uniform(jax.random.fold_in(key, 2),
-                                          shape[:-1], minval=0.01,
-                                          maxval=0.1),
+                                          scales, minval=0.01, maxval=0.1),
             "v_scale": jax.random.uniform(jax.random.fold_in(key, 3),
-                                          shape[:-1], minval=0.01,
-                                          maxval=0.1),
+                                          scales, minval=0.01, maxval=0.1),
         }
     return {"k": jax.random.normal(jax.random.fold_in(key, 0), shape,
                                    dtype),
@@ -149,7 +160,7 @@ class TestKernelVsGatherOracle:
         key_mask = jnp.ones((3, L), bool).at[1, 1].set(False)
         x_tok = jax.random.normal(jax.random.PRNGKey(9), (3, CFG.dim))
 
-        view = decode_ops.paged_view(pool, bt, L)
+        view = decode_ops.paged_view(pool, bt, L, tcfg.heads)
         h_g, ks_g, vs_g = decode_ops._decode_step_math(
             params["transformer"], x_tok, pos, view, cfg=tcfg,
             key_mask=key_mask)
@@ -340,9 +351,9 @@ class TestPagedViewTrim:
 
     def test_shapes_and_values_independent_of_tail_columns(self):
         L, pool, bt, bt_wide = self._pool_and_tables()
-        view = decode_ops.paged_view(pool, bt, L)
-        wide = decode_ops.paged_view(pool, bt_wide, L)
         tcfg = CFG.transformer
+        view = decode_ops.paged_view(pool, bt, L, tcfg.heads)
+        wide = decode_ops.paged_view(pool, bt_wide, L, tcfg.heads)
         for k in ("k", "v"):
             assert wide[k].shape == (tcfg.depth, 2, tcfg.heads, L,
                                      tcfg.dim_head)
@@ -364,7 +375,7 @@ class TestPagedViewTrim:
         need = KV.pages_for(L, 8)
         wide_shape = tuple(bt_wide.shape)
         jaxpr = jax.make_jaxpr(
-            lambda bt: decode_ops.paged_view(pool, bt, L))(bt_wide)
+            lambda bt: decode_ops.paged_view(pool, bt, L, CFG.heads))(bt_wide)
         consumers = [eqn for eqn in jaxpr.jaxpr.eqns
                      if any(getattr(v, "aval", None) is not None
                             and v.aval.shape == wide_shape
@@ -386,12 +397,12 @@ class TestPerLayerRead:
     PS = 8
     HEADS, DEPTH = CFG.transformer.heads, CFG.transformer.depth
 
-    def _case(self, kind, dim_head, total_len, tables):
+    def _case(self, kind, heads, dim_head, total_len, tables):
         key = jax.random.PRNGKey(dim_head + total_len)
         need = KV.pages_for(total_len, self.PS)
         dtype = jnp.bfloat16 if kind == "bf16" else jnp.float32
         pool = _random_pool(key, self.PS, 3 * need + 1, kind == "int8",
-                            dim_head=dim_head, dtype=dtype)
+                            dim_head=dim_head, dtype=dtype, heads=heads)
         bt = np.zeros((3, need), np.int32)
         bt[0] = np.arange(1, need + 1)
         bt[1] = np.arange(need + 1, 2 * need + 1)
@@ -412,24 +423,33 @@ class TestPerLayerRead:
             bt[2] = 0
             pos[2] = 0
         qkv = [jax.random.normal(jax.random.fold_in(key, 10 + i),
-                                 (3, self.HEADS, 1, dim_head), dtype)
+                                 (3, heads, 1, dim_head), dtype)
                for i in range(3)]
         allowed = (jnp.arange(total_len)[None, :]
                    < jnp.asarray(pos)[:, None])
         allowed = allowed.at[0, 1].set(False)        # a padded-off row
         return pool, jnp.asarray(bt), qkv, allowed
 
+    @pytest.mark.parametrize("mesh", [False, True],
+                             ids=["whole_rows", "mesh_seam"])
     @pytest.mark.parametrize("tables", ["wide", "shared", "trash"])
     @pytest.mark.parametrize("total_len", [24, 20],
                              ids=["whole_pages", "partial_last_page"])
-    @pytest.mark.parametrize("dim_head", [64, 128])
+    @pytest.mark.parametrize("heads,dim_head", [(4, 64), (2, 128), (6, 64)],
+                             ids=["4x64", "2x128", "6x64"])
     @pytest.mark.parametrize("kind", ["f32", "bf16", "int8"])
-    def test_per_layer_read_matches_view_oracle(self, kind, dim_head,
-                                                total_len, tables):
-        pool, bt, (q, k, v), allowed = self._case(kind, dim_head,
+    def test_per_layer_read_matches_view_oracle(self, kind, heads, dim_head,
+                                                total_len, tables, mesh):
+        """ISSUE 36: a page is whole rows ``(ps, heads * dh)`` and the
+        read contracts them whole (the grouped-query read at ``kv_heads ==
+        heads``), or per head under the mesh seam: both equal the dense
+        oracle at 64- and 128-wide heads, an even and an odd head count,
+        a float32 page of whole tiles (8 rows) and bf16 / int8 pages short
+        of one."""
+        pool, bt, (q, k, v), allowed = self._case(kind, heads, dim_head,
                                                   total_len, tables)
         scale = dim_head ** -0.5
-        view = decode_ops.paged_view(pool, bt, total_len)
+        view = decode_ops.paged_view(pool, bt, total_len, heads)
         need = KV.pages_for(total_len, self.PS)
         tol = dict(rtol=2e-2, atol=2e-2) if kind == "bf16" else \
             dict(rtol=2e-5, atol=2e-5)
@@ -441,10 +461,10 @@ class TestPerLayerRead:
                 vsc=view["v_scale"][layer] if kind == "int8" else None)
             gk = decode_ops.layer_pool_view(
                 pool["k"], jnp.asarray(layer), bt[:, :need])
-            assert gk.shape == (3, need, self.HEADS, self.PS, dim_head)
+            assert gk.shape == (3, need, self.PS, heads * dim_head)
             got = decode_ops._paged_gather_read(
                 pool, jnp.asarray(layer), bt[:, :need], q, k, v, allowed,
-                scale=scale)
+                scale=scale, mesh=mesh)
             assert got.shape == want.shape and got.dtype == want.dtype
             np.testing.assert_allclose(
                 np.asarray(got, np.float32), np.asarray(want, np.float32),
@@ -513,7 +533,7 @@ class TestPerLayerRead:
         got = attend()
         np.testing.assert_array_equal(np.asarray(got, np.float32),
                                       np.asarray(whole, np.float32))
-        view = decode_ops.paged_view(pool, bt, total_len)
+        view = decode_ops.paged_view(pool, bt, total_len, self.HEADS)
         want = decode_ops._gather_read(
             q, k, v, view["k"][1], view["v"][1], allowed, scale=scale,
             ksc=view["k_scale"][1] if kind == "int8" else None,
@@ -525,22 +545,25 @@ class TestPerLayerRead:
                                    np.asarray(want, np.float32), **tol)
 
     @pytest.mark.parametrize("slots,columns,page,dtype,want", [
-        (16, 72, (16, 16, 128), jnp.bfloat16, 1),    # rudalle-xl.serve-full
-        (12, 80, (62, 16, 64), jnp.bfloat16, 3),     # dalle-12b.serve-full
+        (16, 72, (16, 16 * 128), jnp.bfloat16, 1),   # rudalle-xl.serve-full
+        (12, 80, (16, 62 * 64), jnp.bfloat16, 2),    # dalle-12b.serve-full
         (32, 272, (16, 640), jnp.bfloat16, 2),       # the latent pool
-        (12, 80, (62, 16, 64), jnp.int8, 3),         # its int8 pool
-        (7, 80, (62, 16, 64), jnp.bfloat16, 7),      # no divisor fits
+        (12, 80, (16, 62 * 64), jnp.int8, 2),        # 12b's int8 pool
+        (12, 80, (16, 62), jnp.float32, 1),          # and its scale pages
+        (7, 160, (16, 62 * 64), jnp.bfloat16, 7),    # no divisor fits
     ], ids=["rudalle-xl", "dalle-12b", "latent", "dalle-12b-int8",
-            "prime_slots"])
+            "dalle-12b-int8-scales", "prime_slots"])
     def test_group_rule_on_the_cells_shapes(self, slots, columns, page,
                                             dtype, want):
-        """The rule sees slots, table columns, the page's shape and the
-        pool's dtype, and counts bytes as laid out (a 64-wide minor
-        dimension fills 128 lanes, 16 int8 rows a 32-row tile): ruDALL-E's
-        75.5 MB a buffer is one group, 12b's 244 MB three of 81 MB (its
-        int8 pool's the same), the latent pool's 178 MB two; a slot count
-        with no divisor that fits falls to one slot a group and does not
-        raise."""
+        """The rule sees slots, table columns, the page's shape (rows,
+        width) and the pool's dtype, and counts bytes as laid out (the
+        width in whole 128-lane tiles, 16 int8 rows a 32-row tile):
+        ruDALL-E's 75.5 MB a buffer is one group; 12b's row of 62 x 64 =
+        3968 numbers is 31 whole tiles, so its 122 MB are two groups of
+        61 MB where the page per head, half padding, made three of 81 MB
+        (ISSUE 36; its int8 pool's the same two); the latent pool's 178
+        MB two; a slot count with no divisor that fits falls to one slot a
+        group and does not raise."""
         groups = decode_ops.view_slot_groups(slots, columns, page, dtype)
         assert groups == want
         assert slots % groups == 0
@@ -551,30 +574,20 @@ class TestPerLayerRead:
             fewer = max(g for g in range(1, groups) if slots % g == 0)
             assert slots // fewer * slot_bytes > decode_ops._VIEW_VMEM_BYTES
 
-    # ---- ISSUE 34: the all-heads (merged) form of the read ----
+    # ---- ISSUEs 34, 36: whole rows against all heads' queries ----
 
     @staticmethod
-    def _merged_case(kind, total_len, slots=4, heads=4, dim_head=128):
-        """A pool of the shapes the rule gives the merged form (whole
-        lanes, a page a whole tile of rows: 16 bf16 rows, 32 int8 rows),
-        random everywhere (trash and unmapped pages too), ``slots`` slots
-        at ragged positions: one sharing a page, one with trash entries,
-        one on its last row."""
+    def _tile_case(kind, total_len, slots=4, heads=4, dim_head=128):
+        """A pool whose page is a whole tile of rows (16 bf16 rows, 32
+        int8 rows), random everywhere (trash and unmapped pages too),
+        ``slots`` slots at ragged positions: one sharing a page, one with
+        trash entries, one on its last row."""
         ps = 32 if kind == "int8" else 16
         need = KV.pages_for(total_len, ps)
         dtype = jnp.float32 if kind == "int8" else jnp.bfloat16
         key = jax.random.PRNGKey(34 + total_len)
-        shape = (2, slots * need + 1, heads, ps, dim_head)
-        if kind == "int8":
-            pool = {n: jax.random.randint(jax.random.fold_in(key, i), shape,
-                                          -127, 128, jnp.int8)
-                    for i, n in enumerate("kv")}
-            pool.update({n + "_scale": jax.random.uniform(
-                jax.random.fold_in(key, 2 + i), shape[:-1], minval=0.01,
-                maxval=0.1) for i, n in enumerate("kv")})
-        else:
-            pool = {n: jax.random.normal(jax.random.fold_in(key, i), shape,
-                                         dtype) for i, n in enumerate("kv")}
+        pool = _random_pool(key, ps, slots * need + 1, kind == "int8",
+                            dim_head=dim_head, dtype=dtype, heads=heads)
         bt = np.arange(1, slots * need + 1, dtype=np.int32).reshape(
             slots, need)
         pos = np.array([total_len - 1, total_len // 2, 5, total_len - 3])
@@ -587,26 +600,38 @@ class TestPerLayerRead:
                    < jnp.asarray(pos)[:, None]).at[0, 1].set(False)
         return pool, jnp.asarray(bt), (q, k, v), allowed, ps
 
+    @staticmethod
+    def _spy_read_form(monkeypatch):
+        """-> the list that collects (slots, per_head) of every call of
+        the one read (``ops.attention.gqa_attend_rows``)."""
+        calls = []
+        real = attn_ops.gqa_attend_rows
+
+        def spy(q, *a, **kw):
+            calls.append((q.shape[0], kw["per_head"]))
+            return real(q, *a, **kw)
+        monkeypatch.setattr(attn_ops, "gqa_attend_rows", spy)
+        return calls
+
     @pytest.mark.parametrize("groups", [1, 2], ids=["one_group",
                                                     "two_groups"])
     @pytest.mark.parametrize("total_len", [96, 83],
                              ids=["whole_pages", "partial_last_page"])
     @pytest.mark.parametrize("table", ["full", "visible_slice"])
     @pytest.mark.parametrize("kind", ["bf16", "int8"])
-    def test_merged_read_matches_view_oracle_and_per_head(
+    def test_whole_row_read_matches_view_oracle_and_per_head(
             self, monkeypatch, kind, table, total_len, groups):
-        """All heads' queries against a slot's pages as whole rows
-        (``read_heads_merged`` gives that form for these shapes) equals
+        """All heads' queries against a slot's pages as whole rows equals
         the ``paged_view`` + ``_gather_read`` oracle under the same masks
-        and the per-head form: over the bf16 pool and the int8 pool with
-        its scale pages, the full table and a sparse layer's visible
-        slice of it, whole pages and a partial last page, one slot group
-        and two (``v_after_k``)."""
-        pool, bt, (q, k, v), allowed, ps = self._merged_case(kind, total_len)
+        and the per-head form that a mesh gets: over the bf16 pool and the
+        int8 pool with its scale pages, the full table and a sparse
+        layer's visible slice of it, whole pages and a partial last page,
+        one slot group and two (``v_after_k``)."""
+        pool, bt, (q, k, v), allowed, ps = self._tile_case(kind, total_len)
         slots, need = bt.shape
         scale = 128 ** -0.5
         layer = jnp.asarray(1)
-        view = decode_ops.paged_view(pool, bt, total_len)
+        view = decode_ops.paged_view(pool, bt, total_len, 4)
         if table == "visible_slice":
             # a sparse layer reads a narrower table: each slot's visible
             # logical pages, and the row mask remapped onto its columns
@@ -631,26 +656,19 @@ class TestPerLayerRead:
             ksc=view["k_scale"][1] if kind == "int8" else None,
             vsc=view["v_scale"][1] if kind == "int8" else None)
 
-        def attend():
+        def attend(mesh):
             return decode_ops._paged_gather_attend(
-                pool, layer, read_bt, q, k, v, read_allowed, scale=scale)
+                pool, layer, read_bt, q, k, v, read_allowed, scale=scale,
+                mesh=mesh)
 
         if groups > 1:
             self._force_groups(monkeypatch, pool, slots, read_bt.shape[1],
                                groups)
-        assert decode_ops.read_heads_merged(pool["k"].shape[2:],
-                                            pool["k"].dtype)
-        calls = []
-        real = decode_ops._scores_heads_merged
-        monkeypatch.setattr(
-            decode_ops, "_scores_heads_merged",
-            lambda *a: calls.append(a[1].shape[0]) or real(*a))
-        got = attend()
-        assert calls == [slots // groups] * groups   # the merged form ran
-        monkeypatch.setattr(decode_ops, "read_heads_merged",
-                            lambda *a, **kw: False)
-        per_head = attend()
-        assert len(calls) == groups                  # and here it did not
+        calls = self._spy_read_form(monkeypatch)
+        got = attend(False)
+        assert calls == [(slots // groups, False)] * groups  # whole rows
+        per_head = attend(True)
+        assert calls[groups:] == [(slots // groups, True)] * groups
         assert got.shape == want.shape and got.dtype == want.dtype
         tol = dict(rtol=2e-2, atol=2e-2) if kind == "bf16" else \
             dict(rtol=2e-5, atol=2e-5)
@@ -658,35 +676,12 @@ class TestPerLayerRead:
             np.testing.assert_allclose(np.asarray(got, np.float32),
                                        np.asarray(other, np.float32), **tol)
 
-    @pytest.mark.parametrize("page,dtype,mesh,want", [
-        ((16, 16, 128), jnp.bfloat16, False, True),
-        ((62, 16, 64), jnp.bfloat16, False, True),
-        ((16, 16, 128), jnp.bfloat16, True, False),
-        ((62, 16, 64), jnp.bfloat16, True, False),
-        ((16, 16, 128), jnp.int8, False, False),
-        ((16, 32, 128), jnp.int8, False, True),
-        ((2, 8, 128), jnp.float32, False, True),
-        ((2, 8, 128), jnp.bfloat16, False, False),
-        ((2, 24, 8), jnp.bfloat16, False, False),
-    ], ids=["rudalle-xl", "dalle-12b", "rudalle-xl-mesh", "dalle-12b-mesh",
-            "int8-half-a-tile-of-rows", "int8-whole-tile", "f32-8-rows",
-            "bf16-8-rows", "bf16-a-tile-and-a-half"])
-    def test_read_form_rule_on_the_cells_shapes(self, page, dtype, mesh,
-                                                want):
-        """``read_heads_merged`` sees the page's shape, the pool's dtype
-        and the mesh seam: the published shapes of both ``dalle``
-        configurations are merged (ruDALL-E's 16 heads of 128; 12b's 62
-        of 64, which the chip run of ISSUE 34 admitted), a mesh call is
-        per head, and so is a page that is not whole tiles of rows (the
-        merge of pages into rows would copy the gathered buffer)."""
-        assert decode_ops.read_heads_merged(page, dtype, mesh) is want
-
     @pytest.mark.parametrize("mesh", [False, True],
                              ids=["one_device", "mesh_seam"])
-    def test_step_hands_the_rule_the_mesh_seam(self, monkeypatch, mesh):
-        """The step decides the form from what it is handed: with
-        ``out_sync`` given (the mesh engine's seam) the rule is asked
-        with ``mesh=True`` and the read stays per head."""
+    def test_step_hands_the_read_the_mesh_seam(self, monkeypatch, mesh):
+        """The step decides the form from what it is handed and from
+        nothing else: with ``out_sync`` given (the mesh engine's seam)
+        every layer's read is per head, without it whole rows."""
         cfg = self.WIDE_CFG
         tcfg = cfg.transformer
         params = D.dalle_init(jax.random.PRNGKey(0), cfg,
@@ -697,20 +692,13 @@ class TestPerLayerRead:
                             dim_head=128)
         bt = jnp.asarray(np.arange(1, 2 * mp + 1, dtype=np.int32)
                          .reshape(2, mp))
-        asked = []
-        real = decode_ops.read_heads_merged
-
-        def spy(*a):
-            asked.append(a[-1])
-            return real(*a)
-        monkeypatch.setattr(decode_ops, "read_heads_merged", spy)
+        calls = self._spy_read_form(monkeypatch)
         decode_ops._decode_step_math(
             params["transformer"], jnp.zeros((2, tcfg.dim)),
             jnp.asarray([9, 3], jnp.int32), pool, cfg=tcfg,
             key_mask=jnp.ones((2, L), bool), block_tables=bt,
             out_sync=(lambda out: out) if mesh else None)
-        assert asked and all(m is mesh for m in asked)
-        assert real((2, ps, 128), jnp.float32, mesh) is (not mesh)
+        assert calls and all(form == (2, mesh) for form in calls)
 
     def _loop_args(self, bundle, page_size, quantized, cfg=CFG):
         """A mid-sequence chunk: 3 slots at ragged positions (one parked
@@ -749,8 +737,10 @@ class TestPerLayerRead:
         oracle's view of the same pool."""
         tp, cur, pos, active, pool, bt, L, kw = self._loop_args(
             bundle, page_size, quantized)
+        heads = CFG.transformer.heads
         dense = decode_ops.decode_loop(
-            tp, cur, pos, active, decode_ops.paged_view(pool, bt, L), **kw)
+            tp, cur, pos, active, decode_ops.paged_view(pool, bt, L, heads),
+            **kw)
         paged = decode_ops.decode_loop_paged(
             tp, cur, pos, active, pool, bt, total_len=L, **kw)
         np.testing.assert_array_equal(np.asarray(paged[4]),
@@ -760,7 +750,7 @@ class TestPerLayerRead:
             np.testing.assert_array_equal(np.asarray(paged[i]),
                                           np.asarray(dense[i]))
         # and the rows the chunk stored are the rows the dense loop stored
-        after = decode_ops.paged_view(paged[3], bt, L)
+        after = decode_ops.paged_view(paged[3], bt, L, heads)
         for name in after:
             np.testing.assert_allclose(
                 np.asarray(after[name][:, :2], np.float32),
@@ -785,7 +775,8 @@ class TestPerLayerRead:
         tp, cur, pos, active, pool, bt, L, kw = self._loop_args(
             bundle, 8, quantized, cfg)
         dense = decode_ops.decode_loop(
-            tp, cur, pos, active, decode_ops.paged_view(pool, bt, L), **kw)
+            tp, cur, pos, active,
+            decode_ops.paged_view(pool, bt, L, cfg.heads), **kw)
         monkeypatch.setattr(decode_ops, "_VIEW_VMEM_BYTES", 1)
         assert decode_ops.pool_view_groups(pool, *bt.shape) == 3
         paged = decode_ops.decode_loop_paged(
@@ -810,63 +801,41 @@ class TestPerLayerRead:
                              ids=["f32", "int8"])
     @pytest.mark.parametrize("sparse_reads", [False, True],
                              ids=["dense_reads", "sparse_reads"])
-    def test_merged_loop_tokens_equal_per_head_loop(
+    def test_whole_row_loop_tokens_equal_per_head_loop(
             self, monkeypatch, sparse_reads, quantized, page_size, groups):
-        """ISSUE 34: on float32 weights the fused loop emits the same
-        greedy tokens whether its reads are traced merged (the rule's
-        choice at 128-wide heads and whole tiles of rows) or per head
-        (the rule patched here; no knob in the program), and both emit
-        the dense loop's."""
+        """ISSUEs 34, 36: on float32 weights the fused loop emits the same
+        greedy tokens whether its reads contract whole rows or, handed the
+        mesh seam (an ``out_sync`` that does nothing here), a head's own
+        columns; both emit the dense loop's."""
         cfg = self.WIDE_SPARSE_CFG if sparse_reads else self.WIDE_CFG
         vae_params = V.vae_init(jax.random.PRNGKey(1), VCFG)
         params = D.dalle_init(jax.random.PRNGKey(0), cfg, vae_params)
         tp, cur, pos, active, pool, bt, L, kw = self._loop_args(
             (params, vae_params), page_size, quantized, cfg)
-        assert pool["k"].shape[2:] == (2, page_size, 128)
+        assert pool["k"].shape[2:] == (page_size, 2 * 128)
         if groups > 1:
             monkeypatch.setattr(decode_ops, "_VIEW_VMEM_BYTES", 1)
         assert decode_ops.pool_view_groups(pool, *bt.shape) == groups
-        assert decode_ops.read_heads_merged(pool["k"].shape[2:],
-                                            pool["k"].dtype)
+        calls = self._spy_read_form(monkeypatch)
 
-        def loop():
+        def loop(**seam):
             return decode_ops.decode_loop_paged(
                 tp, cur, pos, active, pool, bt, total_len=L,
-                sparse_reads=sparse_reads, **kw)
+                sparse_reads=sparse_reads, **kw, **seam)
 
-        merged = loop()
-        monkeypatch.setattr(decode_ops, "read_heads_merged",
-                            lambda *a, **kw: False)
-        per_head = loop()
+        whole = loop()
+        assert calls and not any(per_head for _, per_head in calls)
+        del calls[:]
+        per_head = loop(out_sync=lambda out: out)
+        assert calls and all(per_head for _, per_head in calls)
         dense = decode_ops.decode_loop(
-            tp, cur, pos, active, decode_ops.paged_view(pool, bt, L), **kw)
-        assert (np.asarray(merged[4])[:2] >= 0).all()   # real tokens
+            tp, cur, pos, active,
+            decode_ops.paged_view(pool, bt, L, cfg.heads), **kw)
+        assert (np.asarray(whole[4])[:2] >= 0).all()   # real tokens
         for other in (per_head, dense):
             for i in (0, 1, 2, 4):                # tok, pos, active, ring
-                np.testing.assert_array_equal(np.asarray(merged[i]),
+                np.testing.assert_array_equal(np.asarray(whole[i]),
                                               np.asarray(other[i]))
-
-    @pytest.mark.parametrize("page_size,want", [(4, 0), (8, 1)],
-                             ids=["half_a_tile_of_rows", "whole_tiles"])
-    def test_engine_reports_the_read_form_it_traced(self, bundle, page_size,
-                                                    want):
-        """``stats()["attn_read_heads_merged"]``: the form the decode
-        program's classic read was traced with (float32 pages of 8 rows
-        are whole tiles, of 4 are not), beside ``kv_view_groups``; the
-        served tokens do not depend on it."""
-        params, vae_params = bundle
-        queue = RequestQueue(max_depth=4)
-        engine = Engine(params, CFG, queue, num_slots=2, chunk_steps=4,
-                        kv="paged", page_size=page_size)
-        assert engine.stats()["attn_read_heads_merged"] == 0   # no trace yet
-        h = queue.submit(REQS[0])
-        engine.run_until_idle()
-        np.testing.assert_array_equal(
-            np.asarray(h.result(5).tokens),
-            reference_tokens(params, vae_params, REQS[0]))
-        assert engine.stats()["attn_read_heads_merged"] == want
-        assert engine.stats()["kv_view_groups"] == 1
-        assert engine.decode_traces == 1
 
     @pytest.mark.parametrize("budget,want", [(None, 1), (1, 2)],
                              ids=["the_rule", "one_slot_a_group"])
@@ -898,8 +867,9 @@ class TestPerLayerRead:
             bundle, 8, False)
         tcfg = CFG.transformer
         num_pages = 40 * KV.pages_for(L, 8) + 1    # pool >> everything else
-        pool = {n: jnp.zeros((tcfg.depth, num_pages, tcfg.heads, 8,
-                              tcfg.dim_head)) for n in ("k", "v")}
+        pool = {n: jnp.zeros((tcfg.depth, num_pages, 8,
+                              tcfg.heads * tcfg.dim_head))
+                for n in ("k", "v")}
         pool_bytes = sum(a.size * a.dtype.itemsize for a in pool.values())
 
         def loop(pool, bt, cur, pos, active):
@@ -910,6 +880,127 @@ class TestPerLayerRead:
             pool, bt, cur, pos, active).compile()
         temp = compiled.memory_analysis().temp_size_in_bytes
         assert temp < pool_bytes / 2, (temp, pool_bytes)
+
+
+class TestRowPageWrites:
+    """ISSUE 36: the two writes of the classic pool's row page: a step's
+    new rows through ``_store_entries_paged`` (``_store_rows_paged`` forms
+    the classic rows and hands them on) and the admission's whole pages
+    (``_store_prompt_pages``)."""
+
+    PS, HEADS, DH = 8, 3, 16
+
+    @pytest.mark.parametrize("wide", [1, 3], ids=["one_row", "three_rows"])
+    @pytest.mark.parametrize("kind", ["f32", "bf16", "int8"])
+    def test_store_then_read_round_trip(self, kind, wide):
+        """Rows stored through the tables are the rows the dense writer
+        stores into the oracle's view of the same pool, and the row-page
+        read of them is the dense read; a row on a page boundary, a slot
+        whose rows run past the sequence end, an INACTIVE slot whose table
+        still maps another request's pages (its rows go to the trash
+        page), nothing else touched."""
+        L, ps, heads, dh = 24, self.PS, self.HEADS, self.DH
+        need = KV.pages_for(L, ps)
+        key = jax.random.PRNGKey(36 + wide)
+        dtype = jnp.bfloat16 if kind == "bf16" else jnp.float32
+        pool = _random_pool(key, ps, 3 * need + 1, kind == "int8",
+                            dim_head=dh, dtype=dtype, heads=heads)
+        bt = jnp.asarray(np.arange(1, 3 * need + 1, dtype=np.int32)
+                         .reshape(3, need))
+        pos = jnp.asarray([ps - 1, L - 2, 5], jnp.int32)
+        active = jnp.asarray([True, True, False])
+        ks, vs = [jax.random.normal(jax.random.fold_in(key, 20 + i),
+                                    (2, 3, heads, wide, dh), dtype)
+                  for i in range(2)]
+        total_len = L if wide > 1 else None
+        after = decode_ops._store_rows_paged(pool, ks, vs, pos, bt, active,
+                                             total_len)
+        # the oracle: the dense writer over the dense view of the pool
+        view = decode_ops.paged_view(pool, bt, L, heads)
+        want = decode_ops._store_rows_wide(view, ks, vs, pos)
+        got = decode_ops.paged_view(after, bt, L, heads)
+        for name in got:
+            np.testing.assert_array_equal(
+                np.asarray(got[name][:, :2], np.float32),
+                np.asarray(want[name][:, :2], np.float32))
+            # the inactive slot's pages are as they were: its rows went
+            # to the trash page, the only other page that may differ
+            np.testing.assert_array_equal(
+                np.asarray(got[name][:, 2], np.float32),
+                np.asarray(view[name][:, 2], np.float32))
+            changed = np.any(np.asarray(after[name], np.float32)
+                             != np.asarray(pool[name], np.float32),
+                             axis=(0, 2, 3))
+            touched = {int(bt[0, 0]), int(bt[1, need - 1]), 0}
+            if wide > 1:        # slot 0 crossed into its second page
+                touched.add(int(bt[0, 1]))
+            assert set(np.flatnonzero(changed)) <= touched
+        # and the read of the stored rows is the dense read of them
+        q, k, v = [jax.random.normal(jax.random.fold_in(key, 30 + i),
+                                     (3, heads, 1, dh), dtype)
+                   for i in range(3)]
+        allowed = jnp.arange(L)[None, :] < (pos + wide)[:, None]
+        for layer in range(2):
+            dense = decode_ops._gather_read(
+                q, k, v, want["k"][layer], want["v"][layer], allowed,
+                scale=0.25,
+                ksc=want["k_scale"][layer] if kind == "int8" else None,
+                vsc=want["v_scale"][layer] if kind == "int8" else None)
+            paged = decode_ops._paged_gather_read(
+                after, jnp.asarray(layer), bt, q, k, v, allowed, scale=0.25)
+            tol = 2e-2 if kind == "bf16" else 2e-5
+            np.testing.assert_allclose(
+                np.asarray(paged[:2], np.float32),
+                np.asarray(dense[:2], np.float32), rtol=tol, atol=tol)
+
+    @pytest.mark.parametrize("bucket", [16, 12],
+                             ids=["whole_pages", "partial_last_page"])
+    @pytest.mark.parametrize("width,dtype", [
+        (3 * 16, jnp.float32), (3 * 16, jnp.int8), (3, jnp.float32)],
+        ids=["rows", "int8_rows", "scale_rows"])
+    def test_admission_whole_pages_equal_the_row_scatter(self, bucket,
+                                                         width, dtype):
+        """The admission writes a group's prompt rows as whole pages by
+        page id (``_store_prompt_pages``): every row lands where the row
+        scatter it replaced placed it (row j of group-row g in page
+        ``page_rows[g, j]`` at offset ``j % ps``), the unused group rows'
+        pages and the pages past a prompt's grants go to the trash page,
+        and no other page is touched. What is new: the tail of a
+        partial last page is zeros (never read before it is rewritten)."""
+        ps, G, layers, num_pages = self.PS, 4, 2, 9
+        key = jax.random.PRNGKey(bucket + width)
+        draw = (lambda k, shape: jax.random.randint(k, shape, -127, 128,
+                                                    jnp.int8)) \
+            if dtype == jnp.int8 else jax.random.normal
+        buf = draw(jax.random.fold_in(key, 0), (layers, num_pages, ps, width))
+        rows = draw(jax.random.fold_in(key, 1), (layers, G, bucket, width))
+        # as the engine builds it: two admitted rows with their grants
+        # (the second's run out before the bucket does), two dummy rows
+        tables = np.zeros((G, KV.pages_for(bucket, ps)), np.int32)
+        tables[0] = [3, 7]
+        tables[1, 0] = 5
+        page_rows = tables[:, np.arange(bucket) // ps]          # (G, bucket)
+        got = np.asarray(decode_ops._store_prompt_pages(
+            buf, rows, jnp.asarray(page_rows[:, ::ps].reshape(-1))))
+        want = np.array(buf)
+        for g in range(G):              # the row scatter, written out
+            for j in range(bucket):
+                want[:, page_rows[g, j], j % ps] = np.asarray(rows)[:, g, j]
+        granted = [3, 7, 5]
+        np.testing.assert_array_equal(got[:, 3], want[:, 3])
+        np.testing.assert_array_equal(got[:, 5], want[:, 5])
+        np.testing.assert_array_equal(got[:, 7, :bucket - ps],
+                                      want[:, 7, :bucket - ps])
+        assert not got[:, 7, bucket - ps:].any()     # the zero-filled tail
+        untouched = [p for p in range(1, num_pages) if p not in granted]
+        np.testing.assert_array_equal(got[:, untouched],
+                                      np.asarray(buf)[:, untouched])
+        # the trash page holds one of the pages that were sent there
+        sent = np.asarray(jnp.pad(rows, ((0, 0), (0, 0), (
+            0, -bucket % ps), (0, 0)))).reshape(layers, -1, ps, width)
+        ids = page_rows[:, ::ps].reshape(-1)
+        assert any(np.array_equal(got[:, 0], sent[:, i])
+                   for i in np.flatnonzero(ids == 0))
 
 
 class TestVisibilityOracle:

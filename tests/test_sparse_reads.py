@@ -80,7 +80,10 @@ def reference_tokens(params, vae_params, req: Request,
 
 def _random_pool(key, page_size, num_pages, quantized):
     tcfg = CFG.transformer
-    shape = (tcfg.depth, num_pages, tcfg.heads, page_size, tcfg.dim_head)
+    # a page is whole rows, every head's numbers side by side
+    shape = (tcfg.depth, num_pages, page_size, tcfg.heads * tcfg.dim_head)
+    assert shape[2:] == KV.page_layout(tcfg, page_size)["k"][0]
+    scales = shape[:-1] + (tcfg.heads,)
     if quantized:
         return {
             "k": jax.random.randint(jax.random.fold_in(key, 0), shape,
@@ -88,11 +91,9 @@ def _random_pool(key, page_size, num_pages, quantized):
             "v": jax.random.randint(jax.random.fold_in(key, 1), shape,
                                     -127, 128, jnp.int8),
             "k_scale": jax.random.uniform(jax.random.fold_in(key, 2),
-                                          shape[:-1], minval=0.01,
-                                          maxval=0.1),
+                                          scales, minval=0.01, maxval=0.1),
             "v_scale": jax.random.uniform(jax.random.fold_in(key, 3),
-                                          shape[:-1], minval=0.01,
-                                          maxval=0.1),
+                                          scales, minval=0.01, maxval=0.1),
         }
     return {"k": jax.random.normal(jax.random.fold_in(key, 0), shape),
             "v": jax.random.normal(jax.random.fold_in(key, 1), shape)}
@@ -125,7 +126,7 @@ class TestStepMathParity:
         x_tok = jax.random.normal(jax.random.PRNGKey(9), (3, CFG.dim))
         kw = dict(cfg=cfg, key_mask=key_mask)
 
-        view = decode_ops.paged_view(pool, bt, L)
+        view = decode_ops.paged_view(pool, bt, L, cfg.heads)
         h_ref, ks_ref, vs_ref = decode_ops._decode_step_math(
             params["transformer"], x_tok, pos, view, **kw)
         h_k, ks_k, _ = decode_ops._decode_step_math(
