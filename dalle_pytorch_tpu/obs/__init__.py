@@ -8,7 +8,11 @@ exists):
   * ``obs.trace`` — per-request span timelines: every submitted request
     gets a ``trace_id`` and a tiling sequence of ``perf_counter``-delta
     spans stamped at the existing serving seams (queue wait, route,
-    prefill admission, per-chunk decode, postprocess). Failover replay
+    prefill admission, per-chunk decode, postprocess); a span names
+    what caused it: ``decode_chunk`` its ``chunk`` (the engine's
+    dispatch number) and ``prefill_admit`` its ``admit`` (the admission
+    call), the keys the engine loop's annotations and its chunk ledger
+    (``Engine.loop_ring``) carry too. Failover replay
     LINKS rather than lies: the replay marker span covers the fence gap
     under its own name, so a kill shows up in the timeline as a visible
     labeled gap, never as fabricated decode time.

@@ -15,8 +15,13 @@ Span taxonomy (docs/OBSERVABILITY.md):
   ``route``          zero-duration router hand-off (replica sets);
                      carries the replica index + weights_version
   ``prefill_admit``  pop -> admitted into a slot (cold bucket prefill
-                     or warm prefix-cache admission; ``mode`` says which)
+                     or warm prefix-cache admission; ``mode`` says which;
+                     ``admit`` numbers the admission call that did it)
   ``decode_chunk``   one fused-K harvest's worth of emitted tokens
+                     (``chunk`` numbers the dispatch it came from,
+                     ``admits_ahead`` the admission calls queued in
+                     front of it: the cause keys, shared with the engine
+                     loop's annotations and its chunk ledger)
   ``evict``          paged-pool eviction marker (the request replays)
   ``replayed_from``  failover replay link: covers the FENCE GAP between
                      the victim's last progress and the re-queue, under

@@ -108,8 +108,11 @@ wraps it in a thread for live traffic.
 
 from __future__ import annotations
 
+import gc
+import statistics
 import threading
 import time
+import weakref
 from collections import deque
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
@@ -124,15 +127,46 @@ from dalle_pytorch_tpu.serve import scheduler as S
 COUNTERS = ("tokens_decoded", "decode_steps", "harvests",
             "occupancy_sum", "completed", "expired",
             "decode_traces", "prefill_traces", "evicted",
-            "prefix_hits", "cfg_pairs", "reaped")
+            "prefix_hits", "cfg_pairs", "reaped",
+            "chunks_behind_admit", "loop_stalls")
 
 # the engine loop's cumulative seconds (stats(), /metrics): always on,
-# one clock pair a phase a chunk. engine_loop_s >= harvest_wait_s +
-# admit_s + deliver_s, and admit_prefill_s is a part of admit_s. They
-# answer, with no profiler: does the engine thread wait for the device
-# (harvest_wait_s near engine_loop_s) or the device for the engine thread
+# sums of the chunk ledger's laps (LOOP_PHASES). engine_loop_s >=
+# harvest_wait_s + admit_s + deliver_s, and admit_prefill_s is a part of
+# admit_s. They answer, with no profiler: does the engine thread wait for
+# the device (harvest_wait_s near engine_loop_s) or the device for the
+# engine thread. loop_stall_s: the seconds by which stalled chunks
+# overran their class's median (STALL_FACTOR)
 LOOP_SECONDS = ("engine_loop_s", "harvest_wait_s", "admit_s",
-                "admit_prefill_s", "deliver_s")
+                "admit_prefill_s", "deliver_s", "loop_stall_s")
+
+# the chunk ledger (Engine.loop_ring, docs/OBSERVABILITY.md): every
+# second of the loop thread lies in exactly one phase (Engine._lap: one
+# clock read a boundary), and each names the cumulative LOOP_SECONDS it
+# also counts in. tail_s is a step's end after its last harvest: the
+# periodic serve record, a capture's close, and the interpreter handed to
+# the client threads that the delivery woke. between_s is everything
+# outside step_once: the run loop's turn-round and nap, the step lock, an
+# iteration that found nothing to do, and whatever held the interpreter
+LOOP_PHASES = {
+    "expire_s": ("engine_loop_s",),
+    "admit_plan_s": ("engine_loop_s", "admit_s"),
+    "admit_put_s": ("engine_loop_s", "admit_s"),
+    "admit_prefill_s": ("engine_loop_s", "admit_s", "admit_prefill_s"),
+    "dispatch_s": ("engine_loop_s",),
+    "harvest_wait_s": ("engine_loop_s", "harvest_wait_s"),
+    "deliver_s": ("engine_loop_s", "deliver_s"),
+    "tail_s": ("engine_loop_s",),
+    "between_s": (),
+}
+LOOP_ROWS = 512         # loop_ring: 40-100 s of chunks
+# a chunk whose interval exceeds STALL_FACTOR x the median of the last
+# STALL_WINDOW judged intervals of its class (behind an admission or
+# not) is a stall, once the class holds STALL_MIN of them
+STALL_FACTOR = 3
+STALL_WINDOW = 64
+STALL_MIN = 8
+STALLS_KEPT = 8         # stats()["last_stalls"]
 
 # the routed layers' load of a described block (stats() of such an
 # engine), summed over its routed layers and every decode step: what the
@@ -152,6 +186,27 @@ def _phase(name: str, **meta):
     names are the span tree of docs/OBSERVABILITY.md."""
     import jax
     return jax.profiler.TraceAnnotation(name, **meta)
+
+
+def _watch_collector(engine) -> list:
+    """``[runs, seconds]`` of the interpreter's garbage collector since
+    ``engine`` was built, kept by a ``gc.callbacks`` hook that takes
+    itself off the list once the engine is gone. A collection stops
+    every thread, the engine's too: a stall record says how much of its
+    interval was one."""
+    seen, t0, alive = [0, 0.0], [0.0], weakref.ref(engine)
+
+    def hook(phase, info):
+        if phase == "start":
+            t0[0] = time.perf_counter()
+        elif alive() is None:
+            gc.callbacks.remove(hook)
+        else:
+            seen[0] += 1
+            seen[1] += time.perf_counter() - t0[0]
+
+    gc.callbacks.append(hook)
+    return seen
 
 
 class ProfileError(RuntimeError):
@@ -261,13 +316,21 @@ class _Chunk:
     — a slot expired and re-admitted while the chunk is in flight must
     not leak the old request's tokens into the new one."""
 
-    __slots__ = ("ring", "active", "owners", "load")
+    __slots__ = ("ring", "active", "owners", "load", "chunk",
+                 "t_dispatch", "admits_ahead")
 
-    def __init__(self, ring, active, owners, load=None):
+    def __init__(self, ring, active, owners, load=None, *, chunk,
+                 t_dispatch, admits_ahead):
         self.ring = ring
         self.active = active
         self.owners = owners
         self.load = load        # MOE_COUNTERS of the chunk, or None
+        self.chunk = chunk      # the dispatch's number: its spans',
+        #                         annotations' and ledger row's cause key
+        self.t_dispatch = t_dispatch
+        # the admission calls made since the previous dispatch: the
+        # prefill programs in the device's queue in front of this chunk
+        self.admits_ahead = admits_ahead
 
 
 class _Row:
@@ -664,6 +727,24 @@ class Engine:
         #                                 group cancel, hedge loser)
         for k in LOOP_SECONDS:
             setattr(self, k, 0.0)
+        # the chunk ledger: one row a harvested chunk in a ring of its
+        # own (the span ring turns over in a second or two), the laps
+        # of the open interval, and what a stall is held against
+        self.loop_ring = oflight.FlightRecorder(capacity=LOOP_ROWS)
+        self._lap_s = dict.fromkeys(LOOP_PHASES, 0.0)
+        self._t_lap = self.clock()
+        self._gc = _watch_collector(self)   # [runs, seconds]
+        # at the last row's end: the clock, the loop thread's CPU
+        # seconds, the programs traced, the collector's runs and seconds
+        self._cut = (self._t_lap, time.thread_time(), 0, 0, 0.0)
+        self._intervals = {False: deque(maxlen=STALL_WINDOW),
+                           True: deque(maxlen=STALL_WINDOW)}
+        self._admit_calls = 0           # prefill / warm-admission CALLS
+        self._admits_ahead: List[dict] = []     # since the last dispatch
+        self.chunks_behind_admit = 0    # harvested chunks that had one
+        self.loop_stalls = 0
+        self._stalls: deque = deque(maxlen=STALLS_KEPT)
+        self._stall_open: Optional[dict] = None     # awaits next_wait_s
         self.decode_steps = 0           # fused steps dispatched (chunks*K)
         self.harvests = 0               # emit-ring device_gets — the ONLY
         #                                 host syncs in steady state
@@ -1237,6 +1318,19 @@ class Engine:
         if tr is not None:
             self.flight.record(tr.span(name, now, **meta))
 
+    def _lap(self, phase: str) -> float:
+        """Charge the seconds since the previous lap to ``phase`` (one of
+        ``LOOP_PHASES``): into the open ledger interval and into the
+        cumulative ``LOOP_SECONDS`` it counts in. The ONE clock read a
+        phase boundary; returns it."""
+        t = self.clock()
+        dt = t - self._t_lap
+        self._t_lap = t
+        self._lap_s[phase] += dt
+        for k in LOOP_PHASES[phase]:
+            setattr(self, k, getattr(self, k) + dt)
+        return t
+
     def _finish(self, handle: S.RequestHandle, result: S.Result) -> None:
         if self.fenced:
             return
@@ -1355,6 +1449,7 @@ class Engine:
             return
         with _phase("engine.admit.plan"):
             rows, free = self._plan_admission(handles, now)
+        self._lap("admit_plan_s")
         free = self._admit_cold(rows, free, now)
         self._admit_warm(rows, free, now)
 
@@ -1564,22 +1659,24 @@ class Engine:
                 if cold:
                     self.compiling = True
                 try:
+                    self._lap("admit_plan_s")   # the group's host arrays
                     with _phase("engine.admit.put"):
                         group_args = [put(a) for a in (
                             text, lens, slots, n_seed, n_temp, n_topk,
                             n_top_p, n_partner, n_cfgs, n_uncond)]
                         paged_kw = {"page_rows": put(page_rows)} \
                             if self.kv == "paged" else {}
-                    t_pre = self.clock()
+                    t_pre = self._lap("admit_put_s")
+                    admit = self._admit_calls
                     with _phase("engine.admit.prefill", bucket=bucket,
-                                mode="cold"):
+                                mode="cold", admit=admit):
                         outs = self._prefill_fn(bucket, G)(
                             self.params, self.cache, self.cur_tok,
                             self.pos, self.active, self.rng, self.temp,
                             self.topk_k, self.top_p, *group_args,
                             **paged_kw)
-                    dispatch_s = self.clock() - t_pre
-                    self.admit_prefill_s += dispatch_s
+                    dispatch_s = self._lap("admit_prefill_s") - t_pre
+                    self._admitted(admit, G, bucket, "cold")
                     self.prefill_runs += 1
                 finally:
                     if cold:
@@ -1626,12 +1723,20 @@ class Engine:
                     #                 per slot of a guided pair
                     self._span(p.handle, "prefill_admit", t_slotted,
                                bucket=bucket, mode="cold", slot=i,
-                               dispatch_s=dispatch_s)
+                               dispatch_s=dispatch_s, admit=admit, rows=G)
             self._wire_pairs(group)
             if self.prefix is not None:
                 for p in group:
                     self._prefix_insert(p, h_last)
         return free
+
+    def _admitted(self, admit: int, rows: int, bucket: int,
+                  mode: str) -> None:
+        """Admission call ``admit`` is in the device's queue: the next
+        chunk dispatched runs behind it."""
+        self._admit_calls = admit + 1
+        self._admits_ahead.append({"admit": admit, "rows": rows,
+                                   "bucket": bucket, "mode": mode})
 
     def _fill_admit_row(self, p: _Row, j: int, lens, n_seed, n_temp,
                         n_topk, n_top_p, n_cfgs, n_uncond) -> None:
@@ -1797,18 +1902,21 @@ class Engine:
                     h_rows = h_rows + [h_rows[0]] * (G - len(h_rows))
                 h_stack = jnp.stack(h_rows)
                 put = self._put
+                self._lap("admit_plan_s")
                 with _phase("engine.admit.put"):
                     group_args = [put(a) for a in (
                         lens, slots, n_seed, n_temp, n_topk, n_top_p,
                         n_partner, n_cfgs, n_uncond)]
-                t_warm = self.clock()
-                with _phase("engine.admit.prefill", mode="warm"):
+                t_warm = self._lap("admit_put_s")
+                admit = self._admit_calls
+                with _phase("engine.admit.prefill", mode="warm",
+                            admit=admit):
                     outs = self._warm_admit_fn()(
                         self.params, self.cur_tok, self.pos, self.active,
                         self.rng, self.temp, self.topk_k, self.top_p,
                         h_stack, *group_args)
-                dispatch_s = self.clock() - t_warm
-                self.admit_prefill_s += dispatch_s
+                dispatch_s = self._lap("admit_prefill_s") - t_warm
+                self._admitted(admit, G, 0, "warm")
             finally:
                 if coldw:
                     self.compiling = False
@@ -1852,7 +1960,7 @@ class Engine:
                 self._span(p.handle, "prefill_admit", t_slotted,
                            mode="warm", slot=i,
                            pages_shared=p.shared_n,
-                           dispatch_s=dispatch_s)
+                           dispatch_s=dispatch_s, admit=admit, rows=G)
             if self.metrics is not None:
                 self.metrics.event(**S.structured_event(
                     "serve_prefix_hit",
@@ -2099,7 +2207,12 @@ class Engine:
             for i, _ in owners:
                 self._pos_est[i] = min(self._pos_est[i] + self._chunk_span,
                                        self.total_len)
-        self._pending.append(_Chunk(ring, self.active, owners, *load))
+        self._pending.append(_Chunk(
+            ring, self.active, owners, *load,
+            chunk=self.decode_steps // self.chunk_steps,
+            t_dispatch=self._lap("dispatch_s"),
+            admits_ahead=self._admits_ahead))
+        self._admits_ahead = []
         self.decode_steps += self.chunk_steps
         self.sample_sorted_chunks += any(
             s.handle.request.sampling.top_p > 0 for _, s in owners)
@@ -2114,23 +2227,85 @@ class Engine:
         time is the honest fulfillment time (docs/SERVING.md)."""
         import jax
         rec = self._pending.popleft()
-        t_wait = self.clock()
-        with _phase("engine.harvest_wait"):
+        with _phase("engine.harvest_wait", chunk=rec.chunk):
             # the routed load rides the ring's fetch (None: an empty tree)
             ring, active_after, load = jax.device_get(
                 [rec.ring, rec.active, rec.load])
-        t_got = self.clock()
+        t_got = self._lap("harvest_wait_s")
+        # jaxlint: disable=JL007 — the profiler's host line is on this
+        # clock (docs/OBSERVABILITY.md): a reading, not duration math
+        unix_ns = time.time_ns()
         if load is not None:
             for k, v in zip(MOE_COUNTERS, load):
                 setattr(self, k, getattr(self, k) + int(v))
-        self.harvest_wait_s += t_got - t_wait
-        with _phase("engine.deliver"):
-            self._deliver_chunk(rec, ring, active_after)
-        self.deliver_s += self.clock() - t_got
+        with _phase("engine.deliver", chunk=rec.chunk):
+            tokens = self._deliver_chunk(rec, ring, active_after)
+        self._ledger_row(rec, t_got, unix_ns, tokens)
 
-    def _deliver_chunk(self, rec: _Chunk, ring, active_after) -> None:
+    def _ledger_row(self, rec: _Chunk, t_harvest: float, unix_ns: int,
+                    tokens: int) -> None:
+        """Close the ledger's open interval with this chunk's row, and
+        hold it against its class's median: a stall is an event."""
+        t_cut = self._lap("deliver_s")
+        phases = self._lap_s
+        self._lap_s = dict.fromkeys(LOOP_PHASES, 0.0)
+        cut_t, cut_cpu, cut_traces, cut_gc_runs, cut_gc_s = self._cut
+        self._cut = (t_cut, time.thread_time(), self.decode_traces
+                     + self.prefill_traces + self.warm_admit_traces,
+                     *self._gc)
+        interval = t_cut - cut_t
+        behind = bool(rec.admits_ahead)
+        self.chunks_behind_admit += behind
+        row = {"chunk": rec.chunk, "t_dispatch": rec.t_dispatch,
+               "t_harvest": t_harvest, "unix_ns": unix_ns,
+               "interval_s": interval,
+               "steps": self.chunk_steps, "live_slots": len(rec.owners),
+               "tokens": tokens, "admits_ahead": len(rec.admits_ahead),
+               "admit_rows": sum(a["rows"] for a in rec.admits_ahead),
+               "admit_calls": self._admit_calls,
+               "pending": len(self._pending), **phases}
+        self.loop_ring.record(row)
+        # the device's answer to the stall before: near zero, it had gone
+        # on and only that fetch was late; a whole chunk, it stood still
+        if self._stall_open is not None:
+            self._emit_stall(phases["harvest_wait_s"] if rec.chunk
+                             == self._stall_open["chunk"] + 1 else None)
+        # an interval that opened on an empty pipeline holds idle time,
+        # one that traced a program its compile: neither is judged
+        if rec.t_dispatch > cut_t or self._cut[2] != cut_traces:
+            return
+        seen = self._intervals[behind]
+        median = statistics.median(seen) if len(seen) >= STALL_MIN else None
+        seen.append(interval)
+        if median is None or interval <= STALL_FACTOR * median:
+            return
+        self.loop_stalls += 1
+        self.loop_stall_s += interval - median
+        self._stall_open = {
+            **row, "phase": max(phases, key=phases.get),
+            "median_s": median, "excess_s": interval - median,
+            "thread_cpu_s": self._cut[1] - cut_cpu,
+            "gc_runs": self._cut[3] - cut_gc_runs,
+            "gc_s": self._cut[4] - cut_gc_s,
+            "queue_depth": self.queue.depth(), "next_wait_s": None}
+        self._stalls.append(self._stall_open)
+        if not self._pending:       # no chunk behind it to answer
+            self._emit_stall(None)
+
+    def _emit_stall(self, next_wait_s: Optional[float]) -> None:
+        """The open stall, whole: to the ledger's ring and the event
+        sinks (a JSONL log, the flight ring, ``GET /debug/events``)."""
+        stall, self._stall_open = self._stall_open, None
+        stall["next_wait_s"] = next_wait_s
+        event = S.structured_event("serve_loop_stall", **stall)
+        self.loop_ring.record(event)
+        if self.metrics is not None:
+            self.metrics.event(**event)
+
+    def _deliver_chunk(self, rec: _Chunk, ring, active_after) -> int:
         """The host's half of a harvest, once the ring has landed: rings
-        to sinks and owners, spans, completions, the kill mask."""
+        to sinks and owners, spans, completions, the kill mask. Returns
+        the tokens delivered."""
         self.harvests += 1
         if self._profiler is not None:
             # chunks harvest FIFO, so the countdown set at capture
@@ -2245,7 +2420,8 @@ class Engine:
                 # (or the admit) to THIS harvest — where the request's
                 # decode milliseconds actually went
                 self._span(slot.handle, "decode_chunk", now,
-                           tokens=int(len(toks)))
+                           tokens=int(len(toks)), chunk=rec.chunk,
+                           admits_ahead=len(rec.admits_ahead))
             if capped:
                 # the budget is met mid-sequence: the device bit is
                 # still up, so completion must also kill the slot's
@@ -2264,6 +2440,7 @@ class Engine:
             self.active = self._kill_fn(self.active, self._put(keep))
         self.tokens_decoded += emitted
         self.occupancy_sum += emitted
+        return emitted
 
     def _complete(self, i: int, slot: _Slot, now: float) -> None:
         """Fulfil a finished slot's request and free the slot (its device
@@ -2374,13 +2551,12 @@ class Engine:
             # flush the in-flight pipeline first: the device pos and the
             # host's emitted list must describe the SAME point in the
             # stream, and no orphaned ring row may outlive the export
-            t_flush = self.clock()
+            self._lap("between_s")
             while self._pending:
                 # racelint: disable=RL003 — deliberate: _lock IS the
                 # step serializer; an export must flush (and sync) under
                 # it or the snapshot tears against a concurrent step
                 self._harvest_chunk()
-            self.engine_loop_s += self.clock() - t_flush
             slot = self.slots[i] if 0 <= i < self.num_slots else None
             if slot is None or slot.shadow_of is not None:
                 raise MigrationError("not_found", f"slot {i}")
@@ -2599,7 +2775,7 @@ class Engine:
                     self._profiler.close()
                     self._profiler = None
                 return False        # reclaimed: this pool is dead weight
-            now = self.clock()
+            now = self._lap("between_s")
             self.last_heartbeat = now
             if self._t_start is None:
                 self._t_start = now
@@ -2610,8 +2786,7 @@ class Engine:
                 # device_get, an admission's compile) must run under it,
                 # each for the reason given at its own site in _step
                 did = self._step(now)
-            if did:
-                self.engine_loop_s += self.clock() - now
+            self._lap("tail_s" if did else "between_s")
             return did
 
     def _expire_and_pop(self, now: float):
@@ -2691,11 +2866,14 @@ class Engine:
         """``step_once`` under its lock, phase by phase."""
         with _phase("engine.expire"):
             did, ready = self._expire_and_pop(now)
+        # an iteration that finds nothing to do is the run loop's
+        # turn-round, not the engine's work
+        self._lap("expire_s" if did or self._pending
+                  or self.active_slots() else "between_s")
         if ready:
             # published for the reclaim sweep BEFORE admission can
             # block on a compile (see _admitting)
             self._admitting = list(ready)
-            t_admit = self.clock()
             try:
                 # racelint: disable=RL003 — deliberate: admission
                 # compiles/donates into live slot buffers; it MUST
@@ -2706,11 +2884,12 @@ class Engine:
                     self._admit(ready, now)
             finally:
                 self._admitting = []
-                self.admit_s += self.clock() - t_admit
+                self._lap("admit_plan_s")   # slotting, spans, pair wiring
 
         dispatched = False
         if self.active_slots() > 0:
-            with _phase("engine.dispatch"):
+            with _phase("engine.dispatch",
+                        chunk=self.decode_steps // self.chunk_steps):
                 self._dispatch_chunk(now)
             dispatched = did = True
 
@@ -3060,10 +3239,8 @@ class Engine:
         ring, the pages it reused in place, and what an unshared,
         unwindowed cache would hold at the same positions (every
         attention layer's pages to each slot's mapped position: the
-        denominator of the saving); ``full_pool_readers``, the layers
-        that read the full pool, where they are more than those that
-        store to it. With state-space layers, the state buffers' bytes
-        and layers."""
+        denominator of the saving). With state-space layers, the state
+        buffers' bytes."""
         if self.block is None:
             return {}
         blk, depth = self.block, self.cfg.transformer.depth
@@ -3078,7 +3255,6 @@ class Engine:
             out.update({
                 "full_pages_in_use": full_in_use,
                 "window_pages_in_use": w.alloc.in_use,
-                "window_ring_pages": w.ring,
                 "window_pages_reused": w.reused,
                 # layer-pages held now, and what they would be were
                 # every attention layer a full one with rows of its own
@@ -3089,15 +3265,10 @@ class Engine:
                 "layer_pages_all_full": (readers + win_layers)
                 * full_in_use,
             })
-            if readers > full_layers:
-                out["full_pool_readers"] = readers
         state = blk.pools(depth).get("state")
         if state:
-            out.update({
-                "state_bytes": int(sum(self.cache[n].nbytes
-                                       for n in state)),
-                "state_layers": int(self.cache[state[0]].shape[0]),
-            })
+            out["state_bytes"] = int(sum(self.cache[n].nbytes
+                                         for n in state))
         return out
 
     def pages_in_use_p95(self) -> int:
@@ -3218,6 +3389,12 @@ class Engine:
             "harvests": self.harvests,
             "host_round_trips_per_token": round(
                 self.harvests / max(self.tokens_decoded, 1), 6),
+            # the chunk ledger (loop_ring): of the harvested chunks,
+            # those that ran behind an admission; the stalls, and the
+            # newest of them whole (phase, thread_cpu_s, next_wait_s)
+            "chunks_behind_admit": self.chunks_behind_admit,
+            "loop_stalls": self.loop_stalls,
+            "last_stalls": [dict(st) for st in list(self._stalls)],
             "sample_sorted_chunks": self.sample_sorted_chunks,
             "kv_view_groups": self.kv_view_groups,
             # the obs surface: flight-recorder occupancy (retention is

@@ -607,13 +607,18 @@ class ReplicaSet:
         """The ``GET /debug/events`` body: the set-level ring, every
         live replica's ring (a process replica's parent-side mirror),
         and the last fence dump per fenced replica index."""
-        out = {"server": self.flight.dump(), "replicas": {},
+        out = {"server": self.flight.dump(), "replicas": {}, "loop": {},
                "fenced": {str(i): d for i, d in
                           self.fence_dumps.items()}}
         for r in self.replicas:
             fl = getattr(r.engine, "flight", None)
             if fl is not None:
                 out["replicas"][str(r.index)] = fl.dump()
+            # a thread replica's chunk ledger; a child's rows stay with
+            # it, its stalls arrive in the mirror ring as events
+            ledger = getattr(r.engine, "loop_ring", None)
+            if ledger is not None:
+                out["loop"][str(r.index)] = ledger.dump()
         return out
 
     def _device_for(self, i: int):
@@ -2357,6 +2362,8 @@ class ReplicaSet:
             "harvests": self.harvests,
             "host_round_trips_per_token": round(
                 self.harvests / max(tokens, 1), 6),
+            "chunks_behind_admit": self._agg("chunks_behind_admit"),
+            "loop_stalls": self._agg("loop_stalls"),
             "failovers": self.failovers,
             "reclaimed": self.reclaimed,
             "bringup_failures": self.bringup_failures,

@@ -757,6 +757,12 @@ class InferenceServer:
          "Seconds of admission inside the prefill / warm-admit call"),
         ("deliver_s", "dalle_serve_deliver_seconds_total",
          "Seconds of it delivering harvested tokens on the host"),
+        ("chunks_behind_admit", "dalle_serve_chunks_behind_admit_total",
+         "Harvested chunks that ran behind an admission's prefill"),
+        ("loop_stalls", "dalle_serve_loop_stalls_total",
+         "Chunks whose interval passed 3x their class's median"),
+        ("loop_stall_s", "dalle_serve_loop_stall_seconds_total",
+         "Seconds by which stalled chunks overran that median"),
         ("reaped", "dalle_serve_reaped_total",
          "Slots freed because the handle terminated externally "
          "(stream disconnect, group cancel, hedge loser)"),
@@ -864,7 +870,8 @@ class InferenceServer:
             return self.engine.debug_events()
         fl = getattr(self.engine, "flight", None)
         return {"server": fl.dump() if fl is not None else [],
-                "replicas": {}, "fenced": {}}
+                "replicas": {}, "fenced": {},
+                "loop": self.engine.loop_ring.dump()}
 
     # -- POST /admin/profile (serve-side jax.profiler capture) --------------
 

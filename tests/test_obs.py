@@ -477,6 +477,243 @@ class TestEngineLoop:
 
 
 # ---------------------------------------------------------------------------
+# the chunk ledger: numbers on the spans, one row a chunk, a stall an event
+# ---------------------------------------------------------------------------
+
+class _Clock:
+    """A clock the test moves: a read costs a microsecond, a turn of the
+    loop what ``_drive`` gives it, a pause what ``jump`` is told."""
+
+    def __init__(self):
+        self.t = 0.0
+
+    def __call__(self):
+        self.t += 1e-6
+        return self.t
+
+    def jump(self, seconds):
+        self.t += seconds
+
+
+class _Sink:
+    def __init__(self):
+        self.events = []
+
+    def event(self, **fields):
+        self.events.append(fields)
+
+
+def _ledger_engine(params, **kw):
+    clock = _Clock()
+    queue = RequestQueue(max_depth=64, clock=clock)
+    engine = Engine(params, CFG, queue, clock=clock, **kw)
+    return engine, queue, clock
+
+
+def _drive(engine, clock, steps=10_000):
+    """Turn the loop, a tenth of a second between its steps, until the
+    engine is idle or ``steps`` are spent."""
+    for _ in range(steps):
+        if engine.idle():
+            break
+        clock.jump(0.1)
+        engine.step_once()
+
+
+def _rows(engine):
+    return [r for r in engine.loop_ring.dump() if "kind" not in r]
+
+
+def _stalls(records):
+    return [r for r in records if r.get("kind") == "serve_loop_stall"]
+
+
+class TestChunkLedger:
+    def test_spans_name_their_chunk_and_their_admission(self, bundle):
+        params, _ = bundle
+        engine, queue, clock = _ledger_engine(params, num_slots=2,
+                                              chunk_steps=2)
+        first = queue.submit(REQS[0])
+        _drive(engine, clock, steps=3)
+        second = queue.submit(REQS[3])      # joins mid-stream
+        _drive(engine, clock)
+        results = [h.result(timeout=5) for h in (first, second)]
+        assert all(r.status == OK for r in results)
+        spans = [s for h in (first, second) for s in h.trace.spans()]
+        chunks = [s for s in spans if s["span"] == "decode_chunk"]
+        # every span of one harvest carries that harvest's chunk, and
+        # the ids rise by one a dispatch
+        by_end = {}
+        for s in chunks:
+            by_end.setdefault(round(s["t0"] + s["dur_s"], 9),
+                              set()).add(s["chunk"])
+        assert all(len(ids) == 1 for ids in by_end.values())
+        ids = [min(by_end[t]) for t in sorted(by_end)]
+        assert ids == list(range(ids[0], ids[0] + len(ids)))
+        # admits_ahead counts the admission calls of the same step: the
+        # chunk dispatched behind each of the two, for BOTH streams
+        admits = sorted((s["admit"], s["rows"]) for s in spans
+                        if s["span"] == "prefill_admit")
+        assert [a for a, _ in admits] == [0, 1]
+        assert all(rows in engine.prefill_groups for _, rows in admits)
+        behind = {s["chunk"] for s in chunks if s["admits_ahead"]}
+        assert len(behind) == 2
+        assert all(s["admits_ahead"] == (1 if s["chunk"] in behind else 0)
+                   for s in chunks)
+        joined = [s for s in second.trace.spans()
+                  if s["span"] == "decode_chunk"][0]["chunk"]
+        assert joined in behind and sum(
+            s["chunk"] == joined for s in chunks) == 2
+        # the tiling contract holds with the new keys on the spans
+        for r in results:
+            assert r.trace["span_total_s"] == pytest.approx(r.total_s,
+                                                            abs=1e-4)
+
+    def test_rows_tile_the_loop_and_sum_to_the_counters(self, bundle):
+        from dalle_pytorch_tpu.serve.engine import (LOOP_PHASES,
+                                                    LOOP_SECONDS)
+        params, _ = bundle
+        engine, queue, clock = _ledger_engine(params, num_slots=2,
+                                              chunk_steps=2)
+        for r in REQS[:4]:
+            queue.submit(r)
+        _drive(engine, clock)
+        rows, st = _rows(engine), engine.stats()
+        assert [r["chunk"] for r in rows] == list(range(len(rows)))
+        assert len(rows) == st["harvests"] \
+            == st["decode_steps"] // st["chunk_steps"]
+        for r in rows:
+            assert sum(r[p] for p in LOOP_PHASES) == pytest.approx(
+                r["interval_s"])
+            assert r["t_dispatch"] < r["t_harvest"] and r["unix_ns"] > 0
+        # one source: a cumulative counter is the sum of the rows' phases
+        # that count in it (and of the open interval's: the last step's
+        # tail)
+        for k in LOOP_SECONDS[:-1]:
+            assert st[k] == pytest.approx(sum(
+                r[p] for r in rows + [engine._lap_s]
+                for p, sums in LOOP_PHASES.items() if k in sums)), k
+        assert st["engine_loop_s"] > st["harvest_wait_s"] + st["admit_s"] \
+            + st["deliver_s"]
+        assert st["chunks_behind_admit"] == sum(
+            r["admits_ahead"] > 0 for r in rows) >= 2
+        assert sum(r["tokens"] for r in rows) == st["tokens_decoded"]
+        assert st["loop_stalls"] == 0 and st["last_stalls"] == []
+
+    @pytest.mark.parametrize("where", ["harvest_wait_s", "between_s"])
+    def test_a_pause_is_one_stall_that_names_its_phase(self, bundle,
+                                                       monkeypatch, where):
+        params, _ = bundle
+        sink = _Sink()
+        engine, queue, clock = _ledger_engine(
+            params, num_slots=1, chunk_steps=1, metrics=sink)
+        for r in (REQS[0], REQS[3], REQS[0]):
+            queue.submit(r)
+        _drive(engine, clock, steps=14)     # a median to be held against
+        assert engine.loop_stalls == 0
+        if where == "between_s":
+            clock.jump(5.0)                 # between two step_once calls
+        else:
+            fetch, armed = jax.device_get, [True]
+
+            def late(tree):
+                if armed.pop() if armed else False:
+                    clock.jump(5.0)         # inside the ring's fetch
+                return fetch(tree)
+
+            monkeypatch.setattr(jax, "device_get", late)
+        _drive(engine, clock, steps=1)
+        st = engine.stats()
+        assert st["loop_stalls"] == 1
+        assert st["loop_stall_s"] == pytest.approx(5.0, abs=0.01)
+        # whole at once: the record waits one harvest for next_wait_s
+        assert st["last_stalls"][0]["next_wait_s"] is None
+        assert not _stalls(sink.events)
+        _drive(engine, clock)
+        stall, = _stalls(sink.events)
+        assert stall["phase"] == where and stall[where] > 4.9
+        assert stall["interval_s"] > 3 * stall["median_s"] > 0
+        rows = {r["chunk"]: r for r in _rows(engine)}
+        assert stall["next_wait_s"] \
+            == rows[stall["chunk"] + 1]["harvest_wait_s"]
+        assert stall["thread_cpu_s"] >= 0 and stall["gc_runs"] >= 0 \
+            and stall["queue_depth"] >= 0
+        st = engine.stats()
+        assert st["loop_stalls"] == 1 and len(st["last_stalls"]) == 1
+        assert st["last_stalls"][0]["chunk"] == stall["chunk"]
+        assert st["last_stalls"][0]["next_wait_s"] == stall["next_wait_s"]
+        # the stall outlives a thousand later spans: the ledger's ring is
+        # not the span ring
+        assert len(_stalls(engine.flight.dump())) == 1
+        for i in range(1000):
+            engine.flight.record({"event": "span", "span": "decode_chunk"})
+        assert not _stalls(engine.flight.dump())
+        assert len(_stalls(engine.loop_ring.dump())) == 1
+
+    def test_an_admission_and_a_compile_are_no_stall(self, bundle):
+        params, _ = bundle
+        engine, queue, clock = _ledger_engine(params, num_slots=3,
+                                              chunk_steps=1)
+        admit, cost = engine._admit, [0.0]
+
+        def slow_admit(handles, now):
+            clock.jump(cost[0])
+            admit(handles, now)
+
+        engine._admit = slow_admit
+        queue.submit(REQS[0])
+        _drive(engine, clock, steps=14)
+        # an ordinary admission: half a chunk's time, a program that is
+        # compiled (REQS[3] has REQS[0]'s bucket)
+        cost[0] = 0.05
+        queue.submit(REQS[3])
+        _drive(engine, clock, steps=3)
+        assert engine.chunks_behind_admit == 2 and engine.loop_stalls == 0
+        # a cold bucket: seconds inside an interval that traced a program
+        cost[0] = 5.0
+        traces = engine.prefill_traces
+        queue.submit(Request(codes=(1, 2, 3, 4, 5, 6, 7), seed=3))
+        _drive(engine, clock, steps=3)
+        assert engine.prefill_traces == traces + 1
+        assert max(r["interval_s"] for r in _rows(engine)) > 5.0
+        assert engine.loop_stalls == 0
+        # the same seconds with nothing traced are a stall
+        queue.submit(REQS[3])
+        _drive(engine, clock)
+        assert engine.loop_stalls == 1
+        assert engine.stats()["last_stalls"][0]["phase"] == "admit_plan_s"
+
+    def test_a_workers_stall_reaches_the_parents_mirror(self, bundle):
+        """A process worker ships its flight ring's increments in every
+        snapshot frame (``serve/worker.py``); the stall is an event in
+        that ring, so the parent's mirror has it by the path that is
+        there."""
+        import time
+
+        from dalle_pytorch_tpu.serve import ipc
+        params, _ = bundle
+        engine, queue, clock = _ledger_engine(params, num_slots=1,
+                                              chunk_steps=1)
+        queue.submit(REQS[0])
+        _drive(engine, clock, steps=14)
+        clock.jump(5.0)
+        _drive(engine, clock)
+        _seq, events = engine.flight.since(0)
+        kind, payload, _ = ipc.decode_frame(ipc.encode_frame(
+            ipc.HEARTBEAT, {"snap": None, "events": events}))
+        client = ipc.ChildEngineClient.__new__(ipc.ChildEngineClient)
+        client.clock = time.perf_counter
+        client.flight = FlightRecorder(capacity=512)
+        client._dispatch(kind, payload)
+        stall, = _stalls(client.flight.dump())
+        assert stall["phase"] == "between_s" \
+            and stall["next_wait_s"] is not None
+        # the counters ride the heartbeat's snapshot as the rest do
+        assert ipc.engine_snapshot(engine, 0, 0, False)["counters"][
+            "loop_stalls"] == 1
+
+
+# ---------------------------------------------------------------------------
 # replica-set tracing: thread-mode failover replay link
 # ---------------------------------------------------------------------------
 
@@ -689,6 +926,9 @@ class TestServerObs:
                     "dalle_serve_engine_loop_seconds_total",
                     "dalle_serve_harvest_wait_seconds_total",
                     "dalle_serve_admit_prefill_seconds_total",
+                    "dalle_serve_chunks_behind_admit_total",
+                    "dalle_serve_loop_stalls_total",
+                    "dalle_serve_loop_stall_seconds_total",
                     "dalle_serve_info"):
             assert fam in text, f"missing family {fam}"
         count = [ln for ln in text.splitlines()
@@ -706,6 +946,10 @@ class TestServerObs:
         st, body = self._get(port, "/debug/events")
         events = json.loads(body)["server"]
         assert any(e.get("event") == "span" for e in events)
+        # and the chunk ledger's rows, one a harvested chunk
+        ledger = [r for r in json.loads(body)["loop"] if "kind" not in r]
+        assert ledger and ledger[-1]["chunk"] == len(ledger) - 1
+        assert ledger[-1]["harvest_wait_s"] >= 0
         # HTTP result bodies carry the trace summary
         st, gen = self._post(port, "/generate",
                              {"codes": [1, 2], "seed": 3})
