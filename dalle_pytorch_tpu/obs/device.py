@@ -87,7 +87,7 @@ def scope_of_path(op_name: str) -> str:
 class _Inst:
     """One parsed instruction: what the resolver below walks over."""
     __slots__ = ("name", "shape", "opcode", "operands", "index", "body",
-                 "op_name", "comp", "root")
+                 "branches", "op_name", "comp", "root")
 
     def __init__(self, comp: str, line: str, m):
         self.comp, self.name, self.shape = comp, m.group(1), m.group(2) or ""
@@ -108,6 +108,12 @@ class _Inst:
         self.index = int(idx.group(1)) if idx else None
         body = _BODY.search(args[end:])
         self.body = body.group(1) if body else None
+        # a conditional's branch computations, in the order of its
+        # operands after the index (or the predicate: true, false)
+        listed = _BRANCHES.search(args[end:])
+        self.branches = _NAME.findall(listed.group(1)) if listed else [
+            m.group(1) for m in (_TRUE.search(args[end:]),
+                                 _FALSE.search(args[end:])) if m]
         found = _OP_NAME.search(line)
         self.op_name = found.group(1) if found else ""
 
@@ -116,6 +122,9 @@ _OPCODE = re.compile(r"\s([\w\-]+)\(")
 _NAME = re.compile(r"%([\w.\-]+)")
 _INDEX = re.compile(r"\bindex=(\d+)")
 _BODY = re.compile(r"\bbody=%?([\w.\-]+)")
+_BRANCHES = re.compile(r"\bbranch_computations=\{([^}]*)\}")
+_TRUE = re.compile(r"\btrue_computation=%?([\w.\-]+)")
+_FALSE = re.compile(r"\bfalse_computation=%?([\w.\-]+)")
 
 
 def _parse(text: str):
@@ -140,8 +149,9 @@ def _parse(text: str):
 
 class _Flow:
     """Where a value goes and where it came from, through what the
-    compiler adds without a path of its own: copies, bitcasts, tuples
-    and the carries of loops. A walk stops at the first instruction that
+    compiler adds without a path of its own: copies, bitcasts, tuples,
+    the carries of loops and the operands and results of a conditional's
+    branches. A walk stops at the first instruction that
     has a scope. A position is (instruction, element): element None for
     an array, k for the k-th element of a tuple-valued instruction."""
 
@@ -150,6 +160,7 @@ class _Flow:
         self.by_name = {i.name: i for insts in comps.values() for i in insts}
         self.users: Dict[str, list] = {}
         self.param, self.root, self.loop_of = {}, {}, {}
+        self.branch_of = {}     # branch computation -> (conditional, nth)
         for comp, insts in comps.items():
             for i in insts:
                 for o in i.operands:
@@ -160,6 +171,8 @@ class _Flow:
                     self.root[comp] = i
                 if i.opcode == "while" and i.body:
                     self.loop_of[i.body] = i
+                for nth, branch in enumerate(i.branches):
+                    self.branch_of[branch] = (i, nth)
 
     def _named(self, inst) -> Optional[str]:
         s = self.scope_of.get(inst.name, UNSCOPED)
@@ -196,10 +209,17 @@ class _Flow:
                 out.append((u, None))
             elif u.opcode == "while" and u.body in self.param:
                 out.append((self.param[u.body], k))     # into the loop
+            elif u.opcode == "conditional":             # into its branches:
+                out += [(self.param[b], k)              # operand n + 1 is
+                        for n, b in enumerate(u.branches)   # branch n's
+                        if b in self.param and u.operands[n + 1:n + 2]
+                        == [inst.name]]
         if inst.root and k is not None and inst.comp in self.loop_of:
             loop = self.loop_of[inst.comp]              # a carry: out of
             out += [(loop, k), (self.param[inst.comp], k)]  # it and around
-        return out
+        if inst.root and inst.comp in self.branch_of:   # a branch's result
+            out.append((self.branch_of[inst.comp][0], k))   # is its
+        return out                                      # conditional's
 
     def _backward(self, inst, k):
         ops = [self.by_name[o] for o in inst.operands if o in self.by_name]
@@ -211,11 +231,18 @@ class _Flow:
             return [(ops[k], None)] if k < len(ops) else []
         if inst.opcode == "while" and inst.body in self.root:
             return [(self.root[inst.body], k)] + [(o, k) for o in ops[:1]]
+        if inst.opcode == "conditional":
+            return [(self.root[b], k) for b in inst.branches
+                    if b in self.root]
         if inst.opcode == "parameter" and inst.comp in self.loop_of:
             loop = self.loop_of[inst.comp]
             return [(self.root[inst.comp], k)] + [
                 (self.by_name[o], k) for o in loop.operands[:1]
                 if o in self.by_name]
+        if inst.opcode == "parameter" and inst.comp in self.branch_of:
+            cond, nth = self.branch_of[inst.comp]
+            return [(self.by_name[o], k) for o in
+                    cond.operands[nth + 1:nth + 2] if o in self.by_name]
         return []
 
 

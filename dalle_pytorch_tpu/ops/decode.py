@@ -31,8 +31,9 @@ generate_images in eval_decorator, reference dalle_pytorch.py:30-36,318).
 
 from __future__ import annotations
 
+import functools
 import math
-from typing import Optional, Tuple
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -50,6 +51,30 @@ Array = jax.Array
 # (of v5e's 128 MiB) from a gather to its readers: 89 MB yes, 178 MB no
 # (AOT compiles, PERF.md section 6, PR 27)
 _VIEW_VMEM_BYTES = 96 << 20
+# what halving a slot group must save to pay for the further group, in
+# gathered bytes (``view_slot_groups``): a group costs about 4.5 us
+# whatever it reads (a gather's start is 2.5-3 us, its products' starts
+# the rest), which the memory moves 3.5 MB in. On the chip (`tpot_ms`, one
+# seed a cell, PERF.md section 6, PR 38): ruDALL-E (16 slots x 4.7 MB of
+# table: halving groups of eight saves 4.7 MB a group) 9.952 at four slots
+# a group, 10.025 at eight, 10.529 without the rule; kanana (32 slots x
+# 5.6 MB: 2.8 MB a group) 13.751 at four, 13.494 at eight, 14.104 without.
+# The constant lies between the two
+_VIEW_GROUP_BYTES = 7 << 19
+# the ladder of table widths (``view_widths``): a step is an eighth of
+# the table, so a group reads at most that much past its furthest slot
+_VIEW_WIDTH_STEPS = 8
+# the staircases of widths a step chooses from, before the whole table
+# (``view_profiles``), each one more copy of the switched layers to
+# compile. Over the 704 dispatches of a ruDALL-E window the furthest slots
+# stood 0 / 1 / 2 ladder steps out of the even staircase in 20 / 73 / 6%
+# of the chunks and further in none (PERF.md section 6, PR 38): three hold
+# them all
+_VIEW_PROFILES = 3
+# what the ONE switch of a described block's step may hand out of its
+# branches (``block_view_plan``): it is written and read once more every
+# step, 0.16 ms at the memory bandwidth
+_VIEW_SWITCH_BYTES = 64 << 20
 _TILE = (8, 128)    # a TPU tile: 8 rows of 128 lanes of 4-byte words
 
 
@@ -59,51 +84,329 @@ def _tile_of(dtype) -> Tuple[int, int]:
     return (_TILE[0] * max(4 // jnp.dtype(dtype).itemsize, 1), _TILE[1])
 
 
-def view_slot_groups(slots: int, columns: int, page_shape, dtype) -> int:
-    """The rule of the paged gather reads (``_read_in_slot_groups``): the
-    fewest equal groups of ``slots`` whose gathered pages, ONE buffer of
-    one layer (K's readers finish before V's gather starts,
-    ``_paged_gather_read``), stay under ``_VIEW_VMEM_BYTES``; one slot a
-    group where no divisor fits. It reads what a trace sees and nothing
-    else: the slots, the table's ``columns``, a page's shape (rows, width:
-    ``kv_pool.page_layout``'s, the same in every block) and the pool's
-    dtype. The bytes are counted AS LAID OUT: the width is filled to whole
-    128-lane tiles (the classic block's ``heads * dim_head`` is whole
-    tiles at every published head size) and the rows to whole tiles of 8
-    words (16 bf16 rows, 32 int8 rows: the int8 pool's 16-row page takes
-    the room of the bf16 one)."""
+def _halving_pays(per: int, slots: int, slot_bytes: int) -> bool:
+    """Whether two groups of ``per`` / 2 read enough less than one of
+    ``per`` (of ``slots`` evenly staggered, ``slot_bytes`` a slot's whole
+    table): (per / 2) ** 2 / slots of a table."""
+    return per * per * slot_bytes > 4 * slots * _VIEW_GROUP_BYTES
+
+
+def view_slot_groups(slots: int, columns: int, page_shape, dtype,
+                     ordered: bool = True) -> int:
+    """The group rule of the paged gather reads (``_read_in_slot_groups``):
+    the fewest equal groups of ``slots`` that satisfy both of
+
+      * VMEM: a group's gathered pages, ONE buffer of one layer (K's
+        readers finish before V's gather starts, ``_paged_gather_read``),
+        stay under ``_VIEW_VMEM_BYTES`` at the table's full width; one
+        slot a group where no divisor fits;
+      * the ordering: a group is consecutive slots in the order of their
+        positions and reads the width its FURTHEST slot needs
+        (``view_profiles``). Of ``slots`` evenly staggered over the table
+        a group of n spans n / slots of it, and halved, its nearer half
+        stops n / 2 phases short: n / 2 slots x n / 2 x columns / slots
+        columns fewer. A group is halved while that saves more bytes than
+        the further group costs (``_VIEW_GROUP_BYTES``). (Not ``ordered``:
+        a table that is read whole in slot order, a window ring or a
+        sparse layer's visible columns, which VMEM alone decides.)
+
+    It reads what a trace sees and nothing else: the slots, the table's
+    ``columns``, a page's shape (rows, width: ``kv_pool.page_layout``'s,
+    the same in every block) and the pool's dtype. The bytes are counted
+    AS LAID OUT: the width is filled to whole 128-lane tiles (the classic
+    block's ``heads * dim_head`` is whole tiles at every published head
+    size) and the rows to whole tiles of 8 words (16 bf16 rows, 32 int8
+    rows: the int8 pool's 16-row page takes the room of the bf16 one)."""
     itemsize = jnp.dtype(dtype).itemsize
     filled = [-(-n // t) * t for n, t in zip(page_shape, _tile_of(dtype))]
     slot_bytes = columns * math.prod(filled) * itemsize
     return next((g for g in range(1, slots) if slots % g == 0
-                 and slots // g * slot_bytes <= _VIEW_VMEM_BYTES), slots)
+                 and slots // g * slot_bytes <= _VIEW_VMEM_BYTES
+                 and not (ordered and _halving_pays(
+                     slots // g, slots, slot_bytes))), slots)
 
 
-def pool_view_groups(pool: dict, slots: int, columns: int) -> int:
+def _rows_buffer(pool: dict) -> Array:
+    """The buffer of a page pool that decides its read's groups and
+    widths: a latent pool's one buffer, else the K rows."""
+    return pool["latent"] if "latent" in pool else pool["k"]
+
+
+def pool_view_groups(pool: dict, slots: int, columns: int,
+                     ordered: bool = True) -> int:
     """``view_slot_groups`` of a page pool read through a table of
     (slots, columns), trimmed as the read trims it."""
-    buf = pool["latent"] if "latent" in pool else pool["k"]
-    return view_slot_groups(slots, columns, buf.shape[2:], buf.dtype)
+    buf = _rows_buffer(pool)
+    return view_slot_groups(slots, columns, buf.shape[2:], buf.dtype,
+                            ordered)
 
 
-def _read_in_slot_groups(pool: dict, tables: Array, read) -> Array:
+def view_widths(columns: int) -> Tuple[int, ...]:
+    """The ladder of table widths a slot group may read, in columns
+    (pages), from the table's ``columns`` alone: ``_VIEW_WIDTH_STEPS``
+    equal steps, rounded up to whole pages, the last the whole table (a
+    table of fewer columns: one step a column)."""
+    steps = min(_VIEW_WIDTH_STEPS, columns)
+    return tuple(-(-columns * i // steps) for i in range(1, steps + 1))
+
+
+@functools.lru_cache(maxsize=None)     # (a trace and every chunk's count ask)
+def view_profiles(groups: int, columns: int) -> Tuple[Tuple[int, ...], ...]:
+    """The width profiles a step chooses from, from the shapes alone: a
+    profile gives each slot group (the slots in the order of ``pos``, the
+    group of the least first) its width in table columns. The first is
+    the staircase of evenly staggered slots, group g of n reading the
+    ladder's (``view_widths``) step that holds the first (g + 1) / n of
+    the table; each next one reads one step of the ladder further in
+    every group (slots behind a prompt, or not quite evenly spread);
+    ``_VIEW_PROFILES`` such staircases, then the whole table for every
+    group, which is the read without the rule. Each profile holds every
+    row that the one before it holds, so "the first that holds" is the
+    least."""
+    ladder = view_widths(columns)
+    top = len(ladder) - 1
+    base = [-(-(g + 1) * len(ladder) // groups) - 1 for g in range(groups)]
+    stairs = [tuple(ladder[min(top, at + shift)] for at in base)
+              for shift in range(_VIEW_PROFILES)]
+    # (a short ladder's last staircases are the whole table already)
+    return tuple(dict.fromkeys(stairs + [(columns,) * groups]))
+
+
+def view_profile_index(pos_sorted, groups: int, columns: int,
+                       page_size: int, xp=jnp):
+    """Which of ``view_profiles(groups, columns)`` a step reads:
+    ``pos_sorted`` (..., slots) the slots' positions in ascending order, a
+    group ``slots // groups`` consecutive ones -> the index (...) of the
+    first profile in which every group's width holds every row before the
+    group's furthest ``pos`` (rows 0 .. pos - 1 lie in its first ``ceil(pos
+    / page_size)`` columns; the token's own row is the read's self logit,
+    stored after the layers). The last profile, the whole table, holds
+    any. A parked slot (``pos`` 0) is first in the order and fits the
+    narrowest width. Pure, and the same on the device (``xp`` jnp, once a
+    step) and on the host (numpy, every step of a chunk at once:
+    ``ViewPlan.columns_read``, the engine's counter)."""
+    per = pos_sorted.shape[-1] // groups
+    furthest = pos_sorted[..., per - 1::per]
+    live = xp.minimum((furthest + page_size - 1) // page_size, columns)
+    widths = xp.asarray(view_profiles(groups, columns))     # (profiles, n)
+    holds = xp.all(widths >= live[..., None, :], axis=-1)
+    return xp.argmax(holds, axis=-1)
+
+
+class ViewPlan(NamedTuple):
+    """A decode step's gather reads of the pool whose rows lie in order
+    (the classic pool, a latent pool, a full pool), as shapes: the table
+    of ``columns`` pages of ``page_size`` rows a slot, trimmed to the
+    sequence (a window pool's ring is read whole, all of it live once it
+    has wrapped, and is no part of this); ``groups`` of ``slots`` in the
+    order of ``pos`` (``view_slot_groups``); ``by_rule`` layers of a step
+    read it at the step's width profile and ``whole`` layers at its full
+    width; ``span``, in a described block, the scans that ONE switch a
+    step stands around (``block_view_plan``: None where each read
+    switches for itself). What the step reads by it, the host counts from
+    it (``columns_read``)."""
+    slots: int
+    columns: int
+    page_size: int
+    groups: int
+    by_rule: int
+    whole: int = 0
+    span: Optional[Tuple[int, int]] = None
+
+    def columns_read(self, pos, steps: int = 1) -> Tuple[int, int]:
+        """The host's evaluation of the width rule over a chunk: from the
+        positions ``pos`` (numpy, slot order; 0 for a free slot, which
+        stays parked) of its first step, a live slot one further each of
+        its ``steps`` -> (table columns the chunk's reads gather, summed
+        over layers and steps, what they would gather at full width)."""
+        pos = np.asarray(pos)
+        at = np.minimum(pos + np.arange(steps)[:, None] * (pos > 0),
+                        self.columns * self.page_size)
+        profile = view_profile_index(np.sort(at, axis=1), self.groups,
+                                     self.columns, self.page_size, xp=np)
+        widths = np.asarray(view_profiles(self.groups, self.columns))
+        full = steps * self.slots * self.columns
+        narrowed = self.slots // self.groups * int(
+            widths.sum(axis=1)[profile].sum())
+        return (self.by_rule * narrowed + self.whole * full,
+                (self.by_rule + self.whole) * full)
+
+
+def _nbytes(a) -> int:
+    return math.prod(a.shape) * jnp.dtype(a.dtype).itemsize
+
+
+def _table_of(pool: dict, slots: int, total_len: int):
+    """(columns, page_size, groups) of the ordered pool's table through
+    ``slots``, trimmed to ``total_len`` rows."""
+    buf = _rows_buffer(pool)
+    columns = -(-total_len // buf.shape[2])     # pages_for
+    return columns, buf.shape[2], view_slot_groups(
+        slots, columns, buf.shape[2:], buf.dtype)
+
+
+def paged_view_plan(cfg, params: dict, pool: dict, slots: int,
+                    total_len: int) -> Optional[ViewPlan]:
+    """The plan of the gather step's reads of ``pool``, whichever block
+    ``cfg`` has (what ``decode_loop_paged`` will run; the engine's
+    counters read it)."""
+    if cfg.block is None:       # every layer of the one scan reads at the
+        # step's profile (``_decode_step_math``: one switch around the scan)
+        return ViewPlan(slots, *_table_of(pool, slots, total_len), cfg.depth)
+    return block_view_plan(cfg, params, pool, slots, total_len)
+
+
+def block_view_plan(cfg, params: dict, pool: dict, slots: int,
+                    total_len: int) -> Optional[ViewPlan]:
+    """Where a described block's step switches to its width profile
+    (``decode_step_block``), from shapes alone; None for a block without
+    an ordered pool.
+
+    A conditional costs the chip 14-24 us at its edges (PERF.md section
+    6, PR 38), so ONE stands around the span of scans from the first to
+    the last that holds a layer reading the ordered pool, and every such
+    layer reads at the profile: what the branches close over they are
+    handed in place, but what they write the compiler hands OUT of them,
+    and so it does the routed experts' whole stacks, which a layer reads
+    by its index (``block_stack``). Where those and the recurrent state
+    that the span's layers write pass ``_VIEW_SWITCH_BYTES`` (kanana: 7.25
+    GB of experts, the program no longer fits the chip; the state of
+    phi's nine state-space layers, 94 MB, cost 0.5 ms a step around the
+    whole stack), each read of a SCANNED run switches for itself and a
+    run of one layer, which runs in the step's own body next to the
+    pool's store, reads whole (a conditional over the pool there made
+    phi's program copy the pool)."""
+    from dalle_pytorch_tpu.ops import transformer as T
+    blk = cfg.block
+    if "latent" not in pool and "k" not in pool:
+        return None
+    scans = T.stack_scans(blk, cfg.depth)
+    reading = [i for i, scan in enumerate(scans)
+               if any(run.kind.pool == "full" for run in scan)]
+    first, stop = reading[0], reading[-1] + 1
+    inside = [run for scan in scans[first:stop] for run in scan]
+    experts = {blk.stack_of(run.kind) for run in inside if run.moe}
+    handed = sum(_nbytes(leaf) for stack in experts for leaf in
+                 jax.tree.leaves(params[stack]["ff"]["experts"]))
+    handed += sum(run.count * _nbytes(pool[name]) // pool[name].shape[0]
+                  for run in inside if run.kind.pool == "state"
+                  for name in blk.pool_buffers("state"))
+    readers = [run for run in inside if run.kind.pool == "full"]
+    table = _table_of(pool, slots, total_len)
+    if handed <= _VIEW_SWITCH_BYTES:
+        return ViewPlan(slots, *table, sum(run.count for run in readers),
+                        span=(first, stop))
+    return ViewPlan(
+        slots, *table,
+        by_rule=sum(run.count for run in readers if run.count > 1),
+        whole=sum(run.count for run in readers if run.count == 1))
+
+
+class _View(NamedTuple):
+    """One table of a step's paged gather reads as ``_read_in_slot_groups``
+    takes it. ``tables`` (b, columns) lie in READ order: the slots' order
+    by position (``order`` (b,): which slot a read row is; ``inverse`` the
+    way back) with ``widths`` the static width in columns of each slot
+    group (a profile of ``view_profiles``), or, where all three are None,
+    slot order at full width (a sparse layer's visible columns, which lie
+    in no order of ``pos``; a window ring). ``window`` names the work in
+    a trace."""
+    tables: Array
+    order: Optional[Array] = None
+    inverse: Optional[Array] = None
+    widths: Optional[Tuple[int, ...]] = None
+    window: bool = False
+
+    def ordered(self, *xs):
+        """Per-slot arrays (b, ...) brought into read order."""
+        if self.order is None:
+            return xs
+        with attn_ops._read_scope(self.window):
+            return tuple(x[self.order] for x in xs)
+
+
+def _slot_order(pos: Array) -> Tuple[Array, Array]:
+    """The slots in ascending order of ``pos`` (b,), ties in slot order
+    -> (order (b,): the slot at each place, inverse (b,): each slot's
+    place). By counting, b x b comparisons: cheaper on the chip than two
+    sorts of at most a few dozen numbers."""
+    i = jnp.arange(pos.shape[0])
+    ahead = (pos[None, :] < pos[:, None]) | (
+        (pos[None, :] == pos[:, None]) & (i[None, :] < i[:, None]))
+    inverse = jnp.sum(ahead, axis=1)
+    order = jnp.sum(jnp.where(inverse[None, :] == i[:, None], i[None, :],
+                              0), axis=1)
+    return order, inverse
+
+
+def _width_profile(pool: dict, columns: int, pos_sorted: Array):
+    """The step's width profile, once a step, outside the layer scan: ->
+    (the index (int32 scalar) of the narrowest of ``view_profiles`` that
+    holds the slots' rows (``view_profile_index``), the profiles) for the
+    reads of ``pool``'s table of ``columns`` through the slots in the
+    order of ``pos``."""
+    groups = pool_view_groups(pool, pos_sorted.shape[0], columns)
+    with jax.named_scope("kv.view"):
+        at = view_profile_index(
+            pos_sorted, groups, columns,
+            _rows_buffer(pool).shape[2]).astype(jnp.int32)
+    return at, view_profiles(groups, columns)
+
+
+def _by_width_profile(profile, run):
+    """``run(widths)`` at the step's width profile (``_width_profile``'s):
+    ONE ``lax.switch`` over the static profiles, every branch the same
+    computation with the paged reads at its own per-group ``widths``.
+    What a narrower profile drops is masked in the widest, so every
+    branch computes the same softmax from the rows that count.
+
+    A conditional costs the chip 14-24 us of waiting at its edges
+    (PERF.md section 6, PR 38: one a slot group a layer, 96 a step, took
+    2.1 of ruDALL-E's 11.6 ms and lost what the narrower reads won), so
+    there are as few as the program's shape allows: the classic step
+    switches its whole layer scan, once a step (what a branch hands out,
+    the new rows, is small); a described block switches the scans that
+    read the ordered pool the same way, or one layer's read at a time
+    where what they would hand out is too much (``block_view_plan``)."""
+    at, profiles = profile
+    return lax.switch(at, [functools.partial(run, widths)
+                           for widths in profiles])
+
+
+def _read_in_slot_groups(pool: dict, view: _View, read) -> Array:
     """The one slot-group loop of the paged gather reads, the classic
-    block's and a described block's: a layer's gathered pages stay in VMEM
-    between the gather and the contractions that read them only if they
-    fit it, and what does not fit is written to HBM and read back by each
-    contraction (three crossings of every page where one is needed). So
-    ``read(sl)``, which gathers and attends the slots of the slice ``sl``
-    (``tables[sl]``, ``q[sl]``, ...), runs once a group of
-    ``pool_view_groups`` and the outputs are concatenated along slots. A
+    block's and a described block's. Two things decide what a group
+    reads.
+
+    VMEM: a layer's gathered pages stay in VMEM between the gather and
+    the contractions that read them only if they fit it, and what does
+    not fit is written to HBM and read back by each contraction (three
+    crossings of every page where one is needed).
+
+    The rows that are written: the gather runs at the memory bandwidth,
+    so its time is the columns it reads, and the rows past a slot's
+    ``pos`` are masked in every layer. The view's rows lie in the order
+    of ``pos``, so a group's slots are about as far along as each other,
+    and the group reads ``view.widths[g]`` columns, the step's profile
+    (``_by_width_profile``): ``read(sl, w)`` gathers and attends the read
+    rows of the slice ``sl`` through ``view.tables[sl, :w]`` under
+    ``allowed[sl, :w * page_size]``.
+
+    ``read`` runs once a group of ``pool_view_groups``; the outputs are
+    concatenated along the read rows and brought back to slot order. A
     slot's result is computed from the same rows in the same dtype
     whichever slots share its group (on the chip, bit-equal under a plain
     ``jit``; between two whole engine programs the compiler may still
     round a layer's output in another place: PERF.md section 6, PR 31)."""
-    slots = tables.shape[0]
-    groups = pool_view_groups(pool, *tables.shape)
+    slots, columns = view.tables.shape
+    groups = pool_view_groups(pool, slots, columns, view.order is not None)
     per = slots // groups
-    outs = [read(slice(g * per, (g + 1) * per)) for g in range(groups)]
-    return outs[0] if groups == 1 else jnp.concatenate(outs)
+    widths = view.widths or (columns,) * groups
+    outs = [read(slice(g * per, (g + 1) * per), widths[g])
+            for g in range(groups)]
+    with attn_ops._read_scope(view.window):
+        out = outs[0] if groups == 1 else jnp.concatenate(outs)
+        return out if view.inverse is None else out[view.inverse]
 
 
 def _refuse_block(cfg, option: str, why: str = "") -> None:
@@ -360,10 +663,11 @@ def layer_pool_view(buf: Array, layer: Array, tables: Array,
     latent pool's ``latent``, a grouped-query pool's rows); ``layer`` a
     traced scalar, tables (b, w) -> (b, w, ps, row). The one per-layer
     view of BOTH step maths and of a described block's step: the full
-    table trimmed to ``ceil(total_len / ps)`` columns, or a sparse layer's
-    visible slice of it, always the rows of ONE slot group
-    (``_read_in_slot_groups`` decides the groups), so that the pages stay
-    in VMEM from this gather to the contraction that reads them.
+    table trimmed to ``ceil(total_len / ps)`` columns and then to the
+    width its slot group reads, or a sparse layer's visible slice of it,
+    always the rows of ONE slot group (``_read_in_slot_groups`` decides
+    the groups and their widths), so that the pages stay in VMEM from
+    this gather to the contraction that reads them.
 
     Three choices keep this a gather of whole pages and nothing else,
     each read off the compiled TPU program (PERF.md, PR 25):
@@ -408,8 +712,10 @@ def _paged_gather_read(pool: dict, layer: Array, tables: Array, q: Array,
                        v_after_k: bool = False, mesh: bool = False) -> Array:
     """``_gather_read`` for ONE slot group over the classic block's page
     pool: tables (b, w) into the K/V pool, q/k/v (b, h, 1, dh), allowed
-    (b, rows) with rows <= w * ps (logical row j is page j // ps, offset
-    j % ps; a partial last page's tail rows are dead). A page is whole
+    (b, rows) (logical row j is page j // ps, offset j % ps; a partial
+    last page's tail rows are dead; where the group reads fewer columns
+    than the mask has rows for, a narrower width of its step's profile,
+    the first w * ps rows count). A page is whole
     rows, ``(ps, heads * dh)``, so the gathered pages ARE the slot's rows
     in logical order (``_gathered_rows``: a bitcast), and the read is the
     grouped-query one at ``kv_heads == heads``
@@ -426,10 +732,10 @@ def _paged_gather_read(pool: dict, layer: Array, tables: Array, q: Array,
     ``mesh`` (whether the step was handed ``out_sync``) asks for the same
     rows read per head: with the pool's row sharded over the heads a
     whole-row contraction would sum partial scores across chips."""
-    ps = pool["k"].shape[2]
+    rows = tables.shape[1] * pool["k"].shape[2]
     with jax.named_scope("attn.read"):    # a partial last page's tail
-        allowed = jnp.pad(allowed, ((0, 0), (
-            0, tables.shape[1] * ps - allowed.shape[1])))
+        allowed = jnp.pad(allowed[:, :rows], ((0, 0), (
+            0, max(rows - allowed.shape[1], 0))))
     scales = {}
     if "k_scale" in pool:
         scales = dict(
@@ -445,21 +751,29 @@ def _paged_gather_read(pool: dict, layer: Array, tables: Array, q: Array,
     return out[:, :, None, :]
 
 
-def _paged_gather_attend(pool: dict, layer: Array, tables: Array,
+def _paged_gather_attend(pool: dict, layer: Array, view,
                          q: Array, k: Array, v: Array, allowed: Array, *,
                          scale: float, mesh: bool = False) -> Array:
     """One layer's paged gather read of the classic block, whole:
-    ``_paged_gather_read`` over the slots of ``tables`` (b, w), a slot
-    group at a time (``_read_in_slot_groups`` decides the groups from the
-    shapes; ``mesh``, whether the step was handed ``out_sync``, the form
-    of a group's two contractions). q/k/v (b, h, 1, dh), allowed (b,
-    rows) -> (b, h, 1, dh) BEFORE out_sync/out-projection."""
-    def read(sl):
-        t = tables[sl]
+    ``_paged_gather_read`` over the slots of ``view`` (a ``_View``: the
+    step's tables in the order of ``pos``, or plain tables (b, w), read
+    in slot order at full width), a slot group at a time
+    (``_read_in_slot_groups`` decides the groups from the shapes and each
+    group's width from the view; ``mesh``, whether the step was handed
+    ``out_sync``, the form of a group's two contractions). q/k/v (b, h,
+    1, dh) in slot order, allowed (b, rows) in the view's order -> (b, h,
+    1, dh) BEFORE out_sync/out-projection."""
+    if not isinstance(view, _View):
+        view = _View(view)
+    several = pool_view_groups(pool, *view.tables.shape,
+                               view.order is not None) > 1
+    q, k, v = view.ordered(q, k, v)
+
+    def read(sl, w):
         return _paged_gather_read(
-            pool, layer, t, q[sl], k[sl], v[sl], allowed[sl], scale=scale,
-            v_after_k=t.shape[0] < tables.shape[0], mesh=mesh)
-    return _read_in_slot_groups(pool, tables, read)
+            pool, layer, view.tables[sl, :w], q[sl], k[sl], v[sl],
+            allowed[sl], scale=scale, v_after_k=several, mesh=mesh)
+    return _read_in_slot_groups(pool, view, read)
 
 
 def _attn_with_kv(lp: dict, h: Array, allowed: Array, cfg,
@@ -698,30 +1012,38 @@ def _decode_step_math(params: dict, x_tok: Array, pos: Array, cache: dict,
         if block_tables is None:
             raise ValueError("attn_impl='kernel' requires block_tables")
     paged_gather = block_tables is not None and not kernel_mode
+    at, valid = pos, key_mask       # the masks' positions and rows' validity
     if paged_gather:
-        view_tables = _view_tables(block_tables, total_len,
-                                   cache["k"].shape[2])
+        # the gather reads go through the slots in the order of ``pos``
+        # (``_read_in_slot_groups``): the tables and the masks are laid
+        # in that order here, once a step
+        with jax.named_scope("attn.read"):
+            order, inverse = _slot_order(jnp.broadcast_to(pos, (b,)))
+            at, valid = pos[order] if per_slot else pos, key_mask[order]
+        with jax.named_scope("kv.view"):
+            tables = _view_tables(block_tables, total_len,
+                                  cache["k"].shape[2])[order]
 
     with jax.named_scope("attn.read"):       # the masks
         j = jnp.arange(total_len)
         # strictly-before rows; self added as the concatenated extra logit
-        causal_ok = (j[None, :] < pos[:, None]) if per_slot \
-            else (j < pos)[None, :]
-        dense_allowed = causal_ok & key_mask                     # (b, L)
+        causal_ok = (j[None, :] < at[:, None]) if per_slot \
+            else (j < at)[None, :]
+        dense_allowed = causal_ok & valid                        # (b, L)
         if any_sparse:
             layout = _sparse_layout(cfg, total_len)
             if per_slot:
-                row = jnp.take(layout, pos, axis=0)              # (b, L)
+                row = jnp.take(layout, at, axis=0)               # (b, L)
                 sparse_allowed = dense_allowed & row
             else:
-                row = lax.dynamic_slice(layout, (pos, 0), (1, total_len))[0]
+                row = lax.dynamic_slice(layout, (at, 0), (1, total_len))[0]
                 sparse_allowed = dense_allowed & row[None, :]
         else:
             sparse_allowed = dense_allowed
 
     h_in = x_tok[:, None, :]                                  # (b, 1, dim)
 
-    def attn_cached(lp, h, kv, is_sparse):
+    def attn_cached(lp, h, kv, is_sparse, widths):
         p = lp["attn"]
         hn = core.layernorm(p["ln"], h)
         q, k, v = attn_ops.qkv_project(p, hn, cfg.heads)      # (b, h, 1, dh)
@@ -731,9 +1053,9 @@ def _decode_step_math(params: dict, x_tok: Array, pos: Array, cache: dict,
         if paged_gather:
             # kv is this layer's INDEX: gather its pages from the pool
             # and contract them as they lie
-            out = _paged_gather_attend(cache, kv, view_tables, q, k, v,
-                                       allowed, scale=cfg.scale,
-                                       mesh=out_sync is not None)
+            out = _paged_gather_attend(
+                cache, kv, _View(tables, order, inverse, widths), q, k, v,
+                allowed, scale=cfg.scale, mesh=out_sync is not None)
         elif kernel_mode:
             # kv is the raw page pool for this layer; the kernel walks
             # the block tables in place (_kernel_read completes the
@@ -755,16 +1077,16 @@ def _decode_step_math(params: dict, x_tok: Array, pos: Array, cache: dict,
             out = out_sync(out)
         return attn_ops.output_tail(p, out), k, v
 
-    def body(carry, xs):
+    def body(widths, carry, xs):
         lp, kv, is_sparse = xs
         if cfg.reversible:
             x1, x2 = carry
-            a, k, v = attn_cached(lp, x2, kv, is_sparse)
+            a, k, v = attn_cached(lp, x2, kv, is_sparse, widths)
             y1 = x1 + a
             y2 = x2 + T.ff_or_moe(lp, y1, cfg, None, False)[0]
             return (y1, y2), (k, v)
         h = carry
-        a, k, v = attn_cached(lp, h, kv, is_sparse)
+        a, k, v = attn_cached(lp, h, kv, is_sparse, widths)
         h = h + a
         h = h + T.ff_or_moe(lp, h, cfg, None, False)[0]
         return h, (k, v)
@@ -775,7 +1097,15 @@ def _decode_step_math(params: dict, x_tok: Array, pos: Array, cache: dict,
     # so the layer gets its index and reads the pool itself
     xs = (params, jnp.arange(cfg.depth) if paged_gather else cache,
           sparse_flags)
-    carry, (ks, vs) = lax.scan(body, carry0, xs)
+
+    def layers(widths):
+        return lax.scan(functools.partial(body, widths), carry0, xs)
+
+    if paged_gather:
+        carry, (ks, vs) = _by_width_profile(_width_profile(
+            cache, tables.shape[1], jnp.broadcast_to(at, (b,))), layers)
+    else:
+        carry, (ks, vs) = layers(None)
     h_out = (carry[0] + carry[1]) * 0.5 if cfg.reversible else carry
 
     return h_out[:, 0, :], ks, vs
@@ -950,11 +1280,16 @@ def _decode_step_math_sparse_reads(params: dict, x_tok: Array, pos: Array,
 # matrix unit, the described blocks' grouped-query read at kv_heads ==
 # heads); the gathered pages are the slot's rows in logical order, so the
 # softmax is the dense step's and paged-vs-dense tokens are equal. The
-# slots are read a group at a time, so that a group's gathered pages stay
-# in VMEM between the gather and its readers; the groups are
-# decided from the shapes in ONE place (``view_slot_groups``, looped by
-# ``_read_in_slot_groups``) for this step and a described block's
-# (``decode_step_block``). The new row is written by in-place row updates
+# slots are read a group at a time, in the order of their positions, so
+# that a group's gathered pages stay in VMEM between the gather and its
+# readers and a group reads little further into its table than its
+# furthest slot has written: the step's layers run at the narrowest of a
+# few static profiles of widths (``view_profiles``) that holds every
+# group's rows. The groups and the profiles are decided from the shapes
+# and the step's profile from ``pos`` in ONE place (``view_slot_groups``,
+# ``view_profile_index``; ``_by_width_profile`` switches the layer stack,
+# ``_read_in_slot_groups`` loops the groups) for this step and a
+# described block's (``decode_step_block``). The new row is written by in-place row updates
 # (``_store_entries_paged``), so the pool keeps one layout, a page one
 # contiguous run, through the whole chunk: the compiled program holds no
 # buffer of the pool's size besides the pool (tests/test_paged_attention.py
@@ -966,10 +1301,12 @@ def _decode_step_math_sparse_reads(params: dict, x_tok: Array, pos: Array,
 # position j, so ``_decode_step_math`` over it is literally the dense
 # step. The tests hold the per-layer read, the kernel and the engines to
 # it; the speculative verify (``decode_loop_spec_paged``) reads through it,
-# having no per-layer page-major wide read. The gather reads
-# every page of the trimmed table whether live or not; the HBM win of
-# paging is *residency* — the pool can be far smaller than num_slots x
-# total_len. ``attn_impl='kernel'`` reads only each slot's LIVE pages: the
+# having no per-layer page-major wide read. The gather reads the
+# table's columns up to its slot group's width, live or not (what lies
+# between a slot's ``pos`` and its group's width is read and masked); the
+# HBM win of paging is *residency* — the pool can be far smaller than
+# num_slots x total_len. ``attn_impl='kernel'`` reads only each slot's
+# LIVE pages: the
 # Pallas ragged paged-attention kernel (ops/paged_attention.py) consumes
 # the block tables in place, HBM->VMEM.
 
@@ -1151,9 +1488,12 @@ def _block_reads(cfg, pool: dict, block_tables: dict, pos: Array,
     """The reads of a described block's decode step, one query a slot
     (``block_tables``: a table a page pool, ``{"full": ..., "window":
     ...}``): ->
-    ``read_of(layer, run)``, which gives ``block_layer`` its read for the
-    layer ``layer`` (traced, its index in the cache it reads) of the run
-    ``run``. A paged read gathers its layer's pages through the tables
+    (``read_of(layer, run, widths)``, which gives ``block_layer`` its read
+    for the layer ``layer`` (traced, its index in the cache it reads) of
+    the run ``run``, at the static per-group ``widths`` of the profile
+    that the caller switched the layer's scan to, or None; the step's
+    width profile for the ordered pool, ``_width_profile``'s, or None). A
+    paged read gathers its layer's pages through the tables
     (``layer_pool_view``), a slot group at a time (the groups are decided
     by ``view_slot_groups``, the classic step's rule, and looped by
     ``_read_in_slot_groups``), and contracts them as they lie; a layer
@@ -1164,53 +1504,92 @@ def _block_reads(cfg, pool: dict, block_tables: dict, pos: Array,
     from dalle_pytorch_tpu.ops import transformer as T
     blk = cfg.block
     total_len = key_mask.shape[1]
+    # the reads of the pool whose rows lie in order go through the slots
+    # in the order of ``pos``, at the step's width profile
+    # (``_by_width_profile``): its table and mask are laid in that order
+    # and the profile is chosen here, once a step. A window pool's ring
+    # stays in slot order, whole (all of it is live once it has wrapped),
+    # and so does the ordered pool for a layer that is no scan's (a run of
+    # ONE layer runs in the step's own body)
+    with jax.named_scope("attn.read"):
+        order, inverse = _slot_order(pos)
 
-    def before(tables, ps):
-        """(b, w * ps) rows strictly before ``pos`` (self is each read's
-        own extra logit); rows past total_len on a partial last page are
-        dead."""
-        rows_len = tables.shape[1] * ps
-        return (jnp.arange(rows_len)[None, :] < pos[:, None]) & jnp.pad(
-            key_mask, ((0, 0), (0, rows_len - total_len)))
+    def full_views():
+        """The ordered pool's table as its reads take it, {scanned: (view,
+        its rows strictly before ``pos`` (b, w * ps): self is each read's
+        own extra logit; rows past total_len on a partial last page are
+        dead; the step's profile)}: for a scanned run's layer in the
+        order of ``pos``, for a lone layer in slot order with no
+        profile."""
+        ps = _rows_buffer(pool).shape[2]
+        with jax.named_scope("kv.view"):
+            tables = _view_tables(block_tables["full"], total_len, ps)
+            views = {False: _View(tables),
+                     True: _View(tables[order], order, inverse)}
+        out = {}
+        for scanned, view in views.items():
+            with jax.named_scope("attn.read"):
+                at, valid = (pos[order], key_mask[order]) if scanned \
+                    else (pos, key_mask)
+                rows_len = tables.shape[1] * ps
+                allowed = (jnp.arange(rows_len)[None, :] < at[:, None]) \
+                    & jnp.pad(valid, ((0, 0), (0, rows_len - total_len)))
+            out[scanned] = (view, allowed, _width_profile(
+                pool, tables.shape[1], at) if scanned else None)
+        return out
+    full = full_views() if "latent" in pool or "k" in pool else None
+
+    def at_profile(profile, widths, rows, view, read_group):
+        """The read at the step's profile: whole in slot order where the
+        view has none; at ``widths`` where the layer's scan was switched
+        (``decode_step_block``); else its own switch."""
+        if profile is None or widths is not None:
+            return _read_in_slot_groups(
+                rows, view._replace(widths=widths), read_group)
+        return _by_width_profile(
+            profile, lambda widths: _read_in_slot_groups(
+                rows, view._replace(widths=widths), read_group))
+
+    def full_view(run, widths):
+        """A layer's view of the ordered pool (``block_view_plan``): in
+        the order of ``pos`` at its switched scan's ``widths``, or with a
+        switch of its own inside a scanned run; a lone layer outside any
+        switch whole in slot order."""
+        return full[widths is not None or run.count > 1]
 
     if isinstance(blk, T.LatentMoEBlock):
         ps = pool["latent"].shape[2]
-        tables = _view_tables(block_tables["full"], total_len, ps)
-        with jax.named_scope("attn.read"):       # the mask
-            allowed = before(tables, ps)
 
-        def read_of(layer, _run):
+        def read_of(layer, run, widths=None):
+            view, allowed, profile = full_view(run, widths)
+
             def read(p, query, entry):
                 # all slots' pages at once miss VMEM at the published
                 # widths (178 MB), so they are read a slot group at a time
-                def read_group(sl):
+                q_nope, q_rope, own = view.ordered(*query, entry)
+
+                def read_group(sl, w):
                     return attn_ops.latent_attend_absorbed(
-                        p, query[0][sl], query[1][sl],
-                        _gathered_rows(pool["latent"], layer, tables[sl]),
-                        allowed[sl],
-                        entry[sl], blk, cfg.scale)
-                return _read_in_slot_groups(pool, tables, read_group)
+                        p, q_nope[sl], q_rope[sl],
+                        _gathered_rows(pool["latent"], layer,
+                                       view.tables[sl, :w]),
+                        allowed[sl, :w * ps], own[sl], blk, cfg.scale)
+                return at_profile(profile, widths, pool, view, read_group)
             return read
-        return read_of
+        return read_of, full[True][2]
 
     # pools of whole K and V rows: a pool and a table a layer type. A full
     # layer's table is as wide as the sequence and its rows lie in order;
     # a window layer's is a ring of ``ring_pages`` columns
-    by_type = {}
-    if "k" in pool:
-        ps = pool["k"].shape[2]
-        full_t = _view_tables(block_tables["full"], total_len, ps)
-        with jax.named_scope("attn.read"):
-            by_type[True] = (full_t, before(full_t, ps))
     if "window_k" in pool:
         ring_t = block_tables["window"]
         with jax.named_scope("attn.window"):
             held, ring_ok = window_rows(
                 pos, ring_t.shape[1] * pool["window_k"].shape[2], blk.window)
-            by_type[False] = (ring_t,
-                              ring_ok & ring_key_mask(key_mask, held))
+            ring = (_View(ring_t, window=True),
+                    ring_ok & ring_key_mask(key_mask, held), None)
 
-    def read_of(layer, run):
+    def read_of(layer, run, widths=None):
         if run.kind.pool is None:
             return None             # a layer that reads no cache
         if run.kind.pool == "state":
@@ -1223,25 +1602,32 @@ def _block_reads(cfg, pool: dict, block_tables: dict, pos: Array,
             return advance
         window = not run.full
         k_name, v_name = blk.pool_buffers(run.kind.pool)
-        tables, allowed = by_type[run.full]
-        view = {"k": pool[k_name]}      # what decides the slot groups
+        if window:
+            view, allowed, profile = ring
+            widths = None           # (a switched stack's ring all the same)
+        else:
+            view, allowed, profile = full_view(run, widths)
+        rows = {"k": pool[k_name]}      # what decides the slot groups
+        ps = pool[k_name].shape[2]
+        several = pool_view_groups(rows, *view.tables.shape,
+                                   view.order is not None) > 1
 
         def read(p, q, entry):
             lam = attn_ops.diff_lambda(p) if "lam" in p else None
+            q, own_k, own_v = view.ordered(q, *entry)
 
-            def read_group(sl):
-                t = tables[sl]
-                several = t.shape[0] < tables.shape[0]
+            def read_group(sl, w):
+                t = view.tables[sl, :w]
                 return attn_ops.gqa_attend_rows(
-                    q[sl], entry[0][sl], entry[1][sl],
+                    q[sl], own_k[sl], own_v[sl],
                     _gathered_rows(pool[k_name], layer, t, window),
                     lambda wts: _gathered_rows(
                         pool[v_name], layer, t, window,
                         after=wts if several else None),
-                    allowed[sl], cfg.scale, window, diff_lam=lam)
-            return _read_in_slot_groups(view, tables, read_group)
+                    allowed[sl, :w * ps], cfg.scale, window, diff_lam=lam)
+            return at_profile(profile, widths, rows, view, read_group)
         return read
-    return read_of
+    return read_of, full[True][2] if full else None
 
 
 def _store_block_rows(cfg, pool: dict, entries: dict, pos: Array,
@@ -1288,13 +1674,20 @@ def decode_step_block(params: dict, x_tok: Array, pos: Array, pool: dict,
     from dalle_pytorch_tpu.ops import transformer as T
     if not isinstance(block_tables, dict):      # one pool: its one table
         block_tables = {"full": block_tables}
-    read_of = _block_reads(cfg, pool, block_tables, pos, key_mask)
+    read_of, profile = _block_reads(cfg, pool, block_tables, pos, key_mask)
 
-    def layer_fn(lp, h, shared, layer, run):
-        return T.block_layer(lp, h, shared, pos, read_of(layer, run), cfg,
-                             run)
+    def layer_fn(lp, h, shared, layer, run, widths=None):
+        return T.block_layer(lp, h, shared, pos, read_of(layer, run, widths),
+                             cfg, run)
 
-    h_out, entries, loads = T.block_stack(params, x_tok, layer_fn, cfg)
+    # the one switch of the step, around the scans whose layers read the
+    # ordered pool, where ``block_view_plan`` has one; else ``read_of``
+    # switches a scanned run's reads one by one
+    plan = block_view_plan(cfg, params, pool, x_tok.shape[0],
+                           key_mask.shape[1])
+    span = None if plan is None or plan.span is None else (
+        *plan.span, functools.partial(_by_width_profile, profile))
+    h_out, entries, loads = T.block_stack(params, x_tok, layer_fn, cfg, span)
     return (h_out, _store_block_rows(cfg, pool, entries, pos, block_tables,
                                      active), jnp.sum(loads, axis=0))
 

@@ -854,7 +854,7 @@ def block_ff(p: dict, x: Array, blk, moe: bool):
     return out, load
 
 
-def block_stack(params: dict, h: Array, layer_fn, cfg):
+def block_stack(params: dict, h: Array, layer_fn, cfg, span=None):
     """The non-uniform stack: one scan a pattern of layers repeated
     (``stack_scans``: at period 1 a run of layers alike), in the published
     order. ``layer_fn(lp, h, shared, layer, run) -> (h, shared, (entry,
@@ -862,7 +862,14 @@ def block_stack(params: dict, h: Array, layer_fn, cfg):
     reads (``run.cache`` + the index in the run) and ``run`` static. ->
     (h, entries: ``{buffer: what the layers that store to it gave, stacked
     over them}`` for every buffer of ``blk.pools``, loads stacked over the
-    whole depth)."""
+    whole depth).
+
+    ``span`` ``(first, stop, around)`` runs the scans ``first`` up to
+    ``stop`` as ONE function of a static ``choice``, which ``around``
+    calls (under a ``lax.switch``: a decode step's width profile,
+    ops/decode.py ``decode_step_block``); their layers get
+    ``layer_fn(..., choice)``, and what comes out of ``around`` is the
+    stream, what the layers share, and those scans' entries and loads."""
     blk = cfg.block
 
     def member(run: LayerRun):
@@ -877,7 +884,7 @@ def block_stack(params: dict, h: Array, layer_fn, cfg):
         whole_stack = run.count == jax.tree.leaves(sub)[0].shape[0]
         return sub, whole, whole_stack
 
-    def scan_pattern(carry, scan):
+    def scan_pattern(carry, scan, layer_fn):
         members = [member(run) for run in scan]
 
         def body(carry, xs):
@@ -911,11 +918,25 @@ def block_stack(params: dict, h: Array, layer_fn, cfg):
         return parts[0] if len(parts) == 1 else jax.tree.map(
             lambda *a: jnp.concatenate(a), *parts)
 
-    carry = (h, blk.carried(h))
+    def scan_all(carry, scans, layer_fn):
+        outs = []
+        for scan in scans:
+            carry, out = scan_pattern(carry, scan, layer_fn)
+            outs.append(out)
+        return carry, outs
+
+    scans = stack_scans(blk, cfg.depth)
+    first, stop, around = span or (len(scans), len(scans), None)
+    carry, outs = scan_all((h, blk.carried(h)), scans[:first], layer_fn)
+    if stop > first:
+        carry, inside = around(lambda choice: scan_all(
+            carry, scans[first:stop],
+            lambda *layer: layer_fn(*layer, choice)))
+        carry, after = scan_all(carry, scans[stop:], layer_fn)
+        outs += inside + after
     stored, loads = {}, []
-    for scan in stack_scans(blk, cfg.depth):
-        carry, outs = scan_pattern(carry, scan)
-        for run, (entry, load) in zip(scan, outs):
+    for scan, out in zip(scans, outs):
+        for run, (entry, load) in zip(scan, out):
             loads.append(load)
             if run.kind.stores:
                 stored.setdefault(run.kind.pool, []).append(entry)

@@ -317,10 +317,10 @@ class _Chunk:
     not leak the old request's tokens into the new one."""
 
     __slots__ = ("ring", "active", "owners", "load", "chunk",
-                 "t_dispatch", "admits_ahead")
+                 "t_dispatch", "admits_ahead", "view_read_pct")
 
     def __init__(self, ring, active, owners, load=None, *, chunk,
-                 t_dispatch, admits_ahead):
+                 t_dispatch, admits_ahead, view_read_pct=None):
         self.ring = ring
         self.active = active
         self.owners = owners
@@ -331,6 +331,9 @@ class _Chunk:
         # the admission calls made since the previous dispatch: the
         # prefill programs in the device's queue in front of this chunk
         self.admits_ahead = admits_ahead
+        # of the table columns of its steps' gather reads of the ordered
+        # pool, the share the width rule read (None: no such reads)
+        self.view_read_pct = view_read_pct
 
 
 class _Row:
@@ -772,6 +775,13 @@ class Engine:
         self.kv_view_groups = 1         # slot groups a layer's paged gather
         #                                 read was traced with (ops.decode
         #                                 view_slot_groups; 1 = no such read)
+        # table columns that the dispatched chunks' gather reads of the
+        # ordered pool read, summed over layers and steps, and what they
+        # would have read at full width (``_view_columns``; 0 / 0 where no
+        # layer reads by the width rule)
+        self.kv_view_columns_read = 0
+        self.kv_view_columns_full = 0
+        self._view_plan = None  # ops.decode.ViewPlan, set where traced
         self._t_start = None
         self._last_log = 0
 
@@ -1014,9 +1024,16 @@ class Engine:
                 sample_fn=sample_fn, attn_impl=self.paged_attn,
                 out_sync=self._decode_out_sync())
         if self.paged_attn == "gather":
+            # what the step reads of the ordered pool, from the shapes
             # (the full layers' groups, of a window-and-full block)
-            self.kv_view_groups = decode_ops.pool_view_groups(
-                cache, self.num_slots, self.slot_max_pages)
+            plan = None if self.sparse_reads else decode_ops.paged_view_plan(
+                self.cfg.transformer, params["transformer"], cache,
+                self.num_slots, self.total_len)
+            self._view_plan = plan if plan and plan.by_rule else None
+            self.kv_view_groups = plan.groups if self._view_plan \
+                else decode_ops.pool_view_groups(
+                    cache, self.num_slots, self.slot_max_pages,
+                    ordered=False)
         return decode_ops.decode_loop_paged(
             params["transformer"], cur_tok, pos, active, cache,
             block_tables, cfg=self.cfg.transformer,
@@ -2203,6 +2220,7 @@ class Engine:
         self.cur_tok, self.pos, self.active, self.cache, ring, *load = outs
         owners = [(i, s) for i, s in enumerate(self.slots)
                   if s is not None]
+        view_read_pct = self._view_columns(owners)
         if self.kv == "paged":
             for i, _ in owners:
                 self._pos_est[i] = min(self._pos_est[i] + self._chunk_span,
@@ -2211,11 +2229,35 @@ class Engine:
             ring, self.active, owners, *load,
             chunk=self.decode_steps // self.chunk_steps,
             t_dispatch=self._lap("dispatch_s"),
-            admits_ahead=self._admits_ahead))
+            admits_ahead=self._admits_ahead, view_read_pct=view_read_pct))
         self._admits_ahead = []
         self.decode_steps += self.chunk_steps
         self.sample_sorted_chunks += any(
             s.handle.request.sampling.top_p > 0 for _, s in owners)
+
+    def _view_columns(self, owners) -> Optional[float]:
+        """Count what the chunk being dispatched reads of its block
+        table: the width rule of the paged gather reads is a pure
+        function of the slots' positions and the program's shapes, so
+        the host evaluates it too (``ops.decode.ViewPlan.columns_read``:
+        the layers that read at the step's width profile and those that
+        read whole, as the traced program has them), at the positions of
+        every step of the chunk (``_pos_est`` before the chunk and one
+        further a step; a free slot is parked at 0), into
+        ``kv_view_columns_read`` / ``kv_view_columns_full``. -> the
+        chunk's own share in percent (the ledger row's
+        ``view_read_pct``), None where no layer reads by the rule (the
+        dense cache, the kernel, ``sparse_reads``, the speculative
+        verify, a block whose ordered reads are all runs of one layer)."""
+        if self._view_plan is None:
+            return None
+        pos = np.zeros((self.num_slots,), np.int64)
+        for i, _ in owners:
+            pos[i] = self._pos_est[i]
+        read, full = self._view_plan.columns_read(pos, self.chunk_steps)
+        self.kv_view_columns_read += read
+        self.kv_view_columns_full += full
+        return 100.0 * read / full
 
     def _harvest_chunk(self) -> None:
         """Fetch the OLDEST in-flight chunk's emit ring — the single
@@ -2263,7 +2305,8 @@ class Engine:
                "tokens": tokens, "admits_ahead": len(rec.admits_ahead),
                "admit_rows": sum(a["rows"] for a in rec.admits_ahead),
                "admit_calls": self._admit_calls,
-               "pending": len(self._pending), **phases}
+               "pending": len(self._pending),
+               "view_read_pct": rec.view_read_pct, **phases}
         self.loop_ring.record(row)
         # the device's answer to the stall before: near zero, it had gone
         # on and only that fetch was late; a whole chunk, it stood still
@@ -3397,6 +3440,8 @@ class Engine:
             "last_stalls": [dict(st) for st in list(self._stalls)],
             "sample_sorted_chunks": self.sample_sorted_chunks,
             "kv_view_groups": self.kv_view_groups,
+            "kv_view_columns_read": self.kv_view_columns_read,
+            "kv_view_columns_full": self.kv_view_columns_full,
             # the obs surface: flight-recorder occupancy (retention is
             # the ring capacity, /debug/events serves the contents) and
             # the serve-side profiler state

@@ -17,6 +17,7 @@ if "xla_force_host_platform_device_count" not in _flags:
         _flags + " --xla_force_host_platform_device_count=8").strip()
 
 import jax  # noqa: E402
+import pytest  # noqa: E402
 
 from dalle_pytorch_tpu.utils.device import enable_compile_cache  # noqa: E402
 
@@ -44,3 +45,100 @@ jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
 # own jax from env vars, not from this process's jax.config
 os.environ["JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS"] = "0.5"
 os.environ["JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES"] = "0"
+
+
+# -- the width rule of the paged gather reads (ISSUE 38) -------------------------
+# Its tests hold a step that reads by the rule against the same step at
+# full width and against one that reads a profile too narrow (the planted
+# fault): both stand-ins for ``ops.decode.view_profile_index`` live here,
+# for the classic pool's tests and the three described blocks' alike.
+# (A jitted step must be traced inside the context it is meant for.)
+
+@pytest.fixture
+def four_slots_a_group(monkeypatch):
+    """The toy tables are too small for the group rule's bytes to halve
+    any group (``ops.decode._halving_pays``), so a test that needs the
+    slots in several groups has every group of more than four halved, as
+    ruDALL-E's, 12b's and phi's shapes come out; the rule itself is held
+    to the cells' shapes in ``test_group_rule_on_the_cells_shapes``."""
+    from dalle_pytorch_tpu.ops import decode as decode_ops
+    monkeypatch.setattr(decode_ops, "_halving_pays",
+                        lambda per, slots, slot_bytes: per > 4)
+
+
+@pytest.fixture(params=["one_switch", "a_switch_a_read"])
+def switch_placement(request, monkeypatch):
+    """Both places of a described block's switch
+    (``ops.decode.block_view_plan``): around the span of scans that read
+    the ordered pool, which the toy shapes get (they hand little out of a
+    branch), and around each scanned read, which kanana's and trinity's
+    expert stacks force."""
+    from dalle_pytorch_tpu.ops import decode as decode_ops
+    if request.param == "a_switch_a_read":
+        monkeypatch.setattr(decode_ops, "_VIEW_SWITCH_BYTES", -1)
+    return request.param
+
+
+@pytest.fixture
+def profile_positions():
+    """-> the function (widths, page_size, total_len) -> a slot a
+    position, in shuffled order, that need exactly the profile ``widths``
+    (one of ``ops.decode.view_profiles``; four slots a group): in every
+    group a slot AT its width's edge (every row of the width is before
+    its ``pos``), one a row before it, one a row after the edge of the
+    group before, one in between; in the first group a parked slot
+    (0) and one at 1; and the last row."""
+    import numpy as np
+
+    def positions(widths, page_size: int, total_len: int):
+        at, lo = [], 0
+        for g, w in enumerate(widths):
+            top = min(w * page_size, total_len - 1)
+            at += [top, max(top - 1, 0)] + (
+                [0, 1] if g == 0 else [min(lo + 1, top), (lo + top) // 2])
+            lo = top
+        return np.random.default_rng(38).permutation(
+            np.asarray(at, np.int32))
+    return positions
+
+
+@pytest.fixture
+def reads_at(monkeypatch):
+    """-> ``reads_at(which)``, a context in which every step reads
+    ``"full_width"`` (the last profile: the whole table in every group)
+    or ``"too_narrow"`` (the planted fault: the profile before the first
+    that holds the slots' rows)."""
+    import contextlib
+
+    import jax.numpy as jnp
+    from dalle_pytorch_tpu.ops import decode as decode_ops
+    real = decode_ops.view_profile_index
+
+    def stand_in(which):
+        def index(pos_sorted, groups, columns, page_size, xp=jnp):
+            got = real(pos_sorted, groups, columns, page_size, xp)
+            if which == "too_narrow":
+                return xp.maximum(got - 1, 0)
+            return xp.zeros_like(got) + len(
+                decode_ops.view_profiles(groups, columns)) - 1
+        return index
+
+    @contextlib.contextmanager
+    def reads(which):
+        assert which in ("full_width", "too_narrow")
+        with monkeypatch.context() as m:
+            m.setattr(decode_ops, "view_profile_index", stand_in(which))
+            yield
+    return reads
+
+
+@pytest.fixture
+def release_programs():
+    """Drop the compiled programs a test leaves in jax's caches: a step or
+    a fused loop of sixteen slots with a branch a width profile holds
+    thousands of memory mappings, and a worker that keeps every one of
+    them nears ``vm.max_map_count`` (65530) and dies in a LATER test's
+    compile (seen in the whole suite, PR 38: a segmentation fault in
+    ``tests/test_quant.py``, which passes alone)."""
+    yield
+    jax.clear_caches()

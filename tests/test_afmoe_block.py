@@ -124,11 +124,12 @@ def _tables(b):
                 b, RING)}
 
 
-def _prefilled_pools(params, sequences, t0):
-    """The prompt's rows [0, t0) of both sequences in the two pools: a
+def _prefilled_pools(params, sequences, t0, upto=None):
+    """The prompt's rows [0, t0) of the sequences in the two pools: a
     full layer's row j in page j // PS of the slot's full table, a window
     layer's in column (j // PS) % RING of its ring, later rows over
-    earlier ones (page 0 of each pool is the trash page)."""
+    earlier ones (page 0 of each pool is the trash page); with ``upto``
+    (b,), slot i's rows [0, upto[i]) alone."""
     b = sequences.shape[0]
     tables = _tables(b)
     pool = KV.init_page_pool(TCFG, 1 + b * WIDTH, PS,
@@ -147,16 +148,17 @@ def _prefilled_pools(params, sequences, t0):
     assert cache["window_k"].shape == (len(WINDOW_LAYERS), b, t0, 2, 8)
     pool = dict(pool)
     for name in ("k", "v"):
-        full, ring = pool[name], pool["window_" + name]
+        full, ring = (np.array(pool[n]) for n in (name, "window_" + name))
+        rows, ring_rows = (np.asarray(cache[n]) for n in
+                           (name, "window_" + name))
         for i in range(b):
-            for j in range(t0):
-                full = full.at[:, tables["full"][i, j // PS], j % PS].set(
-                    cache[name][:, i, j].reshape(len(FULL_LAYERS), -1))
-                ring = ring.at[:, tables["window"][i, (j // PS) % RING],
-                               j % PS].set(
-                    cache["window_" + name][:, i, j].reshape(
-                        len(WINDOW_LAYERS), -1))
-        pool[name], pool["window_" + name] = full, ring
+            for j in range(t0 if upto is None else upto[i]):
+                full[:, tables["full"][i, j // PS], j % PS] = \
+                    rows[:, i, j].reshape(len(FULL_LAYERS), -1)
+                ring[:, tables["window"][i, (j // PS) % RING], j % PS] = \
+                    ring_rows[:, i, j].reshape(len(WINDOW_LAYERS), -1)
+        pool[name], pool["window_" + name] = (jnp.asarray(full),
+                                              jnp.asarray(ring))
     return h, pool, tables
 
 
@@ -227,6 +229,73 @@ def test_prefill_then_paged_decode_matches_the_full_forward(    # past it
                                atol=1e-5, rtol=1e-5)
 
 
+def _step_at(params, seqs, positions):
+    """One decode step with slot i at ``positions[i]`` of ``seqs[i]``,
+    the rows before it in its pages of both pools (its ring as far as it
+    has turned) -> the logits (forbidden ones -inf)."""
+    _, pool, tables = _prefilled_pools(params, seqs, int(positions.max()),
+                                       positions)
+    p = jnp.asarray(positions)
+    b = len(positions)
+    x = D.decode_token_embed(
+        params, CFG, jnp.asarray(seqs[np.arange(b), positions]), p)
+    _step_at.plan = decode_ops.block_view_plan(
+        TCFG, params["transformer"], pool, b, DIMS.seq_len)
+    h_tok, _, _ = jax.jit(lambda x, p, pool: decode_ops.decode_step_block(
+        params["transformer"], x, p, pool, tables, cfg=TCFG,
+        key_mask=jnp.ones((b, DIMS.seq_len), bool),
+        active=jnp.ones((b,), bool)))(x, p, pool)
+    return np.where(np.asarray(D.logits_mask(CFG))[positions], -np.inf,
+                    np.asarray(D.to_logits(params, h_tok, CFG)))
+
+
+@pytest.mark.parametrize("at", [0, 3])
+def test_slots_at_spread_positions_match_the_full_forward(
+        params, sequences, ref_logits, profile_positions, reads_at, at,
+        release_programs, four_slots_a_group, switch_placement):
+    """ISSUE 38 in this block: the published pattern's full layers are
+    runs of ONE layer (every fourth). At the published sizes the routed
+    experts' stacks are too much to hand out of ONE switch around the
+    scans that read the full pool (``block_view_plan``), so each scanned
+    read would switch for itself, and a lone layer, which no scan runs,
+    reads its pool whole in slot order: ``a_switch_a_read``, where the
+    stand-ins for the width rule change nothing. The toy's experts are
+    small, so by its own shapes ONE switch stands around the full layers'
+    span and they read at the step's profile: ``one_switch``, where the
+    planted fault must be caught. Either way a window layer reads its
+    ring whole, and sixteen slots at positions spread as a width profile
+    has them (some rings unwrapped, some wrapped, a parked slot, the last
+    row) give the reference's full-forward logits at every slot's own
+    position."""
+    profiles = decode_ops.view_profiles(4, WIDTH)
+    positions = profile_positions(profiles[at], PS, DIMS.seq_len - 1)
+    assert (positions < RING * PS).sum() >= 2 <= (
+        positions > RING * PS).sum()
+    full = [r for r in T.layer_runs(BLK, 5) if r.full]
+    assert full and all(r.count == 1 for r in full)
+    rows = np.arange(len(positions)) % len(sequences)
+    seqs = sequences[rows]
+    want = ref_logits[rows, positions]
+    got = _step_at(params, seqs, positions)
+    _close(got, want)
+    plan = _step_at.plan
+    with reads_at("too_narrow"):
+        cut = _step_at(params, seqs, positions)
+    if switch_placement == "a_switch_a_read":
+        assert plan.span is None and plan.by_rule == 0 \
+            and plan.whole == len(full)
+        np.testing.assert_array_equal(got, cut)
+    else:
+        assert plan.span is not None and plan.whole == 0 \
+            and plan.by_rule == len(full) and plan.groups == 4
+        with reads_at("full_width"):
+            whole = _step_at(params, seqs, positions)
+        np.testing.assert_array_equal(got.argmax(-1), whole.argmax(-1))
+        if at:
+            with pytest.raises(AssertionError):
+                _close(cut, want)
+
+
 def test_window_rows_are_the_latest_positions_of_a_ring():
     rows, window = 12, 8
     pos = jnp.asarray([0, 1, 5, 12, 13, 30])
@@ -294,12 +363,17 @@ def test_cached_rows_read_equals_the_materialised_read():
 
 # -- (iii) the engine: both pools, the ring, chunks of 8 -----------------------
 
-def test_engine_serves_the_reference_s_tokens_in_chunks_of_8(params):
+def test_engine_serves_the_reference_s_tokens_in_chunks_of_8(
+        params, switch_placement):
     """Through the engine: admission's whole-page write into both pools,
     the ring's pages reused as the slots move on, slot reuse, the fused
     chunks. Greedy tokens are the reference's best at every served
     position (gap 0 but for float32 near-ties), the routed load comes
-    out with the ring, and both pools are empty at the end."""
+    out with the ring, and both pools are empty at the end. The counters
+    of the width rule (ISSUE 38) count what the traced program reads:
+    nothing where the one full layer, a run of one, reads whole
+    (``a_switch_a_read``: the published sizes), the layer's table a step
+    where it stands in the step's switch."""
     queue = RequestQueue(max_depth=8)
     engine = Engine(params, CFG, queue, num_slots=2, chunk_steps=8,
                     kv="paged", page_size=PS)
@@ -352,6 +426,17 @@ def test_engine_serves_the_reference_s_tokens_in_chunks_of_8(params):
     assert st["kv_hbm_bytes"] == (4 * 7 + 1 * 19) * 2 * (2 * PS * 8) * 4
     assert st["kv_read_bytes_per_token"] == \
         (4 * RING + 1 * WIDTH) * 2 * (2 * PS * 8) * 4
+    rows = [r for r in engine.loop_ring.dump() if "kind" not in r]
+    if switch_placement == "a_switch_a_read":
+        assert engine._view_plan is None
+        assert st["kv_view_columns_read"] == st["kv_view_columns_full"] == 0
+        assert rows and all(r["view_read_pct"] is None for r in rows)
+    else:
+        # two slots are one group, which reads the whole table
+        assert engine._view_plan.by_rule == 1 and st["kv_view_groups"] == 1
+        assert st["kv_view_columns_read"] == st["kv_view_columns_full"] \
+            == st["decode_steps"] * 2 * WIDTH
+        assert rows and all(r["view_read_pct"] == 100.0 for r in rows)
 
 
 def test_an_undersized_pool_evicts_and_replays_the_same_tokens(params):

@@ -83,23 +83,26 @@ def test_dalle_apply_matches_the_reference_logits(params, sequences,
 
 # -- (ii) prefill, then the paged gather decode --------------------------------
 
-def _prefilled_pool(params, sequences, t0):
-    """The prompt's rows [0, t0) of both sequences in a pool, slot i's
-    pages 1 + i * W .. (page 0 is the trash page)."""
+def _prefilled_pool(params, sequences, t0, upto=None):
+    """The prompt's rows [0, t0) of the sequences in a pool, slot i's
+    pages 1 + i * W .. (page 0 is the trash page); with ``upto`` (b,),
+    slot i's rows [0, upto[i]) alone."""
     b = sequences.shape[0]
     width = KV.pages_for(DIMS.seq_len, PS)
     tables = 1 + jnp.arange(b * width, dtype=jnp.int32).reshape(b, width)
     pool = KV.init_page_pool(TCFG, 1 + b * width, PS)
-    x = D.embed_prompt(params, CFG, jnp.asarray(sequences[:, :t0]))
+    t = min(t0, DIMS.text_seq_len)
+    x = D.embed_prompt(params, CFG, jnp.asarray(sequences[:, :t]),
+                       jnp.asarray(sequences[:, t:t0]))
     h, cache = decode_ops.prefill(params["transformer"], x, cfg=TCFG,
                                   total_len=DIMS.seq_len)
-    rows = cache["latent"]                      # (depth, b, t0, width)
+    rows = np.asarray(cache["latent"])          # (depth, b, t0, width)
     assert rows.shape == (DIMS.depth, b, t0, BLK.row_width)
-    buf = pool["latent"]
+    buf = np.array(pool["latent"])
     for i in range(b):
-        for j in range(t0):
-            buf = buf.at[:, tables[i, j // PS], j % PS].set(rows[:, i, j])
-    return h, {"latent": buf}, tables
+        for j in range(t0 if upto is None else upto[i]):
+            buf[:, tables[i, j // PS], j % PS] = rows[:, i, j]
+    return h, {"latent": jnp.asarray(buf)}, tables
 
 
 def _teacher_forced(params, sequences):
@@ -163,6 +166,76 @@ def test_prefill_then_paged_decode_matches_the_full_forward(
         step_pool["latent"], jnp.int32(1), tables)
         ).reshape(b, -1, BLK.row_width)
     np.testing.assert_allclose(live[:, rows], want[:, rows], atol=1e-6)
+
+
+def _step_at(params, seqs, positions):
+    """One decode step with slot i at ``positions[i]`` of ``seqs[i]``,
+    the rows before it in its pages -> the logits (forbidden ones -inf)."""
+    top = int(positions.max())
+    _, pool, tables = _prefilled_pool(params, seqs, top, positions)
+    p = jnp.asarray(positions)
+    b = len(positions)
+    x = D.decode_token_embed(
+        params, CFG, jnp.asarray(seqs[np.arange(b), positions]), p)
+    _step_at.plan = decode_ops.block_view_plan(
+        TCFG, params["transformer"], pool, b, DIMS.seq_len)
+    h_tok, _, _ = jax.jit(lambda x, p, pool: decode_ops.decode_step_block(
+        params["transformer"], x, p, pool, tables, cfg=TCFG,
+        key_mask=jnp.ones((b, DIMS.seq_len), bool),
+        active=jnp.ones((b,), bool)))(x, p, pool)
+    return np.where(np.asarray(D.logits_mask(CFG))[positions], -np.inf,
+                    np.asarray(D.to_logits(params, h_tok)))
+
+
+@pytest.mark.parametrize("at", [0, 1, 3])
+def test_slots_up_to_each_width_profile_match_the_full_forward(
+        params, sequences, ref_logits, profile_positions, reads_at, at,
+        release_programs, four_slots_a_group, switch_placement):
+    """ISSUE 38: the reads of the pool whose rows lie in order stop at
+    the rows that are written (the absorbed read of the latent pool).
+    Sixteen slots in shuffled phase order whose positions need profile
+    ``at`` of the table's staircases (in every group a slot AT its
+    width's edge, one a row before it, one a row after the edge of the
+    group before; a parked slot, one at 1; the last row at the last profile):
+    one step by the rule gives the reference's full-forward logits at
+    every slot's own position, and the greedy tokens of the same step at
+    full width; the profile before (the planted fault) fails the same
+    comparison."""
+    width = KV.pages_for(DIMS.seq_len, PS)
+    assert decode_ops.view_slot_groups(16, width, (PS, BLK.row_width),
+                                       jnp.float32) == 4
+    profiles = decode_ops.view_profiles(4, width)
+    assert len(profiles) == 4
+    positions = profile_positions(profiles[at], PS, DIMS.seq_len - 1)
+    assert int(decode_ops.view_profile_index(
+        np.sort(positions), 4, width, PS, xp=np)) == at
+    rows = np.arange(len(positions)) % len(sequences)
+    seqs = sequences[rows]
+    want = ref_logits[rows, positions]
+    got = _step_at(params, seqs, positions)
+    _close(got, want)
+    # where the switch stands (``block_view_plan``): one around the span
+    # of scans that read the ordered pool, every reader at the profile;
+    # or one a scanned read, a run of one layer whole
+    plan = _step_at.plan
+    readers = [r for r in T.layer_runs(BLK, TCFG.depth)
+               if r.kind.pool == "full"]
+    lone = sum(r.count for r in readers if r.count == 1)
+    assert lone and plan.groups == 4
+    if switch_placement == "one_switch":
+        assert plan.span is not None and plan.whole == 0
+    else:
+        assert plan.span is None and plan.whole == lone
+    assert plan.by_rule + plan.whole == sum(r.count for r in readers)
+    with reads_at("full_width"):
+        whole = _step_at(params, seqs, positions)
+    _close(whole, want)
+    np.testing.assert_array_equal(got.argmax(-1), whole.argmax(-1))
+    if at:
+        with reads_at("too_narrow"):
+            cut = _step_at(params, seqs, positions)
+        with pytest.raises(AssertionError):
+            _close(cut, want)
 
 
 def test_engine_serves_the_reference_s_tokens_in_chunks_of_8(params):

@@ -85,6 +85,25 @@ HloModule jit_step, entry_computation_layout={()->f32[8]}
   ROOT %lt = pred[] constant(true)
 }
 
+%narrow (arg.1: (f32[8], f32[4,8])) -> (f32[8], f32[2,8]) {
+  %arg.1 = (f32[8]{0}, f32[4,8]{1,0}) parameter(0)
+  %rows.1 = f32[4,8]{1,0} get-tuple-element(%arg.1), index=1
+  %slice.1 = f32[2,8]{1,0} slice(%rows.1), slice={[0:2], [0:8]}
+  %q.1 = f32[8]{0} get-tuple-element(%arg.1), index=0
+  %dot.1 = f32[8]{0} dot(%q.1, %slice.1), metadata={op_name="jit(step)/cond/branch_0_fun/attn.read/dot_general"}
+  %stack.1 = f32[2,8]{1,0} dynamic-update-slice(%slice.1, %dot.1)
+  ROOT %tuple.6 = (f32[8]{0}, f32[2,8]{1,0}) tuple(%dot.1, %stack.1)
+}
+
+%wide (arg.2: (f32[8], f32[4,8])) -> (f32[8], f32[2,8]) {
+  %arg.2 = (f32[8]{0}, f32[4,8]{1,0}) parameter(0)
+  %rows.2 = f32[4,8]{1,0} get-tuple-element(%arg.2), index=1
+  %q.2 = f32[8]{0} get-tuple-element(%arg.2), index=0
+  %dot.2 = f32[8]{0} dot(%q.2, %rows.2), metadata={op_name="jit(step)/cond/branch_1_fun/attn.read/dot_general"}
+  %stack.2 = f32[2,8]{1,0} broadcast(%dot.2)
+  ROOT %tuple.7 = (f32[8]{0}, f32[2,8]{1,0}) tuple(%dot.2, %stack.2)
+}
+
 ENTRY %main (x: f32[8], cache: f32[4,8]) -> f32[4,8] {
   %x = f32[8]{0} parameter(0)
   %cache = f32[4,8]{1,0} parameter(1), metadata={op_name="cache"}
@@ -95,6 +114,11 @@ ENTRY %main (x: f32[8], cache: f32[4,8]) -> f32[4,8] {
   %while.1 = (s32[], f32[8]{0}, f32[4,8]{0,1}) while(%tuple.1), condition=%cond, body=%body, metadata={op_name="jit(step)/while"}
   %get-tuple-element.8 = f32[4,8]{0,1} get-tuple-element(%while.1), index=2
   %multi.1 = (f32[2,3]{1,0}, s32[]) custom-call(%x), custom_call_target="Foo"
+  %copy.3 = f32[4,8]{1,0} copy(%cache)
+  %tuple.8 = (f32[8]{0}, f32[4,8]{1,0}) tuple(%x, %copy.3)
+  %conditional.1 = (f32[8]{0}, f32[2,8]{1,0}) conditional(%zero, %tuple.8, %tuple.8), branch_computations={%narrow, %wide}, metadata={op_name="jit(step)/cond"}
+  %get-tuple-element.9 = f32[2,8]{1,0} get-tuple-element(%conditional.1), index=1
+  %store.1 = f32[2,8]{1,0} negate(%get-tuple-element.9), metadata={op_name="jit(step)/kv.store/neg"}
   ROOT %copy.2 = f32[4,8]{1,0} copy(%get-tuple-element.8)
 }
 """
@@ -120,10 +144,23 @@ def test_scopes_of_hlo_on_a_small_text():
                            "inherited": True}
     # ... and the one at exit was made from the loop's stored pool
     assert m["copy.2"]["scope"] == "kv.store" and m["copy.2"]["inherited"]
+    # a conditional's branches are computations of their own, and a value
+    # is followed through them (PR 38: a step's layers run inside one):
+    # what a branch stacks for a store outside it reaches that store
+    # through the branch's result, the entry's copy reaches a branch's
+    # read through the conditional's operand
+    assert m["dot.1"]["scope"] == m["dot.2"]["scope"] == "attn.read"
+    assert m["stack.1"] == {"scope": "kv.store", "recompute": False,
+                            "op_name": "", "shape": "f32[2,8]",
+                            "inherited": True}
+    assert m["stack.2"]["scope"] == "kv.store"
+    assert m["copy.3"]["scope"] == "attn.read" and m["copy.3"]["inherited"]
+    assert m["slice.1"]["scope"] == "attn.read"
     # an expansion that lost its path and reaches nothing stays unscoped,
-    # and a loop is never given its body's scope
+    # and a loop or a conditional is never given its body's scope
     assert m["reduce.4"]["scope"] == "unscoped"
     assert m["while.1"]["scope"] == "unscoped"
+    assert m["conditional.1"]["scope"] == "unscoped"
 
 
 # -- the programs --------------------------------------------------------------

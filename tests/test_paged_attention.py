@@ -14,7 +14,7 @@ naming the kernel tile constraint) and the ``paged_view`` trim: the
 gather never drags K/V or scale pages for wholly-unmapped logical pages
 beyond ``total_len``. Since ISSUE 25 the gather path itself reads the
 pool per layer and page-major (``layer_pool_view`` +
-``_paged_gather_read``): ``TestPerLayerRead`` holds it to the
+``_paged_gather_attend``): ``TestPerLayerRead`` holds it to the
 ``paged_view`` + ``_gather_read`` oracle, to the dense loop's tokens,
 and to a temporaries budget that an all-layer view cannot meet; since
 ISSUE 31 the read runs a slot group at a time, and the same class holds
@@ -462,7 +462,7 @@ class TestPerLayerRead:
             gk = decode_ops.layer_pool_view(
                 pool["k"], jnp.asarray(layer), bt[:, :need])
             assert gk.shape == (3, need, self.PS, heads * dim_head)
-            got = decode_ops._paged_gather_read(
+            got = decode_ops._paged_gather_attend(
                 pool, jnp.asarray(layer), bt[:, :need], q, k, v, allowed,
                 scale=scale, mesh=mesh)
             assert got.shape == want.shape and got.dtype == want.dtype
@@ -482,7 +482,10 @@ class TestPerLayerRead:
 
     def _force_groups(self, monkeypatch, pool, slots, columns, groups):
         """Set the VMEM budget (the constant, not a knob of the program)
-        so that the rule gives ``groups`` for this pool and table."""
+        so that the rule gives ``groups`` for this pool and table, with
+        the ordering's halving of a group (ISSUE 38) out of the way."""
+        monkeypatch.setattr(decode_ops, "_halving_pays",
+                            lambda per, slots, slot_bytes: False)
         monkeypatch.setattr(decode_ops, "_VIEW_VMEM_BYTES", 1)
         # with a budget of 1 byte nothing fits: the rule's floor, one slot
         assert decode_ops.pool_view_groups(pool, slots, columns) == slots
@@ -527,7 +530,14 @@ class TestPerLayerRead:
             return decode_ops._paged_gather_attend(
                 pool, layer, bt, q, k, v, allowed, scale=scale)
 
+        # six slots: one group by their bytes, two of three where the
+        # ordering halves a group over four, VMEM's where it is out of
+        # the way
         assert decode_ops.pool_view_groups(pool, 6, need) == 1
+        monkeypatch.setattr(decode_ops, "_halving_pays",
+                            lambda per, slots, slot_bytes: per > 4)
+        assert decode_ops.pool_view_groups(pool, 6, need) == 2
+        self._force_groups(monkeypatch, pool, 6, need, 1)
         whole = attend()
         self._force_groups(monkeypatch, pool, 6, need, groups)
         got = attend()
@@ -544,35 +554,57 @@ class TestPerLayerRead:
         np.testing.assert_allclose(np.asarray(got, np.float32),
                                    np.asarray(want, np.float32), **tol)
 
-    @pytest.mark.parametrize("slots,columns,page,dtype,want", [
-        (16, 72, (16, 16 * 128), jnp.bfloat16, 1),   # rudalle-xl.serve-full
-        (12, 80, (16, 62 * 64), jnp.bfloat16, 2),    # dalle-12b.serve-full
-        (32, 272, (16, 640), jnp.bfloat16, 2),       # the latent pool
-        (12, 80, (16, 62 * 64), jnp.int8, 2),        # 12b's int8 pool
-        (12, 80, (16, 62), jnp.float32, 1),          # and its scale pages
-        (7, 160, (16, 62 * 64), jnp.bfloat16, 7),    # no divisor fits
+    @pytest.mark.parametrize("slots,columns,page,dtype,vmem,want", [
+        (16, 72, (16, 16 * 128), jnp.bfloat16, 1, 4),  # rudalle-xl.serve-full
+        (12, 80, (16, 62 * 64), jnp.bfloat16, 2, 3),   # dalle-12b.serve-full
+        (32, 272, (16, 640), jnp.bfloat16, 2, 4),      # the latent pool
+        (12, 80, (16, 62 * 64), jnp.int8, 2, 3),       # 12b's int8 pool
+        (12, 80, (16, 62), jnp.float32, 1, 1),         # and its scale pages
+        (7, 160, (16, 62 * 64), jnp.bfloat16, 7, 7),   # no divisor fits
+        (16, 592, (16, 1024), jnp.bfloat16, 4, 8),     # trinity's full layer
+        (32, 272, (16, 1280), jnp.bfloat16, 4, 8),     # phi's full layer
+        (32, 33, (16, 1280), jnp.bfloat16, 1, 2),      # and its rings
     ], ids=["rudalle-xl", "dalle-12b", "latent", "dalle-12b-int8",
-            "dalle-12b-int8-scales", "prime_slots"])
+            "dalle-12b-int8-scales", "prime_slots", "trinity-full",
+            "phi-full", "phi-ring"])
     def test_group_rule_on_the_cells_shapes(self, slots, columns, page,
-                                            dtype, want):
+                                            dtype, vmem, want):
         """The rule sees slots, table columns, the page's shape (rows,
         width) and the pool's dtype, and counts bytes as laid out (the
-        width in whole 128-lane tiles, 16 int8 rows a 32-row tile):
+        width in whole 128-lane tiles, 16 int8 rows a 32-row tile). VMEM
+        alone (``vmem``: a table read whole in slot order, a ring or a
+        sparse layer's visible columns, ``ordered=False``):
         ruDALL-E's 75.5 MB a buffer is one group; 12b's row of 62 x 64 =
         3968 numbers is 31 whole tiles, so its 122 MB are two groups of
         61 MB where the page per head, half padding, made three of 81 MB
         (ISSUE 36; its int8 pool's the same two); the latent pool's 178
         MB two; a slot count with no divisor that fits falls to one slot a
-        group and does not raise."""
+        group and does not raise. With the ordering (ISSUE 38: a group
+        reads the width of its furthest slot, so it is halved while that
+        saves more bytes than a further group costs,
+        ``_VIEW_GROUP_BYTES``) the count rises to ``want``: groups of four
+        slots in ruDALL-E, 12b and phi, of eight over the latent pool's
+        narrow rows and 32 slots, of two over trinity's long table (whose
+        full layers, runs of one, read whole all the same); it never
+        falls under what VMEM asks."""
         groups = decode_ops.view_slot_groups(slots, columns, page, dtype)
         assert groups == want
         assert slots % groups == 0
         slot_bytes = columns * self._laid_out(page, dtype)
+
+        def saved_by_halving(per):      # (per / 2) ** 2 / slots of a table
+            return per * per * slot_bytes / (4 * slots)
         if groups < slots:
             assert slots // groups * slot_bytes <= decode_ops._VIEW_VMEM_BYTES
-        if groups > 1:       # and one group fewer would not have fitted
+            assert saved_by_halving(slots // groups) \
+                <= decode_ops._VIEW_GROUP_BYTES
+        if groups > 1:       # and one group fewer would have broken a bound
             fewer = max(g for g in range(1, groups) if slots % g == 0)
-            assert slots // fewer * slot_bytes > decode_ops._VIEW_VMEM_BYTES
+            assert slots // fewer * slot_bytes > decode_ops._VIEW_VMEM_BYTES \
+                or saved_by_halving(slots // fewer) \
+                > decode_ops._VIEW_GROUP_BYTES
+        assert decode_ops.view_slot_groups(
+            slots, columns, page, dtype, ordered=False) == vmem <= want
 
     # ---- ISSUEs 34, 36: whole rows against all heads' queries ----
 
@@ -946,7 +978,7 @@ class TestRowPageWrites:
                 scale=0.25,
                 ksc=want["k_scale"][layer] if kind == "int8" else None,
                 vsc=want["v_scale"][layer] if kind == "int8" else None)
-            paged = decode_ops._paged_gather_read(
+            paged = decode_ops._paged_gather_attend(
                 after, jnp.asarray(layer), bt, q, k, v, allowed, scale=0.25)
             tol = 2e-2 if kind == "bf16" else 2e-5
             np.testing.assert_allclose(

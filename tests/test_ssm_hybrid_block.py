@@ -207,12 +207,13 @@ def _tables(b):
                 b, RING)}
 
 
-def _prefilled_pools(params, sequences, t0):
-    """The prompt's rows [0, t0) of both sequences in the two pools (a
+def _prefilled_pools(params, sequences, t0, upto=None):
+    """The prompt's rows [0, t0) of the sequences in the two pools (a
     full layer's row j in page j // PS of the slot's full table, a window
     layer's in column (j // PS) % RING of its ring, later rows over
     earlier ones; page 0 of each pool is the trash page) and each
-    sequence's state in its slot."""
+    sequence's state in its slot; with ``upto`` (b,), slot i's rows [0,
+    upto[i]) alone and its state after exactly that many tokens."""
     b = sequences.shape[0]
     tables = _tables(b)
     pool = dict(KV.init_page_pool(TCFG, 1 + b * WIDTH, PS,
@@ -225,18 +226,19 @@ def _prefilled_pools(params, sequences, t0):
     t = min(t0, DIMS.text_seq_len)
     x = D.embed_prompt(params, CFG, jnp.asarray(sequences[:, :t]),
                        jnp.asarray(sequences[:, t:t0]))
-    h, cache = decode_ops.prefill(params["transformer"], x, cfg=TCFG,
-                                  total_len=DIMS.seq_len)
+    h, cache = decode_ops.prefill(
+        params["transformer"], x, cfg=TCFG, total_len=DIMS.seq_len,
+        lens=None if upto is None else jnp.asarray(upto))
     for name, table, ring in (("k", "full", False), ("v", "full", False),
                               ("window_k", "window", True),
                               ("window_v", "window", True)):
-        buf = pool[name]
+        buf, rows = np.array(pool[name]), np.asarray(cache[name])
         for i in range(b):
-            for j in range(t0):
+            for j in range(t0 if upto is None else upto[i]):
                 col = (j // PS) % RING if ring else j // PS
-                buf = buf.at[:, tables[table][i, col], j % PS].set(
-                    cache[name][:, i, j].reshape(buf.shape[0], -1))
-        pool[name] = buf
+                buf[:, tables[table][i, col], j % PS] = \
+                    rows[:, i, j].reshape(buf.shape[0], -1)
+        pool[name] = jnp.asarray(buf)
     pool["ssm_state"], pool["ssm_conv"] = cache["ssm_state"], \
         cache["ssm_conv"]
     return h, pool, tables
@@ -274,6 +276,87 @@ def test_prefill_then_paged_decode_matches_the_full_forward(    # past it
                                    atol=ATOL, rtol=0)
 
 
+def _step_at(params, seqs, positions):
+    """One decode step with slot i at ``positions[i]`` of ``seqs[i]``:
+    the rows before it in its pages of both pools (its ring as far as it
+    has turned), its state after that many tokens -> the logits
+    (forbidden ones -inf)."""
+    _, pool, tables = _prefilled_pools(params, seqs, int(positions.max()),
+                                       positions)
+    p = jnp.asarray(positions)
+    b = len(positions)
+    x = D.decode_token_embed(
+        params, CFG, jnp.asarray(seqs[np.arange(b), positions]), p)
+    _step_at.plan = decode_ops.block_view_plan(
+        TCFG, params["transformer"], pool, b, DIMS.seq_len)
+    h_tok, _, _ = jax.jit(lambda x, p, pool: decode_ops.decode_step_block(
+        params["transformer"], x, p, pool, tables, cfg=TCFG,
+        key_mask=jnp.ones((b, DIMS.seq_len), bool),
+        active=jnp.ones((b,), bool)))(x, p, pool)
+    return np.where(np.asarray(D.logits_mask(CFG))[positions], -np.inf,
+                    np.asarray(D.to_logits(params, h_tok, CFG)))
+
+
+@pytest.mark.parametrize("at", [0, 1, 3])
+def test_slots_up_to_each_width_profile_match_the_full_forward(
+        params, sequences, ref_logits, profile_positions, reads_at, at,
+        release_programs, four_slots_a_group, switch_placement):
+    """ISSUE 38: the reads of the pool whose rows lie in order stop at
+    the rows that are written: the full layer's differential
+    read and the cross layers' of the SAME table at the full layer's
+    index, each with a query of its own (a window layer reads its ring
+    whole, in slot order; the positions leave some rings unwrapped and
+    wrap others).
+    Sixteen slots in shuffled phase order whose positions need profile
+    ``at`` of the table's staircases (in every group a slot AT its
+    width's edge, one a row before it, one a row after the edge of the
+    group before; a slot at 1; the last row at the last profile):
+    one step by the rule gives the reference's full-forward logits at
+    every slot's own position, and the greedy tokens of the same step at
+    full width; the profile before (the planted fault) fails the same
+    comparison."""
+    width = WIDTH
+    assert decode_ops.view_slot_groups(16, width, (PS, 2 * 8),
+                                       jnp.float32) == 4
+    profiles = decode_ops.view_profiles(4, width)
+    assert len(profiles) == 4
+    positions = profile_positions(profiles[at], PS, DIMS.seq_len - 1)
+    # (a state after no token at all is no prompt's: the slot parked at 0
+    # is the classic pool's case)
+    positions = np.where(positions == 0, 2, positions)
+    assert int(decode_ops.view_profile_index(
+        np.sort(positions), 4, width, PS, xp=np)) == at
+    assert (positions < RING * PS).sum() >= 2 <= (
+        positions > RING * PS).sum()
+    rows = np.arange(len(positions)) % len(sequences)
+    seqs = sequences[rows]
+    want = ref_logits[rows, positions]
+    got = _step_at(params, seqs, positions)
+    _close(got, want)
+    # where the switch stands (``block_view_plan``): one around the span
+    # of scans that read the ordered pool, every reader at the profile;
+    # or one a scanned read, a run of one layer whole
+    plan = _step_at.plan
+    readers = [r for r in T.layer_runs(BLK, TCFG.depth)
+               if r.kind.pool == "full"]
+    lone = sum(r.count for r in readers if r.count == 1)
+    assert lone and plan.groups == 4
+    if switch_placement == "one_switch":
+        assert plan.span is not None and plan.whole == 0
+    else:
+        assert plan.span is None and plan.whole == lone
+    assert plan.by_rule + plan.whole == sum(r.count for r in readers)
+    with reads_at("full_width"):
+        whole = _step_at(params, seqs, positions)
+    _close(whole, want)
+    np.testing.assert_array_equal(got.argmax(-1), whole.argmax(-1))
+    if at:
+        with reads_at("too_narrow"):
+            cut = _step_at(params, seqs, positions)
+        with pytest.raises(AssertionError):
+            _close(cut, want)
+
+
 def test_an_inactive_slots_state_is_not_advanced(params, sequences):
     _, pool, tables = _prefilled_pools(params, sequences, 7)
     p = jnp.full((2,), 7, jnp.int32)
@@ -305,7 +388,8 @@ def test_every_cross_layer_reads_the_full_layers_pages(params, sequences):
         """Each layer's two branches' sum for the token, the stack
         unrolled by hand: one layer at a time, each on the stream that
         the layers before it left."""
-        read_of = decode_ops._block_reads(TCFG, pool, tables, p, key_mask)
+        read_of, _ = decode_ops._block_reads(TCFG, pool, tables, p,
+                                             key_mask)
         outs, h, shared = [], x, BLK.carried(x)
         for scan in T.stack_scans(BLK, 12):
             for local in range(scan[0].count):
