@@ -89,19 +89,95 @@ def test_every_cell_resolves_to_its_files_by_name(name):
     check_cell(name)
 
 
-@pytest.mark.parametrize("metric", [m["name"] for m in BENCH["per_layer"]])
-def test_per_layer_metric_moves_a_metric_its_cells_report(metric):
-    m = next(x for x in BENCH["per_layer"] if x["name"] == metric)
-    e2e = {x["name"]: x for x in BENCH["end_to_end"]}
+def cells_of_kind(kind: str, root: str = harness.ROOT) -> list:
+    """The names of a root's train or serve cells, in its order: a cell's
+    kind is its traffic file's, whatever the file is called."""
+    return [w["name"] for w in harness.load_benchmark(root)["workloads"]
+            if harness.Cell(w["name"], root=root).kind == kind]
+
+
+def _entry(metric: str, root: str, **keys) -> dict:
+    """The one per-layer entry of that NAME, with these keys."""
+    found = [m for m in harness.load_benchmark(root)["per_layer"]
+             if m["name"] == metric]
+    assert len(found) == 1, metric
+    for key, value in keys.items():
+        assert found[0][key] == value, (metric, key)
+    return found[0]
+
+
+def check_declared(metric: str, root: str = harness.ROOT, **keys) -> dict:
+    """A per-layer metric that every serve cell reports is declared once,
+    with these keys, for exactly those cells. It is found by its NAME: a
+    PR appends its entries at the end of ``per_layer`` and adds its cell
+    to such a metric's list, so no position in either list means anything
+    (``test_benchmark_families.py`` holds a root with both to this).
+    -> the entry."""
+    m = _entry(metric, root, **keys)
+    assert m["workloads"] == cells_of_kind("serve", root), metric
+    return m
+
+
+def check_declared_for_some(metric: str, root: str = harness.ROOT,
+                            cells=(), but=(), **keys) -> dict:
+    """A per-layer metric that only SOME serve cells report: ``cells`` are
+    listed, ``but`` are not, and whatever else is listed is a serve cell
+    of the root. Which further cells list it is theirs to say: a later
+    PR's cell whose reader finds nothing to read stays out and edits no
+    test (``later_pr``'s second serve cell is one). -> the entry."""
+    m = _entry(metric, root, **keys)
+    assert set(cells) <= set(m["workloads"]), metric
+    assert not set(but) & set(m["workloads"]), metric
+    assert set(m["workloads"]) <= set(cells_of_kind("serve", root)), metric
+    return m
+
+
+# the chunk ledger's per-layer metrics (PR 37), in the order of their entries
+CHUNK_LEDGER = ["admit_delayed_delivery_pct", "admit_stream_stall_ms",
+                "chunk_interval_ms", "loop_stall_ms"]
+# the per-layer metrics that every serve cell reports and a test of this
+# suite holds to that, each with the keys its test asks of it
+EVERY_SERVE_CELL = {
+    "decode_sample_ms": {"layer": "decode math", "moves": "tpot_ms",
+                         "source": "device_trace"},
+    **{name: {"layer": "engine", "better": "lower"} for name in CHUNK_LEDGER},
+}
+# those that only some serve cells report: the cells a test of this suite
+# holds it to, the cells that may not list it, and its keys
+SOME_SERVE_CELLS = {
+    # trinity's ordered reads are runs of one full layer, read whole: its
+    # counters stay 0 by design (``docs/OBSERVABILITY.md`` "Loop counters")
+    "kv_view_columns_read_pct": {
+        "cells": ("rudalle-xl.serve-full", "dalle-12b.serve-full",
+                  "kanana-2-30b-a3b.serve-full",
+                  "phi-4-mini-flash-reasoning.serve-full"),
+        "but": ("trinity-large-preview.serve-full",),
+        "unit": "%", "better": "lower", "source": "program_counter",
+        "layer": "decode math", "moves": "tpot_ms"},
+}
+
+
+def check_moves(metric: str, root: str = harness.ROOT) -> None:
+    """A per-layer entry moves an end-to-end metric that each of its
+    cells reports, and holds the contract's keys and no other."""
+    bench = harness.load_benchmark(root)
+    m = next(x for x in bench["per_layer"] if x["name"] == metric)
+    e2e = {x["name"]: x for x in bench["end_to_end"]}
     assert m["moves"] in e2e
     target = e2e[m["moves"]]
-    reported_in = set(target.get("workloads", CELLS))
+    reported_in = set(target.get(
+        "workloads", [w["name"] for w in bench["workloads"]]))
     cells = set(m.get("workloads", reported_in))
     assert cells and cells <= reported_in
     assert set(m) <= {"name", "unit", "better", "source", "layer", "moves",
                       "workloads"}
     assert m["source"] in ("device_trace", "program_span", "program_counter",
                            "host_clock")
+
+
+@pytest.mark.parametrize("metric", [m["name"] for m in BENCH["per_layer"]])
+def test_per_layer_metric_moves_a_metric_its_cells_report(metric):
+    check_moves(metric)
 
 
 def _config_file(name: str) -> tuple:

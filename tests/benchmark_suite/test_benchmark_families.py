@@ -24,7 +24,10 @@ import numpy as np
 import pytest
 
 from benchmark import harness, seeds, serve_cell, tiny, traffic, train_cell
-from test_benchmark_contract import check_cell
+from test_benchmark_contract import (EVERY_SERVE_CELL, SOME_SERVE_CELLS,
+                                     cells_of_kind, check_cell,
+                                     check_declared, check_declared_for_some,
+                                     check_moves)
 
 HERE = harness.HERE
 DEVICE = {"platform": "cpu", "kind": "TPU v5 lite", "count": 1}
@@ -274,6 +277,67 @@ def test_a_familys_own_reference_decides_its_correct(tmp_path, listener):
     # the first family beside it is held to its own reference, untouched
     assert _correct(root, "dalle-12b.train", listener) is True
     assert _correct(root, "rudalle-xl.serve-full", listener) is True
+
+
+# -- (c') what a later PR's BENCHMARK.json owes the suite's declaration checks ---
+
+@pytest.fixture(scope="module")
+def later_pr(tmp_path_factory):
+    """The root of ``_second_family`` (a copy of the committed
+    BENCHMARK.json with a configuration, a train and a serve cell added,
+    each cell listed wherever the first family's cell of its kind is, and
+    a per-layer entry appended at the END) with one more serve cell, whose
+    traffic file has a name of its own and which lists only the metrics
+    that EVERY serve cell reports (the README's rule; not
+    ``kv_view_columns_read_pct``, ``decode_weights_ms`` or another that
+    only some list: trinity's case): what the next ``model_config`` PR
+    brings, which may edit no test of this directory."""
+    root, _ = _second_family(tmp_path_factory.mktemp("later_pr"))
+    here = os.path.join(root, "benchmark")
+    shutil.copy(os.path.join(here, "traffic", "serve-full.json"),
+                os.path.join(here, "traffic", "serve-long.json"))
+    shutil.copy(os.path.join(here, "cells", "other-12b.serve-full.json"),
+                os.path.join(here, "cells", "other-12b.serve-long.json"))
+    bench = harness.load_benchmark(root)
+    serve = set(cells_of_kind("serve", root))
+    bench["workloads"].append({"name": "other-12b.serve-long",
+                               "config": "other-12b", "traffic": "serve-long",
+                               "chips": 1, "why": "test"})
+    for m in bench["end_to_end"] + bench["per_layer"]:
+        if serve <= set(m.get("workloads", [])):
+            m["workloads"].append("other-12b.serve-long")
+    _dump(bench, root, "BENCHMARK.json")
+    return root
+
+
+@pytest.mark.parametrize("metric", sorted(EVERY_SERVE_CELL))
+def test_a_later_prs_serve_cells_pass_every_serve_cells_declaration(later_pr,
+                                                                    metric):
+    check_declared(metric, later_pr, **EVERY_SERVE_CELL[metric])
+
+
+@pytest.mark.parametrize("metric", sorted(SOME_SERVE_CELLS))
+def test_a_later_prs_serve_cell_may_leave_out_what_only_some_report(later_pr,
+                                                                    metric):
+    m = check_declared_for_some(metric, later_pr, **SOME_SERVE_CELLS[metric])
+    # the cell under the shared traffic file lists it, the other does not
+    assert "other-12b.serve-full" in m["workloads"]
+    assert "other-12b.serve-long" not in m["workloads"]
+
+
+def test_a_later_prs_entries_stand_at_the_end_and_pass_the_moves_check(
+        later_pr):
+    bench, committed = harness.load_benchmark(later_pr), harness.load_benchmark()
+    # appended: what was there stands first and in its order, in both lists
+    assert [m["name"] for m in bench["per_layer"]] \
+        == [m["name"] for m in committed["per_layer"]] + ["flops_per_token"]
+    assert cells_of_kind("serve", later_pr) == cells_of_kind("serve") \
+        + ["other-12b.serve-full", "other-12b.serve-long"]
+    assert cells_of_kind("train", later_pr) \
+        == cells_of_kind("train") + ["other-12b.train"]
+    for m in bench["per_layer"]:
+        check_moves(m["name"], later_pr)
+    check_cell("other-12b.serve-long", later_pr)
 
 
 # -- (d) a configuration has to name a family that is there --------------------

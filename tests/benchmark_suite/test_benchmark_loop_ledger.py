@@ -9,9 +9,10 @@ import types
 import pytest
 
 from benchmark import harness, scopes
+from test_benchmark_contract import (CHUNK_LEDGER, EVERY_SERVE_CELL,
+                                     check_declared)
 
-NEW = ["admit_delayed_delivery_pct", "admit_stream_stall_ms",
-       "chunk_interval_ms", "loop_stall_ms"]
+NEW = CHUNK_LEDGER
 BEHIND = {3: 0.104, 7: 0.106}       # chunk -> seconds, behind an admission
 PAUSED = {5: 3.08}                  # a clean chunk a host pause held
 
@@ -88,11 +89,9 @@ def test_a_window_without_an_admission_has_a_step_and_no_stall_cost():
 
 
 def test_the_new_metrics_are_the_engines_in_every_serve_cell():
-    bench = harness.load_benchmark()
-    serve = [w["name"] for w in bench["workloads"]
-             if w["traffic"] == "serve-full"]
-    got = {m["name"]: m for m in bench["per_layer"][-len(NEW):]}
-    assert list(got) == NEW
-    for m in got.values():
-        assert m["layer"] == "engine" and m["workloads"] == serve \
-            and m["better"] == "lower"
+    # found by name: entries that later PRs append stand after these four
+    declared = [m["name"] for m in harness.load_benchmark()["per_layer"]
+                if m["name"] in NEW]
+    assert declared == NEW
+    for name in NEW:
+        check_declared(name, **EVERY_SERVE_CELL[name])

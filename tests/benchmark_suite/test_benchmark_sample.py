@@ -7,6 +7,7 @@ import pytest
 
 from benchmark import harness
 from benchmark import reduce as R
+from test_benchmark_contract import EVERY_SERVE_CELL, check_declared
 
 MS = 1_000_000      # nanoseconds
 
@@ -72,9 +73,4 @@ def test_reader_finds_nothing_where_there_is_nothing_to_read():
 
 
 def test_the_metric_is_declared_for_the_serve_cells():
-    bench = harness.load_benchmark()
-    m = next(x for x in bench["per_layer"] if x["name"] == "decode_sample_ms")
-    assert (m["layer"], m["moves"], m["source"]) \
-        == ("decode math", "tpot_ms", "device_trace")
-    assert m["workloads"] == [w["name"] for w in bench["workloads"]
-                              if harness.Cell(w["name"]).kind == "serve"]
+    check_declared("decode_sample_ms", **EVERY_SERVE_CELL["decode_sample_ms"])
