@@ -312,47 +312,73 @@ def rope_half(x: Array, positions: Array, theta: float) -> Array:
 
 
 def gqa_init(key: Array, dim: int, heads: int, blk,
-             dtype=jnp.float32) -> dict:
+             dtype=jnp.float32, full: bool = False) -> dict:
     """No biases; the norms over a query and a key head have one gain
-    vector for all heads."""
+    vector for all heads. A layer type's key/value heads, and what else
+    the block's fields leave out or add (``WindowGQABlock``): the gate,
+    the two norms, a window layer's sink logits (float32, a query head)."""
     ks = jax.random.split(key, 5)
-    dh, kvh = blk.head_dim, blk.kv_heads
-    return {
+    dh, kvh = blk.head_dim, blk.kv_heads_of(full)
+    dv = blk.v_head_dim or dh
+    out = {
         "q": core.linear_init(ks[0], dim, heads * dh, bias=False,
                               dtype=dtype),
         "k": core.linear_init(ks[1], dim, kvh * dh, bias=False, dtype=dtype),
-        "v": core.linear_init(ks[2], dim, kvh * dh, bias=False, dtype=dtype),
-        "gate": core.linear_init(ks[3], dim, heads * dh, bias=False,
-                                 dtype=dtype),
-        "q_ln": core.rmsnorm_init(dh, dtype),
-        "k_ln": core.rmsnorm_init(dh, dtype),
-        "out": core.linear_init(ks[4], heads * dh, dim, bias=False,
+        "v": core.linear_init(ks[2], dim, kvh * dv, bias=False, dtype=dtype),
+        "out": core.linear_init(ks[4], heads * dv, dim, bias=False,
                                 dtype=dtype),
     }
+    if blk.out_gate:
+        out["gate"] = core.linear_init(ks[3], dim, heads * dh, bias=False,
+                                       dtype=dtype)
+    if blk.qk_norm:
+        out["q_ln"] = core.rmsnorm_init(dh, dtype)
+        out["k_ln"] = core.rmsnorm_init(dh, dtype)
+    if blk.sink and not full:
+        out["sink"] = jnp.zeros((heads,), jnp.float32)
+    return out
 
 
 def gqa_project(params: dict, h: Array, positions: Array, heads: int, blk,
-                rotary: bool):
+                full: bool):
     """h (..., dim) normed input, ``positions`` broadcastable to
-    ``h.shape[:-1]`` -> (q (..., heads, dh), gate (..., heads * dh), (k,
-    v) each (..., kv_heads, dh): the rows to cache). Query and key heads
-    are normed; ``rotary`` (a sliding layer) turns them by position, a
-    full layer's carry no position."""
-    dh, kvh = blk.head_dim, blk.kv_heads
+    ``h.shape[:-1]`` -> (q (..., heads, dh), gate (..., heads * dh) or
+    None, (k (..., kv_heads, dh), v (..., kv_heads, dv)): the rows to
+    cache), the key/value heads those of the layer's type (``full`` or
+    window). Query and key heads are normed where the block has such
+    norms and turned by position at their layer type's rotary base
+    (``blk.rope_theta_of``; None: a layer that carries no position), on
+    their first ``rotary_dim`` numbers where the block turns a part; the
+    values are multiplied by ``value_scale``."""
+    dh, kvh = blk.head_dim, blk.kv_heads_of(full)
     lead = h.shape[:-1]
     with jax.named_scope("attn.proj"):
         q = core.linear(params["q"], h).reshape(lead + (heads, dh))
         k = core.linear(params["k"], h).reshape(lead + (kvh, dh))
-        v = core.linear(params["v"], h).reshape(lead + (kvh, dh))
-        gate = core.linear(params["gate"], h)
-    q = core.rmsnorm(params["q_ln"], q, eps=blk.norm_eps)
-    k = core.rmsnorm(params["k_ln"], k, eps=blk.norm_eps)
-    if rotary:
+        v = core.linear(params["v"], h).reshape(lead + (kvh, -1))
+        gate = core.linear(params["gate"], h) if "gate" in params else None
+        if blk.value_scale != 1.0:
+            v = v * jnp.asarray(blk.value_scale, v.dtype)
+    if "q_ln" in params:
+        q = core.rmsnorm(params["q_ln"], q, eps=blk.norm_eps)
+        k = core.rmsnorm(params["k_ln"], k, eps=blk.norm_eps)
+    theta = blk.rope_theta_of(full)
+    if theta is not None:
         with jax.named_scope("attn.proj"):
             at = jnp.asarray(positions)[..., None]
-            q = rope_half(q, at, blk.rope_theta)
-            k = rope_half(k, at, blk.rope_theta)
+            q = rope_part(q, at, theta, blk.rotary_dim)
+            k = rope_part(k, at, theta, blk.rotary_dim)
     return q, gate, (k, v)
+
+
+def rope_part(x: Array, positions: Array, theta: float,
+              turned: Optional[int]) -> Array:
+    """``rope_half`` on the first ``turned`` numbers of the last axis (the
+    pairs lie inside them), the others left as they are; None: on all."""
+    if turned is None or turned == x.shape[-1]:
+        return rope_half(x, positions, theta)
+    return jnp.concatenate([rope_half(x[..., :turned], positions, theta),
+                            x[..., turned:]], axis=-1)
 
 
 def _read_scope(window: bool):
@@ -363,12 +389,17 @@ def _read_scope(window: bool):
 
 def gqa_attend_materialised(q: Array, k: Array, v: Array, allowed: Array,
                             scale: float, window: bool,
-                            diff_lam: Optional[Array] = None) -> Array:
-    """The prefill read. q (b, n, heads, dh), k / v (b, m, kv_heads, dh),
-    allowed broadcastable to (b, 1, n, m) -> (b, n, heads, dh). ``window``
-    names the read in a trace (``_read_scope``); the window itself is in
-    ``allowed``. With ``diff_lam`` the read is the differential one
-    (``_pair_weights``) -> (b, n, heads / 2, 2 dh)."""
+                            diff_lam: Optional[Array] = None,
+                            sink: Optional[Array] = None):
+    """The prefill read. q (b, n, heads, dh), k (b, m, kv_heads, dh), v
+    (b, m, kv_heads, dv), allowed broadcastable to (b, 1, n, m) -> (b, n,
+    heads, dv). ``window`` names the read in a trace (``_read_scope``);
+    the window itself is in ``allowed``. With ``diff_lam`` the read is the
+    differential one (``_pair_weights``) -> (b, n, heads / 2, 2 dh). With
+    ``sink`` (heads,), a learned logit a query head, the softmax's
+    denominator holds ``exp(sink)`` beside the rows' terms and no value
+    answers it -> (the output, the sink's weight summed over the heads
+    (b, n) float32)."""
     b, n, heads, dh = q.shape
     kvh = k.shape[2]
     with _read_scope(window):
@@ -377,27 +408,37 @@ def gqa_attend_materialised(q: Array, k: Array, v: Array, allowed: Array,
                           preferred_element_type=jnp.float32) * scale
         dots = jnp.where(allowed[:, :, None], dots,
                          core.neg_inf(dots.dtype))
+        if sink is not None:
+            dots = jnp.concatenate([dots, jnp.broadcast_to(
+                sink.astype(dots.dtype).reshape(kvh, -1, 1, 1),
+                dots.shape[:-1] + (1,))], axis=-1)
         w = jax.nn.softmax(dots, axis=-1)
+        if sink is not None:
+            w, mass = w[..., :-1], jnp.sum(w[..., -1], axis=(1, 2))
         if diff_lam is not None:
             w = _pair_weights(w, diff_lam)
             v = v.reshape(v.shape[:2] + (kvh // 2, 2 * dh))
         o = jnp.einsum("bkgij,bjkd->bikgd", w.astype(v.dtype), v)
-        return o.reshape((b, n, -1, v.shape[-1]))
+        o = o.reshape((b, n, -1, v.shape[-1]))
+        return o if sink is None else (o, mass)
 
 
 def gqa_attend_rows(q: Array, k: Array, v: Array, rows_k: Array,
                     gather_v, allowed: Array, scale: float,
                     window: bool, diff_lam: Optional[Array] = None,
                     k_scale: Optional[Array] = None, gather_v_scale=None,
-                    per_head: bool = False) -> Array:
-    """The decode read, one query a slot: q (b, heads, dh); k / v (b,
-    kv_heads, dh) the token's own rows (always attended); ``rows_k`` (b,
-    m, kv_heads * dh) the cached K rows as they lie in the gathered pages,
-    every key/value head's dh numbers side by side in a row, and
-    ``gather_v(wts)`` the V rows the same way, asked for once K's readers
-    are done (the budget of a slot group is ONE gathered buffer:
-    ops/decode.py ``view_slot_groups``); ``allowed`` (b, m) -> (b, heads,
-    dh). The classic block's read is this one at ``kv_heads == heads``.
+                    per_head: bool = False,
+                    sink: Optional[Array] = None):
+    """The decode read, one query a slot: q (b, heads, dh); k (b,
+    kv_heads, dh) / v (b, kv_heads, dv) the token's own rows (always
+    attended); ``rows_k`` (b, m, kv_heads * dh) the cached K rows as they
+    lie in the gathered pages, every key/value head's dh numbers side by
+    side in a row, and ``gather_v(wts)`` the V rows (b, m, kv_heads * dv)
+    the same way, asked for once K's readers are done (the budget of a
+    slot group is ONE gathered buffer: ops/decode.py
+    ``view_slot_groups``); ``allowed`` (b, m) -> (b, heads, dv). The
+    classic block's read is this one at ``kv_heads == heads``; a value
+    head may be narrower than a key head (``dv`` is ``v``'s).
 
     The rows are contracted whole, all query heads at once, as the latent
     block's absorbed read contracts its rows: a query head is given zeros
@@ -419,9 +460,13 @@ def gqa_attend_rows(q: Array, k: Array, v: Array, rows_k: Array,
     dequantised copy of a page is made. ``window`` names the read in a
     trace. With ``diff_lam`` the read is the differential one: the same
     products and softmaxes, the pairing (``_pair_weights``) after them
-    -> (b, heads / 2, 2 dh); it has no int8 form."""
+    -> (b, heads / 2, 2 dh); it has no int8 form. With ``sink`` (heads,),
+    a learned logit a query head, the softmax holds one term more, which
+    no value answers -> (the output, the sink's weight summed over the
+    heads (b,) float32)."""
     b, heads, dh = q.shape
     kvh = k.shape[1]
+    dv = v.shape[-1]
     g = heads // kvh
     qg = q.reshape(b, kvh, g, dh)
 
@@ -445,16 +490,21 @@ def gqa_attend_rows(q: Array, k: Array, v: Array, rows_k: Array,
                            core.neg_inf(scores.dtype))
         own = jnp.einsum("bkgd,bkd->bkg", qg, k,
                          preferred_element_type=jnp.float32) * scale
-        wts = jax.nn.softmax(jnp.concatenate(
-            [scores, own.reshape(b, heads, 1)], axis=-1), axis=-1)
+        logits = [scores, own.reshape(b, heads, 1)]
+        if sink is not None:
+            logits.append(jnp.broadcast_to(
+                sink.astype(scores.dtype)[None, :, None], (b, heads, 1)))
+        wts = jax.nn.softmax(jnp.concatenate(logits, axis=-1), axis=-1)
+        if sink is not None:
+            wts, mass = wts[..., :-1], jnp.sum(wts[..., -1], axis=-1)
         if diff_lam is not None:
             # from here on a pair of heads is one head of twice the width
             # over a pair of key/value heads
             wts = _pair_weights(
                 wts.reshape(b, kvh, g, -1), diff_lam
             ).reshape(b, heads // 2, -1)
-            heads, kvh, dh = heads // 2, kvh // 2, 2 * dh
-            v = v.reshape(b, kvh, dh)
+            heads, kvh, dv = heads // 2, kvh // 2, 2 * dv
+            v = v.reshape(b, kvh, dv)
         wts = wts.astype(v.dtype)
     rows_v = gather_v(wts)
     with _read_scope(window):
@@ -464,15 +514,16 @@ def gqa_attend_rows(q: Array, k: Array, v: Array, rows_k: Array,
             rows_v = rows_v.astype(wj.dtype)
         if per_head:
             o = jnp.einsum("bkgj,bjkd->bkgd", wj.reshape(b, kvh, g, -1),
-                           rows_v.reshape(b, -1, kvh, dh))
+                           rows_v.reshape(b, -1, kvh, dv))
         else:
             o_rows = jnp.einsum("bhj,bjc->bhc", wj, rows_v)
             # of each whole-row sum, the columns of the head's own kv head
             o = jnp.einsum("bkgjd,kj->bkgd",
-                           o_rows.reshape(b, kvh, g, kvh, dh),
+                           o_rows.reshape(b, kvh, g, kvh, dv),
                            jnp.eye(kvh, dtype=o_rows.dtype))
         o = o + wts[..., -1].reshape(b, kvh, g, 1) * v[:, :, None, :]
-        return o.reshape(b, heads, dh)
+        o = o.reshape(b, heads, dv)
+        return o if sink is None else (o, mass)
 
 
 def _pair_weights(w: Array, lam: Array) -> Array:
@@ -497,11 +548,13 @@ def _own_columns(qg: Array) -> Array:
 
 
 @jax.named_scope("attn.proj")
-def gqa_out(params: dict, o: Array, gate: Array) -> Array:
-    """(..., heads, dh), gate (..., heads * dh) -> (..., dim): the output
-    gate and the output projection."""
+def gqa_out(params: dict, o: Array, gate: Optional[Array]) -> Array:
+    """(..., heads, dv), gate (..., heads * dv) or None -> (..., dim): the
+    output gate, where the block has one, and the output projection."""
     o = o.reshape(o.shape[:-2] + (-1,))
-    return core.linear(params["out"], o * jax.nn.sigmoid(gate))
+    if gate is not None:
+        o = o * jax.nn.sigmoid(gate)
+    return core.linear(params["out"], o)
 
 
 # ---------------------------------------------------------------------------

@@ -404,9 +404,13 @@ def _read_in_slot_groups(pool: dict, view: _View, read) -> Array:
     widths = view.widths or (columns,) * groups
     outs = [read(slice(g * per, (g + 1) * per), widths[g])
             for g in range(groups)]
+    # (a read may give more than its output, each a row a slot: a sink's
+    # weight, ``ops.attention.gqa_attend_rows``)
     with attn_ops._read_scope(view.window):
-        out = outs[0] if groups == 1 else jnp.concatenate(outs)
-        return out if view.inverse is None else out[view.inverse]
+        out = outs[0] if groups == 1 else jax.tree.map(
+            lambda *parts: jnp.concatenate(parts), *outs)
+        return out if view.inverse is None else jax.tree.map(
+            lambda a: a[view.inverse], out)
 
 
 def _refuse_block(cfg, option: str, why: str = "") -> None:
@@ -1624,7 +1628,8 @@ def _block_reads(cfg, pool: dict, block_tables: dict, pos: Array,
                     lambda wts: _gathered_rows(
                         pool[v_name], layer, t, window,
                         after=wts if several else None),
-                    allowed[sl, :w * ps], cfg.scale, window, diff_lam=lam)
+                    allowed[sl, :w * ps], cfg.scale, window, diff_lam=lam,
+                    sink=p.get("sink"))
             return at_profile(profile, widths, rows, view, read_group)
         return read
     return read_of, full[True][2] if full else None
@@ -1670,7 +1675,11 @@ def decode_step_block(params: dict, x_tok: Array, pos: Array, pool: dict,
     layer's step against its slot's state. The new rows and states are
     written after the scan; an inactive slot's state stays as it was.
     x_tok (b, dim), pos (b,) -> (h_out (b, dim), pool, load int32: the
-    routed layers' load summed over them, ops.moe.dropless_apply)."""
+    routed layers' load summed over them, ops.moe.dropless_apply). Of a
+    block whose window layers hold a sink the load is float32 and two
+    numbers longer (``load_like``): the sink's softmax weight summed over
+    the window layers, the query heads and the ACTIVE slots, and the
+    number of softmaxes that is summed over."""
     from dalle_pytorch_tpu.ops import transformer as T
     if not isinstance(block_tables, dict):      # one pool: its one table
         block_tables = {"full": block_tables}
@@ -1687,9 +1696,28 @@ def decode_step_block(params: dict, x_tok: Array, pos: Array, pool: dict,
                            key_mask.shape[1])
     span = None if plan is None or plan.span is None else (
         *plan.span, functools.partial(_by_width_profile, profile))
-    h_out, entries, loads = T.block_stack(params, x_tok, layer_fn, cfg, span)
+    h_out, entries, loads, shared = T.block_stack(params, x_tok, layer_fn,
+                                                  cfg, span)
+    load = jnp.sum(loads, axis=0)
+    if cfg.block.sink:
+        with jax.named_scope("attn.window"):
+            reads = len(cfg.block.cache_layers("window", cfg.depth)) \
+                * cfg.heads * jnp.sum(active)
+            load = jnp.concatenate([load, jnp.stack([
+                jnp.sum(jnp.where(active, shared["sink_mass"], 0.0)),
+                reads])], dtype=load_like(cfg.block).dtype)
     return (h_out, _store_block_rows(cfg, pool, entries, pos, block_tables,
-                                     active), jnp.sum(loads, axis=0))
+                                     active), load)
+
+
+def load_like(blk) -> jax.ShapeDtypeStruct:
+    """What a described block's decode step counts
+    (``decode_step_block``): ``ops.moe.load_width`` int32s; with a sink
+    two more, and all of them float32, since the sink's weight is one (the
+    counts stay whole: a chunk's are far under 2 ** 24)."""
+    if blk.sink:
+        return jax.ShapeDtypeStruct((load_width(blk) + 2,), jnp.float32)
+    return jax.ShapeDtypeStruct((load_width(blk),), jnp.int32)
 
 
 def decode_step_paged(params: dict, x_tok: Array, pos: Array, pool: dict,
@@ -1744,9 +1772,9 @@ def decode_loop_paged(params: dict, cur_tok: Array, pos: Array,
 
     A described block (``cfg.block``) runs the gather step with its own
     branches (``decode_step_block``) and the program returns one value
-    more, after the ring: the routed layers' load (``ops.moe.load_width``
-    int32s) summed over the chunk's steps, for the engine to fetch with
-    the ring; its ``block_tables`` are what ``decode_step_block`` takes
+    more, after the ring: the routed layers' load (``load_like``) summed
+    over the chunk's steps, for the engine to fetch with the ring; its
+    ``block_tables`` are what ``decode_step_block`` takes
     (a table a pool for a window-and-full block)."""
     blk = cfg.block
     if blk is not None:
@@ -1777,8 +1805,10 @@ def decode_loop_paged(params: dict, cur_tok: Array, pos: Array,
         pos = jnp.where(act, pos, 0)
         return (cur_tok, pos, act, pool, *load), emit
 
-    load0 = () if blk is None else (
-        jnp.zeros((load_width(blk),), jnp.int32),)
+    load0 = ()
+    if blk is not None:
+        like = load_like(blk)
+        load0 = (jnp.zeros(like.shape, like.dtype),)
     (cur_tok, pos, active, pool, *load), emits = lax.scan(
         one_step, (cur_tok, pos, active, pool, *load0), None, length=steps)
     return (cur_tok, pos, active, pool, jnp.moveaxis(emits, 0, 1), *load)

@@ -166,11 +166,11 @@ def load_width(blk) -> int:
 def dropless_init(key: Array, dim: int, blk, dtype=jnp.float32) -> dict:
     """Router (with its selection bias) over every expert, the HELD
     experts' stacked SiLU-gated units (gate and up side by side in
-    ``w_in``) and the shared unit."""
+    ``w_in``) and the shared unit (none where ``shared_hidden`` is 0)."""
     k_r, k_in, k_out, k_s = jax.random.split(key, 4)
     e, he = blk.num_experts, blk.expert_hidden
     held = blk.experts_held
-    return {
+    out = {
         "router": {"w": core.uniform_fan_in(k_r, (dim, e), dim, dtype),
                    "bias": jnp.zeros((e,), jnp.float32)},
         "experts": {
@@ -178,8 +178,10 @@ def dropless_init(key: Array, dim: int, blk, dtype=jnp.float32) -> dict:
                                         dtype),
             "w_out": core.uniform_fan_in(k_out, (held, he, dim), he,
                                          dtype)},
-        "shared": core.swiglu_init(k_s, dim, blk.shared_hidden, dtype),
     }
+    if blk.shared_hidden:
+        out["shared"] = core.swiglu_init(k_s, dim, blk.shared_hidden, dtype)
+    return out
 
 
 @jax.named_scope("moe.route")
@@ -265,8 +267,9 @@ def dropless_apply(params: dict, x: Array, blk):
     whole = holds_all(blk)
     out, sizes = dropless_experts(params["experts"], xt, picks, weights,
                                   None if whole else blk.first_expert)
-    with jax.named_scope("moe.shared"):
-        out = out + core.swiglu(params["shared"], xt)
+    if "shared" in params:
+        with jax.named_scope("moe.shared"):
+            out = out + core.swiglu(params["shared"], xt)
     held = jnp.sum(sizes)
     load = [held if whole else jnp.int32(picks.size),
             jnp.sum(sizes > 0).astype(jnp.int32), jnp.max(sizes)]

@@ -153,6 +153,7 @@ class DescribedBlock:
     period = 1      # kinds in the pattern that the stack repeats
     tied_head = False   # an output head of its own (models/dalle.py)
     layer_norms = False     # RMSNorms (a gain alone), not LayerNorms
+    sink = False    # no learned logit beside a window softmax's rows
 
     @staticmethod
     def stack_of(kind: LayerKind) -> str:
@@ -183,6 +184,13 @@ class DescribedBlock:
         stack, beside the residual stream (``block_layer``'s ``shared``),
         as zeros shaped after the stream ``x``."""
         return {}
+
+    def buffer_row_width(self, name: str) -> int:
+        """Numbers in one row of the page-pool buffer ``name``
+        (``pool_buffers``): a width a BUFFER (serve/kv_pool.py
+        ``page_layout``). One width serves every buffer of a block whose
+        cached rows are all alike."""
+        return self.page_row_width
 
     def ring_pages(self, page_size: int, total_len: int) -> int:
         """Pages a slot holds of a window layer at most: the window and
@@ -284,7 +292,31 @@ class WindowGQABlock(DescribedBlock):
     all ``num_experts`` while the chip HOLDS ``experts_held`` of them from
     ``first_expert`` on (ops/moe.py: the picks that fall on other experts
     take no part). Token embeddings enter times ``embed_scale``.
-    ``dim_head`` and ``ff_mult`` of the configuration are not read."""
+    ``dim_head`` and ``ff_mult`` of the configuration are not read.
+
+    The fields from ``full_kv_heads`` on describe window-and-full
+    configurations whose two layer types differ in more than the mask;
+    their defaults are the block above, unchanged:
+
+      * ``full_kv_heads``: key/value heads of a FULL layer (None: as many
+        as a window layer's ``kv_heads``). Where they differ a layer
+        type's K and V projections differ in shape, so the full layers
+        lie in parameter stacks of their own (``stack_of``), and the two
+        pools' rows differ in width (``buffer_row_width``);
+      * ``v_head_dim``: a value head's numbers (None: ``head_dim``, which
+        queries and keys always have): a K row is then wider than a V row;
+      * ``rotary_dim``: the leading numbers of a query or key head that
+        rotary positions turn (None: all ``head_dim``);
+      * ``full_rope_theta``: the rotary base of a full layer (None: a
+        full layer carries no position);
+      * ``value_scale``: what the projected values are multiplied by;
+      * ``qk_norm`` / ``out_gate`` / ``sandwich_norms``: whether a layer
+        has the norms over a query and a key head, the output gate, and
+        the second norm a branch;
+      * ``sink``: a window layer's softmax holds one learned logit a
+        query head beside its rows' (it joins the denominator and takes
+        no value, so a row's weights sum to less than 1);
+      * ``shared_hidden`` 0: a routed layer without a shared expert."""
     kv_heads: int = 8
     head_dim: int = 128
     window: int = 4096
@@ -302,6 +334,15 @@ class WindowGQABlock(DescribedBlock):
     first_expert: int = 0
     embed_scale: float = 1.0
     name: str = "window_gqa_moe"
+    full_kv_heads: Optional[int] = None
+    v_head_dim: Optional[int] = None
+    rotary_dim: Optional[int] = None
+    full_rope_theta: Optional[float] = None
+    value_scale: float = 1.0
+    qk_norm: bool = True
+    out_gate: bool = True
+    sandwich_norms: bool = True
+    sink: bool = False
 
     def __post_init__(self):
         bad = set(self.layer_types) - {"sliding", "full"}
@@ -315,6 +356,10 @@ class WindowGQABlock(DescribedBlock):
                 f"experts {self.first_expert}..{self.first_expert} + "
                 f"{self.experts_held} are not a share of "
                 f"{self.num_experts}")
+        turned = self.rotary_dim or self.head_dim
+        if turned % 2 or not 0 < turned <= self.head_dim:
+            raise ValueError(f"rotary_dim {turned}: an even number of a "
+                             f"head's {self.head_dim}")
 
     @property
     def score_dim(self) -> int:
@@ -332,10 +377,44 @@ class WindowGQABlock(DescribedBlock):
     def mixer_of(kind: LayerKind) -> str:
         return "gqa"
 
+    def kv_heads_of(self, full: bool) -> int:
+        """Key/value heads of a layer of one type."""
+        return self.full_kv_heads if full and self.full_kv_heads \
+            else self.kv_heads
+
+    def rope_theta_of(self, full: bool) -> Optional[float]:
+        """The rotary base of a layer of one type; None: no position."""
+        return self.full_rope_theta if full else self.rope_theta
+
     @property
-    def page_row_width(self) -> int:
-        """A cached row: every key/value head's numbers side by side."""
-        return self.kv_heads * self.head_dim
+    def types_differ(self) -> bool:
+        """Whether a full layer's parameters differ in shape from a
+        window layer's (its key/value heads, or the sink it lacks)."""
+        return self.sink or self.kv_heads_of(True) != self.kv_heads
+
+    def stack_of(self, kind: LayerKind) -> str:
+        """``"dense"`` / ``"moe"``; where the layer types differ in shape
+        the full layers' stacks go by ``"dense_full"`` / ``"moe_full"``."""
+        name = "moe" if kind.moe else "dense"
+        return name + "_full" if kind.full and self.types_differ else name
+
+    def carried(self, x: Array) -> dict:
+        """Of a block with a sink: the weight that the sinks of the window
+        layers so far took, summed over them and the query heads, a
+        number a token of ``x`` (a counter, ops/decode.py
+        ``decode_step_block``; no layer reads it)."""
+        if not self.sink:
+            return {}
+        return {"sink_mass": jnp.zeros(x.shape[:-1], jnp.float32)}
+
+    def buffer_row_width(self, name: str) -> int:
+        """A cached row of one buffer, every key/value head's numbers side
+        by side: the key/value heads of its layer type (``window_*``: the
+        window layers') times a key head's numbers (``*k``) or a value
+        head's."""
+        heads = self.kv_heads_of(not name.startswith("window_"))
+        return heads * (self.head_dim if name.endswith("k")
+                        else self.v_head_dim or self.head_dim)
 
 
 MIXER_NAMES = ("ssm", "window", "full", "cross", "gmu")
@@ -567,8 +646,10 @@ def block_layer_init(key: Array, cfg: TransformerConfig, kind: LayerKind,
     norm = {"ln": (core.layernorm_init if blk.layer_norms
                    else core.rmsnorm_init)(cfg.dim, dtype)}
     if mixer == "gqa":
-        norm["post_ln"] = core.rmsnorm_init(cfg.dim, dtype)
-        attn = attn_ops.gqa_init(k_attn, cfg.dim, cfg.heads, blk, dtype)
+        if blk.sandwich_norms:
+            norm["post_ln"] = core.rmsnorm_init(cfg.dim, dtype)
+        attn = attn_ops.gqa_init(k_attn, cfg.dim, cfg.heads, blk, dtype,
+                                 full=kind.full)
     elif mixer == "latent":
         attn = attn_ops.latent_init(k_attn, cfg.dim, cfg.heads, blk, dtype)
     elif mixer == "ssm":
@@ -617,10 +698,10 @@ def is_block_params(params: dict) -> bool:
 def block_name_of(params: dict) -> str:
     """Which described block a transformer subtree holds, for a caller
     that has parameters and no configuration to name in its refusal."""
-    if not {"dense", "moe"} & set(params):
+    if not any(name.startswith(("dense", "moe")) for name in params):
         return SSMHybridBlock.name      # ``stack_of``: stacks by mixer
     attn = next(iter(params.values()))["attn"]
-    return WindowGQABlock.name if "gate" in attn else LatentMoEBlock.name
+    return LatentMoEBlock.name if "k_up" in attn else WindowGQABlock.name
 
 
 # ---------------------------------------------------------------------------
@@ -783,9 +864,16 @@ def _mix_latent(p, hn, positions, read, shared, cfg, run):
 def _mix_gqa(p, hn, positions, read, shared, cfg, run):
     blk = cfg.block
     q, gate, entry = attn_ops.gqa_project(
-        p, hn, positions, cfg.heads, blk, rotary=not run.full)
-    a = attn_ops.gqa_out(p, read(p, q, entry), gate)
-    return core.rmsnorm(p["post_ln"], a, eps=blk.norm_eps), entry, shared
+        p, hn, positions, cfg.heads, blk, full=run.full)
+    o = read(p, q, entry)
+    if "sink" in p:
+        # a window layer's read gives the sink's weight beside its output
+        o, mass = o
+        shared = {**shared, "sink_mass": shared["sink_mass"] + mass}
+    a = attn_ops.gqa_out(p, o, gate)
+    if "post_ln" in p:
+        a = core.rmsnorm(p["post_ln"], a, eps=blk.norm_eps)
+    return a, entry, shared
 
 
 def _mix_ssm(p, hn, positions, read, shared, cfg, run):
@@ -862,7 +950,7 @@ def block_stack(params: dict, h: Array, layer_fn, cfg, span=None):
     reads (``run.cache`` + the index in the run) and ``run`` static. ->
     (h, entries: ``{buffer: what the layers that store to it gave, stacked
     over them}`` for every buffer of ``blk.pools``, loads stacked over the
-    whole depth).
+    whole depth, ``shared`` as the last layer left it).
 
     ``span`` ``(first, stop, around)`` runs the scans ``first`` up to
     ``stop`` as ONE function of a static ``choice``, which ``around``
@@ -945,7 +1033,7 @@ def block_stack(params: dict, h: Array, layer_fn, cfg, span=None):
         rows = joined(parts)
         names = blk.pool_buffers(pool)
         entries.update(zip(names, rows if len(names) > 1 else (rows,)))
-    return carry[0], entries, joined(loads)
+    return carry[0], entries, joined(loads), carry[1]
 
 
 def block_apply_full(params: dict, x: Array, cfg: TransformerConfig,
@@ -991,10 +1079,11 @@ def block_apply_full(params: dict, x: Array, cfg: TransformerConfig,
             return attn_ops.gqa_attend_materialised(
                 query, *entry, allowed if run.full else in_window,
                 cfg.scale, window=not run.full,
-                diff_lam=attn_ops.diff_lambda(p) if "lam" in p else None)
+                diff_lam=attn_ops.diff_lambda(p) if "lam" in p else None,
+                sink=p.get("sink"))
         return block_layer(lp, h, shared, positions, read, cfg, run)
 
-    return block_stack(params, x, layer_fn, cfg)
+    return block_stack(params, x, layer_fn, cfg)[:3]
 
 
 # ---------------------------------------------------------------------------

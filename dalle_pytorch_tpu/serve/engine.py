@@ -176,6 +176,21 @@ STALLS_KEPT = 8         # stats()["last_stalls"]
 # its experts, the picks that fell on the share
 MOE_COUNTERS = ("moe_picks", "moe_experts_touched", "moe_load_max",
                 "moe_picks_held")
+# of a block whose window layers' softmax holds a learned sink
+# (ops.transformer.WindowGQABlock.sink): the sink's softmax weight summed
+# over the window layers, the query heads, the active slots and every
+# decode step, and the number of softmaxes it is summed over: the last two
+# numbers of such a block's load (ops.decode.decode_step_block)
+SINK_COUNTERS = ("window_sink_mass", "window_sink_reads")
+
+
+def load_counters(blk) -> tuple:
+    """The names of a described block's load, in the order in which its
+    decode program returns them after the ring (``moe_picks_held`` only
+    where the block holds a share, the sink's two only where it has one)."""
+    from dalle_pytorch_tpu.ops.moe import load_width
+    return MOE_COUNTERS[:load_width(blk)] + (
+        SINK_COUNTERS if blk.sink else ())
 
 
 def _phase(name: str, **meta):
@@ -324,7 +339,7 @@ class _Chunk:
         self.ring = ring
         self.active = active
         self.owners = owners
-        self.load = load        # MOE_COUNTERS of the chunk, or None
+        self.load = load        # load_counters of the chunk, or None
         self.chunk = chunk      # the dispatch's number: its spans',
         #                         annotations' and ledger row's cause key
         self.t_dispatch = t_dispatch
@@ -772,6 +787,8 @@ class Engine:
         #                                 round's truncation"
         for k in MOE_COUNTERS:          # a described block's routed load
             setattr(self, k, 0)
+        self.window_sink_mass = 0.0     # SINK_COUNTERS: a weight, a count
+        self.window_sink_reads = 0
         self.kv_view_groups = 1         # slot groups a layer's paged gather
         #                                 read was traced with (ops.decode
         #                                 view_slot_groups; 1 = no such read)
@@ -2278,8 +2295,9 @@ class Engine:
         # clock (docs/OBSERVABILITY.md): a reading, not duration math
         unix_ns = time.time_ns()
         if load is not None:
-            for k, v in zip(MOE_COUNTERS, load):
-                setattr(self, k, getattr(self, k) + int(v))
+            for k, v in zip(load_counters(self.block), load):
+                have = getattr(self, k)     # an int; a sink's weight a float
+                setattr(self, k, have + type(have)(v))
         with _phase("engine.deliver", chunk=rec.chunk):
             tokens = self._deliver_chunk(rec, ring, active_after)
         self._ledger_row(rec, t_got, unix_ns, tokens)
@@ -3394,10 +3412,7 @@ class Engine:
             }
         moe = {}
         if self.block is not None:
-            from dalle_pytorch_tpu.ops.moe import load_width
-            # (moe_picks_held only where the block holds a share)
-            moe = {k: getattr(self, k)
-                   for k in MOE_COUNTERS[:load_width(self.block)]}
+            moe = {k: getattr(self, k) for k in load_counters(self.block)}
         return {
             "kv": self.kv,
             "kv_hbm_bytes": self.kv_hbm_bytes(),

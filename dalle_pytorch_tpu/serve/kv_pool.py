@@ -181,7 +181,11 @@ def page_layout(cfg, page_size: int, quantized: bool = False) -> dict:
         each (no head axis between page and row: the read contracts whole
         rows, as the latent block's does), in two pools, one a layer type
         (``pool_plan``): ``k`` / ``v`` hold the full layers, ``window_k``
-        / ``window_v`` the window layers;
+        / ``window_v`` the window layers. The width is a BUFFER's
+        (``DescribedBlock.buffer_row_width``): where a key head is wider
+        than a value head, or the layer types differ in key/value heads,
+        the four buffers have four widths, and everything below follows
+        this table buffer by buffer;
       * a block with state-space layers holds, beside its page pools, a
         cache that is NOT pages: a fixed-size recurrent state a slot a
         layer, ``ssm_state`` ``(d_state, d_inner)`` (the wide dimension
@@ -200,8 +204,8 @@ def page_layout(cfg, page_size: int, quantized: bool = False) -> dict:
                 out[names[0]] = ((blk.d_state, blk.d_inner), 4)
                 out[names[1]] = ((blk.d_conv - 1, blk.d_inner), None)
             else:
-                out.update({name: ((page_size, blk.page_row_width), None)
-                            for name in names})
+                out.update({name: ((page_size, blk.buffer_row_width(name)),
+                                   None) for name in names})
         return out
     page = (page_size, cfg.heads * cfg.dim_head)
     if quantized:

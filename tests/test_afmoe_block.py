@@ -337,7 +337,7 @@ def test_cached_rows_read_equals_the_materialised_read():
     b, m = 3, 2 * PS
     h = jax.random.normal(k[1], (b, m + 1, 32))
     q, gate, (kk, vv) = attn_ops.gqa_project(p, h, jnp.arange(m + 1), 4, BLK,
-                                             rotary=True)
+                                             full=False)
     assert q.shape == (b, m + 1, 4, 8) and kk.shape == (b, m + 1, 2, 8)
     allowed = jax.random.bernoulli(k[2], 0.7, (b, m))
     full = jnp.concatenate([allowed, jnp.ones((b, 1), bool)], axis=1)
