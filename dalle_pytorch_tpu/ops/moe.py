@@ -17,7 +17,15 @@ of an expert-parallel deployment: ``blk.first_expert``,
 ``blk.experts_held``): the router still scores and picks among all of
 them, the pairs whose expert is held elsewhere lie in no group and add
 nothing, and the load says how many picks were held. Nothing stands in
-for the absent chips: their part of the sum is left out.
+for the absent chips: their part of the sum is left out. What an absent
+pair adds is nothing; what its row COSTS, handed to the grouped products,
+is a row of every touched group's tile, since the kernel multiplies all
+the rows it is given against each group that holds any. The absent pairs
+are sorted behind the held ones, so a share's products are handed the
+first R sorted rows alone: R the least step of a short static ladder
+(``row_ladder``: from the shapes, twice the rows an even load brings,
+that doubled, every pair) that holds the step's held pairs, by one
+``lax.switch`` a layer, and the load says which step was taken.
 
 **Capacity routing** (``moe_apply``; the trainable ``moe_experts`` option
 of the classic block, expert-parallel over an ``ep`` axis): the standard
@@ -35,6 +43,7 @@ serving a routed block uses the dropless path above.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Optional, Tuple
 
 import jax
@@ -157,10 +166,11 @@ def holds_all(blk) -> bool:
 def load_width(blk) -> int:
     """Entries of a routed layer's load: picks routed, experts touched,
     the fullest expert's picks; and, where a share is held, the picks
-    that fell on it. None for a block without routed layers."""
+    that fell on it and the rows handed to the grouped products
+    (``dropless_experts``). None for a block without routed layers."""
     if not blk.num_experts:
         return 0
-    return 3 if holds_all(blk) else 4
+    return 3 if holds_all(blk) else 5
 
 
 def dropless_init(key: Array, dim: int, blk, dtype=jnp.float32) -> dict:
@@ -196,17 +206,41 @@ def route(router: dict, x: Array, k: int, scale: float):
     return picks, scale * picked / jnp.sum(picked, axis=-1, keepdims=True)
 
 
+def row_ladder(pairs: int, held: int, num_experts: int) -> Tuple[int, ...]:
+    """The row counts at which a block that holds ``held`` of its
+    ``num_experts`` experts runs the grouped products of ``pairs`` (token,
+    pick) pairs, from shapes alone: twice the rows an even load brings the
+    held experts, rounded up to a power of two and not under 64; that
+    doubled; and every pair. Steps at or over ``pairs`` fall away, so the
+    ladder of a call whose first step already holds every pair is
+    ``(pairs,)``: no ladder."""
+    expected = -(-2 * pairs * held // num_experts)
+    step = max(64, 1 << (expected - 1).bit_length())
+    return tuple(r for r in (step, 2 * step) if r < pairs) + (pairs,)
+
+
 def dropless_experts(experts: dict, x: Array, picks: Array,
-                     weights: Array, first: Optional[int] = None):
+                     weights: Array, first: Optional[int] = None,
+                     num_experts: Optional[int] = None):
     """Every (token, pick) pair through its expert, summed per token with
     its weight. x (t, dim), picks / weights (t, k) -> (out (t, dim),
-    sizes (E,) int32: picks each HELD expert received).
+    sizes (E,) int32: picks each HELD expert received, the rows handed to
+    the grouped products, int32).
 
     ``first`` is None where the E experts of ``experts`` are all that the
     picks name. Where they are a share, ``first`` .. ``first + E`` of
-    them, a pair whose expert is held elsewhere is sorted behind every
-    group and lies in none: the grouped products pass its row by and it
-    adds nothing.
+    ``num_experts``, a pair whose expert is held elsewhere is sorted
+    behind every group and lies in none: it adds nothing. It is not free:
+    the compiler's grouped-product kernel multiplies EVERY row it is
+    handed against each touched group's weights and keeps the rows of the
+    group, so a row in no group costs a row of every touched group's
+    tile (at 512 pair rows of which 32 are held, sixteen times the
+    products' work, and past what the weights' read takes). So a share's
+    products are handed the first R sorted rows only, R the least step of
+    ``row_ladder`` that holds ``sum(sizes)``, chosen by one ``lax.switch``
+    around the products; the sort, the sizes and the way back to token
+    order stay outside it. Where the ladder is one step (every expert
+    held, or few pairs) there is no switch.
 
     ``experts`` holds ``w_in`` (E, dim, 2 * hidden) and ``w_out``; or, from
     a scanned stack (``ops.transformer.block_stack``), the WHOLE stack's
@@ -217,8 +251,8 @@ def dropless_experts(experts: dict, x: Array, picks: Array,
     slice of a layer's experts, handed to a kernel, is a copy of them:
     1.2 GB a layer a step at the published widths)."""
     t, k = picks.shape
-    w_in, w_out = experts["w_in"], experts["w_out"]
-    e = w_in.shape[-3]
+    e = experts["w_in"].shape[-3]
+    ladder = (t * k,) if first is None else row_ladder(t * k, e, num_experts)
     with jax.named_scope("moe.route"):
         flat = picks.reshape(-1)
         if first is not None:
@@ -228,45 +262,65 @@ def dropless_experts(experts: dict, x: Array, picks: Array,
             flat = jnp.where(here, flat - first, e)
         order = jnp.argsort(flat, stable=True)      # pairs, by expert
         sizes = jnp.zeros((e,), jnp.int32).at[flat].add(1)
-        rows = jnp.take(x, order // k, axis=0)      # (t * k, dim)
-        pair_weights = jnp.take(weights.reshape(-1), order)
-        groups = sizes
-        if "layer" in experts:
-            w_in = w_in.reshape((-1,) + w_in.shape[2:])
-            w_out = w_out.reshape((-1,) + w_out.shape[2:])
-            groups = lax.dynamic_update_slice(
-                jnp.zeros((w_in.shape[0],), jnp.int32), sizes,
-                (experts["layer"] * e,))
-    with jax.named_scope("moe.experts"):
-        gate, up = jnp.split(
-            lax.ragged_dot(rows, w_in.astype(x.dtype), groups), 2, axis=-1)
-        out = lax.ragged_dot(jax.nn.silu(gate) * up, w_out.astype(x.dtype),
-                             groups)
-        # each pair's weight, in f32, here: the compiler's grouped-product
-        # kernel carries no scope of its own and takes its first reader's
-        out = out.astype(jnp.float32) * pair_weights[:, None]
-        if first is not None:
-            # rows behind the last group are no product's output
-            out = jnp.where((jnp.take(flat, order) < e)[:, None], out, 0.0)
+
+    def products(r: int):
+        """The first ``r`` sorted pairs through their experts -> (t * k,
+        dim) f32 in sorted order, the rows behind ``r`` zero."""
+        w_in, w_out = experts["w_in"], experts["w_out"]
+        with jax.named_scope("moe.route"):
+            rows = jnp.take(x, order[:r] // k, axis=0)      # (r, dim)
+            pair_weights = jnp.take(weights.reshape(-1), order[:r])
+            groups = sizes
+            if "layer" in experts:
+                w_in = w_in.reshape((-1,) + w_in.shape[2:])
+                w_out = w_out.reshape((-1,) + w_out.shape[2:])
+                groups = lax.dynamic_update_slice(
+                    jnp.zeros((w_in.shape[0],), jnp.int32), sizes,
+                    (experts["layer"] * e,))
+        with jax.named_scope("moe.experts"):
+            gate, up = jnp.split(
+                lax.ragged_dot(rows, w_in.astype(x.dtype), groups), 2,
+                axis=-1)
+            out = lax.ragged_dot(jax.nn.silu(gate) * up,
+                                 w_out.astype(x.dtype), groups)
+            # each pair's weight, in f32, here: the compiler's
+            # grouped-product kernel carries no scope of its own and takes
+            # its first reader's
+            out = out.astype(jnp.float32) * pair_weights[:, None]
+            if first is not None:
+                # rows behind the last group are no product's output
+                out = jnp.where((jnp.take(flat, order[:r]) < e)[:, None],
+                                out, 0.0)
+            return out if r == t * k else jnp.pad(
+                out, ((0, t * k - r), (0, 0)))
+
+    if len(ladder) == 1:
+        out, handed = products(t * k), jnp.int32(t * k)
+    else:
+        step = jnp.sum(jnp.sum(sizes) > jnp.asarray(ladder[:-1]))
+        out = lax.switch(step, [functools.partial(products, r)
+                                for r in ladder])
+        handed = jnp.asarray(ladder, jnp.int32)[step]
     with jax.named_scope("moe.route"):
         # back to (token, pick) order, and the sum over a token's picks
         out = jnp.take(out, jnp.argsort(order), axis=0).reshape(t, k, -1)
         out = jnp.sum(out, axis=1)
-    return out.astype(x.dtype), sizes
+    return out.astype(x.dtype), sizes, handed
 
 
 def dropless_apply(params: dict, x: Array, blk):
     """x (..., dim) -> (out (..., dim), load (``load_width``,) int32:
     picks routed, held experts that received one, the fullest held
     expert's picks and, where a share is held, the picks that fell on
-    it)."""
+    it and the rows handed to the grouped products)."""
     lead = x.shape[:-1]
     xt = x.reshape(-1, x.shape[-1])
     picks, weights = route(params["router"], xt, blk.experts_per_token,
                            blk.routed_scale)
     whole = holds_all(blk)
-    out, sizes = dropless_experts(params["experts"], xt, picks, weights,
-                                  None if whole else blk.first_expert)
+    out, sizes, handed = dropless_experts(
+        params["experts"], xt, picks, weights,
+        None if whole else blk.first_expert, blk.num_experts)
     if "shared" in params:
         with jax.named_scope("moe.shared"):
             out = out + core.swiglu(params["shared"], xt)
@@ -274,5 +328,5 @@ def dropless_apply(params: dict, x: Array, blk):
     load = [held if whole else jnp.int32(picks.size),
             jnp.sum(sizes > 0).astype(jnp.int32), jnp.max(sizes)]
     if not whole:
-        load.append(held)
+        load += [held, handed]
     return out.reshape(lead + (-1,)), jnp.stack(load)

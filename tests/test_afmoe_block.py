@@ -416,6 +416,8 @@ def test_engine_serves_the_reference_s_tokens_in_chunks_of_8(
     assert st["moe_picks"] == (st["decode_steps"] * engine.num_slots
                                * BLK.experts_per_token * DIMS.moe_layers)
     assert 0 < st["moe_picks_held"] < st["moe_picks"]
+    # two slots' pairs are under the row ladder's first step: all handed on
+    assert st["moe_rows_computed"] == st["moe_picks"]
     assert 0 < st["moe_experts_touched"] <= \
         st["decode_steps"] * DIMS.moe_layers * BLK.experts_held
     assert st["kv_hbm_bytes"] == KV.modeled_kv_bytes(
@@ -557,8 +559,8 @@ def test_a_share_routes_over_all_experts_and_computes_its_own():
     sizes = np.bincount(picks.reshape(-1), minlength=8)[2:5]
     assert sizes[1] == n and sizes.sum() < 2 * n
     assert list(np.asarray(load)) == [n * 2, (sizes > 0).sum(), sizes.max(),
-                                      sizes.sum()]
-    # with every expert held, the load has no fourth entry
+                                      sizes.sum(), n * 2]
+    # with every expert held, the load has no fourth or fifth entry
     assert moe_ops.load_width(dataclasses.replace(
         blk, experts_held=8, first_expert=0)) == 3
 

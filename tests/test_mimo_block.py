@@ -329,15 +329,16 @@ def test_prefill_then_paged_decode_matches_the_full_forward(
         np.testing.assert_allclose(logits[fin], ref_logits[:, pos][fin],
                                    atol=2e-5, rtol=0)
         picks = b * BLK.experts_per_token * DIMS.moe_layers
-        # (with a sink the load is float32: the four counts, then the
+        # (with a sink the load is float32: the five counts, then the
         # sink's weight over every window softmax of the step and their
         # number, ``decode_ops.load_like``)
-        assert load.shape == (6,) and load.dtype == jnp.float32
+        assert load.shape == (7,) and load.dtype == jnp.float32
         assert int(load[0]) == picks and 0 <= int(load[3]) <= picks
+        assert int(load[4]) == picks    # too few pairs for a row ladder
         reads = b * TCFG.heads * len(WINDOW_LAYERS)
-        assert int(load[5]) == reads
-        assert 0.0 < float(load[4]) < reads
-        mass += float(load[4]) / reads
+        assert int(load[6]) == reads
+        assert 0.0 < float(load[5]) < reads
+        mass += float(load[5]) / reads
     # the draw gives the sink a real share of a window row's weight
     assert 0.05 < mass / (DIMS.seq_len - 1 - t0) < 0.9
     # the same steps in chunks of 8 write the same pools and count alike
@@ -352,7 +353,7 @@ def test_prefill_then_paged_decode_matches_the_full_forward(
                 cfg=TCFG, key_mask=key_mask, total_len=DIMS.seq_len, steps=8,
                 embed_fn=embed_fn, sample_fn=sample_fn)
         picks += int(load[0])
-        reads += int(load[5])
+        reads += int(load[6])
     assert picks == 16 * b * BLK.experts_per_token * DIMS.moe_layers
     assert reads == 16 * b * TCFG.heads * len(WINDOW_LAYERS)
     np.testing.assert_array_equal(np.asarray(ring)[:, -1],
@@ -401,7 +402,7 @@ def test_slots_at_the_ring_s_edges_match_the_full_forward(
     got, load = _step_at(params, sequences[rows], positions, active)
     _close(got[1:], ref_logits[rows, positions][1:])
     # the parked slot's softmaxes are not counted
-    assert int(load[5]) == 15 * TCFG.heads * len(WINDOW_LAYERS)
+    assert int(load[6]) == 15 * TCFG.heads * len(WINDOW_LAYERS)
 
 
 def test_window_rows_at_the_published_ring_s_edges():
@@ -531,6 +532,8 @@ def test_engine_serves_the_reference_s_tokens_in_chunks_of_8(
     assert st["moe_picks"] == (st["decode_steps"] * engine.num_slots
                                * BLK.experts_per_token * DIMS.moe_layers)
     assert 0 < st["moe_picks_held"] < st["moe_picks"]
+    # two slots' pairs are under the row ladder's first step: all handed on
+    assert st["moe_rows_computed"] == st["moe_picks"]
     # the sink's counters: a softmax a window layer a head an ACTIVE slot a
     # step, fewer than every slot's every step; its weight a real share
     assert 0 < st["window_sink_reads"] <= st["decode_steps"] * 2 \

@@ -1,0 +1,245 @@
+"""The held experts' grouped products run over the rows that lie in a held
+group (ops/moe.py ``row_ladder``, ``dropless_experts``): the ladder from
+shapes, every step of it against a float32 per-pair reference, the load's
+fifth entry, and the call with no ladder left as it was."""
+
+import dataclasses
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax import lax
+
+from benchmark import harness, seeds
+from dalle_pytorch_tpu.ops import moe as moe_ops
+from dalle_pytorch_tpu.serve import scheduler as S
+from dalle_pytorch_tpu.serve.engine import Engine
+
+DIM, HIDDEN, E, OF, K = 16, 8, 16, 256, 8     # 16 of 256 held, top 8
+
+
+def _experts(layers=None, seed=0):
+    k = jax.random.split(jax.random.PRNGKey(seed), 2)
+    lead = (E,) if layers is None else (layers, E)
+    return {"w_in": jax.random.normal(k[0], lead + (DIM, 2 * HIDDEN)) / 4,
+            "w_out": jax.random.normal(k[1], lead + (HIDDEN, DIM)) / 3}
+
+
+def _pairs(tokens: int, held: int, first: int, seed=1):
+    """picks (tokens, K) of which exactly ``held`` pairs, anywhere, name
+    an expert of ``first`` .. ``first + E``; weights; x."""
+    rng = np.random.default_rng(seed)
+    away = np.setdiff1d(np.arange(OF), np.arange(first, first + E))
+    flat = rng.choice(away, tokens * K)
+    at = rng.choice(tokens * K, held, replace=False)
+    flat[at] = first + rng.integers(0, E, held)
+    k = jax.random.split(jax.random.PRNGKey(seed), 2)
+    return (jnp.asarray(flat.reshape(tokens, K), jnp.int32),
+            jax.random.uniform(k[0], (tokens, K), minval=0.1),
+            jax.random.normal(k[1], (tokens, DIM)))
+
+
+def _reference(w_in, w_out, x, picks, weights, first):
+    """Each (token, pick) pair through its expert's own matrices, one by
+    one, in float32; a pair whose expert is held elsewhere adds nothing."""
+    here = (picks >= first) & (picks < first + E)
+    at = jnp.where(here, picks - first, 0)
+    hp = lax.Precision.HIGHEST
+    h = jnp.einsum("td,tkdf->tkf", x, w_in[at], precision=hp)
+    gate, up = jnp.split(h, 2, axis=-1)
+    out = jnp.einsum("tkf,tkfd->tkd", jax.nn.silu(gate) * up, w_out[at],
+                     precision=hp)
+    return jnp.sum(jnp.where(here[..., None], weights[..., None] * out, 0.0),
+                   axis=1)
+
+
+# (tokens, [(held pairs, the step that holds them)]): none, under the
+# first step, exactly a step, between steps, exactly the second, over it,
+# every row
+CASES = [(64, held, step) for held, step in (
+             (0, 64), (40, 64), (64, 64), (100, 128), (128, 128), (300, 512),
+             (512, 512))] \
+    + [(1024, held, step) for held, step in (
+        (0, 1024), (700, 1024), (1024, 1024), (1500, 2048), (2048, 2048),
+        (5000, 8192), (8192, 8192))]
+
+
+@pytest.mark.parametrize("stacked", [False, True], ids=["a_layer", "a_stack"])
+@pytest.mark.parametrize("tokens,held,step", CASES)
+def test_every_step_of_the_ladder_against_the_per_pair_reference(
+        tokens, held, step, stacked):
+    first = 32
+    assert moe_ops.row_ladder(tokens * K, E, OF)[:2] == (
+        (64, 128) if tokens == 64 else (1024, 2048))
+    picks, weights, x = _pairs(tokens, held, first, seed=held + 1)
+    experts = _experts(3 if stacked else None)
+    w_in, w_out = experts["w_in"], experts["w_out"]
+    if stacked:
+        experts["layer"] = jnp.int32(1)
+        w_in, w_out = w_in[1], w_out[1]
+    out, sizes, handed = jax.jit(
+        lambda ex, x, p, w: moe_ops.dropless_experts(ex, x, p, w, first, OF)
+    )(experts, x, picks, weights)
+    assert int(handed) == step and int(sizes.sum()) == held
+    want = _reference(w_in, w_out, x, picks, weights, first)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=2e-5)
+    if not held:
+        assert not np.asarray(out).any()
+
+
+def _parent_dropless_experts(experts, x, picks, weights, first=None):
+    """``dropless_experts`` as it stood before the ladder (PR 40), for the
+    jaxpr of a call that has none."""
+    t, k = picks.shape
+    w_in, w_out = experts["w_in"], experts["w_out"]
+    e = w_in.shape[-3]
+    with jax.named_scope("moe.route"):
+        flat = picks.reshape(-1)
+        if first is not None:
+            here = (flat >= first) & (flat < first + e)
+            flat = jnp.where(here, flat - first, e)
+        order = jnp.argsort(flat, stable=True)
+        sizes = jnp.zeros((e,), jnp.int32).at[flat].add(1)
+        rows = jnp.take(x, order // k, axis=0)
+        pair_weights = jnp.take(weights.reshape(-1), order)
+        groups = sizes
+        if "layer" in experts:
+            w_in = w_in.reshape((-1,) + w_in.shape[2:])
+            w_out = w_out.reshape((-1,) + w_out.shape[2:])
+            groups = lax.dynamic_update_slice(
+                jnp.zeros((w_in.shape[0],), jnp.int32), sizes,
+                (experts["layer"] * e,))
+    with jax.named_scope("moe.experts"):
+        gate, up = jnp.split(
+            lax.ragged_dot(rows, w_in.astype(x.dtype), groups), 2, axis=-1)
+        out = lax.ragged_dot(jax.nn.silu(gate) * up, w_out.astype(x.dtype),
+                             groups)
+        out = out.astype(jnp.float32) * pair_weights[:, None]
+        if first is not None:
+            out = jnp.where((jnp.take(flat, order) < e)[:, None], out, 0.0)
+    with jax.named_scope("moe.route"):
+        out = jnp.take(out, jnp.argsort(order), axis=0).reshape(t, k, -1)
+        out = jnp.sum(out, axis=1)
+    return out.astype(x.dtype), sizes
+
+
+@pytest.mark.parametrize("stacked", [False, True], ids=["a_layer", "a_stack"])
+@pytest.mark.parametrize("tokens,first", [
+    (32, None),     # every expert held (kanana's decode: 192 pair rows)
+    (512, None),    # the same in a prefill
+    (8, 32),        # a share, the pairs under the first step (trinity's 64)
+], ids=["all_held", "all_held_prefill", "a_share_of_few_pairs"])
+def test_a_call_without_a_ladder_traces_as_the_parent_s(tokens, first,
+                                                        stacked):
+    picks, weights, x = _pairs(tokens, tokens, 32)
+    if first is None:
+        picks = picks % E
+    experts = _experts(3 if stacked else None)
+    if stacked:
+        experts["layer"] = jnp.int32(2)
+    args = (experts, x.astype(jnp.bfloat16), picks, weights)
+    now = jax.make_jaxpr(lambda *a: moe_ops.dropless_experts(
+        *a, first, OF)[:2])(*args)
+    then = jax.make_jaxpr(lambda *a: _parent_dropless_experts(
+        *a, first))(*args)
+    assert str(now) == str(then)
+    assert "cond" not in str(now)
+    handed = moe_ops.dropless_experts(*args, first, OF)[2]
+    assert int(handed) == tokens * K
+
+
+def test_a_call_with_a_ladder_has_one_conditional_of_its_steps():
+    picks, weights, x = _pairs(64, 40, 32)
+    text = str(jax.make_jaxpr(lambda *a: moe_ops.dropless_experts(
+        *a, 32, OF))(_experts(), x, picks, weights))
+    assert text.count("cond[") == 1 and text.count("= ragged_dot_general[") == 6
+    for rows in (64, 128, 512):
+        assert f"f32[{rows},{2 * HIDDEN}] = ragged_dot_general[" in text
+
+
+# -- the load's fifth entry, and the engine's counter --------------------------
+
+@dataclasses.dataclass(frozen=True)
+class _Blk:
+    num_experts: int = OF
+    experts_held: int = E
+    first_expert: int = 32
+    experts_per_token: int = K
+    routed_scale: float = 1.0
+    expert_hidden: int = HIDDEN
+    shared_hidden: int = 0
+
+
+@pytest.mark.parametrize("bias,step", [(-1.0, 64), (0.0, 64), (0.06, 128),
+                                       (1.0, 512)])
+def test_the_load_s_fifth_entry_is_the_step_taken(bias, step):
+    """A selection bias on the held experts moves the picks onto them: the
+    products are handed the least step that holds the held picks."""
+    blk = _Blk()
+    p = moe_ops.dropless_init(jax.random.PRNGKey(3), DIM, blk)
+    held = (jnp.arange(OF) >= 32) & (jnp.arange(OF) < 32 + E)
+    p["router"]["bias"] = jnp.where(held, bias, 0.0)
+    x = jax.random.normal(jax.random.PRNGKey(4), (4, 16, DIM))
+    out, load = jax.jit(lambda p, x: moe_ops.dropless_apply(p, x, blk))(p, x)
+    assert moe_ops.load_width(blk) == 5 and load.shape == (5,)
+    picks, handed = int(load[3]), int(load[4])
+    assert int(load[0]) == 512 and handed == step
+    assert picks <= handed and not any(
+        picks <= r < handed for r in moe_ops.row_ladder(512, E, OF))
+    assert out.shape == x.shape
+    whole = dataclasses.replace(blk, experts_held=OF, first_expert=0)
+    assert moe_ops.load_width(whole) == 3
+
+
+def _toy_engine(family: str, config: str, depth: int) -> Engine:
+    fam = harness.load_family(family)
+    conf = dict(harness.load_json(
+        f"{harness.ROOT}/benchmark/configs/{config}.json"), **fam.tiny)
+    dims = fam.weights.dims_of(conf, depth)
+    cfg = fam.build.program_config(dims, {})
+    p = jax.jit(lambda h: fam.weights.tree(h, dims, jnp.float32))(
+        seeds.split_seed(5))
+    return Engine(p, cfg, S.RequestQueue(max_depth=2), num_slots=1,
+                  kv="paged", page_size=4)
+
+
+@pytest.mark.parametrize("family,config,depth,there", [
+    ("mimo_v2", "mimo-v2.5", 7, True),
+    ("afmoe", "trinity-large-preview", 5, True),
+    ("mla_moe", "kanana-2-30b-a3b", 3, False),
+])
+def test_the_engine_counts_the_rows_where_a_share_is_held(family, config,
+                                                          depth, there):
+    stats = _toy_engine(family, config, depth).stats()
+    assert "moe_picks" in stats
+    assert ("moe_rows_computed" in stats) == there
+    assert ("moe_picks_held" in stats) == there
+
+
+# -- the ladder from shapes, at the three routed configurations' sizes ---------
+
+@pytest.mark.parametrize("cell,decode,prefill", [
+    ("mimo-v2.5.serve-full", (64, 128, 512), (1024, 2048, 8192)),
+    ("trinity-large-preview.serve-full", None, (1024, 2048, 4096)),
+    ("kanana-2-30b-a3b.serve-full", None, None),
+])
+def test_the_ladder_of_each_routed_cell_s_programs(cell, decode, prefill):
+    """Decode hands the function a pair row a slot a pick, an admission a
+    group of 4 rows x the 256-token bucket x the picks."""
+    cell = harness.Cell(cell)
+    dims = cell.family.weights.dims_of(cell.config, cell.spec["depth"])
+    blk = cell.family.build.program_config(
+        dims, cell.spec["flags"]).transformer.block
+    slots = int(cell.spec["num_slots"])
+    assert min(S.prefill_groups(slots)) == 4
+
+    def ladder(tokens):
+        pairs = tokens * blk.experts_per_token
+        if moe_ops.holds_all(blk):
+            return None
+        steps = moe_ops.row_ladder(pairs, blk.experts_held, blk.num_experts)
+        return steps if len(steps) > 1 else None
+
+    assert ladder(slots) == decode
+    assert ladder(4 * 256) == prefill
