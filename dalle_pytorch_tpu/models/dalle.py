@@ -88,7 +88,7 @@ class DALLEConfig:
     # positions inside the block, so no position tables; an untied,
     # bias-free head behind an RMSNorm. Served, not trained.
     block: Optional[Union[T.LatentMoEBlock, T.WindowGQABlock,
-                          T.SSMHybridBlock]] = None
+                          T.SSMHybridBlock, T.ShortConvGQABlock]] = None
 
     @property
     def image_seq_len(self) -> int:
@@ -164,11 +164,13 @@ def dalle_init(key: Array, cfg: DALLEConfig,
     }
     k_head = jax.random.fold_in(ks[5], 1)
     if cfg.block is not None and cfg.block.tied_head:
-        # no position of any kind; the head is the embedding rows
-        # themselves behind a LayerNorm (``to_logits``): text rows, image
-        # rows, and the EOS row, which is never an input
+        # no position table; the head is the embedding rows themselves
+        # behind the block's kind of norm (``to_logits``): text rows,
+        # image rows, and the EOS row, which is never an input
         params["eos_emb"] = core.embedding_init(k_head, 1, cfg.dim, dtype)
-        params["to_logits"] = {"ln": core.layernorm_init(cfg.dim, dtype)}
+        params["to_logits"] = {"ln": (
+            core.layernorm_init if cfg.block.layer_norms
+            else core.rmsnorm_init)(cfg.dim, dtype)}
         return params
     if cfg.block is not None:
         # positions are the block's own (rotary): no tables; an untied,

@@ -49,6 +49,10 @@ SCOPES = (
     "ssm.scan",         # its convolution, the state's read, update, readout
                         # and write; in prefill the scan over the positions
     "gmu",              # a gated memory unit: its two products and the gate
+    "conv.proj",        # a gated short convolution's input and output
+                        # projections
+    "conv.mix",         # its two gates, the taps, and the tail's read, roll
+                        # and store
     "head",             # logits
     "sample",           # filtering and sampling
     "loss",             # cross-entropy

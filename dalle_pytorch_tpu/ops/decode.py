@@ -1502,9 +1502,9 @@ def _block_reads(cfg, pool: dict, block_tables: dict, pos: Array,
     by ``view_slot_groups``, the classic step's rule, and looped by
     ``_read_in_slot_groups``), and contracts them as they lie; a layer
     that reads ANOTHER layer's rows (``LayerKind.stores`` False) reads the
-    same pool at that layer's index. A state-space layer's read takes the
-    layer's state of every slot and advances it by the token."""
-    from dalle_pytorch_tpu.ops import ssm as ssm_ops
+    same pool at that layer's index. A recurrent layer's read (a
+    state-space layer's, a short convolution's) takes what every slot
+    carries of the layer and advances it by the token."""
     from dalle_pytorch_tpu.ops import transformer as T
     blk = cfg.block
     total_len = key_mask.shape[1]
@@ -1598,11 +1598,12 @@ def _block_reads(cfg, pool: dict, block_tables: dict, pos: Array,
             return None             # a layer that reads no cache
         if run.kind.pool == "state":
             def advance(p, x, _entry):
-                with jax.named_scope("ssm.scan"):
+                with T.state_scope(blk):
                     state = tuple(lax.dynamic_index_in_dim(
                         pool[name], layer, keepdims=False)
                         for name in blk.pool_buffers("state"))
-                return ssm_ops.ssm_step(p, x, state)
+                return T.STATE_STEP[run.kind.mixer](
+                    p, x, state if len(state) > 1 else state[0])
             return advance
         window = not run.full
         k_name, v_name = blk.pool_buffers(run.kind.pool)
@@ -1640,13 +1641,14 @@ def _store_block_rows(cfg, pool: dict, entries: dict, pos: Array,
     """A decode step's new entries (``block_stack``'s, a buffer of the
     pool each) into a described block's pool: the new rows of every layer
     of a paged pool through its table (``_store_entries_paged``), a
-    state-space layer's new state over the old one where the slot is
+    recurrent layer's new state over the old one where the slot is
     active."""
+    from dalle_pytorch_tpu.ops import transformer as T
     blk = cfg.block
     out = {}
     for kind, names in blk.pools(cfg.depth).items():
         if kind == "state":
-            with jax.named_scope("ssm.scan"):
+            with T.state_scope(blk):
                 for name in names:
                     on = active.reshape((1, -1) + (1,) * (
                         pool[name].ndim - 2))

@@ -195,15 +195,19 @@ def dropless_init(key: Array, dim: int, blk, dtype=jnp.float32) -> dict:
 
 
 @jax.named_scope("moe.route")
-def route(router: dict, x: Array, k: int, scale: float):
+def route(router: dict, x: Array, k: int, scale: float, eps: float = 0.0):
     """x (t, dim) -> (picks (t, k) expert ids, weights (t, k) f32).
     Scores are sigmoids in f32; the bias moves the SELECTION only, the
-    weights are the picked scores over their sum, times ``scale``."""
+    weights are the picked scores over their sum (plus ``eps`` where a
+    block's equations have one), times ``scale``."""
     scores = jax.nn.sigmoid(jnp.dot(x.astype(jnp.float32),
                                     router["w"].astype(jnp.float32)))
     _, picks = lax.top_k(scores + router["bias"].astype(jnp.float32), k)
     picked = jnp.take_along_axis(scores, picks, axis=-1)
-    return picks, scale * picked / jnp.sum(picked, axis=-1, keepdims=True)
+    total = jnp.sum(picked, axis=-1, keepdims=True)
+    if eps:
+        total = total + eps
+    return picks, scale * picked / total
 
 
 def row_ladder(pairs: int, held: int, num_experts: int) -> Tuple[int, ...]:
@@ -316,7 +320,7 @@ def dropless_apply(params: dict, x: Array, blk):
     lead = x.shape[:-1]
     xt = x.reshape(-1, x.shape[-1])
     picks, weights = route(params["router"], xt, blk.experts_per_token,
-                           blk.routed_scale)
+                           blk.routed_scale, blk.route_eps)
     whole = holds_all(blk)
     out, sizes, handed = dropless_experts(
         params["experts"], xt, picks, weights,

@@ -36,6 +36,7 @@ from jax import lax
 
 from dalle_pytorch_tpu.ops import attention as attn_ops
 from dalle_pytorch_tpu.ops import core, sparse
+from dalle_pytorch_tpu.ops import shortconv as conv_ops
 from dalle_pytorch_tpu.ops import ssm as ssm_ops
 
 Array = jax.Array
@@ -63,7 +64,8 @@ class LayerKind:
     over a window of them; and the ``mixer``: ``"attn"`` (attends its own
     keys and values and caches them), ``"cross"`` (attends the rows that
     an earlier full layer cached, and caches nothing), ``"ssm"`` (a
-    state-space layer: a recurrent state a slot, no rows) or ``"gmu"``
+    state-space layer: a recurrent state a slot, no rows), ``"conv"`` (a
+    gated short convolution: its tail a slot, no rows) or ``"gmu"``
     (reads an earlier state-space layer's output of the same token, and
     caches nothing)."""
     moe: bool
@@ -74,7 +76,7 @@ class LayerKind:
     def pool(self) -> Optional[str]:
         """The cache this layer reads: ``"full"``, ``"window"``,
         ``"state"`` or None."""
-        if self.mixer == "ssm":
+        if self.mixer in ("ssm", "conv"):
             return "state"
         if self.mixer == "gmu":
             return None
@@ -83,7 +85,7 @@ class LayerKind:
     @property
     def stores(self) -> bool:
         """Whether the layer writes the cache it reads."""
-        return self.mixer in ("attn", "ssm")
+        return self.mixer in ("attn", "ssm", "conv")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -154,18 +156,28 @@ class DescribedBlock:
     tied_head = False   # an output head of its own (models/dalle.py)
     layer_norms = False     # RMSNorms (a gain alone), not LayerNorms
     sink = False    # no learned logit beside a window softmax's rows
+    route_eps = 0.0     # nothing beside the picked scores' sum (ops/moe.py)
+    state_mixer = "ssm"     # the recurrent mixer whose state a slot carries
 
     @staticmethod
     def stack_of(kind: LayerKind) -> str:
         """The parameter stack that holds a layer of ``kind``."""
         return "moe" if kind.moe else "dense"
 
-    @staticmethod
-    def pool_buffers(pool: str) -> Tuple[str, ...]:
-        """The buffers of one cache: a pool's K and V, or the recurrent
-        state and the convolution's tail."""
-        return {"full": ("k", "v"), "window": ("window_k", "window_v"),
-                "state": ("ssm_state", "ssm_conv")}[pool]
+    def pool_buffers(self, pool: str) -> Tuple[str, ...]:
+        """The buffers of one cache: a page pool's K and V, or what
+        ``state_layout`` names."""
+        if pool == "state":
+            return tuple(self.state_layout(0))
+        return {"full": ("k", "v"), "window": ("window_k", "window_v")}[pool]
+
+    def state_layout(self, dim: int) -> dict:
+        """What a slot carries a layer of the ``"state"`` cache, buffer by
+        buffer: ``{name: (shape, bytes an
+        element; None = the pool's float type)}`` at a stream ``dim``
+        wide (serve/kv_pool.py ``page_layout``). A block without
+        recurrent layers holds none."""
+        return {}
 
     def cache_layers(self, pool: str, depth: int) -> Tuple[int, ...]:
         """The layers (indices into the layers run here) that STORE to one
@@ -507,6 +519,13 @@ class SSMHybridBlock(DescribedBlock):
     def mixer_of(kind: LayerKind) -> str:
         return "diff" if kind.mixer == "attn" else kind.mixer
 
+    def state_layout(self, dim: int) -> dict:
+        """The recurrent state, float32 whatever the pool's type, with
+        the wide dimension minor (whole lanes), and the convolution's
+        tail."""
+        return {"ssm_state": ((self.d_state, self.d_inner), 4),
+                "ssm_conv": ((self.d_conv - 1, self.d_inner), None)}
+
     def carried(self, x: Array) -> dict:
         """The last state-space layer's scan output and the full layer's
         K and V rows, of the tokens in ``x``."""
@@ -519,6 +538,109 @@ class SSMHybridBlock(DescribedBlock):
     def lam_init(layer) -> Array:
         """Differential attention's constant of layer ``layer``."""
         return 0.8 - 0.6 * jnp.exp(-0.3 * jnp.asarray(layer, jnp.float32))
+
+
+@dataclasses.dataclass(frozen=True)
+class ShortConvGQABlock(DescribedBlock):
+    """The fourth described block: ``layer_types`` says, layer by layer of
+    the layers run here, whether a layer's mixer is a gated short
+    convolution (``"conv"``, ops/shortconv.py: a slot carries the last
+    ``conv_taps - 1`` gated inputs, ``dim`` wide, and caches no row) or
+    grouped-query attention over every earlier row (``"full"``:
+    ``TransformerConfig.heads`` query heads read ``kv_heads`` key/value
+    heads of ``head_dim``, RMSNorms over each query and key head, then
+    rotary positions on the whole head at ``rope_theta``; no gate, no
+    bias; a token caches one K and one V row a layer in the one page
+    pool). Two RMSNorms a layer, one before each branch. SiLU-gated
+    feed-forwards without biases: ``dense_layers`` leading dense ones,
+    then layers of ``num_experts`` routed experts, ALL held here, the
+    picked sigmoid scores over (their sum + ``route_eps``), no shared
+    expert. The head is the embedding rows behind a final RMSNorm; the
+    rotary positions of the full layers are the only positions.
+
+    The stack runs at period 1 (``stack_scans``: runs of layers alike),
+    whatever the published ratio of the two layer types: a scanned
+    period of it would hold several layers of one parameter stack.
+    ``dim_head`` and ``ff_mult`` of the configuration are not read.
+
+    What ``ops.attention.gqa_*`` and ``ops.moe`` read of a block beyond
+    the fields is fixed here (the class constants): the combination that
+    this block is, and no other, is what its tests hold."""
+    layer_types: Tuple[str, ...] = ("conv", "full", "conv", "conv", "conv")
+    kv_heads: int = 8
+    head_dim: int = 64
+    conv_taps: int = 3
+    rope_theta: float = 1e6
+    norm_eps: float = 1e-5
+    dense_layers: int = 1
+    dense_hidden: int = 11776
+    num_experts: int = 64
+    experts_per_token: int = 4
+    expert_hidden: int = 1536
+    routed_scale: float = 1.0
+    route_eps: float = 1e-6
+    name: str = "shortconv_gqa_moe"
+    tied_head = True                # logits against the embedding rows
+    state_mixer = "conv"
+    embed_scale = 1.0
+    first_expert = 0                # every routed expert is held here
+    shared_hidden = 0               # no shared expert
+    qk_norm = True
+    out_gate = False
+    sandwich_norms = False          # no second norm a branch
+    value_scale = 1.0
+    v_head_dim = None               # a value head is a key head's size
+    rotary_dim = None               # the whole head turns
+
+    def __post_init__(self):
+        bad = set(self.layer_types) - {"conv", "full"}
+        if bad:
+            raise ValueError(f"layer_types holds {sorted(bad)}: a layer is "
+                             f"'conv' or 'full'")
+        if self.conv_taps < 2 or self.head_dim % 2:
+            raise ValueError(f"conv_taps {self.conv_taps} leaves no tail, "
+                             f"or head_dim {self.head_dim} no rotary pairs")
+
+    @property
+    def experts_held(self) -> int:
+        return self.num_experts
+
+    @property
+    def score_dim(self) -> int:
+        return self.head_dim
+
+    @property
+    def page_row_width(self) -> int:
+        return self.kv_heads * self.head_dim
+
+    def layer_kinds(self, depth: int) -> Tuple[LayerKind, ...]:
+        if len(self.layer_types) != depth:
+            raise ValueError(f"layer_types names {len(self.layer_types)} "
+                             f"layers, depth is {depth}")
+        return tuple(LayerKind(moe=i >= self.dense_layers, full=t == "full",
+                               mixer="attn" if t == "full" else "conv")
+                     for i, t in enumerate(self.layer_types))
+
+    @staticmethod
+    def mixer_of(kind: LayerKind) -> str:
+        return "gqa" if kind.mixer == "attn" else "conv"
+
+    @staticmethod
+    def stack_of(kind: LayerKind) -> str:
+        """``"dense"`` / ``"moe"`` hold the short-convolution layers,
+        ``"dense_full"`` / ``"moe_full"`` the attention layers."""
+        return ("moe" if kind.moe else "dense") + (
+            "_full" if kind.full else "")
+
+    def state_layout(self, dim: int) -> dict:
+        """ONE buffer: the convolution's tail, in the pool's type."""
+        return {"conv_tail": ((self.conv_taps - 1, dim), None)}
+
+    def kv_heads_of(self, full: bool) -> int:
+        return self.kv_heads
+
+    def rope_theta_of(self, full: bool) -> float:
+        return self.rope_theta
 
 
 @dataclasses.dataclass(frozen=True)
@@ -559,8 +681,8 @@ class TransformerConfig:
     moe_k: int = 2
     moe_capacity: float = 1.25
     # a described block in place of the classic one
-    block: Optional[Union[LatentMoEBlock, WindowGQABlock,
-                          SSMHybridBlock]] = None
+    block: Optional[Union[LatentMoEBlock, WindowGQABlock, SSMHybridBlock,
+                          ShortConvGQABlock]] = None
 
     def __post_init__(self):
         blk = self.block
@@ -656,6 +778,8 @@ def block_layer_init(key: Array, cfg: TransformerConfig, kind: LayerKind,
         attn = ssm_ops.ssm_init(k_attn, cfg.dim, blk, dtype)
     elif mixer == "gmu":
         attn = ssm_ops.gmu_init(k_attn, cfg.dim, blk, dtype)
+    elif mixer == "conv":
+        attn = conv_ops.shortconv_init(k_attn, cfg.dim, blk, dtype)
     else:
         attn = attn_ops.diff_init(k_attn, cfg.dim, cfg.heads, blk,
                                   blk.lam_init(layer), dtype,
@@ -700,6 +824,8 @@ def block_name_of(params: dict) -> str:
     that has parameters and no configuration to name in its refusal."""
     if not any(name.startswith(("dense", "moe")) for name in params):
         return SSMHybridBlock.name      # ``stack_of``: stacks by mixer
+    if any("conv" in stack["attn"] for stack in params.values()):
+        return ShortConvGQABlock.name
     attn = next(iter(params.values()))["attn"]
     return LatentMoEBlock.name if "k_up" in attn else WindowGQABlock.name
 
@@ -881,6 +1007,11 @@ def _mix_ssm(p, hn, positions, read, shared, cfg, run):
     return out, state, {**shared, "m": m}
 
 
+def _mix_conv(p, hn, positions, read, shared, cfg, run):
+    out, tail = read(p, hn, None)
+    return out, tail, shared
+
+
 def _mix_gmu(p, hn, positions, read, shared, cfg, run):
     return ssm_ops.gmu(p, hn, shared["m"]), None, shared
 
@@ -900,7 +1031,22 @@ def _mix_diff(p, hn, positions, read, shared, cfg, run):
 
 
 _MIXERS = {"latent": _mix_latent, "gqa": _mix_gqa, "ssm": _mix_ssm,
-           "gmu": _mix_gmu, "diff": _mix_diff, "cross": _mix_diff}
+           "conv": _mix_conv, "gmu": _mix_gmu, "diff": _mix_diff,
+           "cross": _mix_diff}
+
+def state_scope(blk):
+    """A state pool's reads and stores in a trace: its block's recurrent
+    mixer's scope."""
+    return jax.named_scope("conv.mix") if blk.state_mixer == "conv" \
+        else jax.named_scope("ssm.scan")
+
+
+# a recurrent mixer's two forms, by ``LayerKind.mixer``: over whole
+# sequences (p, x, mask) and one token against what its slot carries
+# (p, x, the carried buffers: one alone, or the tuple of several)
+STATE_SEQUENCE = {"ssm": ssm_ops.ssm_sequence,
+                  "conv": conv_ops.shortconv_sequence}
+STATE_STEP = {"ssm": ssm_ops.ssm_step, "conv": conv_ops.shortconv_step}
 
 
 def block_layer(lp: dict, h: Array, shared: dict, positions: Array, read,
@@ -911,7 +1057,8 @@ def block_layer(lp: dict, h: Array, shared: dict, positions: Array, read,
     the block's projection gives: (q_nope, q_rope) and the latent row for
     the latent block; q and the (k, v) rows for the grouped-query and the
     differential layers; the normed input and None for a state-space
-    layer, whose read is the whole recurrence (-> out, m, the new state).
+    layer, whose read is the whole recurrence (-> out, m, the new state),
+    and for a short convolution (-> out, the new tail).
     ``shared`` is what earlier layers handed on (``DescribedBlock
     .carried``). -> (h, shared, (entry, load)): what to cache (None for a
     layer that caches nothing) and the routed layer's load (zeros for a
@@ -1048,8 +1195,9 @@ def block_apply_full(params: dict, x: Array, cfg: TransformerConfig,
     entries: every buffer's rows to cache stacked over the layers that
     store to it (``block_stack``): (layers, b, n, row_width), or (layers,
     b, n, kv_heads, head_dim) K and V, or a state-space layer's (layers,
-    b, d_state, d_inner) and (layers, b, d_conv - 1, d_inner) after each
-    row's last position; loads (depth, load width))."""
+    b, d_state, d_inner) and (layers, b, d_conv - 1, d_inner), or a short
+    convolution's tail (layers, b, conv_taps - 1, dim), after each row's
+    last position; loads (depth, load width))."""
     blk = cfg.block
     n = x.shape[1]
     positions = jnp.arange(n)
@@ -1063,7 +1211,7 @@ def block_apply_full(params: dict, x: Array, cfg: TransformerConfig,
             near = (positions[:, None] - positions[None, :]) < blk.window
             in_window = allowed & near[None, None]
     if "state" in pools:
-        with jax.named_scope("ssm.scan"):
+        with state_scope(blk):
             advance = mask
             if lens is not None:
                 within = positions[None, :] < lens[:, None]
@@ -1072,7 +1220,7 @@ def block_apply_full(params: dict, x: Array, cfg: TransformerConfig,
     def layer_fn(lp, h, shared, _layer, run):
         def read(p, query, entry):
             if run.kind.pool == "state":
-                return ssm_ops.ssm_sequence(p, query, advance)
+                return STATE_SEQUENCE[run.kind.mixer](p, query, advance)
             if blk.mixer_of(run.kind) == "latent":
                 return attn_ops.latent_attend_materialised(
                     p, *query, entry, allowed, blk, cfg.scale)

@@ -3302,8 +3302,9 @@ class Engine:
         ring, the pages it reused in place, and what an unshared,
         unwindowed cache would hold at the same positions (every
         attention layer's pages to each slot's mapped position: the
-        denominator of the saving). With state-space layers, the state
-        buffers' bytes."""
+        denominator of the saving). With recurrent layers, the state
+        pool's bytes: together (``state_bytes``) and each buffer's under
+        its own name (``<buffer>_bytes``)."""
         if self.block is None:
             return {}
         blk, depth = self.block, self.cfg.transformer.depth
@@ -3330,8 +3331,9 @@ class Engine:
             })
         state = blk.pools(depth).get("state")
         if state:
-            out["state_bytes"] = int(sum(self.cache[n].nbytes
-                                         for n in state))
+            out.update({f"{n}_bytes": int(self.cache[n].nbytes)
+                        for n in state})
+            out["state_bytes"] = sum(out[f"{n}_bytes"] for n in state)
         return out
 
     def pages_in_use_p95(self) -> int:

@@ -186,13 +186,17 @@ def page_layout(cfg, page_size: int, quantized: bool = False) -> dict:
         than a value head, or the layer types differ in key/value heads,
         the four buffers have four widths, and everything below follows
         this table buffer by buffer;
-      * a block with state-space layers holds, beside its page pools, a
-        cache that is NOT pages: a fixed-size recurrent state a slot a
-        layer, ``ssm_state`` ``(d_state, d_inner)`` (the wide dimension
-        minor: whole lanes) in float32 whatever the pool's type, and the
-        convolution's tail ``ssm_conv`` ``(d_conv - 1, d_inner)``. The axis that is a pool's ``num_pages`` is the
-        engine's slots there (``pool_plan``): nothing is allocated or
-        freed, a slot's state is overwritten at admission."""
+      * a block with recurrent layers holds, beside its page pools, a
+        cache that is NOT pages: what a slot carries a layer, fixed in
+        size, buffer by buffer as the BLOCK names it
+        (``DescribedBlock.state_layout``). A state-space layer's is
+        ``ssm_state`` ``(d_state, d_inner)`` (the wide dimension minor:
+        whole lanes) in float32 whatever the pool's type, and the
+        convolution's tail ``ssm_conv`` ``(d_conv - 1, d_inner)``; a
+        gated short convolution's is ONE buffer, ``conv_tail``
+        ``(conv_taps - 1, dim)``. The axis that is a pool's ``num_pages``
+        is the engine's slots there (``pool_plan``): nothing is allocated
+        or freed, a slot's state is overwritten at admission."""
     blk = getattr(cfg, "block", None)
     if blk is not None:
         if quantized:
@@ -201,8 +205,7 @@ def page_layout(cfg, page_size: int, quantized: bool = False) -> dict:
         out = {}
         for pool, names in blk.pools(cfg.depth).items():
             if pool == "state":
-                out[names[0]] = ((blk.d_state, blk.d_inner), 4)
-                out[names[1]] = ((blk.d_conv - 1, blk.d_inner), None)
+                out.update(blk.state_layout(cfg.dim))
             else:
                 out.update({name: ((page_size, blk.buffer_row_width(name)),
                                    None) for name in names})
@@ -226,7 +229,7 @@ def pool_plan(cfg, num_pages: int, window_pages: int,
     its window of a slot's rows and its pages are reused as the slot moves
     on (``WindowPages``): the full layers' ``k`` / ``v`` with
     ``num_pages`` pages and the window layers' ``window_k`` / ``window_v``
-    with ``window_pages``. A state-space layer's buffers span
+    with ``window_pages``. A recurrent layer's buffers span
     ``num_slots``: a state a slot, and a block that has such layers is
     given no plan without them."""
     blk = getattr(cfg, "block", None)

@@ -167,6 +167,7 @@ class _Blk:
     first_expert: int = 32
     experts_per_token: int = K
     routed_scale: float = 1.0
+    route_eps: float = 0.0
     expert_hidden: int = HIDDEN
     shared_hidden: int = 0
 
