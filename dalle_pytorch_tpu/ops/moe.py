@@ -11,10 +11,10 @@ expert's weights); the results go back to token order and are summed
 with their weights, shared experts added. No capacity exists and no
 token is dropped, whatever the load: the same function serves a
 prefill's thousands of tokens and a decode step's few dozen. It returns
-its load (picks routed, experts touched, the fullest expert's picks) for
-the engine's counters. A block may HOLD a share of its experts (one chip
-of an expert-parallel deployment: ``blk.first_expert``,
-``blk.experts_held``): the router still scores and picks among all of
+its load (picks routed, experts touched, the fullest expert's picks, the
+reads of an expert's weights) for the engine's counters. A block may HOLD
+a share of its experts (one chip of an expert-parallel deployment:
+``blk.first_expert``, ``blk.experts_held``): the router still scores and picks among all of
 them, the pairs whose expert is held elsewhere lie in no group and add
 nothing, and the load says how many picks were held. Nothing stands in
 for the absent chips: their part of the sum is left out. What an absent
@@ -25,7 +25,16 @@ are sorted behind the held ones, so a share's products are handed the
 first R sorted rows alone: R the least step of a short static ladder
 (``row_ladder``: from the shapes, twice the rows an even load brings,
 that doubled, every pair) that holds the step's held pairs, by one
-``lax.switch`` a layer, and the load says which step was taken.
+``lax.switch`` a layer, and the load says which step was taken. Where a
+call has no ladder (every expert held, or few pairs) the same cost is paid
+for the rows of OTHER groups, so its sorted rows are cut into static ROW
+TILES of ``ROW_TILE`` rows, the ladder's floor, and each tile's products
+run over the groups clipped to the tile (``row_tiles``, ``tile_sizes``): a
+touched expert's weights meet the 64 sorted rows its picks lie in, an
+expert whose rows straddle a boundary is read by both tiles, and the load
+counts those reads. A call of no more rows than the chip's ridge
+(``RIDGE_ROWS``: the operations hide beneath the weights' read), or of
+more rows than the kernel's own tile (a prefill's thousands), runs as one.
 
 **Capacity routing** (``moe_apply``; the trainable ``moe_experts`` option
 of the classic block, expert-parallel over an ``ep`` axis): the standard
@@ -165,12 +174,14 @@ def holds_all(blk) -> bool:
 
 def load_width(blk) -> int:
     """Entries of a routed layer's load: picks routed, experts touched,
-    the fullest expert's picks; and, where a share is held, the picks
-    that fell on it and the rows handed to the grouped products
-    (``dropless_experts``). None for a block without routed layers."""
+    the fullest expert's picks, the reads of an expert's weights
+    (``dropless_experts``: the (row tile, expert) pairs that hold a row);
+    and, where a share is held, the picks that fell on it and the rows
+    handed to the grouped products. None for a block without routed
+    layers."""
     if not blk.num_experts:
         return 0
-    return 3 if holds_all(blk) else 5
+    return 4 if holds_all(blk) else 6
 
 
 def dropless_init(key: Array, dim: int, blk, dtype=jnp.float32) -> dict:
@@ -210,17 +221,58 @@ def route(router: dict, x: Array, k: int, scale: float, eps: float = 0.0):
     return picks, scale * picked / total
 
 
+# the fewest sorted rows the grouped products are handed at once: the
+# ladder's least step and a row tile's height. At 64 rows handed the
+# products run at 82% of the touched experts' read in two cells (PERF.md
+# section 6, PRs 33 and 41); under it a tile's re-reads buy nothing
+ROW_TILE = 64
+# the rows handed at which a grouped product's operations (two a weight a
+# row) take this chip as long as the read of the weights (two bytes a
+# weight): 197 TFLOP/s over 819 GB/s (a v5e: utils/device.py CHIP_PEAKS).
+# Under it the operations hide beneath the read, and cutting the rows buys
+# nothing and costs a call a tile (measured at 192 rows: PERF.md section
+# 6, PR 43)
+RIDGE_ROWS = 240
+# the compiler's grouped-product kernel cuts the rows it is handed into
+# tiles of this many itself (its row operand in the compiled text) and
+# passes a tile that holds no row of a group: over it, nothing is gained
+# by cutting the rows here (a prefill's thousands: PERF.md section 6, PR 41)
+KERNEL_ROWS = 512
+
+
 def row_ladder(pairs: int, held: int, num_experts: int) -> Tuple[int, ...]:
     """The row counts at which a block that holds ``held`` of its
     ``num_experts`` experts runs the grouped products of ``pairs`` (token,
     pick) pairs, from shapes alone: twice the rows an even load brings the
-    held experts, rounded up to a power of two and not under 64; that
-    doubled; and every pair. Steps at or over ``pairs`` fall away, so the
-    ladder of a call whose first step already holds every pair is
+    held experts, rounded up to a power of two and not under ``ROW_TILE``;
+    that doubled; and every pair. Steps at or over ``pairs`` fall away, so
+    the ladder of a call whose first step already holds every pair is
     ``(pairs,)``: no ladder."""
     expected = -(-2 * pairs * held // num_experts)
-    step = max(64, 1 << (expected - 1).bit_length())
+    step = max(ROW_TILE, 1 << (expected - 1).bit_length())
     return tuple(r for r in (step, 2 * step) if r < pairs) + (pairs,)
+
+
+def row_tiles(rows: int) -> int:
+    """The tiles into which the grouped products of ``rows`` sorted rows
+    are cut, from the shape alone: ``ROW_TILE`` rows each (the last one
+    what is left) where ``RIDGE_ROWS < rows <= KERNEL_ROWS``, the rows at
+    which the products are bound by operations that a tile saves; else
+    one."""
+    return -(-rows // ROW_TILE) if RIDGE_ROWS < rows <= KERNEL_ROWS else 1
+
+
+def tile_sizes(sizes: Array, rows: int) -> Array:
+    """(tiles of ``ROW_TILE`` rows, E) int32: of each group's rows, those
+    that lie in each tile of the first ``rows`` sorted rows. A group that
+    no row of a tile lies in has size 0 there (its weights are not read
+    for that tile); one that straddles a boundary has rows in both tiles
+    (and is read for both)."""
+    ends = jnp.cumsum(sizes)
+    starts = ends - sizes
+    lo = jnp.arange(0, rows, ROW_TILE, dtype=jnp.int32)[:, None]
+    hi = jnp.minimum(lo + ROW_TILE, rows)
+    return jnp.clip(ends, lo, hi) - jnp.clip(starts, lo, hi)
 
 
 def dropless_experts(experts: dict, x: Array, picks: Array,
@@ -229,7 +281,8 @@ def dropless_experts(experts: dict, x: Array, picks: Array,
     """Every (token, pick) pair through its expert, summed per token with
     its weight. x (t, dim), picks / weights (t, k) -> (out (t, dim),
     sizes (E,) int32: picks each HELD expert received, the rows handed to
-    the grouped products, int32).
+    the grouped products, int32, the reads of an expert's weights: the
+    (row tile, group) pairs that hold a row, int32).
 
     ``first`` is None where the E experts of ``experts`` are all that the
     picks name. Where they are a share, ``first`` .. ``first + E`` of
@@ -245,6 +298,26 @@ def dropless_experts(experts: dict, x: Array, picks: Array,
     around the products; the sort, the sizes and the way back to token
     order stay outside it. Where the ladder is one step (every expert
     held, or few pairs) there is no switch.
+
+    A row that lies in ANOTHER group costs the same as one that lies in
+    none: where every expert is held there is no dead row to leave out,
+    and 256 pair rows against 63 touched groups are as many operations as
+    the experts' read takes time (the products sit on the chip's ridge).
+    So a call without a ladder runs its products a ROW TILE at a time
+    (``row_tiles``): the sorted rows are cut into static tiles of
+    ``ROW_TILE`` rows, and each tile's two products run over the groups
+    clipped to it (``tile_sizes``). A touched group is then multiplied
+    against the 64 rows its picks lie in, a group with no row in a tile
+    is not read for it, and one that straddles a boundary is read by both
+    tiles: the fourth output counts that. ``ROW_TILE`` is the ladder's
+    floor, since 64 rows handed is where the records put the products at
+    82% of the touched experts' read. Tiles do not engage at
+    ``RIDGE_ROWS`` or fewer (the operations hide beneath the read there,
+    and a tile costs a call: 192 rows over 99 of 128 smaller experts ran
+    3% slower in tiles), over ``KERNEL_ROWS`` (the kernel cuts such rows
+    itself and passes the tiles that hold no row of a group), or inside a
+    ladder's steps, whose row count is the cut already: those calls trace
+    as they did.
 
     ``experts`` holds ``w_in`` (E, dim, 2 * hidden) and ``w_out``; or, from
     a scanned stack (``ops.transformer.block_stack``), the WHOLE stack's
@@ -266,27 +339,36 @@ def dropless_experts(experts: dict, x: Array, picks: Array,
             flat = jnp.where(here, flat - first, e)
         order = jnp.argsort(flat, stable=True)      # pairs, by expert
         sizes = jnp.zeros((e,), jnp.int32).at[flat].add(1)
+        # (tiles, E): a call without a ladder, its rows several tiles
+        tiled = tile_sizes(sizes, t * k) \
+            if len(ladder) == 1 and row_tiles(t * k) > 1 else None
 
     def products(r: int):
-        """The first ``r`` sorted pairs through their experts -> (t * k,
-        dim) f32 in sorted order, the rows behind ``r`` zero."""
+        """The first ``r`` sorted pairs through their experts, a row tile
+        at a time -> (t * k, dim) f32 in sorted order, the rows behind
+        ``r`` zero."""
         w_in, w_out = experts["w_in"], experts["w_out"]
         with jax.named_scope("moe.route"):
             rows = jnp.take(x, order[:r] // k, axis=0)      # (r, dim)
             pair_weights = jnp.take(weights.reshape(-1), order[:r])
-            groups = sizes
+            groups = [sizes] if tiled is None else list(tiled)
             if "layer" in experts:
                 w_in = w_in.reshape((-1,) + w_in.shape[2:])
                 w_out = w_out.reshape((-1,) + w_out.shape[2:])
-                groups = lax.dynamic_update_slice(
-                    jnp.zeros((w_in.shape[0],), jnp.int32), sizes,
-                    (experts["layer"] * e,))
+                groups = [lax.dynamic_update_slice(
+                    jnp.zeros((w_in.shape[0],), jnp.int32), g,
+                    (experts["layer"] * e,)) for g in groups]
         with jax.named_scope("moe.experts"):
-            gate, up = jnp.split(
-                lax.ragged_dot(rows, w_in.astype(x.dtype), groups), 2,
-                axis=-1)
-            out = lax.ragged_dot(jax.nn.silu(gate) * up,
-                                 w_out.astype(x.dtype), groups)
+            outs = []
+            for j, g in enumerate(groups):
+                tile = rows if tiled is None else \
+                    rows[j * ROW_TILE:(j + 1) * ROW_TILE]
+                gate, up = jnp.split(
+                    lax.ragged_dot(tile, w_in.astype(x.dtype), g), 2,
+                    axis=-1)
+                outs.append(lax.ragged_dot(jax.nn.silu(gate) * up,
+                                           w_out.astype(x.dtype), g))
+            out = outs[0] if tiled is None else jnp.concatenate(outs)
             # each pair's weight, in f32, here: the compiler's
             # grouped-product kernel carries no scope of its own and takes
             # its first reader's
@@ -309,20 +391,24 @@ def dropless_experts(experts: dict, x: Array, picks: Array,
         # back to (token, pick) order, and the sum over a token's picks
         out = jnp.take(out, jnp.argsort(order), axis=0).reshape(t, k, -1)
         out = jnp.sum(out, axis=1)
-    return out.astype(x.dtype), sizes, handed
+        # one tile reads each touched group once
+        reads = jnp.sum((sizes if tiled is None else tiled) > 0).astype(
+            jnp.int32)
+    return out.astype(x.dtype), sizes, handed, reads
 
 
 def dropless_apply(params: dict, x: Array, blk):
     """x (..., dim) -> (out (..., dim), load (``load_width``,) int32:
     picks routed, held experts that received one, the fullest held
-    expert's picks and, where a share is held, the picks that fell on
-    it and the rows handed to the grouped products)."""
+    expert's picks, the (row tile, expert) pairs that hold a row and,
+    where a share is held, the picks that fell on it and the rows handed
+    to the grouped products)."""
     lead = x.shape[:-1]
     xt = x.reshape(-1, x.shape[-1])
     picks, weights = route(params["router"], xt, blk.experts_per_token,
                            blk.routed_scale, blk.route_eps)
     whole = holds_all(blk)
-    out, sizes, handed = dropless_experts(
+    out, sizes, handed, reads = dropless_experts(
         params["experts"], xt, picks, weights,
         None if whole else blk.first_expert, blk.num_experts)
     if "shared" in params:
@@ -330,7 +416,7 @@ def dropless_apply(params: dict, x: Array, blk):
             out = out + core.swiglu(params["shared"], xt)
     held = jnp.sum(sizes)
     load = [held if whole else jnp.int32(picks.size),
-            jnp.sum(sizes > 0).astype(jnp.int32), jnp.max(sizes)]
+            jnp.sum(sizes > 0).astype(jnp.int32), jnp.max(sizes), reads]
     if not whole:
         load += [held, handed]
     return out.reshape(lead + (-1,)), jnp.stack(load)

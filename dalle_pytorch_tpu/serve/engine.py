@@ -172,11 +172,14 @@ STALLS_KEPT = 8         # stats()["last_stalls"]
 # engine), summed over its routed layers and every decode step: what the
 # fused decode program returns beside the ring, in the order it stacks
 # them (ops.moe.dropless_apply): token-picks routed, experts that received
-# one, the fullest expert's picks and, where the block holds a share of
-# its experts, the picks that fell on the share and the rows its grouped
-# products were handed (ops.moe.row_ladder)
+# one, the fullest expert's picks, the reads of an expert's weights (the
+# (row tile, expert) pairs that hold a row, ops.moe.dropless_experts: the
+# experts touched where the products run as one tile; FOURTH, so that the
+# slice below ends a block that holds every expert there) and, where the
+# block holds a share of its experts, the picks that fell on the share and
+# the rows its grouped products were handed (ops.moe.row_ladder)
 MOE_COUNTERS = ("moe_picks", "moe_experts_touched", "moe_load_max",
-                "moe_picks_held", "moe_rows_computed")
+                "moe_group_reads", "moe_picks_held", "moe_rows_computed")
 # of a block whose window layers' softmax holds a learned sink
 # (ops.transformer.WindowGQABlock.sink): the sink's softmax weight summed
 # over the window layers, the query heads, the active slots and every
@@ -187,9 +190,10 @@ SINK_COUNTERS = ("window_sink_mass", "window_sink_reads")
 
 def load_counters(blk) -> tuple:
     """The names of a described block's load, in the order in which its
-    decode program returns them after the ring (``moe_picks_held`` and
-    ``moe_rows_computed`` only where the block holds a share, the sink's
-    two only where it has one)."""
+    decode program returns them after the ring (the first four of
+    ``MOE_COUNTERS``, ``moe_group_reads`` the last of them, where the
+    block holds every expert; ``moe_picks_held`` and ``moe_rows_computed``
+    too where it holds a share; the sink's two only where it has one)."""
     from dalle_pytorch_tpu.ops.moe import load_width
     return MOE_COUNTERS[:load_width(blk)] + (
         SINK_COUNTERS if blk.sink else ())

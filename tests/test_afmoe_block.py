@@ -203,7 +203,7 @@ def test_prefill_then_paged_decode_matches_the_full_forward(    # past it
         np.testing.assert_allclose(logits[fin], ref_logits[:, pos][fin],
                                    atol=2e-5, rtol=0)
         picks = b * BLK.experts_per_token * DIMS.moe_layers
-        assert int(load[0]) == picks and 0 <= int(load[3]) <= picks
+        assert int(load[0]) == picks and 0 <= int(load[4]) <= picks
     # the same steps in chunks of 8 write the same pools and count the picks
     embed_fn, sample_fn = _teacher_forced(params, sequences)
     cur = jnp.asarray(sequences[:, t0])
@@ -512,7 +512,7 @@ def test_all_the_shares_add_up_to_the_uncut_reference_layer():
             np.asarray(ref_p["experts"]["w_in"][first:first + 4]))
         out, load = moe_ops.dropless_apply(p, m, blk)
         total += np.asarray(out) - shared
-        held += int(load[3])
+        held += int(load[4])
         assert int(load[0]) == 24 * 2 and int(load[1]) <= 4
         # and the family's reference, given the same share, agrees
         R = FAMILY.reference
@@ -559,10 +559,10 @@ def test_a_share_routes_over_all_experts_and_computes_its_own():
     sizes = np.bincount(picks.reshape(-1), minlength=8)[2:5]
     assert sizes[1] == n and sizes.sum() < 2 * n
     assert list(np.asarray(load)) == [n * 2, (sizes > 0).sum(), sizes.max(),
-                                      sizes.sum(), n * 2]
-    # with every expert held, the load has no fourth or fifth entry
+                                      (sizes > 0).sum(), sizes.sum(), n * 2]
+    # with every expert held, the load has no fifth or sixth entry
     assert moe_ops.load_width(dataclasses.replace(
-        blk, experts_held=8, first_expert=0)) == 3
+        blk, experts_held=8, first_expert=0)) == 4
 
 
 # -- (v) every path that cannot run the block refuses it ----------------------

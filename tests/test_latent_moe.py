@@ -329,7 +329,9 @@ def test_dropless_routing_equals_a_per_token_loop_and_drops_nothing():
     np.testing.assert_allclose(np.asarray(out).reshape(n, 32), want,
                                atol=1e-5)
     sizes = np.bincount(picks.reshape(-1), minlength=8)
-    assert list(np.asarray(load)) == [n * 2, (sizes > 0).sum(), sizes.max()]
+    # (n * 2 pair rows are one row tile: each touched expert read once)
+    assert list(np.asarray(load)) == [n * 2, (sizes > 0).sum(), sizes.max(),
+                                      (sizes > 0).sum()]
     assert int(load[2]) >= n                    # what a capacity would drop
 
 
@@ -346,7 +348,7 @@ def test_stack_of_one_dense_and_two_expert_layers_indexes_the_pool(params):
     h, entries, loads = T.block_apply_full(tp, x, TCFG)
     entries = entries["latent"]         # the one pool's one buffer
     assert entries.shape == (3, 1, 6, BLK.row_width)
-    assert np.asarray(loads)[0].tolist() == [0, 0, 0]       # the dense layer
+    assert np.asarray(loads)[0].tolist() == [0, 0, 0, 0]    # the dense layer
     assert (np.asarray(loads)[1:, 0] == 6 * 2).all()
     # layer l of the stack is the pool's layer l: a decode step at position
     # 5 over the first five rows writes each layer's own row

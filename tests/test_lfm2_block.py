@@ -300,9 +300,10 @@ def test_a_routed_layer_is_the_uncut_reference_s_layer():
     np.testing.assert_allclose(
         np.asarray(out), np.asarray(R.routed(p["experts"], m, weights)),
         atol=1e-5)
-    assert moe_ops.holds_all(BLK) and moe_ops.load_width(BLK) == 3
-    assert load.shape == (3,) and int(load[0]) == 24 * 2 \
-        and 0 < int(load[1]) <= 8 and int(load[2]) >= 24 * 2 // 8
+    assert moe_ops.holds_all(BLK) and moe_ops.load_width(BLK) == 4
+    assert load.shape == (4,) and int(load[0]) == 24 * 2 \
+        and 0 < int(load[1]) <= 8 and int(load[2]) >= 24 * 2 // 8 \
+        and int(load[3]) == int(load[1])        # 48 pair rows: one tile
     picks, w = moe_ops.route(p["router"], m, 2, 1.0, BLK.route_eps)
     dense = np.zeros((24, 8), np.float32)
     np.put_along_axis(dense, np.asarray(picks), np.asarray(w), axis=1)
@@ -474,8 +475,8 @@ def test_prefill_then_paged_decode_matches_the_full_forward(
         np.testing.assert_allclose(logits[fin], ref_logits[:, pos][fin],
                                    atol=ATOL, rtol=0)
         # a step that carries a state a slot AND returns a routed load:
-        # the three counts of a block that holds every expert
-        assert load.shape == (3,) and load.dtype == jnp.int32
+        # the four counts of a block that holds every expert
+        assert load.shape == (4,) and load.dtype == jnp.int32
         assert int(load[0]) == b * BLK.experts_per_token * DIMS.moe_layers
         assert 0 < int(load[1]) <= DIMS.moe_layers * DIMS.experts
 
@@ -553,6 +554,8 @@ def test_engine_serves_the_reference_s_tokens_in_chunks_of_8(params, alone):
     assert 0 < st["moe_experts_touched"] <= st["decode_steps"] \
         * DIMS.moe_layers * DIMS.experts
     assert "moe_picks_held" not in st and "moe_rows_computed" not in st
+    # a step's pair rows are one row tile here: nothing read twice
+    assert st["moe_group_reads"] == st["moe_experts_touched"]
     assert st["kv_hbm_bytes"] == KV.modeled_kv_bytes(
         TCFG, kv="paged", num_slots=2, total_len=DIMS.seq_len, page_size=PS)
     # what a step reads: two full layers' tables, seven layers' tails

@@ -329,16 +329,17 @@ def test_prefill_then_paged_decode_matches_the_full_forward(
         np.testing.assert_allclose(logits[fin], ref_logits[:, pos][fin],
                                    atol=2e-5, rtol=0)
         picks = b * BLK.experts_per_token * DIMS.moe_layers
-        # (with a sink the load is float32: the five counts, then the
+        # (with a sink the load is float32: the six counts, then the
         # sink's weight over every window softmax of the step and their
         # number, ``decode_ops.load_like``)
-        assert load.shape == (7,) and load.dtype == jnp.float32
-        assert int(load[0]) == picks and 0 <= int(load[3]) <= picks
-        assert int(load[4]) == picks    # too few pairs for a row ladder
+        assert load.shape == (8,) and load.dtype == jnp.float32
+        assert int(load[0]) == picks and 0 <= int(load[4]) <= picks
+        assert int(load[5]) == picks    # too few pairs for a row ladder
+        assert int(load[3]) == int(load[1])     # and for a second row tile
         reads = b * TCFG.heads * len(WINDOW_LAYERS)
-        assert int(load[6]) == reads
-        assert 0.0 < float(load[5]) < reads
-        mass += float(load[5]) / reads
+        assert int(load[7]) == reads
+        assert 0.0 < float(load[6]) < reads
+        mass += float(load[6]) / reads
     # the draw gives the sink a real share of a window row's weight
     assert 0.05 < mass / (DIMS.seq_len - 1 - t0) < 0.9
     # the same steps in chunks of 8 write the same pools and count alike
@@ -353,7 +354,7 @@ def test_prefill_then_paged_decode_matches_the_full_forward(
                 cfg=TCFG, key_mask=key_mask, total_len=DIMS.seq_len, steps=8,
                 embed_fn=embed_fn, sample_fn=sample_fn)
         picks += int(load[0])
-        reads += int(load[6])
+        reads += int(load[7])
     assert picks == 16 * b * BLK.experts_per_token * DIMS.moe_layers
     assert reads == 16 * b * TCFG.heads * len(WINDOW_LAYERS)
     np.testing.assert_array_equal(np.asarray(ring)[:, -1],
@@ -402,7 +403,7 @@ def test_slots_at_the_ring_s_edges_match_the_full_forward(
     got, load = _step_at(params, sequences[rows], positions, active)
     _close(got[1:], ref_logits[rows, positions][1:])
     # the parked slot's softmaxes are not counted
-    assert int(load[6]) == 15 * TCFG.heads * len(WINDOW_LAYERS)
+    assert int(load[7]) == 15 * TCFG.heads * len(WINDOW_LAYERS)
 
 
 def test_window_rows_at_the_published_ring_s_edges():
@@ -601,7 +602,7 @@ def test_all_the_shares_add_up_to_the_uncut_reference_layer():
             np.asarray(ref_p["experts"]["w_in"][first:first + 4]))
         out, load = moe_ops.dropless_apply(p, m, blk)
         total += np.asarray(out)
-        held += int(load[3])
+        held += int(load[4])
         assert int(load[0]) == 24 * 2 and int(load[1]) <= 4
         # and the family's reference, given the same share, agrees
         np.testing.assert_allclose(
@@ -626,7 +627,7 @@ def test_a_routed_layer_without_a_shared_expert():
     nothing = ~((np.asarray(picks) >= 2) & (np.asarray(picks) < 5)).any(-1)
     assert nothing.any() and not np.asarray(out)[nothing].any()
     assert np.asarray(out)[~nothing].any(-1).all()
-    assert int(load[0]) == 48 and int(load[3]) < 48
+    assert int(load[0]) == 48 and int(load[4]) < 48
 
 
 # -- (v) every path that cannot run the block refuses it ----------------------

@@ -1,7 +1,9 @@
 """The held experts' grouped products run over the rows that lie in a held
 group (ops/moe.py ``row_ladder``, ``dropless_experts``): the ladder from
 shapes, every step of it against a float32 per-pair reference, the load's
-fifth entry, and the call with no ladder left as it was."""
+last entry, and the call with no ladder and one row tile left as it was
+(a call without a ladder whose rows make several tiles:
+tests/test_moe_row_tiles.py)."""
 
 import dataclasses
 
@@ -78,10 +80,12 @@ def test_every_step_of_the_ladder_against_the_per_pair_reference(
     if stacked:
         experts["layer"] = jnp.int32(1)
         w_in, w_out = w_in[1], w_out[1]
-    out, sizes, handed = jax.jit(
+    out, sizes, handed, reads = jax.jit(
         lambda ex, x, p, w: moe_ops.dropless_experts(ex, x, p, w, first, OF)
     )(experts, x, picks, weights)
     assert int(handed) == step and int(sizes.sum()) == held
+    # a ladder's steps are one tile each: every touched group read once
+    assert int(reads) == int((sizes > 0).sum())
     want = _reference(w_in, w_out, x, picks, weights, first)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=2e-5)
     if not held:
@@ -90,7 +94,8 @@ def test_every_step_of_the_ladder_against_the_per_pair_reference(
 
 def _parent_dropless_experts(experts, x, picks, weights, first=None):
     """``dropless_experts`` as it stood before the ladder (PR 40), for the
-    jaxpr of a call that has none."""
+    jaxpr of a call that has none; and, last, the load's entry of PR 43:
+    the groups that hold a row, which one tile reads once each."""
     t, k = picks.shape
     w_in, w_out = experts["w_in"], experts["w_out"]
     e = w_in.shape[-3]
@@ -121,17 +126,25 @@ def _parent_dropless_experts(experts, x, picks, weights, first=None):
     with jax.named_scope("moe.route"):
         out = jnp.take(out, jnp.argsort(order), axis=0).reshape(t, k, -1)
         out = jnp.sum(out, axis=1)
-    return out.astype(x.dtype), sizes
+        reads = jnp.sum(sizes > 0).astype(jnp.int32)
+    return out.astype(x.dtype), sizes, reads
 
 
 @pytest.mark.parametrize("stacked", [False, True], ids=["a_layer", "a_stack"])
 @pytest.mark.parametrize("tokens,first", [
-    (32, None),     # every expert held (kanana's decode: 192 pair rows)
+    (8, None),      # every expert held, one tile of pair rows
+    (24, None),     # the same under the ridge (kanana's decode: 192 rows)
+    (65, None),     # the same just over the kernel's own row tile
     (512, None),    # the same in a prefill
     (8, 32),        # a share, the pairs under the first step (trinity's 64)
-], ids=["all_held", "all_held_prefill", "a_share_of_few_pairs"])
+], ids=["all_held", "all_held_under_the_ridge",
+        "all_held_over_the_kernel_s_tile", "all_held_prefill",
+        "a_share_of_few_pairs"])
 def test_a_call_without_a_ladder_traces_as_the_parent_s(tokens, first,
                                                         stacked):
+    """No more rows than the chip's ridge, or more than the kernel's own
+    tile: the parent's jaxpr but for the load's new entry."""
+    assert moe_ops.row_tiles(tokens * K) == 1
     picks, weights, x = _pairs(tokens, tokens, 32)
     if first is None:
         picks = picks % E
@@ -139,8 +152,11 @@ def test_a_call_without_a_ladder_traces_as_the_parent_s(tokens, first,
     if stacked:
         experts["layer"] = jnp.int32(2)
     args = (experts, x.astype(jnp.bfloat16), picks, weights)
-    now = jax.make_jaxpr(lambda *a: moe_ops.dropless_experts(
-        *a, first, OF)[:2])(*args)
+    def but_handed(*a):
+        out, sizes, _, reads = moe_ops.dropless_experts(*a, first, OF)
+        return out, sizes, reads
+
+    now = jax.make_jaxpr(but_handed)(*args)
     then = jax.make_jaxpr(lambda *a: _parent_dropless_experts(
         *a, first))(*args)
     assert str(now) == str(then)
@@ -153,12 +169,13 @@ def test_a_call_with_a_ladder_has_one_conditional_of_its_steps():
     picks, weights, x = _pairs(64, 40, 32)
     text = str(jax.make_jaxpr(lambda *a: moe_ops.dropless_experts(
         *a, 32, OF))(_experts(), x, picks, weights))
+    # a step's row count is the cut already: no row tiles inside a ladder
     assert text.count("cond[") == 1 and text.count("= ragged_dot_general[") == 6
     for rows in (64, 128, 512):
         assert f"f32[{rows},{2 * HIDDEN}] = ragged_dot_general[" in text
 
 
-# -- the load's fifth entry, and the engine's counter --------------------------
+# -- the load's last entry, and the engine's counter ---------------------------
 
 @dataclasses.dataclass(frozen=True)
 class _Blk:
@@ -174,7 +191,7 @@ class _Blk:
 
 @pytest.mark.parametrize("bias,step", [(-1.0, 64), (0.0, 64), (0.06, 128),
                                        (1.0, 512)])
-def test_the_load_s_fifth_entry_is_the_step_taken(bias, step):
+def test_the_load_s_last_entry_is_the_step_taken(bias, step):
     """A selection bias on the held experts moves the picks onto them: the
     products are handed the least step that holds the held picks."""
     blk = _Blk()
@@ -183,14 +200,14 @@ def test_the_load_s_fifth_entry_is_the_step_taken(bias, step):
     p["router"]["bias"] = jnp.where(held, bias, 0.0)
     x = jax.random.normal(jax.random.PRNGKey(4), (4, 16, DIM))
     out, load = jax.jit(lambda p, x: moe_ops.dropless_apply(p, x, blk))(p, x)
-    assert moe_ops.load_width(blk) == 5 and load.shape == (5,)
-    picks, handed = int(load[3]), int(load[4])
+    assert moe_ops.load_width(blk) == 6 and load.shape == (6,)
+    picks, handed = int(load[4]), int(load[5])
     assert int(load[0]) == 512 and handed == step
     assert picks <= handed and not any(
         picks <= r < handed for r in moe_ops.row_ladder(512, E, OF))
     assert out.shape == x.shape
     whole = dataclasses.replace(blk, experts_held=OF, first_expert=0)
-    assert moe_ops.load_width(whole) == 3
+    assert moe_ops.load_width(whole) == 4
 
 
 def _toy_engine(family: str, config: str, depth: int) -> Engine:
@@ -213,21 +230,27 @@ def _toy_engine(family: str, config: str, depth: int) -> Engine:
 def test_the_engine_counts_the_rows_where_a_share_is_held(family, config,
                                                           depth, there):
     stats = _toy_engine(family, config, depth).stats()
-    assert "moe_picks" in stats
+    assert "moe_picks" in stats and "moe_group_reads" in stats
     assert ("moe_rows_computed" in stats) == there
     assert ("moe_picks_held" in stats) == there
 
 
-# -- the ladder from shapes, at the three routed configurations' sizes ---------
+# -- the ladder and the tiles from shapes, at the routed cells' sizes -----------
 
-@pytest.mark.parametrize("cell,decode,prefill", [
-    ("mimo-v2.5.serve-full", (64, 128, 512), (1024, 2048, 8192)),
-    ("trinity-large-preview.serve-full", None, (1024, 2048, 4096)),
-    ("kanana-2-30b-a3b.serve-full", None, None),
+@pytest.mark.parametrize("cell,decode,prefill,tiles", [
+    ("mimo-v2.5.serve-full", (64, 128, 512), (1024, 2048, 8192), 1),
+    ("trinity-large-preview.serve-full", None, (1024, 2048, 4096), 1),
+    ("kanana-2-30b-a3b.serve-full", None, None, 1),
+    ("lfm2-24b-a2b.serve-full", None, None, 4),
 ])
-def test_the_ladder_of_each_routed_cell_s_programs(cell, decode, prefill):
+def test_the_ladder_of_each_routed_cell_s_programs(cell, decode, prefill,
+                                                   tiles):
     """Decode hands the function a pair row a slot a pick, an admission a
-    group of 4 rows x the 256-token bucket x the picks."""
+    group of 4 rows x the 256-token bucket x the picks. ``tiles``: the row
+    tiles of the step that a decode step of the cell takes (mimo's, by
+    ``moe_rows_computed_pct`` 12.5, its first; kanana's 192 pair rows lie
+    under the ridge); every step of every admission is one tile, the rows
+    being more than the kernel's own."""
     cell = harness.Cell(cell)
     dims = cell.family.weights.dims_of(cell.config, cell.spec["depth"])
     blk = cell.family.build.program_config(
@@ -235,12 +258,17 @@ def test_the_ladder_of_each_routed_cell_s_programs(cell, decode, prefill):
     slots = int(cell.spec["num_slots"])
     assert min(S.prefill_groups(slots)) == 4
 
-    def ladder(tokens):
+    def steps(tokens):
         pairs = tokens * blk.experts_per_token
         if moe_ops.holds_all(blk):
-            return None
-        steps = moe_ops.row_ladder(pairs, blk.experts_held, blk.num_experts)
-        return steps if len(steps) > 1 else None
+            return (pairs,)
+        return moe_ops.row_ladder(pairs, blk.experts_held, blk.num_experts)
+
+    def ladder(tokens):
+        return steps(tokens) if len(steps(tokens)) > 1 else None
 
     assert ladder(slots) == decode
     assert ladder(4 * 256) == prefill
+    assert moe_ops.row_tiles(steps(slots)[0]) == tiles
+    for rows in S.prefill_groups(slots):
+        assert {moe_ops.row_tiles(r) for r in steps(rows * 256)} == {1}
