@@ -4,37 +4,46 @@
 ops/transformer.py, served): sigmoid scores in float32, the k largest of
 ``score + bias`` picked, their scores renormalised and scaled, and every
 (token, pick) pair computed. The pairs are sorted by expert and the
-experts run as grouped matrix products over the sorted rows
-(``jax.lax.ragged_dot``, which the TPU compiler lowers to its own grouped
-matmul kernel: it visits the groups that hold rows and reads no other
-expert's weights); the results go back to token order and are summed
-with their weights, shared experts added. No capacity exists and no
-token is dropped, whatever the load: the same function serves a
-prefill's thousands of tokens and a decode step's few dozen. It returns
-its load (picks routed, experts touched, the fullest expert's picks, the
-reads of an expert's weights) for the engine's counters. A block may HOLD
-a share of its experts (one chip of an expert-parallel deployment:
-``blk.first_expert``, ``blk.experts_held``): the router still scores and picks among all of
+experts run as grouped matrix products over the sorted rows; the results
+go back to token order and are summed with their weights, shared experts
+added. No capacity exists and no token is dropped, whatever the load: the
+same function serves a prefill's thousands of tokens and a decode step's
+few dozen. It returns its load (picks routed, experts touched, the
+fullest expert's picks, the reads of an expert's weights) for the
+engine's counters. A block may HOLD a share of its experts (one chip of
+an expert-parallel deployment: ``blk.first_expert``,
+``blk.experts_held``): the router still scores and picks among all of
 them, the pairs whose expert is held elsewhere lie in no group and add
 nothing, and the load says how many picks were held. Nothing stands in
-for the absent chips: their part of the sum is left out. What an absent
-pair adds is nothing; what its row COSTS, handed to the grouped products,
-is a row of every touched group's tile, since the kernel multiplies all
-the rows it is given against each group that holds any. The absent pairs
-are sorted behind the held ones, so a share's products are handed the
-first R sorted rows alone: R the least step of a short static ladder
-(``row_ladder``: from the shapes, twice the rows an even load brings,
-that doubled, every pair) that holds the step's held pairs, by one
-``lax.switch`` a layer, and the load says which step was taken. Where a
-call has no ladder (every expert held, or few pairs) the same cost is paid
-for the rows of OTHER groups, so its sorted rows are cut into static ROW
-TILES of ``ROW_TILE`` rows, the ladder's floor, and each tile's products
-run over the groups clipped to the tile (``row_tiles``, ``tile_sizes``): a
-touched expert's weights meet the 64 sorted rows its picks lie in, an
-expert whose rows straddle a boundary is read by both tiles, and the load
-counts those reads. A call of no more rows than the chip's ridge
-(``RIDGE_ROWS``: the operations hide beneath the weights' read), or of
-more rows than the kernel's own tile (a prefill's thousands), runs as one.
+for the absent chips: their part of the sum is left out.
+
+WHICH grouped products run, a call's shapes decide
+(``kernel_hidden_tile``). A decode step's rows (at most ``KERNEL_ROWS``
+pair rows, bfloat16, widths of whole lanes) run in the repo's Pallas
+kernel (``expert_products``): its grid visits the (row tile of
+``ROW_TILE``, group) pairs that share a row and multiplies a group's
+weights against its own tiles only, both products in one pass over the
+expert, the stack indexed in place. Every other call (a prefill's
+thousands of rows, float32, toy widths) runs ``jax.lax.ragged_dot``, which
+the TPU compiler lowers to its own grouped matmul kernel: it visits the
+groups that hold rows and reads no other expert's weights, but
+multiplies ALL the rows it is given against each group that holds any.
+There, what an absent pair's row COSTS is a row of every touched group's
+tile. The absent pairs are sorted behind the held ones, so a share's
+products are handed the first R sorted rows alone: R the least step of a
+short static ladder (``row_ladder``: from the shapes, twice the rows an
+even load brings, that doubled, every pair) that holds the step's held
+pairs, by one ``lax.switch`` a layer, and the load says which step was
+taken. Where a call has no ladder (every expert held, or few pairs) the
+compiler's products pay the same for the rows of OTHER groups, so its
+sorted rows are cut into static ROW TILES of ``ROW_TILE`` rows, the
+ladder's floor, and each tile's products run over the groups clipped to
+the tile (``row_tiles``, ``tile_sizes``): a touched expert's weights meet
+the 64 sorted rows its picks lie in, an expert whose rows straddle a
+boundary is read by both tiles, and the load counts those reads. A call
+of no more rows than the chip's ridge (``RIDGE_ROWS``: the operations hide
+beneath the weights' read), or of more rows than the compiler's kernel's
+own tile (a prefill's thousands), runs as one.
 
 **Capacity routing** (``moe_apply``; the trainable ``moe_experts`` option
 of the classic block, expert-parallel over an ``ep`` axis): the standard
@@ -58,6 +67,8 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from dalle_pytorch_tpu.ops import core
 
@@ -222,22 +233,36 @@ def route(router: dict, x: Array, k: int, scale: float, eps: float = 0.0):
 
 
 # the fewest sorted rows the grouped products are handed at once: the
-# ladder's least step and a row tile's height. At 64 rows handed the
-# products run at 82% of the touched experts' read in two cells (PERF.md
-# section 6, PRs 33 and 41); under it a tile's re-reads buy nothing
+# ladder's least step, a row tile's height, and the row tile of the
+# experts' kernel (``expert_products``). At 64 rows handed the compiler's
+# grouped product runs at 82% of the touched experts' read in two cells
+# (PERF.md section 6, PRs 33 and 41); under it a tile's re-reads buy nothing
 ROW_TILE = 64
 # the rows handed at which a grouped product's operations (two a weight a
 # row) take this chip as long as the read of the weights (two bytes a
 # weight): 197 TFLOP/s over 819 GB/s (a v5e: utils/device.py CHIP_PEAKS).
-# Under it the operations hide beneath the read, and cutting the rows buys
-# nothing and costs a call a tile (measured at 192 rows: PERF.md section
-# 6, PR 43)
+# Under it the operations of the COMPILER'S grouped product hide beneath
+# the read, and cutting the rows buys nothing and costs a call a tile
+# (measured at 192 rows: PERF.md section 6, PR 43). It binds a call that
+# the experts' kernel does not take; the kernel multiplies a group's own
+# rows only and has no ridge to stay under
 RIDGE_ROWS = 240
-# the compiler's grouped-product kernel cuts the rows it is handed into
-# tiles of this many itself (its row operand in the compiled text) and
-# passes a tile that holds no row of a group: over it, nothing is gained
-# by cutting the rows here (a prefill's thousands: PERF.md section 6, PR 41)
+# the most rows a call hands the experts' kernel: a decode step's. The
+# compiler's grouped product cuts the rows it is handed into tiles of this
+# many itself (its row operand in the compiled text) and passes a tile
+# that holds no row of a group: over it, nothing is gained by cutting the
+# rows here, the products are bound by their operations, and they stay the
+# compiler's (a prefill's thousands: PERF.md section 6, PR 41)
 KERNEL_ROWS = 512
+# the bytes of one expert's weights that a grid step of the experts'
+# kernel brings in (a hidden tile's gate, up and down blocks); two such
+# are in flight. 20 MB takes a whole expert of 2048 x 1536 (18.9 MB) a
+# step: measured at four cells' decode shapes, whole experts run 2.4% and
+# 0.6% faster than tiles of them (a group that straddles a row tile is
+# then read once, not twice), and 50 MB experts lose under 1% to tiles of
+# 6 MB (PERF.md section 6, PR 44)
+KERNEL_BLOCK_BYTES = 20 << 20
+NUM_LANES = 128
 
 
 def row_ladder(pairs: int, held: int, num_experts: int) -> Tuple[int, ...]:
@@ -254,11 +279,11 @@ def row_ladder(pairs: int, held: int, num_experts: int) -> Tuple[int, ...]:
 
 
 def row_tiles(rows: int) -> int:
-    """The tiles into which the grouped products of ``rows`` sorted rows
-    are cut, from the shape alone: ``ROW_TILE`` rows each (the last one
-    what is left) where ``RIDGE_ROWS < rows <= KERNEL_ROWS``, the rows at
-    which the products are bound by operations that a tile saves; else
-    one."""
+    """The tiles into which the COMPILER'S grouped products of ``rows``
+    sorted rows are cut, from the shape alone: ``ROW_TILE`` rows each (the
+    last one what is left) where ``RIDGE_ROWS < rows <= KERNEL_ROWS``, the
+    rows at which the products are bound by operations that a tile saves;
+    else one."""
     return -(-rows // ROW_TILE) if RIDGE_ROWS < rows <= KERNEL_ROWS else 1
 
 
@@ -267,12 +292,139 @@ def tile_sizes(sizes: Array, rows: int) -> Array:
     that lie in each tile of the first ``rows`` sorted rows. A group that
     no row of a tile lies in has size 0 there (its weights are not read
     for that tile); one that straddles a boundary has rows in both tiles
-    (and is read for both)."""
+    (and is visited for both)."""
     ends = jnp.cumsum(sizes)
     starts = ends - sizes
     lo = jnp.arange(0, rows, ROW_TILE, dtype=jnp.int32)[:, None]
     hi = jnp.minimum(lo + ROW_TILE, rows)
     return jnp.clip(ends, lo, hi) - jnp.clip(starts, lo, hi)
+
+
+def kernel_hidden_tile(rows: int, dim: int, hidden: int, dtype
+                       ) -> Optional[int]:
+    """The hidden units that a grid step of the experts' kernel takes for a
+    call that hands it ``rows`` sorted rows of ``dim`` numbers, or None
+    where the call stays on the compiler's grouped product; from the
+    shapes and the type alone. The kernel takes a decode step's rows (at
+    most ``KERNEL_ROWS``, whole row tiles) in bfloat16, the type the served
+    configurations compute in (a float32 call, a reference's or a test's,
+    is not what it was measured on), at widths that fill the chip's lanes;
+    its hidden tile is the largest whole number of lanes that divides
+    ``hidden`` and whose three blocks are within ``KERNEL_BLOCK_BYTES``."""
+    if rows > KERNEL_ROWS or rows % ROW_TILE or dim % NUM_LANES \
+            or jnp.dtype(dtype) != jnp.bfloat16:
+        return None
+    fit = [h for h in range(NUM_LANES, hidden + 1, NUM_LANES)
+           if hidden % h == 0 and 3 * dim * h * 2 <= KERNEL_BLOCK_BYTES]
+    return max(fit, default=None)
+
+
+def _experts_kernel(tile_ref, group_ref, start_ref, end_ref, offset_ref,
+                    x_ref, gate_ref, up_ref, down_ref, out_ref, acc_ref):
+    """One (row tile, group) pair and one hidden tile of the group's
+    expert: the tile's rows through the gate and up columns, their gated
+    product through the down rows, summed over the hidden tiles in f32;
+    at the last of them the group's own rows are stored."""
+    del offset_ref                                  # the index maps' alone
+    pair, j = pl.program_id(0), pl.program_id(1)
+
+    @pl.when(j == 0)
+    def _():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    x = x_ref[...]
+    gate = jnp.dot(x, gate_ref[...], preferred_element_type=jnp.float32)
+    up = jnp.dot(x, up_ref[...], preferred_element_type=jnp.float32)
+    acc_ref[...] += jnp.dot((jax.nn.silu(gate) * up).astype(x.dtype),
+                            down_ref[...],
+                            preferred_element_type=jnp.float32)
+
+    @pl.when(j == pl.num_programs(1) - 1)
+    def _():
+        tile, group = tile_ref[pair], group_ref[pair]
+        row = tile * ROW_TILE + lax.broadcasted_iota(
+            jnp.int32, acc_ref.shape, 0)
+        own = (row >= start_ref[group]) & (row < end_ref[group])
+        # the block of a tile's first visit holds no earlier group's rows
+        first = (pair == 0) | (tile != tile_ref[jnp.maximum(pair - 1, 0)])
+        kept = jnp.where(first, 0.0, out_ref[...].astype(jnp.float32))
+        out_ref[...] = jnp.where(own, acc_ref[...], kept).astype(
+            out_ref.dtype)
+
+
+def expert_products(rows: Array, w_in: Array, w_out: Array, layer,
+                    sizes: Array, tiled: Array, hidden_tile: int) -> Array:
+    """The experts' two products in one Pallas kernel that multiplies each
+    group's weights against the row tiles its own rows lie in, and against
+    nothing else. rows (r, dim), sorted by group, group ``g`` holding
+    ``sizes[g]`` of them, ``tiled`` (``tile_sizes``) of those in each row
+    tile; w_in (L * E, dim, 2 * hidden) and w_out (L * E, hidden, dim), a
+    whole scanned stack as its groups, indexed in place at ``layer * E +
+    group`` through scalar prefetch: no slice, no copy. -> (r, dim) in the
+    rows' type: each group's rows through its expert, the rows of a
+    VISITED tile that lie in no group zero, an unvisited tile's unwritten.
+
+    The grid is (visited pairs, hidden tiles): the (row tile of
+    ``ROW_TILE``, group) pairs that share a row, tile by tile and group by
+    group within a tile, so that a tile's output block is revisited by
+    consecutive steps only; its first bound is the count of such pairs, a
+    traced number: no step idles on an untouched expert or a dead tile. A
+    step's operands are bfloat16, its three products accumulate in f32,
+    and the gated hidden units pass to the down product in bfloat16, as
+    between the compiler's two grouped products. A group whose rows
+    straddle a tile boundary is visited by both tiles, one after the
+    other: where a step takes a whole expert (one hidden tile) the second
+    visit finds the blocks it needs in place and reads nothing, so each
+    touched expert is read once; where it takes a hidden tile of several,
+    each visit reads the expert again."""
+    r, dim = rows.shape
+    e, hidden = sizes.shape[0], w_out.shape[1]
+    hidden_tiles = hidden // hidden_tile
+    with jax.named_scope("moe.route"):
+        visited = (tiled > 0).reshape(-1)                   # tile-major
+        pairs = jnp.nonzero(visited, size=r // ROW_TILE + e - 1,
+                            fill_value=0)[0].astype(jnp.int32)
+        ends = jnp.cumsum(sizes)
+        prefetch = (pairs // e, pairs % e, ends - sizes, ends,
+                    jnp.asarray(layer * e, jnp.int32).reshape(1))
+        # a share that no pair fell on still zeroes a tile
+        visits = jnp.maximum(jnp.sum(visited), 1).astype(jnp.int32)
+
+    def rows_map(pair, j, tile, group, start, end, offset):
+        return tile[pair], 0
+
+    def column_map(first):
+        def index(pair, j, tile, group, start, end, offset):
+            return offset[0] + group[pair], 0, first + j
+        return index
+
+    def down_map(pair, j, tile, group, start, end, offset):
+        return offset[0] + group[pair], j, 0
+
+    columns = (None, dim, hidden_tile)
+    block_bytes = 3 * dim * hidden_tile * w_in.dtype.itemsize
+    with jax.named_scope("moe.experts"):
+        return pl.pallas_call(
+            _experts_kernel,
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=len(prefetch),
+                grid=(visits, hidden_tiles),
+                in_specs=[
+                    pl.BlockSpec((ROW_TILE, dim), rows_map),
+                    pl.BlockSpec(columns, column_map(0)),           # gate
+                    pl.BlockSpec(columns, column_map(hidden_tiles)),  # up
+                    pl.BlockSpec((None, hidden_tile, dim), down_map)],
+                out_specs=pl.BlockSpec((ROW_TILE, dim), rows_map),
+                scratch_shapes=[pltpu.VMEM((ROW_TILE, dim), jnp.float32)]),
+            out_shape=jax.ShapeDtypeStruct((r, dim), rows.dtype),
+            # an output block is revisited along the pairs and summed
+            # along the hidden tiles: both run in order
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary", "arbitrary"),
+                vmem_limit_bytes=2 * block_bytes + (16 << 20)),
+            interpret=core.pallas_interpret(),
+            name="moe.experts",
+        )(*prefetch, rows, w_in, w_in, w_out)
 
 
 def dropless_experts(experts: dict, x: Array, picks: Array,
@@ -281,55 +433,74 @@ def dropless_experts(experts: dict, x: Array, picks: Array,
     """Every (token, pick) pair through its expert, summed per token with
     its weight. x (t, dim), picks / weights (t, k) -> (out (t, dim),
     sizes (E,) int32: picks each HELD expert received, the rows handed to
-    the grouped products, int32, the reads of an expert's weights: the
-    (row tile, group) pairs that hold a row, int32).
+    the grouped products, int32, the reads of an expert's weights, int32).
 
     ``first`` is None where the E experts of ``experts`` are all that the
     picks name. Where they are a share, ``first`` .. ``first + E`` of
     ``num_experts``, a pair whose expert is held elsewhere is sorted
-    behind every group and lies in none: it adds nothing. It is not free:
-    the compiler's grouped-product kernel multiplies EVERY row it is
-    handed against each touched group's weights and keeps the rows of the
-    group, so a row in no group costs a row of every touched group's
-    tile (at 512 pair rows of which 32 are held, sixteen times the
-    products' work, and past what the weights' read takes). So a share's
-    products are handed the first R sorted rows only, R the least step of
-    ``row_ladder`` that holds ``sum(sizes)``, chosen by one ``lax.switch``
-    around the products; the sort, the sizes and the way back to token
-    order stay outside it. Where the ladder is one step (every expert
-    held, or few pairs) there is no switch.
+    behind every group and lies in none: it adds nothing. Handed to the
+    COMPILER'S grouped product it is not free: that kernel multiplies
+    EVERY row it is handed against each touched group's weights and keeps
+    the rows of the group, so a row in no group costs a row of every
+    touched group's tile (at 512 pair rows of which 32 are held, sixteen
+    times the products' work, and past what the weights' read takes). So
+    a share's products are handed the first R sorted rows only, R the
+    least step of ``row_ladder`` that holds ``sum(sizes)``, chosen by one
+    ``lax.switch`` around the products; the sort, the sizes and the way
+    back to token order stay outside it. Where the ladder is one step
+    (every expert held, or few pairs) there is no switch.
 
-    A row that lies in ANOTHER group costs the same as one that lies in
-    none: where every expert is held there is no dead row to leave out,
-    and 256 pair rows against 63 touched groups are as many operations as
-    the experts' read takes time (the products sit on the chip's ridge).
-    So a call without a ladder runs its products a ROW TILE at a time
-    (``row_tiles``): the sorted rows are cut into static tiles of
+    WHICH PRODUCTS RUN is decided a call, from its shapes
+    (``kernel_hidden_tile``), for every step of its ladder: a decode
+    step's pair rows (at most ``KERNEL_ROWS``) in bfloat16 at widths that
+    fill the lanes run in the repo's kernel (``expert_products``), which
+    visits the (row tile, group) pairs that share a row and multiplies a
+    group's weights against its own tiles only, both products in one pass
+    over the expert: the four routed configurations' decode steps, in a
+    scratch call 0.06-0.31 ms a layer under the compiler's products
+    (PERF.md section 6, PR 44). There the load's fourth entry is each
+    touched expert once
+    where a grid step takes a whole expert, and the visited pairs where it
+    takes a tile of one. The ladder stays around the kernel, though a dead
+    tile costs the kernel nothing: the kernel over all 512 rows ran 0.6%
+    faster than in the ladder's first step, not worth a second meaning of
+    the load's last entry in this change (PERF.md Open questions).
+
+    Every other call (a prefill's thousands of rows, which are bound by
+    their operations and which the compiler's kernel cuts and passes
+    itself; float32; widths under a lane) runs ``lax.ragged_dot``, where a
+    row that lies in ANOTHER group costs the same as one that lies in
+    none. So such a call without a ladder runs its products a ROW TILE at
+    a time (``row_tiles``): the sorted rows are cut into static tiles of
     ``ROW_TILE`` rows, and each tile's two products run over the groups
     clipped to it (``tile_sizes``). A touched group is then multiplied
     against the 64 rows its picks lie in, a group with no row in a tile
     is not read for it, and one that straddles a boundary is read by both
-    tiles: the fourth output counts that. ``ROW_TILE`` is the ladder's
-    floor, since 64 rows handed is where the records put the products at
-    82% of the touched experts' read. Tiles do not engage at
+    tiles: the fourth output counts that. Tiles do not engage at
     ``RIDGE_ROWS`` or fewer (the operations hide beneath the read there,
     and a tile costs a call: 192 rows over 99 of 128 smaller experts ran
-    3% slower in tiles), over ``KERNEL_ROWS`` (the kernel cuts such rows
-    itself and passes the tiles that hold no row of a group), or inside a
-    ladder's steps, whose row count is the cut already: those calls trace
-    as they did.
+    3% slower in tiles), over ``KERNEL_ROWS`` (the compiler's kernel cuts
+    such rows itself and passes the tiles that hold no row of a group), or
+    inside a ladder's steps, whose row count is the cut already.
 
     ``experts`` holds ``w_in`` (E, dim, 2 * hidden) and ``w_out``; or, from
     a scanned stack (``ops.transformer.block_stack``), the WHOLE stack's
-    (L, E, ...) with ``layer``, this layer's index in it. The grouped
-    product then runs over all L * E groups with this layer's sizes laid at
-    its offset and zero elsewhere: it reads the groups that hold rows, and
-    the compiler is given no slice of a layer to copy first (a scan's
-    slice of a layer's experts, handed to a kernel, is a copy of them:
-    1.2 GB a layer a step at the published widths)."""
+    (L, E, ...) with ``layer``, this layer's index in it: the compiler is
+    given no slice of a layer to copy first (a scan's slice of a layer's
+    experts, handed to a kernel, is a copy of them: 1.2 GB a layer a step
+    at the published widths). Both products take the stack as its L * E
+    groups: the repo's kernel indexes it at ``layer * E + group``, the
+    compiler's grouped product runs over all the groups with this layer's
+    sizes laid at its offset and zero elsewhere, and reads the groups that
+    hold rows."""
     t, k = picks.shape
     e = experts["w_in"].shape[-3]
+    stacked = "layer" in experts
     ladder = (t * k,) if first is None else row_ladder(t * k, e, num_experts)
+    # the call's shapes decide its products, for every step of its ladder
+    hidden_tile = kernel_hidden_tile(
+        t * k, x.shape[-1], experts["w_out"].shape[-2], x.dtype) \
+        if experts["w_in"].dtype == x.dtype else None
     with jax.named_scope("moe.route"):
         flat = picks.reshape(-1)
         if first is not None:
@@ -339,20 +510,17 @@ def dropless_experts(experts: dict, x: Array, picks: Array,
             flat = jnp.where(here, flat - first, e)
         order = jnp.argsort(flat, stable=True)      # pairs, by expert
         sizes = jnp.zeros((e,), jnp.int32).at[flat].add(1)
-        # (tiles, E): a call without a ladder, its rows several tiles
-        tiled = tile_sizes(sizes, t * k) \
-            if len(ladder) == 1 and row_tiles(t * k) > 1 else None
+        # (tiles, E): the kernel's visits; or the tiles of a call without
+        # a ladder whose rows the compiler's products take a tile at a time
+        tiled = tile_sizes(sizes, t * k) if hidden_tile or (
+            len(ladder) == 1 and row_tiles(t * k) > 1) else None
 
-    def products(r: int):
-        """The first ``r`` sorted pairs through their experts, a row tile
-        at a time -> (t * k, dim) f32 in sorted order, the rows behind
-        ``r`` zero."""
+    def compiler_products(rows):
+        """``lax.ragged_dot`` twice over the rows, a row tile at a time."""
         w_in, w_out = experts["w_in"], experts["w_out"]
         with jax.named_scope("moe.route"):
-            rows = jnp.take(x, order[:r] // k, axis=0)      # (r, dim)
-            pair_weights = jnp.take(weights.reshape(-1), order[:r])
             groups = [sizes] if tiled is None else list(tiled)
-            if "layer" in experts:
+            if stacked:
                 w_in = w_in.reshape((-1,) + w_in.shape[2:])
                 w_out = w_out.reshape((-1,) + w_out.shape[2:])
                 groups = [lax.dynamic_update_slice(
@@ -368,13 +536,32 @@ def dropless_experts(experts: dict, x: Array, picks: Array,
                     axis=-1)
                 outs.append(lax.ragged_dot(jax.nn.silu(gate) * up,
                                            w_out.astype(x.dtype), g))
-            out = outs[0] if tiled is None else jnp.concatenate(outs)
+            return outs[0] if tiled is None else jnp.concatenate(outs)
+
+    def products(r: int):
+        """The first ``r`` sorted pairs through their experts -> (t * k,
+        dim) f32 in sorted order, the rows behind ``r`` zero."""
+        with jax.named_scope("moe.route"):
+            rows = jnp.take(x, order[:r] // k, axis=0)      # (r, dim)
+            pair_weights = jnp.take(weights.reshape(-1), order[:r])
+        if hidden_tile:
+            w_in, w_out = experts["w_in"], experts["w_out"]
+            out = expert_products(
+                rows, w_in.reshape((-1,) + w_in.shape[-2:]),
+                w_out.reshape((-1,) + w_out.shape[-2:]),
+                experts["layer"] if stacked else 0, sizes,
+                tiled[:r // ROW_TILE], hidden_tile)
+        else:
+            out = compiler_products(rows)
+        with jax.named_scope("moe.experts"):
             # each pair's weight, in f32, here: the compiler's
             # grouped-product kernel carries no scope of its own and takes
-            # its first reader's
+            # its first reader's (the repo's kernel carries this scope's
+            # name)
             out = out.astype(jnp.float32) * pair_weights[:, None]
             if first is not None:
-                # rows behind the last group are no product's output
+                # rows behind the last group are no product's output (the
+                # repo's kernel leaves a tile it did not visit unwritten)
                 out = jnp.where((jnp.take(flat, order[:r]) < e)[:, None],
                                 out, 0.0)
             return out if r == t * k else jnp.pad(
@@ -391,9 +578,11 @@ def dropless_experts(experts: dict, x: Array, picks: Array,
         # back to (token, pick) order, and the sum over a token's picks
         out = jnp.take(out, jnp.argsort(order), axis=0).reshape(t, k, -1)
         out = jnp.sum(out, axis=1)
-        # one tile reads each touched group once
-        reads = jnp.sum((sizes if tiled is None else tiled) > 0).astype(
-            jnp.int32)
+        # one tile reads each touched group once; so does the kernel where
+        # a grid step takes a whole expert
+        whole = hidden_tile == experts["w_out"].shape[-2]
+        reads = jnp.sum((sizes if tiled is None or whole else tiled) > 0
+                        ).astype(jnp.int32)
     return out.astype(x.dtype), sizes, handed, reads
 
 

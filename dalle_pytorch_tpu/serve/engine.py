@@ -174,8 +174,9 @@ STALLS_KEPT = 8         # stats()["last_stalls"]
 # them (ops.moe.dropless_apply): token-picks routed, experts that received
 # one, the fullest expert's picks, the reads of an expert's weights (the
 # (row tile, expert) pairs that hold a row, ops.moe.dropless_experts: the
-# experts touched where the products run as one tile; FOURTH, so that the
-# slice below ends a block that holds every expert there) and, where the
+# experts touched where the products run as one tile, or in the repo's
+# kernel a whole expert a grid step; FOURTH, so that the slice below ends
+# a block that holds every expert there) and, where the
 # block holds a share of its experts, the picks that fell on the share and
 # the rows its grouped products were handed (ops.moe.row_ladder)
 MOE_COUNTERS = ("moe_picks", "moe_experts_touched", "moe_load_max",

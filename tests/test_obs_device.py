@@ -311,4 +311,4 @@ def test_every_scope_and_kernel_name_in_the_package_is_in_SCOPES():
             used.add(name.group(1).strip('"'))
             kernels += 1
     assert used == set(odev.SCOPES), used ^ set(odev.SCOPES)
-    assert kernels == 6
+    assert kernels == 7
