@@ -67,6 +67,7 @@ import dataclasses
 import json
 import os
 import signal
+import threading
 import time
 from typing import Iterator, Optional
 
@@ -203,14 +204,19 @@ class FaultPlan:
 
 _active: Optional[FaultPlan] = None
 _fired: set = set()
+# set when the plan is cleared: an injected replica hang waits on it, so
+# the thread it holds is released with the plan and does not sleep on into
+# whatever the process does next
+_cleared = threading.Event()
 
 ENV = "DALLE_FAULTS"
 
 
 def activate(plan: FaultPlan) -> FaultPlan:
-    global _active
+    global _active, _cleared
     _active = plan
     _fired.clear()
+    _cleared = threading.Event()
     return plan
 
 
@@ -218,6 +224,7 @@ def deactivate() -> None:
     global _active
     _active = None
     _fired.clear()
+    _cleared.set()
 
 
 def active() -> Optional[FaultPlan]:
@@ -325,8 +332,9 @@ def on_replica_chunk(replica: int, chunk: int) -> None:
     """Inside a replica's serving loop, before each engine step, with the
     count of fused decode chunks the replica has dispatched so far.
     ``replica_crash_at_chunk=N`` raises (the loop dies and the supervisor
-    must fence + reclaim + replay); ``replica_hang_at_chunk=N`` sleeps
-    ``replica_hang_s`` OUTSIDE the engine lock (the heartbeat stalls
+    must fence + reclaim + replay); ``replica_hang_at_chunk=N`` waits
+    ``replica_hang_s``, or until the plan is cleared if that comes first,
+    OUTSIDE the engine lock (the heartbeat stalls
     exactly as it would on a wedged device sync, and the supervisor must
     fence the replica without the wedged thread's cooperation). Both
     target ``fault_replica`` only and fire at most once."""
@@ -341,7 +349,7 @@ def on_replica_chunk(replica: int, chunk: int) -> None:
     if p.replica_hang_at_chunk >= 0 \
             and chunk >= p.replica_hang_at_chunk \
             and _once("replica_hang"):
-        time.sleep(p.replica_hang_s)
+        _cleared.wait(p.replica_hang_s)
 
 
 def child_plan_for(replica: int) -> Optional[dict]:

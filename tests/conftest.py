@@ -28,15 +28,21 @@ jax.config.update("jax_default_matmul_precision", "highest")
 # persistent compilation cache, placed by the one helper every entry point
 # uses: JAX_COMPILATION_CACHE_DIR when the environment sets it, else the
 # fixed <checkout>/.jax_cache (the path is part of the cache key, so a
-# per-run temporary name could never be warm). Many tests build identical
-# programs from DISTINCT jit objects (every serve test constructs its own
-# Engine, whose fused decode program re-traces but compiles to the same
-# HLO), and on the CPU backend XLA compilation dominates tier-1 wall time.
-# Trace-count contracts are unaffected: guards.compile_count and
-# Engine.decode_traces count TRACES, which still happen once per jit
-# object. Process-isolated serving tests spawn child workers
-# (serve/worker.py) that call the same helper, so they land on the same
-# directory without any hand-off.
+# per-run temporary name could never be warm). The cache spares the XLA
+# compile of an identical program and nothing else: every new jit object
+# (every Engine) pays the trace, the lowering, the cache read and the load
+# again, so a file builds an engine of one shape once
+# (tests/block_contract.py ``served``) and shares a traced step between
+# cases that differ in values only. Trace-count contracts are unaffected:
+# guards.compile_count and Engine.decode_traces count TRACES, which still
+# happen once per jit object. Process-isolated serving tests spawn child
+# workers (serve/worker.py) that call the same helper, so they land on the
+# same directory without any hand-off.
+#
+# The driver's whole run (/root/TESTS_LAST_RUN.json: -n 6 --dist loadfile,
+# a limit of 1470 s) at PR 45: 740-800 s on a warm cache, 1083 s on
+# a cold one. To run cold without touching <checkout>/.jax_cache, point
+# JAX_COMPILATION_CACHE_DIR at an empty directory outside the repo.
 enable_compile_cache()
 jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
 jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
@@ -48,7 +54,7 @@ os.environ["JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES"] = "0"
 
 
 
-# -- cases that pin the parent's compiled form (ISSUE 44) -------------------------
+# -- cases that pin the parent's compiled form (ISSUE 44) ---------------------
 # They sit in files of the benchmark's paths, which a ``perf_opt`` PR may
 # not edit, and assert ``%ragged-dot`` instructions in a decode program
 # whose routed experts run in the repo's kernel since PR 44
@@ -75,7 +81,37 @@ def pytest_collection_modifyitems(items):
                 "runs the kernel since PR 44; rewritten by the next "
                 "benchmark issue")))
 
-# -- the width rule of the paged gather reads (ISSUE 38) -------------------------
+
+# -- which file a worker takes next, and what it keeps of the last ------------
+
+def pytest_configure(config):
+    """``--dist loadfile`` hands out whole files, by default those with the
+    most cases first (xdist's ``loadscopereorder``), so the eight cases of
+    tests/benchmark_suite/test_benchmark_aot.py, ten minutes of compiles,
+    started last and ran alone for as long again while five workers sat
+    idle (1240 s at PR 44). In the order of collection the benchmark's
+    files start first, and the run ends with files that take under a sixth
+    of it each (ROADMAP.md Queue 3 item 1)."""
+    if hasattr(config.option, "loadscopereorder"):
+        config.option.loadscopereorder = False
+
+
+@pytest.fixture(scope="module", autouse=True)
+def programs_end_with_their_file():
+    """Drop the compiled programs a file leaves in jax's caches: each holds
+    memory mappings, a worker that keeps every program of a chain of files
+    nears ``vm.max_map_count`` (65530) and dies in a LATER file's compile
+    or cache read (a segmentation fault: in tests/test_quant.py at PR 38,
+    in tests/benchmark_suite/test_benchmark_correct.py after
+    tests/test_paged_attention.py on the parent of PR 45). The next file
+    builds other programs anyway. The most a file holds when it ends is
+    34,804 lines of ``/proc/self/maps`` (tests/test_ssm_hybrid_block.py,
+    the whole runs of PR 45, read once by a line here); 700-1,100 are
+    left after the release."""
+    yield
+    jax.clear_caches()
+
+# -- the width rule of the paged gather reads (ISSUE 38) ----------------------
 # Its tests hold a step that reads by the rule against the same step at
 # full width and against one that reads a profile too narrow (the planted
 # fault): both stand-ins for ``ops.decode.view_profile_index`` live here,
@@ -158,15 +194,3 @@ def reads_at(monkeypatch):
             m.setattr(decode_ops, "view_profile_index", stand_in(which))
             yield
     return reads
-
-
-@pytest.fixture
-def release_programs():
-    """Drop the compiled programs a test leaves in jax's caches: a step or
-    a fused loop of sixteen slots with a branch a width profile holds
-    thousands of memory mappings, and a worker that keeps every one of
-    them nears ``vm.max_map_count`` (65530) and dies in a LATER test's
-    compile (seen in the whole suite, PR 38: a segmentation fault in
-    ``tests/test_quant.py``, which passes alone)."""
-    yield
-    jax.clear_caches()

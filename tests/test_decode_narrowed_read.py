@@ -4,10 +4,11 @@ width its step's profile gives it). The rule as pure functions, where
 each described cell's switch stands, the classic pool's read against its
 oracle and against the full-width read, the planted fault, the engine's
 counters. A file of its own beside tests/test_paged_attention.py, whose
-pools, requests and reference it shares: the sixteen-slot programs are
-the slowest of the suite, and the suite's workers take a file each, in
-the order of the files' names: this one starts early."""
+pools, requests and reference it shares (tests/paged_pool.py): the
+sixteen-slot programs are the slowest of the suite, and the suite's
+workers take a file each."""
 
+import contextlib
 
 import jax
 import jax.numpy as jnp
@@ -20,8 +21,8 @@ from dalle_pytorch_tpu.ops import decode as decode_ops
 from dalle_pytorch_tpu.serve import RequestQueue
 from dalle_pytorch_tpu.serve import kv_pool as KV
 from dalle_pytorch_tpu.serve.engine import Engine
-from test_paged_attention import (CFG, REQS, VCFG, _random_pool,  # noqa: F401
-                                  bundle, reference_tokens)
+from paged_pool import random_pool
+from tiny_model import bundle, CFG, reference_tokens, REQS, VCFG  # noqa: F401
 
 
 class TestNarrowedRead:
@@ -37,9 +38,9 @@ class TestNarrowedRead:
     test_afmoe_block.py, test_ssm_hybrid_block.py)."""
 
     @pytest.fixture(autouse=True)
-    def _release(self, release_programs, four_slots_a_group):
-        """(conftest.py: the sixteen-slot programs do not outlive a test,
-        and their slots read in groups of four)"""
+    def _groups(self, four_slots_a_group):
+        """(conftest.py: the sixteen slots read in groups of four; the
+        programs end with the file, ``programs_end_with_their_file``)"""
 
     # the tables of the cells (ruDALL-E, 12b, the latent and phi's full
     # pool, trinity's full pool) and some small ones
@@ -219,7 +220,7 @@ class TestNarrowedRead:
             np.sort(pos), 4, self.COLS, ps, xp=np)) == at
         dtype = jnp.float32 if kind != "bf16" else jnp.bfloat16
         key = jax.random.PRNGKey(38)
-        pool = _random_pool(key, ps, slots * self.COLS + 1, kind == "int8",
+        pool = random_pool(key, ps, slots * self.COLS + 1, kind == "int8",
                             dim_head=self.DH, dtype=dtype, heads=self.HEADS)
         assert decode_ops.pool_view_groups(pool, slots, self.COLS) == 4
         bt = np.arange(1, slots * self.COLS + 1, dtype=np.int32).reshape(
@@ -235,28 +236,69 @@ class TestNarrowedRead:
     WIDE = D.DALLEConfig(dim=16, depth=2, vae=VCFG, num_text_tokens=50,
                          text_seq_len=8, heads=HEADS, dim_head=DH)
 
-    def _step(self, pool, bt, pos, x, key_mask, params, mesh=False):
-        """The step math over the pool through the tables: by the rule."""
-        return decode_ops._decode_step_math(
-            params, x, pos, pool, cfg=self._tcfg(), key_mask=key_mask,
-            block_tables=bt, out_sync=(lambda out: out) if mesh else None)
+    # (kind, per-head form, how it reads) -> (the jitted step, the shapes of
+    # the tables that its trace handed ``_paged_gather_read``): the
+    # positions are values, so the cases of one key differ in no shape
+    # and share a program; it is traced inside the context it is meant
+    # for (``reads_at``), which is part of the key
+    _TRACED: dict = {}
+
+    def _step(self, kind, case, params, mesh=False, reads_at=None,
+              reads="by_rule"):
+        """The step math over the pool through the tables, reading by the
+        rule or as ``reads_at(reads)`` has it -> (its outputs, the table
+        shapes its trace read: a group a read, a branch a profile)."""
+        pool, bt, pos, x, key_mask, _ = case
+        key = (kind, mesh, reads)
+        with reads_at(reads) if reads != "by_rule" \
+                else contextlib.nullcontext():
+            if key not in self._TRACED:
+                shapes, real = [], decode_ops._paged_gather_read
+
+                def spy(pool_, layer, tables, *a, **kw):
+                    shapes.append(tables.shape)
+                    return real(pool_, layer, tables, *a, **kw)
+
+                def step(params, x, pos, pool, key_mask, bt):
+                    return decode_ops._decode_step_math(
+                        params, x, pos, pool, cfg=self._tcfg(),
+                        key_mask=key_mask, block_tables=bt,
+                        out_sync=(lambda out: out) if mesh else None)
+                self._TRACED[key] = (jax.jit(step), shapes)
+                with pytest.MonkeyPatch.context() as m:
+                    m.setattr(decode_ops, "_paged_gather_read", spy)
+                    self._TRACED[key][0](params, x, pos, pool, key_mask, bt)
+            step, shapes = self._TRACED[key]
+            return step(params, x, pos, pool, key_mask, bt), shapes
 
     def _tcfg(self):
         return self.WIDE.transformer
 
-    def _params(self, dtype):
-        params = D.dalle_init(jax.random.PRNGKey(0), self.WIDE,
-                              V.vae_init(jax.random.PRNGKey(1), VCFG))
-        return jax.tree.map(lambda a: a.astype(dtype) if a.dtype
-                            == jnp.float32 else a, params["transformer"])
+    _PARAMS: dict = {}
 
-    def _oracle(self, pool, bt, pos, x, key_mask, params):
+    def _params(self, dtype):
+        if dtype not in self._PARAMS:
+            params = D.dalle_init(jax.random.PRNGKey(0), self.WIDE,
+                                  V.vae_init(jax.random.PRNGKey(1), VCFG))
+            self._PARAMS[dtype] = jax.tree.map(
+                lambda a: a.astype(dtype) if a.dtype == jnp.float32 else a,
+                params["transformer"])
+        return self._PARAMS[dtype]
+
+    def _oracle(self, kind, case, params):
         """The same step over the ``paged_view`` of the same pool: the
         dense step's one einsum softmax (``_gather_read``)."""
-        view = decode_ops.paged_view(pool, bt, key_mask.shape[1],
-                                     self.HEADS)
-        return decode_ops._decode_step_math(
-            params, x, pos, view, cfg=self._tcfg(), key_mask=key_mask)
+        pool, bt, pos, x, key_mask, _ = case
+        if (kind, "oracle") not in self._TRACED:
+            def oracle(params, x, pos, pool, key_mask, bt):
+                view = decode_ops.paged_view(pool, bt, key_mask.shape[1],
+                                             self.HEADS)
+                return decode_ops._decode_step_math(
+                    params, x, pos, view, cfg=self._tcfg(),
+                    key_mask=key_mask)
+            self._TRACED[kind, "oracle"] = jax.jit(oracle)
+        return self._TRACED[kind, "oracle"](params, x, pos, pool, key_mask,
+                                            bt)
 
     TOL = {"bf16": dict(rtol=5e-2, atol=5e-2),
            "f32": dict(rtol=1e-4, atol=1e-4),
@@ -275,7 +317,7 @@ class TestNarrowedRead:
     @pytest.mark.parametrize("at", [0, 1, 2, 3])
     @pytest.mark.parametrize("kind", ["f32", "bf16", "int8"])
     def test_narrowed_step_matches_the_oracle_and_the_full_width_step(
-            self, monkeypatch, profile_positions, reads_at, kind, at, mesh):
+            self, profile_positions, reads_at, kind, at, mesh):
         """Slots in shuffled phase order that need each profile in turn
         (in every group one AT its width's edge, one a row before it, one
         a row after the edge of the group before; a parked slot, one at
@@ -285,26 +327,17 @@ class TestNarrowedRead:
         and in the mesh's per-head form; every profile is traced, a
         branch each, and a group reads its profile's width; the same step
         at full width agrees."""
-        pool, bt, pos, x, key_mask, ps = self._profile_case(
-            kind, at, profile_positions)
-        params = self._params(x.dtype)
+        case = self._profile_case(kind, at, profile_positions)
+        params = self._params(case[3].dtype)
         profiles = decode_ops.view_profiles(4, self.COLS)
         assert len(profiles) == 4
-        widths_read = []
-        real = decode_ops._paged_gather_read
-
-        def spy(pool_, layer, tables, *a, **kw):
-            widths_read.append(tables.shape)
-            return real(pool_, layer, tables, *a, **kw)
-        monkeypatch.setattr(decode_ops, "_paged_gather_read", spy)
-        got = self._step(pool, bt, pos, x, key_mask, params, mesh)
+        got, widths_read = self._step(kind, case, params, mesh)
         # every profile traced once (the layer scan's body), a group a read
         assert widths_read == [(4, w) for widths in profiles
                                for w in widths]
-        want = self._oracle(pool, bt, pos, x, key_mask, params)
-        self._assert_step_close(got, want, kind)
-        with reads_at("full_width"):
-            whole = self._step(pool, bt, pos, x, key_mask, params, mesh)
+        self._assert_step_close(got, self._oracle(kind, case, params), kind)
+        whole, _ = self._step(kind, case, params, mesh, reads_at,
+                              "full_width")
         self._assert_step_close(got, whole, kind)
 
     @pytest.mark.parametrize("at", [1, 2, 3])
@@ -315,14 +348,13 @@ class TestNarrowedRead:
         need drops rows that are written, and the parity case above
         fails on it (a test that passes with a planted fault is the
         finding: PR 35)."""
-        pool, bt, pos, x, key_mask, ps = self._profile_case(
-            kind, at, profile_positions)
-        params = self._params(x.dtype)
-        with reads_at("too_narrow"):
-            got = self._step(pool, bt, pos, x, key_mask, params)
-        want = self._oracle(pool, bt, pos, x, key_mask, params)
+        case = self._profile_case(kind, at, profile_positions)
+        params = self._params(case[3].dtype)
+        got, _ = self._step(kind, case, params, reads_at=reads_at,
+                            reads="too_narrow")
         with pytest.raises(AssertionError):
-            self._assert_step_close(got, want, kind)
+            self._assert_step_close(got, self._oracle(kind, case, params),
+                                    kind)
 
     def test_plain_tables_read_in_slot_order_at_full_width(
             self, profile_positions):
@@ -374,7 +406,7 @@ class TestNarrowedRead:
         pos = np.maximum(profile_positions(
             profiles[min(at, len(profiles) - 1)], page_size, L) - 5, 0)
         slots = len(pos)
-        pool = _random_pool(jax.random.PRNGKey(21), page_size,
+        pool = random_pool(jax.random.PRNGKey(21), page_size,
                             slots * mp + 1, quantized,
                             dim_head=tcfg.dim_head)
         assert decode_ops.pool_view_groups(pool, slots, mp) == 4

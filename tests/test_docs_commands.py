@@ -150,3 +150,21 @@ def test_every_command_a_document_names_exists(path):
     missing = [f"{kind} {name}" for kind, name in found
                if not exists(kind, name, targets)]
     assert not missing, f"{path} names what is not in the tree: {missing}"
+
+
+def test_the_documents_name_the_same_tier_1_run():
+    """What follows ROADMAP.md's "Tier-1 verify" line and the verify skill
+    both say how the driver runs the suite: the same worker count and the
+    same ``--dist`` mode (a string comparison; the run itself is
+    ``/root/TESTS_LAST_RUN.json``'s)."""
+    told = {}
+    for path in ("ROADMAP.md", ".claude/skills/verify/SKILL.md"):
+        with open(os.path.join(REPO, path)) as f:
+            text = f.read()
+        if path == "ROADMAP.md":
+            text = text[text.index("**Tier-1 verify:**"):]
+        run = re.search(r"-p xdist -n (\d+) --dist (\w+)",
+                        re.sub(r"\s+", " ", text))
+        assert run, f"{path} names no worker count and --dist mode"
+        told[path] = run.groups()
+    assert len(set(told.values())) == 1, told

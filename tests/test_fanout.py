@@ -23,36 +23,16 @@ import numpy as np
 import pytest
 
 from dalle_pytorch_tpu.models import dalle as D
-from dalle_pytorch_tpu.models import vae as V
 from dalle_pytorch_tpu.resilience import faults
 from dalle_pytorch_tpu.serve import (OK, QueueFull, Request,
                                      RequestQueue, pages_for)
 from dalle_pytorch_tpu.serve import scheduler as S
 from dalle_pytorch_tpu.serve.engine import Engine
+from tiny_model import (CFG, VCFG, _no_leaked_plan,  # noqa: F401
+                        bundle)
 from dalle_pytorch_tpu.serve.fanout import (group_pages_saved,
                                             rank_samples, sample_seed,
                                             submit_group)
-
-VCFG = V.VAEConfig(image_size=16, num_tokens=32, codebook_dim=16,
-                   num_layers=2, hidden_dim=8)
-CFG = D.DALLEConfig(dim=16, depth=2, vae=VCFG, num_text_tokens=50,
-                    text_seq_len=8, heads=2, dim_head=8)
-
-
-@pytest.fixture(scope="module")
-def bundle():
-    key = jax.random.PRNGKey(0)
-    vae_params = V.vae_init(jax.random.fold_in(key, 1), VCFG)
-    params = D.dalle_init(key, CFG, vae_params)
-    return params, vae_params
-
-
-@pytest.fixture(autouse=True)
-def _no_leaked_plan():
-    faults.deactivate()
-    yield
-    faults.deactivate()
-
 
 _REF_CACHE: dict = {}
 

@@ -1,12 +1,14 @@
 """The short-convolution hybrid block (``ops.transformer.ShortConvGQABlock``)
-at toy widths, float32, seeded: the program against the benchmark family's
-plain reference (``benchmark/families/lfm2_moe/reference.py``) at logit
-level; the two forms of the gated short convolution as one identity; the
-tail a slot beside the one page pool (ONE buffer of two rows, written at
-each row's own prompt length, never advanced for an inactive slot,
-overwritten when a slot is reused, rebuilt by the replay after an
-eviction); routed layers behind layers that cache no row; and every option
-that cannot run the block refusing it by the one typed error.
+at toy widths, float32, seeded: the contract of every described block
+(``block_contract.py``: the program against the benchmark family's plain
+reference, ``benchmark/families/lfm2_moe/reference.py``, at logit level;
+the paged decode from prompts of 1 and 2 tokens; the engine, a reused slot
+and an evicted request's replay; every refusal), then its own: the two
+forms of the gated short convolution as one identity; the tail a slot
+beside the one page pool (ONE buffer of two rows, written at each row's
+own prompt length, never advanced for an inactive slot, overwritten when a
+slot is reused, rebuilt by the replay after an
+eviction); routed layers behind layers that cache no row.
 
 Tolerances: the program and the reference compute the same float32
 mathematics in another order (grouped products, a cached read in page
@@ -24,6 +26,8 @@ import numpy as np
 import pytest
 
 from benchmark import harness, seeds
+from block_contract import (GREEDY, BlockContract, Toy, params,  # noqa: F401
+                            ref_logits, sequences, served)
 from dalle_pytorch_tpu.models import dalle as D
 from dalle_pytorch_tpu.ops import core
 from dalle_pytorch_tpu.ops import decode as decode_ops
@@ -32,72 +36,64 @@ from dalle_pytorch_tpu.ops import shortconv as conv_ops
 from dalle_pytorch_tpu.ops import transformer as T
 from dalle_pytorch_tpu.serve import kv_pool as KV
 from dalle_pytorch_tpu.serve.engine import Engine
-from dalle_pytorch_tpu.serve.scheduler import (Request, RequestQueue,
-                                               SamplingParams)
+from dalle_pytorch_tpu.serve.scheduler import Request, RequestQueue
 
-FAMILY = harness.load_family("lfm2_moe")
-R = FAMILY.reference
-SEED = 2 ** 31 + 13
-PS = 4                      # page size: the text window is not a multiple
-PUBLISHED = harness.load_json(
-    harness.ROOT + "/benchmark/configs/lfm2-24b-a2b.json")
-CONF = dict(PUBLISHED, **FAMILY.tiny)
-CONF.update(text_seq_len=10, image_grid=5)
-DEPTH = 9
-ATOL = 2e-5
-
-
-def _dims(depth=DEPTH, **kw):
-    return FAMILY.weights.dims_of(dict(CONF, **kw), depth)
-
-
-DIMS = _dims()
-CFG = FAMILY.build.program_config(DIMS, {})
-TCFG = CFG.transformer
-BLK = TCFG.block
-WIDTH = KV.pages_for(DIMS.seq_len, PS)
+# prompts of 1, 10, 2 and 7 tokens: the tail of a prompt shorter than the
+# taps; with two slots the third and fourth requests reuse one
+TOY = Toy("lfm2_moe", "lfm2-24b-a2b", 9, "shortconv_gqa_moe",
+          overrides=dict(text_seq_len=10, image_grid=5), gap=1e-4,
+          t0s=(1, 2, 7), bf16_misses=100, evicted=(1, 3), reused=True,
+          requests=(Request(codes=(3,), seed=11, sampling=GREEDY),
+                    Request(codes=tuple(range(1, 11)), seed=2,
+                            sampling=GREEDY),
+                    Request(codes=(6, 6), seed=3, sampling=GREEDY),
+                    Request(codes=(6, 6, 1, 2, 3, 9, 4), seed=5,
+                            sampling=GREEDY)))
+FAMILY, PUBLISHED, DIMS, CFG, TCFG, BLK = (TOY.family, TOY.published,
+                                           TOY.dims, TOY.cfg, TOY.tcfg,
+                                           TOY.blk)
+R, SEED, PS, DEPTH, ATOL, WIDTH, REQS = (FAMILY.reference, TOY.seed,
+                                         TOY.page_size, TOY.depth, TOY.atol,
+                                         TOY.width, TOY.requests)
 FULL_LAYERS = [i for i, t in enumerate(DIMS.layer_types) if t == "full"]
 CONV_LAYERS = [i for i, t in enumerate(DIMS.layer_types) if t == "conv"]
 
 
-def _tree(dims, dtype=jnp.float32):
-    return jax.jit(lambda h: FAMILY.weights.tree(h, dims, dtype))(
-        seeds.split_seed(SEED))
+class TestContract(BlockContract):
+    toy = TOY
+
+    def step_loads(self, loads, b, t0):
+        """A step that carries a state a slot AND returns a routed load:
+        the four counts of a block that holds every expert."""
+        for load in loads:
+            assert load.shape == (4,) and load.dtype == jnp.int32
+            assert int(load[0]) \
+                == b * BLK.experts_per_token * DIMS.moe_layers
+            assert 0 < int(load[1]) <= DIMS.moe_layers * DIMS.experts
+
+    def engine_counters(self, engine, st, placement):
+        """Prompts of 1 and 10 tokens admitted in one bucket (each row's
+        tail at its own length), slots reused by the third and fourth
+        requests."""
+        assert {n: a.shape for n, a in engine.cache.items()} == {
+            "k": (2, 2 * WIDTH + 1, PS, 16), "v": (2, 2 * WIDTH + 1, PS, 16),
+            "conv_tail": (7, 2, 2, 32)}
+        # the state pool's bytes under the buffer's own name
+        assert st["conv_tail_bytes"] == st["state_bytes"] \
+            == 7 * 2 * 2 * 32 * 4
+        assert "window_pages_in_use" not in st \
+            and "window_sink_mass" not in st
+        assert 0 < st["moe_experts_touched"] <= st["decode_steps"] \
+            * DIMS.moe_layers * DIMS.experts
+        assert "moe_picks_held" not in st and "moe_rows_computed" not in st
+        # a step's pair rows are one row tile here: nothing read twice
+        assert st["moe_group_reads"] == st["moe_experts_touched"]
+        # what a step reads: two full layers' tables, seven layers' tails
+        assert st["kv_read_bytes_per_token"] == (
+            2 * WIDTH * 2 * PS * 16 + 7 * 2 * 32) * 4
 
 
-@pytest.fixture(scope="module")
-def params():
-    return _tree(DIMS)
-
-
-@pytest.fixture(scope="module")
-def sequences():
-    rng = np.random.default_rng(3)
-    return np.concatenate(
-        [rng.integers(1, DIMS.num_text_tokens, (2, DIMS.text_seq_len)),
-         rng.integers(0, DIMS.num_image_tokens, (2, DIMS.image_seq_len))], 1)
-
-
-@pytest.fixture(scope="module")
-def ref_logits(sequences):
-    return np.asarray(R.served_logits(SEED, DIMS, jnp.float32,
-                                      sequences.tolist()))
-
-
-def _close(got, want, atol=ATOL):
-    fin = np.isfinite(want)
-    assert (np.asarray(got)[~fin] < -1e30).all()      # forbidden either way
-    np.testing.assert_allclose(np.asarray(got)[fin], want[fin], atol=atol,
-                               rtol=0)
-
-
-def _apply(params, sequences, cfg=CFG):
-    t = DIMS.text_seq_len
-    return D.dalle_apply(params, jnp.asarray(sequences[:, :t]),
-                         jnp.asarray(sequences[:, t:-1]), cfg=cfg)
-
-
-# -- (i) the stack as it is scanned ---------------------------------------------
+# -- (i) the stack as it is scanned -------------------------------------------
 
 def test_the_toy_is_the_published_layers_1_to_9_at_period_1():
     assert DIMS.layer_types == ("conv", "full", "conv", "conv", "conv",
@@ -151,7 +147,7 @@ def test_all_40_published_layers_in_the_published_order():
         == (2048, 32, 8, 64, 3, 11776, 1536, 64, 4, 65536)
 
 
-# -- (ii) the mixer: two forms, one identity -----------------------------------
+# -- (ii) the mixer: two forms, one identity ----------------------------------
 
 def _conv_layer(i=0):
     key = seeds.layer_key(seeds.seed_key(SEED), DIMS.first_layer + i)
@@ -206,22 +202,7 @@ def test_the_tail_is_the_last_two_gated_inputs():
                ) == {"in", "conv", "out"}
 
 
-# -- (iii) the full forward against the reference -------------------------------
-
-def test_dalle_apply_matches_the_reference_logits(params, sequences,
-                                                  ref_logits):
-    _close(_apply(params, sequences), ref_logits)
-
-
-def test_bfloat16_fails_the_tolerance(sequences, ref_logits):
-    """The same program with its weights and its arithmetic in bfloat16
-    misses the float32 tolerance by three orders of magnitude: the
-    comparison is tight enough to tell the precisions apart."""
-    got = np.asarray(_apply(_tree(DIMS, jnp.bfloat16), sequences),
-                     np.float32)
-    fin = np.isfinite(ref_logits)
-    assert np.abs(got[fin] - ref_logits[fin]).max() > 100 * ATOL
-
+# -- (iii) each mechanism in the logits --------------------------------------
 
 class _NoRope(T.ShortConvGQABlock):
     def rope_theta_of(self, full):
@@ -246,12 +227,11 @@ def test_each_mechanism_is_in_the_logits(sequences, without, monkeypatch):
     a key head, the rotary positions, or the router's selection bias,
     fails the tolerance. (The selection bias at 0.5, not the published
     cell's 0.001: it moves a selection, and few at that scale.)"""
-    dims = _dims(router_bias_std=0.5) if without == "selection_bias" \
+    dims = TOY.dims_of(router_bias_std=0.5) if without == "selection_bias" \
         else DIMS
-    params = _tree(dims)
-    want = np.asarray(R.served_logits(SEED, dims, jnp.float32,
-                                      sequences.tolist()))
-    _close(_apply(params, sequences), want)
+    params = TOY.tree(dims)
+    want = TOY.ref_logits(sequences, dims)
+    TOY.close(TOY.apply(params, sequences), want)
     cfg, p = CFG, params
     convs = [("dense", "attn"), ("moe", "attn")]
     if without == "tail":
@@ -277,7 +257,7 @@ def test_each_mechanism_is_in_the_logits(sequences, without, monkeypatch):
             return dict(ff, router=dict(
                 ff["router"], bias=jnp.zeros_like(ff["router"]["bias"])))
         p = _without(params, [("moe", "ff"), ("moe_full", "ff")], unbiased)
-    got = np.asarray(_apply(p, sequences, cfg))
+    got = np.asarray(TOY.apply(p, sequences, cfg))
     fin = np.isfinite(want)
     assert np.abs(got[fin] - want[fin]).max() > 50 * ATOL
 
@@ -313,7 +293,7 @@ def test_a_routed_layer_is_the_uncut_reference_s_layer():
     np.testing.assert_allclose(np.asarray(plain).sum(-1), 1.0, rtol=1e-6)
 
 
-# -- (iv) the pools: pages of rows, and ONE buffer that is not pages -----------
+# -- (iv) the pools: pages of rows, and ONE buffer that is not pages ----------
 
 def test_the_state_pool_is_one_buffer_that_the_block_names():
     layout = KV.page_layout(TCFG, PS)
@@ -394,40 +374,6 @@ def test_every_other_configuration_s_layout_is_what_it_was(family, config,
                    ) == 9 * slots * (16 * 5120 * 4 + 3 * 5120 * 2)
 
 
-def _tables(b):
-    return {"full": 1 + jnp.arange(b * WIDTH, dtype=jnp.int32).reshape(
-        b, WIDTH)}
-
-
-def _prefilled_pool(params, sequences, t0, upto=None):
-    """The prompt's rows [0, t0) of the sequences in the page pool (page 0
-    is the trash page) and each slot's tail after its prompt; with
-    ``upto`` (b,), slot i's prompt is its first ``upto[i]`` tokens alone
-    (padded on the right to t0)."""
-    b = sequences.shape[0]
-    tables = _tables(b)
-    pool = dict(KV.init_page_pool(TCFG, 1 + b * WIDTH, PS, num_slots=b))
-    t = min(t0, DIMS.text_seq_len)
-    x = D.embed_prompt(params, CFG, jnp.asarray(sequences[:, :t]),
-                       jnp.asarray(sequences[:, t:t0]))
-    h, cache = decode_ops.prefill(
-        params["transformer"], x, cfg=TCFG, total_len=DIMS.seq_len,
-        lens=None if upto is None else jnp.asarray(upto))
-    assert cache["k"].shape == cache["v"].shape \
-        == (len(FULL_LAYERS), b, t0, 2, 8)
-    assert cache["conv_tail"].shape == (len(CONV_LAYERS), b, 2, DIMS.dim)
-    for name in ("k", "v"):
-        buf, rows = np.array(pool[name]), np.asarray(cache[name])
-        table = np.asarray(tables["full"])
-        for i in range(b):
-            for j in range(t0 if upto is None else upto[i]):
-                buf[:, table[i, j // PS], j % PS] = rows[:, i, j].reshape(
-                    rows.shape[0], -1)
-        pool[name] = jnp.asarray(buf)
-    pool["conv_tail"] = cache["conv_tail"]
-    return h, pool, tables
-
-
 def test_a_rows_tail_after_a_padded_prefill_is_its_own_prompts(params,
                                                                sequences):
     """Four rows of four prompt lengths (1 and 2 tokens among them) padded
@@ -435,10 +381,10 @@ def test_a_rows_tail_after_a_padded_prefill_is_its_own_prompts(params,
     and a prefill that is not told the lengths carries the padding's."""
     seqs = np.concatenate([sequences, sequences[::-1]])
     lens = np.asarray([1, 2, 5, 8])
-    _, padded, _ = _prefilled_pool(params, seqs, 8, lens)
-    _, blind, _ = _prefilled_pool(params, seqs, 8)
+    _, padded, _ = TOY.prefilled_pool(params, seqs, 8, lens)
+    _, blind, _ = TOY.prefilled_pool(params, seqs, 8)
     for i, n in enumerate(lens):
-        _, alone, _ = _prefilled_pool(params, seqs[i:i + 1], int(n))
+        _, alone, _ = TOY.prefilled_pool(params, seqs[i:i + 1], int(n))
         np.testing.assert_allclose(
             np.asarray(padded["conv_tail"][:, i]),
             np.asarray(alone["conv_tail"][:, 0]), atol=1e-6)
@@ -447,42 +393,8 @@ def test_a_rows_tail_after_a_padded_prefill_is_its_own_prompts(params,
                   - np.asarray(padded["conv_tail"][:, 0])).max() > 1e-3
 
 
-@pytest.mark.parametrize("t0", [1, 2, 7])       # prompts of 1 and 2 tokens
-def test_prefill_then_paged_decode_matches_the_full_forward(
-        params, sequences, ref_logits, t0):
-    h, pool, tables = _prefilled_pool(params, sequences, t0)
-    b = sequences.shape[0]
-    key_mask = jnp.ones((b, DIMS.seq_len), bool)
-    active = jnp.ones((b,), bool)
-    forbidden = np.asarray(D.logits_mask(CFG))
-    first = np.where(forbidden[t0 - 1], -np.inf,
-                     np.asarray(D.to_logits(params, h[:, -1], CFG)))
-    fin = np.isfinite(ref_logits[:, t0 - 1])
-    np.testing.assert_allclose(first[fin], ref_logits[:, t0 - 1][fin],
-                               atol=ATOL, rtol=0)     # the prefill's own row
-    step = jax.jit(lambda x, p, pool: decode_ops.decode_step_block(
-        params["transformer"], x, p, pool, tables, cfg=TCFG,
-        key_mask=key_mask, active=active))
-    step_pool = pool
-    for pos in range(t0, DIMS.seq_len - 1):
-        p = jnp.full((b,), pos, jnp.int32)
-        x = D.decode_token_embed(params, CFG, jnp.asarray(sequences[:, pos]),
-                                 p)
-        h_tok, step_pool, load = step(x, p, step_pool)
-        logits = np.where(forbidden[pos], -np.inf,
-                          np.asarray(D.to_logits(params, h_tok, CFG)))
-        fin = np.isfinite(ref_logits[:, pos])
-        np.testing.assert_allclose(logits[fin], ref_logits[:, pos][fin],
-                                   atol=ATOL, rtol=0)
-        # a step that carries a state a slot AND returns a routed load:
-        # the four counts of a block that holds every expert
-        assert load.shape == (4,) and load.dtype == jnp.int32
-        assert int(load[0]) == b * BLK.experts_per_token * DIMS.moe_layers
-        assert 0 < int(load[1]) <= DIMS.moe_layers * DIMS.experts
-
-
 def test_an_inactive_slots_tail_is_not_advanced(params, sequences):
-    _, pool, tables = _prefilled_pool(params, sequences, 7)
+    _, pool, tables = TOY.prefilled_pool(params, sequences, 7)
     p = jnp.full((2,), 7, jnp.int32)
     x = D.decode_token_embed(params, CFG, jnp.asarray(sequences[:, 7]), p)
     _, new, load = decode_ops.decode_step_block(
@@ -496,87 +408,17 @@ def test_an_inactive_slots_tail_is_not_advanced(params, sequences):
     assert np.abs(got[:, 0, 1] - old[:, 0, 1]).max() > 1e-3
 
 
-# -- (v) the engine: the tail beside the pool ----------------------------------
-
-GREEDY = SamplingParams(filter_thres=1.0)
-REQS = [Request(codes=(3,), seed=11, sampling=GREEDY),
-        Request(codes=tuple(range(1, 11)), seed=2, sampling=GREEDY),
-        Request(codes=(6, 6), seed=3, sampling=GREEDY),
-        Request(codes=(6, 6, 1, 2, 3, 9, 4), seed=5, sampling=GREEDY)]
-
-
-def _serve(params, reqs, **kw):
-    queue = RequestQueue(max_depth=16)
-    kw.setdefault("num_slots", 2)
-    engine = Engine(params, CFG, queue, chunk_steps=8, kv="paged",
-                    page_size=PS, **kw)
-    handles = [queue.submit(dataclasses.replace(r)) for r in reqs]
-    engine.run_until_idle()
-    out = []
-    for r, h in zip(reqs, handles):
-        res = h.result(timeout=5)
-        assert res.status == "ok"
-        out.append(list(np.asarray(res.text_tokens))
-                   + list(np.asarray(res.tokens)))
-        assert out[-1][:len(r.codes)] == list(r.codes)
-    return engine, out
-
-
-@pytest.fixture(scope="module")
-def alone(params):
-    """Each request's stream from an engine of its own."""
-    return [_serve(params, [r], num_slots=1)[1][0] for r in REQS]
-
-
-def test_engine_serves_the_reference_s_tokens_in_chunks_of_8(params, alone):
-    """Through the engine: prompts of 1 and 10 tokens admitted in one
-    bucket (each row's tail at its own length), the whole-page write into
-    the pool, slots reused by the third and fourth requests, the fused
-    chunks. Greedy tokens are the reference's best at every served
-    position (gap 0 but for float32 near-ties)."""
-    engine, seqs = _serve(params, REQS)
-    assert seqs == alone
-    lens = [len(r.codes) for r in REQS]
-    assert all(len(s) == DIMS.seq_len for s in seqs)
-    gaps, served = R.served_gaps(SEED, DIMS, jnp.float32, seqs, lens)
-    assert float(np.asarray(gaps)[np.asarray(served)].max()) < 1e-4
-    st = engine.stats()
-    assert engine.decode_traces == 1 and engine.window is None
-    assert engine.alloc.in_use == 0
-    assert {n: a.shape for n, a in engine.cache.items()} == {
-        "k": (2, 2 * WIDTH + 1, PS, 16), "v": (2, 2 * WIDTH + 1, PS, 16),
-        "conv_tail": (7, 2, 2, 32)}
-    # the state pool's bytes under the buffer's own name
-    assert st["conv_tail_bytes"] == st["state_bytes"] == 7 * 2 * 2 * 32 * 4
-    assert "window_pages_in_use" not in st and "window_sink_mass" not in st
-    assert st["moe_picks"] == (st["decode_steps"] * engine.num_slots
-                               * BLK.experts_per_token * DIMS.moe_layers)
-    assert 0 < st["moe_experts_touched"] <= st["decode_steps"] \
-        * DIMS.moe_layers * DIMS.experts
-    assert "moe_picks_held" not in st and "moe_rows_computed" not in st
-    # a step's pair rows are one row tile here: nothing read twice
-    assert st["moe_group_reads"] == st["moe_experts_touched"]
-    assert st["kv_hbm_bytes"] == KV.modeled_kv_bytes(
-        TCFG, kv="paged", num_slots=2, total_len=DIMS.seq_len, page_size=PS)
-    # what a step reads: two full layers' tables, seven layers' tails
-    assert st["kv_read_bytes_per_token"] == (
-        2 * WIDTH * 2 * PS * 16 + 7 * 2 * 32) * 4
-
-
-def test_a_reused_slot_does_not_read_the_last_request_s_tail(params, alone):
-    """One slot, four requests one after the other: each starts in a slot
-    whose tail its predecessor left, and serves a fresh engine's tokens."""
-    _, shared = _serve(params, REQS, num_slots=1)
-    assert shared == alone
-
+# -- (v) the engine: the tail beside the pool --------------------------------
 
 def test_a_four_row_group_writes_each_row_s_tail_at_its_own_length(params,
-                                                                   alone):
+                                                                   served):
     """Six slots, so an admission takes 4 rows or 6
     (``scheduler.prefill_groups``): four requests of four prompt lengths
     (1, 10, 2 and 7) start in ONE 4-row group of one bucket, then a fifth
     joins mid-image (its group's unused rows are dropped, not written over
-    a running slot's tail). Every stream is the one a fresh engine gives."""
+    a running slot's tail). Every stream is the one that one slot gives the
+    request."""
+    alone = served.one_slot().seqs
     queue = RequestQueue(max_depth=16)
     bucket = CFG.text_seq_len
     engine = Engine(params, CFG, queue, chunk_steps=8, kv="paged",
@@ -594,81 +436,15 @@ def test_a_four_row_group_writes_each_row_s_tail_at_its_own_length(params,
             + list(np.asarray(res.tokens)) == want
 
 
-def test_an_evicted_request_replays_to_the_same_tokens(params, alone):
-    reqs = REQS[1::2]
-    tight, got = _serve(params, reqs, num_pages=WIDTH + 4)
-    assert got == alone[1::2] and tight.evicted > 0
-    assert tight.alloc.in_use == 0
-
-
-# -- (vi) every path that cannot run the block refuses it ----------------------
-
-def _engine(params, **kw):
-    kw.setdefault("kv", "paged")
-    return Engine(params, CFG, RequestQueue(max_depth=2), num_slots=1, **kw)
-
-
-def _mesh_engine(params):
-    from dalle_pytorch_tpu.serve.mesh_engine import MeshEngine
-    return MeshEngine(params, CFG, RequestQueue(max_depth=2),
-                      devices=jax.devices()[:2], num_slots=1, kv="paged")
-
-
-REFUSED = {
-    "kv_dense": lambda p: _engine(p, kv="dense"),
-    "paged_attn_kernel": lambda p: _engine(p, paged_attn="kernel",
-                                           page_size=8),
-    "speculative": lambda p: _engine(p, speculative=2),
-    "sparse_reads": lambda p: _engine(p, sparse_reads=True),
-    "quantize_cache": lambda p: _engine(p, quantize_cache=True),
-    "prefix_cache": lambda p: _engine(p, prefix_cache=True),
-    "mesh_engine": _mesh_engine,
-    "quantize_int8": lambda p: D.quantize_for_decode(p),
-    "generate_images": lambda p: D.generate_images(
-        p, None, jnp.ones((1, 4), jnp.int32), cfg=CFG,
-        rng=jax.random.PRNGKey(0)),
-    "train": lambda p: D.dalle_apply(
-        p, jnp.ones((1, 10), jnp.int32), jnp.ones((1, 25), jnp.int32),
-        cfg=CFG, train=True, return_loss=True),
-    "reversible": lambda p: dataclasses.replace(CFG, reversible=True)
-    .transformer,
-    "attn_impl_flash": lambda p: dataclasses.replace(CFG, attn_impl="flash")
-    .transformer,
-    "dense_cache": lambda p: decode_ops.init_cache(TCFG, 1, 8),
-    "kernel_loop": lambda p: decode_ops.decode_loop_paged(
-        p["transformer"], None, None, None, {}, None, cfg=TCFG,
-        key_mask=None, total_len=8, steps=1, embed_fn=None, sample_fn=None,
-        attn_impl="kernel"),
-    "int8_pool": lambda p: KV.init_page_pool(TCFG, 4, PS, quantized=True,
-                                             num_slots=1),
-    "export_slot": lambda p: _engine(p).export_slot(0),
-}
-
-
-@pytest.mark.parametrize("option", sorted(REFUSED))
-def test_every_refused_option_raises_the_one_typed_error(params, option):
-    from dalle_pytorch_tpu.serve.engine import MigrationError
-    if option == "export_slot":
-        # a snapshot of a slot's state: refused, with the typed error as
-        # its cause (the caller falls back to replay)
-        with pytest.raises(MigrationError) as e:
-            REFUSED[option](params)
-        assert BLK.name in str(e.value)
-        return
-    with pytest.raises(T.BlockOptionError) as e:
-        REFUSED[option](params)
-    assert BLK.name == "shortconv_gqa_moe" and e.value.block == BLK.name
-    assert BLK.name in str(e.value) and e.value.option in str(e.value)
-    assert T.block_name_of(params["transformer"]) == BLK.name
-
+# -- (vi) what the equations do not hold for ---------------------------------
 
 def test_a_configuration_the_equations_do_not_hold_for_is_refused():
     with pytest.raises(ValueError, match="conv_bias"):
-        _dims(conv_bias=True)
+        TOY.dims_of(conv_bias=True)
     with pytest.raises(ValueError, match="every row is held"):
-        _dims(vocab_size=80)
+        TOY.dims_of(vocab_size=80)
     with pytest.raises(ValueError, match="layer_types holds"):
-        _dims(layer_types=["conv", "sliding"] * 20)
+        TOY.dims_of(layer_types=["conv", "sliding"] * 20)
     with pytest.raises(ValueError, match="'conv' or 'full'"):
         dataclasses.replace(BLK, layer_types=("conv", "sliding"))
     with pytest.raises(ValueError, match="leaves no tail"):

@@ -34,50 +34,16 @@ import pytest
 
 from dalle_pytorch_tpu.analysis import guards
 from dalle_pytorch_tpu.models import dalle as D
-from dalle_pytorch_tpu.models import vae as V
 from dalle_pytorch_tpu.parallel import serve_specs as SS
 from dalle_pytorch_tpu.resilience import faults
-from dalle_pytorch_tpu.resilience.retry import RetryPolicy
-from dalle_pytorch_tpu.serve import (OK, Request, RequestQueue,
-                                     SamplingParams)
+from dalle_pytorch_tpu.serve import OK, Request, RequestQueue
 from dalle_pytorch_tpu.serve.engine import Engine
+from tiny_model import (CFG, FAST_BRINGUP, REQS, _no_leaked_plan,  # noqa: F401
+                        bundle)
 from dalle_pytorch_tpu.serve.mesh_engine import (MeshEngine,
                                                  MeshPagedAttnError,
                                                  hbm_report)
 from dalle_pytorch_tpu.serve.replica import ReplicaSet
-
-VCFG = V.VAEConfig(image_size=16, num_tokens=32, codebook_dim=16,
-                   num_layers=2, hidden_dim=8)
-CFG = D.DALLEConfig(dim=16, depth=2, vae=VCFG, num_text_tokens=50,
-                    text_seq_len=8, heads=2, dim_head=8)
-
-FAST_BRINGUP = RetryPolicy(max_attempts=1, deadline_s=None,
-                           base_backoff_s=0.01, backoff_multiplier=2.0,
-                           max_backoff_s=0.1, jitter=0.0)
-
-REQS = [
-    Request(codes=(3, 7, 9), seed=11),
-    Request(codes=(5, 2, 8, 1, 4), seed=23,
-            sampling=SamplingParams(temperature=0.7, filter_thres=0.8)),
-    Request(codes=(6, 6), seed=5,
-            sampling=SamplingParams(temperature=1.3, top_p=0.9)),
-]
-
-
-@pytest.fixture(scope="module")
-def bundle():
-    key = jax.random.PRNGKey(0)
-    vae_params = V.vae_init(jax.random.fold_in(key, 1), VCFG)
-    params = D.dalle_init(key, CFG, vae_params)
-    return params, vae_params
-
-
-@pytest.fixture(autouse=True)
-def _no_leaked_plan():
-    faults.deactivate()
-    yield
-    faults.deactivate()
-
 
 def mesh_devices(n=2):
     devs = jax.devices()

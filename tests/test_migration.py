@@ -30,22 +30,24 @@ double-buffered pipeline; the acceptance drive uses total_len 408).
 """
 
 import copy
+import functools
 import time
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from dalle_pytorch_tpu.models import dalle as D
 from dalle_pytorch_tpu.models import vae as V
 from dalle_pytorch_tpu.resilience import faults
-from dalle_pytorch_tpu.resilience.retry import RetryPolicy
-from dalle_pytorch_tpu.serve import (OK, Request, RequestQueue,
-                                     SamplingParams)
+from dalle_pytorch_tpu.serve import OK, Request, RequestQueue
 from dalle_pytorch_tpu.serve.engine import Engine, MigrationError
 from dalle_pytorch_tpu.serve.replica import (DRAINED, RUNNING,
                                              ReplicaSet, ScaleError)
+import tiny_model
+from tiny_model import (FAST_BRINGUP, _no_leaked_plan,  # noqa: F401
+                        bundle)
+from tiny_model import MORE_REQS as REQS
 
 # 64 image tokens (total_len 72): wide enough that an export observed
 # at >= 8 emitted tokens can never race the fused pipeline's in-flight
@@ -54,63 +56,7 @@ VCFG = V.VAEConfig(image_size=32, num_tokens=32, codebook_dim=16,
                    num_layers=2, hidden_dim=8)
 CFG = D.DALLEConfig(dim=16, depth=2, vae=VCFG, num_text_tokens=50,
                     text_seq_len=8, heads=2, dim_head=8)
-
-FAST_BRINGUP = RetryPolicy(max_attempts=1, deadline_s=None,
-                           base_backoff_s=0.01, backoff_multiplier=2.0,
-                           max_backoff_s=0.1, jitter=0.0)
-
-
-@pytest.fixture(scope="module")
-def bundle():
-    key = jax.random.PRNGKey(0)
-    vae_params = V.vae_init(jax.random.fold_in(key, 1), VCFG)
-    params = D.dalle_init(key, CFG, vae_params)
-    return params, vae_params
-
-
-@pytest.fixture(autouse=True)
-def _no_leaked_plan():
-    faults.deactivate()
-    yield
-    faults.deactivate()
-
-
-_REF_CACHE: dict = {}
-
-
-def reference_tokens(params, vae_params, req: Request, cfg=CFG,
-                     quantize_cache: bool = False) -> np.ndarray:
-    """generate_images at batch 1 — the undisturbed same-seed run every
-    migrated request must reproduce byte-for-byte (keyed on the params
-    object too: the upgrade test compares per weight generation)."""
-    key = (id(params), req.codes, req.seed, req.sampling.temperature,
-           req.sampling.filter_thres, req.sampling.top_p,
-           req.cfg_scale, quantize_cache)
-    if key not in _REF_CACHE:
-        text = jnp.asarray([req.codes], jnp.int32)
-        _, img_seq = D.generate_images(
-            params, vae_params, text, cfg=cfg,
-            rng=jax.random.PRNGKey(req.seed),
-            filter_thres=req.sampling.filter_thres,
-            top_p=req.sampling.top_p,
-            temperature=req.sampling.temperature,
-            guidance=req.cfg_scale,
-            quantize_cache=quantize_cache, return_img_seq=True)
-        _REF_CACHE[key] = np.asarray(img_seq)[0]
-    return _REF_CACHE[key]
-
-
-REQS = [
-    Request(codes=(3, 7, 9), seed=11),
-    Request(codes=(5, 2, 8, 1, 4), seed=23,
-            sampling=SamplingParams(temperature=0.7, filter_thres=0.8)),
-    Request(codes=(6, 6), seed=5,
-            sampling=SamplingParams(temperature=1.3, top_p=0.9)),
-    Request(codes=(2, 4, 4), seed=7),
-    Request(codes=(1, 5), seed=13),
-    Request(codes=(4, 4, 4, 4), seed=17),
-]
-
+reference_tokens = functools.partial(tiny_model.reference_tokens, cfg=CFG)
 
 def assert_all_token_exact(params, vae_params, handles, reqs):
     for h, r in zip(handles, reqs):

@@ -2,14 +2,15 @@
 (``ops.transformer.WindowGQABlock`` with ``full_kv_heads``, ``v_head_dim``,
 ``rotary_dim``, ``full_rope_theta``, ``value_scale``, ``sink`` and no
 norms over heads, gate, second norms or shared expert) at toy widths,
-float32, seeded: the program against the benchmark family's plain
-reference (``benchmark/families/mimo_v2/reference.py``) at logit level on a
-sequence several windows long; the two pools, whose K rows are wider than
+float32, seeded: the contract of every described block
+(``block_contract.py``: the program against the benchmark family's plain
+reference, ``benchmark/families/mimo_v2/reference.py``, at logit level on a
+sequence several windows long; the paged decode; the engine; every
+refusal), then its own: the two pools, whose K rows are wider than
 their V rows and whose key/value heads differ by layer type (four buffers
 of four widths); the ring at its edges; the sink; the partial rotary
 positions at two bases; the held share of the experts (all the shares add
-up to the uncut layer, nothing counted once but the residual); and every
-option that cannot run the block refusing it by the one typed error.
+up to the uncut layer, nothing counted once but the residual).
 
 Tolerances: the program and the reference compute the same float32
 mathematics in another order (grouped products, a cached ring read in
@@ -26,79 +27,119 @@ import numpy as np
 import pytest
 
 from benchmark import harness, seeds
-from dalle_pytorch_tpu.models import dalle as D
+from block_contract import (BlockContract, Toy, params,  # noqa: F401
+                            ref_logits, sequences, served)
 from dalle_pytorch_tpu.ops import attention as attn_ops
 from dalle_pytorch_tpu.ops import decode as decode_ops
 from dalle_pytorch_tpu.ops import moe as moe_ops
 from dalle_pytorch_tpu.ops import transformer as T
 from dalle_pytorch_tpu.serve import kv_pool as KV
 from dalle_pytorch_tpu.serve.engine import Engine
-from dalle_pytorch_tpu.serve.scheduler import (Request, RequestQueue,
-                                               SamplingParams)
+from dalle_pytorch_tpu.serve.scheduler import RequestQueue
 
-FAMILY = harness.load_family("mimo_v2")
-SEED = 2 ** 31 + 13
-PS = 4                      # page size: the text window is not a multiple
-CONF = dict(harness.load_json(
-    harness.ROOT + "/benchmark/configs/mimo-v2.5.json"), **FAMILY.tiny)
 # a window of two pages in a sequence of nine: the ring (three pages, 12
-# rows) turns twice
-CONF.update(text_seq_len=10, image_grid=5, sliding_window=8,
-            sliding_window_size=8)
-DEPTH = 7
-
-
-def _dims(**kw):
-    return FAMILY.weights.dims_of(dict(CONF, **kw), DEPTH)
-
-
-DIMS = _dims()
-CFG = FAMILY.build.program_config(DIMS, {})
-TCFG = CFG.transformer
-BLK = TCFG.block
-RING = BLK.ring_pages(PS, DIMS.seq_len)
-WIDTH = KV.pages_for(DIMS.seq_len, PS)
+# rows) turns twice. A prompt inside the window's first page; one past the
+# window (8) and the ring's first page boundary; one longer than the whole
+# ring (12): its first rows are overwritten at admission. (The parameter
+# tree's keys are the afmoe block's too.)
+TOY = Toy("mimo_v2", "mimo-v2.5", 7, "window_sink_gqa_moe",
+          overrides=dict(text_seq_len=10, image_grid=5, sliding_window=8,
+                         sliding_window_size=8),
+          t0s=(3, 9, 14), bf16_misses=100, tree_name="window_gqa_moe",
+          chunked=(("k", 1, 1e-5, 1e-5), ("v", 1, 1e-5, 1e-5)))
+FAMILY, CONF, DIMS, CFG, TCFG, BLK = (TOY.family, TOY.conf, TOY.dims,
+                                      TOY.cfg, TOY.tcfg, TOY.blk)
+PS, RING, WIDTH, SEED, DEPTH = (TOY.page_size, TOY.ring, TOY.width, TOY.seed,
+                                TOY.depth)
 FULL_LAYERS = [i for i, t in enumerate(DIMS.layer_types) if t == "full"]
 WINDOW_LAYERS = [i for i, t in enumerate(DIMS.layer_types) if t != "full"]
 # a row of each buffer: the layer type's key/value heads x a K or a V head
 ROWS = {"k": 1 * 12, "v": 1 * 8, "window_k": 2 * 12, "window_v": 2 * 8}
 
 
-def _tree(dims, dtype=jnp.float32):
-    return jax.jit(lambda h: FAMILY.weights.tree(h, dims, dtype))(
-        seeds.split_seed(SEED))
+class TestContract(BlockContract):
+    toy = TOY
 
+    def step_loads(self, loads, b, t0):
+        """Every edge of the window (positions 7, 8, 9), every page
+        boundary and both wraps of the ring (12, 24) lie on the way. With
+        a sink the load is float32: the six counts, then the sink's
+        weight over every window softmax of the step and their number
+        (``decode_ops.load_like``)."""
+        picks = b * BLK.experts_per_token * DIMS.moe_layers
+        reads = b * TCFG.heads * len(WINDOW_LAYERS)
+        mass = 0.0
+        for load in loads:
+            assert load.shape == (8,) and load.dtype == jnp.float32
+            assert int(load[0]) == picks and 0 <= int(load[4]) <= picks
+            assert int(load[5]) == picks    # too few pairs for a row ladder
+            assert int(load[3]) == int(load[1])     # and for a second tile
+            assert int(load[7]) == reads
+            assert 0.0 < float(load[6]) < reads
+            mass += float(load[6]) / reads
+        # the draw gives the sink a real share of a window row's weight
+        assert 0.05 < mass / (DIMS.seq_len - 1 - t0) < 0.9
 
-@pytest.fixture(scope="module")
-def params():
-    return _tree(DIMS)
+    def chunk_loads(self, loads, b):
+        assert sum(int(load[0]) for load in loads) \
+            == 16 * b * BLK.experts_per_token * DIMS.moe_layers
+        assert sum(int(load[7]) for load in loads) \
+            == 16 * b * TCFG.heads * len(WINDOW_LAYERS)
 
+    def watch(self, engine):
+        st = engine.stats()
+        assert st["window_pages_in_use"] <= 2 * RING
+        assert st["layer_pages_in_use"] <= st["layer_pages_all_full"]
 
-@pytest.fixture(scope="module")
-def sequences():
-    rng = np.random.default_rng(3)
-    return np.concatenate(
-        [rng.integers(1, DIMS.num_text_tokens, (2, DIMS.text_seq_len)),
-         rng.integers(0, DIMS.num_image_tokens, (2, DIMS.image_seq_len))], 1)
+    def test_engine_serves_the_reference_s_tokens_in_chunks_of_8(
+            self, served, switch_placement):
+        """Through the engine: admission's whole-page write into both
+        pools (a prompt longer than the window among them), the ring's
+        pages reused as the slots move on, slot reuse, the fused chunks;
+        the routed load and the sink's weight come out with the ring, and
+        both pools are empty at the end. Both full layers are runs of
+        one: at the published sizes (``a_switch_a_read``: the experts'
+        stacks are too much to hand out of one switch) they read their
+        tables whole and the width rule's counters stay 0; the toy's
+        small experts let ONE switch stand around the scans from the
+        first full layer to the last, and both read at the step's
+        profile."""
+        self.engine_serves(self.together(served, switch_placement),
+                           switch_placement)
 
-
-@pytest.fixture(scope="module")
-def ref_logits(sequences):
-    return np.asarray(FAMILY.reference.served_logits(
-        SEED, DIMS, jnp.float32, sequences.tolist()))
-
-
-def _close(got, want, atol=2e-5):
-    fin = np.isfinite(want)
-    assert (np.asarray(got)[~fin] < -1e30).all()      # forbidden either way
-    np.testing.assert_allclose(np.asarray(got)[fin], want[fin], atol=atol,
-                               rtol=0)
-
-
-def _apply(params, sequences, cfg=CFG):
-    t = DIMS.text_seq_len
-    return D.dalle_apply(params, jnp.asarray(sequences[:, :t]),
-                         jnp.asarray(sequences[:, t:-1]), cfg=cfg)
+    def engine_counters(self, engine, st, placement):
+        assert engine.window.ring == RING and engine.block_tables[
+            "window"].shape == (2, RING)
+        assert {n: a.shape[2:] for n, a in engine.cache.items()} == {
+            n: (PS, w) for n, w in ROWS.items()}
+        assert st["window_pages_reused"] == 3 * (WIDTH - RING)
+        assert 0 < st["moe_picks_held"] < st["moe_picks"]
+        # two slots' pairs are under the row ladder's first step: all
+        # handed on
+        assert st["moe_rows_computed"] == st["moe_picks"]
+        # the sink's counters: a softmax a window layer a head an ACTIVE
+        # slot a step, fewer than every slot's every step; its weight a
+        # real share
+        assert 0 < st["window_sink_reads"] <= st["decode_steps"] * 2 \
+            * TCFG.heads * len(WINDOW_LAYERS)
+        assert st["window_sink_reads"] % (TCFG.heads
+                                          * len(WINDOW_LAYERS)) == 0
+        assert 0.05 < st["window_sink_mass"] / st["window_sink_reads"] < 0.9
+        assert st["kv_hbm_bytes"] == (2 * 19 * 20 + 5 * 7 * 40) * PS * 4
+        # what a step's gathers read: a full layer its table, a window
+        # layer its ring, each page at its own K and V widths
+        assert st["kv_read_bytes_per_token"] == \
+            (2 * WIDTH * 20 + 5 * RING * 40) * PS * 4
+        if placement == "a_switch_a_read":
+            assert engine._view_plan is None
+            assert st["kv_view_columns_read"] \
+                == st["kv_view_columns_full"] == 0
+        else:
+            # two slots are one group, which reads the whole table
+            assert engine._view_plan.by_rule == 2 \
+                and st["kv_view_groups"] == 1
+            assert st["kv_view_columns_read"] == st["kv_view_columns_full"] \
+                == st["decode_steps"] * 2 * 2 * WIDTH
 
 
 def test_the_toy_is_the_published_pattern_and_wraps_its_window():
@@ -130,22 +171,7 @@ def test_the_toy_is_the_published_pattern_and_wraps_its_window():
         "k": 768, "v": 512, "window_k": 1536, "window_v": 1024}
 
 
-# -- (i) the full forward against the reference -------------------------------
-
-def test_dalle_apply_matches_the_reference_logits(params, sequences,
-                                                  ref_logits):
-    _close(_apply(params, sequences), ref_logits)
-
-
-def test_bfloat16_fails_the_tolerance(sequences, ref_logits):
-    """The same program with its weights and its arithmetic in bfloat16
-    misses the float32 tolerance by two orders of magnitude: the
-    comparison is tight enough to tell the precisions apart."""
-    got = np.asarray(_apply(_tree(DIMS, jnp.bfloat16), sequences),
-                     np.float32)
-    fin = np.isfinite(ref_logits)
-    assert np.abs(got[fin] - ref_logits[fin]).max() > 100 * 2e-5
-
+# -- (i) each mechanism in the logits ----------------------------------------
 
 @pytest.mark.parametrize("without", ["sink", "value_scale", "rotary_part",
                                      "full_base", "window_base"])
@@ -167,8 +193,8 @@ def test_each_mechanism_is_in_the_logits(params, sequences, ref_logits,
             "rotary_part": dict(rotary_dim=None),
             "full_base": dict(full_rope_theta=BLK.rope_theta),
             "window_base": dict(rope_theta=BLK.full_rope_theta)}[without])
-    got = np.asarray(_apply(p, sequences, dataclasses.replace(CFG,
-                                                              block=blk)))
+    got = np.asarray(TOY.apply(p, sequences,
+                               dataclasses.replace(CFG, block=blk)))
     fin = np.isfinite(ref_logits)
     assert np.abs(got[fin] - ref_logits[fin]).max() > 50 * 2e-5
 
@@ -195,7 +221,7 @@ def test_partial_rotary_against_the_reference():
         np.asarray(attn_ops.rope_half(x, pos[:, None], 1e4)))
 
 
-# -- (ii) the pools: a width a buffer ------------------------------------------
+# -- (ii) the pools: a width a buffer -----------------------------------------
 
 def test_a_page_has_its_buffer_s_own_width():
     layout = KV.page_layout(TCFG, PS)
@@ -242,153 +268,8 @@ def test_every_configuration_s_page_widths(family, config, widths):
     assert pages == {n: (16, w) for n, w in widths.items()}
 
 
-def _tables(b):
-    return {"full": 1 + jnp.arange(b * WIDTH, dtype=jnp.int32).reshape(
-                b, WIDTH),
-            "window": 1 + jnp.arange(b * RING, dtype=jnp.int32).reshape(
-                b, RING)}
-
-
-def _prefilled_pools(params, sequences, t0, upto=None):
-    """The prompt's rows [0, t0) of the sequences in the two pools: a
-    full layer's row j in page j // PS of the slot's full table, a window
-    layer's in column (j // PS) % RING of its ring, later rows over
-    earlier ones (page 0 of each pool is the trash page); with ``upto``
-    (b,), slot i's rows [0, upto[i]) alone."""
-    b = sequences.shape[0]
-    tables = _tables(b)
-    pool = dict(KV.init_page_pool(TCFG, 1 + b * WIDTH, PS,
-                                  window_pages=1 + b * RING))
-    t = min(t0, DIMS.text_seq_len)
-    x = D.embed_prompt(params, CFG, jnp.asarray(sequences[:, :t]),
-                       jnp.asarray(sequences[:, t:t0]))
-    h, cache = decode_ops.prefill(params["transformer"], x, cfg=TCFG,
-                                  total_len=DIMS.seq_len)
-    # the prompt's rows come a buffer of the pool each, over the layers
-    # that store to it, the heads and head sizes of its own layer type
-    assert cache["k"].shape == (len(FULL_LAYERS), b, t0, 1, 12)
-    assert cache["v"].shape == (len(FULL_LAYERS), b, t0, 1, 8)
-    assert cache["window_k"].shape == (len(WINDOW_LAYERS), b, t0, 2, 12)
-    assert cache["window_v"].shape == (len(WINDOW_LAYERS), b, t0, 2, 8)
-    for name in ROWS:
-        buf, rows = np.array(pool[name]), np.asarray(cache[name])
-        ring = name.startswith("window_")
-        table = np.asarray(tables["window" if ring else "full"])
-        for i in range(b):
-            for j in range(t0 if upto is None else upto[i]):
-                column = (j // PS) % RING if ring else j // PS
-                buf[:, table[i, column], j % PS] = rows[:, i, j].reshape(
-                    rows.shape[0], -1)
-        pool[name] = jnp.asarray(buf)
-    return h, pool, tables
-
-
-def _teacher_forced(params, sequences):
-    def embed_fn(tok, pos):
-        return D.decode_token_embed(params, CFG, tok, pos)
-
-    def sample_fn(_h, pred_pos):
-        # the NEXT token of the given sequences, as the loop stores it
-        return jnp.take_along_axis(jnp.asarray(sequences),
-                                   pred_pos[:, None], axis=1)[:, 0]
-    return embed_fn, sample_fn
-
-
-# a prompt inside the window's first page; one past the window (8) and
-# the ring's first page boundary; one longer than the whole ring (12): its
-# first rows are overwritten at admission
-@pytest.mark.parametrize("t0", [3, 9, 14])
-def test_prefill_then_paged_decode_matches_the_full_forward(
-        params, sequences, ref_logits, t0):
-    h, pool, tables = _prefilled_pools(params, sequences, t0)
-    b = sequences.shape[0]
-    key_mask = jnp.ones((b, DIMS.seq_len), bool)
-    active = jnp.ones((b,), bool)
-    forbidden = np.asarray(D.logits_mask(CFG))
-    first = np.where(forbidden[t0 - 1], -np.inf,
-                     np.asarray(D.to_logits(params, h[:, -1], CFG)))
-    fin = np.isfinite(ref_logits[:, t0 - 1])
-    np.testing.assert_allclose(first[fin], ref_logits[:, t0 - 1][fin],
-                               atol=2e-5, rtol=0)     # the prefill's own row
-    # position by position to the sequence's end: every edge of the
-    # window (positions 7, 8, 9), every page boundary and both wraps of
-    # the ring (12, 24), logits against the reference's full forward
-    step_pool = pool
-    step = jax.jit(lambda x, p, pool: decode_ops.decode_step_block(
-        params["transformer"], x, p, pool, tables, cfg=TCFG,
-        key_mask=key_mask, active=active))
-    mass = 0.0
-    for pos in range(t0, DIMS.seq_len - 1):
-        p = jnp.full((b,), pos, jnp.int32)
-        x = D.decode_token_embed(params, CFG, jnp.asarray(sequences[:, pos]),
-                                 p)
-        h_tok, step_pool, load = step(x, p, step_pool)
-        logits = np.asarray(D.to_logits(params, h_tok, CFG))
-        logits = np.where(forbidden[pos], -np.inf, logits)
-        fin = np.isfinite(ref_logits[:, pos])
-        np.testing.assert_allclose(logits[fin], ref_logits[:, pos][fin],
-                                   atol=2e-5, rtol=0)
-        picks = b * BLK.experts_per_token * DIMS.moe_layers
-        # (with a sink the load is float32: the six counts, then the
-        # sink's weight over every window softmax of the step and their
-        # number, ``decode_ops.load_like``)
-        assert load.shape == (8,) and load.dtype == jnp.float32
-        assert int(load[0]) == picks and 0 <= int(load[4]) <= picks
-        assert int(load[5]) == picks    # too few pairs for a row ladder
-        assert int(load[3]) == int(load[1])     # and for a second row tile
-        reads = b * TCFG.heads * len(WINDOW_LAYERS)
-        assert int(load[7]) == reads
-        assert 0.0 < float(load[6]) < reads
-        mass += float(load[6]) / reads
-    # the draw gives the sink a real share of a window row's weight
-    assert 0.05 < mass / (DIMS.seq_len - 1 - t0) < 0.9
-    # the same steps in chunks of 8 write the same pools and count alike
-    embed_fn, sample_fn = _teacher_forced(params, sequences)
-    cur = jnp.asarray(sequences[:, t0])
-    p = jnp.full((b,), t0, jnp.int32)
-    chunk_pool, picks, reads = pool, 0, 0
-    for _ in range(2):
-        cur, p, act, chunk_pool, ring, load = \
-            decode_ops.decode_loop_paged(
-                params["transformer"], cur, p, active, chunk_pool, tables,
-                cfg=TCFG, key_mask=key_mask, total_len=DIMS.seq_len, steps=8,
-                embed_fn=embed_fn, sample_fn=sample_fn)
-        picks += int(load[0])
-        reads += int(load[7])
-    assert picks == 16 * b * BLK.experts_per_token * DIMS.moe_layers
-    assert reads == 16 * b * TCFG.heads * len(WINDOW_LAYERS)
-    np.testing.assert_array_equal(np.asarray(ring)[:, -1],
-                                  sequences[:, t0 + 15])
-    # both pools' K rows t0 .. t0 + 16, each at its own width
-    for name, table in (("k", "full"), ("v", "full")):
-        live, want = (np.asarray(decode_ops.layer_pool_view(
-            pl[name], jnp.int32(1), tables[table])).reshape(
-                b, -1, ROWS[name]) for pl in (chunk_pool, step_pool))
-        np.testing.assert_allclose(live[:, t0:t0 + 16], want[:, t0:t0 + 16],
-                                   atol=1e-5, rtol=1e-5)
-
-
-def _step_at(params, seqs, positions, active=None):
-    """One decode step with slot i at ``positions[i]`` of ``seqs[i]``,
-    the rows before it in its pages of both pools (its ring as far as it
-    has turned) -> (the logits (forbidden ones -inf), the step's load)."""
-    _, pool, tables = _prefilled_pools(params, seqs, int(positions.max()),
-                                       positions)
-    p = jnp.asarray(positions)
-    b = len(positions)
-    x = D.decode_token_embed(
-        params, CFG, jnp.asarray(seqs[np.arange(b), positions]), p)
-    h_tok, _, load = jax.jit(lambda x, p, pool: decode_ops.decode_step_block(
-        params["transformer"], x, p, pool, tables, cfg=TCFG,
-        key_mask=jnp.ones((b, DIMS.seq_len), bool),
-        active=jnp.ones((b,), bool) if active is None else active))(
-            x, p, pool)
-    return np.where(np.asarray(D.logits_mask(CFG))[positions], -np.inf,
-                    np.asarray(D.to_logits(params, h_tok, CFG))), load
-
-
 def test_slots_at_the_ring_s_edges_match_the_full_forward(
-        params, sequences, ref_logits, release_programs):
+        params, sequences, ref_logits):
     """Sixteen slots at the positions where a ring can go wrong: the
     window's edge (7, 8, 9: the last row inside, the first that leaves),
     the ring's page boundaries (4, 12, 16), its wraps (11, 12, 13 and 23,
@@ -400,8 +281,8 @@ def test_slots_at_the_ring_s_edges_match_the_full_forward(
     assert len(full) == 2 and all(r.count == 1 for r in full)
     rows = np.arange(len(positions)) % len(sequences)
     active = jnp.asarray(positions > 0)
-    got, load = _step_at(params, sequences[rows], positions, active)
-    _close(got[1:], ref_logits[rows, positions][1:])
+    got, load, _ = TOY.step_at(params, sequences[rows], positions, active)
+    TOY.close(got[1:], ref_logits[rows, positions][1:])
     # the parked slot's softmaxes are not counted
     assert int(load[7]) == 15 * TCFG.heads * len(WINDOW_LAYERS)
 
@@ -483,80 +364,7 @@ def test_cached_rows_read_equals_the_materialised_read_with_a_sink():
             )[:, 0], atol=2e-6)
 
 
-# -- (iii) the engine: both pools, the ring, chunks of 8 -----------------------
-
-def test_engine_serves_the_reference_s_tokens_in_chunks_of_8(
-        params, switch_placement):
-    """Through the engine: admission's whole-page write into both pools
-    (a prompt longer than the window among them), the ring's pages reused
-    as the slots move on, slot reuse, the fused chunks. Greedy tokens are
-    the reference's best at every served position (gap 0 but for float32
-    near-ties), the routed load and the sink's weight come out with the
-    ring, and both pools are empty at the end. Both full layers are runs
-    of one: at the published sizes (``a_switch_a_read``: the experts'
-    stacks are too much to hand out of one switch) they read their tables
-    whole and the width rule's counters stay 0; the toy's small experts
-    let ONE switch stand around the scans from the first full layer to the
-    last, and both read at the step's profile."""
-    queue = RequestQueue(max_depth=8)
-    engine = Engine(params, CFG, queue, num_slots=2, chunk_steps=8,
-                    kv="paged", page_size=PS)
-    assert engine.window.ring == RING and engine.block_tables[
-        "window"].shape == (2, RING)
-    assert {n: a.shape[2:] for n, a in engine.cache.items()} == {
-        n: (PS, w) for n, w in ROWS.items()}
-    greedy = SamplingParams(filter_thres=1.0)
-    reqs = [Request(codes=(3, 7, 9), seed=11, sampling=greedy),
-            Request(codes=tuple(range(1, 11)), seed=2, sampling=greedy),
-            Request(codes=(6, 6, 1, 2, 3, 9, 4), seed=3, sampling=greedy)]
-    handles = [queue.submit(r) for r in reqs]
-    while not engine.idle():
-        engine.step_once()
-        st = engine.stats()
-        assert st["window_pages_in_use"] <= 2 * RING
-        assert st["layer_pages_in_use"] <= st["layer_pages_all_full"]
-    seqs, lens = [], []
-    for r, h in zip(reqs, handles):
-        res = h.result(timeout=5)
-        assert res.status == "ok"
-        seqs.append(list(np.asarray(res.text_tokens))
-                    + list(np.asarray(res.tokens)))
-        lens.append(len(r.codes))
-        assert seqs[-1][:lens[-1]] == list(r.codes)
-    gaps, served = FAMILY.reference.served_gaps(SEED, DIMS, jnp.float32,
-                                                seqs, lens)
-    assert float(np.asarray(gaps)[np.asarray(served)].max()) < 1e-5
-    st = engine.stats()
-    assert engine.decode_traces == 1
-    assert engine.alloc.in_use == 0 and engine.window.alloc.in_use == 0
-    assert st["window_pages_reused"] == 3 * (WIDTH - RING)
-    assert st["moe_picks"] == (st["decode_steps"] * engine.num_slots
-                               * BLK.experts_per_token * DIMS.moe_layers)
-    assert 0 < st["moe_picks_held"] < st["moe_picks"]
-    # two slots' pairs are under the row ladder's first step: all handed on
-    assert st["moe_rows_computed"] == st["moe_picks"]
-    # the sink's counters: a softmax a window layer a head an ACTIVE slot a
-    # step, fewer than every slot's every step; its weight a real share
-    assert 0 < st["window_sink_reads"] <= st["decode_steps"] * 2 \
-        * TCFG.heads * len(WINDOW_LAYERS)
-    assert st["window_sink_reads"] % (TCFG.heads * len(WINDOW_LAYERS)) == 0
-    assert 0.05 < st["window_sink_mass"] / st["window_sink_reads"] < 0.9
-    assert st["kv_hbm_bytes"] == KV.modeled_kv_bytes(
-        TCFG, kv="paged", num_slots=2, total_len=DIMS.seq_len,
-        page_size=PS) == (2 * 19 * 20 + 5 * 7 * 40) * PS * 4
-    # what a step's gathers read: a full layer its table, a window layer
-    # its ring, each page at its own K and V widths
-    assert st["kv_read_bytes_per_token"] == \
-        (2 * WIDTH * 20 + 5 * RING * 40) * PS * 4
-    if switch_placement == "a_switch_a_read":
-        assert engine._view_plan is None
-        assert st["kv_view_columns_read"] == st["kv_view_columns_full"] == 0
-    else:
-        # two slots are one group, which reads the whole table
-        assert engine._view_plan.by_rule == 2 and st["kv_view_groups"] == 1
-        assert st["kv_view_columns_read"] == st["kv_view_columns_full"] \
-            == st["decode_steps"] * 2 * 2 * WIDTH
-
+# -- (iii) the sink's counters -----------------------------------------------
 
 def test_a_block_without_a_sink_has_no_sink_counters():
     fam = harness.load_family("afmoe")
@@ -576,13 +384,13 @@ def test_a_block_without_a_sink_has_no_sink_counters():
     assert T.block_name_of(p["transformer"]) == "window_gqa_moe"
 
 
-# -- (iv) the held share of the experts ----------------------------------------
+# -- (iv) the held share of the experts ---------------------------------------
 
 def test_all_the_shares_add_up_to_the_uncut_reference_layer():
     """The routed parts that the 4 shares of 4 experts give add up to the
     reference's whole layer of 16 experts: no shared expert, so nothing
     is counted once but the residual, which the layer adds outside."""
-    whole = _dims(experts_held=16, first_expert=0)
+    whole = TOY.dims_of(experts_held=16, first_expert=0)
     key = seeds.layer_key(seeds.seed_key(SEED), whole.first_layer + 2)
     ref_p = FAMILY.weights.layer(key, whole, jnp.float32, True, False)["ff"]
     assert "shared" not in ref_p
@@ -594,7 +402,7 @@ def test_all_the_shares_add_up_to_the_uncut_reference_layer():
     want = R.routed(ref_p["experts"], m, weights)
     total, held = np.zeros((24, whole.dim), np.float32), 0
     for first in range(0, 16, 4):
-        dims = _dims(first_expert=first)
+        dims = TOY.dims_of(first_expert=first)
         blk = FAMILY.build.program_config(dims, {}).transformer.block
         p = FAMILY.weights.layer(key, dims, jnp.float32, True, False)["ff"]
         np.testing.assert_array_equal(
@@ -630,63 +438,11 @@ def test_a_routed_layer_without_a_shared_expert():
     assert int(load[0]) == 48 and int(load[4]) < 48
 
 
-# -- (v) every path that cannot run the block refuses it ----------------------
-
-def _engine(params, **kw):
-    kw.setdefault("kv", "paged")
-    return Engine(params, CFG, RequestQueue(max_depth=2), num_slots=1, **kw)
-
-
-def _mesh_engine(params):
-    from dalle_pytorch_tpu.serve.mesh_engine import MeshEngine
-    return MeshEngine(params, CFG, RequestQueue(max_depth=2),
-                      devices=jax.devices()[:2], num_slots=1, kv="paged")
-
-
-REFUSED = {
-    "kv_dense": lambda p: _engine(p, kv="dense"),
-    "paged_attn_kernel": lambda p: _engine(p, paged_attn="kernel",
-                                           page_size=8),
-    "speculative": lambda p: _engine(p, speculative=2),
-    "sparse_reads": lambda p: _engine(p, sparse_reads=True),
-    "quantize_cache": lambda p: _engine(p, quantize_cache=True),
-    "prefix_cache": lambda p: _engine(p, prefix_cache=True),
-    "mesh_engine": _mesh_engine,
-    "quantize_int8": lambda p: D.quantize_for_decode(p),
-    "generate_images": lambda p: D.generate_images(
-        p, None, jnp.ones((1, 4), jnp.int32), cfg=CFG,
-        rng=jax.random.PRNGKey(0)),
-    "train": lambda p: D.dalle_apply(
-        p, jnp.ones((1, 10), jnp.int32), jnp.ones((1, 25), jnp.int32),
-        cfg=CFG, train=True, return_loss=True),
-    "reversible": lambda p: dataclasses.replace(CFG, reversible=True)
-    .transformer,
-    "attn_impl_flash": lambda p: dataclasses.replace(CFG, attn_impl="flash")
-    .transformer,
-    "dense_cache": lambda p: decode_ops.init_cache(TCFG, 1, 8),
-    "kernel_loop": lambda p: decode_ops.decode_loop_paged(
-        p["transformer"], None, None, None, {}, None, cfg=TCFG,
-        key_mask=None, total_len=8, steps=1, embed_fn=None, sample_fn=None,
-        attn_impl="kernel"),
-    "int8_pool": lambda p: KV.init_page_pool(TCFG, 4, PS, quantized=True),
-}
-
-
-@pytest.mark.parametrize("option", sorted(REFUSED))
-def test_every_refused_option_raises_the_one_typed_error(params, option):
-    with pytest.raises(T.BlockOptionError) as e:
-        REFUSED[option](params)
-    name = "window_gqa_moe" if option == "quantize_int8" else BLK.name
-    assert BLK.name == "window_sink_gqa_moe" and e.value.block == name
-    assert name in str(e.value) and e.value.option in str(e.value)
-
-
 def test_a_block_whose_turned_part_is_no_part_of_a_head_is_refused():
     for turned in (3, 14):
         with pytest.raises(ValueError, match="rotary_dim"):
             dataclasses.replace(BLK, rotary_dim=turned)
     with pytest.raises(ValueError, match="do not all lead"):
-        FAMILY.weights.dims_of(dict(CONF, moe_layer_freq=[1, 0] + [1] * 46),
-                               DEPTH)
+        TOY.dims_of(moe_layer_freq=[1, 0] + [1] * 46)
     with pytest.raises(ValueError, match="add_swa_attention_sink_bias"):
-        _dims(add_swa_attention_sink_bias=False)
+        TOY.dims_of(add_swa_attention_sink_bias=False)

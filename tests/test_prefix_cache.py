@@ -29,63 +29,22 @@ All CPU, tiny model (total_len 24) so the file stays cheap in tier-1.
 
 import time
 
-import jax
-import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from dalle_pytorch_tpu.analysis import guards
-from dalle_pytorch_tpu.models import dalle as D
-from dalle_pytorch_tpu.models import vae as V
 from dalle_pytorch_tpu.serve import (ERROR, OK, PageAllocator,
                                      PageReleaseUnderflow, PrefixEntry,
                                      PrefixIndex, Request, RequestQueue,
                                      SamplingParams, pages_for)
 from dalle_pytorch_tpu.serve.engine import Engine
-
-VCFG = V.VAEConfig(image_size=16, num_tokens=32, codebook_dim=16,
-                   num_layers=2, hidden_dim=8)
-CFG = D.DALLEConfig(dim=16, depth=2, vae=VCFG, num_text_tokens=50,
-                    text_seq_len=8, heads=2, dim_head=8)
+from tiny_model import CFG, bundle, reference_tokens  # noqa: F401
 
 # len-8 prompt: two FULL pages at page_size 4 (physical sharing), one
 # full page at page_size 8 (the kernel's tile minimum); len-5 prompt:
 # exercises the partial-boundary COW snapshot at both page sizes
 P8 = (4, 1, 2, 3, 5, 6, 7, 2)
 P5 = (5, 2, 8, 1, 4)
-
-
-@pytest.fixture(scope="module")
-def bundle():
-    key = jax.random.PRNGKey(0)
-    vae_params = V.vae_init(jax.random.fold_in(key, 1), VCFG)
-    params = D.dalle_init(key, CFG, vae_params)
-    return params, vae_params
-
-
-_REF_CACHE: dict = {}
-
-
-def reference_tokens(params, vae_params, req: Request,
-                     quantize_cache: bool = False) -> np.ndarray:
-    """generate_images at batch 1 (``guidance=req.cfg_scale``) — the
-    one-shot stream warm hits, cold runs, and guided pairs must all
-    reproduce token-for-token. Memoized on the sampling identity."""
-    key = (req.codes, req.seed, req.sampling.temperature,
-           req.sampling.filter_thres, req.sampling.top_p,
-           req.cfg_scale, quantize_cache)
-    if key not in _REF_CACHE:
-        text = jnp.asarray([req.codes], jnp.int32)
-        _, img_seq = D.generate_images(
-            params, vae_params, text, cfg=CFG,
-            rng=jax.random.PRNGKey(req.seed),
-            filter_thres=req.sampling.filter_thres,
-            top_p=req.sampling.top_p,
-            temperature=req.sampling.temperature,
-            guidance=req.cfg_scale,
-            quantize_cache=quantize_cache, return_img_seq=True)
-        _REF_CACHE[key] = np.asarray(img_seq)[0]
-    return _REF_CACHE[key]
 
 
 def drain_tokens(engine, queue, reqs, timeout=30):

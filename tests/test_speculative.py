@@ -43,56 +43,11 @@ import pytest
 
 from dalle_pytorch_tpu.analysis import guards
 from dalle_pytorch_tpu.models import dalle as D
-from dalle_pytorch_tpu.models import vae as V
 from dalle_pytorch_tpu.ops import decode as decode_ops
-from dalle_pytorch_tpu.serve import (OK, Request, RequestQueue,
-                                     SamplingParams)
+from dalle_pytorch_tpu.serve import OK, Request, RequestQueue
 from dalle_pytorch_tpu.serve.engine import Engine
-
-VCFG = V.VAEConfig(image_size=16, num_tokens=32, codebook_dim=16,
-                   num_layers=2, hidden_dim=8)
-CFG = D.DALLEConfig(dim=16, depth=2, vae=VCFG, num_text_tokens=50,
-                    text_seq_len=8, heads=2, dim_head=8)
-
-REQS = [
-    Request(codes=(3, 7, 9), seed=11),
-    Request(codes=(5, 2, 8, 1, 4), seed=23,
-            sampling=SamplingParams(temperature=0.7, filter_thres=0.8)),
-    Request(codes=(6, 6), seed=5,
-            sampling=SamplingParams(temperature=1.3, top_p=0.9)),
-]
-
-
-@pytest.fixture(scope="module")
-def bundle():
-    key = jax.random.PRNGKey(0)
-    vae_params = V.vae_init(jax.random.fold_in(key, 1), VCFG)
-    params = D.dalle_init(key, CFG, vae_params)
-    return params, vae_params
-
-
-_REF_CACHE: dict = {}
-
-
-def reference_tokens(params, vae_params, req: Request,
-                     quantize_cache: bool = False) -> np.ndarray:
-    """Memoized generate_images at batch 1 — the one-shot stream every
-    speculative run must reproduce byte-for-byte."""
-    key = (req.codes, req.seed, req.sampling.temperature,
-           req.sampling.filter_thres, req.sampling.top_p,
-           quantize_cache)
-    if key not in _REF_CACHE:
-        text = jnp.asarray([req.codes], jnp.int32)
-        _, img_seq = D.generate_images(
-            params, vae_params, text, cfg=CFG,
-            rng=jax.random.PRNGKey(req.seed),
-            filter_thres=req.sampling.filter_thres,
-            top_p=req.sampling.top_p,
-            temperature=req.sampling.temperature,
-            quantize_cache=quantize_cache, return_img_seq=True)
-        _REF_CACHE[key] = np.asarray(img_seq)[0]
-    return _REF_CACHE[key]
-
+from tiny_model import (CFG, REQS, VCFG, bundle,  # noqa: F401
+                        reference_tokens)
 
 def _kv_kwargs(layout: str) -> dict:
     return {"dense": dict(kv="dense"),

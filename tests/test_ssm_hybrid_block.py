@@ -1,13 +1,16 @@
 """The state-space + window + shared-full-cache hybrid block
 (``ops.transformer.SSMHybridBlock``) at toy widths, float32, seeded: the
-program against the benchmark family's plain reference
-(``benchmark/families/phi4flash/reference.py``) at logit level on a
-sequence several windows long; the two forms of the state-space layer as
-one identity; the recurrent state a slot beside the page pools (written at
-each row's own prompt length, never advanced for an inactive slot,
-overwritten when a slot is reused, rebuilt by the replay after an
-eviction); the one full layer's pages read by every cross layer; and every
-option that cannot run the block refusing it by the one typed error.
+contract of every described block (``block_contract.py``: the program
+against the benchmark family's plain reference,
+``benchmark/families/phi4flash/reference.py``, at logit level on a
+sequence several windows long; the paged decode; the engine, a reused slot
+and an evicted request's replay; the width profiles of the one full
+layer's pool, which the full layer's differential read and the cross
+layers' read at the full layer's index, each with a query of its own;
+every refusal), then its own: the two forms of the state-space layer as
+one identity; the recurrent state a slot beside the page pools (written
+at each row's own prompt length, never advanced for an inactive slot);
+the one full layer's pages read by every cross layer.
 
 Tolerances: the program and the reference compute the same float32
 mathematics in another order (all positions' products at once, a cached
@@ -23,60 +26,47 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from benchmark import harness, seeds
+from block_contract import (BlockContract, Toy, params,  # noqa: F401
+                            ref_logits, sequences, served)
 from dalle_pytorch_tpu.models import dalle as D
 from dalle_pytorch_tpu.ops import attention as attn_ops
 from dalle_pytorch_tpu.ops import decode as decode_ops
 from dalle_pytorch_tpu.ops import ssm as ssm_ops
 from dalle_pytorch_tpu.ops import transformer as T
 from dalle_pytorch_tpu.serve import kv_pool as KV
-from dalle_pytorch_tpu.serve.engine import Engine, MigrationError
-from dalle_pytorch_tpu.serve.scheduler import (Request, RequestQueue,
-                                               SamplingParams)
+from dalle_pytorch_tpu.serve.engine import Engine
+from dalle_pytorch_tpu.serve.scheduler import RequestQueue
 
-FAMILY = harness.load_family("phi4flash")
-SEED = 2 ** 31 + 13
-PS = 4                      # page size: the text window is not a multiple
-CONF = dict(harness.load_json(
-    harness.ROOT + "/benchmark/configs/phi-4-mini-flash-reasoning.json"),
-    **FAMILY.tiny)
 # a window of two pages in a sequence of nine: the ring (three pages)
 # turns twice
-CONF.update(text_seq_len=10, image_grid=5, sliding_window=8)
-DIMS = FAMILY.weights.dims_of(CONF, 12)
-CFG = FAMILY.build.program_config(DIMS, {})
-TCFG = CFG.transformer
-BLK = TCFG.block
-RING = BLK.ring_pages(PS, DIMS.seq_len)
-WIDTH = KV.pages_for(DIMS.seq_len, PS)
-ATOL = 5e-5
+TOY = Toy("phi4flash", "phi-4-mini-flash-reasoning", 12, "ssm_hybrid",
+          overrides=dict(text_seq_len=10, image_grid=5, sliding_window=8),
+          atol=5e-5, gap=1e-4,
+          t0s=(7, 14),      # inside one page of the ring; past it
+          evicted=(0, 1), reused=True, profiles=(0, 1, 3))
+FAMILY, CONF, DIMS, CFG, TCFG, BLK = (TOY.family, TOY.conf, TOY.dims,
+                                      TOY.cfg, TOY.tcfg, TOY.blk)
+PS, RING, WIDTH, REQS = TOY.page_size, TOY.ring, TOY.width, TOY.requests
 
 
-@pytest.fixture(scope="module")
-def params():
-    return jax.jit(lambda h: FAMILY.weights.tree(h, DIMS, jnp.float32))(
-        seeds.split_seed(SEED))
+class TestContract(BlockContract):
+    toy = TOY
 
+    def step_loads(self, loads, b, t0):
+        assert all(load.shape == (0,) for load in loads)   # no routed layer
 
-@pytest.fixture(scope="module")
-def sequences():
-    rng = np.random.default_rng(3)
-    return np.concatenate(
-        [rng.integers(1, DIMS.num_text_tokens, (2, DIMS.text_seq_len)),
-         rng.integers(0, DIMS.num_image_tokens, (2, DIMS.image_seq_len))], 1)
-
-
-@pytest.fixture(scope="module")
-def ref_logits(sequences):
-    return np.asarray(FAMILY.reference.served_logits(
-        SEED, DIMS, jnp.float32, sequences.tolist()))
-
-
-def _close(got, want, atol=ATOL):
-    fin = np.isfinite(want)
-    assert (np.asarray(got)[~fin] < -1e30).all()      # forbidden either way
-    np.testing.assert_allclose(np.asarray(got)[fin], want[fin], atol=atol,
-                               rtol=0)
+    def engine_counters(self, engine, st, placement):
+        """Prompts of 3 and 10 tokens admitted in one bucket (each row's
+        state at its own length), the ring's pages reused, a slot reused
+        by the third request."""
+        assert st["window_pages_reused"] > 0
+        assert st["state_bytes"] == 4 * 2 * (DIMS.d_inner * DIMS.d_state * 4
+                                             + 3 * DIMS.d_inner * 4)
+        # the full pool's one layer is read by three layers, the rings by
+        # three, the state by four
+        row = 2 * (2 * PS * 8) * 4
+        assert st["kv_read_bytes_per_token"] == (
+            3 * WIDTH + 3 * RING) * row + st["state_bytes"] // 2
 
 
 def test_the_toy_is_the_published_pattern_scanned_in_periods():
@@ -108,17 +98,7 @@ def test_the_toy_is_the_published_pattern_scanned_in_periods():
         KV.pool_plan(whole, 100, 40)
 
 
-# -- (i) the full forward against the reference -------------------------------
-
-def test_dalle_apply_matches_the_reference_logits(params, sequences,
-                                                  ref_logits):
-    t = DIMS.text_seq_len
-    logits = D.dalle_apply(params, jnp.asarray(sequences[:, :t]),
-                           jnp.asarray(sequences[:, t:-1]), cfg=CFG)
-    _close(logits, ref_logits)
-
-
-# -- (ii) the two forms of the state-space layer ------------------------------
+# -- (i) the two forms of the state-space layer ------------------------------
 
 def _ssm_layer(params, i=1):
     return jax.tree.map(lambda a: a[i], params["transformer"]["ssm"]["attn"])
@@ -198,167 +178,10 @@ def test_a_padded_prefills_last_row_is_its_own_prompts(params, sequences):
                                    np.asarray(own[0, -1]), atol=2e-5)
 
 
-# -- (iii) prefill, then the paged gather decode -------------------------------
-
-def _tables(b):
-    return {"full": 1 + jnp.arange(b * WIDTH, dtype=jnp.int32).reshape(
-                b, WIDTH),
-            "window": 1 + jnp.arange(b * RING, dtype=jnp.int32).reshape(
-                b, RING)}
-
-
-def _prefilled_pools(params, sequences, t0, upto=None):
-    """The prompt's rows [0, t0) of the sequences in the two pools (a
-    full layer's row j in page j // PS of the slot's full table, a window
-    layer's in column (j // PS) % RING of its ring, later rows over
-    earlier ones; page 0 of each pool is the trash page) and each
-    sequence's state in its slot; with ``upto`` (b,), slot i's rows [0,
-    upto[i]) alone and its state after exactly that many tokens."""
-    b = sequences.shape[0]
-    tables = _tables(b)
-    pool = dict(KV.init_page_pool(TCFG, 1 + b * WIDTH, PS,
-                                  window_pages=1 + b * RING, num_slots=b))
-    assert pool["k"].shape == (1, 1 + b * WIDTH, PS, 2 * 8)
-    assert pool["window_v"].shape == (3, 1 + b * RING, PS, 2 * 8)
-    assert pool["ssm_state"].shape == (4, b, DIMS.d_state, DIMS.d_inner) \
-        and pool["ssm_state"].dtype == jnp.float32
-    assert pool["ssm_conv"].shape == (4, b, 3, DIMS.d_inner)
-    t = min(t0, DIMS.text_seq_len)
-    x = D.embed_prompt(params, CFG, jnp.asarray(sequences[:, :t]),
-                       jnp.asarray(sequences[:, t:t0]))
-    h, cache = decode_ops.prefill(
-        params["transformer"], x, cfg=TCFG, total_len=DIMS.seq_len,
-        lens=None if upto is None else jnp.asarray(upto))
-    for name, table, ring in (("k", "full", False), ("v", "full", False),
-                              ("window_k", "window", True),
-                              ("window_v", "window", True)):
-        buf, rows = np.array(pool[name]), np.asarray(cache[name])
-        for i in range(b):
-            for j in range(t0 if upto is None else upto[i]):
-                col = (j // PS) % RING if ring else j // PS
-                buf[:, tables[table][i, col], j % PS] = \
-                    rows[:, i, j].reshape(buf.shape[0], -1)
-        pool[name] = jnp.asarray(buf)
-    pool["ssm_state"], pool["ssm_conv"] = cache["ssm_state"], \
-        cache["ssm_conv"]
-    return h, pool, tables
-
-
-@pytest.mark.parametrize("t0", [7, 14])     # inside one page of the ring;
-def test_prefill_then_paged_decode_matches_the_full_forward(    # past it
-        params, sequences, ref_logits, t0):
-    h, pool, tables = _prefilled_pools(params, sequences, t0)
-    b = sequences.shape[0]
-    key_mask = jnp.ones((b, DIMS.seq_len), bool)
-    active = jnp.ones((b,), bool)
-    forbidden = np.asarray(D.logits_mask(CFG))
-    first = np.where(forbidden[t0 - 1], -np.inf,
-                     np.asarray(D.to_logits(params, h[:, -1], CFG)))
-    fin = np.isfinite(ref_logits[:, t0 - 1])
-    np.testing.assert_allclose(first[fin], ref_logits[:, t0 - 1][fin],
-                               atol=ATOL, rtol=0)     # the prefill's own row
-    # position by position to the sequence's end (the ring turns twice),
-    # logits against the reference's full forward
-    step = jax.jit(lambda x, p, pool: decode_ops.decode_step_block(
-        params["transformer"], x, p, pool, tables, cfg=TCFG,
-        key_mask=key_mask, active=active))
-    step_pool = pool
-    for pos in range(t0, DIMS.seq_len - 1):
-        p = jnp.full((b,), pos, jnp.int32)
-        x = D.decode_token_embed(params, CFG, jnp.asarray(sequences[:, pos]),
-                                 p)
-        h_tok, step_pool, load = step(x, p, step_pool)
-        assert load.shape == (0,)               # no routed layer
-        logits = np.asarray(D.to_logits(params, h_tok, CFG))
-        logits = np.where(forbidden[pos], -np.inf, logits)
-        fin = np.isfinite(ref_logits[:, pos])
-        np.testing.assert_allclose(logits[fin], ref_logits[:, pos][fin],
-                                   atol=ATOL, rtol=0)
-
-
-def _step_at(params, seqs, positions):
-    """One decode step with slot i at ``positions[i]`` of ``seqs[i]``:
-    the rows before it in its pages of both pools (its ring as far as it
-    has turned), its state after that many tokens -> the logits
-    (forbidden ones -inf)."""
-    _, pool, tables = _prefilled_pools(params, seqs, int(positions.max()),
-                                       positions)
-    p = jnp.asarray(positions)
-    b = len(positions)
-    x = D.decode_token_embed(
-        params, CFG, jnp.asarray(seqs[np.arange(b), positions]), p)
-    _step_at.plan = decode_ops.block_view_plan(
-        TCFG, params["transformer"], pool, b, DIMS.seq_len)
-    h_tok, _, _ = jax.jit(lambda x, p, pool: decode_ops.decode_step_block(
-        params["transformer"], x, p, pool, tables, cfg=TCFG,
-        key_mask=jnp.ones((b, DIMS.seq_len), bool),
-        active=jnp.ones((b,), bool)))(x, p, pool)
-    return np.where(np.asarray(D.logits_mask(CFG))[positions], -np.inf,
-                    np.asarray(D.to_logits(params, h_tok, CFG)))
-
-
-@pytest.mark.parametrize("at", [0, 1, 3])
-def test_slots_up_to_each_width_profile_match_the_full_forward(
-        params, sequences, ref_logits, profile_positions, reads_at, at,
-        release_programs, four_slots_a_group, switch_placement):
-    """ISSUE 38: the reads of the pool whose rows lie in order stop at
-    the rows that are written: the full layer's differential
-    read and the cross layers' of the SAME table at the full layer's
-    index, each with a query of its own (a window layer reads its ring
-    whole, in slot order; the positions leave some rings unwrapped and
-    wrap others).
-    Sixteen slots in shuffled phase order whose positions need profile
-    ``at`` of the table's staircases (in every group a slot AT its
-    width's edge, one a row before it, one a row after the edge of the
-    group before; a slot at 1; the last row at the last profile):
-    one step by the rule gives the reference's full-forward logits at
-    every slot's own position, and the greedy tokens of the same step at
-    full width; the profile before (the planted fault) fails the same
-    comparison."""
-    width = WIDTH
-    assert decode_ops.view_slot_groups(16, width, (PS, 2 * 8),
-                                       jnp.float32) == 4
-    profiles = decode_ops.view_profiles(4, width)
-    assert len(profiles) == 4
-    positions = profile_positions(profiles[at], PS, DIMS.seq_len - 1)
-    # (a state after no token at all is no prompt's: the slot parked at 0
-    # is the classic pool's case)
-    positions = np.where(positions == 0, 2, positions)
-    assert int(decode_ops.view_profile_index(
-        np.sort(positions), 4, width, PS, xp=np)) == at
-    assert (positions < RING * PS).sum() >= 2 <= (
-        positions > RING * PS).sum()
-    rows = np.arange(len(positions)) % len(sequences)
-    seqs = sequences[rows]
-    want = ref_logits[rows, positions]
-    got = _step_at(params, seqs, positions)
-    _close(got, want)
-    # where the switch stands (``block_view_plan``): one around the span
-    # of scans that read the ordered pool, every reader at the profile;
-    # or one a scanned read, a run of one layer whole
-    plan = _step_at.plan
-    readers = [r for r in T.layer_runs(BLK, TCFG.depth)
-               if r.kind.pool == "full"]
-    lone = sum(r.count for r in readers if r.count == 1)
-    assert lone and plan.groups == 4
-    if switch_placement == "one_switch":
-        assert plan.span is not None and plan.whole == 0
-    else:
-        assert plan.span is None and plan.whole == lone
-    assert plan.by_rule + plan.whole == sum(r.count for r in readers)
-    with reads_at("full_width"):
-        whole = _step_at(params, seqs, positions)
-    _close(whole, want)
-    np.testing.assert_array_equal(got.argmax(-1), whole.argmax(-1))
-    if at:
-        with reads_at("too_narrow"):
-            cut = _step_at(params, seqs, positions)
-        with pytest.raises(AssertionError):
-            _close(cut, want)
-
+# -- (ii) the state and the full layer's pages beside the pools --------------
 
 def test_an_inactive_slots_state_is_not_advanced(params, sequences):
-    _, pool, tables = _prefilled_pools(params, sequences, 7)
+    _, pool, tables = TOY.prefilled_pool(params, sequences, 7)
     p = jnp.full((2,), 7, jnp.int32)
     x = D.decode_token_embed(params, CFG, jnp.asarray(sequences[:, 7]), p)
     _, after, _ = decode_ops.decode_step_block(
@@ -379,7 +202,7 @@ def test_an_inactive_slots_state_is_not_advanced(params, sequences):
 def test_every_cross_layer_reads_the_full_layers_pages(params, sequences):
     """Change one page of the full pool's ONE layer: the full layer and
     both cross layers move, and no layer before the full one."""
-    _, pool, tables = _prefilled_pools(params, sequences, 14)
+    _, pool, tables = TOY.prefilled_pool(params, sequences, 14)
     p = jnp.full((2,), 14, jnp.int32)
     x = D.decode_token_embed(params, CFG, jnp.asarray(sequences[:, 14]), p)
     key_mask = jnp.ones((2, DIMS.seq_len), bool)
@@ -418,7 +241,7 @@ def test_every_cross_layer_reads_the_full_layers_pages(params, sequences):
             assert same, i          # nothing before the full layer reads it
 
 
-# -- (iv) the differential read over gathered rows -----------------------------
+# -- (iv) the differential read over gathered rows ----------------------------
 
 @pytest.mark.parametrize("window", [False, True])
 def test_differential_read_over_rows_equals_the_materialised_read(window):
@@ -457,79 +280,17 @@ def test_differential_read_over_rows_equals_the_materialised_read(window):
                                atol=2e-6)
 
 
-# -- (v) the engine: state beside the pools ------------------------------------
-
-GREEDY = SamplingParams(filter_thres=1.0)
-
-
-def _serve(params, reqs, **kw):
-    queue = RequestQueue(max_depth=8)
-    kw.setdefault("num_slots", 2)
-    engine = Engine(params, CFG, queue, chunk_steps=8, kv="paged",
-                    page_size=PS, **kw)
-    handles = [queue.submit(dataclasses.replace(r)) for r in reqs]
-    engine.run_until_idle()
-    out = []
-    for r, h in zip(reqs, handles):
-        res = h.result(timeout=5)
-        assert res.status == "ok"
-        out.append(list(np.asarray(res.text_tokens))
-                   + list(np.asarray(res.tokens)))
-        assert out[-1][:len(r.codes)] == list(r.codes)
-    return engine, out
-
-
-REQS = [Request(codes=(3, 7, 9), seed=11, sampling=GREEDY),
-        Request(codes=tuple(range(1, 11)), seed=2, sampling=GREEDY),
-        Request(codes=(6, 6, 1, 2, 3, 9, 4), seed=3, sampling=GREEDY)]
-
-
-def test_engine_serves_the_reference_s_tokens_in_chunks_of_8(params):
-    """Through the engine: prompts of 3 and 10 tokens admitted in one
-    bucket (each row's state at its own length), the whole-page write
-    into both pools, the ring's pages reused, a slot reused by the third
-    request, the fused chunks. Greedy tokens are the reference's best at
-    every served position (gap 0 but for float32 near-ties)."""
-    engine, seqs = _serve(params, REQS)
-    lens = [len(r.codes) for r in REQS]
-    assert all(len(s) == DIMS.seq_len for s in seqs)
-    gaps, served = FAMILY.reference.served_gaps(SEED, DIMS, jnp.float32,
-                                                seqs, lens)
-    assert float(np.asarray(gaps)[np.asarray(served)].max()) < 1e-4
-    st = engine.stats()
-    assert engine.decode_traces == 1
-    assert engine.alloc.in_use == 0 and engine.window.alloc.in_use == 0
-    assert st["window_pages_reused"] > 0
-    assert st["state_bytes"] == 4 * 2 * (DIMS.d_inner * DIMS.d_state * 4
-                                         + 3 * DIMS.d_inner * 4)
-    assert "moe_picks" not in st
-    assert st["kv_hbm_bytes"] == KV.modeled_kv_bytes(
-        TCFG, kv="paged", num_slots=2, total_len=DIMS.seq_len,
-        page_size=PS)
-    # the full pool's one layer is read by three layers, the rings by
-    # three, the state by four
-    row = 2 * (2 * PS * 8) * 4
-    assert st["kv_read_bytes_per_token"] == (
-        3 * WIDTH + 3 * RING) * row + st["state_bytes"] // 2
-
-
-def test_a_reused_slot_gives_the_tokens_of_a_fresh_engine(params):
-    """One slot, three requests one after the other: the second and third
-    start in a slot whose state their predecessor left."""
-    _, shared = _serve(params, REQS, num_slots=1)
-    for i, req in enumerate(REQS):
-        _, alone = _serve(params, [req], num_slots=1)
-        assert shared[i] == alone[0], i
-
+# -- (iii) the engine: state beside the pools --------------------------------
 
 def test_a_row_joining_through_the_small_group_leaves_the_others_state(
-        params):
+        params, served):
     """Six slots, so an admission takes 4 rows or 6
     (``scheduler.prefill_groups``): two requests start in the 4-row
     group, a third joins them mid-image in it (its unused rows' state
     is dropped, not written over a running slot's), then six at once
-    take the whole group. Every stream is the one a fresh engine gives."""
-    alone = [_serve(params, [r], num_slots=1)[1][0] for r in REQS]
+    take the whole group. Every stream is the one that one slot gives the
+    request."""
+    alone = served.one_slot().seqs
     queue = RequestQueue(max_depth=16)
     bucket = CFG.text_seq_len       # one bucket: a burst is one group
     engine = Engine(params, CFG, queue, chunk_steps=8, kv="paged",
@@ -548,86 +309,6 @@ def test_a_row_joining_through_the_small_group_leaves_the_others_state(
         res = h.result(timeout=5)
         assert list(np.asarray(res.text_tokens)) \
             + list(np.asarray(res.tokens)) == want
-
-
-def test_an_evicted_request_replays_to_the_same_tokens(params):
-    reqs = REQS[:2]
-    roomy, want = _serve(params, reqs)
-    tight, got = _serve(params, reqs, num_pages=WIDTH + 4)
-    assert got == want and tight.evicted > 0 and roomy.evicted == 0
-    assert tight.alloc.in_use == 0 and tight.window.alloc.in_use == 0
-
-
-# -- (vi) every path that cannot run the block refuses it ----------------------
-
-def _engine(params, **kw):
-    kw.setdefault("kv", "paged")
-    return Engine(params, CFG, RequestQueue(max_depth=2), num_slots=1, **kw)
-
-
-def _mesh_engine(params):
-    from dalle_pytorch_tpu.serve.mesh_engine import MeshEngine
-    return MeshEngine(params, CFG, RequestQueue(max_depth=2),
-                      devices=jax.devices()[:2], num_slots=1, kv="paged")
-
-
-REFUSED = {
-    "kv_dense": lambda p: _engine(p, kv="dense"),
-    "paged_attn_kernel": lambda p: _engine(p, paged_attn="kernel",
-                                           page_size=8),
-    "speculative": lambda p: _engine(p, speculative=2),
-    "sparse_reads": lambda p: _engine(p, sparse_reads=True),
-    "quantize_cache": lambda p: _engine(p, quantize_cache=True),
-    "prefix_cache": lambda p: _engine(p, prefix_cache=True),
-    "mesh_engine": _mesh_engine,
-    "quantize_int8": lambda p: D.quantize_for_decode(p),
-    "generate_images": lambda p: D.generate_images(
-        p, None, jnp.ones((1, 4), jnp.int32), cfg=CFG,
-        rng=jax.random.PRNGKey(0)),
-    "train": lambda p: D.dalle_apply(
-        p, jnp.ones((1, 10), jnp.int32), jnp.ones((1, 25), jnp.int32),
-        cfg=CFG, train=True, return_loss=True),
-    "reversible": lambda p: dataclasses.replace(CFG, reversible=True)
-    .transformer,
-    "sparse_attn": lambda p: dataclasses.replace(CFG, sparse_attn=True)
-    .transformer,
-    "attn_impl_flash": lambda p: dataclasses.replace(CFG, attn_impl="flash")
-    .transformer,
-    "remat": lambda p: dataclasses.replace(CFG, remat="full").transformer,
-    "capacity_moe": lambda p: dataclasses.replace(CFG, moe_experts=4)
-    .transformer,
-    "dense_cache": lambda p: decode_ops.init_cache(TCFG, 1, 8),
-    "dense_decode_step": lambda p: decode_ops.decode_step(
-        p["transformer"], jnp.zeros((1, 32)), 3, {}, cfg=TCFG,
-        key_mask=jnp.ones((1, 8), bool)),
-    "speculative_loop": lambda p: decode_ops.decode_loop_spec_paged(
-        p["transformer"], None, None, None, None, {}, None, cfg=TCFG,
-        draft_cfg=None, key_mask=None, total_len=8, steps=1, k=2,
-        embed_fn=None, sample_fn=None),
-    "kernel_loop": lambda p: decode_ops.decode_loop_paged(
-        p["transformer"], None, None, None, {}, None, cfg=TCFG,
-        key_mask=None, total_len=8, steps=1, embed_fn=None, sample_fn=None,
-        attn_impl="kernel"),
-    "int8_pool": lambda p: KV.init_page_pool(TCFG, 4, PS, quantized=True),
-}
-
-
-@pytest.mark.parametrize("option", sorted(REFUSED))
-def test_every_refused_option_raises_the_one_typed_error(params, option):
-    with pytest.raises(T.BlockOptionError) as e:
-        REFUSED[option](params)
-    assert e.value.block == BLK.name == "ssm_hybrid" and e.value.option
-    assert BLK.name in str(e.value) and e.value.option in str(e.value)
-
-
-@pytest.mark.parametrize("call", ["export", "import"])
-def test_migration_refuses_the_block_and_falls_back_to_replay(params, call):
-    engine = _engine(params, page_size=PS)
-    with pytest.raises(MigrationError, match="ssm_hybrid.*export/import") \
-            as e:
-        engine.export_slot(0) if call == "export" \
-            else engine.import_slot({"weights_version": "0"})
-    assert e.value.reason == "block"
 
 
 def test_a_block_whose_readers_have_no_source_is_refused():
