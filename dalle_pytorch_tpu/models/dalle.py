@@ -88,7 +88,8 @@ class DALLEConfig:
     # positions inside the block, so no position tables; an untied,
     # bias-free head behind an RMSNorm. Served, not trained.
     block: Optional[Union[T.LatentMoEBlock, T.WindowGQABlock,
-                          T.SSMHybridBlock, T.ShortConvGQABlock]] = None
+                          T.SSMHybridBlock, T.ShortConvGQABlock,
+                          T.DeltaGQABlock]] = None
 
     @property
     def image_seq_len(self) -> int:

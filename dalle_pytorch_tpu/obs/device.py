@@ -53,6 +53,11 @@ SCOPES = (
                         # projections
     "conv.mix",         # its two gates, the taps, and the tail's read, roll
                         # and store
+    "delta.proj",       # a gated delta-rule layer's two input projections
+                        # and its output projection
+    "delta.rule",       # its convolution and tail, the norms and gates, the
+                        # state's read, decay, update, readout and write,
+                        # and the gated norm
     "head",             # logits
     "sample",           # filtering and sampling
     "loss",             # cross-entropy

@@ -17,8 +17,8 @@ them, the pairs whose expert is held elsewhere lie in no group and add
 nothing, and the load says how many picks were held. Nothing stands in
 for the absent chips: their part of the sum is left out.
 
-WHICH grouped products run, a call's shapes decide
-(``kernel_hidden_tile``). A decode step's rows (at most ``KERNEL_ROWS``
+WHICH grouped products run, the shapes of a step of a call's ladder
+decide (``kernel_hidden_tile``). A decode step's rows (at most ``KERNEL_ROWS``
 pair rows, bfloat16, widths of whole lanes) run in the repo's Pallas
 kernel (``expert_products``): its grid visits the (row tile of
 ``ROW_TILE``, group) pairs that share a row and multiplies a group's
@@ -196,15 +196,19 @@ def load_width(blk) -> int:
 
 
 def dropless_init(key: Array, dim: int, blk, dtype=jnp.float32) -> dict:
-    """Router (with its selection bias) over every expert, the HELD
-    experts' stacked SiLU-gated units (gate and up side by side in
-    ``w_in``) and the shared unit (none where ``shared_hidden`` is 0)."""
+    """Router (with its selection bias, where the block's scores are
+    sigmoids) over every expert, the HELD experts' stacked SiLU-gated
+    units (gate and up side by side in ``w_in``), the shared unit (none
+    where ``shared_hidden`` is 0) and, where the block gates it, the row
+    that scores its gate."""
     k_r, k_in, k_out, k_s = jax.random.split(key, 4)
     e, he = blk.num_experts, blk.expert_hidden
     held = blk.experts_held
+    router = {"w": core.uniform_fan_in(k_r, (dim, e), dim, dtype)}
+    if blk.route_scores == "sigmoid":
+        router["bias"] = jnp.zeros((e,), jnp.float32)
     out = {
-        "router": {"w": core.uniform_fan_in(k_r, (dim, e), dim, dtype),
-                   "bias": jnp.zeros((e,), jnp.float32)},
+        "router": router,
         "experts": {
             "w_in": core.uniform_fan_in(k_in, (held, dim, 2 * he), dim,
                                         dtype),
@@ -213,19 +217,29 @@ def dropless_init(key: Array, dim: int, blk, dtype=jnp.float32) -> dict:
     }
     if blk.shared_hidden:
         out["shared"] = core.swiglu_init(k_s, dim, blk.shared_hidden, dtype)
+        if blk.shared_gate:
+            out["shared_gate"] = core.linear_init(
+                jax.random.fold_in(k_s, 1), dim, 1, bias=False, dtype=dtype)
     return out
 
 
 @jax.named_scope("moe.route")
-def route(router: dict, x: Array, k: int, scale: float, eps: float = 0.0):
+def route(router: dict, x: Array, k: int, scale: float, eps: float = 0.0,
+          scores: str = "sigmoid"):
     """x (t, dim) -> (picks (t, k) expert ids, weights (t, k) f32).
     Scores are sigmoids in f32; the bias moves the SELECTION only, the
     weights are the picked scores over their sum (plus ``eps`` where a
-    block's equations have one), times ``scale``."""
-    scores = jax.nn.sigmoid(jnp.dot(x.astype(jnp.float32),
-                                    router["w"].astype(jnp.float32)))
-    _, picks = lax.top_k(scores + router["bias"].astype(jnp.float32), k)
-    picked = jnp.take_along_axis(scores, picks, axis=-1)
+    block's equations have one), times ``scale``. With ``scores``
+    ``"softmax"`` the scores are a softmax over all the experts in f32
+    and the largest are picked as they are (such a router holds no
+    bias)."""
+    logits = jnp.dot(x.astype(jnp.float32), router["w"].astype(jnp.float32))
+    if scores == "softmax":
+        picked, picks = lax.top_k(jax.nn.softmax(logits, axis=-1), k)
+    else:
+        marks = jax.nn.sigmoid(logits)
+        _, picks = lax.top_k(marks + router["bias"].astype(jnp.float32), k)
+        picked = jnp.take_along_axis(marks, picks, axis=-1)
     total = jnp.sum(picked, axis=-1, keepdims=True)
     if eps:
         total = total + eps
@@ -450,8 +464,12 @@ def dropless_experts(experts: dict, x: Array, picks: Array,
     back to token order stay outside it. Where the ladder is one step
     (every expert held, or few pairs) there is no switch.
 
-    WHICH PRODUCTS RUN is decided a call, from its shapes
-    (``kernel_hidden_tile``), for every step of its ladder: a decode
+    WHICH PRODUCTS RUN is decided a step of the call's ladder, from the
+    rows that step hands them (``kernel_hidden_tile``; a call without a
+    ladder is its one step, and every ladder of PRs 41-44 lies on one side
+    of ``KERNEL_ROWS``; 640 pairs of which a quarter is held run their
+    first step, 512 rows, in the kernel and the step of every pair on the
+    compiler's product): a decode
     step's pair rows (at most ``KERNEL_ROWS``) in bfloat16 at widths that
     fill the lanes run in the repo's kernel (``expert_products``), which
     visits the (row tile, group) pairs that share a row and multiplies a
@@ -497,10 +515,15 @@ def dropless_experts(experts: dict, x: Array, picks: Array,
     e = experts["w_in"].shape[-3]
     stacked = "layer" in experts
     ladder = (t * k,) if first is None else row_ladder(t * k, e, num_experts)
-    # the call's shapes decide its products, for every step of its ladder
-    hidden_tile = kernel_hidden_tile(
-        t * k, x.shape[-1], experts["w_out"].shape[-2], x.dtype) \
-        if experts["w_in"].dtype == x.dtype else None
+    # a step's shapes decide its products: the rows it hands them
+    hidden = experts["w_out"].shape[-2]
+    hidden_tiles = {r: kernel_hidden_tile(r, x.shape[-1], hidden, x.dtype)
+                    if experts["w_in"].dtype == x.dtype else None
+                    for r in ladder}
+    # a call without a ladder, off the kernel, whose rows the compiler's
+    # products take a tile at a time
+    in_tiles = len(ladder) == 1 and not hidden_tiles[t * k] \
+        and row_tiles(t * k) > 1
     with jax.named_scope("moe.route"):
         flat = picks.reshape(-1)
         if first is not None:
@@ -510,16 +533,15 @@ def dropless_experts(experts: dict, x: Array, picks: Array,
             flat = jnp.where(here, flat - first, e)
         order = jnp.argsort(flat, stable=True)      # pairs, by expert
         sizes = jnp.zeros((e,), jnp.int32).at[flat].add(1)
-        # (tiles, E): the kernel's visits; or the tiles of a call without
-        # a ladder whose rows the compiler's products take a tile at a time
-        tiled = tile_sizes(sizes, t * k) if hidden_tile or (
-            len(ladder) == 1 and row_tiles(t * k) > 1) else None
+        # (tiles, E): the kernel's visits, or the compiler's tiles
+        tiled = tile_sizes(sizes, t * k) \
+            if in_tiles or any(hidden_tiles.values()) else None
 
     def compiler_products(rows):
         """``lax.ragged_dot`` twice over the rows, a row tile at a time."""
         w_in, w_out = experts["w_in"], experts["w_out"]
         with jax.named_scope("moe.route"):
-            groups = [sizes] if tiled is None else list(tiled)
+            groups = list(tiled) if in_tiles else [sizes]
             if stacked:
                 w_in = w_in.reshape((-1,) + w_in.shape[2:])
                 w_out = w_out.reshape((-1,) + w_out.shape[2:])
@@ -529,14 +551,14 @@ def dropless_experts(experts: dict, x: Array, picks: Array,
         with jax.named_scope("moe.experts"):
             outs = []
             for j, g in enumerate(groups):
-                tile = rows if tiled is None else \
-                    rows[j * ROW_TILE:(j + 1) * ROW_TILE]
+                tile = rows[j * ROW_TILE:(j + 1) * ROW_TILE] if in_tiles \
+                    else rows
                 gate, up = jnp.split(
                     lax.ragged_dot(tile, w_in.astype(x.dtype), g), 2,
                     axis=-1)
                 outs.append(lax.ragged_dot(jax.nn.silu(gate) * up,
                                            w_out.astype(x.dtype), g))
-            return outs[0] if tiled is None else jnp.concatenate(outs)
+            return jnp.concatenate(outs) if in_tiles else outs[0]
 
     def products(r: int):
         """The first ``r`` sorted pairs through their experts -> (t * k,
@@ -544,13 +566,13 @@ def dropless_experts(experts: dict, x: Array, picks: Array,
         with jax.named_scope("moe.route"):
             rows = jnp.take(x, order[:r] // k, axis=0)      # (r, dim)
             pair_weights = jnp.take(weights.reshape(-1), order[:r])
-        if hidden_tile:
+        if hidden_tiles[r]:
             w_in, w_out = experts["w_in"], experts["w_out"]
             out = expert_products(
                 rows, w_in.reshape((-1,) + w_in.shape[-2:]),
                 w_out.reshape((-1,) + w_out.shape[-2:]),
                 experts["layer"] if stacked else 0, sizes,
-                tiled[:r // ROW_TILE], hidden_tile)
+                tiled[:r // ROW_TILE], hidden_tiles[r])
         else:
             out = compiler_products(rows)
         with jax.named_scope("moe.experts"):
@@ -579,10 +601,17 @@ def dropless_experts(experts: dict, x: Array, picks: Array,
         out = jnp.take(out, jnp.argsort(order), axis=0).reshape(t, k, -1)
         out = jnp.sum(out, axis=1)
         # one tile reads each touched group once; so does the kernel where
-        # a grid step takes a whole expert
-        whole = hidden_tile == experts["w_out"].shape[-2]
-        reads = jnp.sum((sizes if tiled is None or whole else tiled) > 0
-                        ).astype(jnp.int32)
+        # a grid step takes a whole expert. A step (of the ladder, or a
+        # call in tiles) that takes less reads a group a tile it lies in
+        once = jnp.sum(sizes > 0).astype(jnp.int32)
+        again = [in_tiles or hidden_tiles[r] not in (None, hidden)
+                 for r in ladder]
+        if not any(again):
+            reads = once
+        else:
+            visits = jnp.sum(tiled > 0).astype(jnp.int32)
+            reads = visits if all(again) else jnp.where(
+                jnp.asarray(again)[step], visits, once)
     return out.astype(x.dtype), sizes, handed, reads
 
 
@@ -595,14 +624,18 @@ def dropless_apply(params: dict, x: Array, blk):
     lead = x.shape[:-1]
     xt = x.reshape(-1, x.shape[-1])
     picks, weights = route(params["router"], xt, blk.experts_per_token,
-                           blk.routed_scale, blk.route_eps)
+                           blk.routed_scale, blk.route_eps, blk.route_scores)
     whole = holds_all(blk)
     out, sizes, handed, reads = dropless_experts(
         params["experts"], xt, picks, weights,
         None if whole else blk.first_expert, blk.num_experts)
     if "shared" in params:
         with jax.named_scope("moe.shared"):
-            out = out + core.swiglu(params["shared"], xt)
+            shared = core.swiglu(params["shared"], xt)
+            if "shared_gate" in params:
+                shared = shared * jax.nn.sigmoid(
+                    core.linear(params["shared_gate"], xt))
+            out = out + shared
     held = jnp.sum(sizes)
     load = [held if whole else jnp.int32(picks.size),
             jnp.sum(sizes > 0).astype(jnp.int32), jnp.max(sizes), reads]

@@ -36,6 +36,7 @@ from jax import lax
 
 from dalle_pytorch_tpu.ops import attention as attn_ops
 from dalle_pytorch_tpu.ops import core, sparse
+from dalle_pytorch_tpu.ops import deltanet as delta_ops
 from dalle_pytorch_tpu.ops import shortconv as conv_ops
 from dalle_pytorch_tpu.ops import ssm as ssm_ops
 
@@ -65,9 +66,10 @@ class LayerKind:
     keys and values and caches them), ``"cross"`` (attends the rows that
     an earlier full layer cached, and caches nothing), ``"ssm"`` (a
     state-space layer: a recurrent state a slot, no rows), ``"conv"`` (a
-    gated short convolution: its tail a slot, no rows) or ``"gmu"``
-    (reads an earlier state-space layer's output of the same token, and
-    caches nothing)."""
+    gated short convolution: its tail a slot, no rows), ``"delta"`` (a
+    gated delta-rule layer: a matrix state a value head and the
+    convolution's tail a slot, no rows) or ``"gmu"`` (reads an earlier
+    state-space layer's output of the same token, and caches nothing)."""
     moe: bool
     full: bool
     mixer: str = "attn"
@@ -76,7 +78,7 @@ class LayerKind:
     def pool(self) -> Optional[str]:
         """The cache this layer reads: ``"full"``, ``"window"``,
         ``"state"`` or None."""
-        if self.mixer in ("ssm", "conv"):
+        if self.mixer in ("ssm", "conv", "delta"):
             return "state"
         if self.mixer == "gmu":
             return None
@@ -85,7 +87,7 @@ class LayerKind:
     @property
     def stores(self) -> bool:
         """Whether the layer writes the cache it reads."""
-        return self.mixer in ("attn", "ssm", "conv")
+        return self.mixer in ("attn", "ssm", "conv", "delta")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -157,6 +159,8 @@ class DescribedBlock:
     layer_norms = False     # RMSNorms (a gain alone), not LayerNorms
     sink = False    # no learned logit beside a window softmax's rows
     route_eps = 0.0     # nothing beside the picked scores' sum (ops/moe.py)
+    route_scores = "sigmoid"    # the router's scores, with a selection bias
+    shared_gate = False     # the shared unit's output enters as it is
     state_mixer = "ssm"     # the recurrent mixer whose state a slot carries
 
     @staticmethod
@@ -286,6 +290,20 @@ class LatentMoEBlock(DescribedBlock):
         return -(-self.entry_width // 128) * 128
 
 
+def _check_share_and_rotary(blk) -> None:
+    """What a block that holds a share of its experts and turns a part
+    of a head owes its fields."""
+    if not 0 <= blk.first_expert <= blk.first_expert + blk.experts_held \
+            <= blk.num_experts or blk.experts_held < 1:
+        raise ValueError(
+            f"experts {blk.first_expert}..{blk.first_expert} + "
+            f"{blk.experts_held} are not a share of {blk.num_experts}")
+    turned = blk.rotary_dim or blk.head_dim
+    if turned % 2 or not 0 < turned <= blk.head_dim:
+        raise ValueError(f"rotary_dim {turned}: an even number of a "
+                         f"head's {blk.head_dim}")
+
+
 @dataclasses.dataclass(frozen=True)
 class WindowGQABlock(DescribedBlock):
     """The second described block: grouped-query attention
@@ -361,17 +379,7 @@ class WindowGQABlock(DescribedBlock):
         if bad:
             raise ValueError(f"layer_types holds {sorted(bad)}: a layer is "
                              f"'sliding' or 'full'")
-        if not 0 <= self.first_expert <= self.first_expert \
-                + self.experts_held <= self.num_experts \
-                or self.experts_held < 1:
-            raise ValueError(
-                f"experts {self.first_expert}..{self.first_expert} + "
-                f"{self.experts_held} are not a share of "
-                f"{self.num_experts}")
-        turned = self.rotary_dim or self.head_dim
-        if turned % 2 or not 0 < turned <= self.head_dim:
-            raise ValueError(f"rotary_dim {turned}: an even number of a "
-                             f"head's {self.head_dim}")
+        _check_share_and_rotary(self)
 
     @property
     def score_dim(self) -> int:
@@ -644,6 +652,128 @@ class ShortConvGQABlock(DescribedBlock):
 
 
 @dataclasses.dataclass(frozen=True)
+class DeltaGQABlock(DescribedBlock):
+    """The fifth described block: ``layer_types`` says, layer by layer of
+    the layers run here, whether a layer's mixer is a gated delta-rule
+    layer (``"delta"``, ops/deltanet.py: ``key_heads`` key heads of
+    ``key_head_dim`` under ``value_heads`` value heads of
+    ``value_head_dim``; a slot carries a float32 matrix state a value head
+    and the last ``conv_taps - 1`` inputs of its convolution, and caches
+    no row) or gated grouped-query attention over every earlier row
+    (``"full"``: ``TransformerConfig.heads`` query heads read ``kv_heads``
+    key/value heads of ``head_dim``, RMSNorms over each query and key
+    head, rotary positions on a head's first ``rotary_dim`` numbers at
+    ``rope_theta``, an output gate ``o * sigmoid(W_g x)``, no bias; a
+    token caches one K and one V row a layer in the one page pool). Two
+    RMSNorms a layer, one before each branch. EVERY layer's feed-forward
+    is routed: ``num_experts`` SiLU-gated experts scored by a softmax
+    over all of them in float32, the ``experts_per_token`` largest picked
+    (no selection bias), their scores over their sum, of which the chip
+    HOLDS ``experts_held`` from ``first_expert`` on (ops/moe.py: the picks
+    that fall on other experts take no part); beside them a shared unit
+    of ``shared_hidden`` whose output enters times ``sigmoid(w_s . x)``.
+    An untied head behind a final RMSNorm; the rotary positions of the
+    full layers are the only positions.
+
+    The stack runs at period 1 (``stack_scans``: runs of layers alike),
+    whatever the published ratio of the two layer types. ``dim_head`` and
+    ``ff_mult`` of the configuration are not read.
+
+    What ``ops.attention.gqa_*`` and ``ops.moe`` read of a block beyond
+    the fields is fixed here (the class constants): the combination that
+    this block is, and no other, is what its tests hold."""
+    layer_types: Tuple[str, ...] = ("delta", "delta", "delta", "full")
+    kv_heads: int = 2
+    head_dim: int = 256
+    rotary_dim: Optional[int] = 64
+    rope_theta: float = 1e7
+    norm_eps: float = 1e-6
+    key_heads: int = 16
+    value_heads: int = 32
+    key_head_dim: int = 128
+    value_head_dim: int = 128
+    conv_taps: int = 4
+    num_experts: int = 512
+    experts_per_token: int = 10
+    expert_hidden: int = 512
+    shared_hidden: int = 512
+    experts_held: int = 512
+    first_expert: int = 0
+    name: str = "delta_gqa_moe"
+    state_mixer = "delta"
+    route_scores = "softmax"        # no selection bias, nothing beside the sum
+    shared_gate = True              # sigmoid(w_s . x) on the shared unit
+    routed_scale = 1.0
+    dense_layers = 0                # every layer is routed
+    dense_hidden = 0
+    embed_scale = 1.0
+    qk_norm = True
+    out_gate = True
+    sandwich_norms = False          # no second norm a branch
+    value_scale = 1.0
+    v_head_dim = None               # a value head is a key head's size
+
+    def __post_init__(self):
+        bad = set(self.layer_types) - {"delta", "full"}
+        if bad:
+            raise ValueError(f"layer_types holds {sorted(bad)}: a layer is "
+                             f"'delta' or 'full'")
+        _check_share_and_rotary(self)
+        if self.conv_taps < 2 or self.value_heads % self.key_heads:
+            raise ValueError(
+                f"conv_taps {self.conv_taps} leaves no tail, or the "
+                f"{self.value_heads} value heads are no multiple of the "
+                f"{self.key_heads} key heads")
+        if self.norm_eps != delta_ops.NORM_EPS:
+            raise ValueError(
+                f"norm_eps {self.norm_eps}: the delta-rule layer's gated "
+                f"norm is written at {delta_ops.NORM_EPS}")
+
+    @property
+    def score_dim(self) -> int:
+        return self.head_dim
+
+    @property
+    def page_row_width(self) -> int:
+        return self.kv_heads * self.head_dim
+
+    def layer_kinds(self, depth: int) -> Tuple[LayerKind, ...]:
+        if len(self.layer_types) != depth:
+            raise ValueError(f"layer_types names {len(self.layer_types)} "
+                             f"layers, depth is {depth}")
+        return tuple(LayerKind(moe=True, full=t == "full",
+                               mixer="attn" if t == "full" else "delta")
+                     for t in self.layer_types)
+
+    @staticmethod
+    def mixer_of(kind: LayerKind) -> str:
+        return "gqa" if kind.mixer == "attn" else "delta"
+
+    @staticmethod
+    def stack_of(kind: LayerKind) -> str:
+        """``"moe"`` holds the delta-rule layers, ``"moe_full"`` the
+        attention layers."""
+        return "moe_full" if kind.full else "moe"
+
+    def state_layout(self, dim: int) -> dict:
+        """The matrix state of every value head, float32 whatever the
+        pool's type (the minor dimension whole lanes at the published
+        sizes), and the convolution's tail, in the pool's type."""
+        return {"delta_state": ((self.value_heads, self.key_head_dim,
+                                 self.value_head_dim), 4),
+                "delta_conv": ((self.conv_taps - 1,
+                                2 * self.key_heads * self.key_head_dim
+                                + self.value_heads * self.value_head_dim),
+                               None)}
+
+    def kv_heads_of(self, full: bool) -> int:
+        return self.kv_heads
+
+    def rope_theta_of(self, full: bool) -> float:
+        return self.rope_theta
+
+
+@dataclasses.dataclass(frozen=True)
 class TransformerConfig:
     dim: int
     depth: int
@@ -682,7 +812,7 @@ class TransformerConfig:
     moe_capacity: float = 1.25
     # a described block in place of the classic one
     block: Optional[Union[LatentMoEBlock, WindowGQABlock, SSMHybridBlock,
-                          ShortConvGQABlock]] = None
+                          ShortConvGQABlock, DeltaGQABlock]] = None
 
     def __post_init__(self):
         blk = self.block
@@ -780,6 +910,8 @@ def block_layer_init(key: Array, cfg: TransformerConfig, kind: LayerKind,
         attn = ssm_ops.gmu_init(k_attn, cfg.dim, blk, dtype)
     elif mixer == "conv":
         attn = conv_ops.shortconv_init(k_attn, cfg.dim, blk, dtype)
+    elif mixer == "delta":
+        attn = delta_ops.delta_init(k_attn, cfg.dim, blk, dtype)
     else:
         attn = attn_ops.diff_init(k_attn, cfg.dim, cfg.heads, blk,
                                   blk.lam_init(layer), dtype,
@@ -824,6 +956,9 @@ def block_name_of(params: dict) -> str:
     that has parameters and no configuration to name in its refusal."""
     if not any(name.startswith(("dense", "moe")) for name in params):
         return SSMHybridBlock.name      # ``stack_of``: stacks by mixer
+    if any("a_log" in stack["attn"] and "ba" in stack["attn"]
+           for stack in params.values()):
+        return DeltaGQABlock.name
     if any("conv" in stack["attn"] for stack in params.values()):
         return ShortConvGQABlock.name
     attn = next(iter(params.values()))["attn"]
@@ -1008,8 +1143,11 @@ def _mix_ssm(p, hn, positions, read, shared, cfg, run):
 
 
 def _mix_conv(p, hn, positions, read, shared, cfg, run):
-    out, tail = read(p, hn, None)
-    return out, tail, shared
+    """A short convolution (-> out, the new tail) or a delta-rule layer
+    (-> out, the new state and tail): what it carries is all it hands
+    on."""
+    out, carried = read(p, hn, None)
+    return out, carried, shared
 
 
 def _mix_gmu(p, hn, positions, read, shared, cfg, run):
@@ -1031,12 +1169,15 @@ def _mix_diff(p, hn, positions, read, shared, cfg, run):
 
 
 _MIXERS = {"latent": _mix_latent, "gqa": _mix_gqa, "ssm": _mix_ssm,
-           "conv": _mix_conv, "gmu": _mix_gmu, "diff": _mix_diff,
-           "cross": _mix_diff}
+           "conv": _mix_conv, "delta": _mix_conv, "gmu": _mix_gmu,
+           "diff": _mix_diff, "cross": _mix_diff}
+
 
 def state_scope(blk):
     """A state pool's reads and stores in a trace: its block's recurrent
     mixer's scope."""
+    if blk.state_mixer == "delta":
+        return jax.named_scope("delta.rule")
     return jax.named_scope("conv.mix") if blk.state_mixer == "conv" \
         else jax.named_scope("ssm.scan")
 
@@ -1045,8 +1186,10 @@ def state_scope(blk):
 # sequences (p, x, mask) and one token against what its slot carries
 # (p, x, the carried buffers: one alone, or the tuple of several)
 STATE_SEQUENCE = {"ssm": ssm_ops.ssm_sequence,
-                  "conv": conv_ops.shortconv_sequence}
-STATE_STEP = {"ssm": ssm_ops.ssm_step, "conv": conv_ops.shortconv_step}
+                  "conv": conv_ops.shortconv_sequence,
+                  "delta": delta_ops.delta_sequence}
+STATE_STEP = {"ssm": ssm_ops.ssm_step, "conv": conv_ops.shortconv_step,
+              "delta": delta_ops.delta_step}
 
 
 def block_layer(lp: dict, h: Array, shared: dict, positions: Array, read,
@@ -1058,7 +1201,8 @@ def block_layer(lp: dict, h: Array, shared: dict, positions: Array, read,
     the latent block; q and the (k, v) rows for the grouped-query and the
     differential layers; the normed input and None for a state-space
     layer, whose read is the whole recurrence (-> out, m, the new state),
-    and for a short convolution (-> out, the new tail).
+    for a short convolution (-> out, the new tail) and for a delta-rule
+    layer (-> out, the new state and tail).
     ``shared`` is what earlier layers handed on (``DescribedBlock
     .carried``). -> (h, shared, (entry, load)): what to cache (None for a
     layer that caches nothing) and the routed layer's load (zeros for a
@@ -1196,8 +1340,10 @@ def block_apply_full(params: dict, x: Array, cfg: TransformerConfig,
     store to it (``block_stack``): (layers, b, n, row_width), or (layers,
     b, n, kv_heads, head_dim) K and V, or a state-space layer's (layers,
     b, d_state, d_inner) and (layers, b, d_conv - 1, d_inner), or a short
-    convolution's tail (layers, b, conv_taps - 1, dim), after each row's
-    last position; loads (depth, load width))."""
+    convolution's tail (layers, b, conv_taps - 1, dim), or a delta-rule
+    layer's (layers, b, value_heads, dk, dv) and (layers, b, conv_taps -
+    1, its convolution's width), after each row's last position; loads
+    (depth, load width))."""
     blk = cfg.block
     n = x.shape[1]
     positions = jnp.arange(n)

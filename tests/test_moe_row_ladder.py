@@ -185,8 +185,10 @@ class _Blk:
     experts_per_token: int = K
     routed_scale: float = 1.0
     route_eps: float = 0.0
+    route_scores: str = "sigmoid"
     expert_hidden: int = HIDDEN
     shared_hidden: int = 0
+    shared_gate: bool = False
 
 
 @pytest.mark.parametrize("bias,step", [(-1.0, 64), (0.0, 64), (0.06, 128),
@@ -208,6 +210,63 @@ def test_the_load_s_last_entry_is_the_step_taken(bias, step):
     assert out.shape == x.shape
     whole = dataclasses.replace(blk, experts_held=OF, first_expert=0)
     assert moe_ops.load_width(whole) == 4
+
+
+def test_the_ladder_of_a_quarter_held_of_512_top_10():
+    """ISSUE 47's shape, 128 of 512 experts held under top 10: 64 slots'
+    640 pairs, and the 48 slots' that the cell runs (its fallback: the
+    64-row wave's prefill does not fit the chip)."""
+    assert moe_ops.row_ladder(640, 128, 512) == (512, 640)
+    assert moe_ops.row_ladder(480, 128, 512) == (256, 480)
+
+
+@pytest.mark.parametrize("every_pair", [False, True],
+                         ids=["half_the_first_step", "every_pair"])
+@pytest.mark.parametrize("pairs,first_step", [(640, 512), (480, 256)])
+def test_a_step_s_rows_decide_its_products(pairs, first_step, every_pair):
+    """A ladder that straddles ``KERNEL_ROWS``, or ends in a step that is
+    no whole row tiles: the first step's rows run in the repo's kernel,
+    the step of every pair on the compiler's product, under the ladder's
+    conditional; both give the per-pair reference's sums, and the load's
+    fourth entry is each touched expert once either way (a grid step
+    takes a whole expert)."""
+    dim, hidden, e, of, k = 128, 128, 4, 16, 10
+    assert moe_ops.row_ladder(pairs, e, of) == (first_step, pairs)
+    assert moe_ops.kernel_hidden_tile(first_step, dim, hidden,
+                                      jnp.bfloat16) == hidden
+    assert moe_ops.kernel_hidden_tile(pairs, dim, hidden,
+                                      jnp.bfloat16) is None
+    ks = jax.random.split(jax.random.PRNGKey(0), 4)
+    experts = {"w_in": (jax.random.normal(ks[0], (e, dim, 2 * hidden))
+                        / 12).astype(jnp.bfloat16),
+               "w_out": (jax.random.normal(ks[1], (e, hidden, dim))
+                         / 12).astype(jnp.bfloat16)}
+    x = jax.random.normal(ks[2], (pairs // k, dim)).astype(jnp.bfloat16)
+    weights = jax.random.uniform(ks[3], (pairs // k, k), minval=0.1)
+    held, step = (pairs, pairs) if every_pair else (first_step // 2,
+                                                    first_step)
+    rng = np.random.default_rng(3)
+    flat = rng.integers(e, of, pairs)
+    flat[rng.choice(pairs, held, replace=False)] = rng.integers(0, e, held)
+    picks = jnp.asarray(flat.reshape(-1, k), jnp.int32)
+    args = (experts, x, picks, weights)
+    text = str(jax.make_jaxpr(lambda *a: moe_ops.dropless_experts(
+        *a, 0, of))(*args))
+    assert "pallas_call" in text and "ragged_dot_general" in text
+    out, sizes, handed, reads = moe_ops.dropless_experts(*args, 0, of)
+    assert int(handed) == step and int(jnp.sum(sizes)) == held
+    assert int(reads) == int(jnp.sum(sizes > 0))
+    here = picks < e
+    at = jnp.where(here, picks, 0)
+    h = jnp.einsum("td,tkdf->tkf", x.astype(jnp.float32),
+                   experts["w_in"].astype(jnp.float32)[at])
+    gate, up = jnp.split(h, 2, axis=-1)
+    want = jnp.einsum("tkf,tkfd->tkd", jax.nn.silu(gate) * up,
+                      experts["w_out"].astype(jnp.float32)[at])
+    want = jnp.sum(jnp.where(here[..., None], weights[..., None] * want,
+                             0.0), axis=1)
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(want), atol=0.05, rtol=0.05)
 
 
 def _toy_engine(family: str, config: str, depth: int) -> Engine:
@@ -242,6 +301,7 @@ def test_the_engine_counts_the_rows_where_a_share_is_held(family, config,
     ("trinity-large-preview.serve-full", None, (1024, 2048, 4096), 1),
     ("kanana-2-30b-a3b.serve-full", None, None, 1),
     ("lfm2-24b-a2b.serve-full", None, None, 4),
+    ("qwen3-next-80b-a3b.serve-full", (256, 480), (8192, 10240), 4),
 ])
 def test_the_ladder_of_each_routed_cell_s_programs(cell, decode, prefill,
                                                    tiles):
